@@ -1,0 +1,757 @@
+#!/usr/bin/env python3
+# -*- coding: utf-8 -*-
+"""
+The quickest proof that the system still starts on the chip: drive the
+train → generate → serve path once, through the entry points a user
+calls, at the full width of the model the repo trains and times (the
+``lm_8l_16k`` row of ``bench.py``: vocab 32768, dim 768, 8 heads of 96,
+8 scanned + remat'd layers, bf16, flash causal attention), and check
+what comes out by the repo's own means.
+
+    python chip_smoke.py             # one chip: train, generate, serve
+    python chip_smoke.py --chips 4   # ONLY the cross-chip phases
+    JAX_PLATFORMS=cpu python chip_smoke.py --tiny   # CPU rehearsal
+
+One process for every phase and every chip; it starts no child. Each
+phase prints one JSON line; the LAST line of stdout is exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``
+with the device as JAX reports it. Any failed check, any exception, or a
+platform other than ``tpu`` makes ``ok`` false and the exit code
+non-zero. Without an accelerator the script refuses and prints no
+result; ``--tiny`` shrinks the sizes so the control flow can be
+rehearsed on the CPU — it relaxes no check, so such a run still fails.
+"""
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+import traceback
+
+FULL = dict(
+    vocab=32768, dim=768, heads=8, layers=8,
+    train_t=16384, ref_t=512,                       # train
+    prompt=2048, new_tokens=16, gen_t_max=4096,     # generate
+    slots=8, serve_t_max=32768, page=256, chunk=256,  # serve
+    requests=12, prompt_lo=256, prompt_hi=4096, serve_new=32,
+    mm_t=75000, mm_offset=25000, lm4_t=65536,       # --chips 4
+    shard_ctx=25000)
+TINY = dict(
+    vocab=128, dim=64, heads=2, layers=1,
+    train_t=128, ref_t=32,
+    prompt=16, new_tokens=3, gen_t_max=32,
+    slots=2, serve_t_max=128, page=16, chunk=16,
+    requests=3, prompt_lo=8, prompt_hi=40, serve_new=4,
+    mm_t=512, mm_offset=128, lm4_t=256,
+    shard_ctx=100)
+
+# bf16 tolerance, relative to the compared tensor's own scale: two paths
+# that are equal in exact arithmetic may differ by max|a - b| <=
+# BF16_RTOL * max|b| (the repo's "atol ~ 2e-2 at unit scale" class —
+# SKILL.md "Known expected behaviors", tests/test_paged_int8.py). The
+# error is absolute, inherited from bf16 hidden states, so it does not
+# shrink with the magnitude of the individual logit. Measured on the
+# chip (PR 21): kernel-vs-XLA decode logits and flash-vs-plain logits
+# differ by at most 0.03125 at max|logit| 4.5-5.5 — one bf16 ulp in
+# [4, 8), 0.7e-2 of scale.
+BF16_RTOL = 2e-2
+LOSS_ATOL = 2e-2
+
+
+def emit(rec):
+    print(json.dumps(rec, default=str), flush=True)
+
+
+class Programs:
+    """Per-phase record of the programs compiled ahead of running them:
+    seconds to trace + lower, seconds in the backend compile (the part
+    a warm persistent cache removes), and whether the compiled HLO
+    holds a Mosaic kernel (``tpu_custom_call``) — an interpreted or
+    reference path cannot pass for a program that is supposed to
+    contain one."""
+
+    def __init__(self):
+        self.records = {}
+
+    def compile(self, name, jitted, *args, pallas, collectives=()):
+        t0 = time.perf_counter()
+        lowered = jitted.lower(*args)
+        t1 = time.perf_counter()
+        compiled = lowered.compile()
+        rec = {'lower_seconds': round(t1 - t0, 3),
+               'compile_seconds': round(time.perf_counter() - t1, 3)}
+        text = compiled.as_text()
+        if pallas:
+            rec['tpu_custom_call'] = 'tpu_custom_call' in text
+        for op in collectives:
+            rec[op] = op in text
+        self.records[name] = rec
+        return compiled
+
+    @property
+    def seconds(self):
+        return round(sum(r['compile_seconds']
+                         for r in self.records.values()), 3)
+
+    def checks(self):
+        return {f'{name}.{key}': val
+                for name, rec in self.records.items()
+                for key, val in rec.items() if isinstance(val, bool)}
+
+
+def run_phase(name, fn, *args):
+    """Run one phase and print its line. An exception is reported as a
+    failed phase (traceback on stderr) so the remaining phases still
+    run in the same chip call; it can never turn into a pass."""
+    t0 = time.perf_counter()
+    progs = Programs()
+    try:
+        rec = fn(progs, *args)
+    except Exception as e:
+        traceback.print_exc()
+        rec = {'checks': {}, 'error': f'{type(e).__name__}: {e}'[:800]}
+    checks = {**progs.checks(), **rec.pop('checks')}
+    ok = 'error' not in rec and bool(checks) and all(checks.values())
+    emit({'phase': name, 'ok': ok,
+          'seconds': round(time.perf_counter() - t0, 3),
+          'compile_seconds': progs.seconds, **rec, 'checks': checks,
+          'programs': progs.records})
+    return ok
+
+
+def max_err(a, b):
+    """``(max|a - b|, max|b|)`` in f32."""
+    import numpy as np
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b))), float(np.max(np.abs(b)))
+
+
+def within_bf16(err, scale):
+    return err <= BF16_RTOL * scale
+
+
+# -- information lines ---------------------------------------------------
+
+def sync_probe(tiny):
+    """Does ``jax.block_until_ready`` fence on this machine, and what do
+    it and ``utils.tracing.hard_sync`` cost on a finished array? (The
+    timing helpers were shaped around a backend where it did not.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu.utils.tracing import hard_sync
+
+    n, reps = (256, 8) if tiny else (4096, 64)
+    x = jnp.ones((n, n), jnp.bfloat16)
+    eye = jnp.eye(n, dtype=jnp.bfloat16)
+    work = jax.jit(lambda a, w: jax.lax.fori_loop(
+        0, reps, lambda _, c: c @ w, a))
+    hard_sync(work(x, eye))                      # compile + warm
+    t0 = time.perf_counter()
+    y = work(x, eye)
+    dispatched = time.perf_counter() - t0
+    jax.block_until_ready(y)
+    blocked = time.perf_counter() - t0
+    hard_sync(y)
+    synced = time.perf_counter() - t0
+
+    def cost(fn):
+        out = []
+        for _ in range(50):
+            t = time.perf_counter()
+            fn(y)
+            out.append(time.perf_counter() - t)
+        return statistics.median(out)
+
+    emit({'info': 'sync', 'work': f'{reps} x ({n},{n}) bf16 matmul',
+          'dispatch_returned_s': dispatched,
+          'block_until_ready_returned_s': blocked,
+          'hard_sync_after_returned_s': synced,
+          # Nearly all of the wait sat inside block_until_ready.
+          'block_until_ready_fences': blocked >= 0.9 * synced,
+          'block_until_ready_on_finished_s': cost(jax.block_until_ready),
+          'hard_sync_on_finished_s': cost(hard_sync)})
+
+
+# -- one chip: train -----------------------------------------------------
+
+def lm(cfg, **attn_kwargs):
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu import TransformerLM
+    return TransformerLM(
+        vocab_size=cfg['vocab'], dim=cfg['dim'], num_heads=cfg['heads'],
+        n_layers=cfg['layers'], dtype=jnp.bfloat16, scan_layers=True,
+        remat=True,
+        attn_kwargs={'softmax_impl': 'flash', **attn_kwargs})
+
+
+def seeded_batch(cfg, seed, t, mesh):
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_dot_product_tpu import lm_targets
+    from distributed_dot_product_tpu.parallel.mesh import globalize
+    from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
+    tokens = jax.random.randint(jax.random.key(seed), (1, t), 0,
+                                cfg['vocab'], dtype='int32')
+    spec = NamedSharding(mesh, P(None, SEQ_AXIS))
+    return (globalize(tokens, spec),
+            globalize(lm_targets(tokens), spec)), tokens
+
+
+def phase_train(progs, cfg, seed, state):
+    import jax
+    import numpy as np
+    import optax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_dot_product_tpu import (
+        TrainLoopConfig, TrainState, lm_targets, run_training,
+    )
+    from distributed_dot_product_tpu.parallel.mesh import seq_mesh
+    from distributed_dot_product_tpu.train import make_lm_train_step
+    from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
+
+    mesh = seq_mesh(1)
+    model = lm(cfg)
+    batch, tokens = seeded_batch(cfg, seed, cfg['train_t'], mesh)
+    params = model.init(jax.random.key(seed + 1), tokens[:, :16])
+    optimizer = optax.adam(1e-3)
+    opt_state = optimizer.init(params)
+    # The step examples/train_lm.py builds: guarded, not donating.
+    step = make_lm_train_step(model, optimizer, mesh, donate=False,
+                              guard=True)
+    progs.compile('train_step', step, params, opt_state, batch,
+                  pallas=True)
+    result = run_training(
+        step, TrainState(0, params, opt_state), lambda i: batch,
+        TrainLoopConfig(num_steps=3, final_save=False,
+                        tokens_per_step=cfg['train_t']))
+    losses = [result.losses[i] for i in range(3)]
+    state['model'], state['params'] = model, result.state.params
+
+    # Same weights, short sequence: the flash path against the plain
+    # one (no shard_map, full softmax) — loss and logits.
+    ref = lm(cfg, distributed=False, softmax_impl='full')
+    tok = tokens[:, :cfg['ref_t']]
+    tgt = lm_targets(tok)
+
+    def flash_local(p, tk, tg):
+        s, c = model.apply(p, tk, tg, deterministic=True,
+                           method='nll_sum')
+        logits = model.apply(p, tk, deterministic=True)
+        return lax.psum(s, SEQ_AXIS) / lax.psum(c, SEQ_AXIS), logits
+
+    seq = P(None, SEQ_AXIS)
+    flash = jax.jit(jax.shard_map(
+        flash_local, mesh=mesh, in_specs=(P(), seq, seq),
+        out_specs=(P(), P(None, SEQ_AXIS, None)), check_vma=False))
+
+    def plain(p, tk, tg):
+        s, c = ref.apply(p, tk, tg, deterministic=True,
+                         method='nll_sum')
+        return s / c, ref.apply(p, tk, deterministic=True)
+
+    trained = result.state.params
+    progs.compile('flash_forward', flash, trained, tok, tgt, pallas=True)
+    loss_f, logits_f = flash(trained, tok, tgt)
+    loss_p, logits_p = jax.jit(plain)(trained, tok, tgt)
+    logit_err, logit_scale = max_err(logits_f, logits_p)
+    return {
+        'T': cfg['train_t'], 'losses': losses,
+        'bad_steps': result.bad_steps,
+        'ref_T': cfg['ref_t'], 'loss_flash': float(loss_f),
+        'loss_plain': float(loss_p), 'logits_max_abs_err': logit_err,
+        'logits_max_abs': logit_scale,
+        'checks': {
+            'losses_finite': bool(np.all(np.isfinite(losses))),
+            'no_bad_steps': result.bad_steps == 0,
+            'loss_step3_below_step1': losses[2] < losses[0],
+            'loss_matches_plain_path':
+                abs(float(loss_f) - float(loss_p)) <= LOSS_ATOL,
+            'logits_match_plain_path': within_bf16(logit_err,
+                                                   logit_scale),
+        }}
+
+
+# -- one chip: generate --------------------------------------------------
+
+def forced_logits(progs, tag, model, params, prompt, forced, t_max,
+                  pallas_step):
+    """Per-step logits of ``model`` along the token path ``forced``
+    (teacher forcing: token ids flip on near-ties between decode impls,
+    logits compare)."""
+    import jax
+    import numpy as np
+    prefill = jax.jit(lambda p, t, c: model.apply(p, t, c,
+                                                  method='prefill'))
+    step = jax.jit(lambda p, t, c: model.apply(p, t, c, method='decode'),
+                   donate_argnums=(2,))
+    caches = model.make_decode_caches(prompt.shape[0], t_max)
+    progs.compile(f'{tag}.prefill', prefill, params, prompt, caches,
+                  pallas=True)
+    progs.compile(f'{tag}.decode', step, params, forced[:, :1], caches,
+                  pallas=pallas_step)
+    caches, logits = prefill(params, prompt, caches)
+    out = [np.asarray(logits[:, -1], np.float32)]
+    for j in range(forced.shape[1] - 1):
+        caches, logits = step(params, forced[:, j:j + 1], caches)
+        out.append(np.asarray(logits[:, -1], np.float32))
+    return np.stack(out)
+
+
+def generate_once(progs, tag, cfg, seed, params, **attn_kwargs):
+    """greedy_generate with the module's decode_impl left at its
+    default, then the same token path through an ``decode_impl='xla'``
+    twin: resolved impl, kernel presence, finite tokens, logit parity."""
+    import jax
+    import numpy as np
+
+    from distributed_dot_product_tpu import greedy_generate
+    from distributed_dot_product_tpu.models import lm as lm_mod
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    model = lm(cfg, **attn_kwargs)
+    twin = lm(cfg, decode_impl='xla', **attn_kwargs)
+    n, steps, t_max = cfg['prompt'], cfg['new_tokens'], cfg['gen_t_max']
+    prompt = jax.random.randint(jax.random.key(seed + 2), (1, n), 0,
+                                cfg['vocab'], dtype='int32')
+    # The compiled pair greedy_generate itself runs.
+    g_prefill, g_step = lm_mod._generate_programs(model, True, 1, n,
+                                                  t_max)
+    caches = model.make_decode_caches(1, t_max)
+    with decode_impl_traces() as traces:
+        progs.compile(f'{tag}.generate_prefill', g_prefill, params,
+                      prompt, caches, pallas=True)
+        progs.compile(f'{tag}.generate_step', g_step, params,
+                      prompt[:, :1], caches, pallas=True)
+        tokens = greedy_generate(model, params, prompt, steps,
+                                 t_max=t_max)
+    resolved = sorted({t['resolved'] for t in traces})
+    tokens = np.asarray(tokens)
+    ours = forced_logits(progs, f'{tag}.auto', model, params, prompt,
+                         tokens, t_max, pallas_step=True)
+    theirs = forced_logits(progs, f'{tag}.xla', twin, params, prompt,
+                           tokens, t_max, pallas_step=False)
+    err, scale = max_err(ours[1:], theirs[1:])
+    return {
+        f'{tag}_resolved_impl': resolved,
+        f'{tag}_logits_max_abs_err': err,
+        f'{tag}_logits_max_abs': scale,
+        f'{tag}_replay_argmax_agrees': int(np.sum(
+            ours.argmax(-1)[:, 0] == tokens[0])),
+    }, {
+        f'{tag}.resolved_kernel': resolved == ['kernel'],
+        f'{tag}.tokens_shape': tokens.shape == (1, steps),
+        f'{tag}.tokens_in_vocab': bool(
+            np.all((tokens >= 0) & (tokens < cfg['vocab']))),
+        f'{tag}.logits_finite': bool(np.all(np.isfinite(ours))),
+        f'{tag}.decode_logits_match_xla': within_bf16(err, scale),
+    }
+
+
+def phase_generate(progs, cfg, seed, state):
+    import jax
+    if 'params' not in state:
+        raise RuntimeError('the train phase left no weights to '
+                           'generate with')
+    rec, checks = generate_once(progs, 'bf16', cfg, seed,
+                                state['params'])
+    # The int8 K-mirror form of the kernel: a fresh model, no training.
+    q_model = lm(cfg, qk_quant='int8')
+    q_params = q_model.init(
+        jax.random.key(seed + 3),
+        jax.numpy.zeros((1, 16), 'int32'))
+    q_rec, q_checks = generate_once(progs, 'int8', cfg, seed, q_params,
+                                    qk_quant='int8')
+    return {'prompt': cfg['prompt'], 'new_tokens': cfg['new_tokens'],
+            't_max': cfg['gen_t_max'], **rec, **q_rec,
+            'checks': {**checks, **q_checks}}
+
+
+# -- one chip: serve -----------------------------------------------------
+
+def engine(cfg, seed, **kw):
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu.serve import KernelEngine
+    return KernelEngine(
+        slots=cfg['slots'], t_max=cfg['serve_t_max'], vocab=cfg['vocab'],
+        heads=cfg['heads'], head_dim=cfg['dim'] // cfg['heads'],
+        dtype=jnp.bfloat16, cache_mode='paged', page_size=cfg['page'],
+        prefill_chunk=cfg['chunk'], seed=seed, **kw)
+
+
+def prefill_slot(eng, slot, prompt):
+    for i in range(0, len(prompt), eng.prefill_chunk):
+        eng.prefill(slot, prompt[i:i + eng.prefill_chunk])
+
+
+def compile_engine_decode(progs, name, eng, **kw):
+    import jax.numpy as jnp
+    s = eng.slots
+    return progs.compile(
+        name, eng._decode, eng.cache, jnp.zeros(s, jnp.int32),
+        jnp.ones(s, bool), jnp.zeros(s, bool), **kw)
+
+
+def phase_serve(progs, cfg, seed, out_dir):
+    import numpy as np
+
+    from distributed_dot_product_tpu import obs
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    from distributed_dot_product_tpu.obs import critpath
+    from distributed_dot_product_tpu.serve import ServeConfig
+    from distributed_dot_product_tpu.serve.loadgen import (
+        LoadGenConfig, TenantSpec, generate_trace, run_load,
+    )
+    load = LoadGenConfig(
+        seed=seed, rate=200.0, requests=cfg['requests'],
+        vocab=cfg['vocab'],
+        tenants=[TenantSpec('t0', prompt_lo=cfg['prompt_lo'],
+                            prompt_hi=cfg['prompt_hi'],
+                            new_lo=cfg['serve_new'],
+                            new_hi=cfg['serve_new'])])
+    trace = generate_trace(load)
+
+    eng = engine(cfg, seed)              # decode_impl left at default
+    with decode_impl_traces() as traces:
+        compile_engine_decode(progs, 'engine.decode', eng, pallas=True)
+    resolved = sorted({t['resolved'] for t in traces})
+
+    # First decode step after the first request's prompt: logits of
+    # the default engine against an XLA-step twin, same seed.
+    twin = engine(cfg, seed, decode_impl='xla')
+    first = trace[0].prompt
+    active = np.arange(cfg['slots']) == 0
+    tokens = np.where(active, first[-1], 0)
+    for e in (eng, twin):
+        prefill_slot(e, 0, first[:-1])
+    ours = eng.peek_logits(tokens, active)[0]
+    theirs = twin.peek_logits(tokens, active)[0]
+    err, scale = max_err(ours, theirs)
+    eng.reset(0)
+    del twin
+
+    path = os.path.join(out_dir, 'serve_events.jsonl')
+    if os.path.exists(path):
+        os.remove(path)
+    log = obs.EventLog(path)
+    before = eng.program_seconds
+    res = run_load(load, engine=eng, event_log=log,
+                   serve_config=ServeConfig(
+                       queue_limit=32, max_new_tokens=cfg['serve_new'],
+                       watchdog=False))
+    log.close()
+    device_seconds = eng.program_seconds - before
+    _, errors = obs.validate_file(path)
+    timelines = obs.reconstruct(path)
+    dispatch = critpath.dispatch_floor(path)['per_replica']
+    emit({'info': 'serve.dispatch',
+          'engine_program_seconds': device_seconds,
+          'run_wall_seconds': res.wall_seconds, 'ticks': res.ticks,
+          'per_replica': dispatch})
+
+    ids = [rid for rid, _ in res.submitted]
+    results = [res.results.get(rid) for rid in ids]
+    return {
+        'requests': len(ids), 'resolved_impl': resolved,
+        'prompt_lens': [len(a.prompt) for a in trace],
+        'tokens_generated': sum(len(r.tokens) for r in results if r),
+        'first_step_logits_max_abs_err': err,
+        'first_step_logits_max_abs': scale,
+        'event_log': path, 'schema_errors': errors[:5],
+        'checks': {
+            'resolved_kernel': resolved == ['kernel'],
+            'all_submitted': len(ids) == cfg['requests']
+                             and not res.rejected_at_submit,
+            'all_completed': all(r is not None
+                                 and r.status == 'completed'
+                                 for r in results),
+            'all_full_length': all(
+                r is not None and not r.degraded
+                and len(r.tokens) == cfg['serve_new'] for r in results),
+            'event_log_schema_clean': not errors,
+            'all_timelines_complete': all(
+                rid in timelines and timelines[rid].complete
+                for rid in ids),
+            'first_step_logits_finite': bool(np.all(np.isfinite(ours))),
+            'first_step_logits_match_xla': within_bf16(err, scale),
+        }}
+
+
+# -- four chips ----------------------------------------------------------
+
+def on_all(x, n):
+    return len(x.sharding.device_set) == n
+
+
+def phase_matmuls(progs, cfg, seed, mesh):
+    """The three primitives on the reference workload, each against the
+    unsharded product on sampled row blocks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_dot_product_tpu.ops.functions import (
+        distributed_matmul_all_global, distributed_matmul_nt_global,
+        distributed_matmul_tn_global,
+    )
+    from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
+
+    n = mesh.devices.size
+    t, d, offset = cfg['mm_t'], cfg['dim'], cfg['mm_offset']
+    t -= t % n
+    rows_n = min(256, t // n)
+
+    def normal(key, shape):
+        # Each shard draws its own rows on its own chip: a (T, T)
+        # operand never exists whole on one device.
+        def local(k):
+            k = jax.random.fold_in(k, lax.axis_index(SEQ_AXIS))
+            return jax.random.normal(k, (shape[0] // n, shape[1]),
+                                     jnp.bfloat16)
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh, in_specs=P(), out_specs=P(SEQ_AXIS, None),
+            check_vma=False))(key)
+
+    k1, k2, k3 = jax.random.split(jax.random.key(seed), 3)
+    small_l, right = normal(k1, (t, d)), normal(k2, (t, d))
+    square = normal(k3, (t, t))
+    def shards(x):
+        return sorted(x.addressable_shards,
+                      key=lambda sh: sh.index[0].start or 0)
+
+    def rows(x, s):
+        """Rows ``s`` (inside ONE shard) of a row-sharded array, f32 on
+        the host: a plain slice of that shard on its own chip. Indexing
+        the global array instead compiles a gather over all of it — at
+        (75000, 75000) on the four-chip v5e that compile alone outlasted
+        a 30-minute call (PR 21)."""
+        sh = next(sh for sh in shards(x)
+                  if sh.index[0].start <= s.start
+                  and s.stop <= sh.index[0].stop)
+        lo = s.start - sh.index[0].start
+        return np.asarray(lax.slice_in_dim(sh.data, lo, lo + rows_n),
+                          np.float32)
+
+    def cols(x, s):
+        """Columns ``s`` of every shard, stacked in row order."""
+        return np.concatenate([
+            np.asarray(lax.slice_in_dim(sh.data, s.start, s.stop, axis=1),
+                       np.float32) for sh in shards(x)])
+
+    # name: (program, left operand, expected collective, rows [s] of
+    # the unsharded product from host f32 copies of the operands)
+    ops = {
+        'nt': (lambda l, r: distributed_matmul_nt_global(
+            l, r, offset=offset, mesh=mesh), small_l, 'all-gather',
+            lambda l, r, s: rows(l, s) @ r.T),
+        'all': (lambda l, r: distributed_matmul_all_global(
+            l, r, offset=offset, mesh=mesh), square, 'all-gather',
+            lambda l, r, s: rows(l, s) @ r),
+        'tn': (lambda l, r: distributed_matmul_tn_global(
+            l, r, mesh=mesh), square, 'reduce-scatter',
+            lambda l, r, s: cols(l, s).T @ r),
+    }
+    right_host = np.concatenate([np.asarray(sh.data, np.float32)
+                                 for sh in shards(right)])
+    per = t // n
+    rec, checks = {}, {}
+    for name, (fn, left, collective, reference) in ops.items():
+        compiled = progs.compile(name, jax.jit(fn), left, right,
+                                 pallas=False, collectives=(collective,))
+        out = compiled(left, right)
+        errs = []
+        # One block in the first, a middle and the last shard.
+        for shard in sorted({0, n // 2, n - 1}):
+            start = shard * per + (per - rows_n) // 2
+            s = slice(start, start + rows_n)
+            want = reference(left, right_host, s)
+            err, scale = max_err(rows(out, s), want)
+            errs.append((err, scale))
+            checks[f'{name}.rows_{start}_match'] = within_bf16(err,
+                                                               scale)
+        rec[f'{name}_max_abs_err'] = max(e for e, _ in errs)
+        rec[f'{name}_max_abs'] = max(s for _, s in errs)
+        checks[f'{name}.output_on_{n}_devices'] = on_all(out, n)
+        checks[f'{name}.inputs_on_{n}_devices'] = (on_all(left, n)
+                                                   and on_all(right, n))
+        del out, compiled
+    return {'T': t, 'd': d, 'offset': offset, **rec, 'checks': checks}
+
+
+def phase_lm_sharded(progs, cfg, seed, mesh):
+    """One LM train step with the sequence split over the mesh — ring
+    ('online') and all-gather ('flash') attention — each loss against
+    the same step on a one-device sub-mesh of the same process."""
+    import jax
+    import numpy as np
+    import optax
+
+    from distributed_dot_product_tpu.parallel.mesh import seq_mesh
+    from distributed_dot_product_tpu.train import make_lm_train_step
+
+    n = mesh.devices.size
+    one = seq_mesh(1)
+    t = cfg['lm4_t']
+    rec, checks = {'T': t}, {}
+    for impl, collective in (('online', 'collective-permute'),
+                             ('flash', 'all-gather')):
+        model = lm(cfg, softmax_impl=impl)
+        losses = {}
+        for tag, m in ((f'{n}chip', mesh), ('1chip', one)):
+            batch, tokens = seeded_batch(cfg, seed, t, m)
+            params = model.init(jax.random.key(seed + 1),
+                                tokens[:, :16 * n])
+            optimizer = optax.adam(1e-3)
+            opt_state = optimizer.init(params)
+            step = make_lm_train_step(model, optimizer, m, donate=False)
+            many = m is mesh
+            compiled = progs.compile(
+                f'{impl}.{tag}', step, params, opt_state, batch,
+                pallas=True,
+                collectives=(collective, 'all-reduce') if many else ())
+            new_params, _, loss = compiled(params, opt_state, batch)
+            losses[tag] = float(loss)
+            if many:
+                leaf = jax.tree.leaves(new_params)[0]
+                checks[f'{impl}.tokens_on_{n}_devices'] = on_all(
+                    batch[0], n)
+                checks[f'{impl}.params_on_{n}_devices'] = on_all(leaf, n)
+            del new_params, compiled
+        rec[f'{impl}_losses'] = losses
+        checks[f'{impl}.losses_finite'] = bool(
+            np.all(np.isfinite(list(losses.values()))))
+        checks[f'{impl}.loss_matches_one_device'] = (
+            abs(losses[f'{n}chip'] - losses['1chip']) <= LOSS_ATOL)
+    return {**rec, 'checks': checks}
+
+
+def phase_kv_sharded(progs, cfg, seed, mesh):
+    """One stream whose context spans every shard of a kv_shards
+    engine: next-step logits and a few greedy tokens against the
+    single-pool engine."""
+    import numpy as np
+
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    n = mesh.devices.size
+    ctx = cfg['shard_ctx']
+    prompt = np.random.default_rng(seed).integers(
+        0, cfg['vocab'], size=ctx).astype(np.int32)
+    active = np.arange(cfg['slots']) == 0
+    tokens = np.where(active, prompt[-1], 0)
+    sharded = engine(cfg, seed, kv_shards=n)
+    single = engine(cfg, seed)
+    with decode_impl_traces() as traces:
+        compile_engine_decode(progs, f'engine.decode.kv_shards_{n}',
+                              sharded, pallas=True,
+                              collectives=('all-reduce',))
+    resolved = sorted({t['resolved'] for t in traces})
+    for e in (sharded, single):
+        prefill_slot(e, 0, prompt[:-1])
+    per_shard = [p.used_pages for p in sharded.pool.shards]
+    ours = sharded.peek_logits(tokens, active)[0]
+    theirs = single.peek_logits(tokens, active)[0]
+    err, scale = max_err(ours, theirs)
+    streams = []
+    for e in (sharded, single):
+        tok, out = tokens, []
+        for _ in range(4):
+            tok, finite = e.step(tok, active)
+            out.append((int(tok[0]), bool(finite[0])))
+        streams.append(out)
+    return {
+        'context': ctx, 'kv_shards': n, 'resolved_impl': resolved,
+        'pages_used_by_shard': per_shard,
+        'logits_max_abs_err': err, 'logits_max_abs': scale,
+        'streams': streams,
+        'checks': {
+            'resolved_kernel': resolved == ['kernel'],
+            f'pool_on_{n}_devices': on_all(sharded.cache.k_pool, n),
+            'context_spans_every_shard': all(p > 0 for p in per_shard),
+            'logits_finite': bool(np.all(np.isfinite(ours))),
+            'logits_match_single_pool': within_bf16(err, scale),
+            'steps_finite': all(f for _, f in streams[0]),
+        }}
+
+
+# -- driver --------------------------------------------------------------
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
+    ap.add_argument('--chips', type=int, choices=(1, 4), default=1,
+                    help='4: run ONLY the cross-chip phases')
+    ap.add_argument('--tiny', action='store_true',
+                    help='CPU rehearsal sizes; relaxes no check')
+    ap.add_argument('--seed', type=int, default=0)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from distributed_dot_product_tpu.parallel.mesh import seq_mesh
+    from distributed_dot_product_tpu.utils.compile_cache import (
+        setup_compile_cache,
+    )
+    cache_dir = setup_compile_cache()
+    # Every program, small ones too: a second run in the same machine
+    # then compiles nothing.
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)
+
+    devices = jax.devices()
+    device = {'platform': devices[0].platform,
+              'kind': devices[0].device_kind, 'count': len(devices)}
+    on_chip = device['platform'] == 'tpu'
+    if not on_chip and not args.tiny:
+        print(f'chip_smoke: JAX found no TPU (platform '
+              f'{device["platform"]!r}); nothing was run and no result '
+              f'is printed. `--tiny` rehearses the control flow on the '
+              f'CPU (and still fails).', file=sys.stderr)
+        return 2
+    if len(devices) < args.chips:
+        print(f'chip_smoke: --chips {args.chips} needs {args.chips} '
+              f'devices, JAX found {len(devices)}; nothing was run.',
+              file=sys.stderr)
+        return 2
+
+    cfg = TINY if args.tiny else FULL
+    out_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           'chiprun_out', 'chip_smoke')
+    os.makedirs(out_dir, exist_ok=True)
+    emit({'info': 'start', 'device': device, 'chips': args.chips,
+          'sizes': 'tiny' if args.tiny else 'full', 'seed': args.seed,
+          'jax': jax.__version__, 'compile_cache': cache_dir})
+
+    if args.chips == 1:
+        sync_probe(args.tiny)
+        state = {}
+        oks = [run_phase('train', phase_train, cfg, args.seed, state),
+               run_phase('generate', phase_generate, cfg, args.seed,
+                         state),
+               run_phase('serve', phase_serve, cfg, args.seed, out_dir)]
+    else:
+        mesh = seq_mesh(args.chips)
+        oks = [run_phase('matmuls', phase_matmuls, cfg, args.seed, mesh),
+               run_phase('lm_sharded', phase_lm_sharded, cfg, args.seed,
+                         mesh),
+               run_phase('kv_sharded', phase_kv_sharded, cfg, args.seed,
+                         mesh)]
+    ok = on_chip and all(oks)
+    print(json.dumps({'ok': ok, 'device': device}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == '__main__':
+    sys.exit(main())

@@ -28,7 +28,6 @@ Version parity note: the reference exposes ``VERSION_INFO`` in its
 ``__init__.py`` (reference __init__.py:9-10); we keep the same convention.
 """
 
-from distributed_dot_product_tpu import _compat  # noqa: F401  (shims first)
 from distributed_dot_product_tpu._version import (  # noqa: F401
     VERSION_INFO, __version__,
 )
@@ -56,8 +55,9 @@ from distributed_dot_product_tpu.models.ring_attention import (  # noqa: F401
 )
 from distributed_dot_product_tpu.models.decode import (  # noqa: F401
     DecodeCache, append_kv, append_kv_sharded, append_kv_slots,
-    decode_attention, decode_kernel_eligible, decode_step, init_cache,
-    init_slot_cache, reset_slot, slots_all_finite,
+    decode_attention, decode_impl_traces, decode_kernel_eligible,
+    decode_step, init_cache, init_slot_cache, reset_slot,
+    slots_all_finite,
 )
 from distributed_dot_product_tpu.models.dense import (  # noqa: F401
     OwnedDense, quantize_dense_params, quantize_kernel,

@@ -1,77 +1,16 @@
 # -*- coding: utf-8 -*-
-"""
-Version-compatibility shims (no new dependencies — gate, don't install).
-
-The codebase targets the current jax API surface; deployment containers
-often pin older wheels (this repo's CI image ships jax 0.4.x). Rather than
-fork every call site, the two renamed surfaces are bridged here once:
-
-- ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=..., check_vma=...)``
-  became a top-level alias of ``jax.experimental.shard_map.shard_map``
-  only in newer jax, and the replication-check kwarg was renamed
-  ``check_rep`` → ``check_vma``. On old jax we install a thin adapter
-  under the NEW name (the name the whole codebase and its tests use), so
-  one code path runs on both versions.
-- ``jax.config.jax_num_cpu_devices`` (virtual CPU device provisioning)
-  does not exist on old jax; :func:`ensure_cpu_devices` falls back to the
-  ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` env knob, which
-  must land before the CPU backend initializes (backend choice is lazy,
-  so any import-time caller — conftest, subprocess re-execs — is in time).
-
-Importing this module applies the shard_map shim; it is imported by
-``distributed_dot_product_tpu/__init__.py`` before anything else, so any
-``import distributed_dot_product_tpu`` is sufficient.
-"""
-
-import os
-import re
+"""Virtual CPU device provisioning for tests, CI gates and CPU-mesh
+subprocesses (the name is historical: this module once bridged older
+JAX releases; the code now targets the installed JAX only)."""
 
 import jax
 
-__all__ = ['ensure_cpu_devices', 'apply_shims']
-
-
-def _shard_map_adapter():
-    """A ``jax.shard_map``-shaped wrapper over the legacy
-    ``jax.experimental.shard_map.shard_map``."""
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=True, **kwargs):
-        # Accept either kwarg spelling; the legacy API only knows check_rep.
-        check_rep = kwargs.pop('check_rep', check_vma)
-        return _legacy(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=check_rep, **kwargs)
-
-    shard_map.__doc__ = _legacy.__doc__
-    return shard_map
-
-
-def apply_shims():
-    """Install the bridges on old jax; a no-op on current jax."""
-    if not hasattr(jax, 'shard_map'):
-        jax.shard_map = _shard_map_adapter()
+__all__ = ['ensure_cpu_devices']
 
 
 def ensure_cpu_devices(n, force_cpu=True):
-    """Provision an ``n``-wide virtual CPU platform on ANY jax version.
-
-    Must run before the backend initializes (the first ``jax.devices()``
-    /computation). On new jax this is ``jax_num_cpu_devices``; on old jax
-    it falls back to the XLA_FLAGS host-platform knob, which the CPU
-    client reads at initialization.
-    """
+    """Provision an ``n``-wide virtual CPU platform. Must run before the
+    backend initializes (the first ``jax.devices()``/computation)."""
     if force_cpu:
         jax.config.update('jax_platforms', 'cpu')
-    try:
-        jax.config.update('jax_num_cpu_devices', n)
-    except AttributeError:
-        # Replace (not append-beside) any existing count: re-exec chains
-        # legitimately move between widths (1-device probe -> 8-wide mesh).
-        flags = re.sub(r'--xla_force_host_platform_device_count=\d+', '',
-                       os.environ.get('XLA_FLAGS', ''))
-        os.environ['XLA_FLAGS'] = (
-            f'{flags} --xla_force_host_platform_device_count={n}'.strip())
-
-
-apply_shims()
+    jax.config.update('jax_num_cpu_devices', n)

@@ -58,15 +58,12 @@ _COLLECTIVES = frozenset({
 
 def _src(eqn):
     """(file, line) of the user frame that traced this equation, or
-    (None, None) — best-effort, jaxpr source_info is optional."""
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return frame.file_name, frame.start_line
-    except Exception:  # graphlint: allow[silent-except] best-effort
-        pass       # (source info is optional metadata; None is the API)
-    return None, None
+    (None, None) — an equation may carry no traceback."""
+    from jax._src import source_info_util
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return None, None
+    return frame.file_name, frame.start_line
 
 
 def _sub_jaxprs(eqn):

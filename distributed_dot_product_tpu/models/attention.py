@@ -46,7 +46,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_dot_product_tpu.models import features
 from distributed_dot_product_tpu.models.dense import OwnedDense
@@ -759,6 +759,17 @@ def apply_seq_parallel(module, params, mesh, keys, queries, values,
       dropout_seed, drop_key)
 
 
+def _decode_cache_spec(module, mesh_axis):
+    """PartitionSpecs of the slab-sharded decode cache (``t_max`` axis
+    over the mesh, global length replicated)."""
+    from distributed_dot_product_tpu.models.decode import DecodeCache
+    spec4 = P(None, None, mesh_axis, None)
+    quant = module.qk_quant == 'int8'
+    return DecodeCache(k=spec4, v=spec4, length=P(),
+                       k_q=spec4 if quant else None,
+                       k_scale=spec4 if quant else None)
+
+
 def make_decode_step(module, mesh, mesh_axis=None, donate=True):
     """Build the sequence-sharded decode step ONCE for a serving loop:
     ``step(params, keys, queries, values, cache) -> (cache, out)`` with
@@ -774,12 +785,7 @@ def make_decode_step(module, mesh, mesh_axis=None, donate=True):
     in place — donation then means the slab is NEVER copied, not even
     once per step."""
     mesh_axis = mesh_axis or module.axis_name
-    from distributed_dot_product_tpu.models.decode import DecodeCache
-    spec4 = P(None, None, mesh_axis, None)
-    quant = module.qk_quant == 'int8'
-    cache_spec = DecodeCache(k=spec4, v=spec4, length=P(),
-                             k_q=spec4 if quant else None,
-                             k_scale=spec4 if quant else None)
+    cache_spec = _decode_cache_spec(module, mesh_axis)
 
     def fn(p, k, q, v, c):
         return module.apply(p, k, q, v, c, method='decode_sharded',
@@ -859,6 +865,13 @@ def decode_seq_parallel(module, params, mesh, keys, queries, values,
                 'hashable field (e.g. a tuple of slopes) or build the '
                 'step once with make_decode_step.', stacklevel=2)
         step = make_decode_step(module, mesh, mesh_axis)
+    # Shard the cache BEFORE the call (free once it is the step's own
+    # output): to jit, a fresh single-device cache and the mesh-sharded
+    # one the step returns are different signatures, and the loop would
+    # trace and compile the step a second time at token two.
+    cache = jax.device_put(cache, jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec),
+        _decode_cache_spec(module, mesh_axis or module.axis_name)))
     return step(params, keys, queries, values, cache)
 
 

@@ -44,6 +44,7 @@ copies the whole K/V buffer pair first (~1 ms/token at T=131K, measured;
 RESULTS.md "KV-cache decode").
 """
 
+import contextlib
 import math
 import zlib
 from typing import NamedTuple, Optional
@@ -59,7 +60,8 @@ from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 __all__ = ['DecodeCache', 'init_cache', 'append_kv', 'append_kv_sharded',
            'decode_attention', 'init_slot_cache', 'append_kv_slots',
            'reset_slot', 'slots_all_finite', 'decode_step',
-           'decode_kernel_eligible', 'rollback_slots',
+           'decode_kernel_eligible', 'decode_impl_traces',
+           'rollback_slots',
            'PagedDecodeCache', 'PagePool', 'PageChecksums',
            'ShardedPageTable', 'init_sharded_paged_cache',
            'init_paged_cache', 'paged_gather', 'paged_gather_mirror',
@@ -1766,18 +1768,41 @@ def decode_kernel_eligible(cache, n=1, segment_ids=None, qk_quant=None,
 
 def _axis_env_size(axis_name):
     """Static size of ``axis_name`` when tracing inside its shard_map
-    (the axis env records the mesh axis size — a host int, no traced
-    value involved); 2 — "sharded, count unknown" — when no axis env
-    is active (a direct host-side probe outside any mesh: every
-    sharded gate keys on ``n_shards > 1``, not the count)."""
+    (a host int, no traced value involved); 2 — "sharded, count
+    unknown" — when the axis is unbound (a direct host-side probe
+    outside any mesh: every sharded gate keys on ``n_shards > 1``, not
+    the count)."""
     if axis_name is None:
         return 1
     try:
-        frame = jax.core.axis_frame(axis_name)
-    except NameError:       # no axis env: probed outside the mesh
+        return lax.axis_size(axis_name)
+    except NameError:       # unbound axis: probed outside the mesh
         return 2
-    # 0.4.x returns the size directly; older envs a frame object.
-    return int(getattr(frame, 'size', frame))
+
+
+_IMPL_SINKS = []        # lists of the active decode_impl_traces() blocks
+
+
+@contextlib.contextmanager
+def decode_impl_traces():
+    """Collect what :func:`decode_step` resolves ``impl`` to while the
+    block runs: one dict ``{'requested', 'resolved', 'reason'}`` per
+    TRACE (= per compiled step; ``reason`` names why ``'auto'`` fell
+    back to ``'xla'``, else None). ``'auto'`` takes the XLA formulation
+    off-TPU and wherever the kernel does not cover the call, so a smoke
+    or benchmark run wraps the compile of its step in this and asserts
+    the path the program holds instead of trusting it::
+
+        with decode_impl_traces() as traces:
+            step.lower(*args).compile()
+        assert {t['resolved'] for t in traces} == {'kernel'}
+    """
+    sink = []
+    _IMPL_SINKS.append(sink)
+    try:
+        yield sink
+    finally:
+        _IMPL_SINKS[:] = [s for s in _IMPL_SINKS if s is not sink]
 
 
 def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
@@ -1788,32 +1813,35 @@ def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
     # (unsharded) probe here and only blew up at the late kernel-path
     # check, with no geometry in the error.
     n_shards = _axis_env_size(axis_name)
-    if impl in (None, 'auto'):
-        # Mirror the flash-kernel gating: the kernel is the TPU path;
-        # elsewhere it would run interpreted (covered by tests that
-        # force impl='kernel'), so the portable XLA step is the default.
-        # Sharded verify-k (axis_name + n > 1) is XLA-only — the
-        # kernel's flash-decoding merge carries one row per shard —
-        # so 'auto' must fall back rather than resolve to a path that
-        # raises; the n_shards-aware probe encodes that gate.
-        if (decode_kernel_eligible(cache, n, segment_ids, qk_quant,
-                                   n_shards=n_shards)
-                and jax.default_backend() == 'tpu'):
-            return 'kernel'
-        return 'xla'
-    if impl not in ('kernel', 'xla'):
+    if impl not in (None, 'auto', 'kernel', 'xla'):
         raise ValueError(f"decode impl must be None/'auto'/'kernel'/"
                          f"'xla', got {impl!r}")
-    if impl == 'kernel':
-        ok, reason = decode_kernel_eligible(cache, n, segment_ids,
-                                            qk_quant, explain=True,
-                                            n_shards=n_shards)
-        if not ok:
-            raise ValueError(
-                f'decode_step: the fused kernel does not cover this '
-                f"call — {reason} — use impl='auto' to fall back to "
-                f'the XLA formulation')
-    return impl
+    resolved, reason = impl, None
+    if impl != 'xla':
+        ok, why = decode_kernel_eligible(cache, n, segment_ids, qk_quant,
+                                         explain=True, n_shards=n_shards)
+        if impl == 'kernel':
+            if not ok:
+                raise ValueError(
+                    f'decode_step: the fused kernel does not cover this '
+                    f"call — {why} — use impl='auto' to fall back to "
+                    f'the XLA formulation')
+        elif not ok:
+            # Sharded verify-k, packed segments, … : 'auto' must fall
+            # back rather than resolve to a path that raises.
+            resolved, reason = 'xla', why
+        elif jax.default_backend() != 'tpu':
+            # Mirror the flash-kernel gating: the kernel is the TPU
+            # path; elsewhere it would run interpreted (covered by
+            # tests that force impl='kernel').
+            resolved = 'xla'
+            reason = f'backend is {jax.default_backend()}, not tpu'
+        else:
+            resolved = 'kernel'
+    for sink in _IMPL_SINKS:
+        sink.append({'requested': impl or 'auto', 'resolved': resolved,
+                     'reason': reason})
+    return resolved
 
 
 def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,

@@ -9,8 +9,8 @@ Two pieces:
   visible device into labeled gauges (``device.memory.bytes_in_use
   {device="tpu:0"}`` …) on a background thread, so the ``/metrics``
   endpoint answers "how full is each chip RIGHT NOW" without any run
-  touching the devices itself. Backends without stats (CPU, some
-  tunneled PJRT plugins) simply report no gauges — the monitor records
+  touching the devices itself. Backends without stats (the CPU)
+  simply report no gauges — the monitor records
   how many devices answered in ``device.memory.devices_reporting``.
 - :class:`ProfileCapture` owns bounded on-demand ``jax.profiler``
   trace captures: one at a time (a second request while one is in

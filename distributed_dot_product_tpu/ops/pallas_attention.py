@@ -865,16 +865,7 @@ def _pallas_call(kernel, grid, in_specs, out_specs, scratch, out_shape,
     prefetch = [p for p in prefetch if p is not None]
     interp = interpret
     if interpret is True and prefetch:
-        # The HLO interpreter cannot evaluate scalar-prefetch grids —
-        # upgrade to the Mosaic TPU interpreter. The params class moved
-        # across jax versions (InterpretParams / TPUInterpretParams);
-        # old jax has neither, and its HLO interpreter is left to try
-        # (callers on those versions fall back to non-prefetch paths).
-        for name in ('InterpretParams', 'TPUInterpretParams'):
-            cls = getattr(pltpu, name, None)
-            if cls is not None:
-                interp = cls()
-                break
+        interp = pltpu.InterpretParams()
     if prefetch:
         call = pl.pallas_call(
             kernel,

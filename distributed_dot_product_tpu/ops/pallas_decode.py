@@ -93,6 +93,12 @@ def decode_block_k(t_max, cap=_BLOCK_K_CAP):
     return None
 
 
+def _sublane(dtype):
+    """Rows of one TPU sublane tile at ``dtype`` (f32 8, bf16 16,
+    int8 32)."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
 def _pad_rows(x, mult):
     """Pad axis -2 up to a multiple of ``mult``."""
     n = x.shape[-2]
@@ -448,8 +454,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     qg = jnp.swapaxes(q.reshape(b, h_kv, group, n, d), 2, 3
                       ).reshape(nb, n * group, d)
     rows = n * group
-    sub = 32 if quantized else (16 if cache_k.dtype == jnp.bfloat16
-                                else 8)
+    sub = _sublane(jnp.int8 if quantized else cache_k.dtype)
     g_pad = -(-rows // sub) * sub
     if quantized:
         qi, sq = _quantize_rows(qg, nb, rows, d)
@@ -457,13 +462,21 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         sqf = _pad_rows(sq * (scale * _LOG2E), sub)
         kni, kns = _quantize_rows(
             k_new.astype(cache_k.dtype).reshape(nb, 1, d), nb, 1, d)
+        kni = _pad_rows(kni, sub)
     else:
         qf = _pad_rows(
             (qg.astype(jnp.float32) * (scale * _LOG2E)
              ).astype(cache_k.dtype), sub)
 
-    knf = k_new.astype(cache_k.dtype).reshape(nb, n, d)
-    vnf = v_new.astype(cache_v.dtype).reshape(nb, n, dv)
+    # The new rows ride padded to the sublane tile of their dtype: the
+    # Pallas-TPU lowering refuses a dot against a one-row operand (the
+    # n = 1 score of the query rows against the appended row), and an
+    # unaligned (n, d) block would relayout on every load. Rows >= n
+    # are zeros the kernel never substitutes (its loops stop at n).
+    knf = _pad_rows(k_new.astype(cache_k.dtype).reshape(nb, n, d),
+                    _sublane(cache_k.dtype))
+    vnf = _pad_rows(v_new.astype(cache_v.dtype).reshape(nb, n, dv),
+                    _sublane(cache_v.dtype))
     if paged:
         # Pool flattening mirrors the slab's (B, H_kv) fold: pool page
         # p's head hh lives at flat row p·H_kv + hh, so one BlockSpec
@@ -570,13 +583,13 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     if quantized:
         in_specs.append(pl.BlockSpec((1, g_pad, 1), const_idx))
         args.append(sqf)
-    in_specs.append(pl.BlockSpec((1, n, d), const_idx))
+    in_specs.append(pl.BlockSpec((1,) + knf.shape[1:], const_idx))
     args.append(knf)
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, d), const_idx),
+        in_specs += [pl.BlockSpec((1,) + kni.shape[1:], const_idx),
                      pl.BlockSpec((1, 1, 1), const_idx)]
         args += [kni, kns.reshape(nb, 1, 1)]
-    in_specs.append(pl.BlockSpec((1, n, dv), const_idx))
+    in_specs.append(pl.BlockSpec((1,) + vnf.shape[1:], const_idx))
     args.append(vnf)
     # The bf16 K buffer: streamed for scoring in the plain path; in the
     # quantized path scoring reads the mirror instead, so K is fetched

@@ -412,6 +412,7 @@ class KernelEngine:
         # fixed compiled shape under its own retrace budget.
         self._verifies = {}
         self._rollbacks = {}
+        self._peek = None           # peek_logits' program, built lazily
         # Cross-cache KV handoff programs (disaggregated serving):
         # one per SOURCE pool shape — a topology has exactly one
         # prefill pool shape, so one program for the engine's life.
@@ -444,8 +445,7 @@ class KernelEngine:
                 self._dot(x, self._wk).reshape(shape),
                 self._dot(x, self._wv).reshape(shape))
 
-    def _decode_impl(self, cache, tokens, active, poison,
-                     axis_name=None):
+    def _logits_impl(self, cache, tokens, active, axis_name=None):
         q, k, v = self._project(tokens)
         # Fused append+attend (one Pallas program on the kernel path —
         # the cache buffers are aliased in place and, with the jit
@@ -455,8 +455,13 @@ class KernelEngine:
         cache, out = decode_step(q, cache, k, v, slot_mask=active,
                                  impl=self.decode_impl,
                                  axis_name=axis_name)      # (S, H, 1, D)
-        logits = self._dot(out.reshape(self.slots, -1),
-                           self._wo)                       # (S, vocab)
+        return cache, self._dot(out.reshape(self.slots, -1),
+                                self._wo)                  # (S, vocab)
+
+    def _decode_impl(self, cache, tokens, active, poison,
+                     axis_name=None):
+        cache, logits = self._logits_impl(cache, tokens, active,
+                                          axis_name=axis_name)
         logits = jnp.where(poison[:, None], jnp.nan, logits)
         finite = slots_all_finite(logits)
         # Fully-masked argmax input for a poisoned row would be NaN-
@@ -655,6 +660,37 @@ class KernelEngine:
             if self.cache_mode == 'paged':
                 self.pool.lengths[np.asarray(active, bool)] += 1
             return out
+
+    def peek_logits(self, tokens, active):
+        """The NEXT decode step's logits ``(S, vocab)`` for ``tokens``,
+        without taking the step: the cache is neither donated nor
+        replaced, so the engine's streams are unchanged. A parity
+        probe (kernel vs XLA impl, ``kv_shards`` vs one pool) — it
+        compiles its own program and the undonated call copies the
+        cache, so it is no serving path."""
+        if self._peek is None:
+            def body(cache, tokens, active):
+                if self.kv_shards > 1:      # a shard_map member's view
+                    cache = cache._replace(
+                        page_table=cache.page_table[0])
+                return self._logits_impl(
+                    cache, tokens, active,
+                    axis_name=(self._seq_axis if self.kv_shards > 1
+                               else None))[1]
+
+            if self.kv_shards > 1:
+                from jax.sharding import PartitionSpec as P
+                body = self._sharded_program(
+                    body, (self._cache_pspec(), P(), P()), P())
+            self._peek = jax.jit(body)
+        act = np.asarray(active, bool)
+        if (self.cache_mode == 'paged'
+                and not self.prepare_step(act).all()):
+            raise RuntimeError('page pool exhausted preparing the '
+                               'peeked step')
+        return np.asarray(self._peek(
+            self.cache, jnp.asarray(tokens, jnp.int32),
+            jnp.asarray(act)))
 
     def _verify_program(self, w):
         """One compiled verify program per width W = k+1, built lazily

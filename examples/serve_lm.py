@@ -47,6 +47,9 @@ from distributed_dot_product_tpu.serve import (  # noqa: E402
     KernelEngine, Readiness, RejectedError, Scheduler, ServeConfig,
 )
 from distributed_dot_product_tpu.utils import faults as faults_lib  # noqa: E402
+from distributed_dot_product_tpu.utils.compile_cache import (  # noqa: E402
+    setup_compile_cache,
+)
 from distributed_dot_product_tpu.utils.tracing import (  # noqa: E402
     MetricsRegistry,
 )
@@ -205,6 +208,7 @@ def run_load_demo(args):
 
 
 def main(argv=None):
+    setup_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument('--slots', type=int, default=4)
     p.add_argument('--t-max', type=int, default=64)

@@ -50,6 +50,9 @@ from distributed_dot_product_tpu.parallel.mesh import (  # noqa: E402
     data_seq_mesh, seq_mesh,
 )
 from distributed_dot_product_tpu.train import make_lm_train_step  # noqa: E402
+from distributed_dot_product_tpu.utils.compile_cache import (  # noqa: E402
+    setup_compile_cache,
+)
 
 BOS_OFF, SEP_OFF = 1, 2   # vocab layout: [0..V-3]=data, V-2=SEP, V-1=BOS
 
@@ -96,6 +99,7 @@ def build_model(args):
 
 
 def main(argv=None):
+    setup_compile_cache()
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument('--steps', type=int, default=300)
     p.add_argument('--batch', type=int, default=2)

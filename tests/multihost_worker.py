@@ -109,8 +109,7 @@ def main():
     process_id, num_processes, port = (int(sys.argv[1]), int(sys.argv[2]),
                                        sys.argv[3])
     ckpt_dir = sys.argv[4] if len(sys.argv) > 4 else None
-    # ensure_cpu_devices: version-portable jax_num_cpu_devices /
-    # XLA_FLAGS provisioning (must run before backend init).
+    # Virtual CPU devices (must run before backend init).
     from distributed_dot_product_tpu._compat import ensure_cpu_devices
     ensure_cpu_devices(LOCAL_DEVICES)
 

@@ -1,0 +1,90 @@
+# -*- coding: utf-8 -*-
+"""
+The chip harness must REFUSE off-chip, never fall back; and the compile
+cache goes to one place: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX
+reads it, the code sets nothing), else ``<checkout>/.jax_cache``.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+from distributed_dot_product_tpu.utils import compile_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(*argv, env=None):
+    env = dict(os.environ if env is None else env, JAX_PLATFORMS='cpu')
+    return subprocess.run([sys.executable, *argv], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=600)
+
+
+@pytest.fixture
+def config_updates(monkeypatch):
+    """Record ``jax.config.update`` calls instead of applying them (the
+    session's own cache setting must not move under the other tests)."""
+    calls = []
+    monkeypatch.setattr(jax.config, 'update',
+                        lambda name, value: calls.append((name, value)))
+    return calls
+
+
+def test_compile_cache_env_var_wins_and_nothing_is_set(
+        monkeypatch, config_updates):
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/some/where/else')
+    assert compile_cache.setup_compile_cache() == '/some/where/else'
+    assert config_updates == []
+
+
+def test_compile_cache_default_is_fixed_path_in_checkout(
+        monkeypatch, config_updates):
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    want = os.path.join(REPO, '.jax_cache')
+    assert compile_cache.setup_compile_cache() == want
+    assert compile_cache.setup_compile_cache() == want
+    assert config_updates == [('jax_compilation_cache_dir', want)] * 2
+    # Another process (another pid) lands on the same directory.
+    env = {k: v for k, v in os.environ.items()
+           if k != 'JAX_COMPILATION_CACHE_DIR'}
+    proc = _run('-c', 'import jax; from distributed_dot_product_tpu.utils'
+                '.compile_cache import setup_compile_cache as s; '
+                'print(s()); print(jax.config.jax_compilation_cache_dir)',
+                env=env)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == [want, want]
+
+
+def test_chip_smoke_refuses_without_accelerator():
+    """No arguments, no chip: non-zero exit and NO result on stdout."""
+    proc = _run('chip_smoke.py')
+    assert proc.returncode not in (0, None)
+    assert proc.stdout.strip() == ''
+    assert 'no TPU' in proc.stderr
+
+
+def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
+    """``--tiny`` walks every phase on the CPU — and must still end
+    non-zero without ever printing ``"ok": true``: the size option
+    relaxes no check (the kernels did not compile for a chip, the
+    decode impl did not resolve to ``kernel``, the platform is not
+    ``tpu``)."""
+    proc = _run('chip_smoke.py', '--tiny')
+    assert proc.returncode == 1, proc.stderr[-3000:]
+    assert '"ok": true' not in proc.stdout
+    lines = [json.loads(line) for line in proc.stdout.splitlines()]
+    assert lines[-1] == {'ok': False, 'device': lines[0]['device']}
+    phases = {rec['phase']: rec for rec in lines if 'phase' in rec}
+    assert set(phases) == {'train', 'generate', 'serve'}
+    for name, rec in phases.items():
+        assert 'error' not in rec, (name, rec.get('error'))
+        failed = {k for k, v in rec['checks'].items() if not v}
+        # Everything a CPU can get right is right; only the checks
+        # that need the chip fail.
+        assert failed and all(
+            k.endswith('tpu_custom_call') or k.endswith('resolved_kernel')
+            for k in failed), (name, failed)

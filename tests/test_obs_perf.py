@@ -75,7 +75,11 @@ def test_snapshot_schema_and_retrace_totals(full_snapshot):
     # must have recorded the traces its own compiles incurred.
     rt = full_snapshot['retrace_totals']
     assert any(v > 0 for v in rt.values()), rt
+    # The hermetic gate names its target chip: the published v5e
+    # peaks, whatever device compiled the programs.
     peaks = full_snapshot['peaks']
+    assert peaks == perf.peaks_for(perf.TARGET_DEVICE_KIND).as_dict()
+    assert (peaks['flops_per_s'], peaks['bytes_per_s']) == (197e12, 819e9)
     assert peaks['ridge_flops_per_byte'] == pytest.approx(
         peaks['flops_per_s'] / peaks['bytes_per_s'])
 
@@ -211,7 +215,12 @@ def test_program_model_measured_columns(devices):
     compiled = jax.jit(
         lambda a, b: a @ b).lower(jnp.ones((64, 64)),
                                   jnp.ones((64, 64))).compile()
-    m = perf.program_model(compiled, measured_seconds=1e-3)
+    # A roofline against the LIVE device looks its kind up; the CPU
+    # mesh has no published peaks, so it must raise, never default.
+    with pytest.raises(ValueError, match='no published peaks'):
+        perf.program_model(compiled, measured_seconds=1e-3)
+    m = perf.program_model(compiled, measured_seconds=1e-3,
+                           peaks=perf.peaks_for(perf.TARGET_DEVICE_KIND))
     assert m['flops'] > 0 and m['bytes_accessed'] > 0
     assert m['measured_gflops_per_s'] == pytest.approx(
         m['flops'] / 1e-3 / 1e9)

@@ -164,8 +164,11 @@ def test_spec_amortizes_steps_and_reports_histograms():
     dispatches than tokens generated, accepted-tokens/step > 2 through
     the serve.spec histograms (the ISSUE acceptance scenario, pinned
     on CPU with the n-gram proposer)."""
+    # seed=18: a random-init engine whose greedy continuation locks
+    # into the prompt's cycle under the installed JAX's RNG (most seeds
+    # wander off it and acceptance collapses; re-pin if the RNG moves).
     eng = KernelEngine(slots=1, t_max=256, vocab=VOCAB,
-                       decode_impl='xla', seed=4)
+                       decode_impl='xla', seed=18)
     cfg = ServeConfig(queue_limit=4, max_new_tokens=64, watchdog=False,
                       spec='ngram', spec_k=4)
     sched = Scheduler(eng, cfg, registry=MetricsRegistry())

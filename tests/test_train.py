@@ -84,7 +84,8 @@ def test_graft_dryrun_self_provisions_from_single_device():
     """Reproduce the driver's environment: a process whose JAX sees ONE
     device calls ``dryrun_multichip(8)``. The dryrun must re-exec itself
     onto an 8-device virtual CPU mesh and succeed — round 1 failed exactly
-    this (MULTICHIP_r01.json rc=1). Runs in a subprocess so the conftest's
+    this (rc=1 in the driver's multichip record). Runs in a subprocess so
+    the conftest's
     8-device pin can't mask the condition."""
     import subprocess
     code = ("from distributed_dot_product_tpu._compat import "
