@@ -57,6 +57,7 @@ from distributed_dot_product_tpu.ops.rope import rope
 from distributed_dot_product_tpu.models.ulysses_attention import (
     ulysses_attention,
 )
+from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_attention import flash_attention
 from distributed_dot_product_tpu.ops.ops import matmul_all, matmul_nt
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
@@ -265,6 +266,15 @@ class DistributedDotProductAttn(nn.Module):
     def __call__(self, keys, queries, values, attn_mask=None,
                  segment_ids=None, deterministic=False,
                  dropout_seed=None):
+        # Everything here that is not a kernel (or the sequence
+        # all-gather, scoped further in) is 'lm.attn_proj' in a device
+        # trace.
+        with device_scope('lm.attn_proj'):
+            return self._attend(keys, queries, values, attn_mask,
+                                segment_ids, deterministic, dropout_seed)
+
+    def _attend(self, keys, queries, values, attn_mask, segment_ids,
+                deterministic, dropout_seed):
         # ``deterministic=True`` disables dropout (eval). ``dropout_seed``:
         # explicit traced int32 scalar for the in-kernel mask (e.g. the
         # step counter) — the SPMD-simplest source; omitted, the seed is
@@ -375,9 +385,11 @@ class DistributedDotProductAttn(nn.Module):
                 # the compact form densifies into the boolean mask (rows =
                 # this shard's positions, columns global). Every other
                 # path consumes the O(T) vector form in-kernel.
-                seg_full = (jax.lax.all_gather(seg_local, self.axis_name,
-                                               axis=-1, tiled=True)
-                            if distributed else seg_local)
+                seg_full = seg_local
+                if distributed:
+                    with device_scope('lm.attn_gather'):
+                        seg_full = jax.lax.all_gather(
+                            seg_local, self.axis_name, axis=-1, tiled=True)
                 dense = seg_local[..., :, None] != seg_full[..., None, :]
                 if self.num_heads > 1:
                     dense = dense[..., None, :, :]
@@ -415,15 +427,15 @@ class DistributedDotProductAttn(nn.Module):
             # (:mod:`..ops.pallas_attention`). Fully-masked rows give 0
             # (reference: NaN).
             scale = 1.0 / math.sqrt(self.head_dim)
+            q_full, v_full = queries, values
             if distributed:
-                q_full = jax.lax.all_gather(
-                    queries, self.axis_name, axis=queries.ndim - 2,
-                    tiled=True)
-                v_full = jax.lax.all_gather(
-                    values, self.axis_name, axis=values.ndim - 2,
-                    tiled=True)
-            else:
-                q_full, v_full = queries, values
+                with device_scope('lm.attn_gather'):
+                    q_full = jax.lax.all_gather(
+                        queries, self.axis_name, axis=queries.ndim - 2,
+                        tiled=True)
+                    v_full = jax.lax.all_gather(
+                        values, self.axis_name, axis=values.ndim - 2,
+                        tiled=True)
             # In the distributed K-first layout the kernel's query rows are
             # this shard's keys — global positions start at idx·T/N. Fed
             # whenever distributed: causal/windows need it, and the
@@ -440,9 +452,11 @@ class DistributedDotProductAttn(nn.Module):
             if seg_local is not None:
                 # K-first layout: the kernel's query rows are this shard's
                 # keys (local segs), its key columns the gathered queries.
-                seg_kv = (jax.lax.all_gather(seg_local, self.axis_name,
-                                             axis=-1, tiled=True)
-                          if distributed else seg_local)
+                seg_kv = seg_local
+                if distributed:
+                    with device_scope('lm.attn_gather'):
+                        seg_kv = jax.lax.all_gather(
+                            seg_local, self.axis_name, axis=-1, tiled=True)
                 sq, sk = seg_local, seg_kv
                 if self.num_heads > 1:
                     sq, sk = sq[..., None, :], sk[..., None, :]
@@ -628,24 +642,26 @@ class DistributedDotProductAttn(nn.Module):
         appended (rows attend their own columns). Returns
         ``(cache, out)``."""
         from distributed_dot_product_tpu.models.decode import append_kv
-        keys, queries, values = self._project_for_decode(
-            keys, queries, values, cache)
-        start = cache.length
-        cache = append_kv(cache, queries, values)
-        seg_pair = None
-        if segment_ids is not None:
-            if seg_cache is None:
-                raise ValueError('segment_ids needs seg_cache (the cached '
-                                 "positions' ids, shape (B, t_max))")
-            sq = segment_ids.astype(jnp.int32)[..., None, :]
-            sk = seg_cache.astype(jnp.int32)[..., None, :]
-            seg_pair = (sq, sk)
-        out = flash_attention(
-            keys, cache.k, cache.v, causal=True, causal_offset=start,
-            scale=1.0 / math.sqrt(self.head_dim), window=self.window,
-            alibi_slopes=self.alibi_slopes, qk_quant=self.qk_quant,
-            segment_ids=seg_pair)
-        return cache, self._merge_decode_heads(out)
+        with device_scope('lm.attn_proj'):
+            keys, queries, values = self._project_for_decode(
+                keys, queries, values, cache)
+            start = cache.length
+            cache = append_kv(cache, queries, values)
+            seg_pair = None
+            if segment_ids is not None:
+                if seg_cache is None:
+                    raise ValueError(
+                        'segment_ids needs seg_cache (the cached '
+                        "positions' ids, shape (B, t_max))")
+                sq = segment_ids.astype(jnp.int32)[..., None, :]
+                sk = seg_cache.astype(jnp.int32)[..., None, :]
+                seg_pair = (sq, sk)
+            out = flash_attention(
+                keys, cache.k, cache.v, causal=True, causal_offset=start,
+                scale=1.0 / math.sqrt(self.head_dim), window=self.window,
+                alibi_slopes=self.alibi_slopes, qk_quant=self.qk_quant,
+                segment_ids=seg_pair)
+            return cache, self._merge_decode_heads(out)
 
     def decode(self, keys, queries, values, cache, segment_ids=None,
                seg_cache=None):
@@ -680,15 +696,16 @@ class DistributedDotProductAttn(nn.Module):
         from distributed_dot_product_tpu.models.decode import (
             decode_step,
         )
-        keys, queries, values = self._project_for_decode(
-            keys, queries, values, cache)
-        cache, out = decode_step(
-            keys, cache, queries, values,
-            scale=1.0 / math.sqrt(self.head_dim),
-            window=self.window, alibi_slopes=self.alibi_slopes,
-            qk_quant=self.qk_quant, segment_ids=seg_cache,
-            seg_q=segment_ids, impl=self.decode_impl)
-        return cache, self._merge_decode_heads(out)
+        with device_scope('lm.attn_proj'):
+            keys, queries, values = self._project_for_decode(
+                keys, queries, values, cache)
+            cache, out = decode_step(
+                keys, cache, queries, values,
+                scale=1.0 / math.sqrt(self.head_dim),
+                window=self.window, alibi_slopes=self.alibi_slopes,
+                qk_quant=self.qk_quant, segment_ids=seg_cache,
+                seg_q=segment_ids, impl=self.decode_impl)
+            return cache, self._merge_decode_heads(out)
 
     def decode_sharded(self, keys, queries, values, cache,
                        segment_ids=None, seg_cache=None, axis_name=None):
@@ -710,15 +727,16 @@ class DistributedDotProductAttn(nn.Module):
             decode_step,
         )
         ax = axis_name or self.axis_name
-        keys, queries, values = self._project_for_decode(
-            keys, queries, values, cache)
-        cache, out = decode_step(
-            keys, cache, queries, values,
-            scale=1.0 / math.sqrt(self.head_dim),
-            window=self.window, alibi_slopes=self.alibi_slopes,
-            qk_quant=self.qk_quant, segment_ids=seg_cache,
-            seg_q=segment_ids, axis_name=ax, impl=self.decode_impl)
-        return cache, self._merge_decode_heads(out)
+        with device_scope('lm.attn_proj'):
+            keys, queries, values = self._project_for_decode(
+                keys, queries, values, cache)
+            cache, out = decode_step(
+                keys, cache, queries, values,
+                scale=1.0 / math.sqrt(self.head_dim),
+                window=self.window, alibi_slopes=self.alibi_slopes,
+                qk_quant=self.qk_quant, segment_ids=seg_cache,
+                seg_q=segment_ids, axis_name=ax, impl=self.decode_impl)
+            return cache, self._merge_decode_heads(out)
 
 
 def apply_seq_parallel(module, params, mesh, keys, queries, values,

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from distributed_dot_product_tpu.models.transformer import (
     TransformerStack,
 )
+from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
 __all__ = ['TransformerLM', 'greedy_generate', 'lm_targets']
@@ -146,21 +147,27 @@ class TransformerLM(nn.Module):
             return self.embed.embedding
         return self.lm_head_kernel.T
 
+    def _embed(self, tokens):
+        with device_scope('lm.embed'):
+            return self.embed(tokens.astype(jnp.int32))
+
     def _head(self, x):
-        x = self.ln_f(x)
-        # logits = x · Eᵀ on the MXU, fp32 accumulation — requested
-        # explicitly (preferred_element_type) so the contraction
-        # accumulates in fp32 on EVERY backend, not just where it's the
-        # hardware default; the result is cast back to the activation
-        # dtype (the contract is fp32 accumulation, not fp32 logits).
-        return jnp.einsum('...d,vd->...v', x,
-                          self._head_table().astype(x.dtype),
-                          preferred_element_type=jnp.float32
-                          ).astype(x.dtype)
+        with device_scope('lm.head'):
+            x = self.ln_f(x)
+            # logits = x · Eᵀ on the MXU, fp32 accumulation — requested
+            # explicitly (preferred_element_type) so the contraction
+            # accumulates in fp32 on EVERY backend, not just where it's
+            # the hardware default; the result is cast back to the
+            # activation dtype (the contract is fp32 accumulation, not
+            # fp32 logits).
+            return jnp.einsum('...d,vd->...v', x,
+                              self._head_table().astype(x.dtype),
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
 
     def __call__(self, tokens, segment_ids=None, deterministic=False,
                  dropout_seed=None):
-        x = self.embed(tokens.astype(jnp.int32))
+        x = self._embed(tokens)
         x = self.stack(x, x, x, None, segment_ids=segment_ids,
                        deterministic=deterministic,
                        dropout_seed=dropout_seed)
@@ -180,10 +187,14 @@ class TransformerLM(nn.Module):
         at T=131K × 32K vocab are 17 GiB — measured OOM on a 16 GiB
         chip; chunked, the live score memory is O(chunk·vocab)).
         ``None`` = unchunked (fine at short T)."""
-        x = self.embed(tokens.astype(jnp.int32))
+        x = self._embed(tokens)
         x = self.stack(x, x, x, None, segment_ids=segment_ids,
                        deterministic=deterministic,
                        dropout_seed=dropout_seed)
+        with device_scope('lm.head_loss'):
+            return self._nll(x, targets, chunk)
+
+    def _nll(self, x, targets, chunk):
         x = self.ln_f(x)
         table = self._head_table().astype(jnp.float32)
         tn = x.shape[-2]
@@ -237,14 +248,12 @@ class TransformerLM(nn.Module):
     def prefill(self, tokens, caches):
         """Ingest a prompt chunk: returns ``(caches, logits (B, n,
         vocab))`` — the last position's logits seed generation."""
-        x = self.embed(tokens.astype(jnp.int32))
-        caches, x = self.stack.prefill(x, caches)
+        caches, x = self.stack.prefill(self._embed(tokens), caches)
         return caches, self._head(x)
 
     def decode(self, tokens, caches):
         """One cached generation step for ``tokens (B, 1)``."""
-        x = self.embed(tokens.astype(jnp.int32))
-        caches, x = self.stack.decode(x, caches)
+        caches, x = self.stack.decode(self._embed(tokens), caches)
         return caches, self._head(x)
 
 

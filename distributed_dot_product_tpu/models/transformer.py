@@ -39,6 +39,7 @@ from distributed_dot_product_tpu.models.attention import (
     DistributedDotProductAttn,
 )
 from distributed_dot_product_tpu.models.dense import OwnedDense
+from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
 __all__ = ['TransformerBlock', 'TransformerStack']
@@ -84,8 +85,9 @@ class TransformerBlock(nn.Module):
                                   name='mlp_out',
                                   weight_quant=self.weight_quant)
 
-    def _mlp(self, h):
-        return self.mlp_out(nn.gelu(self.mlp_in(h)))
+    def _mlp(self, x):
+        with device_scope('lm.mlp'):
+            return self.mlp_out(nn.gelu(self.mlp_in(self.ln2(x))))
 
     def __call__(self, x, attn_mask=None, segment_ids=None,
                  deterministic=False, dropout_seed=None):
@@ -93,19 +95,19 @@ class TransformerBlock(nn.Module):
         x = x + self.attn(h, h, h, attn_mask, segment_ids=segment_ids,
                           deterministic=deterministic,
                           dropout_seed=dropout_seed)
-        return x + self._mlp(self.ln2(x))
+        return x + self._mlp(x)
 
     def prefill(self, x, cache):
         h = self.ln1(x)
         cache, a = self.attn.prefill(h, h, h, cache)
         x = x + a
-        return cache, x + self._mlp(self.ln2(x))
+        return cache, x + self._mlp(x)
 
     def decode(self, x, cache):
         h = self.ln1(x)
         cache, a = self.attn.decode(h, h, h, cache)
         x = x + a
-        return cache, x + self._mlp(self.ln2(x))
+        return cache, x + self._mlp(x)
 
 
 class _ScanStackCore(nn.Module):
@@ -239,16 +241,17 @@ class TransformerStack(nn.Module):
         # keys/queries/values are accepted for train-step signature
         # parity; a transformer block is self-attention on one stream.
         x = keys
-        if self.scan_layers:
-            x, _ = self.layers.layer(
-                x, jnp.arange(self.n_layers, dtype=jnp.int32),
-                attn_mask, segment_ids, deterministic, dropout_seed)
+        with device_scope('lm.stack_carry'):
+            if self.scan_layers:
+                x, _ = self.layers.layer(
+                    x, jnp.arange(self.n_layers, dtype=jnp.int32),
+                    attn_mask, segment_ids, deterministic, dropout_seed)
+                return x
+            for block in self.blocks:
+                x = block(x, attn_mask, segment_ids=segment_ids,
+                          deterministic=deterministic,
+                          dropout_seed=dropout_seed)
             return x
-        for block in self.blocks:
-            x = block(x, attn_mask, segment_ids=segment_ids,
-                      deterministic=deterministic,
-                      dropout_seed=dropout_seed)
-        return x
 
     def make_decode_caches(self, batch, t_max, dtype=None):
         # Plain field arithmetic (no proto Module: flax would try to
@@ -270,21 +273,23 @@ class TransformerStack(nn.Module):
         return caches
 
     def prefill(self, x, caches):
-        if self.scan_layers:
-            x, caches = self.layers.prefill(x, caches)
-            return caches, x
-        out = []
-        for block, cache in zip(self.blocks, caches):
-            cache, x = block.prefill(x, cache)
-            out.append(cache)
-        return out, x
+        with device_scope('lm.stack_carry'):
+            if self.scan_layers:
+                x, caches = self.layers.prefill(x, caches)
+                return caches, x
+            out = []
+            for block, cache in zip(self.blocks, caches):
+                cache, x = block.prefill(x, cache)
+                out.append(cache)
+            return out, x
 
     def decode(self, x, caches):
-        if self.scan_layers:
-            x, caches = self.layers.decode(x, caches)
-            return caches, x
-        out = []
-        for block, cache in zip(self.blocks, caches):
-            cache, x = block.decode(x, cache)
-            out.append(cache)
-        return out, x
+        with device_scope('lm.stack_carry'):
+            if self.scan_layers:
+                x, caches = self.layers.decode(x, caches)
+                return caches, x
+            out = []
+            for block, cache in zip(self.blocks, caches):
+                cache, x = block.decode(x, cache)
+                out.append(cache)
+            return out, x

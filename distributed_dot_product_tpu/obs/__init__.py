@@ -71,8 +71,8 @@ from distributed_dot_product_tpu.obs.exporter import (  # noqa: F401
     MetricsServer, render_prometheus,
 )
 from distributed_dot_product_tpu.obs.spans import (  # noqa: F401
-    SpanCollector, SpanRecord, collecting, enable, enabled,
-    get_collector, span, spanned,
+    DEVICE_SCOPES, SpanCollector, SpanRecord, collecting, device_scope,
+    enable, enabled, get_collector, span, spanned,
 )
 from distributed_dot_product_tpu.obs.timeline import (  # noqa: F401
     Timeline, reconstruct, timeline,
@@ -84,7 +84,8 @@ __all__ = [
     'remove_log', 'set_active', 'validate_file', 'SloReport', 'SloSpec',
     'check_baseline', 'goodput', 'MetricsServer', 'render_prometheus',
     'SpanCollector', 'SpanRecord', 'collecting', 'enable', 'enabled',
-    'get_collector', 'span', 'spanned', 'Timeline', 'reconstruct',
+    'get_collector', 'span', 'spanned', 'DEVICE_SCOPES', 'device_scope',
+    'Timeline', 'reconstruct',
     'timeline', 'CaptureInFlight', 'DeviceMonitor', 'ProfileCapture',
     'device_stats_snapshot', 'FlightRecorder', 'load_bundle',
     'AnomalyWatchdog', 'EwmaZScore', 'RateOfChange', 'StaticThreshold',

@@ -4,7 +4,22 @@ Hierarchical host-side wall-time spans — the structured successor of the
 reference ``measure`` decorator (reference functions.py:24-41), grown
 from a per-call print into a nestable tree an operator can read.
 
-Contract (the part graphlint enforces — see analysis/astlint.py):
+Two kinds of name live here, and which is for what:
+
+- :func:`span` names HOST time (below). It reads the clock, so it never
+  goes inside a jitted function.
+- :func:`device_scope` names DEVICE time. It is the in-jit counterpart:
+  a ``jax.named_scope`` from the fixed :data:`DEVICE_SCOPES` vocabulary,
+  which puts the name on the JAX name stack and so into every HLO
+  instruction's ``op_name`` that is traced under it. It reads no clock,
+  adds no operation and costs nothing at run time; a profiler trace
+  carries the names (the benchmark's ``benchmarks/scopes.py`` reads
+  device time by scope and by pass from them). Pallas kernels carry the
+  matching ``name=`` (``flash_fwd`` for ``ops.flash_fwd``: Mosaic takes
+  the kernel name as a symbol, hence no dot).
+
+Contract of ``span`` (the part graphlint enforces — see
+analysis/astlint.py):
 
 - Spans time HOST-side work: dispatch, readback, scheduling, I/O. A
   ``span`` inside a jitted function would read the clock at TRACE time
@@ -46,9 +61,56 @@ import time
 from typing import Optional, Tuple
 
 __all__ = ['span', 'spanned', 'enable', 'enabled', 'collecting',
-           'get_collector', 'SpanCollector', 'SpanRecord']
+           'get_collector', 'SpanCollector', 'SpanRecord',
+           'DEVICE_SCOPES', 'device_scope']
 
 ENV_VAR = 'DDP_TPU_SPANS'
+
+
+# Every name a compiled program may put on its operations, with what it
+# covers. Scopes nest (a kernel's inside ``lm.attn_proj`` inside
+# ``lm.stack_carry``); a reader attributes an operation to the
+# innermost one, so each entry below reads "… that is in no scope
+# further in". Prefixes follow the host spans' (``ops.``, ``lm.``,
+# ``train.``).
+DEVICE_SCOPES = {
+    'ops.flash_fwd': 'the Pallas flash-attention forward kernel (exact, '
+                     'bounded and int8-score builds; the remat forward '
+                     'is the same kernel under a checkpoint name stack)',
+    'ops.flash_bwd_dq': 'the Pallas flash-attention dq kernel',
+    'ops.flash_bwd_dkv': 'the Pallas flash-attention dk/dv kernel',
+    'ops.flash_decode': 'the fused Pallas decode step kernel (append + '
+                        'attend, any number of new rows)',
+    'lm.attn_gather': 'the all-gather of the softmax-table side (queries, '
+                      'values, segment ids) over the sequence axis',
+    'lm.attn_proj': 'the attention module outside its kernels: the four '
+                    'projections, RoPE / ALiBi preparation, head '
+                    'reshapes, padding, cache append',
+    'lm.mlp': 'ln2, mlp_in, GELU and mlp_out of a block',
+    'lm.embed': 'the embedding gather (and its scatter-add backward)',
+    'lm.head_loss': 'training: ln_f, the chunked head matmul and '
+                    'logsumexp scan with its checkpointed body',
+    'lm.head': 'prefill / decode: ln_f and the head matmul',
+    'lm.stack_carry': 'the layer stack outside its blocks\' sub-scopes: '
+                      'ln1, residual adds, and the scan\'s own slicing, '
+                      'copying and updating of stacked parameters, '
+                      'gradients and KV caches',
+    'train.grad_sync': 'the cross-shard psums of token count, loss and '
+                       'gradients',
+    'train.optimizer': 'optimizer.update and the parameter apply',
+}
+
+
+def device_scope(name):
+    """``jax.named_scope(name)`` for a name in :data:`DEVICE_SCOPES`;
+    any other name raises, so the vocabulary a trace reader matches
+    cannot drift from the one the program opens. For use INSIDE jitted
+    code (see the module docstring)."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f'unknown device scope {name!r}; '
+                         f'DEVICE_SCOPES has {sorted(DEVICE_SCOPES)}')
+    import jax
+    return jax.named_scope(name)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -55,6 +55,8 @@ from jax.experimental import pallas as pl
 # VMEM scratch on CPU.
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_dot_product_tpu.obs.spans import device_scope
+
 __all__ = ['flash_attention']
 
 _NEG_BIG = -0.7 * 3.4e38  # large-finite fp32; keeps exp()/VJP NaN-free
@@ -852,8 +854,18 @@ def _aux_setup(mask, segment_ids, positions, batch, tq, tk, tq_p, tk_p,
     return specs, specs_t, args, flags, runsum
 
 
-def _pallas_call(kernel, grid, in_specs, out_specs, scratch, out_shape,
-                 interpret, prefetch):
+# Kernel name -> the device scope its call runs under.
+_KERNEL_SCOPES = {
+    'flash_fwd': 'ops.flash_fwd',
+    'flash_fwd_int8': 'ops.flash_fwd',
+    'flash_fwd_bounded': 'ops.flash_fwd',
+    'flash_bwd_dq': 'ops.flash_bwd_dq',
+    'flash_bwd_dkv': 'ops.flash_bwd_dkv',
+}
+
+
+def _pallas_call(name, kernel, grid, in_specs, out_specs, scratch,
+                 out_shape, interpret, prefetch):
     """Build + invoke: a scalar-prefetch grid when any prefetch operands
     are live (the dense-mask block-skip summary and/or the window band
     offset), a plain grid otherwise. Prefetch refs reach both the index
@@ -861,7 +873,12 @@ def _pallas_call(kernel, grid, in_specs, out_specs, scratch, out_shape,
     and the kernel (as leading refs). ``interpret=True`` under prefetch
     upgrades to the Mosaic TPU interpreter — the default HLO interpreter
     cannot evaluate scalar-prefetch grids ("MLIR translation rule for
-    primitive 'program_id' not found for platform cpu")."""
+    primitive 'program_id' not found for platform cpu").
+
+    ``name`` is the kernel's stable name in a device trace (a plain
+    identifier: Mosaic takes it as a symbol); the call runs under the
+    :func:`~distributed_dot_product_tpu.obs.spans.device_scope` of the
+    kernel's family (``_KERNEL_SCOPES``)."""
     prefetch = [p for p in prefetch if p is not None]
     interp = interpret
     if interpret is True and prefetch:
@@ -873,11 +890,17 @@ def _pallas_call(kernel, grid, in_specs, out_specs, scratch, out_shape,
                 num_scalar_prefetch=len(prefetch), grid=grid,
                 in_specs=in_specs, out_specs=out_specs,
                 scratch_shapes=scratch),
-            out_shape=out_shape, interpret=interp)
-        return lambda *a: call(*prefetch, *a)
-    return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
-                          out_specs=out_specs, scratch_shapes=scratch,
-                          out_shape=out_shape, interpret=interp)
+            out_shape=out_shape, interpret=interp, name=name)
+    else:
+        call = pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+                              out_specs=out_specs, scratch_shapes=scratch,
+                              out_shape=out_shape, interpret=interp,
+                              name=name)
+
+    def run(*args):
+        with device_scope(_KERNEL_SCOPES[name]):
+            return call(*prefetch, *args)
+    return run
 
 
 def _quantize_rows(x, nb_x, t, d):
@@ -1085,6 +1108,7 @@ def _flash_fwd_impl(q, k, v, mask, causal_offset, scale, causal, interpret,
             o_specs = (_wrap_specs_pairs(o_specs) if save_lse
                        else _wrap_specs_pairs([o_specs])[0])
         return _pallas_call(
+            'flash_fwd_int8' if quantized else 'flash_fwd',
             kernel, grid, in_specs, o_specs, _scratch(bq, d_v), out_shape,
             interpret, trap_pre if trap else [bandoff, runsum],
         )(off, *seed_args, *args, *aux_args)
@@ -1107,6 +1131,7 @@ def _flash_fwd_impl(q, k, v, mask, causal_offset, scale, causal, interpret,
             kernel = _make_fwd_kernel_bounded(
                 causal, bq, bk, tk, *flags, save_lse, window, band_fn)
             return _pallas_call(
+                'flash_fwd_bounded',
                 kernel, grid, [off_spec] + specs + [mvec_spec] + aux_specs,
                 out_specs, _scratch(bq, d_v)[1:],  # no m buffer
                 out_shape, interpret, [bandoff, runsum],
@@ -1631,6 +1656,7 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
         else:
             dq_grid = (nb, nqb, kband if banded else nkb)
         dq = _pallas_call(
+            'flash_bwd_dq',
             _make_dq_kernel(scale, causal, bq, bk, tk, *flags,
                             window=window, band_fn=kband_fn,
                             quantized=quantized, dropout=dropout,
@@ -1666,6 +1692,7 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
         else:
             dkv_grid = (nb, nkb, qband if banded else nqb)
         dk, dv = _pallas_call(
+            'flash_bwd_dkv',
             _make_dkv_kernel(scale, causal, bq, bk, tk, *flags,
                              window=window, band_fn=qband_fn,
                              quantized=quantized, dropout=dropout,

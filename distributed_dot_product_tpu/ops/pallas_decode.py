@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_attention import (
     _LOG2E, _NEG_BIG, _quantize_rows,
 )
@@ -663,19 +664,21 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
                                  quantized, has_alibi, paged=paged)
     prefetch = ((valid_to, append_at, nnv, ptf) if paged
                 else (valid_to, append_at, nnv))
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_prefetch,
-            grid=(nb, ns),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((g_pad, 1), jnp.float32),
-                            pltpu.VMEM((g_pad, 1), jnp.float32),
-                            pltpu.VMEM((g_pad, dv), jnp.float32)]),
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret)(*prefetch, *args)
+    with device_scope('ops.flash_decode'):
+        outs = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=n_prefetch,
+                grid=(nb, ns),
+                in_specs=in_specs,
+                out_specs=out_specs,
+                scratch_shapes=[pltpu.VMEM((g_pad, 1), jnp.float32),
+                                pltpu.VMEM((g_pad, 1), jnp.float32),
+                                pltpu.VMEM((g_pad, dv), jnp.float32)]),
+            out_shape=out_shape,
+            input_output_aliases=aliases,
+            interpret=interpret,
+            name='flash_decode')(*prefetch, *args)
 
     num, m, l, new_k, new_v = outs[:5]
     new_kq = new_ks = None

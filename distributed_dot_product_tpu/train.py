@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
 __all__ = ['make_train_step', 'make_lm_train_step', 'mse_loss']
@@ -225,19 +226,25 @@ def make_lm_train_step(model, optimizer, mesh, seq_axis=SEQ_AXIS,
             # cotangent 1/C comes back as W/C (make_train_step's pmean
             # cancels the same factor with its /W; here the weighting
             # is by global token count, so the shape is explicit).
-            return loss_sum / jnp.maximum(lax.psum(count, axes), 1.0)
+            with device_scope('train.grad_sync'):
+                count = lax.psum(count, axes)
+            return loss_sum / jnp.maximum(count, 1.0)
 
         local_val, grads = jax.value_and_grad(local_obj)(params)
-        # Shard-sum OUTSIDE the grad: the global token-mean loss value…
-        loss = lax.psum(local_val, axes)
-        # …and the true gradient of it (sum of per-shard partials).
-        grads = lax.psum(grads, axes)
-        if guard:
-            return _guarded_update(optimizer, params, opt_state, grads,
-                                   loss)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
-        return params, opt_state, loss
+        with device_scope('train.grad_sync'):
+            # Shard-sum OUTSIDE the grad: the global token-mean loss
+            # value…
+            loss = lax.psum(local_val, axes)
+            # …and the true gradient of it (sum of per-shard partials).
+            grads = lax.psum(grads, axes)
+        with device_scope('train.optimizer'):
+            if guard:
+                return _guarded_update(optimizer, params, opt_state,
+                                       grads, loss)
+            updates, opt_state = optimizer.update(grads, opt_state,
+                                                  params)
+            params = jax.tree.map(lambda p, u: p + u, params, updates)
+            return params, opt_state, loss
 
     tok_spec = (P(None, seq_axis) if data_axis is None
                 else P(data_axis, seq_axis))

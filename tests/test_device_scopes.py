@@ -1,0 +1,164 @@
+# -*- coding: utf-8 -*-
+"""The device-side vocabulary (``obs.spans.DEVICE_SCOPES``): every scope
+that applies shows up in the compiled programs' ``op_name``s, every
+Pallas build carries its kernel name, and a scope adds no operation."""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from distributed_dot_product_tpu.analysis.jaxpr_rules import _iter_eqns
+from distributed_dot_product_tpu.models import attention, lm, transformer
+from distributed_dot_product_tpu.models.lm import TransformerLM, lm_targets
+from distributed_dot_product_tpu.obs.spans import (
+    DEVICE_SCOPES, device_scope,
+)
+from distributed_dot_product_tpu.ops import pallas_attention, pallas_decode
+from distributed_dot_product_tpu.ops.pallas_attention import flash_attention
+from distributed_dot_product_tpu.parallel.mesh import seq_mesh
+from distributed_dot_product_tpu import train
+
+TRAIN_SCOPES = ['ops.flash_fwd', 'ops.flash_bwd_dq', 'ops.flash_bwd_dkv',
+                'lm.attn_gather', 'lm.attn_proj', 'lm.mlp', 'lm.embed',
+                'lm.head_loss', 'lm.stack_carry', 'train.grad_sync',
+                'train.optimizer']
+DECODE_SCOPES = ['ops.flash_decode', 'lm.attn_proj', 'lm.mlp', 'lm.embed',
+                 'lm.head', 'lm.stack_carry']
+
+
+def tiny_lm(**attn_kwargs):
+    return TransformerLM(vocab_size=64, dim=32, num_heads=2, n_layers=2,
+                         remat=True, attn_kwargs=attn_kwargs or None)
+
+
+def train_step_and_args(width=2):
+    model = tiny_lm()
+    tokens = jnp.zeros((1, 64), jnp.int32)
+    params = model.init(jax.random.key(0), tokens)
+    optimizer = optax.adamw(1e-3)
+    step = train.make_lm_train_step(model, optimizer, seq_mesh(width),
+                                    loss_chunk=16)
+    return step, (params, optimizer.init(params),
+                  (tokens, lm_targets(tokens)))
+
+
+def op_names(compiled):
+    return set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+
+
+@pytest.fixture(scope='module')
+def train_op_names():
+    step, args = train_step_and_args()
+    return op_names(step.lower(*args).compile())
+
+
+@pytest.fixture(scope='module')
+def decode_op_names():
+    model = tiny_lm(distributed=False, decode_impl='kernel')
+    tokens = jnp.zeros((2, 1), jnp.int32)
+    params = model.init(jax.random.key(0), jnp.zeros((2, 8), jnp.int32))
+    caches = model.make_decode_caches(2, 128)
+
+    def step(p, tok, c):
+        return model.apply(p, tok, c, method='decode')
+
+    return op_names(jax.jit(step).lower(params, tokens, caches).compile())
+
+
+def opened(scope, names):
+    return any(f'/{scope}/' in f'/{name}/' for name in names)
+
+
+@pytest.mark.parametrize('scope', TRAIN_SCOPES)
+def test_train_step_opens(scope, train_op_names):
+    assert opened(scope, train_op_names)
+
+
+@pytest.mark.parametrize('scope', DECODE_SCOPES)
+def test_decode_step_opens(scope, decode_op_names):
+    assert opened(scope, decode_op_names)
+
+
+def test_the_two_steps_cover_the_vocabulary():
+    assert set(TRAIN_SCOPES) | set(DECODE_SCOPES) == set(DEVICE_SCOPES)
+
+
+def test_unknown_scope_raises():
+    with pytest.raises(ValueError, match='unknown device scope'):
+        device_scope('nope')
+
+
+def test_passes_show_in_op_names(train_op_names):
+    """What the trace reader tells the passes by."""
+    flash_fwd = [n for n in train_op_names if '/ops.flash_fwd/' in n]
+    assert any('rematted_computation' in n for n in flash_fwd)
+    assert any('transpose(jvp(' not in n and 'jvp(' in n for n in flash_fwd)
+    assert all('transpose(jvp(' in n for n in train_op_names
+               if '/ops.flash_bwd_dq/' in n)
+
+
+def kernel_names(fn, *args):
+    return [eqn.params['name']
+            for eqn in _iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == 'pallas_call']
+
+
+Q = jnp.zeros((1, 2, 64, 16), jnp.float32)
+
+
+def flash_sum(**kw):
+    return lambda q: flash_attention(q, q, q, causal=True, **kw).sum()
+
+
+@pytest.mark.parametrize('fn, expected', [
+    (flash_sum(), ['flash_fwd']),
+    (jax.grad(flash_sum()), ['flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv']),
+    (flash_sum(qk_quant='int8'), ['flash_fwd_int8']),
+    (flash_sum(softmax_mode='bounded'), ['flash_fwd_bounded', 'flash_fwd']),
+], ids=['fwd', 'grad', 'int8', 'bounded'])
+def test_flash_builds_carry_their_kernel_names(fn, expected):
+    assert sorted(kernel_names(fn, Q)) == sorted(expected)
+
+
+def test_decode_build_carries_its_kernel_name():
+    cache = jnp.zeros((1, 2, 128, 16), jnp.float32)
+    new = jnp.zeros((1, 2, 1, 16), jnp.float32)
+    at = jnp.zeros((1,), jnp.int32)
+
+    def step(q, k, v, ck, cv):
+        return pallas_decode.flash_decode(q, k, v, ck, cv, at, at,
+                                          interpret=True)[0]
+
+    assert kernel_names(step, new, new, new, cache, cache) == [
+        'flash_decode']
+
+
+def test_every_kernel_name_has_its_scope():
+    assert set(pallas_attention._KERNEL_SCOPES.values()) <= set(DEVICE_SCOPES)
+
+
+def equations(jaxpr):
+    return [(eqn.primitive.name,
+             tuple((v.aval.shape, str(v.aval.dtype)) for v in eqn.outvars
+                   if hasattr(v.aval, 'shape')))
+            for eqn in _iter_eqns(jaxpr)]
+
+
+def test_a_scope_adds_no_operation(monkeypatch):
+    """The train step as shipped against the same step traced with
+    ``device_scope`` a null context (patched here, not switched in the
+    program): the same primitives with the same shapes, in order."""
+    step, args = train_step_and_args()
+    shipped = equations(jax.make_jaxpr(step)(*args).jaxpr)
+    for module in (attention, lm, transformer, train, pallas_attention,
+                   pallas_decode):
+        monkeypatch.setattr(module, 'device_scope',
+                            lambda name: contextlib.nullcontext())
+    step, args = train_step_and_args()
+    bare = equations(jax.make_jaxpr(step)(*args).jaxpr)
+    assert len(shipped) > 100
+    assert shipped == bare
