@@ -592,11 +592,11 @@ class DistributedDotProductAttn(nn.Module):
             dtype=dtype or self.dtype or jnp.float32,
             qk_quant=self.qk_quant)
 
-    def _project_for_decode(self, keys, queries, values, cache):
+    def _project_for_decode(self, keys, queries, values, length):
         """Shared front half of :meth:`prefill`/:meth:`decode`: the four
         projections, GQA head split, and RoPE at the true global
-        positions ``cache.length + arange(n)`` — ONE definition so the
-        two inference entry points cannot drift."""
+        positions ``length + arange(n)`` (``length`` the cache's) — ONE
+        definition so the two inference entry points cannot drift."""
         if not self.causal:
             raise ValueError('cached decoding is autoregressive and '
                              'requires causal=True')
@@ -613,7 +613,7 @@ class DistributedDotProductAttn(nn.Module):
         values = split(values, self._kv_heads,
                        self._value_dim // self.num_heads)
         if self.use_rope:
-            pos = cache.length + jnp.arange(n)
+            pos = length + jnp.arange(n)
             keys = rope(keys, pos, base=self.rope_base)
             queries = rope(queries, pos, base=self.rope_base)
         return keys, queries, values
@@ -644,7 +644,7 @@ class DistributedDotProductAttn(nn.Module):
         from distributed_dot_product_tpu.models.decode import append_kv
         with device_scope('lm.attn_proj'):
             keys, queries, values = self._project_for_decode(
-                keys, queries, values, cache)
+                keys, queries, values, cache.length)
             start = cache.length
             cache = append_kv(cache, queries, values)
             seg_pair = None
@@ -664,7 +664,7 @@ class DistributedDotProductAttn(nn.Module):
             return cache, self._merge_decode_heads(out)
 
     def decode(self, keys, queries, values, cache, segment_ids=None,
-               seg_cache=None):
+               seg_cache=None, layer=None):
         """Incremental (KV-cache) inference step — the module-level
         surface over :mod:`distributed_dot_product_tpu.models.decode`.
 
@@ -690,21 +690,28 @@ class DistributedDotProductAttn(nn.Module):
         append+attend pair runs as one fused step
         (:func:`~distributed_dot_product_tpu.models.decode.decode_step`;
         the ``decode_impl`` field selects the Pallas kernel vs the XLA
-        formulation). Use ``apply(params, k, q, v, cache,
-        method='decode')``; returns ``(cache, out (B, n, value_dim))``.
+        formulation). ``layer`` (traced int32 scalar): ``cache`` is a
+        layer-stacked cache (a scanned stack's) and this call is layer
+        ``layer``'s step, addressed in place — see ``decode_step``. Use
+        ``apply(params, k, q, v, cache, method='decode')``; returns
+        ``(cache, out (B, n, value_dim))``.
         """
         from distributed_dot_product_tpu.models.decode import (
             decode_step,
         )
         with device_scope('lm.attn_proj'):
+            length = cache.length
+            if layer is not None:
+                length = jax.lax.dynamic_index_in_dim(
+                    length, layer, keepdims=False)
             keys, queries, values = self._project_for_decode(
-                keys, queries, values, cache)
+                keys, queries, values, length)
             cache, out = decode_step(
                 keys, cache, queries, values,
                 scale=1.0 / math.sqrt(self.head_dim),
                 window=self.window, alibi_slopes=self.alibi_slopes,
                 qk_quant=self.qk_quant, segment_ids=seg_cache,
-                seg_q=segment_ids, impl=self.decode_impl)
+                seg_q=segment_ids, impl=self.decode_impl, layer=layer)
             return cache, self._merge_decode_heads(out)
 
     def decode_sharded(self, keys, queries, values, cache,
@@ -729,7 +736,7 @@ class DistributedDotProductAttn(nn.Module):
         ax = axis_name or self.axis_name
         with device_scope('lm.attn_proj'):
             keys, queries, values = self._project_for_decode(
-                keys, queries, values, cache)
+                keys, queries, values, cache.length)
             cache, out = decode_step(
                 keys, cache, queries, values,
                 scale=1.0 / math.sqrt(self.head_dim),

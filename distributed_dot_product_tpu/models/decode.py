@@ -1780,15 +1780,35 @@ def _axis_env_size(axis_name):
         return 2
 
 
+def _take_layer(x, layer):
+    """Layer ``layer`` of a layer-stacked array (``layer=None``: ``x``
+    is one layer's already)."""
+    if layer is None:
+        return x
+    return lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+
+
+def _put_layer(stacked, x, layer):
+    """``stacked`` with layer ``layer`` replaced by ``x``
+    (``layer=None``: ``x`` itself)."""
+    if layer is None:
+        return x
+    return lax.dynamic_update_index_in_dim(stacked, x, layer, 0)
+
+
 _IMPL_SINKS = []        # lists of the active decode_impl_traces() blocks
 
 
 @contextlib.contextmanager
 def decode_impl_traces():
     """Collect what :func:`decode_step` resolves ``impl`` to while the
-    block runs: one dict ``{'requested', 'resolved', 'reason'}`` per
-    TRACE (= per compiled step; ``reason`` names why ``'auto'`` fell
-    back to ``'xla'``, else None). ``'auto'`` takes the XLA formulation
+    block runs: one dict ``{'requested', 'resolved', 'reason',
+    'cache'}`` per TRACE (= per compiled step; ``reason`` names why
+    ``'auto'`` fell back to ``'xla'``, else None; ``cache`` is
+    ``'stacked'`` where the step addressed a layer-stacked buffer by
+    ``layer`` — a scanned stack's in-place loop — and ``'layer'`` where
+    it was handed one layer's buffers, so a return to slicing the stack
+    per layer shows here). ``'auto'`` takes the XLA formulation
     off-TPU and wherever the kernel does not cover the call, so a smoke
     or benchmark run wraps the compile of its step in this and asserts
     the path the program holds instead of trusting it::
@@ -1806,7 +1826,7 @@ def decode_impl_traces():
 
 
 def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
-                         axis_name=None):
+                         axis_name=None, stacked=False):
     # Thread the mesh geometry into EVERY eligibility probe so the
     # explain string names every gate this resolver actually tests —
     # before this, a forced-kernel sharded verify-k passed the
@@ -1840,14 +1860,15 @@ def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
             resolved = 'kernel'
     for sink in _IMPL_SINKS:
         sink.append({'requested': impl or 'auto', 'resolved': resolved,
-                     'reason': reason})
+                     'reason': reason,
+                     'cache': 'stacked' if stacked else 'layer'})
     return resolved
 
 
 def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
                 counts=None, scale=None, window=None, alibi_slopes=None,
                 segment_ids=None, seg_q=None, qk_quant=None,
-                axis_name=None, impl=None, interpret=None):
+                axis_name=None, impl=None, interpret=None, layer=None):
     """One fused decode step: append ``k_new``/``v_new`` to the cache
     AND attend ``q`` against the result — ``append_kv*`` +
     :func:`decode_attention` as ONE call, so the kernel path
@@ -1888,12 +1909,34 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
     or masked XLA partials; n == 1 only on the kernel). Overflow
     follows the append contracts: concrete lengths raise eagerly,
     traced lengths write nothing while the length still advances.
+
+    ``layer`` (int32 scalar, may be traced): ``cache`` is a
+    LAYER-STACKED :class:`DecodeCache` (every field with a leading
+    layer axis, as ``TransformerStack.make_decode_caches`` builds for
+    a scanned stack) and the step is layer ``layer``'s: the kernel
+    path addresses that layer of the stacked buffers in place
+    (``flash_decode(layer=)``), so a layer loop that CARRIES the stack
+    moves no cache bytes beyond the appended block; the XLA
+    formulation takes the layer out and puts it back
+    (``dynamic_index_in_dim`` / ``dynamic_update_index_in_dim``). The
+    returned cache is the stack with layer ``layer`` updated.
     Returns ``(cache, out (B, H, n, d_v))``.
     """
     n = q.shape[-2]
-    impl = _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
-                                axis_name=axis_name)
     paged = isinstance(cache, PagedDecodeCache)
+    stack = None
+    if layer is not None:
+        if paged:
+            raise ValueError('decode_step: layer addresses a '
+                             'layer-stacked DecodeCache; paged caches '
+                             'have no layer axis')
+        # The layer's own clock from here on; the buffers stay stacked
+        # until a path needs one layer's.
+        stack = cache
+        cache = cache._replace(length=_take_layer(cache.length, layer))
+    impl = _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
+                                axis_name=axis_name,
+                                stacked=stack is not None)
     per_slot = cache.length.ndim == 1
     if per_slot and axis_name is not None and not paged:
         raise ValueError(
@@ -1916,6 +1959,8 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
                          'rows')
 
     if impl == 'xla':
+        if stack is not None:
+            cache = jax.tree.map(lambda x: _take_layer(x, layer), stack)
         before = cache.length
         if axis_name is not None and not paged:
             cache = append_kv_sharded(cache, k_new, v_new,
@@ -1975,6 +2020,9 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
             alibi_slopes=alibi_slopes, segment_ids=segment_ids,
             seg_q=seg_q, qk_quant=qk_quant, axis_name=axis_name,
             col_valid=col_valid, col_offset=col_offset)
+        if stack is not None:
+            cache = jax.tree.map(lambda s, x: _put_layer(s, x, layer),
+                                 stack, cache)
         return cache, out
 
     from distributed_dot_product_tpu.ops.pallas_decode import (
@@ -2082,7 +2130,7 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
         return cache, out
 
     res = flash_decode(
-        q, k_new, v_new, cache.k, cache.v, vt, ap, n_new=nn,
+        q, k_new, v_new, cache.k, cache.v, vt, ap, n_new=nn, layer=layer,
         k_q=cache.k_q if qk_quant == 'int8' else None,
         k_scale=cache.k_scale if qk_quant == 'int8' else None,
         scale=scale, window=window, alibi_slopes=alibi_slopes,
@@ -2096,7 +2144,7 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
         from distributed_dot_product_tpu.ops.pallas_attention import (
             _quantize_rows,
         )
-        bb, h_kv, _, d = cache.k.shape
+        bb, h_kv, _, d = cache.k.shape[-4:]
         ki8, ks = _quantize_rows(k_new.astype(cache.k.dtype), bb * h_kv,
                                  n, d)
         nvec = nn if nn is not None else jnp.where(ap >= 0, n, 0)
@@ -2105,16 +2153,20 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
             jnp.logical_and(g >= ap[:, None], ap[:, None] >= 0),
             g < ap[:, None] + nvec[:, None])[:, None, :, None]
         src = jnp.clip(g - ap[:, None], 0, n - 1)[:, None, :, None]
-        new_kq = jnp.where(
+        new_kq = _put_layer(cache.k_q, jnp.where(
             hit, jnp.take_along_axis(ki8.reshape(bb, h_kv, n, d),
-                                     src, axis=-2), cache.k_q)
-        new_ks = jnp.where(
+                                     src, axis=-2),
+            _take_layer(cache.k_q, layer)), layer)
+        new_ks = _put_layer(cache.k_scale, jnp.where(
             hit, jnp.take_along_axis(ks.reshape(bb, h_kv, n, 1),
-                                     src, axis=-2), cache.k_scale)
+                                     src, axis=-2),
+            _take_layer(cache.k_scale, layer)), layer)
     elif cache.k_q is not None:
         pass                                    # kernel maintained it
     else:
         new_kq = new_ks = None
+    if stack is not None:
+        new_length = _put_layer(stack.length, new_length, layer)
     cache = DecodeCache(k=new_k, v=new_v, length=new_length,
                         k_q=new_kq, k_scale=new_ks)
     if axis_name is None:

@@ -103,9 +103,11 @@ class TransformerBlock(nn.Module):
         x = x + a
         return cache, x + self._mlp(x)
 
-    def decode(self, x, cache):
+    def decode(self, x, cache, layer=None):
+        # layer: cache is a layer-stacked cache and this block is layer
+        # ``layer`` of it (a scanned stack) — see attn.decode.
         h = self.ln1(x)
-        cache, a = self.attn.decode(h, h, h, cache)
+        cache, a = self.attn.decode(h, h, h, cache, layer=layer)
         x = x + a
         return cache, x + self._mlp(x)
 
@@ -152,9 +154,14 @@ class _ScanStackCore(nn.Module):
         cache, x = self.block.prefill(x, cache)
         return x, cache
 
-    def decode(self, x, cache):
-        cache, x = self.block.decode(x, cache)
-        return x, cache
+    def decode(self, carry, layer_idx):
+        # The WHOLE stacked cache rides the carry beside x and the
+        # layer's step addresses its own layer of it in place: as a
+        # scanned input/output pair a layer's buffers would be sliced
+        # out of the stack and written back on every token.
+        x, caches = carry
+        caches, x = self.block.decode(x, caches, layer=layer_idx)
+        return (x, caches), None
 
 
 class TransformerStack(nn.Module):
@@ -171,7 +178,14 @@ class TransformerStack(nn.Module):
     single block with layer-stacked parameters
     (``params['layers']['block']`` with a leading ``n_layers`` axis vs
     the unrolled ``block_i`` subtrees) — same math, O(1) trace/compile
-    in depth; generation scans the stacked KV caches the same way.
+    in depth. Its KV caches are ONE pytree with a leading layer axis:
+    ``prefill`` scans them (a layer's cache in, the filled one out),
+    while ``decode`` — the per-token path — CARRIES the whole stack
+    through the layer loop beside ``x`` and scans only the layer
+    index, each layer's fused step appending to its own layer of the
+    carried buffers in place (``decode_step(layer=)``), so a token
+    moves no cache bytes but the rows it reads and the block it
+    writes.
     ``remat=True`` (scan only) wraps the block in ``jax.checkpoint`` so
     the backward rematerializes one layer at a time — activation memory
     for the stack drops from O(n_layers) to O(1) layers plus the scan
@@ -229,7 +243,7 @@ class TransformerStack(nn.Module):
                 'layer': dict(in_axes=(0, bcast, bcast, bcast, bcast),
                               **common),
                 'prefill': dict(in_axes=0, out_axes=0, **common),
-                'decode': dict(in_axes=0, out_axes=0, **common),
+                'decode': dict(in_axes=0, **common),
             })(dim=self.dim, num_heads=self.num_heads,
                mlp_ratio=self.mlp_ratio, axis_name=self.axis_name,
                dtype=self.dtype, weight_quant=self.weight_quant,
@@ -257,8 +271,8 @@ class TransformerStack(nn.Module):
         # Plain field arithmetic (no proto Module: flax would try to
         # register it as a child of this one) — same layout rule as
         # DistributedDotProductAttn.make_decode_cache. Scanned stacks
-        # get ONE cache pytree with a leading layer axis (the scanned
-        # input of the generation scan); unrolled stacks a list.
+        # get ONE cache pytree with a leading layer axis (prefill's
+        # scanned input, decode's loop carry); unrolled stacks a list.
         from distributed_dot_product_tpu.models.decode import init_cache
         kw = dict(self.attn_kwargs or {})
         kv_heads = kw.get('num_kv_heads') or self.num_heads
@@ -286,7 +300,9 @@ class TransformerStack(nn.Module):
     def decode(self, x, caches):
         with device_scope('lm.stack_carry'):
             if self.scan_layers:
-                x, caches = self.layers.decode(x, caches)
+                (x, caches), _ = self.layers.decode(
+                    (x, caches), jnp.arange(self.n_layers,
+                                            dtype=jnp.int32))
                 return caches, x
             out = []
             for block, cache in zip(self.blocks, caches):

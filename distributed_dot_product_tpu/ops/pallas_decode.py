@@ -10,24 +10,26 @@ appended rows).
 ``models/decode.py``'s XLA formulation runs a decode step as two ops —
 ``append_kv_slots`` (a masked gather over the whole ``t_max`` axis) and
 ``decode_attention`` (a masked einsum softmax over the full buffer) —
-which is correct and backend-portable but leaves the chained serving
-loop ~2× above its own measured physics floor: with the cache riding a
-``lax.scan`` carry between the two ops, XLA materializes full
-cache-shaped copies per step (RESULTS.md "KV-cache decode": 10.34
-ms/step at B=8/131K vs 4.25 + 0.9 ms of attention + append in
-isolation), and the int8 K mirror *loses* to bf16 (0.32 vs 0.21
-ms/step) because XLA's s8 dot lowering at 4-row operands never cashes
-the halved bytes in.
+which is correct and backend-portable, but between the two ops XLA
+materializes cache-shaped copies, and its s8 dot lowering at few-row
+operands never turns the int8 K mirror's halved bytes into halved
+traffic.
 
-This kernel is the fix both RESULTS entries name: ONE Pallas program
-per decode step that
+This kernel is ONE Pallas program per decode step that
 
 - **appends in place**: the K/V buffers (and the int8 mirror, when the
   cache carries one) are passed as aliased outputs
   (``input_output_aliases``), and only the single block containing the
-  append row is ever written — the cache never travels through a scan
-  carry or a donated-copy, and unwritten blocks keep their bits by the
-  aliasing contract;
+  append row is ever written; unwritten blocks keep their bits by the
+  aliasing contract. A scanned stack's caches keep that property
+  through the layer loop: the LAYER-STACKED buffers are the loop's
+  carry, the step takes them whole with a ``layer`` index
+  (scalar-prefetched, added to every cache block's row), and the whole
+  stacked operand aliases the result — so no layer is sliced out of
+  the stack, written back, or copied on the way to the donated
+  buffer. (As a scan's xs → ys the same caches cost six cache-sized
+  operations a token: an ``xs[l]`` slice cannot alias a ``ys[l]``
+  slot.) ``tests/test_tpu_compile.py`` holds the compiled step to it;
 - **splits K over the time axis**: the grid sweeps ``t_max`` in
   ``block_k`` chunks with running ``(max, denom, acc)`` accumulators in
   VMEM scratch (the flash-decoding work partition; on TPU the grid is
@@ -112,7 +114,7 @@ def _pad_rows(x, mult):
 
 
 def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
-                        quantized, has_alibi, paged=False):
+                        quantized, has_alibi, paged=False, stacked=False):
     """Kernel body; refs are ordered to match ``flash_decode``'s spec
     list below. Grid = (B·H_kv, ns) with the K split innermost; the
     running softmax state lives in scratch across splits.
@@ -311,6 +313,13 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
             m_ref[0] = m_s[:]
             l_ref[0] = l_s[:]
 
+    if stacked:
+        # The layer index only steers the BlockSpec index maps: the
+        # body sees one layer's blocks and needs no change.
+        def kernel_stacked(vt_ref, ap_ref, nn_ref, row0_ref, *refs):
+            kernel_body(vt_ref, ap_ref, nn_ref, *refs)
+
+        return kernel_stacked
     if not paged:
         return kernel_body
 
@@ -321,8 +330,8 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
 
 
 def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
-                 *, n_new=None, page_table=None, k_q=None, k_scale=None,
-                 scale=None, window=None, alibi_slopes=None,
+                 *, n_new=None, page_table=None, layer=None, k_q=None,
+                 k_scale=None, scale=None, window=None, alibi_slopes=None,
                  qk_quant=None, interpret=None, block_k=None,
                  partials=False):
     """One fused decode step: in-place cache append + masked online-
@@ -388,6 +397,17 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     paged concurrency — and the mirror pages are appended in place
     alongside the bf16 pool.
 
+    ``layer`` (int32 scalar, may be traced): STACKED mode — the buffers
+    (and the mirror) carry a leading layer axis, ``(L, B, H_kv, t_max,
+    d·)``, and the step reads and appends layer ``layer`` of them in
+    place: the stack is viewed as ``(L·B·H_kv, t_max, d·)`` rows (a
+    bitcast), the layer's first row ``layer · B·H_kv`` rides as one
+    more scalar-prefetch operand and every cache index map adds it to
+    its row. The WHOLE stacked operand is aliased to the result, so a
+    layer loop that carries the stack updates it in place — no
+    per-layer slice, no write-back; every other layer keeps its bits.
+    Not with ``page_table`` (no stack builds paged caches).
+
     Returns ``(out, cache_k, cache_v, k_q, k_scale)`` with
     ``out (B, H, k, dv)`` in ``cache_v.dtype`` — or, with
     ``partials=True``, ``((num, m, l), cache_k, cache_v, k_q, k_scale)``
@@ -397,9 +417,18 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     rescale, psum).
     """
     b, h, n, d = q.shape
-    h_kv = cache_k.shape[1]
+    h_kv = cache_k.shape[-3]
     dv = cache_v.shape[-1]
     paged = page_table is not None
+    stacked = layer is not None
+    if stacked and paged:
+        raise ValueError('flash_decode: layer addresses a layer-stacked '
+                         'slab cache; a paged pool has no layer axis')
+    if cache_k.ndim != 4 + stacked:
+        raise ValueError(
+            f'flash_decode: cache_k {cache_k.shape} needs '
+            f'{4 + stacked} axes — a layer-stacked (L, B, H_kv, t_max, '
+            f'd) buffer goes with layer=, one layer\'s without')
     if n < 1:
         raise ValueError(f'flash_decode needs at least one query row, '
                          f'got {n}')
@@ -428,7 +457,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
             raise ValueError(f'paged decode splits K at the page size '
                              f'{bk}; block_k={block_k} cannot differ')
     else:
-        t_max = cache_k.shape[2]
+        t_max = cache_k.shape[-2]
         bk = block_k or decode_block_k(t_max)
         if bk is None or t_max % bk:
             raise ValueError(
@@ -498,8 +527,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         sink = n_pages - 1
         ptf = jnp.asarray(page_table, jnp.int32).reshape(-1)
     else:
-        kf = cache_k.reshape(nb, t_max, d)
-        vf = cache_v.reshape(nb, t_max, dv)
+        # A stacked buffer folds its layer axis into the rows too:
+        # layer l's (slot, head) row r lives at flat row l·nb + r.
+        kf = cache_k.reshape(-1, t_max, d)
+        vf = cache_v.reshape(-1, t_max, dv)
     valid_to = jnp.asarray(valid_to, jnp.int32)
     append_at = jnp.asarray(append_at, jnp.int32)
     # Per-slot appended-row count: callers without mixed batches get
@@ -563,21 +594,28 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         stream_idx_row = stream_idx
         write_idx_row = write_idx
     else:
-        def stream_idx(bi, ki, vt, ap, nn):
-            return (bi, _stream_blk(bi, ki, vt), 0)
+        # Stacked: the prefetched first row of the layer (layer · nb)
+        # redirects every cache row to its layer's run of nb rows
+        # (``lay`` is empty otherwise) — the same kind of redirect the
+        # page table does above.
+        def _row(bi, lay):
+            return bi + lay[0][0] if lay else bi
 
-        def write_idx(bi, ki, vt, ap, nn):
-            return (bi, _write_blk(bi, ki, ap, nn), 0)
+        def stream_idx(bi, ki, vt, ap, nn, *lay):
+            return (_row(bi, lay), _stream_blk(bi, ki, vt), 0)
+
+        def write_idx(bi, ki, vt, ap, nn, *lay):
+            return (_row(bi, lay), _write_blk(bi, ki, ap, nn), 0)
 
         # The int8 scale mirror rides as a (nb, 1, t_max) ROW vector (a
         # size-1-axis reshape — a bitcast, not a transpose), blocked on
         # the LAST axis, so the kernel consumes (1, BK) scale rows
         # directly.
-        def stream_idx_row(bi, ki, vt, ap, nn):
-            return (bi, 0, _stream_blk(bi, ki, vt))
+        def stream_idx_row(bi, ki, vt, ap, nn, *lay):
+            return (_row(bi, lay), 0, _stream_blk(bi, ki, vt))
 
-        def write_idx_row(bi, ki, vt, ap, nn):
-            return (bi, 0, _write_blk(bi, ki, ap, nn))
+        def write_idx_row(bi, ki, vt, ap, nn, *lay):
+            return (_row(bi, lay), 0, _write_blk(bi, ki, ap, nn))
 
     in_specs = [pl.BlockSpec((1, g_pad, d), const_idx)]
     args = [qf]
@@ -609,8 +647,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
             kqf = k_q.reshape(n_pages * h_kv, bk, d)
             ksf = k_scale.reshape(n_pages * h_kv, 1, bk)
         else:
-            kqf = k_q.reshape(nb, t_max, d)
-            ksf = k_scale.reshape(nb, 1, t_max)
+            kqf = k_q.reshape(-1, t_max, d)
+            ksf = k_scale.reshape(-1, 1, t_max)
         in_specs += [pl.BlockSpec((1, bk, d), stream_idx),
                      pl.BlockSpec((1, 1, bk), stream_idx_row)]
         kq_in_pos = len(args)
@@ -649,8 +687,13 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     ]
     # +n_prefetch: alias indices count the scalar-prefetch operands
     # (valid_to, append_at, n_new, and — paged — the flattened page
-    # table).
-    n_prefetch = 4 if paged else 3
+    # table or — stacked — the layer's first flat row).
+    prefetch = (valid_to, append_at, nnv)
+    if paged:
+        prefetch += (ptf,)
+    elif stacked:
+        prefetch += ((jnp.asarray(layer, jnp.int32) * nb).reshape(1),)
+    n_prefetch = len(prefetch)
     aliases = {n_prefetch + k_in_pos: 3, n_prefetch + v_in_pos: 4}
     if quantized:
         out_specs += [pl.BlockSpec((1, bk, d), write_idx),
@@ -661,9 +704,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         aliases[n_prefetch + ks_in_pos] = 6
 
     kernel = _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
-                                 quantized, has_alibi, paged=paged)
-    prefetch = ((valid_to, append_at, nnv, ptf) if paged
-                else (valid_to, append_at, nnv))
+                                 quantized, has_alibi, paged=paged,
+                                 stacked=stacked)
     with device_scope('ops.flash_decode'):
         outs = pl.pallas_call(
             kernel,

@@ -234,3 +234,104 @@ def test_lm_checkpoint_resume_continues(tmp_path):
         losses.append(float(loss))
     assert all(np.isfinite(losses))
     assert losses[-1] < float(loss0)
+
+
+# ---------------------------------------------------------------------------
+# Scanned decode: the layer loop carries the stacked caches (PR 25)
+# ---------------------------------------------------------------------------
+
+_SLOPES = tuple(2.0 ** -(i + 1) for i in range(HEADS))
+_DECODE_CONFIGS = {
+    'mha-alibi': dict(use_rope=False, alibi_slopes=_SLOPES),
+    'gqa-window-rope': dict(num_kv_heads=2, window=6, use_rope=True),
+    'int8-mirror': dict(qk_quant='int8'),
+}
+_T_MAX, _FILL = 16, 9
+
+
+def _scanned_params(params):
+    """The unrolled LM's parameters in the scanned layout (``block_i``
+    subtrees stacked under ``layers/block``)."""
+    stack = params['params']['stack']
+    blocks = [stack[f'block_{i}'] for i in range(LAYERS)]
+    rest = {k: v for k, v in params['params'].items() if k != 'stack'}
+    return {'params': dict(rest, stack={'layers': {
+        'block': jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)}})}
+
+
+def _filled_caches(model, batch):
+    """Per-layer caches holding ``_FILL`` random rows each (the int8
+    mirror, where the model has one, kept by ``append_kv``)."""
+    from distributed_dot_product_tpu.models.decode import append_kv
+    out = []
+    for i, cache in enumerate(model.make_decode_caches(batch, _T_MAX)):
+        kk, kv = jax.random.split(jax.random.key(100 + i))
+        rows = cache.k.shape[:2] + (_FILL, cache.k.shape[-1])
+        out.append(append_kv(cache, jax.random.normal(kk, rows),
+                             jax.random.normal(kv, rows)))
+    return out
+
+
+@pytest.mark.parametrize('impl', ['xla', 'kernel'])
+@pytest.mark.parametrize('config', sorted(_DECODE_CONFIGS))
+def test_scanned_decode_matches_unrolled(config, impl):
+    """The scanned stack's decode (stacked caches carried by the layer
+    loop, layer l addressed in place) against the unrolled stack's (one
+    cache a layer): the same logits and, layer by layer, the same
+    caches — through the XLA step and the interpreted kernel."""
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    kw = dict(distributed=False, decode_impl=impl,
+              **_DECODE_CONFIGS[config])
+    unrolled = _model(attn_kwargs=kw, scan_layers=False)
+    scanned = _model(attn_kwargs=kw, scan_layers=True)
+    batch = 2
+    params = unrolled.init(jax.random.key(0),
+                           jnp.zeros((batch, 4), jnp.int32))
+    sparams = _scanned_params(params)
+    caches = _filled_caches(unrolled, batch)
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
+    assert (jax.tree.structure(stacked) == jax.tree.structure(
+        scanned.make_decode_caches(batch, _T_MAX)))
+
+    step_u, step_s = (
+        jax.jit(lambda p, t, c, m=m: m.apply(p, t, c, method='decode'))
+        for m in (unrolled, scanned))
+    # What decode_impl_traces says of each: the unrolled stack hands
+    # every step one layer's buffers, the scanned one the stack.
+    tok = jnp.zeros((batch, 1), jnp.int32)
+    with decode_impl_traces() as seen:
+        step_u.lower(params, tok, caches)
+    assert {(t['resolved'], t['cache']) for t in seen} == {
+        (impl, 'layer')}
+    with decode_impl_traces() as seen:
+        step_s.lower(sparams, tok, stacked)
+    assert {(t['resolved'], t['cache']) for t in seen} == {
+        (impl, 'stacked')}
+    for i in range(3):
+        tok = jnp.full((batch, 1), 3 + i, jnp.int32)
+        caches, want = step_u(params, tok, caches)
+        stacked, got = step_s(sparams, tok, stacked)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(stacked.length),
+                                  [_FILL + 3] * LAYERS)
+    for l, cache in enumerate(caches):
+        for name in ('k', 'v', 'k_q', 'k_scale'):
+            a, b = getattr(cache, name), getattr(stacked, name)
+            assert (a is None) == (b is None), name
+            if a is not None:
+                np.testing.assert_allclose(
+                    np.asarray(b[l]), np.asarray(a), atol=2e-5,
+                    err_msg=f'layer {l} {name}')
+
+
+def test_decode_step_layer_refuses_paged_cache():
+    from distributed_dot_product_tpu.models.decode import (
+        decode_step, init_paged_cache,
+    )
+    cache = init_paged_cache(2, 2, 16, 8, pages=4, page_size=8)
+    q = jnp.zeros((2, 2, 1, 8))
+    with pytest.raises(ValueError, match='layer'):
+        decode_step(q, cache, q, q, impl='xla', layer=0)

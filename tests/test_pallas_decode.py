@@ -289,3 +289,84 @@ def test_engine_kernel_path_streams():
     np.testing.assert_array_equal(lens_k, lens_x)
     for a, b in zip(stream_x, stream_k):
         np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# Stacked addressing: flash_decode(layer=l) on a layer-stacked buffer
+# ---------------------------------------------------------------------------
+
+_LAYERS = 3
+_STACKED_CASES = {
+    'mha': (2, 2, {}),
+    'gqa-window-alibi': (4, 2, {'window': 5,
+                                'alibi_slopes': (0.5, 0.25, 0.125, 0.0625)}),
+    'int8-mirror': (4, 2, {'qk_quant': 'int8'}),
+}
+
+
+def _stacked_buffers(h_kv, quant):
+    """``_LAYERS`` layers of distinct cache contents, stacked; with
+    ``quant`` the K mirror too (as ``append_kv`` keeps it)."""
+    layers = []
+    for l in range(_LAYERS):
+        _, _, _, kf, vf = _operands(2, h_kv, key=20 + l)
+        cache = init_cache(B, h_kv, T, D, dtype=jnp.float32,
+                           qk_quant='int8' if quant else None)
+        layers.append(append_kv(cache, kf, vf))
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+
+
+@pytest.mark.parametrize('layer', range(_LAYERS))
+@pytest.mark.parametrize('case', sorted(_STACKED_CASES))
+def test_flash_decode_layer_addresses_stack_in_place(case, layer):
+    """``flash_decode(layer=l)`` on the stacked buffers is
+    ``flash_decode`` on layer l's, bit for bit — output, appended
+    buffers, mirror — and every other layer keeps its bits. The layer
+    index is traced (it is a scan's counter in the model)."""
+    from distributed_dot_product_tpu.ops.pallas_decode import flash_decode
+    h, h_kv, kw = _STACKED_CASES[case]
+    quant = kw.get('qk_quant') == 'int8'
+    q, kn, vn, _, _ = _operands(h, h_kv, key=7)
+    stack = _stacked_buffers(h_kv, quant)
+    vt = jnp.asarray([5, 9, 0], jnp.int32)
+    ap = jnp.asarray([5, 9, -1], jnp.int32)      # slot 2 appends nothing
+
+    def mirror(c):
+        return dict(k_q=c.k_q, k_scale=c.k_scale) if quant else {}
+
+    one = jax.tree.map(lambda x: x[layer], stack)
+    want = jax.jit(lambda: flash_decode(
+        q, kn, vn, one.k, one.v, vt, ap, **mirror(one), **kw))()
+    got = jax.jit(lambda l: flash_decode(
+        q, kn, vn, stack.k, stack.v, vt, ap, layer=l, **mirror(stack),
+        **kw))(jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    before = (stack.k, stack.v, stack.k_q, stack.k_scale)
+    for new, new_one, old in zip(got[1:], want[1:], before):
+        assert (new is None) == (new_one is None)
+        if new is None:
+            continue
+        assert new.shape == old.shape
+        np.testing.assert_array_equal(np.asarray(new[layer]),
+                                      np.asarray(new_one))
+        others = [l for l in range(_LAYERS) if l != layer]
+        np.testing.assert_array_equal(np.asarray(new)[others],
+                                      np.asarray(old)[others])
+
+
+def test_flash_decode_layer_argument_checks():
+    """``layer`` goes with a stacked slab buffer only: not with a page
+    table, and a stacked buffer not without it."""
+    from distributed_dot_product_tpu.models.decode import init_paged_cache
+    from distributed_dot_product_tpu.ops.pallas_decode import flash_decode
+    q, kn, vn, kf, vf = _operands(2, 2, key=8)
+    vt = ap = jnp.zeros((B,), jnp.int32)
+    paged = init_paged_cache(B, 2, T, D, pages=6, page_size=8,
+                             dtype=jnp.float32)
+    with pytest.raises(ValueError, match='layer'):
+        flash_decode(q, kn, vn, paged.k_pool, paged.v_pool, vt, ap,
+                     page_table=paged.page_table, layer=0)
+    with pytest.raises(ValueError, match='layer'):
+        flash_decode(q, kn, vn, kf[None], vf[None], vt, ap)
+    with pytest.raises(ValueError, match='layer'):
+        flash_decode(q, kn, vn, kf, vf, vt, ap, layer=0)

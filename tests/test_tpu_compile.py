@@ -14,7 +14,9 @@ results or times.
 still ``cpu`` in this process.
 """
 
+import math
 import os
+import re
 
 os.environ.setdefault('TPU_LOG_DIR', 'disabled')  # else logs under /tmp
 # Only the compiler is used, no chip: let several test workers (pytest-
@@ -109,3 +111,114 @@ def test_decode_kernel_compiles_for_v5e(chip, layout, h_kv, qk_quant, n):
                            impl='kernel', interpret=False)
 
     _compile(chip, step, q, cache, kv, kv, donate=(1,))
+
+
+# ---------------------------------------------------------------------------
+# The scanned LM decode step: the layer loop carries the stacked caches
+# ---------------------------------------------------------------------------
+
+# Widths of the benchmark cell mpt-7b.decode-12k.
+LM = dict(vocab_size=50432, dim=4096, num_heads=32, n_layers=8)
+SESSIONS, LM_T_MAX = 2, 16384
+LAYER_K_BYTES = SESSIONS * 32 * LM_T_MAX * 128 * 2
+
+_HLO_INSTRUCTION = re.compile(
+    r'^\s*(ROOT )?%?[\w.\-]+ = (.*?)\s([a-z][a-z0-9\-]*)\(')
+_HLO_ARRAY = re.compile(r'\b(pred|[a-z]+\d+)\[([\d,]*)\]')
+_MOVES = ('copy', 'copy-start', 'dynamic-slice', 'dynamic-update-slice')
+
+
+def _result_bytes(result_type):
+    """Bytes of the largest array in an HLO result type."""
+    sizes = [0]
+    for dtype, dims in _HLO_ARRAY.findall(result_type):
+        bits = 8 if dtype == 'pred' else int(re.sub(r'\D', '', dtype))
+        sizes.append(math.prod(int(d) for d in dims.split(',') if d)
+                     * bits // 8)
+    return max(sizes)
+
+
+def _cache_sized_moves(hlo, at_least):
+    """The optimized HLO's copies, dynamic slices and dynamic-update
+    slices — bare, or as the root of a fusion — whose result is at
+    least ``at_least`` bytes."""
+    found, fused = [], False
+    for line in hlo.splitlines():
+        if line.rstrip().endswith('{'):              # a computation opens
+            fused = 'fused_computation' in line.split('(')[0]
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if (m and m.group(3) in _MOVES and (m.group(1) or not fused)
+                and _result_bytes(m.group(2)) >= at_least):
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_cache_sized_moves_reads_hlo():
+    """The reader the test below trusts, on lines of the parent's
+    program (whole-cache copy, slice fusion's root) and an innocent
+    one."""
+    hlo = '\n'.join([
+        'ENTRY %main.1 (p: bf16[8,2,32,16384,128]) -> bf16[4] {',
+        '  %copy.36 = bf16[8,2,32,16384,128]{4,3,2,1,0:T(8,128)(2,1)} '
+        'copy(%get-tuple-element.3)',
+        '  %small = s32[8]{0} dynamic-update-slice(%a, %b, %c)',
+        '}',
+        '%fused_computation.4 (p0: bf16[8,2,32,16384,128]) -> '
+        'bf16[1,2,32,16384,128] {',
+        '  %inner = bf16[1,2,32,16384,128]{4,3,2,1,0} '
+        'dynamic-slice(%p0, %i), dynamic_slice_sizes={1,2,32,16384,128}',
+        '  ROOT %ds = bf16[1,2,32,16384,128]{4,3,2,1,0} '
+        'dynamic-slice(%p0, %i), dynamic_slice_sizes={1,2,32,16384,128}',
+        '}'])
+    found = _cache_sized_moves(hlo, LAYER_K_BYTES)
+    assert [f.split(' = ')[0] for f in found] == ['%copy.36', 'ROOT %ds']
+
+
+@pytest.mark.parametrize('qk_quant', [None, 'int8'],
+                         ids=['bf16', 'int8-mirror'])
+def test_scanned_lm_decode_step_moves_no_cache(chip, monkeypatch,
+                                               qk_quant):
+    """The scanned ``TransformerLM.decode`` step at the decode cell's
+    widths, caches donated: the layer loop carries the stacked caches
+    and the kernel appends layer l in place, so the program holds no
+    copy / slice / write-back as large as one layer's K cache, the
+    stacked buffers alias the results and nothing cache-sized is a
+    temporary. (With the caches as the scan's xs → ys the same step
+    held four such fusions, two whole-stack copies and 5 GiB of
+    temporaries.)"""
+    from distributed_dot_product_tpu import TransformerLM
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    # The program asks the backend whether to compile its kernel or
+    # interpret it; answer for the described chip, here only.
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    slopes = tuple(2.0 ** (-8.0 * (i + 1) / 32) for i in range(32))
+    model = TransformerLM(**LM, dtype=jnp.bfloat16, scan_layers=True,
+                          attn_kwargs=dict(use_rope=False,
+                                           alibi_slopes=slopes,
+                                           qk_quant=qk_quant))
+    tok = jnp.zeros((SESSIONS, 1), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 128),
+                                                        jnp.int32)))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16), params)
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(SESSIONS, LM_T_MAX))
+    assert caches.k.shape == (8, SESSIONS, 32, LM_T_MAX, 128)
+
+    def step(p, t, c):
+        return model.apply(p, t, c, method='decode')
+
+    with decode_impl_traces() as traces:
+        compiled = _compile(chip, step, params, tok, caches, donate=(2,))
+    assert {(t['resolved'], t['cache']) for t in traces} == {
+        ('kernel', 'stacked')}
+    assert _cache_sized_moves(compiled.as_text(), LAYER_K_BYTES) == []
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert mem.alias_size_in_bytes >= cache_bytes    # tiles pad upward
+    assert mem.temp_size_in_bytes < LAYER_K_BYTES
