@@ -6,9 +6,13 @@ train → generate → serve path once, through the entry points a user
 calls, at the full width of the model the repo trains and times (the
 ``lm_8l_16k`` row of ``bench.py``: vocab 32768, dim 768, 8 heads of 96,
 8 scanned + remat'd layers, bf16, flash causal attention), and check
-what comes out by the repo's own means.
+what comes out by the repo's own means. ``generate_latent`` repeats the
+generate checks on a small-depth model of the other block
+``TransformerLM`` composes (latent attention at its published head
+sizes, sparse experts, hyper-connection streams), whose step is
+``flash_decode``'s latent mode.
 
-    python chip_smoke.py             # one chip: train, generate, serve
+    python chip_smoke.py             # one chip: train, generate (x2), serve
     python chip_smoke.py --chips 4   # ONLY the cross-chip phases
     JAX_PLATFORMS=cpu python chip_smoke.py --tiny   # CPU rehearsal
 
@@ -37,7 +41,16 @@ FULL = dict(
     slots=8, serve_t_max=32768, page=256, chunk=256,  # serve
     requests=12, prompt_lo=256, prompt_hi=4096, serve_new=32,
     mm_t=75000, mm_offset=25000, lm4_t=65536,       # --chips 4
-    shard_ctx=25000)
+    shard_ctx=25000,
+    # generate_latent: MLA + sparse experts + hyper-connections at the
+    # published head sizes of benchmarks/configs/xing4-29b-a4b-serve
+    # (top_k = experts: every expert gated, through the same sort and
+    # grouped matmuls. A top-k of fewer flips at near-ties between the
+    # kernel's step and its XLA twin, and a flipped pick moves the
+    # logits by more than any rounding tolerance: chip, PR 26)
+    latent=dict(dim=1024, heads=8, q_rank=256, kv_rank=512, nope=128,
+                rope=64, v=128, dense_hidden=2048, experts=8, top_k=8,
+                expert_hidden=256, layers=3))
 TINY = dict(
     vocab=128, dim=64, heads=2, layers=1,
     train_t=128, ref_t=32,
@@ -45,7 +58,10 @@ TINY = dict(
     slots=2, serve_t_max=128, page=16, chunk=16,
     requests=3, prompt_lo=8, prompt_hi=40, serve_new=4,
     mm_t=512, mm_offset=128, lm4_t=256,
-    shard_ctx=100)
+    shard_ctx=100,
+    latent=dict(dim=64, heads=4, q_rank=24, kv_rank=32, nope=16, rope=16,
+                v=16, dense_hidden=96, experts=4, top_k=4,
+                expert_hidden=32, layers=3))
 
 # bf16 tolerance, relative to the compared tensor's own scale: two paths
 # that are equal in exact arithmetic may differ by max|a - b| <=
@@ -304,10 +320,41 @@ def forced_logits(progs, tag, model, params, prompt, forced, t_max,
     return np.stack(out)
 
 
-def generate_once(progs, tag, cfg, seed, params, **attn_kwargs):
+def latent_lm(cfg, **attn_kwargs):
+    """One dense and ``layers - 1`` expert layers of the latent-attention
+    / sparse-expert / hyper-connection block (``cfg['latent']``)."""
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu import TransformerLM
+    c = cfg['latent']
+    yarn = (('beta_fast', 32), ('beta_slow', 1), ('factor', 64),
+            ('mscale', 1), ('mscale_all_dim', 1),
+            ('original_max_position_embeddings', cfg['prompt'] // 2))
+    return TransformerLM(
+        vocab_size=cfg['vocab'], dim=c['dim'], num_heads=c['heads'],
+        n_layers=c['layers'], dtype=jnp.bfloat16, scan_layers=False,
+        tie_embeddings=False,
+        attn_kwargs={'q_rank': c['q_rank'], 'kv_rank': c['kv_rank'],
+                     'nope_dim': c['nope'], 'rope_dim': c['rope'],
+                     'v_dim': c['v'], 'rope_scaling': yarn,
+                     **attn_kwargs},
+        block_kwargs={'norm': 'rmsnorm', 'mixer': 'latent',
+                      'ffn': 'experts',
+                      'ffn_kwargs': {'n_experts': c['experts'],
+                                     'top_k': c['top_k'],
+                                     'hidden': c['expert_hidden'],
+                                     'scaling': 2.0},
+                      'residual': 'hyper'},
+        dense_prefix=1,
+        prefix_kwargs={'ffn': 'gated',
+                       'ffn_kwargs': {'hidden': c['dense_hidden']}})
+
+
+def generate_once(progs, tag, cfg, seed, params, lm=lm, **attn_kwargs):
     """greedy_generate with the module's decode_impl left at its
     default, then the same token path through an ``decode_impl='xla'``
-    twin: resolved impl, kernel presence, finite tokens, logit parity."""
+    twin: resolved impl, kernel presence, finite tokens, logit parity.
+    ``lm`` builds the model (``lm``, ``latent_lm``)."""
     import jax
     import numpy as np
 
@@ -372,6 +419,21 @@ def phase_generate(progs, cfg, seed, state):
     return {'prompt': cfg['prompt'], 'new_tokens': cfg['new_tokens'],
             't_max': cfg['gen_t_max'], **rec, **q_rec,
             'checks': {**checks, **q_checks}}
+
+
+def phase_generate_latent(progs, cfg, seed):
+    """The same generate checks on the latent-attention model: its
+    prefill runs the expanded form through the flash forward kernel,
+    its step the absorbed form through ``flash_decode``'s latent mode
+    (``mla_decode``), against the XLA formulation of the same step."""
+    import jax
+    model = latent_lm(cfg)
+    params = {'params': model.init(
+        jax.random.key(seed + 5),
+        jax.numpy.zeros((1, 16), 'int32'))['params']}
+    rec, checks = generate_once(progs, 'latent', cfg, seed, params,
+                                lm=latent_lm)
+    return {**rec, 'checks': checks}
 
 
 # -- one chip: serve -----------------------------------------------------
@@ -740,6 +802,8 @@ def main(argv=None):
         oks = [run_phase('train', phase_train, cfg, args.seed, state),
                run_phase('generate', phase_generate, cfg, args.seed,
                          state),
+               run_phase('generate_latent', phase_generate_latent, cfg,
+                         args.seed),
                run_phase('serve', phase_serve, cfg, args.seed, out_dir)]
     else:
         mesh = seq_mesh(args.chips)

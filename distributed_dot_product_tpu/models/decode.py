@@ -1858,11 +1858,19 @@ def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
             reason = f'backend is {jax.default_backend()}, not tpu'
         else:
             resolved = 'kernel'
-    for sink in _IMPL_SINKS:
-        sink.append({'requested': impl or 'auto', 'resolved': resolved,
-                     'reason': reason,
-                     'cache': 'stacked' if stacked else 'layer'})
+    record_decode_impl(impl, resolved, reason,
+                       'stacked' if stacked else 'layer')
     return resolved
+
+
+def record_decode_impl(requested, resolved, reason, cache):
+    """Tell the open :func:`decode_impl_traces` blocks what one traced
+    decode step resolved to (this module's, and the latent cache's in
+    ``models/latent.py``)."""
+    for sink in _IMPL_SINKS:
+        sink.append({'requested': requested or 'auto',
+                     'resolved': resolved, 'reason': reason,
+                     'cache': cache})
 
 
 def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,

@@ -1,0 +1,357 @@
+# -*- coding: utf-8 -*-
+"""
+Multi-head latent attention (MLA, DeepSeek-V2/V3) and its cache.
+
+The layer keeps ONE compressed row a token for all heads,
+``[c_kv ; k_rope]`` (``kv_rank`` latent values after their RMSNorm, then
+the ``rope_dim`` rotated key values every head shares), and reads both K
+and V from it:
+
+    c_q = RMSNorm(x W_qa)                 [q_nope_h ; q_rope_h] = c_q W_qb
+    [c_kv ; k_r] = x W_kva                c_kv = RMSNorm(c_kv)
+    [k_nope_h ; v_h] = c_kv W_kvb,h       k_rope = RoPE(k_r)
+    score_h(t, s) = (q_nope_h(t)·k_nope_h(s) + RoPE(q_rope_h)(t)·k_rope(s)) · scale
+
+Two forms of the same arithmetic:
+
+- **expanded** (``__call__`` and ``prefill``): ``W_kvb`` expands the
+  cached rows to per-head K ``(nope + rope)`` and V, and the flash
+  forward kernel runs over them. Many query rows a key row: the
+  expansion is paid once a chunk.
+- **absorbed** (``decode``): ``W_kvb`` is folded into the query
+  (``q~_h = q_nope_h W_kvb,h^K^T``) and out of the context
+  (``out_h = (sum_s p c_kv(s)) W_kvb,h^V``), so a step reads the cache
+  row itself, once, for all heads: ``flash_decode``'s latent mode
+  (``ops.mla_decode``), or the XLA formulation off the chip.
+
+The cache (:class:`LatentCache`) is one layer-stacked buffer
+``(L, B, t_max, width)`` with per-layer, per-session lengths. It rides
+the layer loop's CARRY in prefill and in decode alike and every layer
+writes its own rows of it in place by index, so no layer is sliced out
+or written back. ``width`` is ``kv_rank + rope_dim`` rounded up to the
+128-lane tile (576 -> 640, the tail zeros): the chip lays an array
+whose minor dimension is no multiple of 128 out with the NEXT dimension
+minor, and the kernel's row blocks would then cost a cache-sized
+relayout a layer a token (AOT for v5e, PR 26).
+"""
+
+import math
+from typing import Any, NamedTuple, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from distributed_dot_product_tpu.models.decode import (
+    _take_layer, record_decode_impl,
+)
+from distributed_dot_product_tpu.models.dense import OwnedDense
+from distributed_dot_product_tpu.obs.spans import device_scope
+from distributed_dot_product_tpu.ops.pallas_attention import (
+    flash_attention,
+)
+from distributed_dot_product_tpu.ops.pallas_decode import (
+    decode_block_k, flash_decode,
+)
+from distributed_dot_product_tpu.ops.rope import (
+    rope_interleaved, yarn_inv_freq,
+)
+
+__all__ = ['LatentCache', 'init_latent_cache', 'insert_session',
+           'LatentAttention']
+
+LANES = 128
+HEAD_GROUP = 8
+
+
+class LatentCache(NamedTuple):
+    """``rows (L, B, t_max, width)``: layer ``l``'s compressed row of
+    session ``b``'s token ``t``; ``length (L, B) int32``: the rows each
+    layer holds of each session (a layer advances its own, as the slab
+    caches' layers do)."""
+    rows: jax.Array
+    length: jax.Array
+
+    @property
+    def t_max(self):
+        return self.rows.shape[-2]
+
+
+def init_latent_cache(layers, batch, t_max, row_dim, dtype=jnp.bfloat16):
+    width = -(-row_dim // LANES) * LANES
+    return LatentCache(
+        rows=jnp.zeros((layers, batch, t_max, width), dtype),
+        length=jnp.zeros((layers, batch), jnp.int32))
+
+
+def insert_session(cache: LatentCache, session, one: LatentCache):
+    """``cache`` with session ``session`` replaced by the single session
+    ``one`` holds (a prompt prefilled alone, then put in its slot of the
+    serving batch). Donate ``cache``: the update is in place."""
+    zero = jnp.zeros((), jnp.int32)
+    session = jnp.asarray(session, jnp.int32)
+    return LatentCache(
+        rows=lax.dynamic_update_slice(cache.rows, one.rows,
+                                      (zero, session, zero, zero)),
+        length=lax.dynamic_update_slice(cache.length, one.length,
+                                        (zero, session)))
+
+
+class LatentAttention(nn.Module):
+    """One MLA layer on ``x (B, T, dim)``. ``rope_scaling``: None, or
+    the YaRN settings as a tuple of ``(key, value)`` pairs (hashable, so
+    a model that carries them still keys the program caches):
+    ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow``, ``mscale``, ``mscale_all_dim``. The softmax scale is
+    ``(nope + rope)^-1/2 · m²`` with ``m = 0.1·mscale_all_dim·ln(factor)
+    + 1`` and the rotation's own magnitude is
+    ``yarn_mscale(mscale) / yarn_mscale(mscale_all_dim)`` (DeepSeek-V3's
+    reading; both 1 where the two are equal).
+
+    ``decode_impl``: ``'auto'`` (the kernel on a TPU where the cache's
+    ``t_max`` has a K split, else XLA), ``'kernel'``, ``'xla'``."""
+    dim: int
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float = 10000.0
+    rope_scaling: Any = None
+    norm_eps: float = 1e-6
+    dtype: Optional[jnp.dtype] = None
+    decode_impl: str = 'auto'
+
+    def setup(self):
+        h = self.num_heads
+        dense = dict(use_bias=False, dtype=self.dtype)
+        self.q_a = OwnedDense(self.q_rank, name='q_a', **dense)
+        self.q_norm = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                                 name='q_norm')
+        self.q_b = OwnedDense(h * (self.nope_dim + self.rope_dim),
+                              name='q_b', **dense)
+        self.kv_a = OwnedDense(self.kv_rank + self.rope_dim, name='kv_a',
+                               **dense)
+        self.kv_norm = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                                  name='kv_norm')
+        # Held as a plain (kv_rank, H, nope + v) array: decode folds its
+        # two halves into the query and out of the context.
+        self.kv_b = self.param(
+            'kv_b', nn.initializers.lecun_normal(in_axis=0,
+                                                 out_axis=(1, 2)),
+            (self.kv_rank, h, self.nope_dim + self.v_dim), jnp.float32)
+        self.out = OwnedDense(self.dim, name='out', **dense)
+
+    # -- static arithmetic ------------------------------------------------
+
+    @property
+    def row_dim(self):
+        return self.kv_rank + self.rope_dim
+
+    def _yarn(self):
+        return dict(self.rope_scaling or ())
+
+    def softmax_scale(self):
+        y = self._yarn()
+        m = 1.0
+        if y.get('factor', 1.0) > 1.0:
+            m = (0.1 * y.get('mscale_all_dim', 0.0)
+                 * math.log(y['factor']) + 1.0)
+        return m * m / math.sqrt(self.nope_dim + self.rope_dim)
+
+    def _rotate(self, x, positions):
+        y = self._yarn()
+        factor = y.get('factor', 1.0)
+        inv = yarn_inv_freq(
+            self.rope_dim, base=self.rope_theta, factor=factor,
+            original_max=y.get('original_max_position_embeddings', 4096),
+            beta_fast=y.get('beta_fast', 32), beta_slow=y.get('beta_slow', 1))
+
+        def mscale(s):
+            return 0.1 * s * math.log(factor) + 1.0 if factor > 1 else 1.0
+        mag = mscale(y.get('mscale', 1.0)) / mscale(
+            y.get('mscale_all_dim', 0.0))
+        out = rope_interleaved(x, positions, inv)
+        return out if mag == 1.0 else (out * mag).astype(x.dtype)
+
+    def make_cache(self, layers, batch, t_max, dtype=None):
+        return init_latent_cache(layers, batch, t_max, self.row_dim,
+                                 dtype or self.dtype or jnp.float32)
+
+    # -- the two halves every entry point shares --------------------------
+
+    def _queries(self, x, positions):
+        """``q_nope (B, H, T, nope)``, rotated ``q_rope (B, H, T, rope)``
+        for ``x (B, T, dim)`` at ``positions (B, T)``."""
+        b, t, _ = x.shape
+        q = self.q_b(self.q_norm(self.q_a(x)))
+        q = q.reshape(b, t, self.num_heads, self.nope_dim + self.rope_dim)
+        q = jnp.swapaxes(q, 1, 2)
+        q_rope = self._rotate(q[..., self.nope_dim:],
+                              positions[:, None, :])
+        return q[..., :self.nope_dim], q_rope
+
+    def _rows(self, x, positions, width=None):
+        """The tokens' cache rows ``(B, T, width)``: normalised latent,
+        rotated shared key, zeros to ``width``."""
+        ckv = self.kv_a(x)
+        c = self.kv_norm(ckv[..., :self.kv_rank])
+        k_rope = self._rotate(ckv[..., self.kv_rank:], positions)
+        rows = jnp.concatenate([c, k_rope.astype(c.dtype)], axis=-1)
+        pad = (width or self.row_dim) - self.row_dim
+        return jnp.pad(rows, ((0, 0), (0, 0), (0, pad))) if pad else rows
+
+    def _kv_b(self, dtype):
+        w = self.kv_b.astype(dtype)
+        return w[..., :self.nope_dim], w[..., self.nope_dim:]
+
+    def _expanded(self, q_nope, q_rope, rows, offset):
+        """Causal attention of the query rows (global positions
+        ``offset + i``) over the keys and values ``W_kvb`` expands
+        ``rows (B, S, >= row_dim)`` to, through the flash forward
+        kernel; returns ``(B, T, dim)``. The heads go through
+        ``HEAD_GROUP`` at a time, so the expanded keys and values that
+        exist at once are a group's (a 32k-row session's are 0.8 GB for
+        32 heads)."""
+        b, h, t, _ = q_nope.shape
+        g = HEAD_GROUP if h % HEAD_GROUP == 0 else h
+        wk, wv = self._kv_b(rows.dtype)
+        c = rows[..., :self.kv_rank]
+        s_len = rows.shape[1]
+        # K and q are built at the lane-tile width the flash kernel
+        # would pad them to anyway (192 -> 256, the tail zeros), so the
+        # expanded keys exist once, not unpadded and padded.
+        tail = -(self.nope_dim + self.rope_dim) % LANES
+        k_tail = jnp.concatenate(
+            [jnp.broadcast_to(rows[:, None, :, self.kv_rank:self.row_dim],
+                              (b, g, s_len, self.rope_dim)),
+             jnp.zeros((b, g, s_len, tail), rows.dtype)], axis=-1)
+        q = jnp.concatenate(
+            [q_nope, q_rope, jnp.zeros((b, h, t, tail), q_nope.dtype)],
+            axis=-1)
+
+        def group(args):
+            q_g, wk_g, wv_g = args
+            k_nope = jnp.einsum('bsc,chd->bhsd', c, wk_g,
+                                preferred_element_type=jnp.float32
+                                ).astype(rows.dtype)
+            v = jnp.einsum('bsc,chd->bhsd', c, wv_g,
+                           preferred_element_type=jnp.float32
+                           ).astype(rows.dtype)
+            return flash_attention(
+                q_g, jnp.concatenate([k_nope, k_tail], axis=-1), v,
+                causal=True, causal_offset=offset,
+                scale=self.softmax_scale())
+
+        def by_group(x, axis):
+            x = x.reshape(*x.shape[:axis], h // g, g, *x.shape[axis + 1:])
+            return jnp.moveaxis(x, axis, 0)
+        if g == h:
+            out = group((q, wk, wv))
+        else:
+            out = lax.map(group, (by_group(q, 1), by_group(wk, 1),
+                                  by_group(wv, 1)))
+            out = jnp.moveaxis(out, 0, 1).reshape(b, h, t, self.v_dim)
+        out = jnp.swapaxes(out, 1, 2).reshape(b, t, h * self.v_dim)
+        return self.out(out)
+
+    # -- entry points -------------------------------------------------------
+
+    def __call__(self, x):
+        with device_scope('lm.attn_proj'):
+            b, t, _ = x.shape
+            pos = jnp.broadcast_to(jnp.arange(t), (b, t))
+            q_nope, q_rope = self._queries(x, pos)
+            return self._expanded(q_nope, q_rope, self._rows(x, pos), 0)
+
+    def prefill(self, x, cache: LatentCache, layer):
+        """Append the chunk ``x (B, n, dim)`` to layer ``layer`` of the
+        stacked cache, in place, and attend it over the rows held. The
+        sessions of one call share a length (one causal offset a kernel
+        call), as the slab caches' do."""
+        with device_scope('lm.attn_proj'):
+            b, n, _ = x.shape
+            start = _take_layer(cache.length, layer)[0]
+            pos = jnp.broadcast_to(start + jnp.arange(n), (b, n))
+            q_nope, q_rope = self._queries(x, pos)
+            new = self._rows(x, pos, cache.rows.shape[-1])
+            zero = jnp.zeros((), jnp.int32)
+            layer = jnp.asarray(layer, jnp.int32)
+            rows = lax.dynamic_update_slice(
+                cache.rows, new.astype(cache.rows.dtype)[None],
+                (layer, zero, start, zero))
+            cache = LatentCache(
+                rows=rows, length=cache.length.at[layer].add(n))
+            out = self._expanded(q_nope, q_rope, _take_layer(rows, layer),
+                                 start)
+            return cache, out
+
+    def decode(self, x, cache: LatentCache, layer):
+        """One token a session, ``x (B, 1, dim)``: the absorbed form
+        over layer ``layer`` of the stacked cache, appended in place."""
+        with device_scope('lm.attn_proj'):
+            b = x.shape[0]
+            length = _take_layer(cache.length, layer)
+            q_nope, q_rope = self._queries(x, length[:, None])
+            new = self._rows(x, length[:, None], cache.rows.shape[-1])
+            wk, wv = self._kv_b(x.dtype)
+            q_lat = jnp.einsum('bhd,chd->bhc', q_nope[:, :, 0], wk,
+                               preferred_element_type=jnp.float32
+                               ).astype(x.dtype)
+            pad = cache.rows.shape[-1] - self.row_dim
+            q = jnp.concatenate(
+                [q_lat, q_rope[:, :, 0],
+                 jnp.zeros((b, self.num_heads, pad), x.dtype)], axis=-1)
+        impl = self._resolve(cache)
+        if impl == 'kernel':
+            shape = cache.rows.shape
+            ctx, rows, *_ = flash_decode(
+                q[:, :, None], new[:, None], None,
+                cache.rows.reshape(*shape[:2], 1, *shape[2:]), None,
+                length, length, layer=layer, latent_v=self.kv_rank,
+                scale=self.softmax_scale())
+            ctx, rows = ctx[:, :, 0], rows.reshape(shape)
+        else:
+            with device_scope('lm.attn_proj'):
+                rows = cache.rows.at[layer, jnp.arange(b), length].set(
+                    new[:, 0].astype(cache.rows.dtype), mode='drop')
+                held = _take_layer(rows, layer)
+                s = jnp.einsum('bhc,bsc->bhs', q, held,
+                               preferred_element_type=jnp.float32)
+                seen = jnp.arange(held.shape[1]) <= length[:, None, None]
+                p = jax.nn.softmax(
+                    jnp.where(seen, s * self.softmax_scale(), -jnp.inf),
+                    axis=-1)
+                ctx = jnp.einsum('bhs,bsc->bhc', p.astype(held.dtype),
+                                 held[..., :self.kv_rank],
+                                 preferred_element_type=jnp.float32
+                                 ).astype(x.dtype)
+        with device_scope('lm.attn_proj'):
+            out = jnp.einsum('bhc,chd->bhd', ctx.astype(x.dtype), wv,
+                             preferred_element_type=jnp.float32
+                             ).astype(x.dtype)
+            out = self.out(out.reshape(b, 1, self.num_heads * self.v_dim))
+            return LatentCache(rows=rows,
+                               length=cache.length.at[layer].add(1)), out
+
+    def _resolve(self, cache):
+        impl, reason = self.decode_impl, None
+        if impl not in ('auto', 'kernel', 'xla'):
+            raise ValueError(f"decode_impl must be 'auto', 'kernel' or "
+                             f"'xla', got {impl!r}")
+        split = decode_block_k(cache.t_max)
+        if impl == 'kernel' and split is None:
+            raise ValueError(f'the latent decode kernel has no K split '
+                             f'for t_max={cache.t_max}')
+        resolved = impl
+        if impl == 'auto':
+            resolved = 'kernel'
+            if split is None:
+                resolved, reason = 'xla', f'no K split for {cache.t_max}'
+            elif jax.default_backend() != 'tpu':
+                resolved = 'xla'
+                reason = f'backend is {jax.default_backend()}, not tpu'
+        record_decode_impl(impl, resolved, reason, 'stacked')
+        return resolved

@@ -103,12 +103,23 @@ class TransformerLM(nn.Module):
     remat: bool = False
     remat_policy: Optional[str] = None
     tie_embeddings: bool = True
+    # The block's composition and a leading run of dense layers —
+    # TransformerStack's fields of the same names (block_kwargs' 'norm'
+    # and 'norm_eps' also choose the final norm). With
+    # block_kwargs['residual'] == 'hyper' the embedding is widened to
+    # ``mult`` equal float32 streams before the stack and the streams
+    # are summed before the final norm.
+    block_kwargs: Any = None
+    dense_prefix: int = 0
+    prefix_kwargs: Any = None
 
     def _attn_kw(self):
         """The stack's attention kwargs with the LM defaults applied —
         plain field arithmetic (shared by ``setup`` and the
         outside-apply cache constructor)."""
         kw = dict(self.attn_kwargs or {})
+        if (self.block_kwargs or {}).get('mixer') == 'latent':
+            return kw                 # causal by construction, own RoPE
         if not kw.setdefault('causal', True):
             raise ValueError('TransformerLM is autoregressive: '
                              'causal=False makes no sense here')
@@ -123,14 +134,21 @@ class TransformerLM(nn.Module):
                     weight_quant=self.weight_quant,
                     attn_kwargs=self._attn_kw(),
                     scan_layers=self.scan_layers, remat=self.remat,
-                    remat_policy=self.remat_policy)
+                    remat_policy=self.remat_policy,
+                    block_kwargs=self.block_kwargs,
+                    dense_prefix=self.dense_prefix,
+                    prefix_kwargs=self.prefix_kwargs)
 
     def setup(self):
         self.embed = nn.Embed(self.vocab_size, self.dim,
                               dtype=self.dtype, name='embed')
         self.stack = TransformerStack(**self._stack_fields(),
                                       name='stack')
-        self.ln_f = nn.LayerNorm(dtype=self.dtype, name='ln_f')
+        kw = self.block_kwargs or {}
+        norm = (nn.RMSNorm if kw.get('norm') == 'rmsnorm'
+                else nn.LayerNorm)
+        self.ln_f = norm(epsilon=kw.get('norm_eps', 1e-6),
+                         dtype=self.dtype, name='ln_f')
         if not self.tie_embeddings:
             # An explicit (dim, vocab) kernel rather than nn.Dense: the
             # chunked loss below reads the table directly (a bound
@@ -147,13 +165,29 @@ class TransformerLM(nn.Module):
             return self.embed.embedding
         return self.lm_head_kernel.T
 
+    def _streams(self):
+        kw = self.block_kwargs or {}
+        if kw.get('residual') != 'hyper':
+            return None
+        return (kw.get('residual_kwargs') or {}).get('mult', 4)
+
     def _embed(self, tokens):
         with device_scope('lm.embed'):
-            return self.embed(tokens.astype(jnp.int32))
+            x = self.embed(tokens.astype(jnp.int32))
+            if self._streams():
+                x = jnp.broadcast_to(
+                    x.astype(jnp.float32)[..., None, :],
+                    x.shape[:-1] + (self._streams(), x.shape[-1]))
+            return x
+
+    def _collapse(self, x):
+        if self._streams():
+            x = jnp.sum(x, axis=-2).astype(self.dtype or x.dtype)
+        return x
 
     def _head(self, x):
         with device_scope('lm.head'):
-            x = self.ln_f(x)
+            x = self.ln_f(self._collapse(x))
             # logits = x · Eᵀ on the MXU, fp32 accumulation — requested
             # explicitly (preferred_element_type) so the contraction
             # accumulates in fp32 on EVERY backend, not just where it's
@@ -195,7 +229,7 @@ class TransformerLM(nn.Module):
             return self._nll(x, targets, chunk)
 
     def _nll(self, x, targets, chunk):
-        x = self.ln_f(x)
+        x = self.ln_f(self._collapse(x))
         table = self._head_table().astype(jnp.float32)
         tn = x.shape[-2]
         targets = targets.astype(jnp.int32)

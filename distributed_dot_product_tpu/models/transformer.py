@@ -39,6 +39,13 @@ from distributed_dot_product_tpu.models.attention import (
     DistributedDotProductAttn,
 )
 from distributed_dot_product_tpu.models.dense import OwnedDense
+from distributed_dot_product_tpu.models.hyper import (
+    HyperConnection, mix_back,
+)
+from distributed_dot_product_tpu.models.latent import (
+    LatentAttention, init_latent_cache,
+)
+from distributed_dot_product_tpu.models.moe import GatedMLP, SparseExperts
 from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
@@ -46,13 +53,29 @@ __all__ = ['TransformerBlock', 'TransformerStack']
 
 
 class TransformerBlock(nn.Module):
-    """Pre-LN block: ``x + Attn(LN(x))`` then ``x + MLP(LN(x))``.
+    """Pre-norm block: the mixer's branch, then the feed-forward's, each
+    around a residual.
 
-    ``attn_kwargs`` passes through to ``DistributedDotProductAttn``
-    (softmax_impl, num_kv_heads, use_rope, window, dropout_rate, ...);
-    the attention is self-attention in the module's K-first convention
-    (the same tensor feeds keys/queries/values, reference
-    example.py:31's usage)."""
+    The block is a composition of four choices; the defaults are the
+    block this file always built (``x + Attn(LN(x))`` then
+    ``x + MLP(LN(x))``, LayerNorm, GELU), with its parameter tree:
+
+    - ``norm``: ``'layernorm'`` | ``'rmsnorm'`` (``norm_eps``);
+    - ``mixer``: ``'attention'`` (``DistributedDotProductAttn``;
+      ``attn_kwargs`` passes through: softmax_impl, num_kv_heads,
+      use_rope, window, dropout_rate, ...; self-attention in the
+      module's K-first convention, the same tensor feeding
+      keys/queries/values, reference example.py:31's usage) |
+      ``'latent'`` (``models/latent.LatentAttention``; ``attn_kwargs``
+      are its ranks and head sizes, its cache one layer-stacked
+      ``LatentCache`` addressed by ``layer``);
+    - ``ffn``: ``'gelu'`` (``mlp_ratio`` x dim) | ``'gated'``
+      (``ffn_kwargs['hidden']``, SiLU-gated, no biases) | ``'experts'``
+      (``models/moe.SparseExperts(**ffn_kwargs)``);
+    - ``residual``: ``'add'`` | ``'hyper'`` (``models/hyper``: the input
+      is a widened stream ``(..., mult, dim)`` float32 and each branch
+      reads and writes it through its own ``HyperConnection(
+      **residual_kwargs)``)."""
     dim: int
     num_heads: int
     mlp_ratio: int = 4
@@ -66,50 +89,123 @@ class TransformerBlock(nn.Module):
     # quantizes the whole block.
     weight_quant: Optional[str] = None
     attn_kwargs: Any = None
+    norm: str = 'layernorm'
+    norm_eps: float = 1e-6
+    mixer: str = 'attention'
+    ffn: str = 'gelu'
+    ffn_kwargs: Any = None
+    residual: str = 'add'
+    residual_kwargs: Any = None
+
+    def _norm(self, name):
+        if self.norm == 'layernorm':
+            return nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                                name=name)
+        if self.norm == 'rmsnorm':
+            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
+                              name=name)
+        raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
+                         f'{self.norm!r}')
 
     def setup(self):
         kw = dict(self.attn_kwargs or {})
         kw.setdefault('dtype', self.dtype)
-        kw.setdefault('axis_name', self.axis_name)
-        kw.setdefault('weight_quant', self.weight_quant)
-        self.attn = DistributedDotProductAttn(
-            key_dim=self.dim, num_heads=self.num_heads, **kw)
-        self.ln1 = nn.LayerNorm(dtype=self.dtype, name='ln1')
-        self.ln2 = nn.LayerNorm(dtype=self.dtype, name='ln2')
-        # OwnedDense (explicit fp32 accumulation + the int8 weight
-        # path) — see models/dense.py; param tree matches nn.Dense.
-        self.mlp_in = OwnedDense(self.mlp_ratio * self.dim,
-                                 dtype=self.dtype, name='mlp_in',
-                                 weight_quant=self.weight_quant)
-        self.mlp_out = OwnedDense(self.dim, dtype=self.dtype,
-                                  name='mlp_out',
-                                  weight_quant=self.weight_quant)
+        if self.mixer == 'latent':
+            self.attn = LatentAttention(dim=self.dim,
+                                        num_heads=self.num_heads, **kw)
+        elif self.mixer == 'attention':
+            kw.setdefault('axis_name', self.axis_name)
+            kw.setdefault('weight_quant', self.weight_quant)
+            self.attn = DistributedDotProductAttn(
+                key_dim=self.dim, num_heads=self.num_heads, **kw)
+        else:
+            raise ValueError(f"mixer must be 'attention' or 'latent', "
+                             f'got {self.mixer!r}')
+        self.ln1 = self._norm('ln1')
+        self.ln2 = self._norm('ln2')
+        ffn_kw = dict(self.ffn_kwargs or {})
+        if self.ffn == 'gelu':
+            # OwnedDense (explicit fp32 accumulation + the int8 weight
+            # path) — see models/dense.py; param tree matches nn.Dense.
+            self.mlp_in = OwnedDense(self.mlp_ratio * self.dim,
+                                     dtype=self.dtype, name='mlp_in',
+                                     weight_quant=self.weight_quant)
+            self.mlp_out = OwnedDense(self.dim, dtype=self.dtype,
+                                      name='mlp_out',
+                                      weight_quant=self.weight_quant)
+        elif self.ffn == 'gated':
+            self.mlp = GatedMLP(dtype=self.dtype, name='mlp', **ffn_kw)
+        elif self.ffn == 'experts':
+            self.moe = SparseExperts(dtype=self.dtype, name='moe',
+                                     **ffn_kw)
+        else:
+            raise ValueError(f"ffn must be 'gelu', 'gated' or 'experts', "
+                             f'got {self.ffn!r}')
+        if self.residual == 'hyper':
+            hc_kw = dict(self.residual_kwargs or {})
+            self.hc_attn = HyperConnection(name='hc_attn', **hc_kw)
+            self.hc_ffn = HyperConnection(name='hc_ffn', **hc_kw)
+        elif self.residual != 'add':
+            raise ValueError(f"residual must be 'add' or 'hyper', got "
+                             f'{self.residual!r}')
+
+    def _around(self, which, x, branch):
+        """The residual around one branch: ``x + branch(x)``, or the
+        hyper-connection ``which`` ('attn' / 'ffn') mixing the stream
+        into the branch and its output back."""
+        if self.residual == 'add':
+            return x + branch(x)
+        u, h_post, h_res = getattr(self, f'hc_{which}')(x)
+        y = branch(u.astype(self.dtype or u.dtype))
+        return mix_back(x, y, h_post, h_res)
 
     def _mlp(self, x):
+        if self.ffn == 'experts':
+            return self.moe(self.ln2(x))[0]
         with device_scope('lm.mlp'):
+            if self.ffn == 'gated':
+                return self.mlp(self.ln2(x))
             return self.mlp_out(nn.gelu(self.mlp_in(self.ln2(x))))
 
     def __call__(self, x, attn_mask=None, segment_ids=None,
                  deterministic=False, dropout_seed=None):
-        h = self.ln1(x)
-        x = x + self.attn(h, h, h, attn_mask, segment_ids=segment_ids,
-                          deterministic=deterministic,
-                          dropout_seed=dropout_seed)
-        return x + self._mlp(x)
+        def mixer(u):
+            h = self.ln1(u)
+            if self.mixer == 'latent':
+                return self.attn(h)
+            return self.attn(h, h, h, attn_mask, segment_ids=segment_ids,
+                             deterministic=deterministic,
+                             dropout_seed=dropout_seed)
+        x = self._around('attn', x, mixer)
+        return self._around('ffn', x, self._mlp)
 
-    def prefill(self, x, cache):
-        h = self.ln1(x)
-        cache, a = self.attn.prefill(h, h, h, cache)
-        x = x + a
-        return cache, x + self._mlp(x)
+    def _cached(self, method, x, cache, layer):
+        """``prefill`` / ``decode`` share this: the mixer's cached entry
+        point inside the first residual, the feed-forward in the
+        second."""
+        held = [cache]
+
+        def mixer(u):
+            h = self.ln1(u)
+            step = getattr(self.attn, method)
+            if self.mixer == 'latent':
+                held[0], a = step(h, held[0], layer)
+            else:
+                held[0], a = step(h, h, h, held[0],
+                                  **({} if layer is None
+                                     else {'layer': layer}))
+            return a
+        x = self._around('attn', x, mixer)
+        return held[0], self._around('ffn', x, self._mlp)
+
+    def prefill(self, x, cache, layer=None):
+        # layer: a latent mixer's cache is layer-stacked in prefill too.
+        return self._cached('prefill', x, cache, layer)
 
     def decode(self, x, cache, layer=None):
         # layer: cache is a layer-stacked cache and this block is layer
         # ``layer`` of it (a scanned stack) — see attn.decode.
-        h = self.ln1(x)
-        cache, a = self.attn.decode(h, h, h, cache, layer=layer)
-        x = x + a
-        return cache, x + self._mlp(x)
+        return self._cached('decode', x, cache, layer)
 
 
 class _ScanStackCore(nn.Module):
@@ -131,13 +227,15 @@ class _ScanStackCore(nn.Module):
     dtype: Any
     weight_quant: Any
     attn_kwargs: Any
+    block_kwargs: Any = None
 
     def setup(self):
         self.block = TransformerBlock(
             dim=self.dim, num_heads=self.num_heads,
             mlp_ratio=self.mlp_ratio, axis_name=self.axis_name,
             dtype=self.dtype, weight_quant=self.weight_quant,
-            attn_kwargs=self.attn_kwargs, name='block')
+            attn_kwargs=self.attn_kwargs, name='block',
+            **(self.block_kwargs or {}))
 
     def layer(self, x, layer_idx, attn_mask, segment_ids, deterministic,
               dropout_seed):
@@ -204,6 +302,27 @@ class TransformerStack(nn.Module):
     scan_layers: bool = False
     remat: bool = False
     remat_policy: Optional[str] = None
+    # The block's choices (TransformerBlock's norm / mixer / ffn /
+    # residual fields, as a dict), and a stack of two layer kinds: the
+    # first ``dense_prefix`` blocks are built with ``prefix_kwargs``
+    # over ``block_kwargs`` (a model's leading dense layers before its
+    # expert layers). Such a stack, and any with a latent mixer or an
+    # expert feed-forward, runs unrolled (``scan_layers=False``).
+    block_kwargs: Any = None
+    dense_prefix: int = 0
+    prefix_kwargs: Any = None
+
+    def _block(self, name, **overrides):
+        return TransformerBlock(
+            dim=self.dim, num_heads=self.num_heads,
+            mlp_ratio=self.mlp_ratio, axis_name=self.axis_name,
+            dtype=self.dtype, weight_quant=self.weight_quant,
+            attn_kwargs=self.attn_kwargs, name=name,
+            **{**(self.block_kwargs or {}), **overrides})
+
+    @property
+    def _latent(self):
+        return (self.block_kwargs or {}).get('mixer') == 'latent'
 
     def setup(self):
         if self.remat and not self.scan_layers:
@@ -214,15 +333,22 @@ class TransformerStack(nn.Module):
             raise ValueError(
                 f'remat_policy {self.remat_policy!r} is not a '
                 f'jax.checkpoint_policies name')
+        kw = self.block_kwargs or {}
+        if self.scan_layers and (self.dense_prefix or self._latent
+                                 or kw.get('ffn') == 'experts'):
+            # XLA's grouped-matmul kernel takes an expert layer's
+            # weights whole, so nn.scan's slice of layer-stacked experts
+            # is a copy of them a layer a token (21.6 of a 36.6 ms step;
+            # chip, PR 26); a scan over two layer kinds would be two
+            # scans; the latent cache is carried from block to block.
+            raise ValueError("a dense prefix, mixer='latent' and "
+                             "ffn='experts' run unrolled: pass "
+                             'scan_layers=False')
         if not self.scan_layers:
             self.blocks = [
-                TransformerBlock(dim=self.dim, num_heads=self.num_heads,
-                                 mlp_ratio=self.mlp_ratio,
-                                 axis_name=self.axis_name,
-                                 dtype=self.dtype,
-                                 weight_quant=self.weight_quant,
-                                 attn_kwargs=self.attn_kwargs,
-                                 name=f'block_{i}')
+                self._block(f'block_{i}', **(
+                    (self.prefix_kwargs or {}) if i < self.dense_prefix
+                    else {}))
                 for i in range(self.n_layers)]
             return
         core = _ScanStackCore
@@ -247,7 +373,8 @@ class TransformerStack(nn.Module):
             })(dim=self.dim, num_heads=self.num_heads,
                mlp_ratio=self.mlp_ratio, axis_name=self.axis_name,
                dtype=self.dtype, weight_quant=self.weight_quant,
-               attn_kwargs=self.attn_kwargs, name='layers')
+               attn_kwargs=self.attn_kwargs,
+               block_kwargs=self.block_kwargs, name='layers')
 
     def __call__(self, keys, queries, values, attn_mask=None,
                  segment_ids=None, deterministic=False,
@@ -275,6 +402,13 @@ class TransformerStack(nn.Module):
         # scanned input, decode's loop carry); unrolled stacks a list.
         from distributed_dot_product_tpu.models.decode import init_cache
         kw = dict(self.attn_kwargs or {})
+        if self._latent:
+            # ONE layer-stacked buffer: every block addresses its own
+            # layer of it.
+            return init_latent_cache(
+                self.n_layers, batch, t_max,
+                kw['kv_rank'] + kw['rope_dim'],
+                dtype or kw.get('dtype') or self.dtype or jnp.float32)
         kv_heads = kw.get('num_kv_heads') or self.num_heads
         head_dim = self.dim // self.num_heads
         caches = [init_cache(batch, kv_heads, t_max, head_dim,
@@ -286,8 +420,18 @@ class TransformerStack(nn.Module):
             return jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
         return caches
 
+    def _latent_step(self, method, x, cache):
+        """Prefill or decode over a layer-stacked latent cache: every
+        block addresses its layer of the one buffer by number, and the
+        buffer is carried from block to block."""
+        for i, block in enumerate(self.blocks):
+            cache, x = getattr(block, method)(x, cache, layer=i)
+        return cache, x
+
     def prefill(self, x, caches):
         with device_scope('lm.stack_carry'):
+            if self._latent:
+                return self._latent_step('prefill', x, caches)
             if self.scan_layers:
                 x, caches = self.layers.prefill(x, caches)
                 return caches, x
@@ -299,6 +443,8 @@ class TransformerStack(nn.Module):
 
     def decode(self, x, caches):
         with device_scope('lm.stack_carry'):
+            if self._latent:
+                return self._latent_step('decode', x, caches)
             if self.scan_layers:
                 (x, caches), _ = self.layers.decode(
                     (x, caches), jnp.arange(self.n_layers,

@@ -81,12 +81,29 @@ DEVICE_SCOPES = {
     'ops.flash_bwd_dkv': 'the Pallas flash-attention dk/dv kernel',
     'ops.flash_decode': 'the fused Pallas decode step kernel (append + '
                         'attend, any number of new rows)',
+    'ops.mla_decode': 'the same kernel in its latent mode: one buffer of '
+                      'compressed rows appended and streamed once, all '
+                      'heads the rows of one score matmul, values the '
+                      'leading columns of the same block',
     'lm.attn_gather': 'the all-gather of the softmax-table side (queries, '
                       'values, segment ids) over the sequence axis',
     'lm.attn_proj': 'the attention module outside its kernels: the four '
                     'projections, RoPE / ALiBi preparation, head '
                     'reshapes, padding, cache append',
-    'lm.mlp': 'ln2, mlp_in, GELU and mlp_out of a block',
+    'lm.mlp': 'ln2, mlp_in, GELU and mlp_out of a block; the gated '
+              'SiLU MLP of a dense layer and the shared expert of an '
+              'expert layer',
+    'lm.moe_route': 'an expert layer around its experts: router matmul, '
+                    'sigmoid, top-k, gates, the sort by expert and the '
+                    'gather into it, the weighted combine back to token '
+                    'order, the per-expert token counts',
+    'lm.moe_experts': 'the routed experts\' grouped matmuls (gate, up, '
+                      'down over the rows sorted by expert) and their '
+                      'activation',
+    'lm.hc': 'a hyper-connection residual: the norm over the widened '
+             'stream, the three Phi products, sigmoid / Sinkhorn, the '
+             'pre-mix into the branch input and the post / residual '
+             'mix back into the stream',
     'lm.embed': 'the embedding gather (and its scatter-add backward)',
     'lm.head_loss': 'training: ln_f, the chunked head matmul and '
                     'logsumexp scan with its checkpointed body',

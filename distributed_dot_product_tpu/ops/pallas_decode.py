@@ -114,7 +114,8 @@ def _pad_rows(x, mult):
 
 
 def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
-                        quantized, has_alibi, paged=False, stacked=False):
+                        quantized, has_alibi, paged=False, stacked=False,
+                        latent_v=None):
     """Kernel body; refs are ordered to match ``flash_decode``'s spec
     list below. Grid = (B·H_kv, ns) with the K split innermost; the
     running softmax state lives in scratch across splits.
@@ -145,7 +146,13 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
     the softmax (their garbage scores would land below the causal fill
     and pollute the denominator). For a single pool the predicate is
     redundant with the fill check; for the sharded table it is the
-    whole shard-local page-range view."""
+    whole shard-local page-range view.
+
+    LATENT (``latent_v``): there is ONE buffer. The values are the first
+    ``latent_v`` columns of the very block the scores were taken from,
+    so the V refs (new rows, cache in, cache out) are absent and every
+    read of them below is a static lane slice of the K ones."""
+    latent = latent_v is not None
 
     def kernel_body(vt_ref, ap_ref, nn_ref, *refs, pt_ref=None):
         b = pl.program_id(0)
@@ -173,14 +180,15 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
         kn_ref = next(it)
         kqn_ref = next(it) if quantized else None
         ksn_ref = next(it) if quantized else None
-        vn_ref = next(it)
+        vn_ref = None if latent else next(it)
         k_ref = next(it)
         kq_ref = next(it) if quantized else None
         ks_ref = next(it) if quantized else None
-        v_ref = next(it)
+        v_ref = None if latent else next(it)
         alibi_ref = next(it) if has_alibi else None
-        (o_ref, m_ref, l_ref, ko_ref, vo_ref) = (
-            next(it), next(it), next(it), next(it), next(it))
+        o_ref, m_ref, l_ref, ko_ref = (
+            next(it), next(it), next(it), next(it))
+        vo_ref = None if latent else next(it)
         kqo_ref = next(it) if quantized else None
         kso_ref = next(it) if quantized else None
         m_s, l_s, acc_s = next(it), next(it), next(it)
@@ -252,13 +260,13 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
                 masked = jnp.logical_or(masked, rel <= -window)
             s = jnp.where(masked, -jnp.inf, s)
 
+            v = k_ref[0, :, :latent_v] if latent else v_ref[0]
             rows_v = (ki * bk
-                      + jax.lax.broadcasted_iota(
-                          jnp.int32, v_ref.shape[1:], 0))
-            v = v_ref[0]
+                      + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0))
             for m in range(n):
                 sel = jnp.logical_and(rows_v == ap + m, m < nn)
-                v = jnp.where(sel, vn_ref[0, m], v)
+                v = jnp.where(sel, (kn_ref[0, m:m + 1, :latent_v]
+                                    if latent else vn_ref[0, m]), v)
 
             m_prev = m_s[:]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -282,17 +290,20 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
             rows_k = (ki * bk
                       + jax.lax.broadcasted_iota(
                           jnp.int32, k_ref.shape[1:], 0))
-            rows_v = (ki * bk
-                      + jax.lax.broadcasted_iota(
-                          jnp.int32, v_ref.shape[1:], 0))
-            ko, vo = k_ref[0], v_ref[0]
+            ko = k_ref[0]
             for m in range(n):
                 ink = jnp.logical_and(rows_k == ap + m, m < nn)
-                inv = jnp.logical_and(rows_v == ap + m, m < nn)
                 ko = jnp.where(ink, kn_ref[0, m], ko)
-                vo = jnp.where(inv, vn_ref[0, m], vo)
             ko_ref[0] = ko
-            vo_ref[0] = vo
+            if not latent:
+                rows_v = (ki * bk
+                          + jax.lax.broadcasted_iota(
+                              jnp.int32, v_ref.shape[1:], 0))
+                vo = v_ref[0]
+                for m in range(n):
+                    inv = jnp.logical_and(rows_v == ap + m, m < nn)
+                    vo = jnp.where(inv, vn_ref[0, m], vo)
+                vo_ref[0] = vo
             if quantized:
                 cols_s = (ki * bk
                           + jax.lax.broadcasted_iota(
@@ -333,7 +344,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
                  *, n_new=None, page_table=None, layer=None, k_q=None,
                  k_scale=None, scale=None, window=None, alibi_slopes=None,
                  qk_quant=None, interpret=None, block_k=None,
-                 partials=False):
+                 partials=False, latent_v=None):
     """One fused decode step: in-place cache append + masked online-
     softmax attention of each slot's queries against its own prefix.
 
@@ -408,6 +419,17 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     per-layer slice, no write-back; every other layer keeps its bits.
     Not with ``page_table`` (no stack builds paged caches).
 
+    ``latent_v`` (static int): LATENT mode, for a cache that keeps ONE
+    row a token for all heads (multi-head latent attention's compressed
+    row, ``[c_kv ; k_rope]``): ``cache_v`` and ``v_new`` are None, the
+    one buffer ``cache_k (…, 1, t_max, d)`` is appended to and streamed
+    ONCE, all ``H`` query heads are the rows of one score matmul over
+    its ``d`` columns, and the values are the first ``latent_v`` columns
+    of the same resident block. ``out`` is ``(B, H, k, latent_v)``; the
+    returned ``cache_v`` is None. The Pallas program and its device
+    scope are named ``mla_decode`` / ``ops.mla_decode``. Not with
+    ``page_table`` or ``qk_quant``.
+
     Returns ``(out, cache_k, cache_v, k_q, k_scale)`` with
     ``out (B, H, k, dv)`` in ``cache_v.dtype`` — or, with
     ``partials=True``, ``((num, m, l), cache_k, cache_v, k_q, k_scale)``
@@ -418,9 +440,21 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     """
     b, h, n, d = q.shape
     h_kv = cache_k.shape[-3]
-    dv = cache_v.shape[-1]
+    latent = latent_v is not None
     paged = page_table is not None
     stacked = layer is not None
+    if latent:
+        if (cache_v is not None or v_new is not None or paged
+                or qk_quant is not None or h_kv != 1
+                or not 0 < latent_v <= d):
+            raise ValueError(
+                'flash_decode: latent_v reads the values from the one '
+                'buffer cache_k (…, 1, t_max, d >= latent_v): pass '
+                'cache_v=None and v_new=None, no page_table and no '
+                'qk_quant')
+        dv, v_dtype = latent_v, cache_k.dtype
+    else:
+        dv, v_dtype = cache_v.shape[-1], cache_v.dtype
     if stacked and paged:
         raise ValueError('flash_decode: layer addresses a layer-stacked '
                          'slab cache; a paged pool has no layer axis')
@@ -505,8 +539,9 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     # are zeros the kernel never substitutes (its loops stop at n).
     knf = _pad_rows(k_new.astype(cache_k.dtype).reshape(nb, n, d),
                     _sublane(cache_k.dtype))
-    vnf = _pad_rows(v_new.astype(cache_v.dtype).reshape(nb, n, dv),
-                    _sublane(cache_v.dtype))
+    vnf = None if latent else _pad_rows(
+        v_new.astype(cache_v.dtype).reshape(nb, n, dv),
+        _sublane(cache_v.dtype))
     if paged:
         # Pool flattening mirrors the slab's (B, H_kv) fold: pool page
         # p's head hh lives at flat row p·H_kv + hh, so one BlockSpec
@@ -530,7 +565,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         # A stacked buffer folds its layer axis into the rows too:
         # layer l's (slot, head) row r lives at flat row l·nb + r.
         kf = cache_k.reshape(-1, t_max, d)
-        vf = cache_v.reshape(-1, t_max, dv)
+        vf = None if latent else cache_v.reshape(-1, t_max, dv)
     valid_to = jnp.asarray(valid_to, jnp.int32)
     append_at = jnp.asarray(append_at, jnp.int32)
     # Per-slot appended-row count: callers without mixed batches get
@@ -628,8 +663,9 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         in_specs += [pl.BlockSpec((1,) + kni.shape[1:], const_idx),
                      pl.BlockSpec((1, 1, 1), const_idx)]
         args += [kni, kns.reshape(nb, 1, 1)]
-    in_specs.append(pl.BlockSpec((1,) + vnf.shape[1:], const_idx))
-    args.append(vnf)
+    if not latent:
+        in_specs.append(pl.BlockSpec((1,) + vnf.shape[1:], const_idx))
+        args.append(vnf)
     # The bf16 K buffer: streamed for scoring in the plain path; in the
     # quantized path scoring reads the mirror instead, so K is fetched
     # ONLY at its write block (one DMA per slot, to seed the append).
@@ -655,9 +691,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         args.append(kqf)
         ks_in_pos = len(args)
         args.append(ksf)
-    in_specs.append(pl.BlockSpec((1, bk, dv), stream_idx))
-    v_in_pos = len(args)
-    args.append(vf)
+    if not latent:
+        in_specs.append(pl.BlockSpec((1, bk, dv), stream_idx))
+        v_in_pos = len(args)
+        args.append(vf)
     has_alibi = alibi_slopes is not None
     if has_alibi:
         # Per-query-head slopes, pre-folded by log2e (the kernel's
@@ -676,14 +713,12 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         pl.BlockSpec((1, g_pad, 1), const_idx),    # m
         pl.BlockSpec((1, g_pad, 1), const_idx),    # l
         pl.BlockSpec((1, bk, d), write_idx),       # k (aliased)
-        pl.BlockSpec((1, bk, dv), write_idx),      # v (aliased)
     ]
     out_shape = [
         jax.ShapeDtypeStruct((nb, g_pad, dv), jnp.float32),
         jax.ShapeDtypeStruct((nb, g_pad, 1), jnp.float32),
         jax.ShapeDtypeStruct((nb, g_pad, 1), jnp.float32),
         jax.ShapeDtypeStruct(kf.shape, kf.dtype),
-        jax.ShapeDtypeStruct(vf.shape, vf.dtype),
     ]
     # +n_prefetch: alias indices count the scalar-prefetch operands
     # (valid_to, append_at, n_new, and — paged — the flattened page
@@ -694,7 +729,11 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     elif stacked:
         prefetch += ((jnp.asarray(layer, jnp.int32) * nb).reshape(1),)
     n_prefetch = len(prefetch)
-    aliases = {n_prefetch + k_in_pos: 3, n_prefetch + v_in_pos: 4}
+    aliases = {n_prefetch + k_in_pos: 3}
+    if not latent:
+        out_specs.append(pl.BlockSpec((1, bk, dv), write_idx))  # v (aliased)
+        out_shape.append(jax.ShapeDtypeStruct(vf.shape, vf.dtype))
+        aliases[n_prefetch + v_in_pos] = 4
     if quantized:
         out_specs += [pl.BlockSpec((1, bk, d), write_idx),
                       pl.BlockSpec((1, 1, bk), write_idx_row)]
@@ -705,8 +744,9 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
 
     kernel = _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
                                  quantized, has_alibi, paged=paged,
-                                 stacked=stacked)
-    with device_scope('ops.flash_decode'):
+                                 stacked=stacked, latent_v=latent_v)
+    name = 'mla_decode' if latent else 'flash_decode'
+    with device_scope(f'ops.{name}'):
         outs = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -720,15 +760,15 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
             out_shape=out_shape,
             input_output_aliases=aliases,
             interpret=interpret,
-            name='flash_decode')(*prefetch, *args)
+            name=name)(*prefetch, *args)
 
-    num, m, l, new_k, new_v = outs[:5]
+    num, m, l, new_k = outs[:4]
     new_kq = new_ks = None
     if quantized:
         new_kq = outs[5].reshape(k_q.shape)
         new_ks = outs[6].reshape(k_scale.shape)   # same flat order
     new_k = new_k.reshape(cache_k.shape)
-    new_v = new_v.reshape(cache_v.shape)
+    new_v = None if latent else outs[4].reshape(cache_v.shape)
 
     def head_shape(x):
         # Rows are new-row-major per kv head: undo the (n, group) fold
@@ -739,5 +779,5 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     num, m, l = head_shape(num), head_shape(m), head_shape(l)
     if partials:
         return (num, m, l), new_k, new_v, new_kq, new_ks
-    out = (num / jnp.where(l == 0.0, 1.0, l)).astype(cache_v.dtype)
+    out = (num / jnp.where(l == 0.0, 1.0, l)).astype(v_dtype)
     return out, new_k, new_v, new_kq, new_ks

@@ -23,7 +23,8 @@ from jax import lax
 
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
-__all__ = ['rope', 'rope_seq_parallel']
+__all__ = ['rope', 'rope_seq_parallel', 'yarn_inv_freq',
+           'rope_interleaved']
 
 
 def rope(x, positions=None, *, base=10000.0, offset=0, dtype=jnp.float32):
@@ -68,3 +69,44 @@ def rope_seq_parallel(x, *, axis_name=SEQ_AXIS, positions=None,
         tn = x.shape[-2]
         positions = lax.axis_index(axis_name) * tn + jnp.arange(tn)
     return rope(x, positions, base=base, dtype=dtype)
+
+
+def yarn_inv_freq(dim, *, base=10000.0, factor=1.0, original_max=4096,
+                  beta_fast=32.0, beta_slow=1.0):
+    """YaRN's blended inverse frequencies ``(dim/2,)`` (Peng et al.,
+    arXiv 2309.00071, as DeepSeek-V3's ``YarnRotaryEmbedding`` computes
+    them): pair ``i`` keeps its frequency ``base^(-2i/dim)`` where it
+    turns more than ``beta_fast`` times over ``original_max`` positions,
+    takes ``1/factor`` of it where it turns fewer than ``beta_slow``
+    times, and a linear ramp between. Plain numpy on static sizes."""
+    import math
+
+    import numpy as np
+    pairs = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / base ** pairs
+    inter = extra / factor
+
+    def correction(turns):
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                   0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_interleaved(x, positions, inv_freq, dtype=jnp.float32):
+    """Rotary embedding over INTERLEAVED pairs: features ``(2i, 2i+1)``
+    of ``x (..., T, d)`` turn by ``positions (..., T) * inv_freq[i]``
+    and stay where they were (the layout DeepSeek's checkpoints keep
+    their rotary dims in; dot products equal the half-split form's on
+    the same pairs)."""
+    d = x.shape[-1]
+    ang = (jnp.asarray(positions, dtype)[..., None]
+           * jnp.asarray(inv_freq, dtype))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    pairs = x.astype(dtype).reshape(*x.shape[:-1], d // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -79,7 +79,8 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     lines = [json.loads(line) for line in proc.stdout.splitlines()]
     assert lines[-1] == {'ok': False, 'device': lines[0]['device']}
     phases = {rec['phase']: rec for rec in lines if 'phase' in rec}
-    assert set(phases) == {'train', 'generate', 'serve'}
+    assert set(phases) == {'train', 'generate', 'generate_latent',
+                           'serve'}
     for name, rec in phases.items():
         assert 'error' not in rec, (name, rec.get('error'))
         failed = {k for k, v in rec['checks'].items() if not v}

@@ -28,6 +28,12 @@ TRAIN_SCOPES = ['ops.flash_fwd', 'ops.flash_bwd_dq', 'ops.flash_bwd_dkv',
                 'train.optimizer']
 DECODE_SCOPES = ['ops.flash_decode', 'lm.attn_proj', 'lm.mlp', 'lm.embed',
                  'lm.head', 'lm.stack_carry']
+# The decode step of the latent-attention / sparse-expert / hyper-
+# connection block (lm.mlp there: the dense layer's MLP and the shared
+# experts).
+LATENT_SCOPES = ['ops.mla_decode', 'lm.attn_proj', 'lm.mlp', 'lm.moe_route',
+                 'lm.moe_experts', 'lm.hc', 'lm.embed', 'lm.head',
+                 'lm.stack_carry']
 
 
 def tiny_lm(**attn_kwargs):
@@ -69,6 +75,29 @@ def decode_op_names():
     return op_names(jax.jit(step).lower(params, tokens, caches).compile())
 
 
+@pytest.fixture(scope='module')
+def latent_op_names():
+    model = TransformerLM(
+        vocab_size=64, dim=32, num_heads=2, n_layers=2,
+        tie_embeddings=False, scan_layers=False,
+        attn_kwargs=dict(q_rank=16, kv_rank=16, nope_dim=8, rope_dim=8,
+                         v_dim=8, decode_impl='kernel'),
+        block_kwargs=dict(norm='rmsnorm', mixer='latent', ffn='experts',
+                          ffn_kwargs=dict(n_experts=4, top_k=2, hidden=16),
+                          residual='hyper'),
+        dense_prefix=1,
+        prefix_kwargs=dict(ffn='gated', ffn_kwargs=dict(hidden=48)))
+    tokens = jnp.zeros((2, 1), jnp.int32)
+    params = {'params': model.init(
+        jax.random.key(0), jnp.zeros((2, 8), jnp.int32))['params']}
+    caches = model.make_decode_caches(2, 128)
+
+    def step(p, tok, c):
+        return model.apply(p, tok, c, method='decode')
+
+    return op_names(jax.jit(step).lower(params, tokens, caches).compile())
+
+
 def opened(scope, names):
     return any(f'/{scope}/' in f'/{name}/' for name in names)
 
@@ -83,8 +112,21 @@ def test_decode_step_opens(scope, decode_op_names):
     assert opened(scope, decode_op_names)
 
 
-def test_the_two_steps_cover_the_vocabulary():
-    assert set(TRAIN_SCOPES) | set(DECODE_SCOPES) == set(DEVICE_SCOPES)
+@pytest.mark.parametrize('scope', LATENT_SCOPES)
+def test_latent_decode_step_opens(scope, latent_op_names):
+    assert opened(scope, latent_op_names)
+
+
+def test_latent_kernel_is_outside_the_projection_scope(latent_op_names):
+    """The benchmark's accepted reader takes ``lm.attn_proj`` for the
+    projections alone: the latent kernel's scope is its sibling."""
+    kernel = [n for n in latent_op_names if '/ops.mla_decode/' in f'/{n}/']
+    assert kernel and not any('lm.attn_proj' in n for n in kernel)
+
+
+def test_the_steps_cover_the_vocabulary():
+    assert (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(LATENT_SCOPES)
+            == set(DEVICE_SCOPES))
 
 
 def test_unknown_scope_raises():
@@ -135,6 +177,19 @@ def test_decode_build_carries_its_kernel_name():
 
     assert kernel_names(step, new, new, new, cache, cache) == [
         'flash_decode']
+
+
+def test_latent_decode_build_carries_its_kernel_name():
+    cache = jnp.zeros((1, 1, 128, 128), jnp.float32)
+    q = jnp.zeros((1, 2, 1, 128), jnp.float32)
+    new = jnp.zeros((1, 1, 1, 128), jnp.float32)
+    at = jnp.zeros((1,), jnp.int32)
+
+    def step(q, k, ck):
+        return pallas_decode.flash_decode(q, k, None, ck, None, at, at,
+                                          latent_v=64, interpret=True)[0]
+
+    assert kernel_names(step, q, new, cache) == ['mla_decode']
 
 
 def test_every_kernel_name_has_its_scope():

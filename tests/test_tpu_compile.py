@@ -222,3 +222,63 @@ def test_scanned_lm_decode_step_moves_no_cache(chip, monkeypatch,
                       for x in jax.tree.leaves(caches))
     assert mem.alias_size_in_bytes >= cache_bytes    # tiles pad upward
     assert mem.temp_size_in_bytes < LAYER_K_BYTES
+
+
+def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
+        chip, monkeypatch):
+    """The token step of the latent-attention / sparse-expert model at
+    the published widths of ``xing4-29b-a4b.decode-32k`` (one dense and
+    two expert layers of its six; 16 sessions x 33792 rows), cache
+    donated: the step resolves to ``flash_decode``'s latent mode over
+    the layer-stacked cache, which aliases the result; nothing as large
+    as one layer of it is copied, sliced or held as a temporary; and no
+    layer's 64 experts are moved on their way into XLA's grouped-matmul
+    kernel (as a scanned layer's were, sliced out of the stack: 21.6 ms
+    of a 36.6 ms step; chip, PR 26)."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_latent as driver
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'xing4-29b-a4b-serve.json')) as f:
+        cfg = dict(json.load(f), num_hidden_layers=3)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-32k-x16.json')) as f:
+        traffic = json.load(f)
+    model = driver.build_lm(cfg)
+    params = {'params': {}}
+    for path, (shape, _) in driver.shapes(cfg).items():
+        node = params['params']
+        for name in path[:-1]:
+            node = node.setdefault(name, {})
+        node[path[-1]] = jax.ShapeDtypeStruct(
+            shape, jnp.float32 if path[-1] in driver.FLOAT32_LEAVES
+            else jnp.bfloat16)
+    sessions, t_max = traffic['sessions'], traffic['t_max']
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(sessions, t_max))
+    assert caches.rows.shape == (3, sessions, t_max, 640)
+    stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
+    tok = jnp.zeros((sessions, 1), jnp.int32)
+    step = driver.make_programs(model, cfg)[2]
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        (params, tok, caches, stats))
+    with decode_impl_traces() as traces:
+        compiled = step.lower(*shapes).compile()
+    assert {(t['resolved'], t['cache']) for t in traces} == {
+        ('kernel', 'stacked')}
+    hlo = compiled.as_text()
+    assert hlo.count('mla_decode') and 'ragged-dot' in hlo
+    layer_bytes = sessions * t_max * 640 * 2
+    experts_bytes = 64 * 3584 * 1024 * 2
+    assert _cache_sized_moves(hlo, min(layer_bytes, experts_bytes)) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 3 * layer_bytes
+    assert mem.temp_size_in_bytes < experts_bytes
