@@ -380,6 +380,8 @@ def generate_once(progs, tag, cfg, seed, params, lm=lm, **attn_kwargs):
         tokens = greedy_generate(model, params, prompt, steps,
                                  t_max=t_max)
     resolved = sorted({t['resolved'] for t in traces})
+    # What one grid step of the kernel holds (decode_geometry).
+    kernel_step = next((t['step'] for t in traces if t['step']), None)
     tokens = np.asarray(tokens)
     ours = forced_logits(progs, f'{tag}.auto', model, params, prompt,
                          tokens, t_max, pallas_step=True)
@@ -388,6 +390,7 @@ def generate_once(progs, tag, cfg, seed, params, lm=lm, **attn_kwargs):
     err, scale = max_err(ours[1:], theirs[1:])
     return {
         f'{tag}_resolved_impl': resolved,
+        f'{tag}_kernel_step': kernel_step,
         f'{tag}_logits_max_abs_err': err,
         f'{tag}_logits_max_abs': scale,
         f'{tag}_replay_argmax_agrees': int(np.sum(

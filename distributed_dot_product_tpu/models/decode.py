@@ -1803,12 +1803,16 @@ _IMPL_SINKS = []        # lists of the active decode_impl_traces() blocks
 def decode_impl_traces():
     """Collect what :func:`decode_step` resolves ``impl`` to while the
     block runs: one dict ``{'requested', 'resolved', 'reason',
-    'cache'}`` per TRACE (= per compiled step; ``reason`` names why
-    ``'auto'`` fell back to ``'xla'``, else None; ``cache`` is
+    'cache', 'step'}`` per TRACE (= per compiled step; ``reason`` names
+    why ``'auto'`` fell back to ``'xla'``, else None; ``cache`` is
     ``'stacked'`` where the step addressed a layer-stacked buffer by
     ``layer`` — a scanned stack's in-place loop — and ``'layer'`` where
     it was handed one layer's buffers, so a return to slicing the stack
-    per layer shows here). ``'auto'`` takes the XLA formulation
+    per layer shows here; ``step`` is the kernel's grid step,
+    ``{'heads', 'block_k', 'bytes'}`` — KV heads and cache rows of one
+    step and the cache bytes it streams, as
+    ``ops.pallas_decode.decode_geometry`` chose them from the call's
+    shapes — or None off the kernel). ``'auto'`` takes the XLA formulation
     off-TPU and wherever the kernel does not cover the call, so a smoke
     or benchmark run wraps the compile of its step in this and asserts
     the path the program holds instead of trusting it::
@@ -1825,8 +1829,26 @@ def decode_impl_traces():
         _IMPL_SINKS[:] = [s for s in _IMPL_SINKS if s is not sink]
 
 
+def _kernel_step(q, cache, qk_quant):
+    """The grid step the fused kernel takes for this call — the trace
+    field ``'step'`` — asked of the kernel's own
+    ``ops.pallas_decode.flash_decode_geometry`` with the operands
+    :func:`decode_step` hands ``flash_decode``."""
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        flash_decode_geometry,
+    )
+    if isinstance(cache, PagedDecodeCache):
+        geom = flash_decode_geometry(
+            q, cache.k_pool, cache.v_pool, page_table=cache.page_table,
+            qk_quant=qk_quant)
+    else:
+        geom = flash_decode_geometry(q, cache.k, cache.v,
+                                     qk_quant=qk_quant)
+    return geom.step()
+
+
 def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
-                         axis_name=None, stacked=False):
+                         axis_name=None, stacked=False, q=None):
     # Thread the mesh geometry into EVERY eligibility probe so the
     # explain string names every gate this resolver actually tests —
     # before this, a forced-kernel sharded verify-k passed the
@@ -1858,19 +1880,24 @@ def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
             reason = f'backend is {jax.default_backend()}, not tpu'
         else:
             resolved = 'kernel'
+    # The step is reported where the caller says what it scores with:
+    # decode_step does; a bare probe of the resolution has no queries.
+    step = None
+    if resolved == 'kernel' and q is not None and _IMPL_SINKS:
+        step = _kernel_step(q, cache, qk_quant)
     record_decode_impl(impl, resolved, reason,
-                       'stacked' if stacked else 'layer')
+                       'stacked' if stacked else 'layer', step)
     return resolved
 
 
-def record_decode_impl(requested, resolved, reason, cache):
+def record_decode_impl(requested, resolved, reason, cache, step=None):
     """Tell the open :func:`decode_impl_traces` blocks what one traced
     decode step resolved to (this module's, and the latent cache's in
     ``models/latent.py``)."""
     for sink in _IMPL_SINKS:
         sink.append({'requested': requested or 'auto',
                      'resolved': resolved, 'reason': reason,
-                     'cache': cache})
+                     'cache': cache, 'step': step})
 
 
 def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
@@ -1944,7 +1971,7 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
         cache = cache._replace(length=_take_layer(cache.length, layer))
     impl = _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
                                 axis_name=axis_name,
-                                stacked=stack is not None)
+                                stacked=stack is not None, q=q)
     per_slot = cache.length.ndim == 1
     if per_slot and axis_name is not None and not paged:
         raise ValueError(

@@ -52,7 +52,7 @@ from distributed_dot_product_tpu.ops.pallas_attention import (
     flash_attention,
 )
 from distributed_dot_product_tpu.ops.pallas_decode import (
-    decode_block_k, flash_decode,
+    flash_decode, flash_decode_geometry,
 )
 from distributed_dot_product_tpu.ops.rope import (
     rope_interleaved, yarn_inv_freq,
@@ -304,13 +304,15 @@ class LatentAttention(nn.Module):
             q = jnp.concatenate(
                 [q_lat, q_rope[:, :, 0],
                  jnp.zeros((b, self.num_heads, pad), x.dtype)], axis=-1)
-        impl = self._resolve(cache)
+        # The kernel's view: one KV "head", one query row a session.
+        shape = cache.rows.shape
+        q4 = q[:, :, None]
+        rows4 = cache.rows.reshape(*shape[:2], 1, *shape[2:])
+        impl = self._resolve(q4, rows4)
         if impl == 'kernel':
-            shape = cache.rows.shape
             ctx, rows, *_ = flash_decode(
-                q[:, :, None], new[:, None], None,
-                cache.rows.reshape(*shape[:2], 1, *shape[2:]), None,
-                length, length, layer=layer, latent_v=self.kv_rank,
+                q4, new[:, None], None, rows4, None, length, length,
+                layer=layer, latent_v=self.kv_rank,
                 scale=self.softmax_scale())
             ctx, rows = ctx[:, :, 0], rows.reshape(shape)
         else:
@@ -336,22 +338,27 @@ class LatentAttention(nn.Module):
             return LatentCache(rows=rows,
                                length=cache.length.at[layer].add(1)), out
 
-    def _resolve(self, cache):
+    def _resolve(self, q, rows):
+        """``decode_impl`` for the kernel operands ``q (B, H, 1, d)`` and
+        ``rows (L, B, 1, t_max, d)``, recorded for
+        ``decode_impl_traces()`` with the grid step the kernel takes."""
         impl, reason = self.decode_impl, None
         if impl not in ('auto', 'kernel', 'xla'):
             raise ValueError(f"decode_impl must be 'auto', 'kernel' or "
                              f"'xla', got {impl!r}")
-        split = decode_block_k(cache.t_max)
-        if impl == 'kernel' and split is None:
+        t_max = rows.shape[-2]
+        geom = flash_decode_geometry(q, rows, latent_v=self.kv_rank)
+        if impl == 'kernel' and geom is None:
             raise ValueError(f'the latent decode kernel has no K split '
-                             f'for t_max={cache.t_max}')
+                             f'for t_max={t_max}')
         resolved = impl
         if impl == 'auto':
             resolved = 'kernel'
-            if split is None:
-                resolved, reason = 'xla', f'no K split for {cache.t_max}'
+            if geom is None:
+                resolved, reason = 'xla', f'no K split for {t_max}'
             elif jax.default_backend() != 'tpu':
                 resolved = 'xla'
                 reason = f'backend is {jax.default_backend()}, not tpu'
-        record_decode_impl(impl, resolved, reason, 'stacked')
+        record_decode_impl(impl, resolved, reason, 'stacked',
+                           geom.step() if resolved == 'kernel' else None)
         return resolved

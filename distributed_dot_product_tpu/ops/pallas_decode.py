@@ -19,9 +19,12 @@ This kernel is ONE Pallas program per decode step that
 
 - **appends in place**: the K/V buffers (and the int8 mirror, when the
   cache carries one) are passed as aliased outputs
-  (``input_output_aliases``), and only the single block containing the
-  append row is ever written; unwritten blocks keep their bits by the
-  aliasing contract. A scanned stack's caches keep that property
+  (``input_output_aliases``), and only the sublane tile containing the
+  append row is ever written (16 rows of bf16 — its own output block,
+  filled from the resident K-split block at the step that holds it; a
+  verify-k step, a paged pool and the int8 mirror write back the whole
+  split or page); unwritten rows keep their bits by the aliasing
+  contract. A scanned stack's caches keep that property
   through the layer loop: the LAYER-STACKED buffers are the loop's
   carry, the step takes them whole with a ``layer`` index
   (scalar-prefetched, added to every cache block's row), and the whole
@@ -35,9 +38,24 @@ This kernel is ONE Pallas program per decode step that
   VMEM scratch (the flash-decoding work partition; on TPU the grid is
   sequential per core, so the split is what lets Pallas double-buffer
   the HBM→VMEM cache stream while the MXU works);
+- **takes several KV heads a grid step**: a step costs ~0.46 µs that
+  are not bytes, most of what streaming one head's 512 KB of K and V
+  takes, so
+  a step holds ``hb`` heads of one slot (consecutive flat rows of the
+  cache, sharing its lengths) — :func:`decode_geometry` picks ``hb``
+  and the split from the call's shapes against a VMEM plan, and
+  ``block_k`` stays small so that a slot's unfilled tail is skipped
+  at a fine grain;
+- **substitutes the new rows where they land**: the step's new rows
+  replace the cache's columns at ``append_at …`` in the one split (two,
+  when a verify-k step straddles a boundary) that holds them; every
+  other tile is scored as it lies in the cache, with no select over its
+  scores or its V block — the same sums in the same order whichever
+  tile the rows fall in, so a verify-k step stays bit-identical to its
+  sequential single-token steps;
 - **masks per slot**: the per-slot valid lengths arrive as a
   scalar-prefetch vector that both the kernel (causal/window masking,
-  the new row's score substitution) and the BlockSpec index maps read —
+  which split holds the new rows) and the BlockSpec index maps read —
   blocks past a slot's fill are never even DMA'd (the index map clamps
   to the last useful block, and Pallas skips re-fetching a resident
   block), so a half-empty serving batch streams half the bytes;
@@ -61,6 +79,7 @@ covers the identical code path.
 """
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -72,12 +91,23 @@ from distributed_dot_product_tpu.ops.pallas_attention import (
     _LOG2E, _NEG_BIG, _quantize_rows,
 )
 
-__all__ = ['flash_decode', 'decode_block_k']
+__all__ = ['flash_decode', 'decode_block_k', 'decode_geometry',
+           'flash_decode_geometry', 'DecodeGeometry']
 
-# K-split cap: 1024 rows/block keeps the double-buffered K+V stream
-# well inside VMEM at every head dim the repo uses (d=256 worst case:
-# 2·(1024·256·2 B)·2 buffers ≈ 4 MB of the ~16 MB budget).
+# K-split cap, in cache rows: the granularity at which a slot's unfilled
+# tail is skipped (never streamed). 1024 rows stream 7 % over a 12.4k
+# fill where 4096 would stream 23 % over, so a grid step grows by KV
+# HEADS (decode_geometry), not by coarser time blocks. A paged pool's
+# page is its K split and may not exceed it.
 _BLOCK_K_CAP = 1024
+# What one grid step should stream (K + V blocks of all its heads): a
+# step costs ~0.46 us of pipeline bookkeeping whatever it moves (chip,
+# PR 27), and 4 MB is ~5 us of HBM time on a v5e.
+_STEP_STREAM_BYTES = 4 << 20
+# VMEM the geometry may plan for (double-buffered streams and write-back
+# tiles, one head's temporaries): the v5e compiler's default scoped
+# limit is 16 MiB; stay a quarter under it.
+_VMEM_BUDGET = 12 << 20
 
 
 def decode_block_k(t_max, cap=_BLOCK_K_CAP):
@@ -94,6 +124,113 @@ def decode_block_k(t_max, cap=_BLOCK_K_CAP):
         if bk <= cap and t_max % bk == 0:
             return bk
     return None
+
+
+class DecodeGeometry(NamedTuple):
+    """One grid step of the decode kernel: ``heads`` KV heads of one
+    slot, ``block_k`` cache rows of each; ``write_rows`` rows of each
+    head's buffers written back for the append; ``bytes`` the cache
+    bytes the step streams."""
+    heads: int
+    block_k: int
+    write_rows: int
+    bytes: int
+
+    def step(self):
+        """What ``decode_impl_traces()`` reports of it."""
+        return {'heads': self.heads, 'block_k': self.block_k,
+                'bytes': self.bytes}
+
+
+def _lanes(x):
+    """Columns as VMEM (and tiled HBM) hold them: whole 128-lane tiles."""
+    return -(-x // 128) * 128
+
+
+def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
+                    n=1, quantized=False, page_size=None, block_k=None):
+    """The decode kernel's grid step for a call of these shapes, or None
+    where no K split divides ``t_max`` (the caller takes the XLA path).
+
+    ``rows`` is the query rows a KV head scores (``group · n``);
+    ``v_dtype=None`` is the latent cache (one buffer, its values a lane
+    slice of the streamed block); ``page_size`` a paged pool's page,
+    which IS the split; ``block_k`` the tests' override of the split.
+
+    The K split stays at :data:`_BLOCK_K_CAP` rows (skip granularity);
+    the step then takes the most KV heads ``hb | h_kv`` whose K + V
+    blocks stay within :data:`_STEP_STREAM_BYTES` and whose VMEM plan —
+    streams and write-back blocks double-buffered, the softmax state,
+    one head's temporaries — stays within :data:`_VMEM_BUDGET`
+    (``tests/test_tpu_compile.py`` compiles the plan's edges for a v5e).
+    A slot's heads are consecutive flat rows of the cache and share
+    its lengths, so one step's heads always belong to one slot.
+
+    The append is written back as the sublane tile(s) holding the new
+    row (16 rows of bf16) where a single row is appended to a slab; a
+    verify-k step (its rows may straddle tiles of ONE resident block), a
+    paged pool (its page is the block) and the int8 mirror (its scale
+    row vector tiles by lanes, not rows) write back the whole split."""
+    bk = page_size or block_k or decode_block_k(t_max)
+    if bk is None or t_max % bk:
+        return None
+    sub = max(_sublane(k_dtype),
+              _sublane(k_dtype if v_dtype is None else v_dtype))
+    wr = bk
+    if n == 1 and not quantized and page_size is None and bk % sub == 0:
+        wr = sub
+    # Bytes of one cache row (one head, one position) as a step streams
+    # it, and as its blocks lie in VMEM: quantized scoring streams the
+    # int8 mirror row and its f32 scale in place of the K row, which is
+    # then fetched at its write block alone.
+    k_row = _lanes(d) * jnp.dtype(k_dtype).itemsize
+    v_row = 0 if v_dtype is None else (_lanes(dv)
+                                       * jnp.dtype(v_dtype).itemsize)
+    stream_row = (_lanes(d) + 4 if quantized else k_row) + v_row
+    held_row = stream_row + (k_row if quantized else 0)
+    q_sub = _sublane(jnp.int8) if quantized else sub
+    g_pad = -(-rows // q_sub) * q_sub
+    # A head's q, new rows and (num, m, l) blocks, double-buffered, and
+    # its f32 softmax state: an upper bound, at 4 bytes an element.
+    small = 3 * (g_pad + sub) * (_lanes(d) + _lanes(dv) + 256) * 4
+    # One head's temporaries at a time (the body walks the step's
+    # heads): scores, probabilities and masks; the V block of the tile
+    # that takes the new rows, as a value beside its row index; the
+    # write-back block. Mosaic's own count (bisected vmem_limit_bytes
+    # over 19 extreme shapes, PERF.md section 6, PR 27) stays under it.
+    v_item = jnp.dtype(k_dtype if v_dtype is None else v_dtype).itemsize
+    temps = (8 * g_pad * bk * 4 + bk * _lanes(dv) * (v_item + 4)
+             + wr * held_row)
+
+    def vmem(hb):
+        # Streams and write-back blocks are double-buffered.
+        return hb * (2 * (bk + wr) * held_row + small) + temps
+
+    hb = max(c for c in range(1, h_kv + 1)
+             if h_kv % c == 0 and (c == 1 or (
+                 c * bk * stream_row <= _STEP_STREAM_BYTES
+                 and vmem(c) <= _VMEM_BUDGET)))
+    return DecodeGeometry(hb, bk, wr, hb * bk * stream_row)
+
+
+def flash_decode_geometry(q, cache_k, cache_v=None, *, page_table=None,
+                          qk_quant=None, block_k=None, latent_v=None):
+    """The grid step :func:`flash_decode` takes for these operands, of
+    which only shapes and dtypes are read (abstract values will do) —
+    or None where no K split divides the cache. ``flash_decode`` asks
+    this itself, so a caller that reports the step (the ``'step'`` of
+    ``models.decode.decode_impl_traces``) hands over what it hands the
+    kernel and cannot drift from it."""
+    h, n, d = q.shape[-3:]
+    h_kv, t_max, page = cache_k.shape[-3], cache_k.shape[-2], None
+    if page_table is not None:
+        page, t_max = t_max, page_table.shape[1] * t_max
+    latent = latent_v is not None
+    return decode_geometry(
+        t_max, h_kv, d, latent_v if latent else cache_v.shape[-1],
+        n * (h // h_kv), cache_k.dtype,
+        None if latent else cache_v.dtype, n=n,
+        quantized=qk_quant == 'int8', page_size=page, block_k=block_k)
 
 
 def _sublane(dtype):
@@ -113,12 +250,34 @@ def _pad_rows(x, mult):
     return jnp.pad(x, pad)
 
 
-def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
+def _write_tile(ki, ap, nn, geom, t_max):
+    """The write-back tile — in units of ``geom.write_rows`` cache rows —
+    that the aliased outputs sit on at K split ``ki`` of a slot
+    appending ``nn`` rows at column ``ap``. ONE definition for the OUT
+    BlockSpec index maps and the kernel body, which must agree exactly.
+    ``ap < 0`` ⇒ tile 0, a copy-through (Pallas writes every output
+    block back; an unwritten one would clobber the aliased cache with
+    garbage). The rows span at most TWO tiles, and two only where the
+    tile is the whole split (``decode_geometry``): clamping ki's first
+    tile into the span walks the write ref over each tile at the step
+    whose resident block holds it, right before Pallas flushes it."""
+    wr = geom.write_rows
+    tiles = t_max // wr
+    first = jnp.clip(ap // wr, 0, tiles - 1)
+    last = jnp.clip((ap + jnp.maximum(nn, 1) - 1) // wr, 0, tiles - 1)
+    return jnp.where(ap >= 0,
+                     jnp.clip(ki * (geom.block_k // wr), first, last), 0)
+
+
+def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                         quantized, has_alibi, paged=False, stacked=False,
                         latent_v=None):
     """Kernel body; refs are ordered to match ``flash_decode``'s spec
-    list below. Grid = (B·H_kv, ns) with the K split innermost; the
-    running softmax state lives in scratch across splits.
+    list below. Grid = (B·H_kv / hb, ns) with the K split innermost:
+    one step holds ``hb = geom.heads`` KV heads of ONE slot (every
+    block gains that leading axis; the body walks the heads, so its
+    temporaries stay one head's) and the running softmax state lives in
+    scratch across splits.
 
     VERIFY-k: ``n`` is the static number of new rows per step (1 =
     classic decode). The per-(b, h_kv) query block carries ``n · group``
@@ -130,8 +289,18 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
     vector ``nn`` carries the PER-SLOT number of rows actually appended
     (mixed spec/non-spec batches: a non-spec slot rides the same program
     with ``nn = 1``); rows ``m >= nn`` are never substituted into scores
-    or written back, and query rows past a slot's real count only ever
-    produce don't-care outputs the caller discards.
+    or written back,
+    and query rows past a slot's real count only ever produce
+    don't-care outputs the caller discards.
+
+    THE NEW ROWS ARE SUBSTITUTED WHERE THEY LAND. The scored tile of
+    the split(s) holding columns ``ap … ap + nn − 1`` takes the new
+    rows' scores and values at those columns (a select over the tile);
+    every other split runs the same body without the selects, which
+    would change nothing there. Either way a column's score enters the
+    online softmax with its tile, so the sums and their order do not
+    depend on the variant — nor on whether a row arrived in this step
+    or an earlier one (verify-k ≡ its sequential steps, bit for bit).
 
     The PAGED variant is the same body plus ONE extra predicate: grid
     step ``ki`` is the LOGICAL page ordinal, so every mask/score/append
@@ -139,7 +308,8 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
     index maps (which translate logical ordinal → pool page, clamping
     unallocated/−1 entries to the sink) live in ``flash_decode``, and
     the body additionally gates its scoring block on
-    ``pt_ref[slot·ns + ki] >= 0``: a −1 table entry means the slot does
+    ``pt_ref[slot·ns + ki] >= 0`` (and a new row's score on the entry
+    of the page it lands in): a −1 table entry means the slot does
     not hold that ordinal's page in THIS pool — beyond the fill on a
     single-pool cache, or owned by ANOTHER mesh shard on a sequence-
     sharded page table — and its sink-redirected bytes must not enter
@@ -153,26 +323,18 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
     so the V refs (new rows, cache in, cache out) are absent and every
     read of them below is a static lane slice of the K ones."""
     latent = latent_v is not None
+    hb, bk, wr = geom.heads, geom.block_k, geom.write_rows
+    per_slot = h_kv // hb                       # grid rows a slot
 
     def kernel_body(vt_ref, ap_ref, nn_ref, *refs, pt_ref=None):
         b = pl.program_id(0)
         ki = pl.program_id(1)
-        br = b // h_kv                          # cache batch row
+        br = b // per_slot                      # cache batch row
         vt = vt_ref[br]                         # first new row's column
         ap = ap_ref[br]                         # append column (−1 none)
-        nn = nn_ref[br]                         # rows appended (0..n)
-        # The block(s) the append write targets — must equal the k/v OUT
-        # BlockSpec index maps exactly (ap < 0 ⇒ a copy-through of
-        # block 0, because Pallas writes every output block back and an
-        # unwritten one would clobber the aliased cache with garbage).
-        # n rows span at most TWO consecutive blocks (n <= bk is
-        # enforced by flash_decode): the write index map clamps ki into
-        # [wfirst, wlast], so the kernel writes the ref exactly when ki
-        # lands on each physical block, right before Pallas flushes it.
-        wfirst = jnp.where(ap >= 0, jnp.clip(ap // bk, 0, ns - 1), 0)
-        wlast = jnp.where(
-            ap >= 0,
-            jnp.clip((ap + jnp.maximum(nn, 1) - 1) // bk, 0, ns - 1), 0)
+        appends = ap >= 0
+        nn = jnp.where(appends, nn_ref[br], 0)  # rows appended (0..n)
+        tile = _write_tile(ki, ap, nn, geom, ns * bk)
 
         it = iter(refs)
         q_ref = next(it)
@@ -193,11 +355,30 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
         kso_ref = next(it) if quantized else None
         m_s, l_s, acc_s = next(it), next(it), next(it)
 
+        # What scoring reads: the int8 mirror and its scales if any.
+        score_ref, score_new_ref = ((kq_ref, kqn_ref) if quantized
+                                    else (k_ref, kn_ref))
+
+        def scores(h, k_rows, k_scales=None):
+            """Head ``h``'s query rows against ``k_rows`` (int8 rows
+            and their scales when quantized): base-2 logits."""
+            s = jax.lax.dot_general(
+                q_ref[h], k_rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=(jnp.int32 if quantized
+                                        else jnp.float32))
+            if quantized:
+                s = s.astype(jnp.float32) * sqf_ref[h] * k_scales
+            return s
+
+        # Intra-step row index: row j·group + g is new row j's head g,
+        # so j = row // group (padded rows land past n — don't-care).
+        jrow = jax.lax.broadcasted_iota(jnp.int32, (g_pad, 1), 0) // group
+
         @pl.when(ki == 0)
         def _():
-            m_s[:] = jnp.full_like(m_s, _NEG_BIG)
-            l_s[:] = jnp.zeros_like(l_s)
-            acc_s[:] = jnp.zeros_like(acc_s)
+            m_s[...] = jnp.full_like(m_s, _NEG_BIG)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
 
         # Block-skip: no valid column in this split — strictly past the
         # LAST new row's fill (row n−1 attends up to vt + n − 1), or —
@@ -216,113 +397,96 @@ def _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
             # of the (num, m, l) partials reassembles exact full
             # attention.
             run = jnp.logical_and(run, pt_ref[br * ns + ki] >= 0)
+        # Does this split hold a column the step appends? (nn is 0
+        # where nothing is appended, so no split does.)
+        lands = jnp.logical_and(ki * bk < ap + nn, ap < ki * bk + bk)
 
-        @pl.when(run)
-        def _():
+        def score_split(substitute):
             cols = (ki * bk
                     + jax.lax.broadcasted_iota(jnp.int32, (g_pad, bk), 1))
-            # Intra-step row index: row j·group + g is new row j's head
-            # g, so j = row // group (padded rows land past n — fully
-            # masked below).
-            jrow = (jax.lax.broadcasted_iota(jnp.int32, (g_pad, bk), 0)
-                    // group)
-            if quantized:
-                # ks_ref blocks are (1, BK): the K-row scales already
-                # laid out as a row vector (the training kernels'
-                # convention — no in-kernel transpose/relayout).
-                s = jax.lax.dot_general(
-                    q_ref[0], kq_ref[0], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.int32).astype(jnp.float32)
-                s = s * sqf_ref[0] * ks_ref[0]
-                s_new = jax.lax.dot_general(
-                    q_ref[0], kqn_ref[0], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.int32).astype(jnp.float32)
-                s_new = s_new * sqf_ref[0] * ksn_ref[0, 0, 0]
-            else:
-                s = jax.lax.dot_general(
-                    q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                s_new = jax.lax.dot_general(
-                    q_ref[0], kn_ref[0], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            # The appended rows' scores replace whatever the buffer held
-            # at their columns (new row m lands at ap + m; the nn guard
-            # keeps rows a mixed-batch slot did NOT append from leaking
-            # in; ap == −1 matches no column: cols are ≥ 0 and nn is 0).
-            for m in range(n):
-                sel = jnp.logical_and(cols == ap + m, m < nn)
-                s = jnp.where(sel, s_new[:, m:m + 1], s)
             rel = cols - vt - jrow                # ≤ 0 on valid columns
-            if alibi_ref is not None:
-                s = s + alibi_ref[0] * rel.astype(jnp.float32)
             masked = rel > 0
             if window is not None:
                 masked = jnp.logical_or(masked, rel <= -window)
-            s = jnp.where(masked, -jnp.inf, s)
+            relf = rel.astype(jnp.float32) if has_alibi else None
+            for h in range(hb):
+                s = scores(h, score_ref[h],
+                           ks_ref[h] if quantized else None)
+                v = k_ref[h, :, :latent_v] if latent else v_ref[h]
+                if substitute:
+                    # New row m replaces whatever the buffer held at
+                    # column ap + m (the nn guard keeps rows a
+                    # mixed-batch slot did NOT append from leaking in).
+                    s_new = scores(h, score_new_ref[h],
+                                   ksn_ref[h, 0, 0] if quantized else None)
+                    rows_v = ki * bk + jax.lax.broadcasted_iota(
+                        jnp.int32, v.shape, 0)
+                    for m in range(n):
+                        s = jnp.where(
+                            jnp.logical_and(cols == ap + m, m < nn),
+                            s_new[:, m:m + 1], s)
+                        v = jnp.where(
+                            jnp.logical_and(rows_v == ap + m, m < nn),
+                            (kn_ref[h, m:m + 1, :latent_v] if latent
+                             else vn_ref[h, m:m + 1, :]), v)
+                if has_alibi:
+                    s = s + alibi_ref[h] * relf
+                s = jnp.where(masked, -jnp.inf, s)
+                m_prev = m_s[h]
+                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                p = jnp.exp2(s - m_new)
+                corr = jnp.exp2(m_prev - m_new)
+                m_s[h] = m_new
+                l_s[h] = l_s[h] * corr + p.sum(axis=-1, keepdims=True)
+                acc_s[h] = acc_s[h] * corr + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
-            v = k_ref[0, :, :latent_v] if latent else v_ref[0]
-            rows_v = (ki * bk
-                      + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0))
-            for m in range(n):
-                sel = jnp.logical_and(rows_v == ap + m, m < nn)
-                v = jnp.where(sel, (kn_ref[0, m:m + 1, :latent_v]
-                                    if latent else vn_ref[0, m]), v)
+        pl.when(jnp.logical_and(run, lands))(
+            lambda: score_split(True))
+        pl.when(jnp.logical_and(run, jnp.logical_not(lands)))(
+            lambda: score_split(False))
 
-            m_prev = m_s[:]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp2(s - m_new)
-            corr = jnp.exp2(m_prev - m_new)
-            m_s[:] = m_new
-            l_s[:] = l_s[:] * corr + p.sum(axis=-1, keepdims=True)
-            acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        # In-place append: substitute the new rows into the resident
-        # block(s) and write them back — the ONLY cache blocks written
-        # this step (every other aliased block keeps its bits
-        # untouched). With n > 1 the rows may straddle one block
-        # boundary; the write index map clamps ki into [wfirst, wlast],
-        # so writing at both gives each physical block its substituted
-        # content before Pallas flushes it.
-        @pl.when(jnp.logical_or(ki == wfirst, ki == wlast))
+        # In-place append: substitute the new rows into the tile of the
+        # resident block and write it back — the ONLY cache rows written
+        # this step (every other aliased row keeps its bits untouched).
+        @pl.when(ki == tile * wr // bk)
         def _():
-            rows_k = (ki * bk
-                      + jax.lax.broadcasted_iota(
-                          jnp.int32, k_ref.shape[1:], 0))
-            ko = k_ref[0]
-            for m in range(n):
-                ink = jnp.logical_and(rows_k == ap + m, m < nn)
-                ko = jnp.where(ink, kn_ref[0, m], ko)
-            ko_ref[0] = ko
-            if not latent:
-                rows_v = (ki * bk
-                          + jax.lax.broadcasted_iota(
-                              jnp.int32, v_ref.shape[1:], 0))
-                vo = v_ref[0]
+            def put(h, src_ref, new_ref, dst_ref):
+                if wr == bk:
+                    old = src_ref[h]
+                else:
+                    off = pl.multiple_of(tile * wr - ki * bk, wr)
+                    old = src_ref[h, pl.ds(off, wr), :]
+                rows = tile * wr + jax.lax.broadcasted_iota(
+                    jnp.int32, old.shape, 0)
                 for m in range(n):
-                    inv = jnp.logical_and(rows_v == ap + m, m < nn)
-                    vo = jnp.where(inv, vn_ref[0, m], vo)
-                vo_ref[0] = vo
-            if quantized:
-                cols_s = (ki * bk
-                          + jax.lax.broadcasted_iota(
-                              jnp.int32, ks_ref.shape[1:], 1))
-                kqo, kso = kq_ref[0], ks_ref[0]
-                for m in range(n):
-                    sel = jnp.logical_and(rows_k == ap + m, m < nn)
-                    kqo = jnp.where(sel, kqn_ref[0, m], kqo)
-                    kso = jnp.where(
-                        jnp.logical_and(cols_s == ap + m, m < nn),
-                        ksn_ref[0, 0, m], kso)
-                kqo_ref[0] = kqo
-                kso_ref[0] = kso
+                    hit = jnp.logical_and(rows == ap + m, m < nn)
+                    old = jnp.where(hit, new_ref[h, m:m + 1, :], old)
+                dst_ref[h] = old
+
+            # Head by head, like the scores: one head's tile in flight.
+            for h in range(hb):
+                put(h, k_ref, kn_ref, ko_ref)
+                if not latent:
+                    put(h, v_ref, vn_ref, vo_ref)
+                if quantized:
+                    put(h, kq_ref, kqn_ref, kqo_ref)
+                    # (1, bk) scale row vector: the appended row is a
+                    # lane, not a sublane.
+                    lanes = tile * wr + jax.lax.broadcasted_iota(
+                        jnp.int32, (1, wr), 1)
+                    kso = ks_ref[h]
+                    for m in range(n):
+                        hit = jnp.logical_and(lanes == ap + m, m < nn)
+                        kso = jnp.where(hit, ksn_ref[h, :, m:m + 1], kso)
+                    kso_ref[h] = kso
 
         @pl.when(ki == ns - 1)
         def _():
-            o_ref[0] = acc_s[:]
-            m_ref[0] = m_s[:]
-            l_ref[0] = l_s[:]
+            o_ref[...] = acc_s[...]
+            m_ref[...] = m_s[...]
+            l_ref[...] = l_s[...]
 
     if stacked:
         # The layer index only steers the BlockSpec index maps: the
@@ -361,8 +525,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     causal triangle among the new rows, each row with its own online-
     softmax state. ``k`` must not exceed the K split (the rows then
     span at most two blocks — both written in place, everything else
-    untouched); the int8 mirror stays single-token (``qk_quant='int8'``
-    requires ``k == 1`` — the XLA path covers quantized verify-k).
+    untouched; a single-token step on a slab writes back only the
+    sublane tile holding its row); the int8 mirror stays single-token
+    (``qk_quant='int8'`` requires ``k == 1`` — the XLA path covers
+    quantized verify-k).
     ``n_new (B,) int32`` (optional): per-slot count of rows ACTUALLY
     appended (mixed spec/non-spec batches — a slot with ``n_new = 1``
     rides the verify program as a classic decode step; rows past a
@@ -483,22 +649,26 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
             "qk_quant='int8' needs the cache's k_q/k_scale mirror — "
             "init_cache(qk_quant='int8') for the slab buffers, "
             "init_paged_cache(qk_quant='int8') for the mirror pools")
+    group = h // h_kv
     if paged:
-        n_pages, bk = cache_k.shape[0], cache_k.shape[2]
+        n_pages, page = cache_k.shape[0], cache_k.shape[2]
         ns = page_table.shape[1]            # logical pages per slot
-        t_max = ns * bk
-        if block_k not in (None, bk):
+        t_max = ns * page
+        if block_k not in (None, page):
             raise ValueError(f'paged decode splits K at the page size '
-                             f'{bk}; block_k={block_k} cannot differ')
+                             f'{page}; block_k={block_k} cannot differ')
     else:
         t_max = cache_k.shape[-2]
-        bk = block_k or decode_block_k(t_max)
-        if bk is None or t_max % bk:
-            raise ValueError(
-                f'no usable K split for t_max={t_max} (block_k must '
-                f'divide it); use the XLA decode path for this cache '
-                f'shape')
-        ns = t_max // bk
+    geom = flash_decode_geometry(
+        q, cache_k, cache_v, page_table=page_table, qk_quant=qk_quant,
+        block_k=block_k, latent_v=latent_v)
+    if geom is None:
+        raise ValueError(
+            f'no usable K split for t_max={t_max} (block_k must '
+            f'divide it); use the XLA decode path for this cache '
+            f'shape')
+    hb, bk, wr = geom.heads, geom.block_k, geom.write_rows
+    ns = t_max // bk
     if n > bk:
         raise ValueError(
             f'verify-k width {n} exceeds the K split {bk} '
@@ -508,8 +678,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     if interpret is None:
         interpret = jax.default_backend() != 'tpu'
     scale = 1.0 / math.sqrt(d) if scale is None else scale
-    group = h // h_kv
     nb = b * h_kv
+    per_slot = h_kv // hb                   # grid rows a slot
 
     # Query rows grouped per cache head, NEW-ROW-major (row j·group + g
     # = new row j, query head g — the layout the kernel's per-row
@@ -575,6 +745,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     else:
         nnv = jnp.asarray(n_new, jnp.int32)
 
+    # Every index map below speaks GRID rows: grid row bi holds heads
+    # bi·hb … bi·hb + hb − 1 of the flat (slot, head) rows — hb of one
+    # slot's, since hb | H_kv — and a block's leading axis is hb rows
+    # long, so bi is also the block index along it.
     def const_idx(bi, ki, *rs):
         return (bi, 0, 0)
 
@@ -583,19 +757,13 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         # attends up to vt + n − 1): beyond-fill splits alias the
         # resident block (skipped in-kernel), so a half-empty slot
         # streams half the bytes.
-        last = jnp.clip((vt[bi // h_kv] + (n - 1)) // bk, 0, ns - 1)
+        last = jnp.clip((vt[bi // per_slot] + (n - 1)) // bk, 0, ns - 1)
         return jnp.minimum(ki, last)
 
     def _write_blk(bi, ki, ap, nn):
-        # The k appended rows span blocks [first, last] (at most two,
-        # n <= bk); clamping ki into the span walks the write ref over
-        # each physical block exactly when the kernel body writes it.
-        br = bi // h_kv
-        a = ap[br]
-        first = jnp.clip(a // bk, 0, ns - 1)
-        last = jnp.clip((a + jnp.maximum(nn[br], 1) - 1) // bk,
-                        0, ns - 1)
-        return jnp.where(a >= 0, jnp.clip(ki, first, last), 0)
+        # In units of the write-back block's wr rows.
+        br = bi // per_slot
+        return _write_tile(ki, ap[br], nn[br], geom, t_max)
 
     if paged:
         # The tentpole redirect: the index map translates the LOGICAL
@@ -604,22 +772,23 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         # paging nearly free (same DMA skip, same aliasing).
         def stream_idx(bi, ki, vt, ap, nn, pt):
             blk = _stream_blk(bi, ki, vt)
-            pg = pt[(bi // h_kv) * ns + blk]
+            pg = pt[(bi // per_slot) * ns + blk]
             # −1 (ordinal not held by this pool) → the sink page; the
             # kernel's run-gate skips scoring it.
-            return (jnp.where(pg >= 0, pg, sink) * h_kv + bi % h_kv,
-                    0, 0)
+            return (jnp.where(pg >= 0, pg, sink) * per_slot
+                    + bi % per_slot, 0, 0)
 
         def write_idx(bi, ki, vt, ap, nn, pt):
             # Appending nothing → write-back lands on the sink page,
             # never on a page some other slot is appending into; same
             # for a −1 table entry (the table rides RAW — clamp here).
-            br = bi // h_kv
+            # The write tile is the page (decode_geometry).
+            br = bi // per_slot
             a = ap[br]
             blk = _write_blk(bi, ki, ap, nn)
             pg = pt[br * ns + blk]
             page = jnp.where(jnp.logical_and(a >= 0, pg >= 0), pg, sink)
-            return (page * h_kv + bi % h_kv, 0, 0)
+            return (page * per_slot + bi % per_slot, 0, 0)
 
         # Mirror-scale flat rows are (pages·H_kv, 1, page_size): one
         # K-split block per pool page, so the block index is always 0
@@ -629,10 +798,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         stream_idx_row = stream_idx
         write_idx_row = write_idx
     else:
-        # Stacked: the prefetched first row of the layer (layer · nb)
-        # redirects every cache row to its layer's run of nb rows
-        # (``lay`` is empty otherwise) — the same kind of redirect the
-        # page table does above.
+        # Stacked: the prefetched first grid row of the layer
+        # (layer · nb / hb) redirects every cache block to its layer's
+        # run of rows (``lay`` is empty otherwise) — the same kind of
+        # redirect the page table does above.
         def _row(bi, lay):
             return bi + lay[0][0] if lay else bi
 
@@ -652,24 +821,24 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         def write_idx_row(bi, ki, vt, ap, nn, *lay):
             return (_row(bi, lay), 0, _write_blk(bi, ki, ap, nn))
 
-    in_specs = [pl.BlockSpec((1, g_pad, d), const_idx)]
+    in_specs = [pl.BlockSpec((hb, g_pad, d), const_idx)]
     args = [qf]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, g_pad, 1), const_idx))
+        in_specs.append(pl.BlockSpec((hb, g_pad, 1), const_idx))
         args.append(sqf)
-    in_specs.append(pl.BlockSpec((1,) + knf.shape[1:], const_idx))
+    in_specs.append(pl.BlockSpec((hb,) + knf.shape[1:], const_idx))
     args.append(knf)
     if quantized:
-        in_specs += [pl.BlockSpec((1,) + kni.shape[1:], const_idx),
-                     pl.BlockSpec((1, 1, 1), const_idx)]
+        in_specs += [pl.BlockSpec((hb,) + kni.shape[1:], const_idx),
+                     pl.BlockSpec((hb, 1, 1), const_idx)]
         args += [kni, kns.reshape(nb, 1, 1)]
     if not latent:
-        in_specs.append(pl.BlockSpec((1,) + vnf.shape[1:], const_idx))
+        in_specs.append(pl.BlockSpec((hb,) + vnf.shape[1:], const_idx))
         args.append(vnf)
     # The bf16 K buffer: streamed for scoring in the plain path; in the
     # quantized path scoring reads the mirror instead, so K is fetched
     # ONLY at its write block (one DMA per slot, to seed the append).
-    in_specs.append(pl.BlockSpec((1, bk, d),
+    in_specs.append(pl.BlockSpec((hb, bk, d),
                                  write_idx if quantized else stream_idx))
     k_in_pos = len(args)
     args.append(kf)
@@ -685,14 +854,14 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         else:
             kqf = k_q.reshape(-1, t_max, d)
             ksf = k_scale.reshape(-1, 1, t_max)
-        in_specs += [pl.BlockSpec((1, bk, d), stream_idx),
-                     pl.BlockSpec((1, 1, bk), stream_idx_row)]
+        in_specs += [pl.BlockSpec((hb, bk, d), stream_idx),
+                     pl.BlockSpec((hb, 1, bk), stream_idx_row)]
         kq_in_pos = len(args)
         args.append(kqf)
         ks_in_pos = len(args)
         args.append(ksf)
     if not latent:
-        in_specs.append(pl.BlockSpec((1, bk, dv), stream_idx))
+        in_specs.append(pl.BlockSpec((hb, bk, dv), stream_idx))
         v_in_pos = len(args)
         args.append(vf)
     has_alibi = alibi_slopes is not None
@@ -705,14 +874,14 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
             h_kv, group, 1) * _LOG2E
         slopes = jnp.broadcast_to(slopes[None, :, None],
                                   (b, h_kv, n, group, 1))
-        in_specs.append(pl.BlockSpec((1, g_pad, 1), const_idx))
+        in_specs.append(pl.BlockSpec((hb, g_pad, 1), const_idx))
         args.append(_pad_rows(slopes.reshape(nb, n * group, 1), sub))
 
     out_specs = [
-        pl.BlockSpec((1, g_pad, dv), const_idx),   # num
-        pl.BlockSpec((1, g_pad, 1), const_idx),    # m
-        pl.BlockSpec((1, g_pad, 1), const_idx),    # l
-        pl.BlockSpec((1, bk, d), write_idx),       # k (aliased)
+        pl.BlockSpec((hb, g_pad, dv), const_idx),  # num
+        pl.BlockSpec((hb, g_pad, 1), const_idx),    # m
+        pl.BlockSpec((hb, g_pad, 1), const_idx),    # l
+        pl.BlockSpec((hb, wr, d), write_idx),      # k (aliased)
     ]
     out_shape = [
         jax.ShapeDtypeStruct((nb, g_pad, dv), jnp.float32),
@@ -727,22 +896,23 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     if paged:
         prefetch += (ptf,)
     elif stacked:
-        prefetch += ((jnp.asarray(layer, jnp.int32) * nb).reshape(1),)
+        prefetch += ((jnp.asarray(layer, jnp.int32)
+                      * (nb // hb)).reshape(1),)
     n_prefetch = len(prefetch)
     aliases = {n_prefetch + k_in_pos: 3}
     if not latent:
-        out_specs.append(pl.BlockSpec((1, bk, dv), write_idx))  # v (aliased)
+        out_specs.append(pl.BlockSpec((hb, wr, dv), write_idx))  # v (aliased)
         out_shape.append(jax.ShapeDtypeStruct(vf.shape, vf.dtype))
         aliases[n_prefetch + v_in_pos] = 4
     if quantized:
-        out_specs += [pl.BlockSpec((1, bk, d), write_idx),
-                      pl.BlockSpec((1, 1, bk), write_idx_row)]
+        out_specs += [pl.BlockSpec((hb, wr, d), write_idx),
+                      pl.BlockSpec((hb, 1, wr), write_idx_row)]
         out_shape += [jax.ShapeDtypeStruct(kqf.shape, kqf.dtype),
                       jax.ShapeDtypeStruct(ksf.shape, ksf.dtype)]
         aliases[n_prefetch + kq_in_pos] = 5
         aliases[n_prefetch + ks_in_pos] = 6
 
-    kernel = _make_decode_kernel(bk, ns, n, group, g_pad, h_kv, window,
+    kernel = _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                                  quantized, has_alibi, paged=paged,
                                  stacked=stacked, latent_v=latent_v)
     name = 'mla_decode' if latent else 'flash_decode'
@@ -751,12 +921,13 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=n_prefetch,
-                grid=(nb, ns),
+                grid=(nb // hb, ns),
                 in_specs=in_specs,
                 out_specs=out_specs,
-                scratch_shapes=[pltpu.VMEM((g_pad, 1), jnp.float32),
-                                pltpu.VMEM((g_pad, 1), jnp.float32),
-                                pltpu.VMEM((g_pad, dv), jnp.float32)]),
+                scratch_shapes=[pltpu.VMEM((hb, g_pad, 1), jnp.float32),
+                                pltpu.VMEM((hb, g_pad, 1), jnp.float32),
+                                pltpu.VMEM((hb, g_pad, dv),
+                                           jnp.float32)]),
             out_shape=out_shape,
             input_output_aliases=aliases,
             interpret=interpret,

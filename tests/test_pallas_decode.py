@@ -159,6 +159,25 @@ def test_kernel_alias_in_place_and_surgical():
                                       np.asarray(kn)[i, :, 0])
         np.testing.assert_array_equal(av[i, :, ln],
                                       np.asarray(vn)[i, :, 0])
+    # Several KV heads a grid step on a layer-stacked buffer: of the
+    # WHOLE stack only layer 1's append rows change their bits.
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        decode_geometry, flash_decode,
+    )
+    assert decode_geometry(T, 2, D, D, 1, jnp.float32,
+                           jnp.float32).heads == 2
+    stack_k = jnp.stack([before.k, before.k + 1.0, before.k + 2.0])
+    stack_v = jnp.stack([before.v, before.v - 1.0, before.v - 2.0])
+    ap = jnp.asarray([5, 9, -1], jnp.int32)      # slot 2 appends nothing
+    _, sk, sv, _, _ = flash_decode(q, kn, vn, stack_k, stack_v,
+                                   jnp.asarray(LENS, jnp.int32), ap,
+                                   layer=jnp.int32(1))
+    for got, old, new in ((sk, stack_k, kn), (sv, stack_v, vn)):
+        want = np.array(old)
+        for i, col in enumerate(np.asarray(ap)):
+            if col >= 0:
+                want[1, i, :, col] = np.asarray(new)[i, :, 0]
+        np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_kernel_not_stale_after_eviction():
@@ -370,3 +389,142 @@ def test_flash_decode_layer_argument_checks():
         flash_decode(q, kn, vn, kf[None], vf[None], vt, ap)
     with pytest.raises(ValueError, match='layer'):
         flash_decode(q, kn, vn, kf, vf, vt, ap, layer=0)
+
+
+# ---------------------------------------------------------------------------
+# The grid step's geometry: several KV heads a step, the new rows
+# substituted in the one split that holds them, a sublane-tile write-back
+# ---------------------------------------------------------------------------
+
+_GT, _GBK = 64, 16                  # four K splits of two f32 tiles each
+# The append row in the first block, straddling blocks 1 -> 2 (n = 3),
+# in the last block's second tile; slot 3 is frozen (append_at = -1).
+_GLENS = (3, 31, 60, 40)
+_GFROZEN = 3
+_GEOMETRIES = [(4, 4, 1), (4, 4, 2), (4, 4, 4),      # MHA
+               (4, 2, 1), (4, 2, 2),                 # GQA 4:2
+               (4, 1, 1)]                            # 4:1
+
+
+def _geometry_case(h, h_kv, n, stacked, key=30):
+    ks = jax.random.split(jax.random.key(key), 6)
+    b = len(_GLENS)
+    q = jax.random.normal(ks[0], (b, h, n, D), jnp.float32)
+    kn = jax.random.normal(ks[1], (b, h_kv, n, D), jnp.float32)
+    vn = jax.random.normal(ks[2], (b, h_kv, n, D), jnp.float32)
+    layers = []
+    for l in range(2 if stacked else 1):
+        kf = jax.random.normal(ks[3 + l], (b, h_kv, _GT, D), jnp.float32)
+        vf = jax.random.normal(ks[5], (b, h_kv, _GT, D), jnp.float32) + l
+        layers.append(append_kv_slots(
+            init_slot_cache(b, h_kv, _GT, D, dtype=jnp.float32), kf, vf,
+            counts=jnp.asarray(_GLENS, jnp.int32)))
+    cache = (jax.tree.map(lambda *xs: jnp.stack(xs), *layers) if stacked
+             else layers[0])
+    return q, kn, vn, cache
+
+
+@pytest.mark.parametrize('stacked', [False, True], ids=['layer', 'stack'])
+@pytest.mark.parametrize('n', [1, 3])
+@pytest.mark.parametrize('alibi', [False, True], ids=['plain', 'alibi'])
+@pytest.mark.parametrize('window', [None, 20], ids=['full', 'window'])
+@pytest.mark.parametrize('h,h_kv,hb', _GEOMETRIES)
+def test_kernel_matches_xla_at_every_geometry(monkeypatch, h, h_kv, hb,
+                                              window, alibi, n, stacked):
+    """The kernel against the XLA formulation at every grid step the
+    geometry function can return for these shapes (``hb`` KV heads a
+    step: the stream budget is lowered until it returns that ``hb``),
+    over four K splits, with the append in the first block, across a
+    block boundary (n = 3) and in the last block, one slot frozen
+    (``append_at = -1``), on one layer's buffers and on ``layer=`` of a
+    stack (the other layer keeps its bits)."""
+    from distributed_dot_product_tpu.ops import pallas_decode
+    one_head = pallas_decode.decode_geometry(
+        _GT, 1, D, D, n * h // h_kv, jnp.float32, jnp.float32, n=n,
+        block_k=_GBK).bytes
+    monkeypatch.setattr(pallas_decode, '_STEP_STREAM_BYTES',
+                        hb * one_head)
+    geom = pallas_decode.decode_geometry(
+        _GT, h_kv, D, D, n * h // h_kv, jnp.float32, jnp.float32, n=n,
+        block_k=_GBK)
+    assert (geom.heads, geom.block_k) == (hb, _GBK)
+    assert geom.write_rows == (8 if n == 1 else _GBK)
+    q, kn, vn, cache = _geometry_case(h, h_kv, n, stacked)
+    layer = 1 if stacked else None
+    kw = dict(window=window)
+    if alibi:
+        kw['alibi_slopes'] = tuple(2.0 ** -(i + 1) for i in range(h))
+    mask = jnp.arange(len(_GLENS)) != _GFROZEN
+    cx, ox = decode_step(q, cache, kn, vn, slot_mask=mask, impl='xla',
+                         layer=layer, **kw)
+    lens = jnp.asarray(_GLENS, jnp.int32)
+    vt = jnp.where(mask, lens, lens - n)
+    ap = jnp.where(mask, lens, -1)
+    ok, nk, nv, _, _ = pallas_decode.flash_decode(
+        q, kn, vn, cache.k, cache.v, vt, ap, block_k=_GBK,
+        layer=None if layer is None else jnp.int32(layer), **kw)
+    np.testing.assert_allclose(np.asarray(ok), np.asarray(ox),
+                               atol=2e-5, rtol=2e-5)
+    # The appended buffers bit for bit (the XLA step's are a scatter of
+    # the same rows), every other layer included.
+    np.testing.assert_array_equal(np.asarray(nk), np.asarray(cx.k))
+    np.testing.assert_array_equal(np.asarray(nv), np.asarray(cx.v))
+
+
+def test_decode_geometry_of_the_cells_and_its_budget():
+    """The geometry function alone: the two decode cells' shapes, the
+    divisor rule, the stream budget over head dims (Mosaic's verdict on
+    the VMEM plan is ``test_tpu_compile``'s), the XLA fallback."""
+    from distributed_dot_product_tpu.ops import pallas_decode as pd
+    bf16 = jnp.bfloat16
+    # mpt-7b.decode-12k: 32 KV heads of 128, one query row each.
+    cell = pd.decode_geometry(16384, 32, 128, 128, 1, bf16, bf16)
+    assert cell.heads >= 4 and cell.block_k == 1024
+    assert cell.write_rows == 16
+    assert cell.bytes == cell.heads * 1024 * 128 * 2 * 2
+    # xing4-29b-a4b.decode-32k: one latent row 640 wide, 32 query rows.
+    latent = pd.decode_geometry(33792, 1, 640, 512, 32, bf16, None)
+    assert (latent.heads, latent.block_k) == (1, 1024)
+    assert latent.bytes == 1024 * 640 * 2
+    for h_kv in (1, 2, 3, 8, 12, 32):
+        for d in (64, 96, 128, 256):
+            for rows in (1, 12):
+                g = pd.decode_geometry(32768, h_kv, d, d, rows, bf16, bf16)
+                assert h_kv % g.heads == 0
+                assert g.bytes <= pd._STEP_STREAM_BYTES
+    # StarCoder2's 2 KV heads cap the step at 2; verify-k, paged pools
+    # and the int8 mirror keep the whole split as their write-back.
+    assert pd.decode_geometry(16384, 2, 128, 128, 12, bf16, bf16).heads == 2
+    assert pd.decode_geometry(16384, 8, 128, 128, 4, bf16, bf16,
+                              n=4).write_rows == 1024
+    assert pd.decode_geometry(32768, 8, 96, 96, 1, bf16, bf16,
+                              page_size=256).write_rows == 256
+    assert pd.decode_geometry(32768, 8, 96, 96, 1, bf16, bf16,
+                              quantized=True).write_rows == 1024
+    # No usable split: the caller takes the XLA path, as before.
+    assert pd.decode_geometry(1027, 8, 128, 128, 1, bf16, bf16) is None
+    assert pd.decode_block_k(1027) is None
+
+
+def test_decode_impl_traces_carry_the_step():
+    """A kernel-resolved trace says what grid step the kernel takes —
+    the geometry function's answer for the call's shapes; an XLA one
+    carries None, and so does a bare probe of the resolution, which
+    has no queries to size a step from."""
+    from distributed_dot_product_tpu.models.decode import (
+        _resolve_decode_impl, decode_impl_traces,
+    )
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        decode_geometry,
+    )
+    q, kn, vn, kf, vf = _operands(4, 2, key=9)
+    with decode_impl_traces() as traces:
+        decode_step(q, _filled(2, kf, vf), kn, vn, impl='kernel')
+        decode_step(q, _filled(2, kf, vf), kn, vn, impl='xla')
+        _resolve_decode_impl('kernel', _filled(2, kf, vf), 1, None, None)
+    geom = decode_geometry(T, 2, D, D, 2, jnp.float32, jnp.float32)
+    assert [t['resolved'] for t in traces] == ['kernel', 'xla', 'kernel']
+    assert traces[0]['step'] == geom.step() == {
+        'heads': 2, 'block_k': T, 'bytes': geom.bytes}
+    assert traces[0]['step']['heads'] == 2
+    assert traces[1]['step'] is None and traces[2]['step'] is None

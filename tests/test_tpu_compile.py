@@ -97,11 +97,20 @@ def _cache(layout, h_kv, qk_quant):
     ('slab', 2, None, 1), ('slot', 2, None, 1),           # GQA 8/2
     ('paged', 2, None, 1), ('paged', 2, 'int8', 1),
     ('slot', 8, None, 4), ('paged', 8, None, 4),          # verify-k
+    ('mpt-7b.decode-12k', 32, None, 1),                   # the cells' own
+    ('xing4-29b-a4b.decode-32k', 1, None, 1),
 ])
 def test_decode_kernel_compiles_for_v5e(chip, layout, h_kv, qk_quant, n):
     """The fused decode step in every cache layout. n=1 is the form the
     chip's compiler refused before the new rows were padded to their
-    sublane tile (a dot against a one-row operand)."""
+    sublane tile (a dot against a one-row operand). The two decode
+    cells' own calls — the layer-stacked slab of ``mpt-7b.decode-12k``
+    (8 KV heads a grid step) and the latent rows of
+    ``xing4-29b-a4b.decode-32k`` — hold Mosaic's VMEM verdict on the
+    geometry ``decode_geometry`` chooses for them."""
+    if '.' in layout:
+        _compile_cell_kernel(chip, layout)
+        return
     cache = jax.eval_shape(lambda: _cache(layout, h_kv, qk_quant))
     q = jax.ShapeDtypeStruct((B, H, n, D), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((B, h_kv, n, D), jnp.bfloat16)
@@ -111,6 +120,106 @@ def test_decode_kernel_compiles_for_v5e(chip, layout, h_kv, qk_quant, n):
                            impl='kernel', interpret=False)
 
     _compile(chip, step, q, cache, kv, kv, donate=(1,))
+
+
+def _compile_cell_kernel(chip, cell):
+    """``flash_decode`` as a decode cell calls it, on its layer-stacked
+    cache at the benchmark's widths."""
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        decode_geometry, flash_decode,
+    )
+    bf16 = jnp.bfloat16
+    if cell == 'mpt-7b.decode-12k':
+        layers, b, h, t_max, d = 8, 2, 32, 16384, 128
+        assert decode_geometry(t_max, h, d, d, 1, bf16, bf16)[:3] == (
+            8, 1024, 16)
+        slopes = tuple(2.0 ** (-8.0 * (i + 1) / h) for i in range(h))
+        row = jax.ShapeDtypeStruct((b, h, 1, d), bf16)
+        buf = jax.ShapeDtypeStruct((layers, b, h, t_max, d), bf16)
+
+        def step(q, k_new, v_new, k, v, at, layer):
+            return flash_decode(q, k_new, v_new, k, v, at, at,
+                                layer=layer, alibi_slopes=slopes,
+                                interpret=False)
+
+        args, donate = (row, row, row, buf, buf), (3, 4)
+    else:
+        layers, b, h, t_max, d, dv = 6, 16, 32, 33792, 640, 512
+        assert decode_geometry(t_max, 1, d, dv, h, bf16, None)[:3] == (
+            1, 1024, 16)
+        q = jax.ShapeDtypeStruct((b, h, 1, d), bf16)
+        row = jax.ShapeDtypeStruct((b, 1, 1, d), bf16)
+        buf = jax.ShapeDtypeStruct((layers, b, 1, t_max, d), bf16)
+
+        def step(q, k_new, rows, at, layer):
+            return flash_decode(q, k_new, None, rows, None, at, at,
+                                layer=layer, latent_v=dv,
+                                interpret=False)
+
+        args, donate = (q, row, buf), (2,)
+    at = jax.ShapeDtypeStruct((b,), jnp.int32)
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
+    compiled = _compile(chip, step, *args, at, layer, donate=donate)
+    cache_bytes = sum(math.prod(x.shape) * 2 for x in args if x is buf)
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+
+
+# The edges of ``decode_geometry``'s VMEM plan: shapes at which it returns
+# the most heads a step for a wide head, a grouped one, the int8 mirror,
+# a verify-k step (whose write-back is the whole split) and page pools.
+# (name, h, h_kv, d, n, page, qk_quant, window) -> heads a step.
+_PLAN_EDGES = {
+    'mha-d256': ((8, 8, 256, 1, None, None, None), 4),
+    'starcoder2-gqa-window': ((24, 2, 128, 1, None, None, 4096), 2),
+    'int8-d128': ((32, 32, 128, 1, None, 'int8', None), 2),
+    'int8-d256': ((8, 8, 256, 1, None, 'int8', None), 1),
+    'verify8-gqa': ((32, 8, 128, 8, None, None, None), 4),
+    'verify4-d256': ((8, 8, 256, 4, None, None, None), 2),
+    'paged16': ((32, 32, 128, 1, 16, None, None), 32),
+    'paged256-verify4': ((32, 32, 128, 4, 256, None, None), 16),
+    'paged1024-d256': ((8, 8, 256, 1, 1024, None, None), 2),
+    'paged1024-int8': ((32, 32, 128, 1, 1024, 'int8', None), 2),
+}
+
+
+@pytest.mark.parametrize('edge', sorted(_PLAN_EDGES))
+def test_decode_kernel_compiles_at_the_plans_edges(chip, edge):
+    """Mosaic's verdict where ``decode_geometry`` packs the most into a
+    step: the plan is arithmetic, the compiler's scoped VMEM limit is
+    not, and a plan that is wrong is a compile error on the chip, not a
+    step of fewer heads."""
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        flash_decode, flash_decode_geometry,
+    )
+    (h, h_kv, d, n, page, qk_quant, window), heads = _PLAN_EDGES[edge]
+    b, t_max, bf16 = 2, 16384, jnp.bfloat16
+    lead = (b,) if page is None else (b * t_max // page + 1,)
+    rows = t_max if page is None else page
+    kv = jax.ShapeDtypeStruct((b, h_kv, n, d), bf16)
+    ops = {'q': jax.ShapeDtypeStruct((b, h, n, d), bf16),
+           'k_new': kv, 'v_new': kv,
+           'k': jax.ShapeDtypeStruct(lead + (h_kv, rows, d), bf16),
+           'v': jax.ShapeDtypeStruct(lead + (h_kv, rows, d), bf16),
+           'at': jax.ShapeDtypeStruct((b,), jnp.int32)}
+    if page is not None:
+        ops['page_table'] = jax.ShapeDtypeStruct((b, t_max // page),
+                                                 jnp.int32)
+    if qk_quant:
+        ops['k_q'] = jax.ShapeDtypeStruct(lead + (h_kv, rows, d), jnp.int8)
+        ops['k_scale'] = jax.ShapeDtypeStruct(lead + (h_kv, rows, 1),
+                                              jnp.float32)
+    assert flash_decode_geometry(
+        ops['q'], ops['k'], ops['v'], page_table=ops.get('page_table'),
+        qk_quant=qk_quant).heads == heads
+
+    def step(o):
+        return flash_decode(
+            o['q'], o['k_new'], o['v_new'], o['k'], o['v'], o['at'],
+            o['at'], page_table=o.get('page_table'), k_q=o.get('k_q'),
+            k_scale=o.get('k_scale'), qk_quant=qk_quant, window=window,
+            interpret=False)
+
+    _compile(chip, step, ops)
 
 
 # ---------------------------------------------------------------------------
