@@ -3,8 +3,8 @@
 """
 The quickest proof that the system still starts on the chip: drive the
 train → generate → serve path once, through the entry points a user
-calls, at the full width of the model the repo trains and times (the
-``lm_8l_16k`` row of ``bench.py``: vocab 32768, dim 768, 8 heads of 96,
+calls, at the full width of the model the repo has trained since its
+first round (vocab 32768, dim 768, 8 heads of 96,
 8 scanned + remat'd layers, bf16, flash causal attention), and check
 what comes out by the repo's own means. ``generate_latent`` repeats the
 generate checks on a small-depth model of the other block

@@ -87,7 +87,7 @@ RULES = {
         'runtime rule (analysis/retrace.py): a watched decode/serve '
         'entrypoint may not trace more often than its declared budget '
         '— automates the round-5 decode_seq_parallel retrace-storm '
-        'finding (ADVICE.md)'),
+        'finding'),
     # -- servelint: protocol / concurrency / determinism (PR 13) --------
     'event-vocab': (
         'protolint (analysis/protolint.py): a literal event kind at an '

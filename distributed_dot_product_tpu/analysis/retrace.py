@@ -6,9 +6,8 @@ entrypoints.
 The hazard class this automates: a jitted per-token step that silently
 re-traces every call. One concrete instance already happened here — an
 unhashable module field made ``decode_seq_parallel`` rebuild and
-re-trace its compiled step EVERY token (caught by hand in round 5, see
-ADVICE.md; the LRU step cache + warn-once in models/attention.py is the
-fix). Nothing mechanical guarded against the next instance: a retrace
+re-trace its compiled step EVERY token (caught by hand in round 5; the
+LRU step cache + warn-once in models/attention.py is the fix). Nothing mechanical guarded against the next instance: a retrace
 storm shows up only as mysterious slowness, because each trace produces
 a *correct* program.
 

@@ -801,8 +801,7 @@ def make_decode_step(module, mesh, mesh_axis=None, donate=True):
     the KV cache slab-sharded on its ``t_max`` axis over the mesh and —
     ``donate=True`` — DONATED to the jitted step, so the append's
     ``dynamic_update_slice`` writes the slab in place (without
-    donation each token copies the full K/V slabs first — the same ~1
-    ms/token copy `benchmark.py`'s local decode isolates). Reuse the
+    donation each token copies the full K/V slabs first). Reuse the
     returned step across tokens; rebuilding it per token would re-trace
     the whole module apply each time. The step routes through the fused
     decode path (``module.decode_impl``): on the kernel path each

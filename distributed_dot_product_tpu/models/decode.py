@@ -40,8 +40,7 @@ over the prompt — the training kernels ARE the prefill kernels).
 Performance note: jit your step with the cache DONATED
 (``jax.jit(step, donate_argnums=(<cache arg>,))``) so the append's
 ``dynamic_update_slice`` writes in place — without donation every token
-copies the whole K/V buffer pair first (~1 ms/token at T=131K, measured;
-RESULTS.md "KV-cache decode").
+copies the whole K/V buffer pair first.
 """
 
 import contextlib

@@ -17,12 +17,11 @@ lints clean at the serving dtype with zero waivers (ROADMAP item 3a,
 retired).
 
 Weight quantization (``weight_quant='int8'``): the serving-side win.
-Decode is bandwidth-bound (RESULTS.md: 474 GB/s floor), and at B·1
+Decode is bandwidth-bound (``PERF.md`` section 5), and at B·1
 query rows the projection weights are most of the bytes a step streams
 — storing them int8 halves that traffic and roughly doubles the
 parameters servable per 16 GiB chip. The treatment mirrors the int8 K
-mirror that fixed the s8 decode regression (RESULTS.md: 0.32 ms →
-beating bf16): weights are quantized ONCE at load/convert time
+mirror that fixed the s8 decode regression: weights are quantized ONCE at load/convert time
 (:func:`quantize_dense_params` — per OUTPUT channel symmetric scales,
 ``w ≈ w_i8 · s_col``), activations are quantized per row on the fly
 (the training kernels' ``_quantize_rows`` rule), and the dot runs

@@ -90,8 +90,8 @@ FEATURE_MATRIX = {
     'flash_softmax_mode=bounded': {
         'full': False,
         'online': False,
-        'flash': 'forward-only win; see RESULTS.md',
-        'ulysses': 'forward-only win; see RESULTS.md',
+        'flash': 'forward-only; not timed on this chip',
+        'ulysses': 'forward-only; not timed on this chip',
     },
     'offset': {
         'full': 'chunked-gather knob (reference semantics)',

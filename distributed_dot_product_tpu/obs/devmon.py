@@ -60,8 +60,8 @@ def _safe_memory_stats(device):
 
 def device_stats_snapshot(devices=None):
     """One-shot plain-dict view of every device's memory stats (None on
-    backends without them) — the form ``benchmark.py --metrics-out``
-    embeds in its JSON artifact."""
+    backends without them) — the form a flight bundle's device samples
+    take."""
     if devices is None:
         import jax
         devices = jax.devices()

@@ -12,8 +12,7 @@ traces on) and extracts:
 - XLA ``cost_analysis()``: FLOPs and bytes accessed — the compiler's own
   accounting of the program, independent of any timer floor.
 - ``memory_analysis()``: argument/output/temp/alias bytes, the exact
-  buffer-assignment footprint RESULTS.md's ``mem GiB`` column reports
-  for the timed programs.
+  buffer-assignment footprint of the program.
 - Compile wall time and HLO structure counts (collectives by kind,
   fusion count) — a fusion that splits or a collective that multiplies
   is a perf regression even when the numerics stay right.
@@ -44,8 +43,9 @@ active; ``report`` renders the roofline table. Refresh the committed
 baseline after an intentional program change with the ``snapshot``
 command above.
 
-``benchmark.py`` uses :func:`program_model` to stamp the same
-model-vs-measured columns onto every benchmark row.
+These are counts of toy-shape programs compiled for the CPU mesh: a
+change detector, never a speed. Speeds come from ``benchmarks/run.py``
+on the chip (``PERF.md``).
 """
 
 import dataclasses
@@ -163,8 +163,7 @@ def _hlo_counts(hlo_text):
 
 def program_model(compiled, *, measured_seconds=None, peaks=None):
     """Cost/roofline model of one compiled XLA program, as a plain JSON-
-    serializable dict — the per-row payload ``benchmark.py`` stamps next
-    to its measured numbers. ``peaks=None`` takes the roofline against
+    serializable dict — one entry of a snapshot. ``peaks=None`` takes the roofline against
     the LIVE device (:func:`device_peaks` — raises on a device kind
     with no published peaks, e.g. a CPU). Returns None for a program
     the compiler counts no work in.

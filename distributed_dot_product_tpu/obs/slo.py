@@ -207,7 +207,7 @@ def make_baseline(report: SloReport, *, tolerances=None, note=None):
         'schema': SLO_BASELINE_SCHEMA,
         '_refresh': note or (
             'Refresh IN THE SAME DIFF as an intentional serving/load '
-            'change: `python benchmark.py --mode serve-load '
+            'change: `python examples/serve_load.py '
             '--event-log /tmp/slo.jsonl` (the flag defaults ARE the '
             'CI smoke config) then `python -m '
             'distributed_dot_product_tpu.obs slo report /tmp/slo.jsonl '

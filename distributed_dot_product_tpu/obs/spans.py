@@ -191,8 +191,7 @@ class SpanCollector:
 
     def summary(self):
         """``{name: {'count', 'total_seconds', 'max_seconds'}}`` over the
-        buffered records — the compact form ``benchmark.py
-        --metrics-out`` serializes."""
+        buffered records."""
         out = {}
         for rec in self.records():
             agg = out.setdefault(rec.name, {'count': 0,

@@ -363,9 +363,8 @@ def _trap_tables(rel, nqb, nkb, bq, bk):
 
     Plain causal attention runs a full (nqb, nkb) grid where nearly half
     the programs are skipped by ``pl.when`` — but a skipped program still
-    pays its block DMA and grid sequencing (RESULTS.md measured that
-    overhead at 19× on the window path, which is why windows got a banded
-    grid). The trapezoid grid removes it for causal: the K axis
+    pays its block DMA and grid sequencing (which is why windows got
+    a banded grid). The trapezoid grid removes it for causal: the K axis
     flattens into ONE grid axis of exactly the valid (Q block, K block)
     pairs, ordered Q-major with K ascending, and scalar-prefetched SMEM
     tables map each program to its actual block indices. Out-of-triangle
@@ -450,8 +449,8 @@ def _trap_eligible(causal, window, mask, positions, causal_offset,
     one program, but their triangles differ) would make the pair count
     dynamic, which a grid size cannot be. Windows have their own banded
     grid; dense masks keep the full grid (their skip tables are indexed
-    by absolute blocks); 'bounded' keeps the full grid (its win case is
-    the forward-only sweep, see RESULTS.md)."""
+    by absolute blocks); 'bounded' keeps the full grid (its case is the
+    forward-only pass)."""
     import numpy as np
     static = (isinstance(causal_offset, (int, np.integer))
               and isinstance(kv_offset, (int, np.integer)))

@@ -1311,8 +1311,15 @@ class KernelEngine:
         vec_src[:needed] = src_pages
         vec_dst[:needed] = pages
         key = (src_cache.k_pool.shape, src_cache.v_pool.shape, width)
+        # The source pool lives on the PREFILL pool's mesh. Bring it to
+        # this cache's own placement first: fed as it is, the transfer's
+        # outputs follow it onto that mesh, and every fixed-shape
+        # program of this engine then traces again for a cache whose
+        # type names the mesh (the retrace sentinel's third trace).
+        home = self.cache.k_pool.sharding
         self.cache = self._transfer_program(key)(
-            self.cache, src_cache.k_pool, src_cache.v_pool,
+            self.cache, jax.device_put(src_cache.k_pool, home),
+            jax.device_put(src_cache.v_pool, home),
             jnp.asarray(vec_src), jnp.asarray(vec_dst))
         pid = self._register_pages(pages, length)
         if self.checksums is not None and src_checksums is not None:

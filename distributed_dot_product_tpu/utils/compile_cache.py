@@ -1,8 +1,8 @@
 # -*- coding: utf-8 -*-
 """
 Where JAX's persistent compilation cache lives — ONE rule for every
-harness (``chip_smoke.py``, ``bench.py``, ``benchmark.py``, the examples
-and ``tests/conftest.py``).
+harness (``chip_smoke.py``, ``benchmarks/run.py``, the examples and
+``tests/conftest.py``).
 
 The cache directory is part of the cache key, so a directory that moves
 never hits: it is either the one the environment names or one fixed

@@ -18,9 +18,9 @@ Differences, deliberate:
   without stats (CPU) it is reported as ``None``.
 - ``measure`` on a function *called inside jit/shard_map* times the trace,
   not the execution (the result is a tracer, which cannot be synced) — the
-  printed line is tagged ``traced`` in that case. For execution numbers use
-  :func:`time_fn` on the jitted callable, or ``jax.profiler.trace`` (see
-  ``benchmark.py --profile-dir``).
+  printed line is tagged ``traced`` in that case. Execution numbers come
+  from the benchmark (``benchmarks/run.py``; its ``--trace 1`` runs read
+  the profiler's own trace), on the chip.
 """
 
 import bisect
@@ -172,23 +172,6 @@ def log_step(step, loss, grad_norm=None, bad=False, seconds=None,
     print(' '.join(parts), flush=True)
 
 
-class timed:
-    """Context manager for honest block timing:
-
-    with timed() as t:
-        out = step(x)
-    print(t.seconds)
-    """
-
-    def __enter__(self):
-        self._start = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.seconds = time.perf_counter() - self._start
-        return False
-
-
 @jax.jit
 def _sync_probe(leaves):
     # One scalar depending on EVERY leaf, so a single host readback fences
@@ -211,10 +194,7 @@ def hard_sync(out):
     prints the measurement (PR 21: behind a 47 ms matmul chain it
     returned after 46.9 ms, this readback 1.1 ms later) — and it is far
     cheaper on a finished array: 1.6 µs against 0.9 ms for this probe
-    (a tiny cached jit plus a device-to-host copy). ``time_fn`` still
-    fences with the readback and subtracts its measured cost; moving it
-    to ``block_until_ready`` changes what is measured, which is the
-    benchmark PR's call, not a clean-up.
+    (a tiny cached jit plus a device-to-host copy).
     """
     leaves = [x for x in jax.tree.leaves(out)
               if getattr(x, 'size', 1)]  # drop zero-size leaves
@@ -222,50 +202,6 @@ def hard_sync(out):
         return  # nothing to sync on (fn returned None / empty pytree)
     import numpy as np
     np.asarray(_sync_probe(leaves))
-
-
-def time_fn(fn, *args, iters=5, warmup=2, inner=None, max_inner=512,
-            **kwargs):
-    """Honest wall-clock timing of ``fn(*args)``: returns
-    ``(best_seconds, mean_seconds)`` per call.
-
-    The reference's ``measure()`` never synchronized the device (reference
-    benchmark.py:56-67), so its GPU numbers are enqueue-biased. Here each
-    sample queues ``inner`` async dispatches (the device executes them
-    serially), hard-syncs once via a host readback, and subtracts the
-    separately-measured sync overhead. ``inner=None`` auto-scales so the
-    measured window dominates that overhead (~0.9 ms on the attached v5e,
-    PR 21's chip run) — without this, sub-millisecond ops disappear into
-    sync noise.
-    """
-    out = fn(*args, **kwargs)
-    hard_sync(out)
-    for _ in range(max(warmup - 1, 0)):
-        out = fn(*args, **kwargs)
-    hard_sync(out)
-    # Steady-state sync overhead on an already-materialized result.
-    overhead = min(_timed_sync(out) for _ in range(3))
-    if inner is None:
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
-        hard_sync(out)
-        est = max(time.perf_counter() - t0 - overhead, 1e-5)
-        inner = max(1, min(max_inner, int(8 * overhead / est)))
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        for _ in range(inner):
-            out = fn(*args, **kwargs)
-        hard_sync(out)
-        dt = time.perf_counter() - t0 - overhead
-        times.append(max(dt, 1e-9) / inner)
-    return min(times), sum(times) / len(times)
-
-
-def _timed_sync(out):
-    t0 = time.perf_counter()
-    hard_sync(out)
-    return time.perf_counter() - t0
 
 
 # ---------------------------------------------------------------------------
@@ -560,7 +496,3 @@ def get_registry() -> MetricsRegistry:
     """The process-default registry (the serving layer's default sink)."""
     return _DEFAULT_REGISTRY
 
-
-def metrics():
-    """Snapshot of the process-default registry."""
-    return _DEFAULT_REGISTRY.snapshot()

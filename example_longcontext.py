@@ -5,8 +5,8 @@ Long-context training demo — the beyond-parity flagship configuration.
 The reference example (example.py here, reference example.py) trains the
 parity module at T=4096 with a dense mask. This demo shows what the
 TPU-native stack adds on top: the fused flash path with in-kernel causal
-masking and no dense mask (memory linear in T — one 16 GiB v5e chip
-trains T=262,144; see RESULTS.md), plus checkpoint/resume.
+masking and no dense mask (memory linear in T), plus
+checkpoint/resume.
 
 Run (CPU simulation, 8 virtual devices):
 

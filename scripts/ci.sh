@@ -18,7 +18,7 @@ cd "$(dirname "$0")/.."
 
 rc=0
 
-echo '=== [1/13] ruff (generic hygiene) ==='
+echo '=== [1/12] ruff (generic hygiene) ==='
 if command -v ruff >/dev/null 2>&1; then
     ruff check . || rc=1
 elif python -c 'import ruff' >/dev/null 2>&1; then
@@ -27,7 +27,7 @@ else
     echo 'ruff not installed in this image — skipping (graphlint still runs)'
 fi
 
-echo '=== [2/13] graphlint + servelint + flowlint (jaxpr/domain/serving contracts) ==='
+echo '=== [2/12] graphlint + servelint + flowlint (jaxpr/domain/serving contracts) ==='
 # Full pass: jaxpr rules over every registered entrypoint (incl. the
 # bf16 serving-dtype and int8-weight twins — the owned dense retired
 # the flax-Dense f32-accum waivers, so zero allowed records remain)
@@ -42,7 +42,7 @@ echo '=== [2/13] graphlint + servelint + flowlint (jaxpr/domain/serving contract
 #   python -m distributed_dot_product_tpu.analysis --changed-only origin/main
 JAX_PLATFORMS=cpu python -m distributed_dot_product_tpu.analysis || rc=1
 
-echo '=== [3/13] tier-1 tests ==='
+echo '=== [3/12] tier-1 tests ==='
 if [ "${SKIP_TESTS:-0}" = "1" ]; then
     echo 'SKIP_TESTS=1 — skipping pytest stage'
 else
@@ -50,7 +50,7 @@ else
         --continue-on-collection-errors -p no:cacheprovider || rc=1
 fi
 
-echo '=== [4/13] smoke serve + event-log schema validation ==='
+echo '=== [4/12] smoke serve + event-log schema validation ==='
 # Drives the real serving process through the fault cocktail and then
 # schema-validates + timeline-reconstructs its JSONL event log (the
 # obs validate CLI runs inside smoke_serve.sh over the run's log).
@@ -60,7 +60,7 @@ else
     scripts/smoke_serve.sh 12 4 || rc=1
 fi
 
-echo '=== [5/13] spec-decode bit-identity smoke (DDP_TPU_SPEC=ngram) ==='
+echo '=== [5/12] spec-decode bit-identity smoke (DDP_TPU_SPEC=ngram) ==='
 # Speculative decoding's exactness guarantee, proven on a real burst
 # through the ENV knob a deployment would flip: the same traffic served
 # with the n-gram proposer (verify-k steps) and without (plain n=1
@@ -118,16 +118,17 @@ print(f'spec smoke OK: {len(base)} streams bit-identical, '
 PY
 fi
 
-echo '=== [6/13] serve-load smoke + SLO goodput gate ==='
+echo '=== [6/12] serve-load smoke + SLO goodput gate ==='
 # A seeded open-loop trace (virtual clock — minutes of simulated
 # traffic in seconds of wall time, CPU-deterministic) drives the
 # scheduler, then the goodput report computed FROM THE EVENT LOG ALONE
 # is gated against the committed SLO_BASELINE.json (generous
-# tolerances; every violation names the metric and tenant). The
-# benchmark's serve-load flag DEFAULTS are the smoke config — on an
+# tolerances; every violation names the metric and tenant). Goodput
+# here is virtual-time: a behaviour check, never a speed. The load
+# driver's constants and flag DEFAULTS are the smoke config — on an
 # intentional serving/load change refresh the baseline in the same
 # diff:
-#   python benchmark.py --mode serve-load --event-log /tmp/slo.jsonl
+#   python examples/serve_load.py --event-log /tmp/slo.jsonl
 #   python -m distributed_dot_product_tpu.obs slo report /tmp/slo.jsonl \
 #       --spec SLO_BASELINE.json --baseline-out SLO_BASELINE.json
 if [ "${SKIP_TESTS:-0}" = "1" ]; then
@@ -135,15 +136,15 @@ if [ "${SKIP_TESTS:-0}" = "1" ]; then
 else
     slo_log="$(mktemp -u /tmp/ddp_slo_smoke.XXXXXX).jsonl"
     slo_row="$(mktemp /tmp/ddp_slo_row.XXXXXX.json)"
-    rm -f "$slo_row"    # benchmark.py appends into a fresh JSON file
-    { JAX_PLATFORMS=cpu python benchmark.py --mode serve-load \
+    rm -f "$slo_row"    # the driver appends into a fresh JSON file
+    { JAX_PLATFORMS=cpu python examples/serve_load.py \
           --event-log "$slo_log" --file "$slo_row" \
       && JAX_PLATFORMS=cpu python -m distributed_dot_product_tpu.obs \
           slo check "$slo_log" --against SLO_BASELINE.json; } || rc=1
     rm -f "$slo_log" "$slo_row"
 fi
 
-echo '=== [7/13] disaggregated-serving smoke (router + 2 decode pools) ==='
+echo '=== [7/12] disaggregated-serving smoke (router + 2 decode pools) ==='
 # The 1-router/2-pool cocktail on the CPU mesh: the seeded trace through
 # the disaggregated topology AND its single-process twin, member logs
 # schema-validated (--require router.route / prefill.handoff), goodput
@@ -155,7 +156,7 @@ else
     scripts/smoke_router.sh || rc=1
 fi
 
-echo '=== [8/13] perf gate (compiled-program cost vs committed baseline) ==='
+echo '=== [8/12] perf gate (compiled-program cost vs committed baseline) ==='
 # Compiles every registered entrypoint hermetically (8-dev CPU mesh),
 # snapshots XLA cost/memory/compile-time/retrace accounting, and gates
 # it against the committed PERF_BASELINE.json (tolerances sized for
@@ -173,44 +174,7 @@ else
     rm -f "$perf_now"
 fi
 
-echo '=== [9/13] weight-quant decode smoke (kv+weight bytes below the bf16 twin) ==='
-# The low-precision acceptance row: the SAME decode shape at bf16 and
-# at int8 weights + int8 K mirror — the quantized row must move fewer
-# kv+weight bytes per step AND be kernel-eligible on the paged pool
-# (decode_kernel_eligible(paged, qk_quant='int8') == True, i.e. the
-# mirror pools ride the fused kernel at paged concurrency).
-if [ "${SKIP_TESTS:-0}" = "1" ]; then
-    echo 'SKIP_TESTS=1 — skipping weight-quant smoke stage'
-else
-    wq_rows="$(mktemp /tmp/ddp_wq_rows.XXXXXX.json)"
-    rm -f "$wq_rows"    # benchmark.py appends into a fresh JSON file
-    { JAX_PLATFORMS=cpu python benchmark.py --mode decode \
-          --seq-len 512 --heads 2 --head-dim 8 --iters 2 --no-ttft \
-          --dtype bf16 --file "$wq_rows" \
-      && JAX_PLATFORMS=cpu python benchmark.py --mode decode \
-          --seq-len 512 --heads 2 --head-dim 8 --iters 2 --no-ttft \
-          --dtype bf16 --weight-quant int8 --qk-quant int8 \
-          --file "$wq_rows" \
-      && python - "$wq_rows" <<'PY'; } || rc=1
-import json
-import sys
-
-rows = json.load(open(sys.argv[1]))
-bf16, wq8 = rows[-2], rows[-1]
-assert wq8['weight_quant'] == 'int8' and bf16['weight_quant'] is None
-assert wq8['step_bytes'] < bf16['step_bytes'], (
-    f"quantized row moves {wq8['step_bytes']} kv+weight bytes/step vs "
-    f"the bf16 twin's {bf16['step_bytes']} — the byte win is gone")
-assert wq8['paged_int8_kernel_eligible'] is True, (
-    'paged+int8 lost fused-kernel eligibility — quantized serving and '
-    '4x concurrency no longer compose')
-print(f"weight-quant smoke OK: {wq8['step_bytes']} vs "
-      f"{bf16['step_bytes']} bytes/step, paged int8 kernel-eligible")
-PY
-    rm -f "$wq_rows"
-fi
-
-echo '=== [10/13] closed-loop control smoke (static vs controlled under a ramp) ==='
+echo '=== [9/12] closed-loop control smoke (static vs controlled under a ramp) ==='
 # The control-plane acceptance row: the SAME seeded ramp trace (rate
 # climbing to 10x across the trace — deterministic overload) through a
 # 1-decode-replica topology twice. STATIC must breach the committed
@@ -226,12 +190,12 @@ else
     ctl_rows="$(mktemp /tmp/ddp_ctl_rows.XXXXXX.json)"
     ctl_static="$(mktemp -d /tmp/ddp_ctl_static.XXXXXX)"
     ctl_logs="$(mktemp -d /tmp/ddp_ctl_logs.XXXXXX)"
-    rm -f "$ctl_rows"    # benchmark.py appends into a fresh JSON file
-    { JAX_PLATFORMS=cpu python benchmark.py --mode serve-load \
+    rm -f "$ctl_rows"    # the driver appends into a fresh JSON file
+    { JAX_PLATFORMS=cpu python examples/serve_load.py \
           --topology 0x1 --arrival ramp --load-rate 300 \
           --ramp-factor 10 --load-requests 64 \
           --event-log "$ctl_static" --file "$ctl_rows" \
-      && JAX_PLATFORMS=cpu python benchmark.py --mode serve-load \
+      && JAX_PLATFORMS=cpu python examples/serve_load.py \
           --topology 0x1 --arrival ramp --load-rate 300 \
           --ramp-factor 10 --load-requests 64 --control \
           --event-log "$ctl_logs" --file "$ctl_rows" \
@@ -271,7 +235,7 @@ PY
     rm -rf "$ctl_rows" "$ctl_static" "$ctl_logs"
 fi
 
-echo '=== [11/13] replica-failure-domain smoke (seeded crash + recovery) ==='
+echo '=== [10/12] replica-failure-domain smoke (seeded crash + recovery) ==='
 # The robustness acceptance row: the seeded CI trace with decode
 # replica r1 killed at a fixed virtual tick. Probes declare the loss,
 # every in-flight stream re-dispatches to the survivor bit-identical
@@ -285,7 +249,7 @@ else
     scripts/smoke_chaos.sh || rc=1
 fi
 
-echo '=== [12/13] data-integrity smoke (seeded bit flip + detect/heal) ==='
+echo '=== [11/12] data-integrity smoke (seeded bit flip + detect/heal) ==='
 # The KV-page-integrity acceptance row: the seeded CI trace with one
 # exponent bit flipped in a live KV page of r0 at a fixed virtual
 # tick. The scrub detects the flip before any poisoned token is
@@ -299,7 +263,7 @@ else
     scripts/smoke_corrupt.sh || rc=1
 fi
 
-echo '=== [13/13] long-context smoke (128k stream on the sharded KV mesh) ==='
+echo '=== [12/12] long-context smoke (128k stream on the sharded KV mesh) ==='
 # The cluster-scale long-context acceptance row: a 128k-token stream
 # prefilled into a kv_shards=8 paged engine (each mesh member owns a
 # contiguous page range, per-shard flash partials psum/pmax-merged)

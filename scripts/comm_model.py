@@ -7,8 +7,8 @@ chip), so this is the checkable substitute: closed-form per-device ICI
 byte counts and collective schedules for the three distributed attention
 strategies as f(N, B, H, H_kv, T, d), cross-validated against the
 collective ops XLA actually compiles on a virtual mesh
-(``--check``, also run by tests/test_comm_model.py). RESULTS.md's
-"Communication model" section is generated by ``--table``.
+(``--check``, also run by tests/test_comm_model.py). ``--table`` prints
+the model as a markdown table.
 
 Model conventions
 -----------------
@@ -203,7 +203,7 @@ def check_against_hlo(n=8, b=1, h=8, h_kv=None, t=256, d=16):
 
 
 def table_markdown(n=8, b=1, h=8, t=131072, d=96, itemsize=2):
-    """RESULTS.md 'Communication model' table."""
+    """The communication model as a markdown table."""
     lines = [
         '| path | collective schedule (per device, fwd+bwd) | '
         'ICI bytes/step | vs allgather |',
@@ -232,7 +232,7 @@ def main():
                     help='compile on a virtual CPU mesh and reconcile '
                          'against the HLO collectives')
     ap.add_argument('--table', action='store_true',
-                    help='emit the RESULTS.md markdown table')
+                    help='emit the markdown table')
     ap.add_argument('-n', type=int, default=8)
     ap.add_argument('--heads', type=int, default=8)
     ap.add_argument('--seq-len', type=int, default=131072)

@@ -5,7 +5,7 @@
 #   scripts/smoke_chaos.sh
 #
 # What it proves (exit 0 = all of it):
-#   1. `benchmark.py --mode serve-load --topology 1x2 --chaos` replays
+#   1. `examples/serve_load.py --topology 1x2 --chaos` replays
 #      the seeded trace with replica r1 killed at a fixed virtual tick:
 #      the router's probes declare the loss, every in-flight stream on
 #      the victim is re-dispatched to the survivor from the recovery
@@ -34,7 +34,7 @@ trap 'rm -rf "$dir"' EXIT
 echo "== smoke_chaos: serve-load --topology 1x2 --chaos (logs in $dir) =="
 # Generous SLO: recovered streams keep their ORIGINAL submit anchor, so
 # their TTFT includes the crash + detection + replay window by design.
-python benchmark.py --mode serve-load --topology 1x2 --chaos \
+python examples/serve_load.py --topology 1x2 --chaos \
     --slo-ttft 2.0 --slo-token 1.0 \
     --event-log "$dir" --file "$row" || exit 1
 

@@ -6,7 +6,7 @@
 #   scripts/smoke_corrupt.sh
 #
 # What it proves (exit 0 = all of it):
-#   1. `benchmark.py --mode serve-load --topology 1x2 --chaos-corrupt`
+#   1. `examples/serve_load.py --topology 1x2 --chaos-corrupt`
 #      replays the seeded trace with one bit flipped in a tracked KV
 #      page of r0 at a fixed virtual tick: the router's per-tick scrub
 #      detects the flip BEFORE any poisoned token is delivered, the
@@ -34,7 +34,7 @@ echo "== smoke_corrupt: serve-load --topology 1x2 --chaos-corrupt (logs in $dir)
 # Page index 2 at tick 8 lands the flip on a registered prefix with a
 # queued rider (seed-7 trace) — a victim exists to expel and heal.
 # Generous SLO: the healed stream keeps its ORIGINAL submit anchor.
-python benchmark.py --mode serve-load --topology 1x2 \
+python examples/serve_load.py --topology 1x2 \
     --chaos-victim r0 --chaos-corrupt 2:8 \
     --slo-ttft 2.0 --slo-token 1.0 \
     --event-log "$dir" --file "$row" || exit 1

@@ -5,7 +5,7 @@
 #   scripts/smoke_router.sh
 #
 # What it proves (exit 0 = all of it):
-#   1. `benchmark.py --mode serve-load --topology 1x2` runs the seeded
+#   1. `examples/serve_load.py --topology 1x2` runs the seeded
 #      CI trace through the router (sequence-sharded prefill pool +
 #      2 paged decode replicas, KV handoff as pool pages) AND through
 #      its single-process twin on the byte-identical serialized trace.
@@ -36,7 +36,7 @@ row="$dir/row.json"
 trap 'rm -rf "$dir"' EXIT
 
 echo "== smoke_router: serve-load --topology 1x2 (logs in $dir) =="
-python benchmark.py --mode serve-load --topology 1x2 \
+python examples/serve_load.py --topology 1x2 \
     --event-log "$dir" --file "$row" || exit 1
 
 echo '== smoke_router: member logs schema-validate + carry the routing events =='

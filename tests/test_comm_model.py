@@ -4,7 +4,7 @@ The analytic ICI communication model (scripts/comm_model.py) must match
 what XLA actually compiles: per path, the multiset of collective ops and
 their per-op byte sizes in the compiled HLO equals the model's predicted
 schedule. This is the checkable substitute for multi-chip measurement
-(one real chip in the environment — RESULTS.md 'Communication model').
+(no benchmark cell spans chips yet).
 """
 
 import os

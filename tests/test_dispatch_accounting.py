@@ -5,7 +5,7 @@ serve/scheduler.py per-tick split): every decode tick stamps REAL tick
 wall time vs device-program time into a `serve.dispatch` event and the
 `serve.dispatch_overhead_seconds` / `serve.device_seconds` histograms,
 each committed token carries its tick's `device_seconds`, the split
-surfaces in /metrics exposition and the benchmark row helper — and
+surfaces in /metrics exposition and the load driver's row helper — and
 none of it touches the virtual timeline (the phase partition stays
 exact with the accounting on, which is always).
 """
@@ -138,12 +138,13 @@ def test_accounting_never_touches_the_virtual_partition(tmp_path,
     assert floor['total']['overhead_per_token'] is not None
 
 
-def test_benchmark_row_helper_reads_the_registry(tmp_path, devices):
-    """benchmark.py's `_dispatch_split` turns the two histograms into
-    the decode-serve/serve-load row columns."""
+def test_load_driver_row_helper_reads_the_registry(tmp_path, devices):
+    """examples/serve_load.py's `_dispatch_split` turns the two
+    histograms into the load driver's row columns."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     spec = importlib.util.spec_from_file_location(
-        'bench_for_test', os.path.join(repo, 'benchmark.py'))
+        'serve_load_for_test',
+        os.path.join(repo, 'examples', 'serve_load.py'))
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
 

@@ -206,8 +206,8 @@ def test_goodput_merges_multi_replica_logs(tmp_path):
 
 def test_committed_slo_baseline_gate_cli(tmp_path):
     """Tier-1 acceptance: the CI stage end to end, subprocess for
-    subprocess — the seeded serve-load smoke (benchmark.py flag
-    DEFAULTS) must pass `slo check` against the COMMITTED
+    subprocess — the seeded serve-load smoke (examples/serve_load.py,
+    bare) must pass `slo check` against the COMMITTED
     SLO_BASELINE.json; the regression fixture — the same seeded trace
     on 50x slower ticks — must exit 1 naming the metric and at least
     one tenant."""
@@ -217,7 +217,7 @@ def test_committed_slo_baseline_gate_cli(tmp_path):
         log = tmp_path / f'{tag}.jsonl'
         rows = tmp_path / f'{tag}_rows.json'
         r = subprocess.run(
-            [sys.executable, 'benchmark.py', '--mode', 'serve-load',
+            [sys.executable, 'examples/serve_load.py',
              '--event-log', str(log), '--file', str(rows), *extra],
             cwd=REPO, env=env, capture_output=True, text=True,
             timeout=300)

@@ -1,6 +1,6 @@
 # -*- coding: utf-8 -*-
 """
-Round-5 advisor-finding regressions (ADVICE.md round 4):
+Round-5 advisor-finding regressions:
 
 1. ``make_train_step`` must REFUSE to run a dropout-enabled module
    without an explicit ``dropout_seed`` (a silent constant seed would

@@ -18,6 +18,10 @@ trace share one injectable clock, so minutes of simulated traffic cost
 milliseconds and the report is bit-reproducible.
 """
 
+import json
+import os
+import subprocess
+import sys
 import urllib.request
 
 import numpy as np
@@ -336,3 +340,39 @@ def test_saved_trace_drives_identical_run(tmp_path, devices):
                 for rid, r in res.results.items()}
 
     assert run(trace) == run(load_trace(path))
+
+
+@pytest.mark.slow
+def test_serve_load_topology_cli(tmp_path):
+    """``examples/serve_load.py --topology 1x2`` as a process: the
+    trace goes through the router AND the single-process twin, the
+    per-member logs merge, and the row records both goodputs plus the
+    routing telemetry. The per-member JSONL logs must exist and the
+    placements must cover every decode replica."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    logs, out = tmp_path / 'topo', tmp_path / 'row.json'
+    # An inherited fault plan or path selector would change the run.
+    env = {k: v for k, v in os.environ.items()
+           if k not in ('XLA_FLAGS', 'JAX_PLATFORMS')
+           and not k.startswith('DDP_TPU_')}
+    env['JAX_PLATFORMS'] = 'cpu'
+    env['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, 'examples', 'serve_load.py'),
+         '--topology', '1x2', '--load-requests', '24',
+         '--event-log', str(logs), '--file', str(out)],
+        cwd=repo, env=env, stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout
+    with open(out) as f:
+        (rec,) = json.load(f)
+    assert rec['topology'] == '1x2' and rec['clock'] == 'virtual'
+    assert rec['requests'] == 24
+    assert set(rec['routed']) == {'r0', 'r1'}
+    assert sum(rec['routed'].values()) + rec['counts']['rejected'] >= 24
+    assert rec['handoffs'] >= 1          # the long-prompt tail offloads
+    # 2x the capacity on the same trace: the topology never does worse.
+    assert rec['goodput_pct'] >= rec['twin_goodput_pct']
+    for name in ('router', 'prefill', 'r0', 'r1', 'twin'):
+        assert (logs / f'{name}.jsonl').exists(), name
+    assert (logs / 'trace.json').exists()

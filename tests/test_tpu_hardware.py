@@ -761,3 +761,50 @@ def test_fused_decode_kernel_compiles_on_chip():
     c2, _ = step(c1, q, kn, vn)
     assert c2.k.unsafe_buffer_pointer() == ptr0, \
         'aliased decode cache was copied between donated steps'
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32],
+                         ids=['bf16', 'f32'])
+@pytest.mark.parametrize('h,h_kv', [(4, 4), (8, 2)], ids=['mha', 'gqa'])
+def test_verify_k_kernel_matches_sequential_bitwise_on_chip(h, h_kv, dtype):
+    """README "Speculative decoding" on the chip's own program: one
+    verify-k step of the fused kernel equals ``counts[i]`` sequential
+    single-token steps of the same kernel BIT for bit, outputs and
+    cache (the CPU suite's ``[*-kernel]`` cases run the Pallas
+    interpreter, whose XLA:CPU dots round differently at M=1)."""
+    from distributed_dot_product_tpu.models.decode import (
+        append_kv_slots, decode_step, init_slot_cache,
+    )
+    b, d, t_max, k = 2, 128, 1024, 3
+    ks = jax.random.split(jax.random.key(7), 5)
+    q = jax.random.normal(ks[0], (b, h, k, d), dtype)
+    kn = jax.random.normal(ks[1], (b, h_kv, k, d), dtype)
+    vn = jax.random.normal(ks[2], (b, h_kv, k, d), dtype)
+    kf = jax.random.normal(ks[3], (b, h_kv, t_max, d), dtype)
+    vf = jax.random.normal(ks[4], (b, h_kv, t_max, d), dtype)
+    # Staggered fills; slot 1's three rows straddle a 512-row K block.
+    fills = jnp.asarray([300, 511], jnp.int32)
+    counts = jnp.asarray([k, k - 1], jnp.int32)
+
+    def filled():
+        return append_kv_slots(init_slot_cache(b, h_kv, t_max, d,
+                                               dtype=dtype),
+                               kf, vf, counts=fills)
+
+    seq, outs = filled(), []
+    for j in range(k):
+        seq, o = decode_step(q[:, :, j:j + 1], seq, kn[:, :, j:j + 1],
+                             vn[:, :, j:j + 1], slot_mask=j < counts,
+                             impl='kernel')
+        outs.append(np.asarray(o, np.float32)[:, :, 0])
+    cv, ov = decode_step(q, filled(), kn, vn, counts=counts,
+                         impl='kernel')
+    ov = np.asarray(ov, np.float32)
+    for i in range(b):
+        for j in range(int(counts[i])):
+            np.testing.assert_array_equal(ov[i, :, j], outs[j][i],
+                                          err_msg=f'slot {i} row {j}')
+    for got, want in ((cv.k, seq.k), (cv.v, seq.v),
+                      (cv.length, seq.length)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))

@@ -7,8 +7,8 @@ directions, across the feature compositions it claims to support.
 
 The pair grid needs the Mosaic interpreter off-TPU (scalar-prefetch index
 maps), so these tests force it via the ``_TRAP_ON_INTERPRET`` hook and
-keep shapes tiny. The real-chip speed claim lives in RESULTS.md
-(T=131,072 causal train: 68.8 → 81.8 TF/s) and the hardware suite.
+keep shapes tiny. The pair grid on the chip: ``tests/test_tpu_hardware.py``
+(parity) and the ``*.train-16k`` benchmark cells (speed, ``PERF.md``).
 """
 
 import jax
