@@ -228,6 +228,9 @@ def phase_train(progs, cfg, seed, state):
     from distributed_dot_product_tpu import (
         TrainLoopConfig, TrainState, lm_targets, run_training,
     )
+    from distributed_dot_product_tpu.ops.pallas_attention import (
+        flash_bwd_traces,
+    )
     from distributed_dot_product_tpu.parallel.mesh import seq_mesh
     from distributed_dot_product_tpu.train import make_lm_train_step
     from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
@@ -241,8 +244,11 @@ def phase_train(progs, cfg, seed, state):
     # The step examples/train_lm.py builds: guarded, not donating.
     step = make_lm_train_step(model, optimizer, mesh, donate=False,
                               guard=True)
-    progs.compile('train_step', step, params, opt_state, batch,
-                  pallas=True)
+    # Which form the flash backward takes at this shape (the training
+    # cells' form: one fused kernel, dq resident in VMEM).
+    with flash_bwd_traces() as bwd_traces:
+        progs.compile('train_step', step, params, opt_state, batch,
+                      pallas=True)
     result = run_training(
         step, TrainState(0, params, opt_state), lambda i: batch,
         TrainLoopConfig(num_steps=3, final_save=False,
@@ -280,12 +286,15 @@ def phase_train(progs, cfg, seed, state):
     return {
         'T': cfg['train_t'], 'losses': losses,
         'bad_steps': result.bad_steps,
+        'flash_bwd': bwd_traces,
         'ref_T': cfg['ref_t'], 'loss_flash': float(loss_f),
         'loss_plain': float(loss_p), 'logits_max_abs_err': logit_err,
         'logits_max_abs': logit_scale,
         'checks': {
             'losses_finite': bool(np.all(np.isfinite(losses))),
             'no_bad_steps': result.bad_steps == 0,
+            'flash_bwd_fused': bool(bwd_traces) and all(
+                t['form'] == 'fused' for t in bwd_traces),
             'loss_step3_below_step1': losses[2] < losses[0],
             'loss_matches_plain_path':
                 abs(float(loss_f) - float(loss_p)) <= LOSS_ATOL,

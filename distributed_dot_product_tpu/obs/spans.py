@@ -78,7 +78,8 @@ DEVICE_SCOPES = {
                      'bounded and int8-score builds; the remat forward '
                      'is the same kernel under a checkpoint name stack)',
     'ops.flash_bwd_dq': 'the Pallas flash-attention dq kernel',
-    'ops.flash_bwd_dkv': 'the Pallas flash-attention dk/dv kernel',
+    'ops.flash_bwd_dkv': 'the Pallas flash-attention dk/dv kernel, and '
+                         'the fused dq/dk/dv kernel that walks as it does',
     'ops.flash_decode': 'the fused Pallas decode step kernel (append + '
                         'attend, any number of new rows)',
     'ops.mla_decode': 'the same kernel in its latent mode: one buffer of '

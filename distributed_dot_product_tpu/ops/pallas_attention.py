@@ -9,9 +9,11 @@ XLA fuses the elementwise pieces; these kernels fuse the *whole* chain in
 VMEM with an online softmax, so score blocks never touch HBM: traffic drops
 from O(T²) to O(T·d) and live score memory from O(Tq·Tk) to
 O(BLOCK_Q·BLOCK_K) — in BOTH directions. The backward is the standard
-flash recompute strategy as two Pallas kernels (a dq pass and a dk/dv
-pass): score blocks are re-derived from q/k and the saved row logsumexp,
-so training memory is O(T·d) too, not O(T²).
+flash recompute strategy: score blocks are re-derived from q/k and the
+saved row logsumexp, so training memory is O(T·d) too, not O(T²). It is
+ONE Pallas kernel (each block pair's scores built once, five matmuls)
+wherever one batch-head's dq fits VMEM, and a dq pass plus a dk/dv pass
+(seven) elsewhere.
 
 No reference analog (SURVEY §7 step 6 names this as the post-parity
 performance pass). Layout, per the TPU Pallas playbook:
@@ -22,11 +24,17 @@ performance pass). Layout, per the TPU Pallas playbook:
   steps; only one ``(BLOCK, d)`` tile of K/V is resident at a time (Pallas
   double-buffers the HBM→VMEM streams), so sequence length is bounded by
   HBM, not VMEM;
-- backward dq grid sweeps K innermost with a dq accumulator; the dk/dv
-  grid transposes the sweep (Q innermost) with dk/dv accumulators — each
-  pass recomputes ``p = exp(s − lse)`` from the residuals ``(q, k, lse)``
-  and contracts with the standard flash-backward algebra
-  ``ds = p · (dp − Δ)``, ``Δ = rowsum(dO ⊙ O)``;
+- the backward walks K-major (Q innermost) with dk/dv accumulators in
+  VMEM scratch; a pair recomputes ``p = exp(s − lse)`` from the residuals
+  ``(q, k, lse)`` once and contracts with the standard flash-backward
+  algebra ``ds = p · (dp − Δ)``, ``Δ = rowsum(dO ⊙ O)`` into all three
+  gradients: dq of the whole batch-head stays in a float32 VMEM
+  accumulator across the walk (``flash_bwd_fused``; ``_bwd_form`` picks it
+  by ``(Tq, d)`` against ``_FUSED_DQ_BYTES`` and states the call's
+  ``vmem_limit_bytes``). Past that budget, and for a pass asked alone, the
+  dk/dv kernel runs without dq and a dq kernel sweeps K innermost with its
+  own accumulator, each rebuilding ``s`` and ``dp``;
+  :func:`flash_bwd_traces` reports which form a trace took;
 - all matmuls hit the MXU with fp32 accumulation
   (``preferred_element_type``) whatever the input dtype; block shapes are
   lane(128)/sublane aligned;
@@ -45,6 +53,7 @@ Pallas interpreter mode, so the identical code paths are covered by the
 regular test suite.
 """
 
+import contextlib
 import functools
 import math
 
@@ -57,7 +66,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributed_dot_product_tpu.obs.spans import device_scope
 
-__all__ = ['flash_attention']
+__all__ = ['flash_attention', 'flash_bwd_traces']
 
 _NEG_BIG = -0.7 * 3.4e38  # large-finite fp32; keeps exp()/VJP NaN-free
 
@@ -81,7 +90,10 @@ def _bwd_block_sizes(tq, tk, dtype, d_total=128, has_mask=False):
     the p/dp/ds score blocks and the dk/dv accumulators). Measured on v5e
     (T=16K, d=64, bf16): 1024×1024 runs the fwd+bwd chain 17% faster than
     512×512 and still fits VMEM; halve when the head dims are large or a
-    (s32-widened) mask block joins the working set."""
+    (s32-widened) mask block joins the working set. One pair of sizes
+    serves the fused kernel and the two split ones; the fused kernel's dq
+    accumulator is not a tile of these sizes but the whole batch-head's
+    (``_bwd_form``), under a ``vmem_limit_bytes`` of its own."""
     sub = 16 if dtype == jnp.bfloat16 else 8
     cap_q = 1024 if d_total <= 256 and not has_mask else 256
     cap_k = 1024 if d_total <= 256 and not has_mask else 512
@@ -860,11 +872,12 @@ _KERNEL_SCOPES = {
     'flash_fwd_bounded': 'ops.flash_fwd',
     'flash_bwd_dq': 'ops.flash_bwd_dq',
     'flash_bwd_dkv': 'ops.flash_bwd_dkv',
+    'flash_bwd_fused': 'ops.flash_bwd_dkv',
 }
 
 
 def _pallas_call(name, kernel, grid, in_specs, out_specs, scratch,
-                 out_shape, interpret, prefetch):
+                 out_shape, interpret, prefetch, vmem_limit_bytes=None):
     """Build + invoke: a scalar-prefetch grid when any prefetch operands
     are live (the dense-mask block-skip summary and/or the window band
     offset), a plain grid otherwise. Prefetch refs reach both the index
@@ -877,11 +890,16 @@ def _pallas_call(name, kernel, grid, in_specs, out_specs, scratch,
     ``name`` is the kernel's stable name in a device trace (a plain
     identifier: Mosaic takes it as a symbol); the call runs under the
     :func:`~distributed_dot_product_tpu.obs.spans.device_scope` of the
-    kernel's family (``_KERNEL_SCOPES``)."""
+    kernel's family (``_KERNEL_SCOPES``). ``vmem_limit_bytes``: the
+    scoped-VMEM limit the call states (None: the compiler's default)."""
     prefetch = [p for p in prefetch if p is not None]
     interp = interpret
     if interpret is True and prefetch:
         interp = pltpu.InterpretParams()
+    params = {}
+    if vmem_limit_bytes is not None:
+        params['compiler_params'] = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes)
     if prefetch:
         call = pl.pallas_call(
             kernel,
@@ -889,12 +907,12 @@ def _pallas_call(name, kernel, grid, in_specs, out_specs, scratch,
                 num_scalar_prefetch=len(prefetch), grid=grid,
                 in_specs=in_specs, out_specs=out_specs,
                 scratch_shapes=scratch),
-            out_shape=out_shape, interpret=interp, name=name)
+            out_shape=out_shape, interpret=interp, name=name, **params)
     else:
         call = pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
                               out_specs=out_specs, scratch_shapes=scratch,
                               out_shape=out_shape, interpret=interp,
-                              name=name)
+                              name=name, **params)
 
     def run(*args):
         with device_scope(_KERNEL_SCOPES[name]):
@@ -1240,6 +1258,64 @@ def _make_fwd_kernel_bounded(causal, bq, bk, kv_len, has_mask, has_seg,
     return kernel
 
 
+# The fused backward keeps dq for one batch-head in VMEM: a float32
+# (Tq_p, d) accumulator and the double-buffered output block it is cast
+# into. v5e has 128 MiB of VMEM; the budget keeps the whole call under
+# half of it when the gradients are float32 (16 MiB: T = 32768 at d 128).
+_FUSED_DQ_BYTES = 16 * 1024 * 1024
+# What the K-major body needs beside dq at the largest blocks
+# (_bwd_block_sizes): its streams and the (bq, bk) score temporaries.
+_BWD_VMEM_BASE = 16 * 1024 * 1024
+
+_BWD_SINKS = []         # lists of the active flash_bwd_traces() blocks
+
+
+@contextlib.contextmanager
+def flash_bwd_traces():
+    """Collect which form each flash backward takes while the block
+    runs: one dict ``{'form', 'reason', 'only', 'dq_bytes',
+    'vmem_limit_bytes'}`` per TRACE of ``_flash_bwd_impl``'s kernels.
+    ``form`` is ``'fused'`` (one K-major kernel gives dq, dk and dv) or
+    ``'split'`` (the dq and dk/dv kernels), ``reason`` says why a split
+    (None when fused), ``dq_bytes`` is the float32 dq accumulator of one
+    batch-head that the choice was made by::
+
+        with flash_bwd_traces() as traces:
+            step.lower(*args).compile()
+        assert {t['form'] for t in traces} == {'fused'}
+    """
+    sink = []
+    _BWD_SINKS.append(sink)
+    try:
+        yield sink
+    finally:
+        _BWD_SINKS[:] = [s for s in _BWD_SINKS if s is not sink]
+
+
+def _bwd_form(only, tq_p, d, dq_dtype):
+    """Fused or split, from what the call can see: which passes are asked
+    and whether one batch-head's float32 dq ``(tq_p, d)``, as VMEM holds
+    it, fits its budget.
+    Returns ``(fused, vmem_limit_bytes)`` and tells the open
+    :func:`flash_bwd_traces` blocks."""
+    lanes = -(-d // 128) * 128      # VMEM tiles pad the minor dim
+    dq_bytes = tq_p * lanes * 4
+    reason = vmem_limit = None
+    if only != 'both':
+        reason = f'only={only!r}: a pass asked alone'
+    elif dq_bytes > _FUSED_DQ_BYTES:
+        reason = (f'dq accumulator {dq_bytes} B a batch-head is past the '
+                  f'{_FUSED_DQ_BYTES} B budget')
+    else:
+        vmem_limit = (_BWD_VMEM_BASE + dq_bytes
+                      + 2 * tq_p * lanes * jnp.dtype(dq_dtype).itemsize)
+    for sink in _BWD_SINKS:
+        sink.append({'form': 'split' if reason else 'fused',
+                     'reason': reason, 'only': only, 'dq_bytes': dq_bytes,
+                     'vmem_limit_bytes': vmem_limit})
+    return reason is None, vmem_limit
+
+
 def _make_dq_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
                     has_pos, has_alibi, has_mask_skip, window=None,
                     band_fn=None, quantized=False, dropout=None,
@@ -1334,7 +1410,14 @@ def _make_dq_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
 def _make_dkv_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
                      has_pos, has_alibi, has_mask_skip, window=None,
                      band_fn=None, quantized=False, dropout=None,
-                     trap=False, nqb=None):
+                     trap=False, nqb=None, fused=False):
+    """The K-major backward body: dk/dv accumulate over each K block's Q
+    run. ``fused``: the same walk also gives dq — ``ds`` of a pair is
+    built once and ``ds·k`` is added to row block ``qi`` of a float32
+    ``(nqb, bq, d)`` accumulator that stays in VMEM for the whole
+    batch-head (zeroed at its first pair, cast out at its last). K blocks
+    are walked in ascending order, so each dq row block sums its terms in
+    the order the dq kernel sums them."""
     def kernel(*refs):
         if trap:
             tq_ref, tk_ref, qlo_ref, *refs = refs
@@ -1355,7 +1438,10 @@ def _make_dkv_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
             quant = (sqf_ref, skr_ref)
         mask_ref, seg, pos, alibi_ref, rest = _split_aux(
             rest, has_mask, has_seg, has_pos, has_alibi)
-        dk_ref, dv_ref, dk_acc, dv_acc = rest
+        if fused:
+            dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = rest
+        else:
+            dk_ref, dv_ref, dk_acc, dv_acc = rest
         if trap:
             # Transposed trapezoid: K-major pair walk; each K block's Q
             # run starts at its first causally-visible Q block and always
@@ -1373,6 +1459,23 @@ def _make_dkv_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
             qi = qr if band_fn is None else band_fn(kj, bandoff_ref[0]) + qr
             first_q = qr == 0
             last_q_cond = qr == pl.num_programs(2) - 1
+
+        if fused:
+            # The batch-head's first and last program of the walk.
+            if trap:
+                first_pair = p == 0
+                last_pair = p == pl.num_programs(1) - 1
+            else:
+                first_pair = jnp.logical_and(kj == 0, first_q)
+                last_pair = jnp.logical_and(
+                    kj == pl.num_programs(1) - 1, last_q_cond)
+
+            @pl.when(first_pair)
+            def _():
+                def zero(i, carry):
+                    dq_acc[i] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+                    return carry
+                jax.lax.fori_loop(0, dq_acc.shape[0], zero, 0)
 
         @pl.when(first_q)
         def _():
@@ -1422,15 +1525,36 @@ def _make_dkv_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
             else:
                 q_op = q_ref[0]
                 dk_scale = 1.0 / _LOG2E
-            ds = (p * (dp - delta_ref[0])).astype(q_op.dtype)
+            ds32 = p * (dp - delta_ref[0])
+            ds = ds32.astype(q_op.dtype)
             dk_acc[:] += dk_scale * jax.lax.dot_general(
                 ds, q_op, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (BK, d)
+            if fused:
+                # The dq kernel's product, operands as it takes them.
+                if quantized:
+                    k_op = (k_ref[0].astype(jnp.float32)
+                            * skc_ref[0]).astype(v.dtype)
+                else:
+                    k_op = k_ref[0]
+                if k_op.dtype != ds.dtype:
+                    ds = ds32.astype(k_op.dtype)
+                dq_acc[qi] += scale * jax.lax.dot_general(
+                    ds, k_op, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)     # (BQ, d)
 
         @pl.when(last_q_cond)
         def _():
             dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+        if fused:
+            @pl.when(last_pair)
+            def _():
+                def flush(i, carry):
+                    dq_ref[0, i] = dq_acc[i].astype(dq_ref.dtype)
+                    return carry
+                jax.lax.fori_loop(0, dq_acc.shape[0], flush, 0)
 
     return kernel
 
@@ -1440,10 +1564,12 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
                     positions=None, window=None, alibi=None, qk_quant=None,
                     dropout_rate=0.0, dropout_seed=None, kv_offset=0,
                     only='both'):
-    """Blockwise flash backward: dq pass + dk/dv pass, O(block²) score
-    memory. Algebra: with ``p = exp(s − lse)`` (the softmax weights),
-    ``dv = pᵀ·dO``, ``ds = p ⊙ (dO·vᵀ − Δ)`` where ``Δ = rowsum(dO ⊙ O)``,
-    ``dq = scale·ds·k``, ``dk = scale·dsᵀ·q``.
+    """Blockwise flash backward, O(block²) score memory. Algebra: with
+    ``p = exp(s − lse)`` (the softmax weights), ``dv = pᵀ·dO``,
+    ``ds = p ⊙ (dO·vᵀ − Δ)`` where ``Δ = rowsum(dO ⊙ O)``,
+    ``dq = scale·ds·k``, ``dk = scale·dsᵀ·q``. One fused K-major kernel
+    gives all three where ``_bwd_form`` allows it; else (``only`` naming
+    one pass, or dq past its VMEM budget) a dq pass and a dk/dv pass.
 
     Empty-row cotangents need no explicit zeroing: with -inf masking the
     recomputed weights of such rows are exactly 0 (``lse`` clamps to
@@ -1549,6 +1675,7 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
     if quantized:
         args += [sqf, skr, sqc, skc]
     nqb, nkb = tq_p // bq, tk_p // bk
+    fused, vmem_limit = _bwd_form(only, tq_p, d, grad_dtype or q.dtype)
 
     # Banded window grids (see _flash_fwd_impl): the dq pass sweeps only
     # each Q block's K band; the dk/dv pass sweeps only each K block's Q
@@ -1633,9 +1760,9 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
                 b // kv_group, j, 0)),
         ]
 
-    # --- dq pass: grid (batch, Q block, K band), K innermost ---
+    # --- dq pass (split form): grid (batch, Q block, K band), K innermost
     dq = dk = dv = None
-    if only in ('both', 'dq'):
+    if only == 'dq' or (only == 'both' and not fused):
         dq_in_specs = [
             off_spec,
             *seed_specs,
@@ -1667,7 +1794,8 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
         )(off, *seed_args, *args, *aux_args)
         dq = dq[:, :tq].reshape(q.shape)
 
-    # --- dk/dv pass: grid (batch, K block, Q band), Q innermost ---
+    # --- dk/dv pass, and dq with it when fused: grid (batch, K block,
+    # Q band), Q innermost ---
     if only in ('both', 'dkv'):
         dkv_in_specs = [
             off_spec,
@@ -1683,6 +1811,20 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
             pl.BlockSpec((1, bk, d), lambda b, j, i, *rs: (b, j, 0)),
             pl.BlockSpec((1, bk, d_v), lambda b, j, i, *rs: (b, j, 0)),
         ]
+        dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
+                       pltpu.VMEM((bk, d_v), jnp.float32)]
+        dkv_out_shape = [
+            jax.ShapeDtypeStruct((nb, tk_p, d), grad_dtype or k.dtype),
+            jax.ShapeDtypeStruct((nb, tk_p, d_v), grad_dtype or v.dtype),
+        ]
+        if fused:
+            # dq rides the same walk: one (nqb, bq, d) block a batch-head,
+            # written back when the walk moves to the next one.
+            dkv_out_specs.append(pl.BlockSpec(
+                (1, nqb, bq, d), lambda b, j, i, *rs: (b, 0, 0, 0)))
+            dkv_scratch.append(pltpu.VMEM((nqb, bq, d), jnp.float32))
+            dkv_out_shape.append(jax.ShapeDtypeStruct(
+                (nb, nqb, bq, d), grad_dtype or q.dtype))
         if trap:
             dkv_grid = (nb, int(trap_pre_t[0].shape[0]))
             dkv_in_specs = _wrap_specs_pairs(dkv_in_specs, transposed=True)
@@ -1690,22 +1832,19 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
                                               transposed=True)
         else:
             dkv_grid = (nb, nkb, qband if banded else nqb)
-        dk, dv = _pallas_call(
-            'flash_bwd_dkv',
+        dk, dv, *dq_fused = _pallas_call(
+            'flash_bwd_fused' if fused else 'flash_bwd_dkv',
             _make_dkv_kernel(scale, causal, bq, bk, tk, *flags,
                              window=window, band_fn=qband_fn,
                              quantized=quantized, dropout=dropout,
-                             trap=bool(trap), nqb=nqb),
-            dkv_grid, dkv_in_specs, dkv_out_specs,
-            [pltpu.VMEM((bk, d), jnp.float32),
-             pltpu.VMEM((bk, d_v), jnp.float32)],
-            [
-                jax.ShapeDtypeStruct((nb, tk_p, d), grad_dtype or k.dtype),
-                jax.ShapeDtypeStruct((nb, tk_p, d_v),
-                                     grad_dtype or v.dtype),
-            ],
-            interpret, trap_pre_t if trap else [bandoff, runsum],
+                             trap=bool(trap), nqb=nqb, fused=fused),
+            dkv_grid, dkv_in_specs, dkv_out_specs, dkv_scratch,
+            dkv_out_shape, interpret,
+            trap_pre_t if trap else [bandoff, runsum],
+            vmem_limit_bytes=vmem_limit,
         )(off, *seed_args, *args, *aux_args)
+        if fused:
+            dq = dq_fused[0].reshape(nb, tq_p, d)[:, :tq].reshape(q.shape)
 
         dk = dk[:, :tk]
         dv = dv[:, :tk]

@@ -58,8 +58,16 @@ def op_names(compiled):
 
 @pytest.fixture(scope='module')
 def train_op_names():
+    """The step as it runs (the flash backward fused: dq rides the dk/dv
+    walk under ``ops.flash_bwd_dkv``) and, joined to it, the step with dq
+    held past its VMEM budget: the split form, the one that opens
+    ``ops.flash_bwd_dq``."""
     step, args = train_step_and_args()
-    return op_names(step.lower(*args).compile())
+    names = op_names(step.lower(*args).compile())
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pallas_attention, '_FUSED_DQ_BYTES', 0)
+        step, args = train_step_and_args()
+        return names | op_names(step.lower(*args).compile())
 
 
 @pytest.fixture(scope='module')
@@ -139,8 +147,10 @@ def test_passes_show_in_op_names(train_op_names):
     flash_fwd = [n for n in train_op_names if '/ops.flash_fwd/' in n]
     assert any('rematted_computation' in n for n in flash_fwd)
     assert any('transpose(jvp(' not in n and 'jvp(' in n for n in flash_fwd)
-    assert all('transpose(jvp(' in n for n in train_op_names
-               if '/ops.flash_bwd_dq/' in n)
+    flash_bwd = [n for n in train_op_names if '/ops.flash_bwd_d' in n]
+    assert {n.rsplit('/', 2)[-2] for n in flash_bwd} >= {
+        'flash_bwd_fused', 'flash_bwd_dq', 'flash_bwd_dkv'}
+    assert all('transpose(jvp(' in n for n in flash_bwd)
 
 
 def kernel_names(fn, *args):
@@ -158,12 +168,19 @@ def flash_sum(**kw):
 
 @pytest.mark.parametrize('fn, expected', [
     (flash_sum(), ['flash_fwd']),
-    (jax.grad(flash_sum()), ['flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv']),
+    (jax.grad(flash_sum()), ['flash_fwd', 'flash_bwd_fused']),
     (flash_sum(qk_quant='int8'), ['flash_fwd_int8']),
     (flash_sum(softmax_mode='bounded'), ['flash_fwd_bounded', 'flash_fwd']),
 ], ids=['fwd', 'grad', 'int8', 'bounded'])
 def test_flash_builds_carry_their_kernel_names(fn, expected):
     assert sorted(kernel_names(fn, Q)) == sorted(expected)
+
+
+def test_split_backward_build_carries_its_kernel_names(monkeypatch):
+    """dq past its VMEM budget: the two backward kernels, by their names."""
+    monkeypatch.setattr(pallas_attention, '_FUSED_DQ_BYTES', 0)
+    assert sorted(kernel_names(jax.grad(flash_sum()), Q)) == [
+        'flash_bwd_dkv', 'flash_bwd_dq', 'flash_fwd']
 
 
 def test_decode_build_carries_its_kernel_name():

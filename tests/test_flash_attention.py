@@ -20,6 +20,7 @@ from jax.sharding import PartitionSpec as P
 from distributed_dot_product_tpu.models.attention import (
     DistributedDotProductAttn,
 )
+import distributed_dot_product_tpu.ops.pallas_attention as pa
 from distributed_dot_product_tpu.ops.pallas_attention import (
     _reference_math, flash_attention,
 )
@@ -28,7 +29,9 @@ from distributed_dot_product_tpu.parallel.mesh import seq_mesh
 B, H, D = 2, 3, 16
 
 
-pytestmark = pytest.mark.slow  # Pallas-interpreter / lax.scan-heavy cases
+# Pallas-interpreter / lax.scan-heavy cases are slow; the fused-backward
+# cases at the end of the file are tiny and run in tier-1.
+slow = pytest.mark.slow
 
 
 def _qkv(t, key=0, d_v=D):
@@ -44,6 +47,7 @@ def _mask(t, p=0.3):
     return m.at[..., 0].set(False)  # keep every row attendable
 
 
+@slow
 @pytest.mark.parametrize('t', [64, 100])   # 100: blocks don't divide T
 @pytest.mark.parametrize('causal', [False, True])
 @pytest.mark.parametrize('masked', [False, True])
@@ -56,6 +60,7 @@ def test_matches_unfused_math(t, causal, masked):
                                atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_rectangular_and_dv():
     """Tq != Tk and d_v != d (the general shape contract)."""
     q, _, _ = _qkv(48)
@@ -67,6 +72,7 @@ def test_rectangular_and_dv():
                                atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_fully_masked_rows_zero_not_nan():
     q, k, v = _qkv(32)
     m = _mask(32).at[:, :, 5, :].set(True)   # row 5 fully masked
@@ -77,6 +83,7 @@ def test_fully_masked_rows_zero_not_nan():
     assert np.isfinite(np.asarray(g)).all()
 
 
+@slow
 @pytest.mark.parametrize('t', [64, 100])   # 100: blocks don't divide T
 @pytest.mark.parametrize('causal', [False, True])
 @pytest.mark.parametrize('masked', [False, True])
@@ -98,6 +105,7 @@ def test_gradients_match_unfused(t, causal, masked):
                                    atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_gradients_rectangular_and_dv():
     """Backward with Tq != Tk and d_v != d (exercises both bwd kernels on
     non-square grids)."""
@@ -118,6 +126,7 @@ def test_gradients_rectangular_and_dv():
                                    atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_gradient_dtype_matches_primal():
     """custom_vjp contract: cotangent dtypes equal primal dtypes (bf16)."""
     q, k, v = _qkv(32)
@@ -128,6 +137,7 @@ def test_gradient_dtype_matches_primal():
     assert all(x.dtype == jnp.bfloat16 for x in g)
 
 
+@slow
 @pytest.mark.parametrize('causal', [False, True])
 def test_bounded_softmax_mode_matches_exact(causal):
     """softmax_mode='bounded' (norm-bound shift, no running max) must agree
@@ -152,6 +162,7 @@ def test_bounded_softmax_mode_matches_exact(causal):
                                    atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_bounded_mode_safe_on_adversarial_norms():
     """Huge-norm near-orthogonal q/k make the Cauchy-Schwarz bound exceed
     fp32's exponent range; 'bounded' must auto-fall back to the exact
@@ -170,6 +181,7 @@ def test_bounded_mode_safe_on_adversarial_norms():
     assert np.isfinite(np.asarray(g)).all()
 
 
+@slow
 @pytest.mark.parametrize('mode', ['exact', 'bounded'])
 def test_row_masked_only_by_causal_union_is_zero(mode):
     """A row whose attendable keys are emptied only by the UNION of the
@@ -194,12 +206,14 @@ def test_row_masked_only_by_causal_union_is_zero(mode):
                                atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_bad_softmax_mode_rejected():
     q, k, v = _qkv(32)
     with pytest.raises(ValueError, match='softmax_mode'):
         flash_attention(q, k, v, softmax_mode='fast')
 
 
+@slow
 @pytest.mark.tpu
 def test_tpu_hardware_compile_path():
     """Mosaic (real-TPU) compile coverage the interpreter can't give:
@@ -226,6 +240,7 @@ def test_tpu_hardware_compile_path():
         assert np.isfinite(np.asarray(g, np.float32)).all()
 
 
+@slow
 def test_mask_with_extra_leading_dims_rejected():
     """A mask may broadcast over q/k/v leading dims but not ADD dims —
     output batch shape comes solely from q/k/v."""
@@ -235,6 +250,7 @@ def test_mask_with_extra_leading_dims_rejected():
         flash_attention(q, k, v, m)
 
 
+@slow
 def test_module_flash_impl_matches_local_oracle(devices):
     """DistributedDotProductAttn(softmax_impl='flash') inside shard_map ==
     the distributed=False local oracle (the reference test_gradient.py
@@ -260,3 +276,169 @@ def test_module_flash_impl_matches_local_oracle(devices):
     )(params, x, x, x, m)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                atol=1e-5, rtol=1e-5)
+
+
+# -- the fused backward against the split one ----------------------------
+# One K-major kernel gives dq, dk and dv where the dq accumulator of a
+# batch-head fits its VMEM budget; past it, and for a pass asked alone,
+# the dq and dk/dv kernels run. Blocks are shrunk to 16 x 16 so that the
+# walks (trapezoid, banded, plain grid) have several blocks at tiny T.
+
+FB, FH, FT, FD = 1, 2, 64, 16
+
+
+def _fused_inputs(tq=FT, tk=FT, hkv=None):
+    ks = jax.random.split(jax.random.key(29), 4)
+    q = jax.random.normal(ks[0], (FB, FH, tq, FD))
+    k = jax.random.normal(ks[1], (FB, hkv or FH, tk, FD))
+    v = jax.random.normal(ks[2], (FB, hkv or FH, tk, FD))
+    g = jax.random.normal(ks[3], (FB, FH, tq, FD))
+    return q, k, v, g
+
+
+def _backward(monkeypatch, budget, *, tq=FT, tk=FT, hkv=None, hooks=(),
+              grad_dtype=None, **kw):
+    """(trace records, (dq, dk, dv)) of one backward under a dq budget of
+    ``budget`` bytes (0: always split)."""
+    monkeypatch.setattr(pa, '_bwd_block_sizes', lambda *a, **k: (16, 16))
+    monkeypatch.setattr(pa, '_FUSED_DQ_BYTES', budget)
+    for hook in hooks:
+        monkeypatch.setattr(pa, hook, True)
+    q, k, v, g = _fused_inputs(tq, tk, hkv)
+    with pa.flash_bwd_traces() as traces:
+        if grad_dtype is None:
+            _, vjp = jax.vjp(
+                lambda q, k, v: flash_attention(q, k, v, **kw), q, k, v)
+            grads = vjp(g)
+        else:   # the ring path's call: float32 partials
+            scale = 1.0 / np.sqrt(FD)
+            causal = kw.pop('causal', False)
+            out, lse = pa._flash_fwd_impl(q, k, v, None, 0, scale, causal,
+                                          True, save_lse=True, **kw)
+            grads = pa._flash_bwd_impl(q, k, v, None, 0, out, lse, g,
+                                       scale, causal, True,
+                                       grad_dtype=grad_dtype, **kw)
+    return traces, grads
+
+
+_SEG = (jnp.arange(FT) // 20, jnp.arange(FT) // 20)
+_POS = (jnp.arange(FT)[::-1], jnp.arange(FT)[::-1])
+FUSED_CASES = {
+    'causal_trapezoid': dict(causal=True, hooks=['_TRAP_ON_INTERPRET']),
+    'causal_full_grid': dict(causal=True),
+    'window_banded': dict(causal=True, window=24,
+                          hooks=['_BAND_ON_INTERPRET']),
+    'gqa_window_banded': dict(causal=True, window=24, hkv=1,
+                              hooks=['_BAND_ON_INTERPRET']),
+    'gqa_trapezoid': dict(causal=True, hkv=1,
+                          hooks=['_TRAP_ON_INTERPRET']),
+    'alibi_trapezoid': dict(causal=True,
+                            alibi_slopes=jnp.asarray([0.5, 0.25]),
+                            hooks=['_TRAP_ON_INTERPRET']),
+    'segments_trapezoid': dict(causal=True, segment_ids=_SEG,
+                               hooks=['_TRAP_ON_INTERPRET']),
+    'segments_full_grid': dict(segment_ids=_SEG),
+    'ragged_q': dict(tq=56, tk=72),         # 3.5 x 4.5 blocks
+    'ragged_causal': dict(causal=True, tq=72, tk=72),
+    'row_offset_trapezoid': dict(causal=True, causal_offset=32,
+                                 hooks=['_TRAP_ON_INTERPRET']),
+    'positions': dict(positions=_POS),
+    'dense_mask': dict(mask=True),
+    'dropout': dict(causal=True, dropout_rate=0.25, dropout_seed=3),
+    'int8': dict(causal=True, qk_quant='int8'),
+    'grad_float32': dict(causal=True, grad_dtype=jnp.float32),
+    'grad_float32_window': dict(causal=True, window=24,
+                                grad_dtype=jnp.float32),
+}
+
+
+def _case_kwargs(case):
+    kw = dict(FUSED_CASES[case])
+    if kw.pop('mask', False):
+        m = jax.random.bernoulli(jax.random.key(7), 0.3,
+                                 (FB, FH, FT, FT))
+        kw['mask'] = m.at[..., 0].set(False)
+    return kw
+
+
+@pytest.mark.parametrize('case', sorted(FUSED_CASES))
+def test_fused_backward_matches_split(monkeypatch, case):
+    """Same inputs, both forms: dq, dk and dv agree to the tolerance this
+    file holds against ``_reference_math``, in the dtype asked."""
+    tf, fused = _backward(monkeypatch, 1 << 20, **_case_kwargs(case))
+    ts, split = _backward(monkeypatch, 0, **_case_kwargs(case))
+    assert [t['form'] for t in tf] == ['fused'], tf
+    assert tf[0]['reason'] is None and tf[0]['vmem_limit_bytes']
+    assert [t['form'] for t in ts] == ['split'], ts
+    assert 'budget' in ts[0]['reason']
+    for a, b in zip(fused, split):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize('causal', [False, True])
+def test_fused_backward_matches_unfused_math(monkeypatch, causal):
+    """The fused form against the plain jnp oracle, several blocks a
+    side."""
+    kw = dict(causal=causal,
+              hooks=['_TRAP_ON_INTERPRET'] if causal else [])
+    traces, fused = _backward(monkeypatch, 1 << 20, **kw)
+    assert [t['form'] for t in traces] == ['fused']
+    q, k, v, g = _fused_inputs()
+    _, vjp = jax.vjp(lambda q, k, v: _reference_math(
+        q, k, v, None, 1.0 / np.sqrt(FD), causal), q, k, v)
+    for a, b in zip(fused, vjp(g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_fused_backward_fully_masked_row_gives_zero(monkeypatch):
+    """A row with no attendable key: zero dq, finite everything, and the
+    other rows' gradients as the split form gives them."""
+    m = jax.random.bernoulli(jax.random.key(7), 0.3, (FB, FH, FT, FT))
+    m = m.at[..., 0].set(False).at[:, :, 21, :].set(True)
+    tf, fused = _backward(monkeypatch, 1 << 20, mask=m)
+    _, split = _backward(monkeypatch, 0, mask=m)
+    assert [t['form'] for t in tf] == ['fused']
+    assert (np.asarray(fused[0])[:, :, 21] == 0).all()
+    for a, b in zip(fused, split):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+def test_backward_past_the_dq_budget_splits_and_says_so(monkeypatch):
+    """The choice is a function of (Tq, d): the largest shape inside the
+    budget is fused, the first past it takes the two kernels and the
+    counter gives the reason."""
+    budget = 80 * 128 * 4        # 5 blocks of 16 rows, 128 lanes, float32
+    inside, _ = _backward(monkeypatch, budget, causal=True, tq=80, tk=80)
+    past, grads = _backward(monkeypatch, budget, causal=True, tq=81, tk=81)
+    assert [(t['form'], t['dq_bytes']) for t in inside] == [
+        ('fused', budget)]
+    assert [(t['form'], t['dq_bytes']) for t in past] == [
+        ('split', 96 * 128 * 4)]
+    assert 'past the' in past[0]['reason'] and str(budget) in \
+        past[0]['reason']
+    assert past[0]['vmem_limit_bytes'] is None
+    assert all(np.isfinite(np.asarray(x)).all() for x in grads)
+
+
+@pytest.mark.parametrize('only', ['dq', 'dkv'])
+def test_backward_pass_asked_alone_is_split(monkeypatch, only):
+    """``only='dq'`` / ``'dkv'`` (the beyond-cap chunking's calls) keep
+    their own kernel whatever the budget."""
+    monkeypatch.setattr(pa, '_bwd_block_sizes', lambda *a, **k: (16, 16))
+    q, k, v, g = _fused_inputs()
+    scale = 1.0 / np.sqrt(FD)
+    out, lse = pa._flash_fwd_impl(q, k, v, None, 0, scale, True, True,
+                                  save_lse=True)
+    with pa.flash_bwd_traces() as traces:
+        grads = pa._flash_bwd_impl(q, k, v, None, 0, out, lse, g, scale,
+                                   True, True, only=only)
+    assert [(t['form'], t['only']) for t in traces] == [('split', only)]
+    assert only in traces[0]['reason']
+    got = [x is not None for x in grads]
+    assert got == ([True, False, False] if only == 'dq'
+                   else [False, True, True])

@@ -58,6 +58,11 @@ def chip():
     compilation_cache.reset_cache()
 
 
+def _mpt_slopes(h):
+    """MPT's ALiBi slopes (``alibi_bias_max`` 8) for ``h`` heads."""
+    return tuple(2.0 ** (-8.0 * (i + 1) / h) for i in range(h))
+
+
 def _compile(chip, fn, *args, donate=()):
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
@@ -80,6 +85,80 @@ def test_flash_attention_compiles_for_v5e(chip, d, grad):
 
     _compile(chip, jax.grad(fwd, argnums=(0, 1, 2)) if grad else fwd,
              x, x, x)
+
+
+# (heads, KV heads, T, flash_attention kwargs, the form the backward takes)
+_BWD_SHAPES = {
+    # the two training cells' calls
+    'mpt-7b.train-16k': (32, 32, 16384, dict(alibi=True), 'fused'),
+    'starcoder2-3b.train-16k': (24, 2, 16384, dict(window=4096), 'fused'),
+    # the dq budget's edges at d 128: 16 MiB of float32 a batch-head
+    'largest_inside_budget': (4, 4, 32768, {}, 'fused'),
+    'first_past_budget': (4, 4, 32768 + 1024, {}, 'split'),
+    'largest_inside_budget_gqa_window': (8, 2, 32768, dict(window=4096),
+                                         'fused'),
+}
+
+
+@pytest.mark.parametrize('shape', sorted(_BWD_SHAPES))
+def test_flash_backward_form_compiles_for_v5e(chip, shape):
+    """The fused backward (dq of a batch-head resident in VMEM) under the
+    ``vmem_limit_bytes`` it states: Mosaic's verdict on the budget at
+    both training cells' shapes and at the budget's edge; one block past
+    it the call keeps the two kernels, under the compiler's default."""
+    from distributed_dot_product_tpu.ops.pallas_attention import (
+        _BWD_VMEM_BASE, _FUSED_DQ_BYTES, flash_bwd_traces,
+    )
+    h, h_kv, t, kw, form = _BWD_SHAPES[shape]
+    kw = dict(kw)
+    if kw.pop('alibi', False):
+        kw['alibi_slopes'] = jnp.asarray(_mpt_slopes(h), jnp.float32)
+    q = jax.ShapeDtypeStruct((1, h, t, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, h_kv, t, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False, **kw),
+                       dtype=jnp.float32)
+
+    with flash_bwd_traces() as traces:
+        hlo = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                       q, kv, kv).as_text()
+    assert [tr['form'] for tr in traces] == [form], traces
+    dq_bytes = t * 128 * 4
+    assert traces[0]['dq_bytes'] == dq_bytes
+    if form == 'fused':
+        # float32 accumulator + the double-buffered bfloat16 block
+        assert traces[0]['vmem_limit_bytes'] == (
+            _BWD_VMEM_BASE + dq_bytes + 2 * t * 128 * 2)
+        assert dq_bytes <= _FUSED_DQ_BYTES
+        assert 'flash_bwd_fused' in hlo and 'flash_bwd_dq' not in hlo
+    else:
+        assert dq_bytes > _FUSED_DQ_BYTES
+        assert 'past the' in traces[0]['reason']
+        assert 'flash_bwd_dq' in hlo and 'flash_bwd_dkv/' in hlo
+        assert 'flash_bwd_fused' not in hlo
+
+
+def test_flash_backward_float32_grads_compile_at_the_budget(chip):
+    """The ring fold's call (``grad_dtype=float32``: the output block is
+    as wide as the accumulator) at the budget's edge: 64 MiB stated, half
+    of the chip's VMEM."""
+    from distributed_dot_product_tpu.ops.pallas_attention import (
+        _flash_bwd_impl, flash_bwd_traces,
+    )
+    h, t, d = 2, 32768, 128
+    x = jax.ShapeDtypeStruct((1, h, t, d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((1, h, t), jnp.float32)
+
+    def bwd(q, k, v, out, lse, g):
+        return _flash_bwd_impl(q, k, v, None, 0, out, lse, g, d ** -0.5,
+                               True, False, grad_dtype=jnp.float32)
+
+    with flash_bwd_traces() as traces:
+        _compile(chip, bwd, x, x, x, x, lse, x)
+    assert [(tr['form'], tr['vmem_limit_bytes']) for tr in traces] == [
+        ('fused', 64 * 1024 * 1024)]
 
 
 def _cache(layout, h_kv, qk_quant):
@@ -133,7 +212,7 @@ def _compile_cell_kernel(chip, cell):
         layers, b, h, t_max, d = 8, 2, 32, 16384, 128
         assert decode_geometry(t_max, h, d, d, 1, bf16, bf16)[:3] == (
             8, 1024, 16)
-        slopes = tuple(2.0 ** (-8.0 * (i + 1) / h) for i in range(h))
+        slopes = _mpt_slopes(h)
         row = jax.ShapeDtypeStruct((b, h, 1, d), bf16)
         buf = jax.ShapeDtypeStruct((layers, b, h, t_max, d), bf16)
 
@@ -303,7 +382,7 @@ def test_scanned_lm_decode_step_moves_no_cache(chip, monkeypatch,
     # The program asks the backend whether to compile its kernel or
     # interpret it; answer for the described chip, here only.
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-    slopes = tuple(2.0 ** (-8.0 * (i + 1) / 32) for i in range(32))
+    slopes = _mpt_slopes(32)
     model = TransformerLM(**LM, dtype=jnp.bfloat16, scan_layers=True,
                           attn_kwargs=dict(use_rope=False,
                                            alibi_slopes=slopes,

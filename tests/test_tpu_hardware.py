@@ -524,6 +524,41 @@ def test_trapezoid_causal_matches_full_grid_on_chip():
                                       np.asarray(b, np.float32))
 
 
+@pytest.mark.parametrize('form', ['trapezoid_alibi', 'banded_gqa'])
+def test_fused_backward_matches_split_bitwise_on_chip(monkeypatch, form):
+    """The fused backward walks K blocks in ascending order, so each dq
+    row block sums its terms in the dq kernel's order: dq, dk and dv
+    equal the split form's bit for bit on the chip's own program, in
+    both training cells' mask forms, several 1024-row blocks a side."""
+    import distributed_dot_product_tpu.ops.pallas_attention as pa
+    h, h_kv, kw = 4, 4, dict(alibi_slopes=jnp.asarray(
+        [2.0 ** (-2.0 * (i + 1)) for i in range(4)], jnp.float32))
+    if form == 'banded_gqa':
+        h, h_kv, kw = 4, 2, dict(window=2048)
+    ks = jax.random.split(jax.random.key(29), 4)
+    t, d = 4096 + 300, 128         # a ragged last block
+    q, g = (jax.random.normal(kk, (1, h, t, d), jnp.bfloat16)
+            for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (1, h_kv, t, d), jnp.bfloat16)
+            for kk in ks[2:])
+
+    def run(budget):
+        monkeypatch.setattr(pa, '_FUSED_DQ_BYTES', budget)
+        with pa.flash_bwd_traces() as traces:
+            _, vjp = jax.vjp(lambda q, k, v: pa.flash_attention(
+                q, k, v, causal=True, **kw), q, k, v)
+            grads = vjp(g)
+        return [tr['form'] for tr in traces], grads
+
+    forms_f, fused = run(16 << 20)
+    forms_s, split = run(0)
+    assert (forms_f, forms_s) == (['fused'], ['split'])
+    for a, b in zip(fused, split):
+        assert bool(jnp.isfinite(a.astype(jnp.float32)).all())
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
 def test_module_gqa_rope_fwd_bwd_on_chip():
     """The round-4 module surface on real hardware: num_kv_heads + RoPE
     through apply_seq_parallel (W=1 mesh) vs the distributed=False
