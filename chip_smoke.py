@@ -10,9 +10,12 @@ what comes out by the repo's own means. ``generate_latent`` repeats the
 generate checks on a small-depth model of the other block
 ``TransformerLM`` composes (latent attention at its published head
 sizes, sparse experts, hyper-connection streams), whose step is
-``flash_decode``'s latent mode.
+``flash_decode``'s latent mode; ``generate_mixed`` on a stack of window
+and full attention layers (the parallel block over sparse experts),
+whose step runs the kernel's slab mode and its ring mode side by side,
+so a ring-mode failure on a new runtime shows here before the benchmark.
 
-    python chip_smoke.py             # one chip: train, generate (x2), serve
+    python chip_smoke.py             # one chip: train, generate (x3), serve
     python chip_smoke.py --chips 4   # ONLY the cross-chip phases
     JAX_PLATFORMS=cpu python chip_smoke.py --tiny   # CPU rehearsal
 
@@ -50,7 +53,14 @@ FULL = dict(
     # logits by more than any rounding tolerance: chip, PR 26)
     latent=dict(dim=1024, heads=8, q_rank=256, kv_rank=512, nope=128,
                 rope=64, v=128, dense_hidden=2048, experts=8, top_k=8,
-                expert_hidden=256, layers=3))
+                expert_hidden=256, layers=3),
+    # generate_mixed: window and full attention layers in one stack
+    # (the parallel block of benchmarks/configs/command-a-plus-serve at
+    # its head size, GQA 4:1): the 2048-token prompt fills the window
+    # layers' 2048-column ring, two K splits, and the first served
+    # token wraps it
+    mixed=dict(dim=1024, heads=8, kv_heads=2, window=512, ring=2048,
+               experts=8, expert_hidden=256, shared=2, layers=4))
 TINY = dict(
     vocab=128, dim=64, heads=2, layers=1,
     train_t=128, ref_t=32,
@@ -61,7 +71,9 @@ TINY = dict(
     shard_ctx=100,
     latent=dict(dim=64, heads=4, q_rank=24, kv_rank=32, nope=16, rope=16,
                 v=16, dense_hidden=96, experts=4, top_k=4,
-                expert_hidden=32, layers=3))
+                expert_hidden=32, layers=3),
+    mixed=dict(dim=64, heads=2, kv_heads=1, window=8, ring=16, experts=4,
+               expert_hidden=32, shared=2, layers=4))
 
 # bf16 tolerance, relative to the compared tensor's own scale: two paths
 # that are equal in exact arithmetic may differ by max|a - b| <=
@@ -359,6 +371,34 @@ def latent_lm(cfg, **attn_kwargs):
                        'ffn_kwargs': {'hidden': c['dense_hidden']}})
 
 
+def mixed_lm(cfg, **attn_kwargs):
+    """A period of three window layers and a full one in the parallel
+    block over sparse experts (``cfg['mixed']``): the window layers
+    decode on a ring cache through the kernel's ring mode, the full
+    layer on a slab. Every expert is gated, as in ``latent_lm``."""
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu import TransformerLM
+    c = cfg['mixed']
+    return TransformerLM(
+        vocab_size=cfg['vocab'], dim=c['dim'], num_heads=c['heads'],
+        n_layers=c['layers'], dtype=jnp.bfloat16, scan_layers=False,
+        attn_kwargs={'num_kv_heads': c['kv_heads'],
+                     'rope_layout': 'interleaved', **attn_kwargs},
+        block_kwargs={'norm': 'layernorm_nobias', 'parallel': True,
+                      'ffn': 'experts',
+                      'ffn_kwargs': {'n_experts': c['experts'],
+                                     'top_k': c['experts'],
+                                     'hidden': c['expert_hidden'],
+                                     'n_shared': c['shared'],
+                                     'shared_combine': 'mean',
+                                     'router_bias': False}},
+        layer_kinds={'window': {'attn_kwargs': {
+            'window': c['window'], 'ring_cache': c['ring']}},
+            'full': {'attn_kwargs': {'use_rope': False}}},
+        layer_pattern=('window', 'window', 'window', 'full'))
+
+
 def generate_once(progs, tag, cfg, seed, params, lm=lm, **attn_kwargs):
     """greedy_generate with the module's decode_impl left at its
     default, then the same token path through an ``decode_impl='xla'``
@@ -389,6 +429,8 @@ def generate_once(progs, tag, cfg, seed, params, lm=lm, **attn_kwargs):
         tokens = greedy_generate(model, params, prompt, steps,
                                  t_max=t_max)
     resolved = sorted({t['resolved'] for t in traces})
+    # The caches the steps were on ('layer', 'stacked', 'ring').
+    on = sorted({t['cache'] for t in traces})
     # What one grid step of the kernel holds (decode_geometry).
     kernel_step = next((t['step'] for t in traces if t['step']), None)
     tokens = np.asarray(tokens)
@@ -399,6 +441,7 @@ def generate_once(progs, tag, cfg, seed, params, lm=lm, **attn_kwargs):
     err, scale = max_err(ours[1:], theirs[1:])
     return {
         f'{tag}_resolved_impl': resolved,
+        f'{tag}_caches': on,
         f'{tag}_kernel_step': kernel_step,
         f'{tag}_logits_max_abs_err': err,
         f'{tag}_logits_max_abs': scale,
@@ -445,6 +488,25 @@ def phase_generate_latent(progs, cfg, seed):
         jax.numpy.zeros((1, 16), 'int32'))['params']}
     rec, checks = generate_once(progs, 'latent', cfg, seed, params,
                                 lm=latent_lm)
+    return {**rec, 'checks': checks}
+
+
+def phase_generate_mixed(progs, cfg, seed):
+    """The same generate checks on a stack of window and full attention
+    layers: the prompt is prefilled through ring and slab caches, and
+    the step runs BOTH modes of ``flash_decode`` (``flash_decode_ring``
+    on the window layers' rings, which the first served token wraps)
+    against the XLA formulation of the same step."""
+    import jax
+    model = mixed_lm(cfg)
+    params = {'params': model.init(
+        jax.random.key(seed + 6),
+        jax.numpy.zeros((1, 16), 'int32'))['params']}
+    rec, checks = generate_once(progs, 'mixed', cfg, seed, params,
+                                lm=mixed_lm)
+    checks['mixed.both_modes_resolved_kernel'] = (
+        rec['mixed_resolved_impl'] == ['kernel']
+        and rec['mixed_caches'] == ['layer', 'ring'])
     return {**rec, 'checks': checks}
 
 
@@ -815,6 +877,8 @@ def main(argv=None):
                run_phase('generate', phase_generate, cfg, args.seed,
                          state),
                run_phase('generate_latent', phase_generate_latent, cfg,
+                         args.seed),
+               run_phase('generate_mixed', phase_generate_mixed, cfg,
                          args.seed),
                run_phase('serve', phase_serve, cfg, args.seed, out_dir)]
     else:

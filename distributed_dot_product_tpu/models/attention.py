@@ -53,7 +53,7 @@ from distributed_dot_product_tpu.models.dense import OwnedDense
 from distributed_dot_product_tpu.models.ring_attention import (
     _layout_positions, local_attention_reference, ring_attention,
 )
-from distributed_dot_product_tpu.ops.rope import rope
+from distributed_dot_product_tpu.ops.rope import rope, rope_interleaved
 from distributed_dot_product_tpu.models.ulysses_attention import (
     ulysses_attention,
 )
@@ -81,6 +81,11 @@ class DistributedDotProductAttn(nn.Module):
     """
     key_dim: int
     value_dim: Optional[int] = None
+    # The composition's output width where it is not ``value_dim``: a
+    # model whose heads together are wider than its residual stream
+    # (128 heads x 128 on a 4096-wide stream) sets ``key_dim`` to the
+    # heads' width and this to the stream's.
+    out_dim: Optional[int] = None
     query_dim: Optional[int] = None
     num_heads: int = 1
     # Grouped-query attention (GQA; None = standard multi-head). The
@@ -158,6 +163,17 @@ class DistributedDotProductAttn(nn.Module):
     # reference module.py:41-58.
     use_rope: bool = False
     rope_base: float = 10000.0
+    # Which features make a rotary pair: 'half' (i, i + d/2 — NeoX /
+    # LLaMA, ops.rope.rope) or 'interleaved' (2i, 2i + 1 — GPT-J /
+    # Cohere, ops.rope.rope_interleaved). The same rotation on other
+    # pairs: a checkpoint's layout, not a choice of the math.
+    rope_layout: str = 'half'
+    # A window layer's decode cache as a RING of this many columns
+    # (models.decode.RingCache; needs ``window <= ring_cache``): it
+    # holds the newest rows whatever the context's length, where the
+    # slab stores all t_max and only skips reading them. Where t_max is
+    # no more than this the slab is the smaller one and is built.
+    ring_cache: Optional[int] = None
     # Decode-step implementation: None/'auto' picks the fused Pallas
     # decode kernel (in-place aliased cache append + split-K masked
     # attention, ops/pallas_decode.py) on TPU and the portable XLA
@@ -241,6 +257,14 @@ class DistributedDotProductAttn(nn.Module):
         if kv_heads != self.num_heads:
             features.check('num_kv_heads', self.softmax_impl)
         self._kv_heads = kv_heads
+        if self.rope_layout not in ('half', 'interleaved'):
+            raise ValueError(f"rope_layout must be 'half' or "
+                             f"'interleaved', got {self.rope_layout!r}")
+        if self.ring_cache is not None and not (
+                self.window is not None and self.window <= self.ring_cache):
+            raise ValueError(
+                f'ring_cache {self.ring_cache} recycles rows: it needs a '
+                f'window no larger, got {self.window!r}')
         if self.use_rope:
             features.check('use_rope', self.softmax_impl)
             if self.head_dim % 2:
@@ -261,7 +285,7 @@ class DistributedDotProductAttn(nn.Module):
         self.queries_proj = dense(kv_heads * self.head_dim, 'queries')
         self.values_proj = dense(
             kv_heads * (value_dim // self.num_heads), 'values')
-        self.composition = dense(value_dim, 'composition')
+        self.composition = dense(self.out_dim or value_dim, 'composition')
 
     def __call__(self, keys, queries, values, attn_mask=None,
                  segment_ids=None, deterministic=False,
@@ -345,8 +369,8 @@ class DistributedDotProductAttn(nn.Module):
                 pos = _layout_positions('zigzag', idx, world, tn)
             else:
                 pos = idx * tn + jnp.arange(tn)
-            keys = rope(keys, pos, base=self.rope_base)
-            queries = rope(queries, pos, base=self.rope_base)
+            keys = self._rope(keys, pos)
+            queries = self._rope(queries, pos)
 
         # Causal handling: ring/ulysses/flash take causal=True natively —
         # the kernels skip whole future blocks and need no materialized
@@ -581,11 +605,21 @@ class DistributedDotProductAttn(nn.Module):
         ``num_kv_heads`` heads of queries/values — the softmax-table side
         under the K-first convention). Plain Python (reads constructor
         fields only), so no ``apply`` is needed."""
-        from distributed_dot_product_tpu.models.decode import init_cache
+        from distributed_dot_product_tpu.models.decode import (
+            init_cache, init_ring_cache,
+        )
         kv_heads = (self.num_kv_heads if self.num_kv_heads is not None
                     else self.num_heads)
         value_dim = (self.value_dim if self.value_dim is not None
                      else self.key_dim)
+        if self.ring_cache is not None and self.ring_cache < t_max:
+            if self.qk_quant is not None:
+                raise ValueError('a ring cache carries no int8 mirror')
+            return init_ring_cache(
+                batch, kv_heads, self.ring_cache,
+                self.key_dim // self.num_heads,
+                v_head_dim=value_dim // self.num_heads,
+                dtype=dtype or self.dtype or jnp.float32)
         return init_cache(
             batch, kv_heads, t_max, self.key_dim // self.num_heads,
             v_head_dim=value_dim // self.num_heads,
@@ -614,9 +648,17 @@ class DistributedDotProductAttn(nn.Module):
                        self._value_dim // self.num_heads)
         if self.use_rope:
             pos = length + jnp.arange(n)
-            keys = rope(keys, pos, base=self.rope_base)
-            queries = rope(queries, pos, base=self.rope_base)
+            keys = self._rope(keys, pos)
+            queries = self._rope(queries, pos)
         return keys, queries, values
+
+    def _rope(self, x, pos):
+        if self.rope_layout == 'half':
+            return rope(x, pos, base=self.rope_base)
+        d = x.shape[-1]
+        return rope_interleaved(
+            x, pos, self.rope_base ** (
+                -jnp.arange(0, d, 2, dtype=jnp.float32) / d))
 
     def _merge_decode_heads(self, out):
         out = jnp.swapaxes(out, -3, -2)
@@ -641,10 +683,30 @@ class DistributedDotProductAttn(nn.Module):
         ``decode``, must already carry the ids of the positions being
         appended (rows attend their own columns). Returns
         ``(cache, out)``."""
-        from distributed_dot_product_tpu.models.decode import append_kv
+        from distributed_dot_product_tpu.models.decode import (
+            RingCache, append_kv, ring_append, ring_window,
+        )
         with device_scope('lm.attn_proj'):
             keys, queries, values = self._project_for_decode(
                 keys, queries, values, cache.length)
+            if isinstance(cache, RingCache):
+                # The chunk sees the ring's previous rows (< window of
+                # them) and itself, laid out as a slab of its own; the
+                # ring then keeps what it keeps of the chunk.
+                if (segment_ids is not None or self.qk_quant is not None
+                        or self.alibi_slopes is not None):
+                    raise ValueError(
+                        'a ring cache is prefilled with causal + window '
+                        'masking alone: no segment_ids, qk_quant or '
+                        'alibi_slopes')
+                k_all, v_all, start = ring_window(cache, queries, values,
+                                                  self.window)
+                out = flash_attention(
+                    keys, k_all, v_all, causal=True, causal_offset=start,
+                    scale=1.0 / math.sqrt(self.head_dim),
+                    window=self.window)
+                return (ring_append(cache, queries, values),
+                        self._merge_decode_heads(out))
             start = cache.length
             cache = append_kv(cache, queries, values)
             seg_pair = None

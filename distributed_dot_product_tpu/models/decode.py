@@ -60,7 +60,8 @@ __all__ = ['DecodeCache', 'init_cache', 'append_kv', 'append_kv_sharded',
            'decode_attention', 'init_slot_cache', 'append_kv_slots',
            'reset_slot', 'slots_all_finite', 'decode_step',
            'decode_kernel_eligible', 'decode_impl_traces',
-           'rollback_slots',
+           'rollback_slots', 'RingCache', 'init_ring_cache',
+           'ring_append', 'ring_window', 'insert_session',
            'PagedDecodeCache', 'PagePool', 'PageChecksums',
            'ShardedPageTable', 'init_sharded_paged_cache',
            'init_paged_cache', 'paged_gather', 'paged_gather_mirror',
@@ -108,6 +109,114 @@ def init_cache(batch, kv_heads, t_max, head_dim, v_head_dim=None,
              if quant else None),
         k_scale=(jnp.zeros((batch, kv_heads, t_max, 1), jnp.float32)
                  if quant else None))
+
+
+class RingCache(NamedTuple):
+    """A sliding-window layer's RECYCLED cache: ``k``/``v`` are
+    ``(B, H_kv, capacity, d·)`` and position ``p`` lives in column
+    ``p mod capacity``, so the buffers hold the newest ``capacity``
+    positions whatever the context's length; ``length`` (traced scalar)
+    counts the positions appended so far. With ``capacity >= window``
+    every row a query may attend is held. Rows are never cleared: a
+    column holds the newest position ``<= length − 1`` that maps to it
+    (:func:`ring_positions`), so after ``length`` is set BACK (a serving
+    loop's reset between requests) the rows that the abandoned positions
+    overwrote are taken for older ones — sound exactly while those rows
+    lay outside every later query's window, i.e. while ``capacity >=
+    window + (positions abandoned)``; :func:`decode_step`'s kernel mode
+    and :func:`decode_attention` both mask by the valid interval, which
+    never reaches them."""
+    k: jax.Array
+    v: jax.Array
+    length: jax.Array
+
+    @property
+    def capacity(self):
+        return self.k.shape[-2]
+
+    t_max = capacity
+
+
+def init_ring_cache(batch, kv_heads, capacity, head_dim, v_head_dim=None,
+                    dtype=jnp.bfloat16):
+    """Zero ring cache of ``capacity`` columns (at least the window of
+    the layer it serves; a multiple of the decode kernel's K split keeps
+    the kernel eligible)."""
+    return RingCache(
+        k=jnp.zeros((batch, kv_heads, capacity, head_dim), dtype),
+        v=jnp.zeros((batch, kv_heads, capacity, v_head_dim or head_dim),
+                    dtype),
+        length=jnp.zeros((), jnp.int32))
+
+
+def ring_positions(length, capacity):
+    """The position each column of a ring holds once ``length``
+    positions were appended: the newest ``p <= length − 1`` with
+    ``p mod capacity`` the column; negative where none was written."""
+    last = length - 1
+    return last - jnp.mod(last - jnp.arange(capacity), capacity)
+
+
+def ring_append(cache: RingCache, k_new, v_new) -> RingCache:
+    """Append ``n`` rows at positions ``length … length + n − 1``: each
+    to its column, the last ``capacity`` of them where ``n`` is more
+    (the ring keeps no others)."""
+    n, cap = k_new.shape[-2], cache.capacity
+    keep = min(n, cap)
+    start = cache.length + (n - keep)
+
+    def write(buf, new):
+        new = new[..., n - keep:, :].astype(buf.dtype)
+        if keep == 1:
+            zero = jnp.zeros((), jnp.int32)
+            return lax.dynamic_update_slice(
+                buf, new, (zero, zero, jnp.mod(start, cap), zero))
+        cols = jnp.mod(start + jnp.arange(keep), cap)
+        return buf.at[:, :, cols].set(new, unique_indices=True)
+    return RingCache(k=write(cache.k, k_new), v=write(cache.v, v_new),
+                     length=cache.length + n)
+
+
+def ring_window(cache: RingCache, k_new, v_new, window):
+    """What a chunk of ``n`` new rows attends, laid out as a slab:
+    ``(k, v, offset)`` with ``k``/``v`` ``(B, H_kv, window + n, d·)``
+    holding positions ``base … base + window + n − 1`` in order
+    (``base = max(length − window, 0)``): the ring's previous rows, then
+    the chunk at row ``offset = length − base``. Rows past the chunk are
+    whatever the ring held there; they lie in the chunk's future, where
+    causal masking never looks. Take it BEFORE :func:`ring_append`, which
+    may recycle rows the chunk's first queries still see."""
+    n, cap = k_new.shape[-2], cache.capacity
+    if window > cap:
+        raise ValueError(f'window {window} exceeds the ring\'s capacity '
+                         f'{cap}: rows a query attends would be recycled')
+    base = jnp.maximum(cache.length - window, 0)
+    cols = jnp.mod(base + jnp.arange(window + n), cap)
+    offset = cache.length - base
+
+    def lay(buf, new):
+        return lax.dynamic_update_slice_in_dim(
+            jnp.take(buf, cols, axis=2), new.astype(buf.dtype), offset,
+            axis=2)
+    return lay(cache.k, k_new), lay(cache.v, v_new), offset
+
+
+def insert_session(cache, session, one):
+    """``cache`` (a :class:`DecodeCache` or :class:`RingCache` of a
+    serving batch, scalar length) with session ``session`` replaced by
+    the single session ``one`` holds — a prompt prefilled alone, then
+    put in its slot. The batch shares one clock, so every session put
+    in must be of ``one``'s length, which becomes the batch's. Donate
+    ``cache``: the update is in place."""
+    if getattr(cache, 'k_q', None) is not None:
+        raise ValueError('insert_session moves k and v alone: a cache '
+                         'with an int8 mirror is not covered')
+    zero = jnp.zeros((), jnp.int32)
+    at = (jnp.asarray(session, jnp.int32), zero, zero, zero)
+    return cache._replace(
+        k=lax.dynamic_update_slice(cache.k, one.k, at),
+        v=lax.dynamic_update_slice(cache.v, one.v, at),
+        length=one.length)
 
 
 def append_kv(cache: DecodeCache, k_new, v_new) -> DecodeCache:
@@ -1751,7 +1860,12 @@ def decode_kernel_eligible(cache, n=1, segment_ids=None, qk_quant=None,
                 f'{cache.page_size} — k rows must span at most two '
                 f'pages')
         return verdict(None)
-    if qk_quant == 'int8' and cache.k_q is None:
+    if isinstance(cache, RingCache):
+        if n != 1 or qk_quant is not None:
+            return verdict(f'the ring kernel step is single-token and '
+                           f'unquantized, got n={n}, qk_quant='
+                           f'{qk_quant!r}')
+    elif qk_quant == 'int8' and cache.k_q is None:
         return verdict('this slab cache carries no int8 K mirror — '
                        "allocate it with init_cache(qk_quant='int8')")
     bk = decode_block_k(cache.t_max)
@@ -1807,7 +1921,9 @@ def decode_impl_traces():
     ``'stacked'`` where the step addressed a layer-stacked buffer by
     ``layer`` — a scanned stack's in-place loop — and ``'layer'`` where
     it was handed one layer's buffers, so a return to slicing the stack
-    per layer shows here; ``step`` is the kernel's grid step,
+    per layer shows here, and ``'ring'`` for a window layer's
+    :class:`RingCache`, whose kernel step is the ring mode; ``step`` is
+    the kernel's grid step,
     ``{'heads', 'block_k', 'bytes'}`` — KV heads and cache rows of one
     step and the cache bytes it streams, as
     ``ops.pallas_decode.decode_geometry`` chose them from the call's
@@ -1884,8 +2000,9 @@ def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
     step = None
     if resolved == 'kernel' and q is not None and _IMPL_SINKS:
         step = _kernel_step(q, cache, qk_quant)
-    record_decode_impl(impl, resolved, reason,
-                       'stacked' if stacked else 'layer', step)
+    kind = ('ring' if isinstance(cache, RingCache)
+            else 'stacked' if stacked else 'layer')
+    record_decode_impl(impl, resolved, reason, kind, step)
     return resolved
 
 
@@ -1957,6 +2074,17 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
     Returns ``(cache, out (B, H, n, d_v))``.
     """
     n = q.shape[-2]
+    if isinstance(cache, RingCache):
+        given = dict(slot_mask=slot_mask, counts=counts,
+                     alibi_slopes=alibi_slopes, segment_ids=segment_ids,
+                     seg_q=seg_q, qk_quant=qk_quant, axis_name=axis_name,
+                     layer=layer)
+        extra = sorted(k for k, v in given.items() if v is not None)
+        if extra:
+            raise ValueError(f'decode_step: a RingCache step takes scale, '
+                             f'window and impl alone, got {extra}')
+        return _ring_step(q, cache, k_new, v_new, scale=scale,
+                          window=window, impl=impl, interpret=interpret)
     paged = isinstance(cache, PagedDecodeCache)
     stack = None
     if layer is not None:
@@ -2206,6 +2334,36 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
     if axis_name is None:
         return cache, out
     return cache, _flash_merge(out, axis_name, cache.v.dtype)
+
+
+def _ring_step(q, cache: RingCache, k_new, v_new, *, scale, window, impl,
+               interpret):
+    """:func:`decode_step` on a window layer's :class:`RingCache`: the
+    new row goes to column ``length mod capacity`` and the query attends
+    the ``min(length + 1, window)`` rows ending there. The kernel
+    (``flash_decode(ring_span=)``) covers the single-token step on a
+    capacity its K split divides (:func:`decode_kernel_eligible`); the
+    XLA formulation (:func:`ring_append` + :func:`decode_attention`)
+    everything, and is its oracle."""
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        flash_decode,
+    )
+    cap = cache.capacity
+    if window is None or window > cap:
+        raise ValueError(f'a RingCache step needs its layer\'s window '
+                         f'(<= capacity {cap}), got {window!r}')
+    impl = _resolve_decode_impl(impl, cache, q.shape[-2], None, None, q=q)
+    if impl == 'xla':
+        cache = ring_append(cache, k_new, v_new)
+        return cache, decode_attention(q, cache, scale=scale,
+                                       window=window)
+    b = q.shape[0]
+    col = jnp.broadcast_to(jnp.mod(cache.length, cap), (b,))
+    span = jnp.broadcast_to(jnp.minimum(cache.length + 1, window), (b,))
+    out, new_k, new_v, _, _ = flash_decode(
+        q, k_new, v_new, cache.k, cache.v, col, col, ring_span=span,
+        scale=scale, interpret=interpret)
+    return RingCache(k=new_k, v=new_v, length=cache.length + 1), out
 
 
 def _flash_merge(partials, axis_name, out_dtype):
@@ -2493,6 +2651,12 @@ def decode_attention(q, cache: DecodeCache, *, scale=None, window=None,
     replicated; ``segment_ids`` (when used) is the slab's local shard;
     ``cache.length`` is global.
 
+    A :class:`RingCache` (a window layer's recycled buffers; needs
+    ``window <= capacity``, scalar length, no ``axis_name``) is masked by
+    the POSITION each column holds (:func:`ring_positions`) in place of
+    the column's index: the same causal and window comparisons, plus
+    "was ever written". This is the oracle of the kernel's ring mode.
+
     ``col_offset``: explicit global position of this buffer's column 0
     (default: ``axis_index · t_max`` when sharded, else 0). The
     sequence-SHARDED PAGED view passes 0 — a shard's gathered slab
@@ -2538,7 +2702,7 @@ def decode_attention(q, cache: DecodeCache, *, scale=None, window=None,
         qi, sq = _quantize_rows(qg, b * h_kv, group * n, d)
         qi = qi.reshape(qg.shape)
         sq = sq.reshape(b, h_kv, group * n, 1)
-        if cache.k_q is not None:
+        if getattr(cache, 'k_q', None) is not None:
             ki, sk = cache.k_q, cache.k_scale
         else:
             ki, sk = _quantize_rows(cache.k, b * h_kv, t_max, d)
@@ -2587,9 +2751,20 @@ def decode_attention(q, cache: DecodeCache, *, scale=None, window=None,
                    else lax.axis_index(axis_name) * t_max)
     lengths = cache.length[:, None] if per_slot else cache.length
     pos_q = lengths - n + jnp.arange(n)       # (B, n) per-slot else (n,)
-    pos_k = col_off + jnp.arange(t_max)                     # (t_local,)
+    ring = isinstance(cache, RingCache)
+    if ring:
+        if (per_slot or axis_name is not None or window is None
+                or window > t_max):
+            raise ValueError(
+                'a RingCache is attended with its layer\'s window (<= '
+                'capacity), a scalar length and no axis_name')
+        pos_k = ring_positions(cache.length, t_max)
+    else:
+        pos_k = col_off + jnp.arange(t_max)                 # (t_local,)
     rel = pos_k - pos_q[..., None]            # ([B,] n, t_max)
     allowed = rel <= 0
+    if ring:
+        allowed = jnp.logical_and(allowed, pos_k >= 0)
     if window is not None:
         allowed = jnp.logical_and(allowed, -rel < window)
     if not per_slot:

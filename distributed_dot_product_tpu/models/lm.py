@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from distributed_dot_product_tpu.models.transformer import (
-    TransformerStack,
+    TransformerStack, make_norm,
 )
 from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
@@ -103,13 +103,22 @@ class TransformerLM(nn.Module):
     remat: bool = False
     remat_policy: Optional[str] = None
     tie_embeddings: bool = True
-    # The block's composition and a leading run of dense layers —
+    # The logits are ``logit_scale · LN_f(x) · Eᵀ`` (Cohere's models
+    # state one; 1 adds no operation).
+    logit_scale: float = 1.0
+    # The block's composition and the kinds of its layers —
     # TransformerStack's fields of the same names (block_kwargs' 'norm'
-    # and 'norm_eps' also choose the final norm). With
-    # block_kwargs['residual'] == 'hyper' the embedding is widened to
-    # ``mult`` equal float32 streams before the stack and the streams
-    # are summed before the final norm.
+    # and 'norm_eps' also choose the final norm). ``dense_prefix`` /
+    # ``prefix_kwargs`` say the commonest case of layer kinds in two
+    # words: the first ``dense_prefix`` layers are of a kind that lays
+    # ``prefix_kwargs`` over ``block_kwargs`` (a model's leading dense
+    # layers before its expert layers); not beside ``layer_pattern``.
+    # With block_kwargs['residual'] == 'hyper' the embedding is widened
+    # to ``mult`` equal float32 streams before the stack and the
+    # streams are summed before the final norm.
     block_kwargs: Any = None
+    layer_kinds: Any = None
+    layer_pattern: Any = None
     dense_prefix: int = 0
     prefix_kwargs: Any = None
 
@@ -128,6 +137,14 @@ class TransformerLM(nn.Module):
         return kw
 
     def _stack_fields(self):
+        kinds, pattern = self.layer_kinds, self.layer_pattern
+        if self.dense_prefix:
+            if pattern:
+                raise ValueError('dense_prefix is a layer_pattern of its '
+                                 'own: pass one or the other')
+            kinds = {'prefix': self.prefix_kwargs or {}, 'rest': {}}
+            pattern = (('prefix',) * self.dense_prefix + ('rest',) * (
+                self.n_layers - self.dense_prefix))
         return dict(dim=self.dim, num_heads=self.num_heads,
                     n_layers=self.n_layers, mlp_ratio=self.mlp_ratio,
                     axis_name=self.axis_name, dtype=self.dtype,
@@ -136,8 +153,7 @@ class TransformerLM(nn.Module):
                     scan_layers=self.scan_layers, remat=self.remat,
                     remat_policy=self.remat_policy,
                     block_kwargs=self.block_kwargs,
-                    dense_prefix=self.dense_prefix,
-                    prefix_kwargs=self.prefix_kwargs)
+                    layer_kinds=kinds, layer_pattern=pattern)
 
     def setup(self):
         self.embed = nn.Embed(self.vocab_size, self.dim,
@@ -145,10 +161,8 @@ class TransformerLM(nn.Module):
         self.stack = TransformerStack(**self._stack_fields(),
                                       name='stack')
         kw = self.block_kwargs or {}
-        norm = (nn.RMSNorm if kw.get('norm') == 'rmsnorm'
-                else nn.LayerNorm)
-        self.ln_f = norm(epsilon=kw.get('norm_eps', 1e-6),
-                         dtype=self.dtype, name='ln_f')
+        self.ln_f = make_norm(kw.get('norm', 'layernorm'),
+                              kw.get('norm_eps', 1e-6), self.dtype, 'ln_f')
         if not self.tie_embeddings:
             # An explicit (dim, vocab) kernel rather than nn.Dense: the
             # chunked loss below reads the table directly (a bound
@@ -194,10 +208,12 @@ class TransformerLM(nn.Module):
             # the hardware default; the result is cast back to the
             # activation dtype (the contract is fp32 accumulation, not
             # fp32 logits).
-            return jnp.einsum('...d,vd->...v', x,
-                              self._head_table().astype(x.dtype),
-                              preferred_element_type=jnp.float32
-                              ).astype(x.dtype)
+            logits = jnp.einsum('...d,vd->...v', x,
+                                self._head_table().astype(x.dtype),
+                                preferred_element_type=jnp.float32)
+            if self.logit_scale != 1.0:
+                logits = logits * self.logit_scale
+            return logits.astype(x.dtype)
 
     def __call__(self, tokens, segment_ids=None, deterministic=False,
                  dropout_seed=None):
@@ -237,6 +253,8 @@ class TransformerLM(nn.Module):
         def chunk_nll(x_c, t_c):
             logits = jnp.einsum('...cd,vd->...cv',
                                 x_c.astype(jnp.float32), table)
+            if self.logit_scale != 1.0:
+                logits = logits * self.logit_scale
             lse = jax.scipy.special.logsumexp(logits, axis=-1)
             valid = t_c >= 0
             ll = jnp.take_along_axis(

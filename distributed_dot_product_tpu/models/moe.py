@@ -10,7 +10,14 @@ group):
     g_i = s_i / sum_picked s · scaling       (norm_topk), i in picked
     y = sum_i g_i E_i(x) + E_shared(x)       E(x) = W_down(silu(W_gate x) * W_up x)
 
-No capacity factor: no token is dropped.
+No capacity factor: no token is dropped. Two switches cover the same
+layer as other families write it: ``router_bias=False`` has no
+correction bias (the pick is the top-k of the scores themselves), and
+``shared_combine='mean'`` adds the MEAN of the ``n_shared`` shared
+experts' outputs where ``'sum'`` adds their sum (Cohere's
+``shared_expert_combination_strategy: "average"``). The shared experts
+are one gated MLP ``n_shared x hidden`` wide either way — their sum —
+so the mean is that over ``n_shared``.
 
 The layer is TOLD which experts it holds (``experts_held = (lo, hi)``, a
 range; default all). It always routes over all ``n_experts``, computes
@@ -68,6 +75,8 @@ class SparseExperts(nn.Module):
     norm_topk: bool = True
     experts_held: Optional[Tuple[int, int]] = None
     add_shared: bool = True
+    shared_combine: str = 'sum'
+    router_bias: bool = True
     dtype: Optional[jnp.dtype] = None
     kernel_init: Any = nn.initializers.lecun_normal(in_axis=-2,
                                                     out_axis=-1,
@@ -80,11 +89,15 @@ class SparseExperts(nn.Module):
         if not 0 <= lo < hi <= self.n_experts:
             raise ValueError(f'experts_held {self.experts_held} is no '
                              f'range of {self.n_experts} experts')
+        if self.shared_combine not in ('sum', 'mean'):
+            raise ValueError(f"shared_combine must be 'sum' or 'mean', "
+                             f'got {self.shared_combine!r}')
         dim = x.shape[-1]
         router = self.param('router', nn.initializers.lecun_normal(),
                             (dim, self.n_experts), jnp.float32)
-        bias = self.param('router_bias', nn.initializers.zeros_init(),
-                          (self.n_experts,), jnp.float32)
+        bias = (self.param('router_bias', nn.initializers.zeros_init(),
+                           (self.n_experts,), jnp.float32)
+                if self.router_bias else None)
         w_gate = self.param('w_gate', self.kernel_init,
                             (held, dim, self.hidden), jnp.float32)
         w_up = self.param('w_up', self.kernel_init,
@@ -99,7 +112,8 @@ class SparseExperts(nn.Module):
             scores = jax.nn.sigmoid(jnp.dot(
                 flat.astype(jnp.float32), router.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST))
-            _, picked = lax.top_k(scores + bias, k)             # (n, k)
+            _, picked = lax.top_k(
+                scores if bias is None else scores + bias, k)   # (n, k)
             gates = jnp.take_along_axis(scores, picked, axis=-1)
             if self.norm_topk:
                 gates = gates / jnp.sum(gates, -1, keepdims=True)
@@ -142,6 +156,9 @@ class SparseExperts(nn.Module):
 
         if self.n_shared and self.add_shared:
             with device_scope('lm.mlp'):
-                y = y + GatedMLP(self.n_shared * self.hidden,
-                                 dtype=self.dtype, name='shared')(flat)
+                shared = GatedMLP(self.n_shared * self.hidden,
+                                  dtype=self.dtype, name='shared')(flat)
+                if self.shared_combine == 'mean':
+                    shared = shared * (1.0 / self.n_shared)
+                y = y + shared
         return y.reshape(x.shape[:-1] + (dim,)), counts

@@ -52,6 +52,17 @@ from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 __all__ = ['TransformerBlock', 'TransformerStack']
 
 
+def make_norm(kind, eps, dtype, name):
+    """The norm a block (and the LM's final norm) is built with."""
+    if kind in ('layernorm', 'layernorm_nobias'):
+        return nn.LayerNorm(epsilon=eps, dtype=dtype, name=name,
+                            use_bias=kind == 'layernorm')
+    if kind == 'rmsnorm':
+        return nn.RMSNorm(epsilon=eps, dtype=dtype, name=name)
+    raise ValueError(f"norm must be 'layernorm', 'layernorm_nobias' or "
+                     f"'rmsnorm', got {kind!r}")
+
+
 class TransformerBlock(nn.Module):
     """Pre-norm block: the mixer's branch, then the feed-forward's, each
     around a residual.
@@ -60,7 +71,9 @@ class TransformerBlock(nn.Module):
     block this file always built (``x + Attn(LN(x))`` then
     ``x + MLP(LN(x))``, LayerNorm, GELU), with its parameter tree:
 
-    - ``norm``: ``'layernorm'`` | ``'rmsnorm'`` (``norm_eps``);
+    - ``norm``: ``'layernorm'`` | ``'layernorm_nobias'`` (Cohere's:
+      mean-subtracting, a scale and no bias) | ``'rmsnorm'``
+      (``norm_eps``);
     - ``mixer``: ``'attention'`` (``DistributedDotProductAttn``;
       ``attn_kwargs`` passes through: softmax_impl, num_kv_heads,
       use_rope, window, dropout_rate, ...; self-attention in the
@@ -75,7 +88,11 @@ class TransformerBlock(nn.Module):
     - ``residual``: ``'add'`` | ``'hyper'`` (``models/hyper``: the input
       is a widened stream ``(..., mult, dim)`` float32 and each branch
       reads and writes it through its own ``HyperConnection(
-      **residual_kwargs)``)."""
+      **residual_kwargs)``);
+    - ``parallel``: ``x + Attn(h) + FFN(h)`` with ``h = LN(x)``, ONE
+      norm a block (``ln1``; there is no ``ln2``) and both branches
+      from it, where the default runs them one after the other, each
+      with its norm (``residual='add'`` only)."""
     dim: int
     num_heads: int
     mlp_ratio: int = 4
@@ -96,16 +113,10 @@ class TransformerBlock(nn.Module):
     ffn_kwargs: Any = None
     residual: str = 'add'
     residual_kwargs: Any = None
+    parallel: bool = False
 
     def _norm(self, name):
-        if self.norm == 'layernorm':
-            return nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype,
-                                name=name)
-        if self.norm == 'rmsnorm':
-            return nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
-                              name=name)
-        raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', got "
-                         f'{self.norm!r}')
+        return make_norm(self.norm, self.norm_eps, self.dtype, name)
 
     def setup(self):
         kw = dict(self.attn_kwargs or {})
@@ -116,13 +127,23 @@ class TransformerBlock(nn.Module):
         elif self.mixer == 'attention':
             kw.setdefault('axis_name', self.axis_name)
             kw.setdefault('weight_quant', self.weight_quant)
+            # attn_kwargs' key_dim: heads wider together than the
+            # stream; the composition then comes back to ``dim``.
+            kw.setdefault('key_dim', self.dim)
+            if kw['key_dim'] != self.dim:
+                kw.setdefault('out_dim', self.dim)
             self.attn = DistributedDotProductAttn(
-                key_dim=self.dim, num_heads=self.num_heads, **kw)
+                num_heads=self.num_heads, **kw)
         else:
             raise ValueError(f"mixer must be 'attention' or 'latent', "
                              f'got {self.mixer!r}')
         self.ln1 = self._norm('ln1')
-        self.ln2 = self._norm('ln2')
+        if self.parallel:
+            if self.residual != 'add':
+                raise ValueError("parallel=True is x + Attn(h) + FFN(h): "
+                                 "it goes with residual='add'")
+        else:
+            self.ln2 = self._norm('ln2')
         ffn_kw = dict(self.ffn_kwargs or {})
         if self.ffn == 'gelu':
             # OwnedDense (explicit fp32 accumulation + the int8 weight
@@ -159,34 +180,43 @@ class TransformerBlock(nn.Module):
         y = branch(u.astype(self.dtype or u.dtype))
         return mix_back(x, y, h_post, h_res)
 
-    def _mlp(self, x):
+    def _mlp(self, x, norm=None):
+        """The feed-forward on its own norm of ``x`` (``norm``: the
+        norm to take; the parallel block's is none, its input is
+        normed already)."""
+        norm = norm or self.ln2
         if self.ffn == 'experts':
-            return self.moe(self.ln2(x))[0]
+            return self.moe(norm(x))[0]
         with device_scope('lm.mlp'):
             if self.ffn == 'gated':
-                return self.mlp(self.ln2(x))
-            return self.mlp_out(nn.gelu(self.mlp_in(self.ln2(x))))
+                return self.mlp(norm(x))
+            return self.mlp_out(nn.gelu(self.mlp_in(norm(x))))
+
+    def _both(self, x, mixer):
+        """The block around ``mixer`` (normed input -> branch output):
+        two residuals one after the other, or the parallel form."""
+        if self.parallel:
+            h = self.ln1(x)
+            return x + mixer(h) + self._mlp(h, norm=lambda u: u)
+        x = self._around('attn', x, lambda u: mixer(self.ln1(u)))
+        return self._around('ffn', x, self._mlp)
 
     def __call__(self, x, attn_mask=None, segment_ids=None,
                  deterministic=False, dropout_seed=None):
-        def mixer(u):
-            h = self.ln1(u)
+        def mixer(h):
             if self.mixer == 'latent':
                 return self.attn(h)
             return self.attn(h, h, h, attn_mask, segment_ids=segment_ids,
                              deterministic=deterministic,
                              dropout_seed=dropout_seed)
-        x = self._around('attn', x, mixer)
-        return self._around('ffn', x, self._mlp)
+        return self._both(x, mixer)
 
     def _cached(self, method, x, cache, layer):
         """``prefill`` / ``decode`` share this: the mixer's cached entry
-        point inside the first residual, the feed-forward in the
-        second."""
+        point in the attention branch."""
         held = [cache]
 
-        def mixer(u):
-            h = self.ln1(u)
+        def mixer(h):
             step = getattr(self.attn, method)
             if self.mixer == 'latent':
                 held[0], a = step(h, held[0], layer)
@@ -195,8 +225,8 @@ class TransformerBlock(nn.Module):
                                   **({} if layer is None
                                      else {'layer': layer}))
             return a
-        x = self._around('attn', x, mixer)
-        return held[0], self._around('ffn', x, self._mlp)
+        x = self._both(x, mixer)
+        return held[0], x
 
     def prefill(self, x, cache, layer=None):
         # layer: a latent mixer's cache is layer-stacked in prefill too.
@@ -303,22 +333,44 @@ class TransformerStack(nn.Module):
     remat: bool = False
     remat_policy: Optional[str] = None
     # The block's choices (TransformerBlock's norm / mixer / ffn /
-    # residual fields, as a dict), and a stack of two layer kinds: the
-    # first ``dense_prefix`` blocks are built with ``prefix_kwargs``
-    # over ``block_kwargs`` (a model's leading dense layers before its
-    # expert layers). Such a stack, and any with a latent mixer or an
-    # expert feed-forward, runs unrolled (``scan_layers=False``).
+    # residual / parallel fields, as a dict), and the KIND of each
+    # layer: ``layer_kinds`` names the kinds, each with what it
+    # overrides of ``block_kwargs`` (its ``'attn_kwargs'`` entry is laid
+    # over the stack's ``attn_kwargs``, not in place of it), and
+    # ``layer_pattern`` gives the layers' kinds in order — one name a
+    # layer, or one period of names that the depth repeats (three window
+    # layers then a full one; a model's leading dense layers before its
+    # expert layers). A stack of more than one kind, and any with a
+    # latent mixer or an expert feed-forward, runs unrolled
+    # (``scan_layers=False``); its caches are a list, each layer's of
+    # its own kind's geometry.
     block_kwargs: Any = None
-    dense_prefix: int = 0
-    prefix_kwargs: Any = None
+    layer_kinds: Any = None
+    layer_pattern: Any = None
 
-    def _block(self, name, **overrides):
+    def _layer_kwargs(self, i):
+        """Layer ``i``'s ``(attn_kwargs, block_kwargs)``: the stack's,
+        with what the layer's kind overrides laid over them."""
+        attn = dict(self.attn_kwargs or {})
+        block = dict(self.block_kwargs or {})
+        if self.layer_pattern:
+            over = dict(self.layer_kinds[self.layer_pattern[
+                i % len(self.layer_pattern)]] or {})
+            attn.update(over.pop('attn_kwargs', None) or {})
+            block.update(over)
+        return attn, block
+
+    def _block(self, name, i):
+        attn, block = self._layer_kwargs(i)
         return TransformerBlock(
             dim=self.dim, num_heads=self.num_heads,
             mlp_ratio=self.mlp_ratio, axis_name=self.axis_name,
             dtype=self.dtype, weight_quant=self.weight_quant,
-            attn_kwargs=self.attn_kwargs, name=name,
-            **{**(self.block_kwargs or {}), **overrides})
+            attn_kwargs=attn, name=name, **block)
+
+    @property
+    def _mixed(self):
+        return len(set(self.layer_pattern or ())) > 1
 
     @property
     def _latent(self):
@@ -334,22 +386,28 @@ class TransformerStack(nn.Module):
                 f'remat_policy {self.remat_policy!r} is not a '
                 f'jax.checkpoint_policies name')
         kw = self.block_kwargs or {}
-        if self.scan_layers and (self.dense_prefix or self._latent
+        if self.layer_pattern:
+            unknown = set(self.layer_pattern) - set(self.layer_kinds or {})
+            if unknown or self.n_layers % len(self.layer_pattern):
+                raise ValueError(
+                    f'layer_pattern {tuple(self.layer_pattern)} must name '
+                    f'kinds of layer_kinds '
+                    f'{sorted(self.layer_kinds or {})} and divide '
+                    f'n_layers {self.n_layers}')
+        if self.scan_layers and (self._mixed or self._latent
                                  or kw.get('ffn') == 'experts'):
             # XLA's grouped-matmul kernel takes an expert layer's
             # weights whole, so nn.scan's slice of layer-stacked experts
             # is a copy of them a layer a token (21.6 of a 36.6 ms step;
-            # chip, PR 26); a scan over two layer kinds would be two
-            # scans; the latent cache is carried from block to block.
-            raise ValueError("a dense prefix, mixer='latent' and "
-                             "ffn='experts' run unrolled: pass "
+            # chip, PR 26); a scan over several layer kinds would be a
+            # scan over periods; the latent cache is carried from block
+            # to block.
+            raise ValueError("more than one layer kind, mixer='latent' "
+                             "and ffn='experts' run unrolled: pass "
                              'scan_layers=False')
         if not self.scan_layers:
-            self.blocks = [
-                self._block(f'block_{i}', **(
-                    (self.prefix_kwargs or {}) if i < self.dense_prefix
-                    else {}))
-                for i in range(self.n_layers)]
+            self.blocks = [self._block(f'block_{i}', i)
+                           for i in range(self.n_layers)]
             return
         core = _ScanStackCore
         if self.remat:
@@ -373,8 +431,8 @@ class TransformerStack(nn.Module):
             })(dim=self.dim, num_heads=self.num_heads,
                mlp_ratio=self.mlp_ratio, axis_name=self.axis_name,
                dtype=self.dtype, weight_quant=self.weight_quant,
-               attn_kwargs=self.attn_kwargs,
-               block_kwargs=self.block_kwargs, name='layers')
+               attn_kwargs=self._layer_kwargs(0)[0],
+               block_kwargs=self._layer_kwargs(0)[1], name='layers')
 
     def __call__(self, keys, queries, values, attn_mask=None,
                  segment_ids=None, deterministic=False,
@@ -395,27 +453,26 @@ class TransformerStack(nn.Module):
             return x
 
     def make_decode_caches(self, batch, t_max, dtype=None):
-        # Plain field arithmetic (no proto Module: flax would try to
-        # register it as a child of this one) — same layout rule as
-        # DistributedDotProductAttn.make_decode_cache. Scanned stacks
-        # get ONE cache pytree with a leading layer axis (prefill's
-        # scanned input, decode's loop carry); unrolled stacks a list.
-        from distributed_dot_product_tpu.models.decode import init_cache
-        kw = dict(self.attn_kwargs or {})
+        # Plain field arithmetic, no ``apply``: each layer's cache is
+        # what ITS attention module builds (``parent=None`` keeps flax
+        # from adopting the throwaway as a child of this one) — a window
+        # kind's ring beside a full kind's slab. Scanned stacks get ONE
+        # cache pytree with a leading layer axis (prefill's scanned
+        # input, decode's loop carry); unrolled stacks a list.
         if self._latent:
             # ONE layer-stacked buffer: every block addresses its own
             # layer of it.
+            kw = self._layer_kwargs(0)[0]
             return init_latent_cache(
                 self.n_layers, batch, t_max,
                 kw['kv_rank'] + kw['rope_dim'],
                 dtype or kw.get('dtype') or self.dtype or jnp.float32)
-        kv_heads = kw.get('num_kv_heads') or self.num_heads
-        head_dim = self.dim // self.num_heads
-        caches = [init_cache(batch, kv_heads, t_max, head_dim,
-                             dtype=(dtype or kw.get('dtype') or self.dtype
-                                    or jnp.float32),
-                             qk_quant=kw.get('qk_quant'))
-                  for _ in range(self.n_layers)]
+        caches = [DistributedDotProductAttn(
+            num_heads=self.num_heads, parent=None,
+            **{'key_dim': self.dim, 'dtype': self.dtype,
+               **self._layer_kwargs(i)[0]}
+        ).make_decode_cache(batch, t_max, dtype=dtype)
+            for i in range(self.n_layers)]
         if self.scan_layers:
             return jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
         return caches

@@ -82,6 +82,12 @@ DEVICE_SCOPES = {
                          'the fused dq/dk/dv kernel that walks as it does',
     'ops.flash_decode': 'the fused Pallas decode step kernel (append + '
                         'attend, any number of new rows)',
+    'ops.flash_decode_ring': 'the same kernel in its ring mode, on a '
+                             'window layer\'s recycled cache (append '
+                             'column and valid interval apart); opened '
+                             'INSIDE ops.flash_decode, so a reader that '
+                             'knows only that name still takes it for '
+                             'the decode kernel',
     'ops.mla_decode': 'the same kernel in its latent mode: one buffer of '
                       'compressed rows appended and streamed once, all '
                       'heads the rows of one score matmul, values the '

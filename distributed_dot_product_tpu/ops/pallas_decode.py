@@ -78,6 +78,7 @@ kernels (``interpret=None`` auto-selects), so the CPU tier-1 suite
 covers the identical code path.
 """
 
+import contextlib
 import math
 from typing import NamedTuple
 
@@ -269,9 +270,21 @@ def _write_tile(ki, ap, nn, geom, t_max):
                      jnp.clip(ki * (geom.block_k // wr), first, last), 0)
 
 
+def _ring_hits(ki, bk, head, span, t_max):
+    """Does K split ``ki`` of a ring of ``t_max`` columns hold one of
+    the ``span`` valid columns that end at column ``head`` (going back,
+    wrapping below column 0 onto the ring's end)? ONE definition for the
+    kernel's block skip and the stream index map, which must agree."""
+    lo = head - span + 1
+    return jnp.where(
+        lo >= 0,
+        jnp.logical_and(ki * bk <= head, ki * bk + bk - 1 >= lo),
+        jnp.logical_or(ki * bk <= head, ki * bk + bk - 1 >= lo + t_max))
+
+
 def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                         quantized, has_alibi, paged=False, stacked=False,
-                        latent_v=None):
+                        latent_v=None, ring=False):
     """Kernel body; refs are ordered to match ``flash_decode``'s spec
     list below. Grid = (B·H_kv / hb, ns) with the K split innermost:
     one step holds ``hb = geom.heads`` KV heads of ONE slot (every
@@ -321,12 +334,22 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
     LATENT (``latent_v``): there is ONE buffer. The values are the first
     ``latent_v`` columns of the very block the scores were taken from,
     so the V refs (new rows, cache in, cache out) are absent and every
-    read of them below is a static lane slice of the K ones."""
+    read of them below is a static lane slice of the K ones.
+
+    RING (``ring``): the buffer's columns are a ring, not positions.
+    ``vt`` is the column of the newest valid row (the append column)
+    and a fourth prefetched vector ``span`` the number of valid rows
+    ending there, cyclically; the two predicates that speak positions —
+    which splits hold a valid column (``run``) and which columns of a
+    split are valid (``masked``) — ask the cyclic interval instead.
+    Everything else (scores, substitution, online softmax, write-back)
+    is the body above, unchanged: a column is a column."""
     latent = latent_v is not None
     hb, bk, wr = geom.heads, geom.block_k, geom.write_rows
     per_slot = h_kv // hb                       # grid rows a slot
 
-    def kernel_body(vt_ref, ap_ref, nn_ref, *refs, pt_ref=None):
+    def kernel_body(vt_ref, ap_ref, nn_ref, *refs, pt_ref=None,
+                    span_ref=None):
         b = pl.program_id(0)
         ki = pl.program_id(1)
         br = b // per_slot                      # cache batch row
@@ -385,9 +408,14 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
         # with a window — wholly before row 0's lookback (later rows
         # look back from later positions, so row 0's bound is the
         # earliest column any row can attend).
-        run = ki * bk <= vt + (n - 1)
-        if window is not None:
-            run = jnp.logical_and(run, ki * bk + bk - 1 > vt - window)
+        if ring:
+            span = span_ref[br]
+            run = _ring_hits(ki, bk, vt, span, ns * bk)
+        else:
+            run = ki * bk <= vt + (n - 1)
+            if window is not None:
+                run = jnp.logical_and(run,
+                                      ki * bk + bk - 1 > vt - window)
         if pt_ref is not None:
             # Paged: only score pages this table actually holds — a −1
             # ordinal streams the sink (see flash_decode's index-map
@@ -404,10 +432,18 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
         def score_split(substitute):
             cols = (ki * bk
                     + jax.lax.broadcasted_iota(jnp.int32, (g_pad, bk), 1))
-            rel = cols - vt - jrow                # ≤ 0 on valid columns
-            masked = rel > 0
-            if window is not None:
-                masked = jnp.logical_or(masked, rel <= -window)
+            if ring:
+                # How far behind the newest row a column lies, around
+                # the ring; every column nearer than span is in-window
+                # by construction (the caller's span never exceeds it).
+                back = vt - cols
+                back = jnp.where(back < 0, back + ns * bk, back)
+                masked = back >= span
+            else:
+                rel = cols - vt - jrow            # ≤ 0 on valid columns
+                masked = rel > 0
+                if window is not None:
+                    masked = jnp.logical_or(masked, rel <= -window)
             relf = rel.astype(jnp.float32) if has_alibi else None
             for h in range(hb):
                 s = scores(h, score_ref[h],
@@ -495,6 +531,11 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
             kernel_body(vt_ref, ap_ref, nn_ref, *refs)
 
         return kernel_stacked
+    if ring:
+        def kernel_ring(vt_ref, ap_ref, nn_ref, span_ref, *refs):
+            kernel_body(vt_ref, ap_ref, nn_ref, *refs, span_ref=span_ref)
+
+        return kernel_ring
     if not paged:
         return kernel_body
 
@@ -508,7 +549,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
                  *, n_new=None, page_table=None, layer=None, k_q=None,
                  k_scale=None, scale=None, window=None, alibi_slopes=None,
                  qk_quant=None, interpret=None, block_k=None,
-                 partials=False, latent_v=None):
+                 partials=False, latent_v=None, ring_span=None):
     """One fused decode step: in-place cache append + masked online-
     softmax attention of each slot's queries against its own prefix.
 
@@ -596,6 +637,23 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     scope are named ``mla_decode`` / ``ops.mla_decode``. Not with
     ``page_table`` or ``qk_quant``.
 
+    ``ring_span (B,) int32``: RING mode, for a window layer's recycled
+    cache (``models.decode.RingCache``), whose ``t_max`` columns hold
+    position ``p`` at column ``p mod t_max``. The append column and the
+    valid rows are then two things: ``append_at`` is the column the new
+    row lands in, ``valid_to`` the column of the newest row the query
+    attends (the same column where the slot appends), and
+    ``ring_span[i]`` the number of valid rows ending there, going back
+    around the ring — ``min(length + 1, window)`` of a decode step, so
+    every one of them is in the window and no position is compared;
+    rows the ring has not filled yet, and rows a reset left behind past
+    the span, are masked. Splits that hold no valid column are neither
+    scored nor streamed. Single-token (``k == 1``), not with ``window``
+    (the span is the window), ``alibi_slopes``, ``page_table``,
+    ``layer``, ``qk_quant`` or ``latent_v``. The same kernel body; the
+    Pallas program is named ``flash_decode_ring`` and its device scope
+    ``ops.flash_decode_ring``, opened inside ``ops.flash_decode``.
+
     Returns ``(out, cache_k, cache_v, k_q, k_scale)`` with
     ``out (B, H, k, dv)`` in ``cache_v.dtype`` — or, with
     ``partials=True``, ``((num, m, l), cache_k, cache_v, k_q, k_scale)``
@@ -621,6 +679,13 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         dv, v_dtype = latent_v, cache_k.dtype
     else:
         dv, v_dtype = cache_v.shape[-1], cache_v.dtype
+    ring = ring_span is not None
+    if ring and (n != 1 or window is not None or latent or paged or stacked
+                 or alibi_slopes is not None or qk_quant is not None):
+        raise ValueError(
+            'flash_decode: ring_span is single-token and masks by the '
+            'ring\'s valid interval alone: no window, alibi_slopes, '
+            'page_table, layer, qk_quant or latent_v with it')
     if stacked and paged:
         raise ValueError('flash_decode: layer addresses a layer-stacked '
                          'slab cache; a paged pool has no layer axis')
@@ -803,9 +868,23 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         # run of rows (``lay`` is empty otherwise) — the same kind of
         # redirect the page table does above.
         def _row(bi, lay):
-            return bi + lay[0][0] if lay else bi
+            return bi + lay[0][0] if lay and not ring else bi
+
+        def _ring_blk(bi, ki, vt, span):
+            # Split ki where it holds a valid column of the slot's
+            # cyclic interval; else a split that does and is resident
+            # or next (the last one before ki, else the first), so a
+            # split that is skipped in-kernel costs no DMA either.
+            br = bi // per_slot
+            head, lo = vt[br], vt[br] - span[br] + 1
+            near = jnp.where(ki * bk > head, head // bk,
+                             jnp.maximum(lo, 0) // bk)
+            return jnp.where(_ring_hits(ki, bk, head, span[br], t_max),
+                             ki, near)
 
         def stream_idx(bi, ki, vt, ap, nn, *lay):
+            if ring:
+                return (bi, _ring_blk(bi, ki, vt, lay[0]), 0)
             return (_row(bi, lay), _stream_blk(bi, ki, vt), 0)
 
         def write_idx(bi, ki, vt, ap, nn, *lay):
@@ -898,6 +977,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     elif stacked:
         prefetch += ((jnp.asarray(layer, jnp.int32)
                       * (nb // hb)).reshape(1),)
+    elif ring:
+        prefetch += (jnp.asarray(ring_span, jnp.int32),)
     n_prefetch = len(prefetch)
     aliases = {n_prefetch + k_in_pos: 3}
     if not latent:
@@ -914,9 +995,17 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
 
     kernel = _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                                  quantized, has_alibi, paged=paged,
-                                 stacked=stacked, latent_v=latent_v)
+                                 stacked=stacked, latent_v=latent_v,
+                                 ring=ring)
     name = 'mla_decode' if latent else 'flash_decode'
-    with device_scope(f'ops.{name}'):
+    # The ring mode's scope opens INSIDE the kernel's own: a reader that
+    # knows only ops.flash_decode still counts it as the decode kernel.
+    inner = contextlib.nullcontext()
+    if ring:
+        name, inner = 'flash_decode_ring', device_scope(
+            'ops.flash_decode_ring')
+    with device_scope('ops.mla_decode' if latent
+                      else 'ops.flash_decode'), inner:
         outs = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(

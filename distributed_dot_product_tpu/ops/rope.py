@@ -99,14 +99,24 @@ def yarn_inv_freq(dim, *, base=10000.0, factor=1.0, original_max=4096,
 def rope_interleaved(x, positions, inv_freq, dtype=jnp.float32):
     """Rotary embedding over INTERLEAVED pairs: features ``(2i, 2i+1)``
     of ``x (..., T, d)`` turn by ``positions (..., T) * inv_freq[i]``
-    and stay where they were (the layout DeepSeek's checkpoints keep
-    their rotary dims in; dot products equal the half-split form's on
-    the same pairs)."""
+    and stay where they were (the layout DeepSeek's and Cohere's
+    checkpoints keep their rotary dims in; dot products equal the
+    half-split form's on the same pairs).
+
+    Written as ``x · cos + swap(x) · (∓sin)`` with ``swap`` the exchange
+    inside each pair (a flip of the size-2 axis), never as the strided
+    slices ``x[..., 0::2]`` / ``x[..., 1::2]``: XLA moves a slice of a
+    matmul's result into the matmul, and where ``x`` is a projection's
+    output that re-lays the WEIGHT out by even and odd columns on every
+    call (two 134 MB copies a layer a token at 4096 x 16384; AOT for a
+    v5e, PR 30). The products and sums are the same ones."""
     d = x.shape[-1]
     ang = (jnp.asarray(positions, dtype)[..., None]
            * jnp.asarray(inv_freq, dtype))
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    pairs = x.astype(dtype).reshape(*x.shape[:-1], d // 2, 2)
-    a, b = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
+    cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)
+    sin = jnp.repeat(jnp.sin(ang), 2, axis=-1) * jnp.tile(
+        jnp.asarray([-1.0, 1.0], dtype), d // 2)
+    xf = x.astype(dtype)
+    swapped = jnp.flip(xf.reshape(*x.shape[:-1], d // 2, 2),
+                       axis=-1).reshape(x.shape)
+    return (xf * cos + swapped * sin).astype(x.dtype)

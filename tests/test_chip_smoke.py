@@ -80,7 +80,9 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     assert lines[-1] == {'ok': False, 'device': lines[0]['device']}
     phases = {rec['phase']: rec for rec in lines if 'phase' in rec}
     assert set(phases) == {'train', 'generate', 'generate_latent',
-                           'serve'}
+                           'generate_mixed', 'serve'}
+    # both kernel modes side by side, off the chip both through XLA
+    assert phases['generate_mixed']['mixed_caches'] == ['layer', 'ring']
     for name, rec in phases.items():
         assert 'error' not in rec, (name, rec.get('error'))
         failed = {k for k, v in rec['checks'].items() if not v}

@@ -34,6 +34,10 @@ DECODE_SCOPES = ['ops.flash_decode', 'lm.attn_proj', 'lm.mlp', 'lm.embed',
 LATENT_SCOPES = ['ops.mla_decode', 'lm.attn_proj', 'lm.mlp', 'lm.moe_route',
                  'lm.moe_experts', 'lm.hc', 'lm.embed', 'lm.head',
                  'lm.stack_carry']
+# The decode step of a stack of window and full attention layers: the
+# window layers' kernel step is the ring mode.
+MIXED_SCOPES = ['ops.flash_decode', 'ops.flash_decode_ring', 'lm.attn_proj',
+                'lm.mlp', 'lm.embed', 'lm.head', 'lm.stack_carry']
 
 
 def tiny_lm(**attn_kwargs):
@@ -106,6 +110,25 @@ def latent_op_names():
     return op_names(jax.jit(step).lower(params, tokens, caches).compile())
 
 
+@pytest.fixture(scope='module')
+def mixed_op_names():
+    model = TransformerLM(
+        vocab_size=64, dim=32, num_heads=2, n_layers=2, scan_layers=False,
+        attn_kwargs=dict(distributed=False, decode_impl='kernel'),
+        layer_kinds={'window': {'attn_kwargs': {'window': 16,
+                                                'ring_cache': 32}},
+                     'full': {}},
+        layer_pattern=('window', 'full'))
+    tokens = jnp.zeros((2, 1), jnp.int32)
+    params = model.init(jax.random.key(0), jnp.zeros((2, 8), jnp.int32))
+    caches = model.make_decode_caches(2, 128)
+
+    def step(p, tok, c):
+        return model.apply(p, tok, c, method='decode')
+
+    return op_names(jax.jit(step).lower(params, tokens, caches).compile())
+
+
 def opened(scope, names):
     return any(f'/{scope}/' in f'/{name}/' for name in names)
 
@@ -125,6 +148,25 @@ def test_latent_decode_step_opens(scope, latent_op_names):
     assert opened(scope, latent_op_names)
 
 
+@pytest.mark.parametrize('scope', MIXED_SCOPES)
+def test_mixed_decode_step_opens(scope, mixed_op_names):
+    assert opened(scope, mixed_op_names)
+
+
+def test_ring_mode_opens_inside_the_decode_kernels_scope(mixed_op_names):
+    """A reader that knows only ``ops.flash_decode`` (the benchmark's
+    accepted patterns) takes the ring mode for the decode kernel, never
+    for the projections around it; the full layer's step has no ring
+    scope."""
+    assert 'ops.flash_decode_ring' in DEVICE_SCOPES
+    ring = [n for n in mixed_op_names if 'ops.flash_decode_ring' in n]
+    assert ring and all(
+        '/ops.flash_decode/ops.flash_decode_ring/' in n for n in ring)
+    assert any('/ops.flash_decode/' in f'{n}/'
+               and 'ops.flash_decode_ring' not in n
+               for n in mixed_op_names)
+
+
 def test_latent_kernel_is_outside_the_projection_scope(latent_op_names):
     """The benchmark's accepted reader takes ``lm.attn_proj`` for the
     projections alone: the latent kernel's scope is its sibling."""
@@ -134,7 +176,7 @@ def test_latent_kernel_is_outside_the_projection_scope(latent_op_names):
 
 def test_the_steps_cover_the_vocabulary():
     assert (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(LATENT_SCOPES)
-            == set(DEVICE_SCOPES))
+            | set(MIXED_SCOPES) == set(DEVICE_SCOPES))
 
 
 def test_unknown_scope_raises():
@@ -194,6 +236,20 @@ def test_decode_build_carries_its_kernel_name():
 
     assert kernel_names(step, new, new, new, cache, cache) == [
         'flash_decode']
+
+
+def test_ring_decode_build_carries_its_kernel_name():
+    cache = jnp.zeros((1, 2, 128, 16), jnp.float32)
+    new = jnp.zeros((1, 2, 1, 16), jnp.float32)
+    at = jnp.zeros((1,), jnp.int32)
+
+    def step(q, k, v, ck, cv):
+        return pallas_decode.flash_decode(q, k, v, ck, cv, at, at,
+                                          ring_span=at + 1,
+                                          interpret=True)[0]
+
+    assert kernel_names(step, new, new, new, cache, cache) == [
+        'flash_decode_ring']
 
 
 def test_latent_decode_build_carries_its_kernel_name():

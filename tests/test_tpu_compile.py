@@ -470,3 +470,71 @@ def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 3 * layer_bytes
     assert mem.temp_size_in_bytes < experts_bytes
+
+
+def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
+                                                         monkeypatch):
+    """The token step of the window + full attention stack at the
+    published widths and the traffic of ``command-a-plus.decode-64k``
+    (4 layers, 12 sessions, a 5120-column ring a window layer beside a
+    66560-row slab), caches donated: every layer's step resolves to the
+    kernel, the window layers' to its ring mode; nothing as large as one
+    window layer's K ring (126 MB: far under the full layer's 1.6 GB
+    cache, and under one 134 MB projection kernel too, which the
+    strided-slice rotary re-laid out a layer a token) is copied, sliced
+    or written back; every cache aliases the result; and arguments +
+    temporaries stay under 15.0 GiB."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_mixed as driver
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'command-a-plus-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-64k-x12.json')) as f:
+        traffic = json.load(f)
+    model = driver.build_lm(cfg)
+    params = {'params': {}}
+    for path, (shape, _) in driver.shapes(cfg).items():
+        node = params['params']
+        for name in path[:-1]:
+            node = node.setdefault(name, {})
+        node[path[-1]] = jax.ShapeDtypeStruct(
+            shape, jnp.float32 if path[-1] in driver.FLOAT32_LEAVES
+            else jnp.bfloat16)
+    sessions, t_max = traffic['sessions'], traffic['t_max']
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(sessions, t_max))
+    assert [c.k.shape for c in caches] == 3 * [
+        (sessions, 8, 5120, 128)] + [(sessions, 8, t_max, 128)]
+    stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
+    tok = jnp.zeros((sessions, 1), jnp.int32)
+    step = driver.make_programs(model, cfg)[2]
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        (params, tok, caches, stats))
+    with decode_impl_traces() as traces:
+        compiled = step.lower(*shapes).compile()
+    assert [(t['resolved'], t['cache']) for t in traces] == 3 * [
+        ('kernel', 'ring')] + [('kernel', 'layer')]
+    assert {tuple(t['step'].items()) for t in traces} == {
+        (('heads', 8), ('block_k', 1024), ('bytes', 4 << 20))}
+    hlo = compiled.as_text()
+    assert hlo.count('flash_decode_ring') and 'ragged-dot' in hlo
+    ring_k_bytes = sessions * 8 * 5120 * 128 * 2
+    assert _cache_sized_moves(hlo, ring_k_bytes) == []
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < ring_k_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            ) <= 15.0 * 2 ** 30
