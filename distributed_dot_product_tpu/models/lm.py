@@ -76,7 +76,11 @@ class TransformerLM(nn.Module):
     defaulted in (a language model without causality is an error — pass
     them explicitly to override the other two). ``scan_layers``/
     ``remat``/``remat_policy`` forward to the stack (deep models compile
-    O(1) in depth and fit backward memory per layer).
+    O(1) in depth and fit backward memory per layer; ``remat=True`` with
+    no policy keeps the flash kernel's output and logsumexp a layer —
+    ``(B, H, T, d_v)`` in the compute type + ``(B, H, T)`` float32 — and
+    rebuilds the rest; ``remat_policy='nothing_saveable'`` keeps
+    nothing).
 
     Call: ``apply(params, tokens (B, T/N int32), segment_ids=None,
     deterministic=False, dropout_seed=None) -> logits (B, T/N, vocab)``

@@ -25,8 +25,8 @@ depths) or — ``scan_layers=True`` — run as ONE ``nn.scan`` over a
 single block with layer-stacked parameters: trace/compile time is
 O(1) in depth, and the ``remat`` knob wraps the block in
 ``jax.checkpoint`` so backward score memory is one layer's, not the
-stack's (``remat_policy`` names a ``jax.checkpoint_policies`` entry,
-e.g. ``'dots_saveable'``, for partial rematerialization).
+stack's (what a rematted layer keeps, and ``remat_policy``:
+:class:`TransformerStack`).
 """
 
 from typing import Any, Optional
@@ -47,6 +47,9 @@ from distributed_dot_product_tpu.models.latent import (
 )
 from distributed_dot_product_tpu.models.moe import GatedMLP, SparseExperts
 from distributed_dot_product_tpu.obs.spans import device_scope
+from distributed_dot_product_tpu.ops.pallas_attention import (
+    FLASH_RESIDUAL_NAMES,
+)
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
 __all__ = ['TransformerBlock', 'TransformerStack']
@@ -317,8 +320,19 @@ class TransformerStack(nn.Module):
     ``remat=True`` (scan only) wraps the block in ``jax.checkpoint`` so
     the backward rematerializes one layer at a time — activation memory
     for the stack drops from O(n_layers) to O(1) layers plus the scan
-    carry; ``remat_policy`` selects a ``jax.checkpoint_policies`` name
-    (e.g. ``'dots_saveable'``) for partial remat."""
+    carry and what the policy keeps a layer. With no ``remat_policy``
+    that is the flash route's two residuals
+    (``save_only_these_names(*FLASH_RESIDUAL_NAMES)``): one
+    ``(B, H, T, d_v)`` tensor in the compute type and one ``(B, H, T)``
+    float32 a layer (136 MB + 2 MB at 32 heads x 16384 x 128 bfloat16),
+    for which the backward runs the O(T²) forward kernel once a step,
+    not twice; the projections, norms and MLP are still rebuilt. Only
+    ``flash_attention``'s differentiated forward emits the names (the
+    flash route, ulysses' local attention): the ``'full'`` and
+    ``'online'`` paths keep nothing. ``remat_policy`` selects a ``jax.checkpoint_policies`` name
+    in its place — ``'nothing_saveable'`` is full rematerialization,
+    for a run at the memory limit; ``'dots_saveable'`` etc. mean what
+    they mean in JAX."""
     dim: int
     num_heads: int
     n_layers: int = 2
@@ -412,7 +426,9 @@ class TransformerStack(nn.Module):
         core = _ScanStackCore
         if self.remat:
             policy = (getattr(jax.checkpoint_policies, self.remat_policy)
-                      if self.remat_policy else None)
+                      if self.remat_policy else
+                      jax.checkpoint_policies.save_only_these_names(
+                          *FLASH_RESIDUAL_NAMES))
             # static_argnums indexes layer()'s args after self:
             # deterministic (a Python bool) is arg 4.
             core = nn.remat(core, policy=policy, prevent_cse=False,

@@ -59,6 +59,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 # pltpu is importable (pure Python) even off-TPU; the interpreter emulates
 # VMEM scratch on CPU.
@@ -66,7 +67,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributed_dot_product_tpu.obs.spans import device_scope
 
-__all__ = ['flash_attention', 'flash_bwd_traces']
+__all__ = ['flash_attention', 'flash_bwd_traces', 'FLASH_RESIDUAL_NAMES']
+
+# ``jax.ad_checkpoint.checkpoint_name`` tags of the two residuals the
+# differentiated forward computes itself: the output ``(*batch, Tq, d_v)``
+# and the row logsumexp ``(*batch, Tq)`` float32. Each costs O(T²·d) to
+# rebuild and O(T) to hold, so a ``jax.checkpoint`` around attention keeps
+# them with ``save_only_these_names(*FLASH_RESIDUAL_NAMES)`` and the
+# forward kernel is not run a second time (``TransformerStack``'s default).
+FLASH_RESIDUAL_NAMES = ('flash_out', 'flash_lse')
 
 _NEG_BIG = -0.7 * 3.4e38  # large-finite fp32; keeps exp()/VJP NaN-free
 
@@ -1910,6 +1919,12 @@ def _flash_fwd(q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k,
                                dropout_rate=dropout_rate,
                                dropout_seed=dropout_seed,
                                kv_offset=kv_offset)
+    # Identities unless a checkpoint policy names them. The NAMED ``out``
+    # is the primal output too, so nothing of the kernel stays live in a
+    # rematerialized forward that kept both; ``lse`` is the squeezed
+    # (*batch, Tq) form, not the kernel's lane-padded (nb, Tq_p, 1).
+    out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return out, (q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k,
                  pos_q, pos_k, alibi, dropout_seed, out, lse)
 

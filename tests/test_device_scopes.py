@@ -4,10 +4,12 @@ that applies shows up in the compiled programs' ``op_name``s, every
 Pallas build carries its kernel name, and a scope adds no operation."""
 
 import contextlib
+import functools
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 import pytest
 
@@ -18,7 +20,9 @@ from distributed_dot_product_tpu.obs.spans import (
     DEVICE_SCOPES, device_scope,
 )
 from distributed_dot_product_tpu.ops import pallas_attention, pallas_decode
-from distributed_dot_product_tpu.ops.pallas_attention import flash_attention
+from distributed_dot_product_tpu.ops.pallas_attention import (
+    FLASH_RESIDUAL_NAMES, flash_attention,
+)
 from distributed_dot_product_tpu.parallel.mesh import seq_mesh
 from distributed_dot_product_tpu import train
 
@@ -40,14 +44,15 @@ MIXED_SCOPES = ['ops.flash_decode', 'ops.flash_decode_ring', 'lm.attn_proj',
                 'lm.mlp', 'lm.embed', 'lm.head', 'lm.stack_carry']
 
 
-def tiny_lm(**attn_kwargs):
+def tiny_lm(remat_policy=None, **attn_kwargs):
     return TransformerLM(vocab_size=64, dim=32, num_heads=2, n_layers=2,
-                         remat=True, attn_kwargs=attn_kwargs or None)
+                         remat=True, remat_policy=remat_policy,
+                         attn_kwargs=attn_kwargs or None)
 
 
-def train_step_and_args(width=2):
-    model = tiny_lm()
-    tokens = jnp.zeros((1, 64), jnp.int32)
+def train_step_and_args(width=2, remat_policy=None, **attn_kwargs):
+    model = tiny_lm(remat_policy, **attn_kwargs)
+    tokens = jax.random.randint(jax.random.key(1), (1, 64), 0, 64)
     params = model.init(jax.random.key(0), tokens)
     optimizer = optax.adamw(1e-3)
     step = train.make_lm_train_step(model, optimizer, seq_mesh(width),
@@ -60,18 +65,23 @@ def op_names(compiled):
     return set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
 
 
-@pytest.fixture(scope='module')
-def train_op_names():
+@functools.cache
+def train_names(remat_policy):
     """The step as it runs (the flash backward fused: dq rides the dk/dv
     walk under ``ops.flash_bwd_dkv``) and, joined to it, the step with dq
     held past its VMEM budget: the split form, the one that opens
     ``ops.flash_bwd_dq``."""
-    step, args = train_step_and_args()
+    step, args = train_step_and_args(remat_policy=remat_policy)
     names = op_names(step.lower(*args).compile())
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(pallas_attention, '_FUSED_DQ_BYTES', 0)
-        step, args = train_step_and_args()
+        step, args = train_step_and_args(remat_policy=remat_policy)
         return names | op_names(step.lower(*args).compile())
+
+
+@pytest.fixture(scope='module')
+def train_op_names():
+    return train_names(None)
 
 
 @pytest.fixture(scope='module')
@@ -184,12 +194,22 @@ def test_unknown_scope_raises():
         device_scope('nope')
 
 
-def test_passes_show_in_op_names(train_op_names):
-    """What the trace reader tells the passes by."""
-    flash_fwd = [n for n in train_op_names if '/ops.flash_fwd/' in n]
-    assert any('rematted_computation' in n for n in flash_fwd)
+@pytest.mark.parametrize('remat_policy', [None, 'nothing_saveable'])
+def test_passes_show_in_op_names(remat_policy):
+    """What the trace reader tells the passes by. The stack's own remat
+    keeps the flash forward's output and logsumexp, so no flash forward
+    is rematerialized while the projections and the MLP are; under full
+    remat (``'nothing_saveable'``) it is, which is what the benchmark's
+    ``recompute`` reader finds on such a program."""
+    names = train_names(remat_policy)
+    flash_fwd = [n for n in names if '/ops.flash_fwd/' in n]
+    assert any('rematted_computation' in n for n in flash_fwd) == (
+        remat_policy == 'nothing_saveable')
+    for scope in ('lm.mlp', 'lm.attn_proj'):
+        assert any('rematted_computation' in n and f'/{scope}/' in n
+                   for n in names)
     assert any('transpose(jvp(' not in n and 'jvp(' in n for n in flash_fwd)
-    flash_bwd = [n for n in train_op_names if '/ops.flash_bwd_d' in n]
+    flash_bwd = [n for n in names if '/ops.flash_bwd_d' in n]
     assert {n.rsplit('/', 2)[-2] for n in flash_bwd} >= {
         'flash_bwd_fused', 'flash_bwd_dq', 'flash_bwd_dkv'}
     assert all('transpose(jvp(' in n for n in flash_bwd)
@@ -216,6 +236,51 @@ def flash_sum(**kw):
 ], ids=['fwd', 'grad', 'int8', 'bounded'])
 def test_flash_builds_carry_their_kernel_names(fn, expected):
     assert sorted(kernel_names(fn, Q)) == sorted(expected)
+
+
+def checkpoint_names(fn, *args):
+    return {eqn.params['name']
+            for eqn in _iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == 'name'}
+
+
+def assert_bitwise(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize('width', [1, 4])
+def test_remat_runs_the_flash_forward_once(width):
+    """The scanned stack's remat keeps the flash forward's two residuals
+    by their names, so the layer body holds ONE ``flash_fwd``; full remat
+    holds two. The same kernels on the same inputs: the step's loss,
+    parameters and optimizer state are bit for bit the same."""
+    kept, kept_args = train_step_and_args(width)
+    full, full_args = train_step_and_args(width, 'nothing_saveable')
+    assert kernel_names(kept, *kept_args) == [
+        'flash_fwd', 'flash_bwd_fused']
+    assert kernel_names(full, *full_args) == [
+        'flash_fwd', 'flash_fwd', 'flash_bwd_fused']
+    assert checkpoint_names(kept, *kept_args) == set(FLASH_RESIDUAL_NAMES)
+    assert_bitwise(kept(*kept_args), full(*full_args))
+
+
+@pytest.mark.parametrize('softmax_impl', ['online', 'full'])
+def test_remat_keeps_nothing_where_no_flash_forward_is_differentiated(
+        softmax_impl):
+    """Only ``flash_attention``'s differentiated forward emits the names
+    (the flash route, ulysses' local attention): the ring fold calls the
+    kernels inside its own rule and the full path has none, so such a
+    stack under the same default is the full-remat program, equation for
+    equation, and gives its outputs."""
+    kept, kept_args = train_step_and_args(softmax_impl=softmax_impl)
+    full, full_args = train_step_and_args(
+        remat_policy='nothing_saveable', softmax_impl=softmax_impl)
+    assert not checkpoint_names(kept, *kept_args)
+    assert (equations(jax.make_jaxpr(kept)(*kept_args).jaxpr)
+            == equations(jax.make_jaxpr(full)(*full_args).jaxpr))
+    assert_bitwise(kept(*kept_args), full(*full_args))
 
 
 def test_split_backward_build_carries_its_kernel_names(monkeypatch):

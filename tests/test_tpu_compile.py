@@ -538,3 +538,49 @@ def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes
             ) <= 15.0 * 2 ** 30
+
+
+@pytest.mark.parametrize('remat_policy, forwards', [
+    (None, 1), ('nothing_saveable', 2)], ids=['kept', 'full-remat'])
+def test_scanned_lm_train_step_runs_the_flash_forward_once(
+        chip, monkeypatch, remat_policy, forwards):
+    """The scanned, rematted LM train step at the MPT training cell's
+    shapes (2 layers x 16384 tokens, bfloat16 compute): the stack keeps
+    the flash forward's output and logsumexp, and XLA drops the
+    recompute's kernel with them — the compiled step holds ONE
+    ``flash_fwd`` custom call beside the fused backward, none of them
+    rematerialized, and the kept tensors ride the scan as stacked
+    ``(L, B, H, T, d)`` / ``(L, B, H, T)`` buffers (not as the kernel's
+    lane-padded ``(nb, T, 1)`` logsumexp). Full remat, by its name, holds
+    the forward twice."""
+    import optax
+    from distributed_dot_product_tpu import TransformerLM
+    from distributed_dot_product_tpu.parallel.mesh import seq_mesh
+    from distributed_dot_product_tpu.train import make_lm_train_step
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    t, layers = 16384, 2
+    model = TransformerLM(**{**LM, 'n_layers': layers}, dtype=jnp.bfloat16,
+                          scan_layers=True, remat=True,
+                          remat_policy=remat_policy,
+                          attn_kwargs=dict(use_rope=False,
+                                           alibi_slopes=_mpt_slopes(32)))
+    optimizer = optax.adamw(3e-4)
+    (device,) = chip.device_set
+    step = make_lm_train_step(model, optimizer,
+                              seq_mesh(1, devices=[device]), guard=False,
+                              loss_chunk=4096)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 128),
+                                                        jnp.int32)))
+    tok = jnp.zeros((1, t), jnp.int32)
+    hlo = _compile(chip, step, params,
+                   jax.eval_shape(optimizer.init, params),
+                   (tok, tok)).as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                       r'op_name="([^"]*)"', hlo)
+    fwd = [n for n in calls if n.endswith('flash_fwd/pallas_call')]
+    assert len(fwd) == forwards and len(calls) == forwards + 1
+    assert sum('rematted_computation' in n for n in fwd) == forwards - 1
+    for stacked in (f'bf16[{layers},1,32,{t},128]', f'f32[{layers},1,32,{t}]'):
+        assert (stacked in hlo) == (forwards == 1)
+    assert f'f32[{layers},32,{t},1]' not in hlo

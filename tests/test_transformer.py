@@ -137,10 +137,12 @@ def test_scanned_matches_unrolled():
                                atol=1e-6)
 
 
-@pytest.mark.parametrize('policy', [None, 'dots_saveable'])
+@pytest.mark.parametrize('policy',
+                         [None, 'nothing_saveable', 'dots_saveable'])
 def test_scanned_remat_matches_unrolled_grads(policy):
-    """remat (full or policy-guided) must not change outputs OR
-    gradients — only the backward's memory schedule."""
+    """remat (the flash residuals kept, full, or policy-guided) must not
+    change outputs OR gradients — only the backward's memory schedule.
+    The stack runs the flash route, so the default's names exist."""
     x = _x(5)
     unrolled = _scan_stack(dist=False, scan=False)
     params = unrolled.init(jax.random.key(0), x[:, :8], x[:, :8],
