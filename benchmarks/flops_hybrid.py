@@ -1,0 +1,89 @@
+"""Operations and bytes that the decode step of a stack of recurrent
+(Mamba-2), attention and latent-expert layers needs (the ``nemotron_h``
+configuration), from the configuration's shapes alone (``flops.py``'s
+rules: a multiply-add is two operations, only needed work is counted).
+Kept with the benchmark so that no PR that claims a gain can change the
+yardstick.
+"""
+
+BYTES = 2       # bfloat16 weights, K/V and convolution window
+STATE_BYTES = 4     # the recurrent state is float32
+
+
+def layer_counts(config):
+    """``{'M': recurrent, 'E': expert, '*': attention}`` layers of the
+    depth held."""
+    kinds = config['hybrid_override_pattern'][:config['num_hidden_layers']]
+    return {kind: kinds.count(kind) for kind in 'ME*'}
+
+
+def conv_channels(config):
+    return (config['mamba_num_heads'] * config['mamba_head_dim']
+            + 2 * config['n_groups'] * config['ssm_state_size'])
+
+
+def state_bytes(config):
+    """One session's state and convolution window in one recurrent
+    layer."""
+    elements = (config['mamba_num_heads'] * config['mamba_head_dim']
+                * config['ssm_state_size'])
+    window = (config['conv_kernel'] - 1) * conv_channels(config)
+    return elements * STATE_BYTES + window * BYTES
+
+
+def ssm_step(config, batch):
+    """The recurrent layers' pass over their states in one token step:
+    every state and window read once and written once; an element of
+    the state takes a multiply by the decay, a multiply-add of the outer
+    product and a multiply-add into the read against C."""
+    layers = layer_counts(config)['M']
+    elements = (config['mamba_num_heads'] * config['mamba_head_dim']
+                * config['ssm_state_size'])
+    return {'bytes': layers * batch * 2 * state_bytes(config),
+            'flops': layers * batch * 5 * elements}
+
+
+def attn_decode_step(config, batch, context):
+    """The attention layers' decode kernel: the new row attends itself
+    and all ``context`` rows before it; every K and V row read once for
+    its KV head's whole query group, and the new row written."""
+    layers = layer_counts(config)['*']
+    kv, heads = config['num_key_value_heads'], config['num_attention_heads']
+    d, rows = config['head_dim'], context + 1
+    return {'bytes': layers * batch * kv * 2 * d * BYTES * (rows + 1),
+            'flops': layers * batch * heads * 4 * d * rows}
+
+
+def expert_bytes(config):
+    """One routed expert's two matrices, in the latent."""
+    return (2 * config['moe_latent_size'] * config['moe_intermediate_size']
+            * BYTES)
+
+
+def experts_held(config):
+    lo, hi = config['experts_held']
+    return hi - lo
+
+
+def expected_distinct_held(config, tokens):
+    """Distinct HELD experts that ``tokens`` uniform top-k picks over
+    the router's whole width hit in one layer: ``held (1 - (1 -
+    k/E)^tokens)``."""
+    e = config['published']['n_routed_experts']
+    k = config['num_experts_per_tok']
+    return experts_held(config) * (1.0 - (1.0 - k / e) ** tokens)
+
+
+def cache_gib(caches):
+    """``{'full_gib', 'state_gib'}``: the bytes of the buffers that
+    ``make_decode_caches`` built, K and V of the layers whose cache
+    grows and state + window of the recurrent ones (a layer without a
+    mixer has None)."""
+    out = {'full_gib': 0.0, 'state_gib': 0.0}
+    for cache in caches:
+        if hasattr(cache, 'state'):
+            out['state_gib'] += (cache.state.nbytes
+                                 + cache.conv.nbytes) / 2.0 ** 30
+        elif cache is not None:
+            out['full_gib'] += (cache.k.nbytes + cache.v.nbytes) / 2.0 ** 30
+    return out

@@ -15,7 +15,7 @@ and full attention layers (the parallel block over sparse experts),
 whose step runs the kernel's slab mode and its ring mode side by side,
 so a ring-mode failure on a new runtime shows here before the benchmark.
 
-    python chip_smoke.py             # one chip: train, generate (x3), serve
+    python chip_smoke.py             # one chip: train, generate (x4), serve
     python chip_smoke.py --chips 4   # ONLY the cross-chip phases
     JAX_PLATFORMS=cpu python chip_smoke.py --tiny   # CPU rehearsal
 
@@ -60,7 +60,14 @@ FULL = dict(
     # layers' 2048-column ring, two K splits, and the first served
     # token wraps it
     mixed=dict(dim=1024, heads=8, kv_heads=2, window=512, ring=2048,
-               experts=8, expert_hidden=256, shared=2, layers=4))
+               experts=8, expert_hidden=256, shared=2, layers=4),
+    # generate_hybrid: a latent-expert, a Mamba-2 and an attention layer
+    # in one stack, each with ONE branch (the three kinds of
+    # benchmarks/configs/nemotron-3-super-serve at its head sizes): a
+    # fixed-size float32 state beside a slab and no cache
+    hybrid=dict(dim=1024, heads=8, kv_heads=2, ssm_heads=32,
+                ssm_head_dim=64, state=128, groups=8, experts=8,
+                expert_hidden=256, latent=256, shared=512))
 TINY = dict(
     vocab=128, dim=64, heads=2, layers=1,
     train_t=128, ref_t=32,
@@ -73,7 +80,10 @@ TINY = dict(
                 v=16, dense_hidden=96, experts=4, top_k=4,
                 expert_hidden=32, layers=3),
     mixed=dict(dim=64, heads=2, kv_heads=1, window=8, ring=16, experts=4,
-               expert_hidden=32, shared=2, layers=4))
+               expert_hidden=32, shared=2, layers=4),
+    hybrid=dict(dim=64, heads=2, kv_heads=1, ssm_heads=4, ssm_head_dim=16,
+                state=16, groups=2, experts=4, expert_hidden=32, latent=32,
+                shared=48))
 
 # bf16 tolerance, relative to the compared tensor's own scale: two paths
 # that are equal in exact arithmetic may differ by max|a - b| <=
@@ -510,6 +520,104 @@ def phase_generate_mixed(progs, cfg, seed):
     return {**rec, 'checks': checks}
 
 
+def hybrid_lm(cfg, **attn_kwargs):
+    """One latent-expert layer, one Mamba-2 layer and one attention
+    layer (``cfg['hybrid']``), each ``x + f(RMSNorm(x))``: no cache, a
+    ``StateCache`` and a slab side by side. Every expert is gated, as in
+    ``latent_lm``."""
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu import TransformerLM
+    c = cfg['hybrid']
+    return TransformerLM(
+        vocab_size=cfg['vocab'], dim=c['dim'], num_heads=c['heads'],
+        n_layers=3, dtype=jnp.bfloat16, scan_layers=False,
+        tie_embeddings=False,
+        attn_kwargs={'num_kv_heads': c['kv_heads'], 'use_rope': False,
+                     **attn_kwargs},
+        block_kwargs={'norm': 'rmsnorm'},
+        layer_kinds={
+            'E': {'mixer': 'none', 'ffn': 'experts', 'ffn_kwargs': {
+                'n_experts': c['experts'], 'top_k': c['experts'],
+                'hidden': c['expert_hidden'], 'latent': c['latent'],
+                'shared_hidden': c['shared'], 'expert_form': 'plain',
+                'activation': 'relu2'}},
+            'M': {'mixer': 'ssm', 'ffn': 'none', 'ssm_kwargs': {
+                'heads': c['ssm_heads'], 'head_dim': c['ssm_head_dim'],
+                'state': c['state'], 'groups': c['groups']}},
+            '*': {'mixer': 'attention', 'ffn': 'none'}},
+        layer_pattern=('E', 'M', '*'))
+
+
+def phase_generate_hybrid(progs, cfg, seed):
+    """The state path end to end: a prompt prefilled through the chunked
+    scan into a ``StateCache`` beside a slab, the state SNAPSHOTTED at
+    the prompt's end, a greedy request, the state restored and the
+    slab's length set back, and the request again — which must read
+    what the first did, bit for bit — with the attention layer's step
+    on the kernel."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces, restore_states, snapshot_states,
+    )
+    model = hybrid_lm(cfg)
+    n, steps, t_max = cfg['prompt'], cfg['new_tokens'], cfg['gen_t_max']
+    params = {'params': model.init(
+        jax.random.key(seed + 7), jnp.zeros((1, 16), 'int32'))['params']}
+    prompt = jax.random.randint(jax.random.key(seed + 2), (1, n), 0,
+                                cfg['vocab'], dtype='int32')
+    prefill = jax.jit(lambda p, t, c: model.apply(p, t, c,
+                                                  method='prefill'))
+    step = jax.jit(lambda p, t, c: model.apply(p, t, c, method='decode'),
+                   donate_argnums=(2,))
+
+    def reset(caches, taken):
+        return [c._replace(length=jnp.asarray(n, jnp.int32))
+                if hasattr(c, 'length') else c
+                for c in restore_states(caches, taken)]
+    reset = jax.jit(reset, donate_argnums=(0,))
+    caches = model.make_decode_caches(1, t_max)
+    kinds = [type(c).__name__ for c in caches]
+    with decode_impl_traces() as traces:
+        progs.compile('hybrid.prefill', prefill, params, prompt, caches,
+                      pallas=True)
+        progs.compile('hybrid.decode', step, params, prompt[:, :1],
+                      caches, pallas=True)
+    caches, logits = prefill(params, prompt, caches)
+    first = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+    taken = snapshot_states(caches)
+
+    def request(caches):
+        tok, out = first, []
+        for _ in range(steps):
+            caches, logits = step(params, tok, caches)
+            out.append(np.asarray(logits[:, -1], np.float32))
+            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        return caches, np.stack(out)
+    caches, once = request(caches)
+    moved = float(np.max(np.abs(
+        np.asarray(caches[1].state) - np.asarray(taken[1].state))))
+    caches, again = request(reset(caches, taken))
+    resolved = sorted({t['resolved'] for t in traces})
+    return {
+        'hybrid_caches': kinds,
+        'hybrid_resolved_impl': resolved,
+        'hybrid_state_moved_by_a_request': moved,
+        'hybrid_logits_max_abs': float(np.max(np.abs(once))),
+        'checks': {
+            'hybrid.cache_kinds': kinds == ['NoneType', 'StateCache',
+                                            'DecodeCache'],
+            'hybrid.resolved_kernel': resolved == ['kernel'],
+            'hybrid.logits_finite': bool(np.all(np.isfinite(once))),
+            'hybrid.a_request_moves_the_state': moved > 0,
+            'hybrid.restored_request_agrees': bool(
+                np.array_equal(once, again)),
+        }}
+
+
 # -- one chip: serve -----------------------------------------------------
 
 def engine(cfg, seed, **kw):
@@ -879,6 +987,8 @@ def main(argv=None):
                run_phase('generate_latent', phase_generate_latent, cfg,
                          args.seed),
                run_phase('generate_mixed', phase_generate_mixed, cfg,
+                         args.seed),
+               run_phase('generate_hybrid', phase_generate_hybrid, cfg,
                          args.seed),
                run_phase('serve', phase_serve, cfg, args.seed, out_dir)]
     else:

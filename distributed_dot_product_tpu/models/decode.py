@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
 __all__ = ['DecodeCache', 'init_cache', 'append_kv', 'append_kv_sharded',
@@ -62,6 +63,7 @@ __all__ = ['DecodeCache', 'init_cache', 'append_kv', 'append_kv_sharded',
            'decode_kernel_eligible', 'decode_impl_traces',
            'rollback_slots', 'RingCache', 'init_ring_cache',
            'ring_append', 'ring_window', 'insert_session',
+           'StateCache', 'snapshot_states', 'restore_states',
            'PagedDecodeCache', 'PagePool', 'PageChecksums',
            'ShardedPageTable', 'init_sharded_paged_cache',
            'init_paged_cache', 'paged_gather', 'paged_gather_mirror',
@@ -201,13 +203,65 @@ def ring_window(cache: RingCache, k_new, v_new, window):
     return lay(cache.k, k_new), lay(cache.v, v_new), offset
 
 
+class StateCache(NamedTuple):
+    """A recurrent layer's cache, of FIXED size: ``state (B, heads,
+    head_dim, N)`` (float32 unless the mixer says otherwise) is what the
+    layer remembers of every position so far, ``conv (B, K − 1,
+    channels)`` the convolution's last inputs. A step OVERWRITES both,
+    so nothing of it rewinds by a length: a serving loop that sets a
+    request back to its prompt's end puts both back from a copy taken
+    there (:func:`snapshot_states` / :func:`restore_states`)."""
+    state: jax.Array
+    conv: jax.Array
+
+
+def snapshot_states(caches):
+    """A copy of every :class:`StateCache` in the per-layer list
+    ``caches`` (None at the layers of other kinds): what a prefix cache
+    of a recurrent model holds at the prompt's end."""
+    with device_scope('lm.state_restore'):
+        return [jax.tree.map(jnp.copy, c) if isinstance(c, StateCache)
+                else None for c in caches]
+
+
+def restore_states(caches, snapshot):
+    """``caches`` with every recurrent layer's state and window put back
+    from ``snapshot`` (:func:`snapshot_states`'s; it is left intact);
+    caches that grow are returned as they are — their length rewinds
+    them. Donate ``caches``: one device copy a state, in place. Each
+    buffer is written over in two halves: under ``jit`` two in-place
+    updates of the donated buffer that carry this scope's name (a
+    whole-buffer update XLA reduces to its operand, and the copy it then
+    inserts is nameless; through a temporary, too, where the program
+    also reads the old state); eagerly the result shares nothing with
+    the snapshot."""
+    def over(old, new):
+        axis = int(np.argmax(old.shape))
+        half = old.shape[axis] // 2
+        for lo, hi in ((0, half), (half, old.shape[axis])):
+            old = lax.dynamic_update_slice_in_dim(
+                old, lax.slice_in_dim(new, lo, hi, axis=axis), lo, axis)
+        return old
+
+    with device_scope('lm.state_restore'):
+        return [c if s is None else jax.tree.map(over, c, s)
+                for c, s in zip(caches, snapshot)]
+
+
 def insert_session(cache, session, one):
-    """``cache`` (a :class:`DecodeCache` or :class:`RingCache` of a
-    serving batch, scalar length) with session ``session`` replaced by
-    the single session ``one`` holds — a prompt prefilled alone, then
-    put in its slot. The batch shares one clock, so every session put
-    in must be of ``one``'s length, which becomes the batch's. Donate
-    ``cache``: the update is in place."""
+    """``cache`` (a :class:`DecodeCache`, :class:`RingCache` or
+    :class:`StateCache` of a serving batch; None, a layer without a
+    mixer, passes through) with session ``session`` replaced by the
+    single session ``one`` holds — a prompt prefilled alone, then put in
+    its slot. The batch shares one clock, so every session put in must
+    be of ``one``'s length, which becomes the batch's (a state has
+    none). Donate ``cache``: the update is in place."""
+    if cache is None:
+        return None
+    if isinstance(cache, StateCache):
+        return StateCache(*(
+            lax.dynamic_update_index_in_dim(buf, new[0], session, 0)
+            for buf, new in zip(cache, one)))
     if getattr(cache, 'k_q', None) is not None:
         raise ValueError('insert_session moves k and v alone: a cache '
                          'with an int8 mirror is not covered')

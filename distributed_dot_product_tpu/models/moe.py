@@ -32,6 +32,32 @@ The routed part sorts the (token, pick) rows by expert and runs three
 grouped matmuls over the sorted rows (``lax.ragged_dot``: on the TPU
 XLA's own grouped-matmul kernel, which reads an expert's weights only
 where its group has rows).
+
+Three more switches cover ``nemotron_h``'s layer. ``expert_form='plain'``
+makes every expert, routed and shared, ``W_down act(W_up x)`` — two
+matrices, no gate (there is no ``w_gate``; the shared expert is a
+:class:`PlainMLP`) — and ``activation='relu2'`` makes ``act`` the
+squared ReLU. ``latent`` puts the ROUTED experts in a latent of that
+width: ``u = W_dn x`` once a token (``latent_down``), the sort, the
+gather and the grouped matmuls run ``latent`` wide, and ``W_up``
+(``latent_up``) is applied once to the combined ``(tokens, latent)``
+result, not to the ``tokens x top_k`` rows; the router and the shared
+expert still read the stream. ``shared_hidden`` is the shared expert's
+width where it is not ``n_shared x hidden``. Both projections are
+linear and bias-free, so the parts of a latent layer divided over
+several holders, each through its own ``W_up``, still add up.
+
+``dense_tokens``: a call of at most that many tokens runs EVERY held
+expert on every token, the gate zero where the expert was not picked —
+two (three) batched matmuls that stream each held expert's weights once,
+with no sort, no gather and no grouped matmul. The same numbers; the
+trade is bytes for rows: XLA's grouped matmul pays a whole row tile a
+group, so where a decode step hits most held experts with two or three
+rows each (48 tokens x top-22 over 128 of 512: 17.1 ms a step at 38 % of
+the hit experts' bytes over the HBM peak; chip, PR 32) streaming all of
+them is the faster route, and its time does not hang on the routing. A
+prefill chunk is far past the bound and takes the sorted route. 0, the
+default, never takes it.
 """
 
 from typing import Any, Optional, Tuple
@@ -44,7 +70,10 @@ from jax import lax
 from distributed_dot_product_tpu.models.dense import OwnedDense
 from distributed_dot_product_tpu.obs.spans import device_scope
 
-__all__ = ['GatedMLP', 'SparseExperts']
+__all__ = ['GatedMLP', 'PlainMLP', 'SparseExperts']
+
+ACTIVATIONS = {'silu': nn.silu,
+               'relu2': lambda x: jnp.square(nn.relu(x))}
 
 
 class GatedMLP(nn.Module):
@@ -59,6 +88,20 @@ class GatedMLP(nn.Module):
         up = OwnedDense(self.hidden, name='up', **dense)(x)
         return OwnedDense(x.shape[-1], name='down', **dense)(
             nn.silu(gate) * up)
+
+
+class PlainMLP(nn.Module):
+    """``W_down act(W_up x)``, no gate, no biases."""
+    hidden: int
+    activation: str = 'relu2'
+    dtype: Optional[jnp.dtype] = None
+
+    @nn.compact
+    def __call__(self, x):
+        dense = dict(use_bias=False, dtype=self.dtype)
+        up = OwnedDense(self.hidden, name='up', **dense)(x)
+        return OwnedDense(x.shape[-1], name='down', **dense)(
+            ACTIVATIONS[self.activation](up))
 
 
 class SparseExperts(nn.Module):
@@ -77,10 +120,40 @@ class SparseExperts(nn.Module):
     add_shared: bool = True
     shared_combine: str = 'sum'
     router_bias: bool = True
+    expert_form: str = 'gated'
+    activation: str = 'silu'
+    latent: Optional[int] = None
+    shared_hidden: Optional[int] = None
+    dense_tokens: int = 0
     dtype: Optional[jnp.dtype] = None
     kernel_init: Any = nn.initializers.lecun_normal(in_axis=-2,
                                                     out_axis=-1,
                                                     batch_axis=(0,))
+
+    def _dense(self, tokens, picked, gates, w_gate, w_up, w_down, act,
+               lo, hi):
+        """Every held expert on every token of ``tokens (n, wide)``:
+        the hidden activations ``(held, n, hidden)`` scaled by the
+        token's gate for that expert (zero where it was not picked) and
+        contracted with ``w_down`` over expert and hidden together, so
+        the picks add up in the matmul's float32 accumulator."""
+        dtype = tokens.dtype
+        with device_scope('lm.moe_route'):
+            table = jnp.zeros((tokens.shape[0], self.n_experts),
+                              jnp.float32)
+            table = table.at[jnp.arange(tokens.shape[0])[:, None],
+                             picked].set(gates)
+            gate_of = table[:, lo:hi].T[..., None]          # (held, n, 1)
+        with device_scope('lm.moe_experts'):
+            def every(w):
+                return jnp.einsum('nk,ekh->enh', tokens, w.astype(dtype),
+                                  preferred_element_type=jnp.float32)
+            hid = (act(every(w_up)) if w_gate is None
+                   else act(every(w_gate)) * every(w_up))
+            return jnp.einsum(
+                'enh,ehk->nk', (hid * gate_of).astype(dtype),
+                w_down.astype(dtype),
+                preferred_element_type=jnp.float32).astype(dtype)
 
     @nn.compact
     def __call__(self, x):
@@ -92,21 +165,35 @@ class SparseExperts(nn.Module):
         if self.shared_combine not in ('sum', 'mean'):
             raise ValueError(f"shared_combine must be 'sum' or 'mean', "
                              f'got {self.shared_combine!r}')
+        if self.expert_form not in ('gated', 'plain'):
+            raise ValueError(f"expert_form must be 'gated' or 'plain', "
+                             f'got {self.expert_form!r}')
+        act = ACTIVATIONS[self.activation]
+        gated = self.expert_form == 'gated'
         dim = x.shape[-1]
+        # The width the routed experts read and write.
+        wide = self.latent or dim
         router = self.param('router', nn.initializers.lecun_normal(),
                             (dim, self.n_experts), jnp.float32)
         bias = (self.param('router_bias', nn.initializers.zeros_init(),
                            (self.n_experts,), jnp.float32)
                 if self.router_bias else None)
-        w_gate = self.param('w_gate', self.kernel_init,
-                            (held, dim, self.hidden), jnp.float32)
+        w_gate = (self.param('w_gate', self.kernel_init,
+                             (held, wide, self.hidden), jnp.float32)
+                  if gated else None)
         w_up = self.param('w_up', self.kernel_init,
-                          (held, dim, self.hidden), jnp.float32)
+                          (held, wide, self.hidden), jnp.float32)
         w_down = self.param('w_down', self.kernel_init,
-                            (held, self.hidden, dim), jnp.float32)
+                            (held, self.hidden, wide), jnp.float32)
         dtype = self.dtype or x.dtype
         flat = x.reshape(-1, dim).astype(dtype)
         n, k = flat.shape[0], self.top_k
+        tokens = flat
+        if self.latent:
+            with device_scope('lm.moe_latent'):
+                tokens = OwnedDense(self.latent, use_bias=False,
+                                    dtype=self.dtype,
+                                    name='latent_down')(flat)
 
         with device_scope('lm.moe_route'):
             scores = jax.nn.sigmoid(jnp.dot(
@@ -121,13 +208,15 @@ class SparseExperts(nn.Module):
             expert = picked.reshape(-1)                          # (n·k,)
             counts = jnp.zeros((self.n_experts,), jnp.int32).at[
                 expert].add(1)
-            mine = (expert >= lo) & (expert < hi)
-            # Rows of experts held elsewhere sort behind the last group
-            # and are masked out of the combine.
-            order = jnp.argsort(jnp.where(mine, expert - lo, held),
-                                stable=True)
-            rows = flat[order // k]                              # (n·k, dim)
-            sizes = lax.dynamic_slice_in_dim(counts, lo, held)
+            dense = n <= self.dense_tokens
+            if not dense:
+                mine = (expert >= lo) & (expert < hi)
+                # Rows of experts held elsewhere sort behind the last
+                # group and are masked out of the combine.
+                order = jnp.argsort(jnp.where(mine, expert - lo, held),
+                                    stable=True)
+                rows = tokens[order // k]                       # (n·k, wide)
+                sizes = lax.dynamic_slice_in_dim(counts, lo, held)
         # Counters for a caller that makes the collection mutable (a
         # no-op otherwise): this call's tokens per expert and picks.
         self.sow('counters', 'expert_tokens', counts,
@@ -137,27 +226,42 @@ class SparseExperts(nn.Module):
                  reduce_fn=lambda old, new: new,
                  init_fn=lambda: jnp.zeros((n, k), jnp.int32))
 
-        with device_scope('lm.moe_experts'):
-            def grouped(a, w):
-                return lax.ragged_dot(a, w.astype(dtype), sizes,
-                                      preferred_element_type=jnp.float32
-                                      ).astype(dtype)
-            out = grouped(nn.silu(grouped(rows, w_gate))
-                          * grouped(rows, w_up), w_down)
+        if dense:
+            y = self._dense(tokens, picked, gates, w_gate, w_up, w_down,
+                            act, lo, hi)
+        else:
+            with device_scope('lm.moe_experts'):
+                def grouped(a, w):
+                    return lax.ragged_dot(
+                        a, w.astype(dtype), sizes,
+                        preferred_element_type=jnp.float32).astype(dtype)
+                if gated:
+                    out = grouped(act(grouped(rows, w_gate))
+                                  * grouped(rows, w_up), w_down)
+                else:
+                    out = grouped(act(grouped(rows, w_up)), w_down)
 
-        with device_scope('lm.moe_route'):
-            weight = (gates.reshape(-1) * mine)[order]
-            out = jnp.where(weight[:, None] != 0,
-                            out.astype(jnp.float32) * weight[:, None], 0.0)
-            # Back to (token, pick) order, then the k picks add up.
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(n * k, dtype=order.dtype))
-            y = out[back].reshape(n, k, dim).sum(axis=1).astype(dtype)
+            with device_scope('lm.moe_route'):
+                weight = (gates.reshape(-1) * mine)[order]
+                out = jnp.where(weight[:, None] != 0,
+                                out.astype(jnp.float32) * weight[:, None],
+                                0.0)
+                # Back to (token, pick) order, then the k picks add up.
+                back = jnp.zeros_like(order).at[order].set(
+                    jnp.arange(n * k, dtype=order.dtype))
+                y = out[back].reshape(n, k, wide).sum(axis=1).astype(dtype)
 
+        if self.latent:
+            with device_scope('lm.moe_latent'):
+                y = OwnedDense(dim, use_bias=False, dtype=self.dtype,
+                               name='latent_up')(y)
         if self.n_shared and self.add_shared:
             with device_scope('lm.mlp'):
-                shared = GatedMLP(self.n_shared * self.hidden,
-                                  dtype=self.dtype, name='shared')(flat)
+                hidden = self.shared_hidden or self.n_shared * self.hidden
+                shared = (GatedMLP(hidden, dtype=self.dtype, name='shared')
+                          if gated else PlainMLP(
+                              hidden, self.activation, dtype=self.dtype,
+                              name='shared'))(flat)
                 if self.shared_combine == 'mean':
                     shared = shared * (1.0 / self.n_shared)
                 y = y + shared

@@ -46,6 +46,7 @@ from distributed_dot_product_tpu.models.latent import (
     LatentAttention, init_latent_cache,
 )
 from distributed_dot_product_tpu.models.moe import GatedMLP, SparseExperts
+from distributed_dot_product_tpu.models.ssm import Mamba2Mixer
 from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_attention import (
     FLASH_RESIDUAL_NAMES,
@@ -84,10 +85,16 @@ class TransformerBlock(nn.Module):
       keys/queries/values, reference example.py:31's usage) |
       ``'latent'`` (``models/latent.LatentAttention``; ``attn_kwargs``
       are its ranks and head sizes, its cache one layer-stacked
-      ``LatentCache`` addressed by ``layer``);
+      ``LatentCache`` addressed by ``layer``) | ``'ssm'``
+      (``models/ssm.Mamba2Mixer(**ssm_kwargs)``, the subtree ``ssm``;
+      its cache a fixed-size ``StateCache``) | ``'none'``;
     - ``ffn``: ``'gelu'`` (``mlp_ratio`` x dim) | ``'gated'``
       (``ffn_kwargs['hidden']``, SiLU-gated, no biases) | ``'experts'``
-      (``models/moe.SparseExperts(**ffn_kwargs)``);
+      (``models/moe.SparseExperts(**ffn_kwargs)``) | ``'none'``. A
+      block with ``mixer='none'`` or ``ffn='none'`` is ``x +
+      branch(LN(x))``: ONE norm (``ln1``), one branch, one residual,
+      and nothing of the absent branch in its parameter tree (it has no
+      cache where it has no mixer);
     - ``residual``: ``'add'`` | ``'hyper'`` (``models/hyper``: the input
       is a widened stream ``(..., mult, dim)`` float32 and each branch
       reads and writes it through its own ``HyperConnection(
@@ -114,6 +121,7 @@ class TransformerBlock(nn.Module):
     mixer: str = 'attention'
     ffn: str = 'gelu'
     ffn_kwargs: Any = None
+    ssm_kwargs: Any = None
     residual: str = 'add'
     residual_kwargs: Any = None
     parallel: bool = False
@@ -137,15 +145,23 @@ class TransformerBlock(nn.Module):
                 kw.setdefault('out_dim', self.dim)
             self.attn = DistributedDotProductAttn(
                 num_heads=self.num_heads, **kw)
-        else:
-            raise ValueError(f"mixer must be 'attention' or 'latent', "
-                             f'got {self.mixer!r}')
+        elif self.mixer == 'ssm':
+            self.ssm = Mamba2Mixer(dim=self.dim, name='ssm', **{
+                'dtype': self.dtype, 'norm_eps': self.norm_eps,
+                **(self.ssm_kwargs or {})})
+        elif self.mixer != 'none':
+            raise ValueError(f"mixer must be 'attention', 'latent', "
+                             f"'ssm' or 'none', got {self.mixer!r}")
+        one_branch = 'none' in (self.mixer, self.ffn)
+        if one_branch and (self.parallel or self.mixer == self.ffn):
+            raise ValueError('a block has a mixer, a feed-forward or '
+                             'both; parallel=True needs both')
         self.ln1 = self._norm('ln1')
         if self.parallel:
             if self.residual != 'add':
                 raise ValueError("parallel=True is x + Attn(h) + FFN(h): "
                                  "it goes with residual='add'")
-        else:
+        elif not one_branch:
             self.ln2 = self._norm('ln2')
         ffn_kw = dict(self.ffn_kwargs or {})
         if self.ffn == 'gelu':
@@ -162,13 +178,15 @@ class TransformerBlock(nn.Module):
         elif self.ffn == 'experts':
             self.moe = SparseExperts(dtype=self.dtype, name='moe',
                                      **ffn_kw)
-        else:
-            raise ValueError(f"ffn must be 'gelu', 'gated' or 'experts', "
-                             f'got {self.ffn!r}')
+        elif self.ffn != 'none':
+            raise ValueError(f"ffn must be 'gelu', 'gated', 'experts' or "
+                             f"'none', got {self.ffn!r}")
         if self.residual == 'hyper':
             hc_kw = dict(self.residual_kwargs or {})
-            self.hc_attn = HyperConnection(name='hc_attn', **hc_kw)
-            self.hc_ffn = HyperConnection(name='hc_ffn', **hc_kw)
+            if self.mixer != 'none':
+                self.hc_attn = HyperConnection(name='hc_attn', **hc_kw)
+            if self.ffn != 'none':
+                self.hc_ffn = HyperConnection(name='hc_ffn', **hc_kw)
         elif self.residual != 'add':
             raise ValueError(f"residual must be 'add' or 'hyper', got "
                              f'{self.residual!r}')
@@ -197,7 +215,13 @@ class TransformerBlock(nn.Module):
 
     def _both(self, x, mixer):
         """The block around ``mixer`` (normed input -> branch output):
-        two residuals one after the other, or the parallel form."""
+        two residuals one after the other, the parallel form, or the one
+        branch a block has, on the one norm."""
+        if self.mixer == 'none':
+            return self._around(
+                'ffn', x, lambda u: self._mlp(u, norm=self.ln1))
+        if self.ffn == 'none':
+            return self._around('attn', x, lambda u: mixer(self.ln1(u)))
         if self.parallel:
             h = self.ln1(x)
             return x + mixer(h) + self._mlp(h, norm=lambda u: u)
@@ -207,6 +231,8 @@ class TransformerBlock(nn.Module):
     def __call__(self, x, attn_mask=None, segment_ids=None,
                  deterministic=False, dropout_seed=None):
         def mixer(h):
+            if self.mixer == 'ssm':
+                return self.ssm(h)
             if self.mixer == 'latent':
                 return self.attn(h)
             return self.attn(h, h, h, attn_mask, segment_ids=segment_ids,
@@ -220,6 +246,9 @@ class TransformerBlock(nn.Module):
         held = [cache]
 
         def mixer(h):
+            if self.mixer == 'ssm':
+                held[0], a = getattr(self.ssm, method)(h, held[0])
+                return a
             step = getattr(self.attn, method)
             if self.mixer == 'latent':
                 held[0], a = step(h, held[0], layer)
@@ -357,7 +386,9 @@ class TransformerStack(nn.Module):
     # expert layers). A stack of more than one kind, and any with a
     # latent mixer or an expert feed-forward, runs unrolled
     # (``scan_layers=False``); its caches are a list, each layer's of
-    # its own kind's geometry.
+    # its own kind's geometry: a slab or a ring for an attention layer,
+    # a ``StateCache`` for a recurrent one, None for a layer without a
+    # mixer.
     block_kwargs: Any = None
     layer_kinds: Any = None
     layer_pattern: Any = None
@@ -409,16 +440,17 @@ class TransformerStack(nn.Module):
                     f'{sorted(self.layer_kinds or {})} and divide '
                     f'n_layers {self.n_layers}')
         if self.scan_layers and (self._mixed or self._latent
+                                 or kw.get('mixer') == 'ssm'
                                  or kw.get('ffn') == 'experts'):
             # XLA's grouped-matmul kernel takes an expert layer's
             # weights whole, so nn.scan's slice of layer-stacked experts
             # is a copy of them a layer a token (21.6 of a 36.6 ms step;
             # chip, PR 26); a scan over several layer kinds would be a
             # scan over periods; the latent cache is carried from block
-            # to block.
-            raise ValueError("more than one layer kind, mixer='latent' "
-                             "and ffn='experts' run unrolled: pass "
-                             'scan_layers=False')
+            # to block, and a recurrent state is no layer of a stack.
+            raise ValueError("more than one layer kind, mixer='latent', "
+                             "mixer='ssm' and ffn='experts' run "
+                             'unrolled: pass scan_layers=False')
         if not self.scan_layers:
             self.blocks = [self._block(f'block_{i}', i)
                            for i in range(self.n_layers)]
@@ -483,12 +515,21 @@ class TransformerStack(nn.Module):
                 self.n_layers, batch, t_max,
                 kw['kv_rank'] + kw['rope_dim'],
                 dtype or kw.get('dtype') or self.dtype or jnp.float32)
-        caches = [DistributedDotProductAttn(
-            num_heads=self.num_heads, parent=None,
-            **{'key_dim': self.dim, 'dtype': self.dtype,
-               **self._layer_kwargs(i)[0]}
-        ).make_decode_cache(batch, t_max, dtype=dtype)
-            for i in range(self.n_layers)]
+        def layer_cache(i):
+            attn, block = self._layer_kwargs(i)
+            mixer = block.get('mixer', 'attention')
+            if mixer == 'none':
+                return None
+            if mixer == 'ssm':
+                # Of FIXED size: t_max says nothing to it.
+                return Mamba2Mixer(dim=self.dim, parent=None, **{
+                    'dtype': self.dtype, **(block.get('ssm_kwargs') or {})
+                }).make_cache(batch, dtype=dtype)
+            return DistributedDotProductAttn(
+                num_heads=self.num_heads, parent=None,
+                **{'key_dim': self.dim, 'dtype': self.dtype, **attn}
+            ).make_decode_cache(batch, t_max, dtype=dtype)
+        caches = [layer_cache(i) for i in range(self.n_layers)]
         if self.scan_layers:
             return jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
         return caches

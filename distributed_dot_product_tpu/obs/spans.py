@@ -107,6 +107,22 @@ DEVICE_SCOPES = {
     'lm.moe_experts': 'the routed experts\' grouped matmuls (gate, up, '
                       'down over the rows sorted by expert) and their '
                       'activation',
+    'lm.moe_latent': 'an expert layer whose experts live in a latent: the '
+                     'projection of the stream down to it (once a token) '
+                     'and of the combined expert output back up',
+    'lm.ssm_proj': 'a Mamba-2 mixer outside its recurrence: the input '
+                   'and output projections, the causal depthwise '
+                   'convolution, softplus / decay, the skip, the gate and '
+                   'the grouped norm',
+    'ops.ssm_step': 'decode: the one read-modify-write pass over a '
+                    'recurrent layer\'s state (decay, outer-product '
+                    'update, the read against C)',
+    'ops.ssm_scan': 'prefill / whole sequence: the recurrence in its '
+                    'chunked form (decay-masked C·B^T products inside a '
+                    'chunk, the state stepped between chunks)',
+    'lm.state_restore': 'copies of the recurrent layers\' states: the '
+                        'snapshot at a prompt\'s end and the restore '
+                        'from it between requests',
     'lm.hc': 'a hyper-connection residual: the norm over the widened '
              'stream, the three Phi products, sigmoid / Sinkhorn, the '
              'pre-mix into the branch input and the post / residual '

@@ -80,7 +80,13 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     assert lines[-1] == {'ok': False, 'device': lines[0]['device']}
     phases = {rec['phase']: rec for rec in lines if 'phase' in rec}
     assert set(phases) == {'train', 'generate', 'generate_latent',
-                           'generate_mixed', 'serve'}
+                           'generate_mixed', 'generate_hybrid', 'serve'}
+    # a recurrent state beside a slab: the request after a restore reads
+    # what the first did
+    hybrid = phases['generate_hybrid']
+    assert hybrid['hybrid_caches'] == ['NoneType', 'StateCache',
+                                       'DecodeCache']
+    assert hybrid['checks']['hybrid.restored_request_agrees'] is True
     # both kernel modes side by side, off the chip both through XLA
     assert phases['generate_mixed']['mixed_caches'] == ['layer', 'ring']
     for name, rec in phases.items():

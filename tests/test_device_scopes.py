@@ -42,6 +42,13 @@ LATENT_SCOPES = ['ops.mla_decode', 'lm.attn_proj', 'lm.mlp', 'lm.moe_route',
 # window layers' kernel step is the ring mode.
 MIXED_SCOPES = ['ops.flash_decode', 'ops.flash_decode_ring', 'lm.attn_proj',
                 'lm.mlp', 'lm.embed', 'lm.head', 'lm.stack_carry']
+# A stack of recurrent, attention and latent-expert layers: the decode
+# step, a prefill chunk (the chunked scan) and the reset between
+# requests (snapshot and restore of the states).
+HYBRID_SCOPES = ['ops.ssm_step', 'ops.ssm_scan', 'lm.ssm_proj',
+                 'lm.moe_latent', 'lm.state_restore', 'ops.flash_decode',
+                 'lm.attn_proj', 'lm.mlp', 'lm.moe_route', 'lm.moe_experts',
+                 'lm.embed', 'lm.head', 'lm.stack_carry']
 
 
 def tiny_lm(remat_policy=None, **attn_kwargs):
@@ -139,6 +146,38 @@ def mixed_op_names():
     return op_names(jax.jit(step).lower(params, tokens, caches).compile())
 
 
+@pytest.fixture(scope='module')
+def hybrid_op_names():
+    from distributed_dot_product_tpu.models.decode import (
+        restore_states, snapshot_states,
+    )
+    model = TransformerLM(
+        vocab_size=64, dim=32, num_heads=2, n_layers=3, scan_layers=False,
+        tie_embeddings=False,
+        attn_kwargs=dict(distributed=False, decode_impl='kernel',
+                         use_rope=False),
+        block_kwargs=dict(norm='rmsnorm'),
+        layer_kinds={
+            'E': dict(mixer='none', ffn='experts', ffn_kwargs=dict(
+                n_experts=4, top_k=2, hidden=16, latent=16,
+                shared_hidden=24, expert_form='plain',
+                activation='relu2')),
+            'M': dict(mixer='ssm', ffn='none', ssm_kwargs=dict(
+                heads=4, head_dim=8, state=8, groups=2, chunk=8)),
+            '*': dict(mixer='attention', ffn='none')},
+        layer_pattern=('E', 'M', '*'))
+    params = model.init(jax.random.key(0), jnp.zeros((2, 8), jnp.int32))
+    caches = model.make_decode_caches(2, 128)
+    names = set()
+    for method, n in (('decode', 1), ('prefill', 8)):
+        names |= op_names(jax.jit(
+            lambda p, tok, c, m=method: model.apply(p, tok, c, method=m)
+        ).lower(params, jnp.zeros((2, n), jnp.int32), caches).compile())
+    return names | op_names(jax.jit(
+        lambda c: restore_states(c, snapshot_states(c))
+    ).lower(caches).compile())
+
+
 def opened(scope, names):
     return any(f'/{scope}/' in f'/{name}/' for name in names)
 
@@ -161,6 +200,11 @@ def test_latent_decode_step_opens(scope, latent_op_names):
 @pytest.mark.parametrize('scope', MIXED_SCOPES)
 def test_mixed_decode_step_opens(scope, mixed_op_names):
     assert opened(scope, mixed_op_names)
+
+
+@pytest.mark.parametrize('scope', HYBRID_SCOPES)
+def test_hybrid_stack_opens(scope, hybrid_op_names):
+    assert opened(scope, hybrid_op_names)
 
 
 def test_ring_mode_opens_inside_the_decode_kernels_scope(mixed_op_names):
@@ -186,7 +230,8 @@ def test_latent_kernel_is_outside_the_projection_scope(latent_op_names):
 
 def test_the_steps_cover_the_vocabulary():
     assert (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(LATENT_SCOPES)
-            | set(MIXED_SCOPES) == set(DEVICE_SCOPES))
+            | set(MIXED_SCOPES) | set(HYBRID_SCOPES)
+            == set(DEVICE_SCOPES))
 
 
 def test_unknown_scope_raises():

@@ -1,0 +1,455 @@
+# -*- coding: utf-8 -*-
+"""Recurrent (Mamba-2), attention and latent-expert layers in one stack
+(the ``nemotron_h`` block: Nemotron 3 Super): the mixer's three entry
+points against the literal recurrence, a fixed-size ``StateCache`` beside
+a slab and no cache, snapshot and restore, one-branch blocks, the shares
+of a latent expert layer against the whole layer — all against the plain
+reference ``benchmarks/reference/nemotron_h.py`` at tiny widths, float32,
+seeded weights."""
+
+import hashlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks import loader  # noqa: E402
+from distributed_dot_product_tpu.models.decode import (  # noqa: E402
+    StateCache, insert_session, restore_states, snapshot_states,
+)
+from distributed_dot_product_tpu.models.moe import (  # noqa: E402
+    SparseExperts,
+)
+from distributed_dot_product_tpu.models.ssm import (  # noqa: E402
+    Mamba2Mixer,
+)
+from distributed_dot_product_tpu.models.transformer import (  # noqa: E402
+    TransformerBlock, TransformerStack,
+)
+
+TINY = os.path.join(ROOT, 'benchmarks', 'tests', 'tiny_hybrid')
+CELL = loader.Cell('tiny-nemotron.decode', root=TINY)
+DRIVER, REF, CFG = CELL.driver(), CELL.reference(), CELL.config
+TOL = 2e-5          # float32 on both sides; logits are O(3)
+
+
+# -- (a) the mixer against the literal recurrence ------------------------
+
+MIX = dict(dim=24, heads=8, head_dim=4, state=8, groups=2, conv=4, chunk=8)
+MIX_CFG = {'mamba_num_heads': 8, 'mamba_head_dim': 4, 'n_groups': 2,
+           'ssm_state_size': 8, 'conv_kernel': 4,
+           'layer_norm_epsilon': 1e-5}
+
+
+@pytest.fixture(scope='module')
+def mixer():
+    """A mixer with heads > groups, its seeded parameters, 37 normed rows
+    of 2 sessions, and what the literal recurrence gives for them: the
+    outputs and the state and window after the last row."""
+    model = Mamba2Mixer(**MIX)
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.normal(size=(2, 37, 24)), jnp.float32)
+    params = model.init(jax.random.key(1), h)['params']
+    params = {**params,
+              'A_log': jnp.log(jnp.linspace(1.0, 16.0, 8)),
+              'dt_bias': jnp.asarray(rng.normal(size=8) - 3.0, jnp.float32),
+              'D': jnp.asarray(rng.normal(size=8), jnp.float32),
+              'conv_bias': jnp.asarray(rng.normal(size=64) * 0.1,
+                                       jnp.float32),
+              'norm_scale': jnp.asarray(1 + rng.normal(size=32) * 0.1,
+                                        jnp.float32)}
+    want = []
+    with jax.default_matmul_precision('highest'):
+        for b in range(2):
+            want.append(REF.ssm_block(
+                MIX_CFG, params, h[b], jnp.zeros((8, 4, 8)),
+                jnp.zeros((3, 64))))
+    out, state, window = (np.stack([np.asarray(w[i]) for w in want])
+                          for i in range(3))
+    return model, {'params': params}, h, out, state, window
+
+
+def test_whole_sequence_matches_the_literal_recurrence(mixer):
+    model, params, h, want, _, _ = mixer
+    np.testing.assert_allclose(model.apply(params, h), want, atol=TOL)
+
+
+@pytest.mark.parametrize('chunks', [(37,), (5, 11, 21), (8, 16, 13)],
+                         ids=['whole', 'splits-a-chunk', 'aligned-then-not'])
+def test_prefill_in_chunks_then_decode_continue_the_state(mixer, chunks):
+    """Chunks whose lengths are no multiple of the module's chunk (8)
+    and that split one, then the last 6 tokens one at a time: outputs,
+    final state and window are the literal recurrence's."""
+    model, params, h, want, state, window = mixer
+    cache = model.make_cache(2)
+    assert cache.state.shape == (2, 8, 4, 8) and cache.state.dtype == (
+        jnp.float32) and cache.conv.shape == (2, 3, 64)
+    got, at = [], 0
+    for n in chunks:
+        n = min(n, 31 - at)
+        if n <= 0:
+            break
+        cache, out = model.apply(params, h[:, at:at + n], cache,
+                                 method='prefill')
+        got.append(out)
+        at += n
+    for i in range(at, 37):
+        cache, out = model.apply(params, h[:, i:i + 1], cache,
+                                 method='decode')
+        got.append(out)
+    np.testing.assert_allclose(np.concatenate(got, axis=1), want, atol=TOL)
+    np.testing.assert_allclose(cache.state, state, atol=TOL)
+    np.testing.assert_allclose(cache.conv, window, atol=TOL)
+
+
+def test_reference_recurrence_is_the_hand_computation():
+    """64 tokens of one head by hand: ``y_t = sum_s (prod_{s < r <= t}
+    a_r) dt_s (B_s · C_t) x_s + D x_t``."""
+    rng = np.random.default_rng(2)
+    cfg = {**MIX_CFG, 'mamba_num_heads': 1, 'n_groups': 1}
+    x = rng.normal(size=(64, 1, 4))
+    b, c = rng.normal(size=(2, 64, 1, 8))
+    dt = np.exp(rng.uniform(-5, -1, size=(64, 1)))
+    a_log, d = np.log(3.0), 0.7
+    y, state = REF.recurrence(
+        cfg, {'A_log': jnp.asarray([a_log]), 'D': jnp.asarray([d])},
+        *(jnp.asarray(u, jnp.float32) for u in (x, b, c, dt)),
+        jnp.zeros((1, 4, 8)))
+    log_a = -dt[:, 0] * 3.0
+    want = np.zeros((64, 4))
+    for t in range(64):
+        for s in range(t + 1):
+            want[t] += (np.exp(log_a[s + 1:t + 1].sum()) * dt[s, 0]
+                        * (b[s, 0] @ c[t, 0]) * x[s, 0])
+        want[t] += d * x[t, 0]
+    np.testing.assert_allclose(np.asarray(y)[:, 0], want, atol=1e-4)
+    last = sum(np.exp(log_a[s + 1:].sum()) * dt[s, 0]
+               * np.outer(x[s, 0], b[s, 0]) for s in range(64))
+    np.testing.assert_allclose(np.asarray(state)[0], last, atol=1e-4)
+
+
+# -- (b) the LM through state, slab and no cache against the reference ---
+
+@pytest.fixture(scope='module')
+def tokens():
+    return np.random.default_rng(0).integers(
+        0, CFG['vocab_size'], size=(3, 56)).astype(np.int32)
+
+
+@pytest.fixture(scope='module')
+def served(tokens):
+    """Weights, the reference's logits of session 0, and a 3-session
+    batch prefilled together in chunks of 13, 20 and 7 tokens."""
+    params = DRIVER.make(CFG, 7, jnp.float32)
+    REF.ROW_BLOCK = 8
+    want, _, _ = REF.logits_at(CFG, params, jnp.asarray(tokens[0]), 56)
+    model = DRIVER.build_lm(CFG)
+    caches = model.make_decode_caches(3, 64)
+    assert [type(c).__name__ for c in caches] == 5 * [
+        'NoneType', 'StateCache'] + ['DecodeCache']
+    logits = []
+    for i, n in ((0, 13), (13, 20), (33, 7)):
+        caches, out = model.apply(params, tokens[:, i:i + n], caches,
+                                  method='prefill')
+        logits.append(out)
+    return model, params, np.asarray(want), caches, np.concatenate(
+        logits, axis=1)
+
+
+def _serve(model, params, caches, tokens, n):
+    step = jax.jit(lambda p, t, c: model.apply(p, t, c, method='decode'))
+    out = []
+    for i in range(40, 40 + n):
+        caches, logits = step(params, tokens[:, i:i + 1], caches)
+        out.append(logits)
+    return caches, np.concatenate(out, axis=1)
+
+
+def test_full_forward_matches_the_reference(tokens, served):
+    _, params, want, _, _ = served
+    model = DRIVER.build_lm(CFG, distributed=False)
+    np.testing.assert_allclose(model.apply(params, tokens[:1])[0], want,
+                               atol=TOL)
+
+
+def test_prefill_and_decode_match_the_reference(tokens, served):
+    model, params, want, caches, prefilled = served
+    _, first = _serve(model, params, caches, tokens, 16)
+    np.testing.assert_allclose(
+        np.concatenate([prefilled[0], first[0]]), want, atol=TOL)
+
+
+def test_a_request_after_restore_reads_what_the_first_did(tokens, served):
+    """The snapshot at the prompt's end, 16 tokens, the states put back
+    and the slab's length set back: the same logits bit for bit; with
+    the length alone set back (what a slab or a ring needs) they
+    differ."""
+    model, params, _, caches, _ = served
+    taken = snapshot_states(caches)
+    assert [type(s).__name__ for s in taken] == 5 * [
+        'NoneType', 'StateCache'] + ['NoneType']
+    after, first = _serve(model, params, caches, tokens, 16)
+
+    def rewind(layers):
+        return [c._replace(length=jnp.asarray(40, jnp.int32))
+                if hasattr(c, 'length') else c for c in layers]
+    restore = jax.jit(lambda c, s: rewind(restore_states(c, s)),
+                      donate_argnums=(0,))
+    lengths_only = rewind(after)
+    _, stale = _serve(model, params, lengths_only, tokens, 16)
+    assert np.max(np.abs(stale - first)) > 100 * TOL
+    _, again = _serve(model, params, restore(after, taken), tokens, 16)
+    np.testing.assert_array_equal(again, first)
+    # The snapshot is left intact: a third request restores from it too.
+    assert all(not s.state.is_deleted() for s in taken if s is not None)
+
+
+def test_sessions_prefilled_alone_and_inserted_equal_the_batch(tokens,
+                                                               served):
+    model, params, _, together, _ = served
+    batch = model.make_decode_caches(3, 64)
+    for s in range(3):
+        one = model.make_decode_caches(1, 64)
+        for i, n in ((0, 13), (13, 20), (33, 7)):
+            one, _ = model.apply(params, tokens[s:s + 1, i:i + n], one,
+                                 method='prefill')
+        batch = [insert_session(c, s, o) for c, o in zip(batch, one)]
+    for got, want in zip(batch, together):
+        assert type(got) is type(want)
+        if isinstance(want, StateCache):
+            np.testing.assert_allclose(got.state, want.state, atol=TOL)
+            np.testing.assert_allclose(got.conv, want.conv, atol=TOL)
+        elif want is not None:
+            assert int(got.length) == int(want.length) == 40
+            np.testing.assert_allclose(got.k, want.k, atol=TOL)
+            np.testing.assert_allclose(got.v, want.v, atol=TOL)
+
+
+def test_the_drivers_shape_table_is_the_models_tree():
+    model = DRIVER.build_lm(CFG)
+    tree = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))['params']
+    flat = {tuple(k.key for k in path): leaf.shape for path, leaf in
+            jax.tree_util.tree_flatten_with_path(tree)[0]}
+    assert flat == {path: shape for path, (shape, _) in
+                    DRIVER.shapes(CFG).items()}
+
+
+# -- (c) the shares of a latent expert layer add up to the layer ----------
+
+@pytest.mark.parametrize('dense_tokens', [0, 24], ids=['sorted', 'dense'])
+def test_four_shares_of_a_latent_layer_add_up_to_the_uncut_layer(
+        dense_tokens):
+    """16 plain relu2 experts in a latent of 12 over 4 holders of 4,
+    top-6 with a correction bias and scaling 5, each holder through its
+    own copy of ``W_up``, the shared expert counted once (holder 0 adds
+    it): the parts add up to the reference's whole layer — through the
+    sorted grouped matmuls, and with every held expert run on all 24
+    tokens (``dense_tokens``)."""
+    dim, lat, hidden, shared, n_exp, k = 16, 12, 10, 20, 16, 6
+    cfg = {'num_experts_per_tok': k, 'norm_topk_prob': True,
+           'routed_scaling_factor': 5,
+           'published': {'n_routed_experts': n_exp}}
+    rng = np.random.default_rng(1)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape) / np.sqrt(shape[-2]),
+                           jnp.float32)
+    whole = {'router': draw(dim, n_exp),
+             'router_bias': jnp.asarray(rng.normal(size=n_exp) * 0.05,
+                                        jnp.float32),
+             'latent_down': {'kernel': draw(dim, lat)},
+             'latent_up': {'kernel': draw(lat, dim)},
+             'w_up': draw(n_exp, lat, hidden),
+             'w_down': draw(n_exp, hidden, lat),
+             'shared': {'up': {'kernel': draw(dim, shared)},
+                        'down': {'kernel': draw(shared, dim)}}}
+    x = jnp.asarray(rng.normal(size=(24, dim)), jnp.float32)
+    with jax.default_matmul_precision('highest'):
+        want, picks, _ = REF.expert_layer(cfg, whole, x)
+    total = 0
+    for share in range(4):
+        lo, hi = 4 * share, 4 * share + 4
+        layer = SparseExperts(
+            n_experts=n_exp, top_k=k, hidden=hidden, latent=lat,
+            shared_hidden=shared, expert_form='plain',
+            activation='relu2', scaling=5.0, experts_held=(lo, hi),
+            add_shared=share == 0, dense_tokens=dense_tokens)
+        mine = {**whole, 'w_up': whole['w_up'][lo:hi],
+                'w_down': whole['w_down'][lo:hi]}
+        if share:
+            del mine['shared']
+        (y, counts), sown = layer.apply({'params': mine}, x,
+                                        mutable=['counters'])
+        assert 'w_gate' not in jax.eval_shape(
+            lambda: layer.init(jax.random.key(0), x))['params']
+        np.testing.assert_array_equal(
+            np.sort(sown['counters']['expert_picks'], -1),
+            np.sort(picks, -1))
+        assert int(counts.sum()) == 24 * k
+        total = total + y
+    np.testing.assert_allclose(total, want, atol=TOL)
+
+
+# The text jax lowers the commit before this architecture's to (CPU):
+# the default expert layer, and prefill and decode of the two expert
+# cells' tiny presets. The new switches are Python-level branches only.
+PARENT_LOWERED = {
+    'experts':
+        '3ee5a11d5306ab89107c9c4389ffa206c0cc05e51e0b049f90807a8f6335e10e',
+    'xing4.prefill':
+        '204781b5075ab113fc6ea2b02aa84682b0184192c978f57d8ddfe35dca48ddc6',
+    'xing4.decode':
+        'f8d9c82820af2fa2867304ecfb05746326f33faf5315bc2583c061c00ffb6188',
+    'command-a.prefill':
+        '1a4285266c22a901f84f5fec0fc173aaa7d9727a8990f466b3f62d09caec1ea1',
+    'command-a.decode':
+        '77b4a278f438d0348bb3fb405253ebd737bfb712dc16375d112330032ac8f4cc',
+}
+PRESETS = {'xing4': ('tiny_latent', 'tiny-xing4.decode'),
+           'command-a': ('tiny_mixed', 'tiny-command-a.decode')}
+
+
+def _sha(lowered):
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+
+
+@pytest.mark.parametrize('what', sorted(PARENT_LOWERED))
+def test_accepted_programs_lower_to_the_parents_text(what):
+    if what == 'experts':
+        layer = SparseExperts(n_experts=8, top_k=2, hidden=8)
+        x = jnp.zeros((2, 6, 16), jnp.float32)
+        params = jax.eval_shape(lambda: layer.init(jax.random.key(0), x))
+        assert set(params['params']) == {
+            'router', 'router_bias', 'w_gate', 'w_up', 'w_down', 'shared'}
+        assert _sha(jax.jit(layer.apply).lower(params, x)) == (
+            PARENT_LOWERED[what])
+        return
+    name, method = what.split('.')
+    root, cell = PRESETS[name]
+    cell = loader.Cell(cell, root=os.path.join(ROOT, 'benchmarks', 'tests',
+                                               root))
+    model = cell.driver().build_lm(cell.config)
+    tok = jnp.zeros((2, 8 if method == 'prefill' else 1), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((2, 8), jnp.int32)))
+    caches = jax.eval_shape(lambda: model.make_decode_caches(2, 32))
+    fn = jax.jit(lambda p, t, c: model.apply(p, t, c, method=method))
+    assert _sha(fn.lower(params, tok, caches)) == PARENT_LOWERED[what]
+
+
+def test_the_dense_route_of_a_gated_layer_is_the_sorted_one():
+    """The accepted layer's form (three gated matmuls at the stream's
+    width) through both routes, a held share and a shared expert: one
+    result, and the dense route sorts nothing."""
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(2, 5, 16)),
+                    jnp.float32)
+    kw = dict(n_experts=8, top_k=3, hidden=12, experts_held=(2, 7))
+    params = SparseExperts(**kw).init(jax.random.key(0), x)
+    sorted_y, counts = SparseExperts(**kw).apply(params, x)
+    dense = SparseExperts(**kw, dense_tokens=10)
+    dense_y, dense_counts = dense.apply(params, x)
+    np.testing.assert_allclose(dense_y, sorted_y, atol=TOL)
+    np.testing.assert_array_equal(dense_counts, counts)
+    text = jax.jit(dense.apply).lower(params, x).as_text()
+    assert 'stablehlo.sort' not in text     # no sort by expert
+    # past the bound the same module takes the sorted route
+    assert 'stablehlo.sort' in jax.jit(dense.apply).lower(
+        params, jnp.zeros((3, 5, 16))).as_text()
+
+
+# -- (d) one-branch blocks ------------------------------------------------
+
+def _rms(x, scale, eps=1e-5):
+    return x / np.sqrt(np.mean(np.square(x), -1, keepdims=True)
+                       + eps) * scale
+
+
+@pytest.mark.parametrize('kind', ['mixer-alone', 'ffn-alone'])
+def test_one_branch_block_is_one_norm_one_branch_one_residual(kind):
+    x = jnp.asarray(np.random.default_rng(3).normal(size=(2, 6, 16)),
+                    jnp.float32)
+    common = dict(dim=16, num_heads=2, norm='rmsnorm', norm_eps=1e-5)
+    if kind == 'mixer-alone':
+        block = TransformerBlock(
+            **common, ffn='none', attn_kwargs=dict(
+                distributed=False, causal=True, softmax_impl='full'))
+        params = block.init(jax.random.key(0), x)
+        assert set(params['params']) == {'ln1', 'attn'}
+        h = _rms(np.asarray(x), np.asarray(
+            params['params']['ln1']['scale']))
+        branch = block.bind(params).attn(h, h, h, None)
+    else:
+        block = TransformerBlock(**common, mixer='none', ffn='gated',
+                                 ffn_kwargs={'hidden': 24})
+        params = block.init(jax.random.key(0), x)
+        assert set(params['params']) == {'ln1', 'mlp'}
+        h = _rms(np.asarray(x), np.asarray(
+            params['params']['ln1']['scale']))
+        branch = block.bind(params).mlp(h)
+        assert block.apply(params, x, None, method='decode')[0] is None
+    np.testing.assert_allclose(block.apply(params, x), x + branch,
+                               atol=TOL)
+
+
+def test_a_block_without_either_branch_is_refused():
+    x = jnp.zeros((1, 4, 16))
+    for kw in (dict(mixer='none', ffn='none'),
+               dict(mixer='none', parallel=True),
+               dict(mixer='state-space')):
+        with pytest.raises(ValueError):
+            TransformerBlock(dim=16, num_heads=2, **kw).init(
+                jax.random.key(0), x)
+    with pytest.raises(ValueError, match='unrolled'):
+        TransformerStack(
+            dim=16, num_heads=2, scan_layers=True,
+            block_kwargs={'mixer': 'ssm', 'ffn': 'none', 'ssm_kwargs': {
+                'heads': 4, 'head_dim': 4, 'state': 4}}).init(
+                    jax.random.key(0), x, x, x)
+
+
+def test_the_configuration_file_states_its_cut():
+    import json
+    with open(os.path.join(ROOT, 'benchmarks', 'configs',
+                           'nemotron-3-super-serve.json')) as f:
+        cfg = json.load(f)
+    with open('/opt/skills/guides/model-configs/architectures.jsonl'
+              ) if os.path.exists(
+            '/opt/skills/guides/model-configs/architectures.jsonl'
+    ) else open(os.devnull) as f:
+        rows = [json.loads(line) for line in f]
+    assert cfg['reduced'] == [
+        'num_hidden_layers', 'hybrid_override_pattern', 'n_routed_experts',
+        'vocab_size', 'num_nextn_predict_layers']
+    assert set(cfg['reduced_why']) == set(cfg['published']) == set(
+        cfg['reduced'])
+    assert cfg['published']['hybrid_override_pattern'].count(
+        cfg['hybrid_override_pattern']) == 4
+    widths = dict(
+        hidden_size=4096, mamba_num_heads=128, mamba_head_dim=64,
+        ssm_state_size=128, n_groups=8, conv_kernel=4, chunk_size=128,
+        num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+        moe_latent_size=1024, moe_intermediate_size=2688,
+        moe_shared_expert_intermediate_size=5376, num_experts_per_tok=22,
+        routed_scaling_factor=5, layer_norm_epsilon=1e-5)
+    assert {k: cfg[k] for k in widths} == widths
+    assert cfg['published']['n_routed_experts'] == 512
+    assert cfg['experts_held'] == [0, cfg['n_routed_experts']] == [0, 128]
+    for row in rows:
+        if row['source_url'] == cfg['source']:
+            differ = {k for k, v in row['config'].items()
+                      if cfg.get(k) != v}
+            assert differ == set(cfg['reduced'])
+            assert {k: cfg['published'][k] for k in differ} == {
+                k: row['config'][k] for k in differ}
+    # The arithmetic of the cut: 4.648 B parameters.
+    count = sum(int(np.prod(shape))
+                for shape, _ in DRIVER.shapes(cfg).values())
+    assert abs(count - 4.648e9) < 1e6

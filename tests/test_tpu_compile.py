@@ -412,6 +412,20 @@ def test_scanned_lm_decode_step_moves_no_cache(chip, monkeypatch,
     assert mem.temp_size_in_bytes < LAYER_K_BYTES
 
 
+def _shape_table_params(driver, cfg):
+    """The parameter tree of a driver's shape table as abstract values,
+    in the types the cell serves in."""
+    params = {'params': {}}
+    for path, (shape, _) in driver.shapes(cfg).items():
+        node = params['params']
+        for name in path[:-1]:
+            node = node.setdefault(name, {})
+        node[path[-1]] = jax.ShapeDtypeStruct(
+            shape, jnp.float32 if path[-1] in driver.FLOAT32_LEAVES
+            else jnp.bfloat16)
+    return params
+
+
 def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
         chip, monkeypatch):
     """The token step of the latent-attention / sparse-expert model at
@@ -440,14 +454,7 @@ def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
                            'decode-32k-x16.json')) as f:
         traffic = json.load(f)
     model = driver.build_lm(cfg)
-    params = {'params': {}}
-    for path, (shape, _) in driver.shapes(cfg).items():
-        node = params['params']
-        for name in path[:-1]:
-            node = node.setdefault(name, {})
-        node[path[-1]] = jax.ShapeDtypeStruct(
-            shape, jnp.float32 if path[-1] in driver.FLOAT32_LEAVES
-            else jnp.bfloat16)
+    params = _shape_table_params(driver, cfg)
     sessions, t_max = traffic['sessions'], traffic['t_max']
     caches = jax.eval_shape(
         lambda: model.make_decode_caches(sessions, t_max))
@@ -501,14 +508,7 @@ def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
                            'decode-64k-x12.json')) as f:
         traffic = json.load(f)
     model = driver.build_lm(cfg)
-    params = {'params': {}}
-    for path, (shape, _) in driver.shapes(cfg).items():
-        node = params['params']
-        for name in path[:-1]:
-            node = node.setdefault(name, {})
-        node[path[-1]] = jax.ShapeDtypeStruct(
-            shape, jnp.float32 if path[-1] in driver.FLOAT32_LEAVES
-            else jnp.bfloat16)
+    params = _shape_table_params(driver, cfg)
     sessions, t_max = traffic['sessions'], traffic['t_max']
     caches = jax.eval_shape(
         lambda: model.make_decode_caches(sessions, t_max))
@@ -538,6 +538,84 @@ def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes
             ) <= 15.0 * 2 ** 30
+
+
+def test_hybrid_stack_decode_step_aliases_every_state_and_fits(
+        chip, monkeypatch):
+    """The token step of the recurrent + attention + latent-expert stack
+    at the published widths and the traffic of
+    ``nemotron-3-super.decode-32k`` (11 layers, 48 sessions, five
+    ``(48, 128, 64, 128)`` float32 states beside one 33792-row slab of 2
+    KV heads), caches donated: the attention layer's step resolves to
+    the kernel at 2 KV heads x 1024 rows; every state and the slab alias
+    the result; nothing as large as one layer's 48 states (201 MB) is
+    copied, sliced or written back, and no temporary is that large (the
+    state update and its read against C are one fusion; the experts'
+    hidden activations of all 128 held experts are 33 MB); arguments +
+    temporaries stay under 15.0 GiB. The reset between requests writes
+    every state over in place, under its scope's name, with no
+    temporary."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_hybrid as driver
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'nemotron-3-super-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-32k-x48.json')) as f:
+        traffic = json.load(f)
+    model = driver.build_lm(cfg)
+    params = _shape_table_params(driver, cfg)
+    sessions, t_max = traffic['sessions'], traffic['t_max']
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(sessions, t_max))
+    assert [None if c is None else tuple(x.shape for x in c[:2])
+            for c in caches] == 5 * [None, (
+                (sessions, 128, 64, 128), (sessions, 3, 10240))] + [
+                    2 * ((sessions, 2, t_max, 128),)]
+    assert caches[1].state.dtype == jnp.float32
+    stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
+    tok = jnp.zeros((sessions, 1), jnp.int32)
+    programs = driver.make_programs(model, cfg)
+    restore, step = programs[-2:]
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+    with decode_impl_traces() as traces:
+        compiled = step.lower(
+            *described((params, tok, caches, stats))).compile()
+    assert [(t['resolved'], t['cache'], t['step']) for t in traces] == [
+        ('kernel', 'layer', {'heads': 2, 'block_k': 1024,
+                             'bytes': 1 << 20})]
+    hlo = compiled.as_text()
+    # 48 tokens: every held expert on every token, no grouped matmul
+    assert 'flash_decode' in hlo and 'ragged-dot' not in hlo
+    state_bytes = sessions * 128 * 64 * 128 * 4
+    assert _cache_sized_moves(hlo, state_bytes) == []
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < state_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            ) <= 15.0 * 2 ** 30
+    states = [c if hasattr(c, 'state') else None for c in caches]
+    restored = restore.lower(*described(
+        (caches, states, jnp.zeros((), jnp.int32)))).compile()
+    assert restored.memory_analysis().temp_size_in_bytes < 1 << 20
+    # Everything but the slab's 4-byte length, which is set, not kept.
+    assert restored.memory_analysis().alias_size_in_bytes >= (
+        cache_bytes - 4)
+    assert restored.as_text().count('lm.state_restore') >= 10
 
 
 @pytest.mark.parametrize('remat_policy, forwards', [
