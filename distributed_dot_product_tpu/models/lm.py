@@ -31,6 +31,7 @@ TPU-first notes:
 """
 
 import dataclasses
+import functools
 import warnings
 from collections import OrderedDict
 from typing import Any, Optional
@@ -234,13 +235,13 @@ class TransformerLM(nn.Module):
         (:func:`~distributed_dot_product_tpu.train.make_lm_train_step`
         psums both and divides).
 
-        ``chunk``: CHUNKED cross-entropy — the loss scans row chunks of
-        the final hidden states, computing each chunk's ``(C, vocab)``
-        logits + logsumexp inside a ``jax.checkpoint`` so neither pass
-        ever materializes the full ``(T, vocab)`` logits (fp32 logits
-        at T=131K × 32K vocab are 17 GiB — measured OOM on a 16 GiB
-        chip; chunked, the live score memory is O(chunk·vocab)).
-        ``None`` = unchunked (fine at short T)."""
+        ``chunk``: CHUNKED cross-entropy — :func:`head_loss` scans row
+        chunks of the final hidden states and a chunk's ``(C, vocab)``
+        logits never leave their scan iteration, differentiated or
+        not, so no pass materializes the full ``(T, vocab)`` logits
+        (fp32 logits at T=131K × 32K vocab are 17 GiB — measured OOM on
+        a 16 GiB chip; chunked, the live score memory is
+        O(chunk·vocab)). ``None`` = one chunk (fine at short T)."""
         x = self._embed(tokens)
         x = self.stack(x, x, x, None, segment_ids=segment_ids,
                        deterministic=deterministic,
@@ -251,43 +252,8 @@ class TransformerLM(nn.Module):
     def _nll(self, x, targets, chunk):
         x = self.ln_f(self._collapse(x))
         table = self._head_table().astype(jnp.float32)
-        tn = x.shape[-2]
-        targets = targets.astype(jnp.int32)
-
-        def chunk_nll(x_c, t_c):
-            logits = jnp.einsum('...cd,vd->...cv',
-                                x_c.astype(jnp.float32), table)
-            if self.logit_scale != 1.0:
-                logits = logits * self.logit_scale
-            lse = jax.scipy.special.logsumexp(logits, axis=-1)
-            valid = t_c >= 0
-            ll = jnp.take_along_axis(
-                logits, jnp.where(valid, t_c, 0)[..., None],
-                -1)[..., 0]
-            s = jnp.sum(jnp.where(valid, lse - ll, 0.0))
-            return s, jnp.sum(valid.astype(jnp.float32))
-
-        if chunk is None or chunk >= tn:
-            return chunk_nll(x, targets)
-        pad = (-tn) % chunk
-        if pad:
-            x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pad), (0, 0)])
-            targets = jnp.pad(targets, [(0, 0)] * (targets.ndim - 1)
-                              + [(0, pad)], constant_values=-1)
-        n = (tn + pad) // chunk
-        xr = jnp.moveaxis(x.reshape(*x.shape[:-2], n, chunk,
-                                    x.shape[-1]), -3, 0)
-        tr = jnp.moveaxis(targets.reshape(*targets.shape[:-1], n, chunk),
-                          -2, 0)
-
-        @jax.checkpoint
-        def body(carry, xs):
-            s, c = chunk_nll(*xs)
-            return (carry[0] + s, carry[1] + c), None
-
-        (s, c), _ = jax.lax.scan(
-            body, (jnp.float32(0.0), jnp.float32(0.0)), (xr, tr))
-        return s, c
+        return head_loss(x, table, targets.astype(jnp.int32), chunk,
+                         self.logit_scale)
 
     # -- cached generation --------------------------------------------
 
@@ -311,6 +277,103 @@ class TransformerLM(nn.Module):
         """One cached generation step for ``tokens (B, 1)``."""
         caches, x = self.stack.decode(self._embed(tokens), caches)
         return caches, self._head(x)
+
+
+def _head_loss_scan(x, table, targets, chunk, logit_scale, with_grad):
+    """The scan behind :func:`head_loss`. A chunk builds its float32
+    logits ONCE; from them come the summed loss and the valid count
+    and, ``with_grad``, the chunk's whole gradient while they are still
+    live: ``dlogits = valid · logit_scale · (softmax − onehot)``, its
+    ``dx`` rows (the scan's ``ys``) and its share of ``dW`` (an
+    accumulator in the carry). Returns ``(s, count)``, and with them
+    ``(dx, dW)`` at a unit cotangent of ``s``, both float32: ``dx`` is
+    rounded to ``x``'s type once, after the cotangent has scaled it, as
+    plain autodiff rounds it."""
+    tn, dim = x.shape[-2:]
+    if chunk is None or chunk >= tn:
+        chunk = tn
+    pad = (-tn) % chunk
+    if pad:
+        # Padded targets are -1: their rows count nothing and their
+        # dlogits are zero.
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pad), (0, 0)])
+        targets = jnp.pad(targets, [(0, 0)] * (targets.ndim - 1)
+                          + [(0, pad)], constant_values=-1)
+    n = (tn + pad) // chunk
+    xr = jnp.moveaxis(x.reshape(*x.shape[:-2], n, chunk, dim), -3, 0)
+    tr = jnp.moveaxis(targets.reshape(*targets.shape[:-1], n, chunk),
+                      -2, 0)
+
+    def body(carry, xs):
+        x_c, t_c = xs
+        x_c = x_c.astype(jnp.float32)
+        logits = jnp.einsum('...cd,vd->...cv', x_c, table)
+        if logit_scale != 1.0:
+            logits = logits * logit_scale
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        valid = t_c >= 0
+        # The target's column by comparison, not by index: the same
+        # select gives the log-likelihood and the onehot of dlogits
+        # (no gather, no scatter); -1 matches no column.
+        hit = t_c[..., None] == jax.lax.broadcasted_iota(
+            jnp.int32, logits.shape, logits.ndim - 1)
+        ll = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        s = carry[0] + jnp.sum(jnp.where(valid, lse - ll, 0.0))
+        c = carry[1] + jnp.sum(valid.astype(jnp.float32))
+        if not with_grad:
+            return (s, c), None
+        dlogits = jnp.where(
+            valid[..., None],
+            jnp.exp(logits - lse[..., None]) - hit.astype(jnp.float32),
+            0.0)
+        if logit_scale != 1.0:
+            dlogits = dlogits * logit_scale
+        dx_c = jnp.einsum('...cv,vd->...cd', dlogits, table)
+        dw = carry[2] + jnp.einsum('...cv,...cd->vd', dlogits, x_c)
+        return (s, c, dw), dx_c
+
+    zero = jnp.float32(0.0)
+    if not with_grad:
+        return jax.lax.scan(body, (zero, zero), (xr, tr))[0]
+    (s, c, dw), dxs = jax.lax.scan(
+        body, (zero, zero, jnp.zeros_like(table)), (xr, tr))
+    dx = jnp.moveaxis(dxs, 0, -3).reshape(*x.shape[:-2], n * chunk, dim)
+    return (s, c), (dx[..., :tn, :], dw)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def head_loss(x, table, targets, chunk, logit_scale):
+    """Summed negative log-likelihood and valid-target count of the
+    head over ``x (..., T, d)`` (the final norm's output) against the
+    float32 ``table (vocab, d)`` and int32 ``targets (..., T)``
+    (``< 0``: ignored), scanned in ``chunk``-row chunks (``None`` or
+    ``>= T``: one chunk).
+
+    The loss is the last thing a forward computes and its cotangent is
+    a scalar, so differentiated it takes its gradient IN THE FORWARD
+    pass: a chunk's logits give the loss, ``dx`` and ``dW`` and are
+    dropped — three vocabulary-wide matmuls a chunk, nothing
+    ``(chunk, vocab)``-sized kept or rebuilt; the backward rule scales
+    ``(dx, dW)`` by the sum's cotangent (the count carries none).
+    Un-differentiated it is the same scan with the logits matmul
+    alone. Reverse mode, first order only (a ``custom_vjp``)."""
+    return _head_loss_scan(x, table, targets, chunk, logit_scale, False)
+
+
+def _head_loss_fwd(x, table, targets, chunk, logit_scale):
+    out, grads = _head_loss_scan(x, table, targets, chunk, logit_scale,
+                                 True)
+    # An empty array carries x's type to the backward rule.
+    return out, (*grads, jnp.zeros((0,), x.dtype))
+
+
+def _head_loss_bwd(chunk, logit_scale, residuals, cotangents):
+    dx, dw, like = residuals
+    g = cotangents[0]
+    return (g * dx).astype(like.dtype), g * dw, None
+
+
+head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
 # Compiled generation programs keyed by (module, donate, batch,
@@ -428,9 +491,13 @@ def graphlint_entrypoints():
     head at bf16 — its einsum's explicit fp32 accumulation IS the PR-3
     contract the f32-accum rule encodes — and the chunked token-mean
     loss (nll_sum) whose scan must keep its logsumexp math in f32,
-    registered at f32 AND at the bf16 serving dtype. The projections
-    are the owned dense (models/dense.py), so the bf16 entry traces
-    with zero f32-accum waivers."""
+    registered at f32 AND at the bf16 serving dtype. The loss entries
+    trace the loss un-differentiated AND its gradient: a rule reads the
+    primal through :func:`head_loss`'s ``custom_vjp`` call but not its
+    forward rule (a callable, traced only under differentiation), and
+    that rule holds the loss's other two matmuls. The projections are
+    the owned dense (models/dense.py), so the bf16 entry traces with
+    zero f32-accum waivers."""
 
     def head_bf16():
         from distributed_dot_product_tpu.analysis.registry import (
@@ -462,7 +529,10 @@ def graphlint_entrypoints():
         targets = jax.ShapeDtypeStruct((1, 16), jnp.int32)
 
         def fn(p, tok, tgt):
-            return model.apply(p, tok, tgt, chunk=4, method='nll_sum')
+            def nll(p):
+                return model.apply(p, tok, tgt, chunk=4, method='nll_sum')
+
+            return nll(p), jax.grad(nll, has_aux=True)(p)[0]
 
         return TraceSpec(name=name, fn=fn,
                          args=(params, jax.ShapeDtypeStruct(

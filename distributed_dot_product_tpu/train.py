@@ -203,8 +203,9 @@ def make_lm_train_step(model, optimizer, mesh, seq_axis=SEQ_AXIS,
     the valid positions distribute across shards — a plain pmean of
     per-shard means would over-weight shards with few valid tokens.
     ``loss_chunk`` bounds the live logit memory: the model's
-    ``nll_sum`` scans row chunks of that size with per-chunk remat, so
-    neither pass materializes the (T, vocab) logits (None = unchunked).
+    ``nll_sum`` scans row chunks of that size and takes each chunk's
+    gradient while its logits are live (``models.lm.head_loss``), so
+    no pass materializes the (T, vocab) logits (None = one chunk).
     ``guard=True``: NaN/Inf-guarded update + ``{'loss', 'bad_step',
     'grad_norm'}`` record, exactly as in :func:`make_train_step`
     (donation refused for the same rollback reason).

@@ -13,7 +13,9 @@ import numpy as np
 import optax
 import pytest
 
-from distributed_dot_product_tpu.analysis.jaxpr_rules import _iter_eqns
+from distributed_dot_product_tpu.analysis.jaxpr_rules import (
+    _iter_eqns, _sub_jaxprs,
+)
 from distributed_dot_product_tpu.models import attention, lm, transformer
 from distributed_dot_product_tpu.models.lm import TransformerLM, lm_targets
 from distributed_dot_product_tpu.obs.spans import (
@@ -258,6 +260,64 @@ def test_passes_show_in_op_names(remat_policy):
     assert {n.rsplit('/', 2)[-2] for n in flash_bwd} >= {
         'flash_bwd_fused', 'flash_bwd_dq', 'flash_bwd_dkv'}
     assert all('transpose(jvp(' in n for n in flash_bwd)
+
+
+def head_scan_vocab_dots(fn, *args, vocab=64):
+    """The ``dot_general``s with a vocabulary-sized dimension inside the
+    scans opened under ``lm.head_loss``, and the primitives of every
+    equation under that scope (``tiny_lm``'s vocabulary is 64 where its
+    width is 32 and the loss's chunk 16). A sub-jaxpr's name stacks are
+    relative to the equation that holds it, so the walk carries what
+    it is under."""
+    dots, under = [], set()
+
+    def walk(jaxpr, in_head, in_scan):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            head = in_head or 'lm.head_loss' in str(
+                eqn.source_info.name_stack)
+            if head:
+                under.add(name)
+            if head and in_scan and name == 'dot_general' and any(
+                    vocab in v.aval.shape
+                    for v in (*eqn.invars, *eqn.outvars)):
+                dots.append(eqn)
+            for sub in _sub_jaxprs(eqn):
+                walk(sub, head, in_scan or (head and name == 'scan'))
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, False, False)
+    return dots, under
+
+
+def test_head_takes_its_gradient_in_the_forward_pass():
+    """The "counter" of a static mechanism: differentiated, the loss's
+    scan over 4 chunks builds a chunk's logits once and takes dx and dW
+    from them — three vocabulary-wide matmuls, under the forward pass's
+    name, with no checkpoint to rebuild a fourth; un-differentiated it
+    holds the logits matmul alone."""
+    model = tiny_lm(distributed=False)
+    tokens = jax.random.randint(jax.random.key(1), (1, 64), 0, 64)
+    params = model.init(jax.random.key(0), tokens)
+
+    def loss(p):
+        return model.apply(p, tokens, lm_targets(tokens), chunk=16,
+                           method='nll_sum')[0]
+
+    dots, under = head_scan_vocab_dots(jax.value_and_grad(loss), params)
+    assert len(dots) == 3
+    assert not {'checkpoint', 'custom_vjp_call'} & under
+    dots, under = head_scan_vocab_dots(loss, params)
+    assert len(dots) == 1
+    assert 'custom_vjp_call' in under and 'checkpoint' not in under
+    # The step as compiled: the same three under the forward pass's
+    # name, nothing of the head rematerialized.
+    head = [n for n in train_names(None) if '/lm.head_loss/' in n]
+    assert not any('rematted_computation' in n or 'checkpoint' in n
+                   for n in head)
+    assert len({n for n in head if n.endswith('/dot_general')
+                and 'transpose(jvp(' not in n and 'jvp(' in n}) == 3
+    assert not any(n.endswith('/dot_general') for n in head
+                   if 'transpose(jvp(' in n)
 
 
 def kernel_names(fn, *args):

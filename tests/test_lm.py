@@ -9,9 +9,11 @@ generation through the KV caches reproduces the prefix; checkpoint /
 resume mid-run continues the same trajectory.
 """
 
+import functools
 import os
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,27 +103,129 @@ def test_lm_step_matches_unsharded_loss_and_grad(mesh_kind):
                                    atol=2e-5, rtol=1e-4)
 
 
-def test_lm_chunked_nll_matches_unchunked():
-    """Chunked cross-entropy (scan + per-chunk remat) is the same math
-    — values and gradients — including a chunk that doesn't divide T."""
-    m = _model(attn_kwargs=dict(distributed=False))
+def _plain_nll(m, params, tokens, targets, seg):
+    """The loss as plain autodiff sees it, written here and not taken
+    from the module: the final norm's output against the float32 table,
+    the whole ``(T, vocab)`` logits at once, ``logsumexp`` minus the
+    target's logit by ``take_along_axis`` — no chunk, no fused rule."""
+    def nll(mdl):
+        x = mdl._embed(tokens)
+        x = mdl.stack(x, x, x, None, segment_ids=seg, deterministic=False,
+                      dropout_seed=None)
+        x = mdl.ln_f(mdl._collapse(x)).astype(jnp.float32)
+        logits = mdl.logit_scale * jnp.einsum(
+            '...d,vd->...v', x, mdl._head_table().astype(jnp.float32))
+        valid = targets >= 0
+        ll = jnp.take_along_axis(
+            logits, jnp.where(valid, targets, 0)[..., None], -1)[..., 0]
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        return (jnp.sum(jnp.where(valid, lse - ll, 0.0)),
+                jnp.sum(valid.astype(jnp.float32)))
+
+    return nn.apply(nll, m)(params)
+
+
+def _assert_grads_close(got, want, dtype):
+    """float32: element for element. bfloat16 compute: a parameter's
+    gradient as a whole, by the norm of its error against the norm of
+    the reference (single elements flip a rounding) — tight all the
+    same, because ``dx`` leaves the head in float32 and is rounded where
+    plain autodiff rounds it: a ``dx`` rounded before the cotangent
+    scales it as well reads 1e-2 here."""
+    got = jax.tree_util.tree_leaves_with_path(got)
+    for (path, a), b in zip(got, jax.tree.leaves(want), strict=True):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype is None:
+            np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4,
+                                       err_msg=jax.tree_util.keystr(path))
+        else:
+            assert (np.linalg.norm(a - b)
+                    <= 1e-4 * np.linalg.norm(b) + 1e-9), (
+                jax.tree_util.keystr(path))
+
+
+def _mean(nll):
+    def loss(p):
+        s, c = nll(p)
+        return s / c
+    return loss
+
+
+@functools.cache
+def _nll_case(tie, logit_scale, dtype):
+    """A model, its batch — the copy task's prefixes are rows whose
+    target is -1, and here a whole chunk of them is — and what the plain
+    loss gives there: the sum, the count, the mean and its gradient."""
+    m = _model(attn_kwargs=dict(distributed=False), tie_embeddings=tie,
+               logit_scale=logit_scale, dtype=dtype)
     tokens, targets, seg = make_copy_batch(jax.random.key(5), 2, 64,
                                            VOCAB, 16)
+    targets = targets.at[0, 16:32].set(-1)
     params = m.init(jax.random.key(1), tokens[:, :16])
 
-    def loss(p, chunk):
-        s, c = m.apply(p, tokens, targets, segment_ids=seg, chunk=chunk,
+    def plain(p):
+        return _plain_nll(m, p, tokens, targets, seg)
+
+    return (m, params, (tokens, targets, seg), plain(params),
+            jax.value_and_grad(_mean(plain))(params))
+
+
+@pytest.mark.parametrize('chunk', [16, 24, 64, None])
+@pytest.mark.parametrize('dtype', [None, jnp.bfloat16],
+                         ids=['float32', 'bfloat16'])
+@pytest.mark.parametrize('logit_scale', [1.0, 0.5])
+@pytest.mark.parametrize('tie', [True, False], ids=['tied', 'untied'])
+def test_lm_chunked_nll_matches_unchunked(tie, logit_scale, dtype, chunk):
+    """The chunked cross-entropy takes its gradient in the forward pass
+    (``models.lm.head_loss``): the same math as the plain loss — the
+    sum, the count and EVERY parameter's gradient — tied or untied head,
+    scaled logits, either compute type, a chunk that does not divide T
+    (24), one chunk (64, None), rows whose target is -1."""
+    m, params, (tokens, targets, seg), (want_s, want_c), (
+        want, want_g) = _nll_case(tie, logit_scale, dtype)
+
+    def nll(p):
+        return m.apply(p, tokens, targets, segment_ids=seg, chunk=chunk,
                        method='nll_sum')
+
+    got_s, got_c = nll(params)          # un-differentiated: the primal
+    assert float(got_c) == float(want_c) == float(jnp.sum(targets >= 0))
+    np.testing.assert_allclose(float(got_s), float(want_s), rtol=2e-6)
+    got, got_g = jax.value_and_grad(_mean(nll))(params)  # the fused rule
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
+    _assert_grads_close(got_g, want_g, dtype)
+
+
+@pytest.mark.parametrize('tie', [True, False], ids=['tied', 'untied'])
+def test_lm_chunked_nll_through_the_train_step(tie):
+    """The step divides the psum'd sum by the psum'd count, so the
+    sum's cotangent is ``1 / count`` and not 1, and each shard scans its
+    own rows in two chunks: SGD(1.0) makes the updated parameters the
+    plain loss's ``params - grad``."""
+    tokens, targets, seg = make_copy_batch(jax.random.key(7), 2, 64,
+                                           VOCAB, 16)
+    m = _model(tie_embeddings=tie, logit_scale=0.5)
+    m_local = m.clone(attn_kwargs=dict(distributed=False))
+    params = m.init(jax.random.key(1), tokens[:, :16])
+    opt = optax.sgd(1.0)
+    step = make_lm_train_step(m, opt, seq_mesh(4), donate=False,
+                              loss_chunk=8)
+    new_params, _, loss = step(params, opt.init(params),
+                               (tokens, targets, seg))
+
+    def plain(p):
+        s, c = _plain_nll(m_local, p, tokens, targets, seg)
         return s / c
 
-    for chunk in (16, 24, 64, None):
-        np.testing.assert_allclose(float(loss(params, chunk)),
-                                   float(loss(params, None)), rtol=1e-6)
-    g_c = jax.grad(lambda p: loss(p, 24))(params)
-    g_u = jax.grad(lambda p: loss(p, None))(params)
-    for a, b in zip(jax.tree.leaves(g_c), jax.tree.leaves(g_u)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-5, rtol=1e-4)
+    want_loss, g = jax.value_and_grad(plain)(params)
+    assert float(jnp.sum(targets >= 0)) > 1
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-5)
+    for got_l, p_l, g_l in zip(jax.tree.leaves(new_params),
+                               jax.tree.leaves(params),
+                               jax.tree.leaves(g), strict=True):
+        np.testing.assert_allclose(np.asarray(got_l),
+                                   np.asarray(p_l - g_l),
+                                   atol=2e-5, rtol=1e-4)
 
 
 def test_greedy_generate_validates_steps():

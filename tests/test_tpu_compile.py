@@ -653,7 +653,7 @@ def test_scanned_lm_train_step_runs_the_flash_forward_once(
     tok = jnp.zeros((1, t), jnp.int32)
     hlo = _compile(chip, step, params,
                    jax.eval_shape(optimizer.init, params),
-                   (tok, tok)).as_text()
+                   (tok, tok), donate=(0, 1)).as_text()
     calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
                        r'op_name="([^"]*)"', hlo)
     fwd = [n for n in calls if n.endswith('flash_fwd/pallas_call')]
@@ -662,3 +662,13 @@ def test_scanned_lm_train_step_runs_the_flash_forward_once(
     for stacked in (f'bf16[{layers},1,32,{t},128]', f'f32[{layers},1,32,{t}]'):
         assert (stacked in hlo) == (forwards == 1)
     assert f'f32[{layers},32,{t},1]' not in hlo
+    # The head takes its gradient in the forward pass: the logits, dx and
+    # dW matmuls of a loss chunk and no fourth, nothing of it rebuilt.
+    # (Parameters and optimizer state donated, as a training loop does:
+    # held twice they pass the chip's memory, and XLA's own
+    # rematerialization then rebuilds a chunk's logits for dW.)
+    head = re.findall(r' convolution\([^\n]*op_name="([^"]*/lm\.head_loss/'
+                      r'[^"]*)"', hlo)
+    assert len(head) == 3 and all(
+        'transpose(jvp(' not in n and 'rematted_computation' not in n
+        for n in head)
