@@ -47,17 +47,23 @@ width where it is not ``n_shared x hidden``. Both projections are
 linear and bias-free, so the parts of a latent layer divided over
 several holders, each through its own ``W_up``, still add up.
 
-``dense_tokens``: a call of at most that many tokens runs EVERY held
-expert on every token, the gate zero where the expert was not picked —
-two (three) batched matmuls that stream each held expert's weights once,
-with no sort, no gather and no grouped matmul. The same numbers; the
-trade is bytes for rows: XLA's grouped matmul pays a whole row tile a
-group, so where a decode step hits most held experts with two or three
-rows each (48 tokens x top-22 over 128 of 512: 17.1 ms a step at 38 % of
-the hit experts' bytes over the HBM peak; chip, PR 32) streaming all of
-them is the faster route, and its time does not hang on the routing. A
+``dense_tokens``: a call of at most that many tokens runs every HIT
+held expert — one that some token of the call picked — on every token,
+the gate zero where the token did not pick it: one Pallas program a
+layer (``ops/pallas_experts.hit_experts``, ``moe_hit_experts``) over the
+call's hit list, which streams each hit expert's weights once and no
+other expert's, adds the picks up in a float32 accumulator, and has no
+sort, no gather and no grouped matmul. The same numbers; the trade is
+rows for tiles: XLA's grouped matmul pays a whole row tile a group, so
+where a decode step hits most held experts with two or three rows each
+(48 tokens x top-22 over 128 of 512: 17.1 ms a step at 38 % of the hit
+experts' bytes over the HBM peak; chip, PR 32) giving every hit expert
+all 48 rows is the faster route. The hit count is a value, not a shape:
+one program whatever the routing, its time the hit experts' bytes. A
 prefill chunk is far past the bound and takes the sorted route. 0, the
-default, never takes it.
+default, never takes it. Under ``jax.grad`` the route differentiates
+through the batched matmuls over every held expert
+(``hit_experts_reference``).
 """
 
 from typing import Any, Optional, Tuple
@@ -69,6 +75,9 @@ from jax import lax
 
 from distributed_dot_product_tpu.models.dense import OwnedDense
 from distributed_dot_product_tpu.obs.spans import device_scope
+from distributed_dot_product_tpu.ops.pallas_experts import (
+    hit_experts, hit_list,
+)
 
 __all__ = ['GatedMLP', 'PlainMLP', 'SparseExperts']
 
@@ -130,30 +139,21 @@ class SparseExperts(nn.Module):
                                                     out_axis=-1,
                                                     batch_axis=(0,))
 
-    def _dense(self, tokens, picked, gates, w_gate, w_up, w_down, act,
-               lo, hi):
-        """Every held expert on every token of ``tokens (n, wide)``:
-        the hidden activations ``(held, n, hidden)`` scaled by the
-        token's gate for that expert (zero where it was not picked) and
-        contracted with ``w_down`` over expert and hidden together, so
-        the picks add up in the matmul's float32 accumulator."""
-        dtype = tokens.dtype
+    def _dense(self, tokens, picked, gates, counts, w_gate, w_up, w_down,
+               act, lo, hi):
+        """Every HIT held expert on every token of ``tokens (n, wide)``,
+        the token's gate for it zero where it was not picked: the gate
+        table and the step's hit list, then one kernel that streams the
+        hit experts' weights and adds the picks up in float32."""
         with device_scope('lm.moe_route'):
             table = jnp.zeros((tokens.shape[0], self.n_experts),
                               jnp.float32)
             table = table.at[jnp.arange(tokens.shape[0])[:, None],
                              picked].set(gates)
-            gate_of = table[:, lo:hi].T[..., None]          # (held, n, 1)
+            hits, count = hit_list(counts[lo:hi])
         with device_scope('lm.moe_experts'):
-            def every(w):
-                return jnp.einsum('nk,ekh->enh', tokens, w.astype(dtype),
-                                  preferred_element_type=jnp.float32)
-            hid = (act(every(w_up)) if w_gate is None
-                   else act(every(w_gate)) * every(w_up))
-            return jnp.einsum(
-                'enh,ehk->nk', (hid * gate_of).astype(dtype),
-                w_down.astype(dtype),
-                preferred_element_type=jnp.float32).astype(dtype)
+            return hit_experts(tokens, table[:, lo:hi], hits, count,
+                               w_gate, w_up, w_down, act)
 
     @nn.compact
     def __call__(self, x):
@@ -227,8 +227,8 @@ class SparseExperts(nn.Module):
                  init_fn=lambda: jnp.zeros((n, k), jnp.int32))
 
         if dense:
-            y = self._dense(tokens, picked, gates, w_gate, w_up, w_down,
-                            act, lo, hi)
+            y = self._dense(tokens, picked, gates, counts, w_gate, w_up,
+                            w_down, act, lo, hi)
         else:
             with device_scope('lm.moe_experts'):
                 def grouped(a, w):

@@ -1,0 +1,234 @@
+# -*- coding: utf-8 -*-
+"""
+The routed experts of a decode step: ONE Pallas program a layer that
+streams the experts the step's tokens picked, and no others.
+
+A decode step of a sparse-expert layer is bound by the bytes of the
+experts' weights: 48 tokens give an expert's matrices two or three rows
+each, so XLA's grouped matmul pays a whole row tile a group for them
+(``models/moe.py``, the sorted route) and a batched matmul over EVERY
+held expert streams the quarter of them that no token picked. This
+kernel keeps the batched form — every hit expert gets all ``n`` rows,
+the gate zero where a token did not pick it — and takes the unhit
+experts' bytes away:
+
+- the step's **hit list** (the held experts with a pick, compacted to
+  the front of a ``(held,)`` vector, and their number; :func:`hit_list`)
+  is scalar-prefetched, and the weights' index maps read slot ``i`` of
+  it: grid ``(held, hidden tiles)``, static, so the hit count changes no
+  shape and no program;
+- a slot past the count repeats the last hit expert's last tile — an
+  unchanged block index is not fetched again (what
+  ``ops/pallas_decode.py`` does for a slot's unfilled K/V blocks) — and
+  its body is skipped;
+- a grid step is one expert's ``(wide, tile)`` columns of ``w_up`` (and
+  ``w_gate``) and the matching ``(tile, wide)`` rows of ``w_down``:
+  ``h = act(x w_up)`` (times the ``w_gate`` product where gated), scaled
+  by the expert's gate column, ``acc += h w_down`` into ONE ``(n, wide)``
+  float32 accumulator that stays in VMEM for the whole grid and is
+  written once, so the picks add up in float32;
+- the gate table rides whole, ``(n, held)`` float32 with the experts on
+  the lanes (24 KB at 48 x 128): a step takes its expert's column with
+  one masked lane reduce.
+
+VMEM plan (:func:`hidden_tile`): the weight blocks of a step,
+double-buffered, within :data:`_VMEM_BUDGET` and within
+:data:`_STEP_STREAM_BYTES` a step. At the hybrid cell's shapes (48
+tokens, ``wide`` 1024, ``hidden`` 2688 = 3 x 896, bfloat16): two blocks
+of 1.75 MiB, 7.0 MiB double-buffered; tokens, gates, output and
+accumulator 0.6 MiB; the step's temporaries (``(48, 896)`` float32
+activations, their bfloat16 copy, the ``(48, 1024)`` float32 product)
+0.5 MiB: 8.1 MiB of the compiler's 16 MiB. ``tests/test_tpu_compile.py``
+compiles it for a v5e.
+
+Off the TPU the kernel runs under the Pallas interpreter, as the other
+kernels do. :func:`hit_experts_reference` is the same layer as two
+(three) batched matmuls over every held expert: the tests' oracle, and
+the kernel's differentiation rule (reverse mode, first order).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from distributed_dot_product_tpu.ops.pallas_decode import (
+    _STEP_STREAM_BYTES, _VMEM_BUDGET, _pad_rows, _sublane,
+)
+
+__all__ = ['hit_list', 'hit_experts', 'hit_experts_reference',
+           'hidden_tile']
+
+
+def hit_list(counts):
+    """``(hits, count)`` for one call's ``counts (held,)`` picks a held
+    expert: the experts with at least one pick, in rising order at the
+    front of ``hits (held,) int32`` (zeros behind), and their number
+    ``count () int32``. A cumulative sum and a scatter: nothing is
+    sorted."""
+    held = counts.shape[0]
+    hit = counts > 0
+    slot = jnp.where(hit, jnp.cumsum(hit) - 1, held)
+    hits = jnp.zeros((held,), jnp.int32).at[slot].set(
+        jnp.arange(held, dtype=jnp.int32), mode='drop')
+    return hits, jnp.sum(hit, dtype=jnp.int32)
+
+
+def hidden_tile(wide, hidden, matrices, itemsize):
+    """Columns of ``hidden`` a grid step takes: the most 128-lane tiles
+    that divide it whose ``matrices`` blocks of ``wide x tile`` stay
+    within the step's stream target and, double-buffered, within the
+    VMEM plan; one lane tile where even that is past them; all of
+    ``hidden`` where it is no multiple of 128 (a block may be a whole
+    axis, whatever its length)."""
+    if hidden % 128:
+        return hidden
+    lanes = hidden // 128
+    step = matrices * wide * 128 * itemsize
+    return 128 * max([c for c in range(1, lanes + 1) if lanes % c == 0
+                      and c * step <= _STEP_STREAM_BYTES
+                      and 2 * c * step <= _VMEM_BUDGET] or [1])
+
+
+def hit_experts_reference(tokens, gates, w_gate, w_up, w_down, act):
+    """Every held expert on every token: the hidden activations
+    ``(held, n, hidden)`` scaled by the token's gate for that expert and
+    contracted with ``w_down`` over expert and hidden together."""
+    dtype = tokens.dtype
+
+    def every(w):
+        return jnp.einsum('nk,ekh->enh', tokens, w.astype(dtype),
+                          preferred_element_type=jnp.float32)
+    hid = (act(every(w_up)) if w_gate is None
+           else act(every(w_gate)) * every(w_up))
+    return jnp.einsum('enh,ehk->nk', (hid * gates.T[..., None]).astype(dtype),
+                      w_down.astype(dtype),
+                      preferred_element_type=jnp.float32).astype(dtype)
+
+
+def _kernel(hits_ref, count_ref, x_ref, g_ref, *refs, act, gated):
+    wg_ref = refs[0] if gated else None
+    wu_ref, wd_ref, o_ref, acc_ref = refs[gated:]
+    i, j = pl.program_id(0), pl.program_id(1)
+    last = jnp.logical_and(i == pl.num_programs(0) - 1,
+                           j == pl.num_programs(1) - 1)
+    expert = hits_ref[i]
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < count_ref[0])
+    def _():
+        x = x_ref[...]
+
+        def times(w_ref):
+            return jnp.dot(x, w_ref[0].astype(x.dtype),
+                           preferred_element_type=jnp.float32)
+        hid = act(times(wg_ref)) * times(wu_ref) if gated else act(
+            times(wu_ref))
+        lanes = jax.lax.broadcasted_iota(jnp.int32, g_ref.shape, 1)
+        gate = jnp.sum(jnp.where(lanes == expert, g_ref[...], 0.0),
+                       axis=1, keepdims=True)
+        acc_ref[...] += jnp.dot((hid * gate).astype(x.dtype),
+                                wd_ref[0].astype(x.dtype),
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(last)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _hit_experts(tokens, gates, hits, count, w_gate, w_up, w_down, act,
+                 interpret, tile):
+    n, wide = tokens.shape
+    held, _, hidden = w_up.shape
+    gated = w_gate is not None
+    if interpret is None:
+        interpret = jax.default_backend() != 'tpu'
+    tile = tile or hidden_tile(wide, hidden, 2 + gated,
+                               jnp.dtype(w_up.dtype).itemsize)
+    tiles = hidden // tile
+    # Rows ride padded to their sublane tile (Mosaic refuses a dot
+    # against a one-row operand); a padded token's gates are zero.
+    x = _pad_rows(tokens, _sublane(tokens.dtype))
+    g = _pad_rows(gates.astype(jnp.float32), _sublane(tokens.dtype))
+
+    # Slot i's expert and tile j; a slot past the count stays on the
+    # block the last hit slot ended on, which is then not fetched again.
+    def slot(i, j, hits, count):
+        live = i < count[0]
+        # (slot 0 where the count is 0: maximum last)
+        return (hits[jnp.maximum(jnp.minimum(i, count[0] - 1), 0)],
+                jnp.where(live, j, tiles - 1))
+
+    def up_idx(i, j, hits, count):
+        e, t = slot(i, j, hits, count)
+        return (e, 0, t)
+
+    def down_idx(i, j, hits, count):
+        e, t = slot(i, j, hits, count)
+        return (e, t, 0)
+
+    def whole(i, j, hits, count):
+        return (0, 0)
+
+    up_spec = pl.BlockSpec((1, wide, tile), up_idx)
+    out = pl.pallas_call(
+        functools.partial(_kernel, act=act, gated=gated),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(held, tiles),
+            in_specs=[pl.BlockSpec(x.shape, whole),
+                      pl.BlockSpec(g.shape, whole),
+                      *([up_spec] if gated else []), up_spec,
+                      pl.BlockSpec((1, tile, wide), down_idx)],
+            out_specs=pl.BlockSpec(x.shape, whole),
+            scratch_shapes=[pltpu.VMEM(x.shape, jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(x.shape, tokens.dtype),
+        interpret=interpret,
+        name='moe_hit_experts')(
+            hits, count.reshape(1), x, g,
+            *([w_gate] if gated else []), w_up, w_down)
+    return out[:n]
+
+
+def _hit_experts_fwd(tokens, gates, hits, count, w_gate, w_up, w_down, act,
+                     interpret, tile):
+    return (_hit_experts(tokens, gates, hits, count, w_gate, w_up, w_down,
+                         act, interpret, tile),
+            (tokens, gates, w_gate, w_up, w_down))
+
+
+def _hit_experts_bwd(act, interpret, tile, kept, cotangent):
+    # An unhit expert's gates are all zero: the batched form over every
+    # held expert has the kernel's gradients.
+    d_tokens, d_gates, d_gate, d_up, d_down = jax.vjp(
+        functools.partial(hit_experts_reference, act=act), *kept)[1](
+            cotangent)
+    return d_tokens, d_gates, None, None, d_gate, d_up, d_down
+
+
+_hit_experts.defvjp(_hit_experts_fwd, _hit_experts_bwd)
+
+
+def hit_experts(tokens, gates, hits, count, w_gate, w_up, w_down, act, *,
+                interpret=None, tile=None):
+    """``sum_e gates[:, e] * E_e(tokens)`` over the experts of the hit
+    list, ``(n, wide)`` in ``tokens``' type.
+
+    ``tokens (n, wide)`` in the compute type; ``gates (n, held)``
+    float32, a token's gate for each held expert, zero where it did not
+    pick it; ``hits (held,) int32`` / ``count () int32`` from
+    :func:`hit_list` (an expert outside the list adds nothing and its
+    weights are not read: its gates must be zero); ``w_up (held, wide,
+    hidden)``, ``w_down (held, hidden, wide)`` and, for a gated expert
+    ``act(x w_gate) * (x w_up)``, ``w_gate`` like ``w_up`` (else None),
+    as they are stored: a block is cast to the compute type in VMEM.
+    ``act`` is the activation, a function of one float32 array.
+    ``tile`` overrides :func:`hidden_tile` (tests)."""
+    return _hit_experts(tokens, gates, hits, count, w_gate, w_up, w_down,
+                        act, interpret, tile)

@@ -365,6 +365,132 @@ def test_the_dense_route_of_a_gated_layer_is_the_sorted_one():
         params, jnp.zeros((3, 5, 16))).as_text()
 
 
+# -- (c') the dense route's kernel: the hit experts and no others ---------
+
+def _layer_and_input(form, latent, held, seed=7, tokens=(3, 5)):
+    """A layer of 8 experts, top-3, scaling 2, and an input of which
+    tokens pick experts inside AND outside ``held``."""
+    kw = dict(n_experts=8, top_k=3, hidden=12, scaling=2.0,
+              experts_held=held, expert_form=form,
+              activation='relu2' if form == 'plain' else 'silu',
+              latent=latent, shared_hidden=20 if latent else None)
+    x = jnp.asarray(np.random.default_rng(seed).normal(size=tokens + (16,)),
+                    jnp.float32)
+    return kw, x, SparseExperts(**kw).init(jax.random.key(seed), x)
+
+
+@pytest.mark.parametrize('held', [None, (2, 7)], ids=['whole', 'share'])
+@pytest.mark.parametrize('latent', [None, 8], ids=['stream', 'latent'])
+@pytest.mark.parametrize('form', ['plain', 'gated'])
+def test_the_dense_route_is_the_sorted_route(form, latent, held):
+    """One result through the sorted grouped matmuls and through the
+    kernel over the call's hit list, for both forms of an expert, in the
+    stream and in a latent, holding every expert and a share that some
+    picks fall outside of; the dense route sorts nothing."""
+    kw, x, params = _layer_and_input(form, latent, held)
+    want, counts = SparseExperts(**kw).apply(params, x)
+    dense = SparseExperts(**kw, dense_tokens=15)
+    got, dense_counts = dense.apply(params, x)
+    np.testing.assert_allclose(got, want, atol=TOL)
+    np.testing.assert_array_equal(dense_counts, counts)
+    if held:
+        lo, hi = held
+        assert int(counts[:lo].sum() + counts[hi:].sum()) > 0
+    assert 'stablehlo.sort' not in jax.jit(dense.apply).lower(
+        params, x).as_text()
+
+
+def _routed(hit, n=10, held=6, wide=16, hidden=12, gated=False, seed=3):
+    """Operands of ``hit_experts`` whose tokens pick exactly the experts
+    ``hit`` (two of them a token where there are two)."""
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape) / np.sqrt(shape[-2]),
+                           jnp.float32)
+    gates = np.zeros((n, held), np.float32)
+    counts = np.zeros((held,), np.int32)
+    for row in range(n if hit else 0):
+        for e in {hit[row % len(hit)], hit[(row + 1) % len(hit)]}:
+            gates[row, e] = rng.uniform(0.2, 1.0)
+            counts[e] += 1
+    return dict(tokens=draw(n, wide), gates=jnp.asarray(gates),
+                w_gate=draw(held, wide, hidden) if gated else None,
+                w_up=draw(held, wide, hidden),
+                w_down=draw(held, hidden, wide)), jnp.asarray(counts)
+
+
+@pytest.mark.parametrize('hit', [(), (4,), (1, 2, 5), tuple(range(6))],
+                         ids=['none', 'one', 'some', 'all'])
+@pytest.mark.parametrize('gated', [False, True], ids=['plain', 'gated'])
+def test_the_kernel_is_the_batched_form_over_the_hit_experts(gated, hit):
+    """``moe_hit_experts`` against the two (three) batched matmuls over
+    every held expert, at hit counts of none (zeros), one, some and all
+    held experts — and whatever the UNHIT experts' weights hold: they
+    are NaN here, and are never read."""
+    from distributed_dot_product_tpu.models.moe import ACTIVATIONS
+    from distributed_dot_product_tpu.ops.pallas_experts import (
+        hit_experts, hit_experts_reference, hit_list,
+    )
+    act = ACTIVATIONS['silu' if gated else 'relu2']
+    ops, counts = _routed(list(hit), gated=gated)
+    hits, count = hit_list(counts)
+    assert int(count) == len(hit)
+    assert tuple(np.asarray(hits)[:len(hit)]) == hit
+    want = hit_experts_reference(**ops, act=act)
+    if not hit:
+        assert not np.any(want)
+    unhit = jnp.asarray([e not in hit for e in range(6)])[:, None, None]
+    poisoned = {k: v if v is None or k in ('tokens', 'gates')
+                else jnp.where(unhit, jnp.nan, v) for k, v in ops.items()}
+    got = hit_experts(hits=hits, count=count, act=act, **poisoned)
+    np.testing.assert_allclose(got, want, atol=TOL)
+
+
+def test_two_hit_counts_share_one_compiled_program():
+    """The hit count is a value, not a shape: steps that hit one and
+    five of six held experts run one trace of one program (the retrace
+    sentinel's budget of 1 holds), and hidden tiles narrower than the
+    layer (two of 128 here) give the same numbers."""
+    from distributed_dot_product_tpu.analysis import retrace
+    from distributed_dot_product_tpu.models.moe import ACTIVATIONS
+    from distributed_dot_product_tpu.ops.pallas_experts import (
+        hit_experts, hit_experts_reference, hit_list,
+    )
+    act = ACTIVATIONS['relu2']
+
+    def step(ops, counts):
+        hits, count = hit_list(counts)
+        return hit_experts(ops['tokens'], ops['gates'], hits, count, None,
+                           ops['w_up'], ops['w_down'], act, tile=128)
+    watched = retrace.watch_traces(step, 'test.moe_hit_experts', budget=1)
+    jitted = jax.jit(watched)
+    for hit in [(3,), (0, 1, 2, 4, 5)]:
+        ops, counts = _routed(list(hit), hidden=256, seed=len(hit))
+        np.testing.assert_allclose(
+            jitted(ops, counts),
+            hit_experts_reference(**ops, act=act), atol=TOL)
+    assert watched._graphlint_counter.count == 1
+
+
+@pytest.mark.parametrize('form', ['plain', 'gated'])
+def test_the_dense_route_differentiates_as_the_sorted_route(form):
+    """``jax.grad`` reaches the kernel through the batched form (its
+    differentiation rule): the gradients of every parameter and of the
+    input are the sorted route's."""
+    kw, x, params = _layer_and_input(form, 8, (2, 7))
+    params = {'params': params['params']}       # init sows counters too
+
+    def loss(params, x, dense_tokens):
+        y, _ = SparseExperts(**kw, dense_tokens=dense_tokens).apply(
+            params, x)
+        return jnp.sum(jnp.sin(y))
+    want = jax.grad(loss, argnums=(0, 1))(params, x, 0)
+    got = jax.grad(loss, argnums=(0, 1))(params, x, 15)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=TOL),
+                 got, want)
+
+
 # -- (d) one-branch blocks ------------------------------------------------
 
 def _rms(x, scale, eps=1e-5):

@@ -316,6 +316,49 @@ _HLO_ARRAY = re.compile(r'\b(pred|[a-z]+\d+)\[([\d,]*)\]')
 _MOVES = ('copy', 'copy-start', 'dynamic-slice', 'dynamic-update-slice')
 
 
+# ``moe_hit_experts`` at the edges of ``hidden_tile``'s plan: (tokens,
+# held, wide, hidden, gated, weights' type) -> the hidden tile a step
+# takes. The hybrid cell's own call; the two other expert cells' layers,
+# were they to take the route (three matrices a step; 4096 wide: one
+# lane tile a step); weights stored in float32 (cast in VMEM, half the
+# tile); a hidden width that is no multiple of 128 (one whole block).
+_EXPERT_SHAPES = {
+    'nemotron-latent': ((48, 128, 1024, 2688, False, jnp.bfloat16), 896),
+    'xing4-gated': ((16, 64, 3584, 1024, True, jnp.bfloat16), 128),
+    'command-a-gated': ((12, 16, 4096, 4096, True, jnp.bfloat16), 128),
+    'float32-weights': ((48, 8, 1024, 2688, False, jnp.float32), 384),
+    'ragged-hidden': ((24, 8, 512, 200, True, jnp.bfloat16), 200),
+}
+
+
+@pytest.mark.parametrize('shape', sorted(_EXPERT_SHAPES))
+def test_hit_experts_kernel_compiles_for_v5e(chip, shape):
+    """The expert kernel of the dense route under the VMEM plan its
+    docstring states (the weight blocks double-buffered within 12 MiB,
+    no ``vmem_limit_bytes``): a plan that is wrong is a compile error."""
+    from distributed_dot_product_tpu.models.moe import ACTIVATIONS
+    from distributed_dot_product_tpu.ops.pallas_experts import (
+        hidden_tile, hit_experts,
+    )
+    (n, held, wide, hidden, gated, w_dtype), tile = _EXPERT_SHAPES[shape]
+    assert hidden_tile(wide, hidden, 2 + gated,
+                       jnp.dtype(w_dtype).itemsize) == tile
+    act = ACTIVATIONS['silu' if gated else 'relu2']
+    w_in = jax.ShapeDtypeStruct((held, wide, hidden), w_dtype)
+
+    def step(x, gates, hits, count, w_gate, w_up, w_down):
+        return hit_experts(x, gates, hits, count, w_gate, w_up, w_down,
+                           act, interpret=False)
+
+    compiled = _compile(
+        chip, step, jax.ShapeDtypeStruct((n, wide), jnp.bfloat16),
+        jax.ShapeDtypeStruct((n, held), jnp.float32),
+        jax.ShapeDtypeStruct((held,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32), w_in if gated else None,
+        w_in, jax.ShapeDtypeStruct((held, hidden, wide), w_dtype))
+    assert 'moe_hit_experts' in compiled.as_text()
+
+
 def _result_bytes(result_type):
     """Bytes of the largest array in an HLO result type."""
     sizes = [0]
@@ -550,11 +593,11 @@ def test_hybrid_stack_decode_step_aliases_every_state_and_fits(
     the kernel at 2 KV heads x 1024 rows; every state and the slab alias
     the result; nothing as large as one layer's 48 states (201 MB) is
     copied, sliced or written back, and no temporary is that large (the
-    state update and its read against C are one fusion; the experts'
-    hidden activations of all 128 held experts are 33 MB); arguments +
-    temporaries stay under 15.0 GiB. The reset between requests writes
-    every state over in place, under its scope's name, with no
-    temporary."""
+    state update and its read against C are one fusion; an expert
+    layer's 48-token step is the ``moe_hit_experts`` kernel over its hit
+    list, whose activations never leave VMEM); arguments + temporaries
+    stay under 15.0 GiB. The reset between requests writes every state
+    over in place, under its scope's name, with no temporary."""
     import json
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -596,8 +639,15 @@ def test_hybrid_stack_decode_step_aliases_every_state_and_fits(
         ('kernel', 'layer', {'heads': 2, 'block_k': 1024,
                              'bytes': 1 << 20})]
     hlo = compiled.as_text()
-    # 48 tokens: every held expert on every token, no grouped matmul
+    # 48 tokens: every hit expert on every token, one kernel an expert
+    # layer, no grouped matmul and no batched one over all held experts
     assert 'flash_decode' in hlo and 'ragged-dot' not in hlo
+    assert 'lm.moe_experts/moe_hit_experts/' in hlo
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*moe_hit_experts',
+        hlo)) == 5
+    # ... whose (held, tokens, hidden) activations no longer exist
+    assert not re.findall(r'(bf16|f32)\[128,48,2688\]', hlo)
     state_bytes = sessions * 128 * 64 * 128 * 4
     assert _cache_sized_moves(hlo, state_bytes) == []
     mem = compiled.memory_analysis()
