@@ -28,10 +28,11 @@ several holders add up to the whole layer with the shared expert
 counted once. On one chip that holds every expert this is the whole
 layer, with no exchange and nothing standing in for absent chips.
 
-The routed part sorts the (token, pick) rows by expert and runs three
-grouped matmuls over the sorted rows (``lax.ragged_dot``: on the TPU
-XLA's own grouped-matmul kernel, which reads an expert's weights only
-where its group has rows).
+The routed part of a call of many rows (a prefill chunk, a training
+batch) sorts the (token, pick) rows by expert and runs three grouped
+matmuls over the sorted rows (``lax.ragged_dot``: on the TPU XLA's own
+grouped-matmul kernel, which reads an expert's weights only where its
+group has rows); a call of few rows takes the hit list (below).
 
 Three more switches cover ``nemotron_h``'s layer. ``expert_form='plain'``
 makes every expert, routed and shared, ``W_down act(W_up x)`` — two
@@ -47,25 +48,32 @@ width where it is not ``n_shared x hidden``. Both projections are
 linear and bias-free, so the parts of a latent layer divided over
 several holders, each through its own ``W_up``, still add up.
 
-``dense_tokens``: a call of at most that many tokens runs every HIT
-held expert — one that some token of the call picked — on every token,
-the gate zero where the token did not pick it: one Pallas program a
-layer (``ops/pallas_experts.hit_experts``, ``moe_hit_experts``) over the
+Two routes give the routed part, and the call's ROWS choose between
+them (:data:`ops.pallas_experts.HIT_LIST_ROWS`). A call of at most that
+many tokens — a decode step — runs every HIT held expert, one that some
+token of the call picked, on every token, the gate zero where the token
+did not pick it: one Pallas program a layer
+(``ops/pallas_experts.hit_experts``, ``moe_hit_experts``) over the
 call's hit list, which streams each hit expert's weights once and no
 other expert's, adds the picks up in a float32 accumulator, and has no
 sort, no gather and no grouped matmul. The same numbers; the trade is
 rows for tiles: XLA's grouped matmul pays a whole row tile a group, so
-where a decode step hits most held experts with two or three rows each
-(48 tokens x top-22 over 128 of 512: 17.1 ms a step at 38 % of the hit
-experts' bytes over the HBM peak; chip, PR 32) giving every hit expert
-all 48 rows is the faster route. The hit count is a value, not a shape:
-one program whatever the routing, its time the hit experts' bytes. A
-prefill chunk is far past the bound and takes the sorted route. 0, the
-default, never takes it. Under ``jax.grad`` the route differentiates
+where a step gives a hit expert one to three rows (16 tokens x top-4
+over 64, 12 x top-8 over 128 with 16 held, 48 x top-22 over 512 with 128
+held) giving every hit expert all the rows costs the MXU nothing more
+and the weights stream at the HBM's practical peak. The hit count is a
+value, not a shape: one program whatever the routing, its time the hit
+experts' bytes. A call past the bound (a prefill chunk, a training
+batch) takes the sorted route, whose work is the picks' rows and not
+rows x hit experts. ``dense_tokens`` is a caller's bound in place of the
+rule's (None, the default: the rule; 0: always the sorted route).
+:func:`expert_route_traces` reports which route a traced call took and
+by whose bound. Under ``jax.grad`` the hit-list route differentiates
 through the batched matmuls over every held expert
 (``hit_experts_reference``).
 """
 
+import contextlib
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -76,13 +84,41 @@ from jax import lax
 from distributed_dot_product_tpu.models.dense import OwnedDense
 from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_experts import (
-    hit_experts, hit_list,
+    HIT_LIST_ROWS, hidden_tile, hit_experts, hit_list,
 )
 
-__all__ = ['GatedMLP', 'PlainMLP', 'SparseExperts']
+__all__ = ['GatedMLP', 'PlainMLP', 'SparseExperts', 'expert_route_traces']
 
 ACTIVATIONS = {'silu': nn.silu,
                'relu2': lambda x: jnp.square(nn.relu(x))}
+
+
+_ROUTE_SINKS = []       # lists of the active expert_route_traces() blocks
+
+
+@contextlib.contextmanager
+def expert_route_traces():
+    """Collect which route each :class:`SparseExperts` call takes while
+    the block runs: one dict ``{'route', 'n', 'bound', 'bound_by',
+    'tile'}`` per TRACE of a layer. ``route`` is ``'hit_list'`` (the
+    ``moe_hit_experts`` kernel over the call's hit experts) or
+    ``'sorted'`` (the grouped matmuls over the rows sorted by expert);
+    ``n`` the call's rows, ``bound`` the most rows that take the hit
+    list, ``bound_by`` whose it was — ``'rule'``
+    (``ops.pallas_experts.HIT_LIST_ROWS``) or ``'caller'``
+    (``dense_tokens``) — and ``tile`` the columns of ``hidden`` one grid
+    step of the kernel takes (None on the sorted route)::
+
+        with expert_route_traces() as traces:
+            step.lower(*args).compile()
+        assert {t['route'] for t in traces} == {'hit_list'}
+    """
+    sink = []
+    _ROUTE_SINKS.append(sink)
+    try:
+        yield sink
+    finally:
+        _ROUTE_SINKS[:] = [s for s in _ROUTE_SINKS if s is not sink]
 
 
 class GatedMLP(nn.Module):
@@ -133,14 +169,14 @@ class SparseExperts(nn.Module):
     activation: str = 'silu'
     latent: Optional[int] = None
     shared_hidden: Optional[int] = None
-    dense_tokens: int = 0
+    dense_tokens: Optional[int] = None
     dtype: Optional[jnp.dtype] = None
     kernel_init: Any = nn.initializers.lecun_normal(in_axis=-2,
                                                     out_axis=-1,
                                                     batch_axis=(0,))
 
-    def _dense(self, tokens, picked, gates, counts, w_gate, w_up, w_down,
-               act, lo, hi):
+    def _hit_list(self, tokens, picked, gates, counts, w_gate, w_up,
+                  w_down, act, lo, hi):
         """Every HIT held expert on every token of ``tokens (n, wide)``,
         the token's gate for it zero where it was not picked: the gate
         table and the step's hit list, then one kernel that streams the
@@ -208,8 +244,12 @@ class SparseExperts(nn.Module):
             expert = picked.reshape(-1)                          # (n·k,)
             counts = jnp.zeros((self.n_experts,), jnp.int32).at[
                 expert].add(1)
-            dense = n <= self.dense_tokens
-            if not dense:
+            # Few enough rows that every hit expert can take them all
+            # behind its weights' DMA: the call's own shape decides.
+            by_rule = self.dense_tokens is None
+            bound = HIT_LIST_ROWS if by_rule else self.dense_tokens
+            hit_route = n <= bound
+            if not hit_route:
                 mine = (expert >= lo) & (expert < hi)
                 # Rows of experts held elsewhere sort behind the last
                 # group and are masked out of the combine.
@@ -226,9 +266,16 @@ class SparseExperts(nn.Module):
                  reduce_fn=lambda old, new: new,
                  init_fn=lambda: jnp.zeros((n, k), jnp.int32))
 
-        if dense:
-            y = self._dense(tokens, picked, gates, counts, w_gate, w_up,
-                            w_down, act, lo, hi)
+        tile = hidden_tile(wide, self.hidden, 2 + gated,
+                           w_up.dtype.itemsize) if hit_route else None
+        for sink in _ROUTE_SINKS:
+            sink.append({'route': 'hit_list' if hit_route else 'sorted',
+                         'n': n, 'bound': bound,
+                         'bound_by': 'rule' if by_rule else 'caller',
+                         'tile': tile})
+        if hit_route:
+            y = self._hit_list(tokens, picked, gates, counts, w_gate, w_up,
+                               w_down, act, lo, hi)
         else:
             with device_scope('lm.moe_experts'):
                 def grouped(a, w):

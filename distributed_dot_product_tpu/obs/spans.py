@@ -103,14 +103,16 @@ DEVICE_SCOPES = {
     'lm.moe_route': 'an expert layer around its experts: router matmul, '
                     'sigmoid, top-k, gates, the sort by expert and the '
                     'gather into it, the weighted combine back to token '
-                    'order, the per-expert token counts; on the dense '
-                    'route the gate table and the step\'s hit list',
+                    'order, the per-expert token counts; on the '
+                    'hit-list route (a call of few rows: a decode step) '
+                    'the gate table and the step\'s hit list',
     'lm.moe_experts': 'the routed experts\' grouped matmuls (gate, up, '
                       'down over the rows sorted by expert) and their '
-                      'activation; on the dense route (a decode step) the '
-                      'Pallas kernel moe_hit_experts, which streams the '
-                      'experts the step picked, scales by the gates and '
-                      'adds the picks up',
+                      'activation; on the hit-list route (a call of few '
+                      'rows: a decode step) the Pallas kernel '
+                      'moe_hit_experts, which streams the experts the '
+                      'step picked, scales by the gates and adds the '
+                      'picks up',
     'lm.moe_latent': 'an expert layer whose experts live in a latent: the '
                      'projection of the stream down to it (once a token) '
                      'and of the combined expert output back up',

@@ -38,8 +38,21 @@ tokens, ``wide`` 1024, ``hidden`` 2688 = 3 x 896, bfloat16): two blocks
 of 1.75 MiB, 7.0 MiB double-buffered; tokens, gates, output and
 accumulator 0.6 MiB; the step's temporaries (``(48, 896)`` float32
 activations, their bfloat16 copy, the ``(48, 1024)`` float32 product)
-0.5 MiB: 8.1 MiB of the compiler's 16 MiB. ``tests/test_tpu_compile.py``
-compiles it for a v5e.
+0.5 MiB: 8.1 MiB of the compiler's 16 MiB. A gated layer at the
+stream's width has three blocks a step and that plan leaves it ONE lane
+tile, a ``w_up`` slab of 256-byte rows, which streams 6 % under slabs of
+1 KB rows (chip, PR 35): the slab is widened to
+:data:`_SLAB_ROW_BYTES` a row, 512 columns of bfloat16, and
+:func:`_vmem_limit` asks the compiler for the blocks' bytes on top of
+its default. Xing4's layer (16 tokens, ``wide`` 3584, ``hidden`` 1024 =
+2 x 512, 64 held): three blocks of 3.5 MiB, 21.0 MiB double-buffered,
+the rest under 0.6 MiB (the ``(16, 3584)`` tokens, output, accumulator
+and float32 product, three ``(16, 512)`` activations), limit 37 MiB.
+Command A+'s (12 tokens, 4096 / 4096 = 8 x 512, 16 held): three blocks
+of 4 MiB, 24 MiB double-buffered, the rest under 0.7 MiB, limit 40 MiB;
+at :data:`HIT_LIST_ROWS` 128 rows the rest is 9 MiB (tokens and output
+1 MiB each and double-buffered, accumulator and product 2 MiB each).
+``tests/test_tpu_compile.py`` compiles each for a v5e.
 
 Off the TPU the kernel runs under the Pallas interpreter, as the other
 kernels do. :func:`hit_experts_reference` is the same layer as two
@@ -59,7 +72,28 @@ from distributed_dot_product_tpu.ops.pallas_decode import (
 )
 
 __all__ = ['hit_list', 'hit_experts', 'hit_experts_reference',
-           'hidden_tile']
+           'hidden_tile', 'HIT_LIST_ROWS']
+
+# The most rows of a call that take this kernel (``models/moe.py``
+# chooses by it): every hit expert gets ALL the call's rows, and one MXU
+# pass takes up to 128 rows at the cost of one, so up to there an
+# expert's matmuls stay behind the DMA of its weights (a row is 2 FLOPs
+# a weight: 128 rows over a GB of bfloat16 weights are 0.65 ms of the
+# MXU's peak behind 1.22 ms of HBM). Past it the work grows with rows x
+# hit experts where the sorted route's grows with the picks. On the chip
+# (PR 35, both gated shapes, a layer's routed part): level from 64 to
+# 256 rows, 1.8x that at 512, and under the sorted route's all the way.
+HIT_LIST_ROWS = 128
+# A block of ``w_up`` / ``w_gate`` is a column slab of a row-major
+# matrix: its DMA moves ``wide`` rows of ``tile x itemsize`` bytes. Rows
+# of 256 and 512 bytes streamed 6 % under rows of 1 KB and 2 KB at both
+# gated shapes (chip, PR 35), so a slab is at least this wide.
+_SLAB_ROW_BYTES = 1024
+# The most VMEM the weight blocks may take double-buffered when the slab
+# rule widens them past the plan: half of a v5e's 128 MiB.
+_VMEM_CEILING = 64 << 20
+# The compiler's default scoped VMEM limit on a v5e.
+_SCOPED_VMEM = 16 << 20
 
 
 def hit_list(counts):
@@ -80,16 +114,32 @@ def hidden_tile(wide, hidden, matrices, itemsize):
     """Columns of ``hidden`` a grid step takes: the most 128-lane tiles
     that divide it whose ``matrices`` blocks of ``wide x tile`` stay
     within the step's stream target and, double-buffered, within the
-    VMEM plan; one lane tile where even that is past them; all of
+    VMEM plan — but no narrower than :data:`_SLAB_ROW_BYTES` a row of
+    the ``w_up`` slab (the fewest tiles that reach it, while the blocks
+    stay within :data:`_VMEM_CEILING`; :func:`_vmem_limit` then asks for
+    the room); one lane tile where even that is past them; all of
     ``hidden`` where it is no multiple of 128 (a block may be a whole
     axis, whatever its length)."""
     if hidden % 128:
         return hidden
     lanes = hidden // 128
     step = matrices * wide * 128 * itemsize
-    return 128 * max([c for c in range(1, lanes + 1) if lanes % c == 0
-                      and c * step <= _STEP_STREAM_BYTES
-                      and 2 * c * step <= _VMEM_BUDGET] or [1])
+    divisors = [c for c in range(1, lanes + 1) if lanes % c == 0]
+    planned = max([c for c in divisors if c * step <= _STEP_STREAM_BYTES
+                   and 2 * c * step <= _VMEM_BUDGET] or [1])
+    # (a slab that is all of ``hidden`` is whole rows, however short)
+    slab = min([c for c in divisors if 2 * c * step <= _VMEM_CEILING and (
+        c * 128 * itemsize >= _SLAB_ROW_BYTES or c == lanes)] or [1])
+    return 128 * max(planned, slab)
+
+
+def _vmem_limit(wide, tile, matrices, itemsize):
+    """``vmem_limit_bytes`` for a step's weight blocks: None (the
+    compiler's default, 16 MiB) where they are within the plan's budget
+    double-buffered, else their bytes and the default on top for the
+    rest (tokens, gates, accumulator, the step's temporaries)."""
+    blocks = 2 * matrices * wide * tile * itemsize
+    return None if blocks <= _VMEM_BUDGET else blocks + _SCOPED_VMEM
 
 
 def hit_experts_reference(tokens, gates, w_gate, w_up, w_down, act):
@@ -149,9 +199,10 @@ def _hit_experts(tokens, gates, hits, count, w_gate, w_up, w_down, act,
     gated = w_gate is not None
     if interpret is None:
         interpret = jax.default_backend() != 'tpu'
-    tile = tile or hidden_tile(wide, hidden, 2 + gated,
-                               jnp.dtype(w_up.dtype).itemsize)
+    itemsize = jnp.dtype(w_up.dtype).itemsize
+    tile = tile or hidden_tile(wide, hidden, 2 + gated, itemsize)
     tiles = hidden // tile
+    vmem_limit = _vmem_limit(wide, tile, 2 + gated, itemsize)
     # Rows ride padded to their sublane tile (Mosaic refuses a dot
     # against a one-row operand); a padded token's gates are zero.
     x = _pad_rows(tokens, _sublane(tokens.dtype))
@@ -189,6 +240,8 @@ def _hit_experts(tokens, gates, hits, count, w_gate, w_up, w_down, act,
             out_specs=pl.BlockSpec(x.shape, whole),
             scratch_shapes=[pltpu.VMEM(x.shape, jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(x.shape, tokens.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit) if vmem_limit else None,
         interpret=interpret,
         name='moe_hit_experts')(
             hits, count.reshape(1), x, g,

@@ -436,9 +436,11 @@ def test_latent_decode_build_carries_its_kernel_name():
 
 
 def test_hit_experts_build_carries_its_kernel_name_under_its_scope():
-    """The dense route of an expert layer is ONE ``pallas_call`` named
-    ``moe_hit_experts``, opened inside ``lm.moe_experts`` (whose time
-    the expert readers take by scope); the sorted route holds none."""
+    """The hit-list route of an expert layer — by a caller's bound or,
+    with none passed, by the rule over the call's rows — is ONE
+    ``pallas_call`` named ``moe_hit_experts``, opened inside
+    ``lm.moe_experts`` (whose time the expert readers take by scope);
+    the sorted route holds none."""
     from distributed_dot_product_tpu.models.moe import SparseExperts
     kw = dict(n_experts=4, top_k=2, hidden=16, latent=16,
               expert_form='plain', activation='relu2')
@@ -446,7 +448,10 @@ def test_hit_experts_build_carries_its_kernel_name_under_its_scope():
     params = SparseExperts(**kw).init(jax.random.key(0), x)
     dense = SparseExperts(**kw, dense_tokens=6).apply
     assert kernel_names(dense, params, x) == ['moe_hit_experts']
-    assert kernel_names(SparseExperts(**kw).apply, params, x) == []
+    assert kernel_names(SparseExperts(**kw).apply, params, x) == [
+        'moe_hit_experts']
+    assert kernel_names(SparseExperts(**kw, dense_tokens=0).apply, params,
+                        x) == []
     kernel = [n for n in op_names(jax.jit(dense).lower(params, x).compile())
               if 'moe_hit_experts' in n]
     assert kernel and all('/lm.moe_experts/moe_hit_experts/' in n

@@ -25,7 +25,10 @@ from distributed_dot_product_tpu.models.decode import (  # noqa: E402
     StateCache, insert_session, restore_states, snapshot_states,
 )
 from distributed_dot_product_tpu.models.moe import (  # noqa: E402
-    SparseExperts,
+    SparseExperts, expert_route_traces,
+)
+from distributed_dot_product_tpu.ops.pallas_experts import (  # noqa: E402
+    HIT_LIST_ROWS, hidden_tile, hit_experts_reference,
 )
 from distributed_dot_product_tpu.models.ssm import (  # noqa: E402
     Mamba2Mixer,
@@ -299,8 +302,10 @@ def test_four_shares_of_a_latent_layer_add_up_to_the_uncut_layer(
 
 
 # The text jax lowers the commit before this architecture's to (CPU):
-# the default expert layer, and prefill and decode of the two expert
-# cells' tiny presets. The new switches are Python-level branches only.
+# the expert layer, and prefill and decode of the two expert cells' tiny
+# presets, on the SORTED route (``dense_tokens=0``: a call of these few
+# rows takes the hit list by the rule). The switches since are
+# Python-level branches only.
 PARENT_LOWERED = {
     'experts':
         '3ee5a11d5306ab89107c9c4389ffa206c0cc05e51e0b049f90807a8f6335e10e',
@@ -324,7 +329,8 @@ def _sha(lowered):
 @pytest.mark.parametrize('what', sorted(PARENT_LOWERED))
 def test_accepted_programs_lower_to_the_parents_text(what):
     if what == 'experts':
-        layer = SparseExperts(n_experts=8, top_k=2, hidden=8)
+        layer = SparseExperts(n_experts=8, top_k=2, hidden=8,
+                              dense_tokens=0)
         x = jnp.zeros((2, 6, 16), jnp.float32)
         params = jax.eval_shape(lambda: layer.init(jax.random.key(0), x))
         assert set(params['params']) == {
@@ -337,6 +343,9 @@ def test_accepted_programs_lower_to_the_parents_text(what):
     cell = loader.Cell(cell, root=os.path.join(ROOT, 'benchmarks', 'tests',
                                                root))
     model = cell.driver().build_lm(cell.config)
+    experts = {**model.block_kwargs['ffn_kwargs'], 'dense_tokens': 0}
+    model = model.clone(block_kwargs={**model.block_kwargs,
+                                      'ffn_kwargs': experts})
     tok = jnp.zeros((2, 8 if method == 'prefill' else 1), jnp.int32)
     params = jax.eval_shape(lambda: model.init(
         jax.random.key(0), jnp.zeros((2, 8), jnp.int32)))
@@ -353,7 +362,7 @@ def test_the_dense_route_of_a_gated_layer_is_the_sorted_one():
                     jnp.float32)
     kw = dict(n_experts=8, top_k=3, hidden=12, experts_held=(2, 7))
     params = SparseExperts(**kw).init(jax.random.key(0), x)
-    sorted_y, counts = SparseExperts(**kw).apply(params, x)
+    sorted_y, counts = SparseExperts(**kw, dense_tokens=0).apply(params, x)
     dense = SparseExperts(**kw, dense_tokens=10)
     dense_y, dense_counts = dense.apply(params, x)
     np.testing.assert_allclose(dense_y, sorted_y, atol=TOL)
@@ -388,9 +397,12 @@ def test_the_dense_route_is_the_sorted_route(form, latent, held):
     stream and in a latent, holding every expert and a share that some
     picks fall outside of; the dense route sorts nothing."""
     kw, x, params = _layer_and_input(form, latent, held)
-    want, counts = SparseExperts(**kw).apply(params, x)
+    want, counts = SparseExperts(**kw, dense_tokens=0).apply(params, x)
     dense = SparseExperts(**kw, dense_tokens=15)
-    got, dense_counts = dense.apply(params, x)
+    with expert_route_traces() as traces:
+        got, dense_counts = dense.apply(params, x)
+    assert traces == [{'route': 'hit_list', 'n': 15, 'bound': 15,
+                       'bound_by': 'caller', 'tile': 12}]
     np.testing.assert_allclose(got, want, atol=TOL)
     np.testing.assert_array_equal(dense_counts, counts)
     if held:
@@ -398,6 +410,82 @@ def test_the_dense_route_is_the_sorted_route(form, latent, held):
         assert int(counts[:lo].sum() + counts[hi:].sum()) > 0
     assert 'stablehlo.sort' not in jax.jit(dense.apply).lower(
         params, x).as_text()
+
+
+@pytest.mark.parametrize('rows', [HIT_LIST_ROWS, HIT_LIST_ROWS + 1],
+                         ids=['at-the-bound', 'one-past'])
+@pytest.mark.parametrize('dense_tokens', [None, 200, 0],
+                         ids=['rule', 'caller', 'never'])
+def test_the_calls_rows_choose_the_route(dense_tokens, rows):
+    """The accepted gated layer (silu, ``w_gate``) holding a sub-range
+    beside two shared experts averaged: with no bound passed the call's
+    rows choose — the hit list up to ``HIT_LIST_ROWS``, the sorted
+    route one row past it — and a caller's integer is its own bound (0:
+    never). Whichever route, one result: the sorted route's, and for the
+    routed part the batched form's over every held expert.
+    ``expert_route_traces`` says which, by whose bound, at what tile."""
+    kw = dict(n_experts=8, top_k=3, hidden=256, n_shared=2, scaling=2.0,
+              shared_combine='mean', experts_held=(2, 7))
+    x = jnp.asarray(np.random.default_rng(rows).normal(size=(rows, 16)),
+                    jnp.float32)
+    params = SparseExperts(**kw).init(jax.random.key(1), x[:4])
+    want, counts = SparseExperts(**kw, dense_tokens=0).apply(params, x)
+    layer = SparseExperts(**kw, dense_tokens=dense_tokens)
+    with expert_route_traces() as traces:
+        got, got_counts = layer.apply(params, x)
+    bound = HIT_LIST_ROWS if dense_tokens is None else dense_tokens
+    assert traces == [{
+        'route': 'hit_list' if rows <= bound else 'sorted', 'n': rows,
+        'bound': bound,
+        'bound_by': 'rule' if dense_tokens is None else 'caller',
+        'tile': hidden_tile(16, 256, 3, 4) if rows <= bound else None}]
+    np.testing.assert_allclose(got, want, atol=TOL)
+    np.testing.assert_array_equal(got_counts, counts)
+    assert int(counts[:2].sum() + counts[7:].sum()) > 0
+
+    p = params['params']
+    scores = jax.nn.sigmoid(jnp.dot(x, p['router'],
+                                    precision=jax.lax.Precision.HIGHEST))
+    _, picked = jax.lax.top_k(scores + p['router_bias'], 3)
+    gates = jnp.take_along_axis(scores, picked, -1)
+    gates = 2.0 * gates / gates.sum(-1, keepdims=True)
+    table = jnp.zeros((rows, 8)).at[jnp.arange(rows)[:, None],
+                                    picked].set(gates)
+    routed, _ = layer.clone(add_shared=False).apply(params, x)
+    np.testing.assert_allclose(
+        routed, hit_experts_reference(x, table[:, 2:7], p['w_gate'],
+                                      p['w_up'], p['w_down'], jax.nn.silu),
+        atol=TOL)
+
+
+@pytest.mark.parametrize('preset', ['xing4', 'command-a', 'nemotron'])
+def test_a_decode_step_of_every_expert_cell_takes_the_hit_list(preset):
+    """The three expert cells' tiny presets: every expert layer of a
+    decode step is on the hit-list route — the two gated cells' by the
+    rule (their drivers pass no bound), the hybrid cell's by its
+    driver's own — and a 200-row prefill chunk of a gated cell is on the
+    sorted one."""
+    root, name = {**PRESETS, 'nemotron': (
+        'tiny_hybrid', 'tiny-nemotron.decode')}[preset]
+    cell = loader.Cell(name, root=os.path.join(ROOT, 'benchmarks', 'tests',
+                                               root))
+    model = cell.driver().build_lm(cell.config)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((2, 8), jnp.int32)))
+    caches = jax.eval_shape(lambda: model.make_decode_caches(2, 256))
+
+    def routes(method, tokens):
+        with expert_route_traces() as traces:
+            jax.jit(lambda p, t, c: model.apply(
+                p, t, c, method=method)).lower(
+                    params, jnp.zeros((2, tokens), jnp.int32), caches)
+        assert traces
+        return ({t['route'] for t in traces}, {t['n'] for t in traces},
+                {t['bound_by'] for t in traces})
+    by = 'caller' if preset == 'nemotron' else 'rule'
+    assert routes('decode', 1) == ({'hit_list'}, {2}, {by})
+    if by == 'rule':
+        assert routes('prefill', 100) == ({'sorted'}, {200}, {by})
 
 
 def _routed(hit, n=10, held=6, wide=16, hidden=12, gated=False, seed=3):
@@ -420,14 +508,16 @@ def _routed(hit, n=10, held=6, wide=16, hidden=12, gated=False, seed=3):
                 w_down=draw(held, hidden, wide)), jnp.asarray(counts)
 
 
-@pytest.mark.parametrize('hit', [(), (4,), (1, 2, 5), tuple(range(6))],
-                         ids=['none', 'one', 'some', 'all'])
+@pytest.mark.parametrize('hit', [(), (4,), (1, 2, 5), (0, 3, 5),
+                                 tuple(range(6))],
+                         ids=['none', 'one', 'some', 'gaps', 'all'])
 @pytest.mark.parametrize('gated', [False, True], ids=['plain', 'gated'])
 def test_the_kernel_is_the_batched_form_over_the_hit_experts(gated, hit):
     """``moe_hit_experts`` against the two (three) batched matmuls over
-    every held expert, at hit counts of none (zeros), one, some and all
-    held experts — and whatever the UNHIT experts' weights hold: they
-    are NaN here, and are never read."""
+    every held expert, at hit counts of none (zeros), one, some (and
+    some with an unhit expert between every two hit ones) and all held
+    experts — and whatever the UNHIT experts' weights hold: they are NaN
+    here, and are never read."""
     from distributed_dot_product_tpu.models.moe import ACTIVATIONS
     from distributed_dot_product_tpu.ops.pallas_experts import (
         hit_experts, hit_experts_reference, hit_list,

@@ -317,32 +317,50 @@ _MOVES = ('copy', 'copy-start', 'dynamic-slice', 'dynamic-update-slice')
 
 
 # ``moe_hit_experts`` at the edges of ``hidden_tile``'s plan: (tokens,
-# held, wide, hidden, gated, weights' type) -> the hidden tile a step
-# takes. The hybrid cell's own call; the two other expert cells' layers,
-# were they to take the route (three matrices a step; 4096 wide: one
-# lane tile a step); weights stored in float32 (cast in VMEM, half the
-# tile); a hidden width that is no multiple of 128 (one whole block).
+# held, wide, hidden, gated, weights' type) -> (the hidden tile a step
+# takes, its ``vmem_limit_bytes``). The hybrid cell's own call (the
+# compiler's default limit); the two gated expert cells' published
+# layers at their decode steps' rows and at the rule's bound of rows
+# (three matrices a step: the slab widened to 1 KB rows, the blocks'
+# bytes asked for); weights stored in float32 (cast in VMEM, half the
+# tile); a hidden width that is no multiple of 128 (one whole block); a
+# stream so wide that a 1 KB slab would pass the ceiling (one lane tile,
+# its blocks still asked for).
 _EXPERT_SHAPES = {
-    'nemotron-latent': ((48, 128, 1024, 2688, False, jnp.bfloat16), 896),
-    'xing4-gated': ((16, 64, 3584, 1024, True, jnp.bfloat16), 128),
-    'command-a-gated': ((12, 16, 4096, 4096, True, jnp.bfloat16), 128),
-    'float32-weights': ((48, 8, 1024, 2688, False, jnp.float32), 384),
-    'ragged-hidden': ((24, 8, 512, 200, True, jnp.bfloat16), 200),
+    'nemotron-latent': ((48, 128, 1024, 2688, False, jnp.bfloat16),
+                        (896, None)),
+    'xing4-gated': ((16, 64, 3584, 1024, True, jnp.bfloat16),
+                    (512, 37 << 20)),
+    'command-a-gated': ((12, 16, 4096, 4096, True, jnp.bfloat16),
+                        (512, 40 << 20)),
+    'xing4-gated-at-the-bound': ((128, 64, 3584, 1024, True, jnp.bfloat16),
+                                 (512, 37 << 20)),
+    'command-a-gated-at-the-bound': (
+        (128, 16, 4096, 4096, True, jnp.bfloat16), (512, 40 << 20)),
+    'float32-weights': ((48, 8, 1024, 2688, False, jnp.float32),
+                        (384, None)),
+    'ragged-hidden': ((24, 8, 512, 200, True, jnp.bfloat16), (200, None)),
+    'wide-stream': ((16, 2, 16384, 1024, True, jnp.bfloat16),
+                    (128, 40 << 20)),
 }
 
 
 @pytest.mark.parametrize('shape', sorted(_EXPERT_SHAPES))
 def test_hit_experts_kernel_compiles_for_v5e(chip, shape):
-    """The expert kernel of the dense route under the VMEM plan its
-    docstring states (the weight blocks double-buffered within 12 MiB,
-    no ``vmem_limit_bytes``): a plan that is wrong is a compile error."""
+    """The expert kernel of the hit-list route under the VMEM plan its
+    docstring states (the weight blocks double-buffered within 12 MiB
+    and no ``vmem_limit_bytes``, or a slab of 1 KB rows and the blocks'
+    bytes asked for): a plan that is wrong is a compile error."""
     from distributed_dot_product_tpu.models.moe import ACTIVATIONS
     from distributed_dot_product_tpu.ops.pallas_experts import (
-        hidden_tile, hit_experts,
+        HIT_LIST_ROWS, _vmem_limit, hidden_tile, hit_experts,
     )
-    (n, held, wide, hidden, gated, w_dtype), tile = _EXPERT_SHAPES[shape]
-    assert hidden_tile(wide, hidden, 2 + gated,
-                       jnp.dtype(w_dtype).itemsize) == tile
+    (n, held, wide, hidden, gated, w_dtype), (tile, limit) = (
+        _EXPERT_SHAPES[shape])
+    assert n <= HIT_LIST_ROWS
+    itemsize = jnp.dtype(w_dtype).itemsize
+    assert hidden_tile(wide, hidden, 2 + gated, itemsize) == tile
+    assert _vmem_limit(wide, tile, 2 + gated, itemsize) == limit
     act = ACTIVATIONS['silu' if gated else 'relu2']
     w_in = jax.ShapeDtypeStruct((held, wide, hidden), w_dtype)
 
@@ -477,9 +495,12 @@ def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
     donated: the step resolves to ``flash_decode``'s latent mode over
     the layer-stacked cache, which aliases the result; nothing as large
     as one layer of it is copied, sliced or held as a temporary; and no
-    layer's 64 experts are moved on their way into XLA's grouped-matmul
-    kernel (as a scanned layer's were, sliced out of the stack: 21.6 ms
-    of a 36.6 ms step; chip, PR 26)."""
+    layer's 64 experts are moved on their way into the expert kernel (as
+    a scanned layer's were, sliced out of the stack: 21.6 ms of a 36.6
+    ms step; chip, PR 26). The 16 rows of the step put both expert
+    layers on the hit-list route by the rule: one ``moe_hit_experts``
+    kernel a layer at 512 hidden columns a grid step, no grouped
+    matmul."""
     import json
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -489,6 +510,7 @@ def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
     from distributed_dot_product_tpu.models.decode import (
         decode_impl_traces,
     )
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     with open(os.path.join(root, 'benchmarks', 'configs',
                            'xing4-29b-a4b-serve.json')) as f:
@@ -508,12 +530,17 @@ def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
         (params, tok, caches, stats))
-    with decode_impl_traces() as traces:
+    with decode_impl_traces() as traces, expert_route_traces() as routes:
         compiled = step.lower(*shapes).compile()
     assert {(t['resolved'], t['cache']) for t in traces} == {
         ('kernel', 'stacked')}
+    assert routes == 2 * [{'route': 'hit_list', 'n': sessions,
+                           'bound': 128, 'bound_by': 'rule', 'tile': 512}]
     hlo = compiled.as_text()
-    assert hlo.count('mla_decode') and 'ragged-dot' in hlo
+    assert hlo.count('mla_decode') and 'ragged-dot' not in hlo
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*moe_hit_experts',
+        hlo)) == 2
     layer_bytes = sessions * t_max * 640 * 2
     experts_bytes = 64 * 3584 * 1024 * 2
     assert _cache_sized_moves(hlo, min(layer_bytes, experts_bytes)) == []
@@ -543,6 +570,7 @@ def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
     from distributed_dot_product_tpu.models.decode import (
         decode_impl_traces,
     )
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     with open(os.path.join(root, 'benchmarks', 'configs',
                            'command-a-plus-serve.json')) as f:
@@ -563,14 +591,20 @@ def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
         (params, tok, caches, stats))
-    with decode_impl_traces() as traces:
+    with decode_impl_traces() as traces, expert_route_traces() as routes:
         compiled = step.lower(*shapes).compile()
     assert [(t['resolved'], t['cache']) for t in traces] == 3 * [
         ('kernel', 'ring')] + [('kernel', 'layer')]
     assert {tuple(t['step'].items()) for t in traces} == {
         (('heads', 8), ('block_k', 1024), ('bytes', 4 << 20))}
+    # 12 rows: the held experts' hit list, by the rule, in every layer
+    assert routes == 4 * [{'route': 'hit_list', 'n': sessions,
+                           'bound': 128, 'bound_by': 'rule', 'tile': 512}]
     hlo = compiled.as_text()
-    assert hlo.count('flash_decode_ring') and 'ragged-dot' in hlo
+    assert hlo.count('flash_decode_ring') and 'ragged-dot' not in hlo
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*moe_hit_experts',
+        hlo)) == 4
     ring_k_bytes = sessions * 8 * 5120 * 128 * 2
     assert _cache_sized_moves(hlo, ring_k_bytes) == []
     mem = compiled.memory_analysis()
@@ -607,6 +641,7 @@ def test_hybrid_stack_decode_step_aliases_every_state_and_fits(
     from distributed_dot_product_tpu.models.decode import (
         decode_impl_traces,
     )
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     with open(os.path.join(root, 'benchmarks', 'configs',
                            'nemotron-3-super-serve.json')) as f:
@@ -632,12 +667,15 @@ def test_hybrid_stack_decode_step_aliases_every_state_and_fits(
     def described(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=chip), tree)
-    with decode_impl_traces() as traces:
+    with decode_impl_traces() as traces, expert_route_traces() as routes:
         compiled = step.lower(
             *described((params, tok, caches, stats))).compile()
     assert [(t['resolved'], t['cache'], t['step']) for t in traces] == [
         ('kernel', 'layer', {'heads': 2, 'block_k': 1024,
                              'bytes': 1 << 20})]
+    # the driver's own bound, honoured as it was
+    assert routes == 5 * [{'route': 'hit_list', 'n': sessions,
+                           'bound': 64, 'bound_by': 'caller', 'tile': 896}]
     hlo = compiled.as_text()
     # 48 tokens: every hit expert on every token, one kernel an expert
     # layer, no grouped matmul and no batched one over all held experts
