@@ -182,6 +182,12 @@ class DistributedDotProductAttn(nn.Module):
     # gating). Applies to decode/decode_sharded; prefill always runs
     # the flash kernel.
     decode_impl: Optional[str] = None
+    # The number the scores are multiplied by before the softmax, where
+    # it is not ``head_dim ** -0.5`` (None; Granite's
+    # ``attention_multiplier``): ONE field read by every route — the
+    # parity path, the flash, ring and Ulysses routes, prefill and both
+    # decode steps — so that they agree.
+    softmax_scale: Optional[float] = None
     # 'int8' = int8 WEIGHT quantization for the four projection
     # matmuls (models/dense.py): kernels stored int8 with per-output-
     # channel scales (quantize_dense_params at load/convert time),
@@ -286,6 +292,12 @@ class DistributedDotProductAttn(nn.Module):
         self.values_proj = dense(
             kv_heads * (value_dim // self.num_heads), 'values')
         self.composition = dense(self.out_dim or value_dim, 'composition')
+
+    @property
+    def _scale(self):
+        if self.softmax_scale is None:
+            return 1.0 / math.sqrt(self.head_dim)
+        return float(self.softmax_scale)
 
     def __call__(self, keys, queries, values, attn_mask=None,
                  segment_ids=None, deterministic=False,
@@ -450,7 +462,7 @@ class DistributedDotProductAttn(nn.Module):
             # with no (T/N, T) score materialization
             # (:mod:`..ops.pallas_attention`). Fully-masked rows give 0
             # (reference: NaN).
-            scale = 1.0 / math.sqrt(self.head_dim)
+            scale = self._scale
             q_full, v_full = queries, values
             if distributed:
                 with device_scope('lm.attn_gather'):
@@ -508,7 +520,7 @@ class DistributedDotProductAttn(nn.Module):
             # fused flash kernel locally over the FULL sequence for H/N
             # heads (see models/ulysses_attention.py). Same q:=keys
             # convention as the flash path.
-            scale = 1.0 / math.sqrt(self.head_dim)
+            scale = self._scale
             outputs = ulysses_attention(
                 keys, queries, values, attn_mask,
                 axis_name=self.axis_name, scale=scale,
@@ -530,7 +542,7 @@ class DistributedDotProductAttn(nn.Module):
             # give 0 here (reference: NaN). Segments ride the ring as
             # O(T/N) vectors; dropout/ALiBi run in the per-fold kernels
             # over global coordinates.
-            scale = 1.0 / math.sqrt(self.head_dim)
+            scale = self._scale
             seg_ring = seg_local
             if seg_ring is not None and self.num_heads > 1:
                 seg_ring = seg_ring[..., None, :]
@@ -585,7 +597,10 @@ class DistributedDotProductAttn(nn.Module):
             scores = jnp.matmul(keys, jnp.swapaxes(queries, -1, -2))
         # K-first convention kept (reference module.py:60-62): row i of
         # `scores` is key_i against every query.
-        scores = scores / math.sqrt(self.head_dim)
+        if self.softmax_scale is None:
+            scores = scores / math.sqrt(self.head_dim)
+        else:
+            scores = scores * self.softmax_scale
         if attn_mask is not None:
             big_neg = jnp.asarray(-jnp.inf, dtype=scores.dtype)
             scores = jnp.where(attn_mask, big_neg, scores)
@@ -703,7 +718,7 @@ class DistributedDotProductAttn(nn.Module):
                                                   self.window)
                 out = flash_attention(
                     keys, k_all, v_all, causal=True, causal_offset=start,
-                    scale=1.0 / math.sqrt(self.head_dim),
+                    scale=self._scale,
                     window=self.window)
                 return (ring_append(cache, queries, values),
                         self._merge_decode_heads(out))
@@ -720,7 +735,7 @@ class DistributedDotProductAttn(nn.Module):
                 seg_pair = (sq, sk)
             out = flash_attention(
                 keys, cache.k, cache.v, causal=True, causal_offset=start,
-                scale=1.0 / math.sqrt(self.head_dim), window=self.window,
+                scale=self._scale, window=self.window,
                 alibi_slopes=self.alibi_slopes, qk_quant=self.qk_quant,
                 segment_ids=seg_pair)
             return cache, self._merge_decode_heads(out)
@@ -770,7 +785,7 @@ class DistributedDotProductAttn(nn.Module):
                 keys, queries, values, length)
             cache, out = decode_step(
                 keys, cache, queries, values,
-                scale=1.0 / math.sqrt(self.head_dim),
+                scale=self._scale,
                 window=self.window, alibi_slopes=self.alibi_slopes,
                 qk_quant=self.qk_quant, segment_ids=seg_cache,
                 seg_q=segment_ids, impl=self.decode_impl, layer=layer)
@@ -801,7 +816,7 @@ class DistributedDotProductAttn(nn.Module):
                 keys, queries, values, cache.length)
             cache, out = decode_step(
                 keys, cache, queries, values,
-                scale=1.0 / math.sqrt(self.head_dim),
+                scale=self._scale,
                 window=self.window, alibi_slopes=self.alibi_slopes,
                 qk_quant=self.qk_quant, segment_ids=seg_cache,
                 seg_q=segment_ids, axis_name=ax, impl=self.decode_impl)

@@ -111,6 +111,10 @@ class TransformerLM(nn.Module):
     # The logits are ``logit_scale · LN_f(x) · Eᵀ`` (Cohere's models
     # state one; 1 adds no operation).
     logit_scale: float = 1.0
+    # The stream starts as ``embed_scale · E[token]`` (Granite's
+    # ``embedding_multiplier``; 1 adds no operation). The tied head
+    # reads the table itself, not the scaled rows.
+    embed_scale: float = 1.0
     # The block's composition and the kinds of its layers —
     # TransformerStack's fields of the same names (block_kwargs' 'norm'
     # and 'norm_eps' also choose the final norm). ``dense_prefix`` /
@@ -193,6 +197,8 @@ class TransformerLM(nn.Module):
     def _embed(self, tokens):
         with device_scope('lm.embed'):
             x = self.embed(tokens.astype(jnp.int32))
+            if self.embed_scale != 1.0:
+                x = x * self.embed_scale
             if self._streams():
                 x = jnp.broadcast_to(
                     x.astype(jnp.float32)[..., None, :],

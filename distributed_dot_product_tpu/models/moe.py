@@ -17,7 +17,11 @@ correction bias (the pick is the top-k of the scores themselves), and
 experts' outputs where ``'sum'`` adds their sum (Cohere's
 ``shared_expert_combination_strategy: "average"``). The shared experts
 are one gated MLP ``n_shared x hidden`` wide either way — their sum —
-so the mean is that over ``n_shared``.
+so the mean is that over ``n_shared``. ``score='softmax_picked'`` is
+Granite's router: the pick is the top-k of the RAW logits ``x W_g`` and
+the gates are the softmax of those k logits — no sigmoid, no bias, no
+second normalisation and no factor, so ``router_bias=True``,
+``norm_topk=False`` and ``scaling != 1`` are refused beside it.
 
 The layer is TOLD which experts it holds (``experts_held = (lo, hi)``, a
 range; default all). It always routes over all ``n_experts``, computes
@@ -165,6 +169,7 @@ class SparseExperts(nn.Module):
     add_shared: bool = True
     shared_combine: str = 'sum'
     router_bias: bool = True
+    score: str = 'sigmoid'
     expert_form: str = 'gated'
     activation: str = 'silu'
     latent: Optional[int] = None
@@ -204,6 +209,17 @@ class SparseExperts(nn.Module):
         if self.expert_form not in ('gated', 'plain'):
             raise ValueError(f"expert_form must be 'gated' or 'plain', "
                              f'got {self.expert_form!r}')
+        if self.score not in ('sigmoid', 'softmax_picked'):
+            raise ValueError(f"score must be 'sigmoid' or "
+                             f"'softmax_picked', got {self.score!r}")
+        picked_softmax = self.score == 'softmax_picked'
+        if picked_softmax and (self.router_bias or not self.norm_topk
+                               or self.scaling != 1.0):
+            raise ValueError(
+                "score='softmax_picked' gates by the softmax of the "
+                'picked logits: it sums to one and takes no bias and no '
+                'factor (pass router_bias=False; norm_topk and scaling '
+                'stay at their defaults)')
         act = ACTIVATIONS[self.activation]
         gated = self.expert_form == 'gated'
         dim = x.shape[-1]
@@ -232,15 +248,20 @@ class SparseExperts(nn.Module):
                                     name='latent_down')(flat)
 
         with device_scope('lm.moe_route'):
-            scores = jax.nn.sigmoid(jnp.dot(
+            scores = jnp.dot(
                 flat.astype(jnp.float32), router.astype(jnp.float32),
-                precision=lax.Precision.HIGHEST))
-            _, picked = lax.top_k(
-                scores if bias is None else scores + bias, k)   # (n, k)
-            gates = jnp.take_along_axis(scores, picked, axis=-1)
-            if self.norm_topk:
-                gates = gates / jnp.sum(gates, -1, keepdims=True)
-            gates = gates * self.scaling
+                precision=lax.Precision.HIGHEST)
+            if picked_softmax:
+                top, picked = lax.top_k(scores, k)               # (n, k)
+                gates = jax.nn.softmax(top, axis=-1)
+            else:
+                scores = jax.nn.sigmoid(scores)
+                _, picked = lax.top_k(
+                    scores if bias is None else scores + bias, k)
+                gates = jnp.take_along_axis(scores, picked, axis=-1)
+                if self.norm_topk:
+                    gates = gates / jnp.sum(gates, -1, keepdims=True)
+                gates = gates * self.scaling
             expert = picked.reshape(-1)                          # (n·k,)
             counts = jnp.zeros((self.n_experts,), jnp.int32).at[
                 expert].add(1)

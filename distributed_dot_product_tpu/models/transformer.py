@@ -98,7 +98,9 @@ class TransformerBlock(nn.Module):
     - ``residual``: ``'add'`` | ``'hyper'`` (``models/hyper``: the input
       is a widened stream ``(..., mult, dim)`` float32 and each branch
       reads and writes it through its own ``HyperConnection(
-      **residual_kwargs)``);
+      **residual_kwargs)``); ``residual_scale`` (``'add'`` only) makes
+      every residual ``x + residual_scale · branch(LN(x))`` (Granite's
+      ``residual_multiplier``; 1 adds no operation);
     - ``parallel``: ``x + Attn(h) + FFN(h)`` with ``h = LN(x)``, ONE
       norm a block (``ln1``; there is no ``ln2``) and both branches
       from it, where the default runs them one after the other, each
@@ -124,6 +126,7 @@ class TransformerBlock(nn.Module):
     ssm_kwargs: Any = None
     residual: str = 'add'
     residual_kwargs: Any = None
+    residual_scale: float = 1.0
     parallel: bool = False
 
     def _norm(self, name):
@@ -187,16 +190,23 @@ class TransformerBlock(nn.Module):
                 self.hc_attn = HyperConnection(name='hc_attn', **hc_kw)
             if self.ffn != 'none':
                 self.hc_ffn = HyperConnection(name='hc_ffn', **hc_kw)
+            if self.residual_scale != 1.0:
+                raise ValueError("residual_scale scales the branch of "
+                                 "residual='add': a hyper-connection "
+                                 'weighs its branch itself')
         elif self.residual != 'add':
             raise ValueError(f"residual must be 'add' or 'hyper', got "
                              f'{self.residual!r}')
+
+    def _scaled(self, y):
+        return y if self.residual_scale == 1.0 else y * self.residual_scale
 
     def _around(self, which, x, branch):
         """The residual around one branch: ``x + branch(x)``, or the
         hyper-connection ``which`` ('attn' / 'ffn') mixing the stream
         into the branch and its output back."""
         if self.residual == 'add':
-            return x + branch(x)
+            return x + self._scaled(branch(x))
         u, h_post, h_res = getattr(self, f'hc_{which}')(x)
         y = branch(u.astype(self.dtype or u.dtype))
         return mix_back(x, y, h_post, h_res)
@@ -224,7 +234,8 @@ class TransformerBlock(nn.Module):
             return self._around('attn', x, lambda u: mixer(self.ln1(u)))
         if self.parallel:
             h = self.ln1(x)
-            return x + mixer(h) + self._mlp(h, norm=lambda u: u)
+            return (x + self._scaled(mixer(h))
+                    + self._scaled(self._mlp(h, norm=lambda u: u)))
         x = self._around('attn', x, lambda u: mixer(self.ln1(u)))
         return self._around('ffn', x, self._mlp)
 

@@ -51,6 +51,13 @@ HYBRID_SCOPES = ['ops.ssm_step', 'ops.ssm_scan', 'lm.ssm_proj',
                  'lm.moe_latent', 'lm.state_restore', 'ops.flash_decode',
                  'lm.attn_proj', 'lm.mlp', 'lm.moe_route', 'lm.moe_experts',
                  'lm.embed', 'lm.head', 'lm.stack_carry']
+# The decode step of the two-branch recurrent / attention + expert stack
+# with every multiplier set (the ``granitemoehybrid`` block): no scope of
+# its own, each new multiply inside its producer's.
+GRANITE_SCOPES = ['ops.ssm_step', 'lm.ssm_proj', 'ops.flash_decode',
+                  'lm.attn_proj', 'lm.mlp', 'lm.moe_route',
+                  'lm.moe_experts', 'lm.embed', 'lm.head',
+                  'lm.stack_carry']
 
 
 def tiny_lm(remat_policy=None, **attn_kwargs):
@@ -180,6 +187,30 @@ def hybrid_op_names():
     ).lower(caches).compile())
 
 
+@pytest.fixture(scope='module')
+def granite_op_names():
+    model = TransformerLM(
+        vocab_size=64, dim=32, num_heads=2, n_layers=2, scan_layers=False,
+        embed_scale=12.0, logit_scale=1 / 16,
+        attn_kwargs=dict(distributed=False, decode_impl='kernel',
+                         use_rope=False, softmax_scale=0.1),
+        block_kwargs=dict(
+            norm='rmsnorm', residual_scale=0.22, ffn='experts',
+            ffn_kwargs=dict(n_experts=8, top_k=2, hidden=16,
+                            shared_hidden=24, router_bias=False,
+                            score='softmax_picked', experts_held=(0, 4))),
+        layer_kinds={
+            'mamba': dict(mixer='ssm', ssm_kwargs=dict(
+                heads=4, head_dim=8, state=8, groups=1, chunk=8)),
+            'attention': dict(mixer='attention')},
+        layer_pattern=('mamba', 'attention'))
+    params = model.init(jax.random.key(0), jnp.zeros((2, 8), jnp.int32))
+    caches = model.make_decode_caches(2, 128)
+    return op_names(jax.jit(
+        lambda p, tok, c: model.apply(p, tok, c, method='decode')
+    ).lower(params, jnp.zeros((2, 1), jnp.int32), caches).compile())
+
+
 def opened(scope, names):
     return any(f'/{scope}/' in f'/{name}/' for name in names)
 
@@ -207,6 +238,34 @@ def test_mixed_decode_step_opens(scope, mixed_op_names):
 @pytest.mark.parametrize('scope', HYBRID_SCOPES)
 def test_hybrid_stack_opens(scope, hybrid_op_names):
     assert opened(scope, hybrid_op_names)
+
+
+@pytest.mark.parametrize('scope', GRANITE_SCOPES)
+def test_granite_decode_step_opens(scope, granite_op_names):
+    assert opened(scope, granite_op_names)
+
+
+def test_the_multipliers_sit_inside_their_producers_scopes(
+        granite_op_names):
+    """Nothing of the step's own arithmetic is unscoped; the embedding's
+    multiply is ``lm.embed``'s, the logits' ``lm.head``'s, a residual's
+    the stack's (no sub-scope takes it) and the gates' softmax
+    ``lm.moe_route``'s."""
+    mine = [n for n in granite_op_names if n.startswith('jit(')]
+    assert mine and all(
+        any(opened(scope, [n]) for scope in DEVICE_SCOPES) for n in mine)
+
+    def innermost(name):
+        return [part for part in name.split('/') if part in DEVICE_SCOPES][
+            -1]
+    ends = {(innermost(n), '/'.join(n.split('/')[-2:])) for n in mine}
+    assert ('lm.embed', 'lm.embed/mul') in ends
+    assert ('lm.head', 'lm.head/mul') in ends
+    assert {('lm.stack_carry', f'block_{i}._scaled/mul')
+            for i in (0, 1)} <= ends
+    # (the softmax lowers to its parts: the gates' ``exp`` is the only
+    # one in the routing)
+    assert ('lm.moe_route', 'lm.moe_route/exp') in ends
 
 
 def test_ring_mode_opens_inside_the_decode_kernels_scope(mixed_op_names):

@@ -318,8 +318,31 @@ PARENT_LOWERED = {
     'command-a.decode':
         '77b4a278f438d0348bb3fb405253ebd737bfb712dc16375d112330032ac8f4cc',
 }
+# The commit before the ``granitemoehybrid`` fields (a residual's scale,
+# the embedding's, the softmax's, the router's score form): the hybrid
+# cell's tiny preset as its driver builds it, the two training cells'
+# loss and gradient (``system.build_lm``, one device), and the slab
+# decode cell's prefill and step. Every new field at its default adds
+# no operation.
+PARENT_LOWERED.update({
+    'nemotron.prefill':
+        '031b8d9a7220df2eca934437bd9632f2c3d012f8e640cb664046c55d034c503d',
+    'nemotron.decode':
+        '29cca6473706468a888f6b950ff60344f8c9b8eb03221748e1b07596747eeee8',
+    'mpt.train':
+        '78a3b10d0d4e3b4ff721c32b29da655797011c42e3666d16c19683d722eba048',
+    'starcoder2.train':
+        '5b25661c9dd07f0ba73c97d6272a452454e10aa733297526b90d31f1df2f0520',
+    'mpt.prefill':
+        '815742e48f5b8dc41bd3067f861f7b5b70d5090b1b6d10d5bb0024cb405e5594',
+    'mpt.decode':
+        '225cd013e0f58c88df7ca82bb05f6ccbb244788f6d84d910e166e89f6ed64b33',
+})
 PRESETS = {'xing4': ('tiny_latent', 'tiny-xing4.decode'),
-           'command-a': ('tiny_mixed', 'tiny-command-a.decode')}
+           'command-a': ('tiny_mixed', 'tiny-command-a.decode'),
+           'nemotron': ('tiny_hybrid', 'tiny-nemotron.decode'),
+           'mpt': ('tiny', 'tiny-mpt.decode'),
+           'starcoder2': ('tiny', 'tiny-starcoder2.decode')}
 
 
 def _sha(lowered):
@@ -342,10 +365,25 @@ def test_accepted_programs_lower_to_the_parents_text(what):
     root, cell = PRESETS[name]
     cell = loader.Cell(cell, root=os.path.join(ROOT, 'benchmarks', 'tests',
                                                root))
-    model = cell.driver().build_lm(cell.config)
-    experts = {**model.block_kwargs['ffn_kwargs'], 'dense_tokens': 0}
-    model = model.clone(block_kwargs={**model.block_kwargs,
-                                      'ffn_kwargs': experts})
+    if name in ('mpt', 'starcoder2'):
+        from benchmarks import system
+        model = system.build_lm(cell.config, distributed=False)
+    else:
+        model = cell.driver().build_lm(cell.config)
+    if method == 'train':
+        tok = jnp.zeros((1, 64), jnp.int32)
+        params = jax.eval_shape(lambda: model.init(jax.random.key(0), tok))
+
+        def loss(p, t):
+            total, count = model.apply(p, t, t, method='nll_sum', chunk=32)
+            return total / count
+        assert _sha(jax.jit(jax.value_and_grad(loss)).lower(
+            params, tok)) == PARENT_LOWERED[what]
+        return
+    if name in ('xing4', 'command-a'):
+        experts = {**model.block_kwargs['ffn_kwargs'], 'dense_tokens': 0}
+        model = model.clone(block_kwargs={**model.block_kwargs,
+                                          'ffn_kwargs': experts})
     tok = jnp.zeros((2, 8 if method == 'prefill' else 1), jnp.int32)
     params = jax.eval_shape(lambda: model.init(
         jax.random.key(0), jnp.zeros((2, 8), jnp.int32)))

@@ -706,6 +706,94 @@ def test_hybrid_stack_decode_step_aliases_every_state_and_fits(
     assert restored.as_text().count('lm.state_restore') >= 10
 
 
+def test_granite_decode_step_aliases_nine_states_and_fits(chip, monkeypatch):
+    """The token step of the two-branch recurrent / attention + expert
+    stack at the published widths and the traffic of
+    ``granite-4.0-h-small.decode-4k`` (10 layers, 80 sessions, nine
+    ``(80, 128, 64, 128)`` float32 states of ONE B / C group beside one
+    5120-row slab of 8 KV heads), caches donated: the attention layer's
+    step resolves to the kernel; all nine states and the slab alias the
+    result; nothing as large as one layer's 80 states (335 MB) is
+    copied, sliced or written back and no temporary is that large (the
+    state update and its read against C stay one fusion a layer); every
+    layer's 80-row expert call is ONE ``moe_hit_experts`` kernel by the
+    rule's bound, a whole 768-wide expert a grid step, and no grouped
+    matmul; arguments + temporaries stay under 14.0 GiB with the
+    snapshot counted. The reset between requests writes all nine states
+    over in place, under its scope's name, with no temporary."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_granite as driver
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
+    from distributed_dot_product_tpu.ops.pallas_experts import _vmem_limit
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'granite-4.0-h-small-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-4k-x80.json')) as f:
+        traffic = json.load(f)
+    model = driver.build_lm(cfg)
+    params = _shape_table_params(driver, cfg)
+    sessions, t_max = traffic['sessions'], traffic['t_max']
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(sessions, t_max))
+    state = ((sessions, 128, 64, 128), (sessions, 3, 8448))
+    slab = 2 * ((sessions, 8, t_max, 128),)
+    assert [tuple(x.shape for x in c[:2]) for c in caches] == (
+        5 * [state] + [slab] + 4 * [state])
+    assert caches[0].state.dtype == jnp.float32
+    stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
+    tok = jnp.zeros((sessions, 1), jnp.int32)
+    restore, step = driver.make_programs(model, cfg)[-2:]
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+    with decode_impl_traces() as traces, expert_route_traces() as routes:
+        compiled = step.lower(
+            *described((params, tok, caches, stats))).compile()
+    assert [(t['resolved'], t['cache']) for t in traces] == [
+        ('kernel', 'layer')]
+    # 768 has no 512-column divisor: the 1 KB slab rule takes the whole
+    # expert, 18.9 MB a grid step, and asks for its blocks' room.
+    assert routes == 10 * [{'route': 'hit_list', 'n': sessions,
+                            'bound': 128, 'bound_by': 'rule',
+                            'tile': 768}]
+    assert _vmem_limit(4096, 768, 3, 2) == 52 << 20
+    hlo = compiled.as_text()
+    assert 'flash_decode' in hlo and 'ragged-dot' not in hlo
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*moe_hit_experts',
+        hlo)) == 10
+    state_bytes = sessions * 128 * 64 * 128 * 4
+    assert _cache_sized_moves(hlo, state_bytes) == []
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < state_bytes
+    states = [c if hasattr(c, 'state') else None for c in caches]
+    snapshot_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                         for x in jax.tree.leaves(states))
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            + snapshot_bytes) <= 14.0 * 2 ** 30
+    restored = restore.lower(*described(
+        (caches, states, jnp.zeros((), jnp.int32)))).compile()
+    assert restored.memory_analysis().temp_size_in_bytes < 1 << 20
+    # Everything but the slab's 4-byte length, which is set, not kept.
+    assert restored.memory_analysis().alias_size_in_bytes >= (
+        cache_bytes - 4)
+    assert restored.as_text().count('lm.state_restore') >= 18
+
+
 @pytest.mark.parametrize('remat_policy, forwards', [
     (None, 1), ('nothing_saveable', 2)], ids=['kept', 'full-remat'])
 def test_scanned_lm_train_step_runs_the_flash_forward_once(
