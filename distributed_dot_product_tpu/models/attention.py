@@ -54,11 +54,16 @@ from distributed_dot_product_tpu.models.ring_attention import (
     _layout_positions, local_attention_reference, ring_attention,
 )
 from distributed_dot_product_tpu.ops.rope import rope, rope_interleaved
+from distributed_dot_product_tpu.models.remat import (
+    LAYER_MATMUL_NAMES, named, note_named,
+)
 from distributed_dot_product_tpu.models.ulysses_attention import (
     ulysses_attention,
 )
 from distributed_dot_product_tpu.obs.spans import device_scope
-from distributed_dot_product_tpu.ops.pallas_attention import flash_attention
+from distributed_dot_product_tpu.ops.pallas_attention import (
+    FLASH_QKV_NAME, flash_attention,
+)
 from distributed_dot_product_tpu.ops.ops import matmul_all, matmul_nt
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
@@ -306,8 +311,12 @@ class DistributedDotProductAttn(nn.Module):
         # all-gather, scoped further in) is 'lm.attn_proj' in a device
         # trace.
         with device_scope('lm.attn_proj'):
-            return self._attend(keys, queries, values, attn_mask,
-                                segment_ids, deterministic, dropout_seed)
+            # Named on every softmax path: a checkpoint that keeps it
+            # rebuilds no output projection (LAYER_MATMUL_NAMES).
+            return named(
+                self._attend(keys, queries, values, attn_mask,
+                             segment_ids, deterministic, dropout_seed),
+                LAYER_MATMUL_NAMES[2])
 
     def _attend(self, keys, queries, values, attn_mask, segment_ids,
                 deterministic, dropout_seed):
@@ -497,6 +506,7 @@ class DistributedDotProductAttn(nn.Module):
                 if self.num_heads > 1:
                     sq, sk = sq[..., None, :], sk[..., None, :]
                 seg_pair = (sq, sk)
+            note_named(FLASH_QKV_NAME, keys, q_full, v_full)
             outputs = flash_attention(keys, q_full, v_full, attn_mask,
                                       scale=scale, causal=native_causal,
                                       causal_offset=causal_offset,
@@ -561,6 +571,7 @@ class DistributedDotProductAttn(nn.Module):
                 # IS the local math for segments/ALiBi/dropout/int8 (the
                 # plain einsum oracle has none of them); GQA is native
                 # there too.
+                note_named(FLASH_QKV_NAME, keys, queries, values)
                 outputs = flash_attention(
                     keys, queries, values, attn_mask, scale=scale,
                     causal=native_causal, window=self.window,

@@ -174,11 +174,12 @@ def quantize_dense_params(params):
     return walk(params)
 
 
-def dense_param_bytes(params):
-    """Total bytes of every array leaf in ``params`` — the
-    weights-streamed-per-step column of the decode benchmark's
-    quantized-vs-bf16 twin rows."""
+def dense_param_bytes(params, dtype=None):
+    """Total bytes of every array leaf in ``params`` (held as ``dtype``
+    where one is given) — the weights-streamed-per-step column of the
+    decode benchmark's quantized-vs-bf16 twin rows, and what a train
+    step reckons it holds (``train.make_lm_train_step``)."""
     import jax
-    return sum(int(x.size) * jnp.dtype(x.dtype).itemsize
+    return sum(int(x.size) * jnp.dtype(dtype or x.dtype).itemsize
                for x in jax.tree.leaves(params)
                if hasattr(x, 'dtype'))

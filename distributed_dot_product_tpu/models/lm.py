@@ -34,7 +34,7 @@ import dataclasses
 import functools
 import warnings
 from collections import OrderedDict
-from typing import Any, Optional
+from typing import Any, Callable, Optional, Union
 
 import flax.linen as nn
 import jax
@@ -79,9 +79,14 @@ class TransformerLM(nn.Module):
     ``remat``/``remat_policy`` forward to the stack (deep models compile
     O(1) in depth and fit backward memory per layer; ``remat=True`` with
     no policy keeps the flash kernel's output and logsumexp a layer —
-    ``(B, H, T, d_v)`` in the compute type + ``(B, H, T)`` float32 — and
-    rebuilds the rest; ``remat_policy='nothing_saveable'`` keeps
-    nothing).
+    ``(B, H, T, d_v)`` in the compute type + ``(B, H, T)`` float32 — and,
+    while they fit the chip beside what the train step holds, the MLP's
+    hidden pre-activation, q / k / v as the kernel takes them and the
+    attention output projection's result, in that order
+    (:class:`~distributed_dot_product_tpu.models.transformer.TransformerStack`
+    has the bytes, the fit and the way back where it does not fit);
+    norms, activations and residual adds are rebuilt;
+    ``remat_policy='nothing_saveable'`` keeps nothing).
 
     Call: ``apply(params, tokens (B, T/N int32), segment_ids=None,
     deterministic=False, dropout_seed=None) -> logits (B, T/N, vocab)``
@@ -106,7 +111,7 @@ class TransformerLM(nn.Module):
     attn_kwargs: Any = None
     scan_layers: bool = True
     remat: bool = False
-    remat_policy: Optional[str] = None
+    remat_policy: Optional[Union[str, Callable]] = None
     tie_embeddings: bool = True
     # The logits are ``logit_scale · LN_f(x) · Eᵀ`` (Cohere's models
     # state one; 1 adds no operation).

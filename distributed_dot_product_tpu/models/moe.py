@@ -86,6 +86,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from distributed_dot_product_tpu.models.dense import OwnedDense
+from distributed_dot_product_tpu.models.remat import (
+    LAYER_MATMUL_NAMES, named,
+)
 from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_experts import (
     HIT_LIST_ROWS, hidden_tile, hit_experts, hit_list,
@@ -126,7 +129,10 @@ def expert_route_traces():
 
 
 class GatedMLP(nn.Module):
-    """``W_down(silu(W_gate x) * W_up x)``, no biases."""
+    """``W_down(silu(W_gate x) * W_up x)``, no biases. Both halves of
+    the hidden pre-activation carry a checkpoint's name
+    (``LAYER_MATMUL_NAMES``: kept, SiLU and the product are rebuilt, the
+    matmuls are not)."""
     hidden: int
     dtype: Optional[jnp.dtype] = None
 
@@ -135,6 +141,7 @@ class GatedMLP(nn.Module):
         dense = dict(use_bias=False, dtype=self.dtype)
         gate = OwnedDense(self.hidden, name='gate', **dense)(x)
         up = OwnedDense(self.hidden, name='up', **dense)(x)
+        gate, up = (named(h, LAYER_MATMUL_NAMES[0]) for h in (gate, up))
         return OwnedDense(x.shape[-1], name='down', **dense)(
             nn.silu(gate) * up)
 

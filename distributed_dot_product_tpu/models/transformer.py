@@ -29,7 +29,8 @@ stack's (what a rematted layer keeps, and ``remat_policy``:
 :class:`TransformerStack`).
 """
 
-from typing import Any, Optional
+import contextlib
+from typing import Any, Callable, Optional, Union
 
 import flax.linen as nn
 import jax
@@ -46,11 +47,11 @@ from distributed_dot_product_tpu.models.latent import (
     LatentAttention, init_latent_cache,
 )
 from distributed_dot_product_tpu.models.moe import GatedMLP, SparseExperts
+from distributed_dot_product_tpu.models.remat import (
+    LAYER_MATMUL_NAMES, KeepWhatFits, named, new_layer,
+)
 from distributed_dot_product_tpu.models.ssm import Mamba2Mixer
 from distributed_dot_product_tpu.obs.spans import device_scope
-from distributed_dot_product_tpu.ops.pallas_attention import (
-    FLASH_RESIDUAL_NAMES,
-)
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
 __all__ = ['TransformerBlock', 'TransformerStack']
@@ -221,7 +222,10 @@ class TransformerBlock(nn.Module):
         with device_scope('lm.mlp'):
             if self.ffn == 'gated':
                 return self.mlp(norm(x))
-            return self.mlp_out(nn.gelu(self.mlp_in(norm(x))))
+            # The pre-activation is what a checkpoint keeps; GELU is
+            # rebuilt from it.
+            hidden = named(self.mlp_in(norm(x)), LAYER_MATMUL_NAMES[0])
+            return self.mlp_out(nn.gelu(hidden))
 
     def _both(self, x, mixer):
         """The block around ``mixer`` (normed input -> branch output):
@@ -317,6 +321,7 @@ class _ScanStackCore(nn.Module):
             seed = jnp.bitwise_xor(
                 jnp.asarray(dropout_seed, jnp.int32),
                 layer_idx * jnp.int32(0x61C88647))
+        new_layer()     # one trace of the body, one account of its names
         return self.block(x, attn_mask, segment_ids=segment_ids,
                           deterministic=deterministic,
                           dropout_seed=seed), None
@@ -361,18 +366,66 @@ class TransformerStack(nn.Module):
     the backward rematerializes one layer at a time — activation memory
     for the stack drops from O(n_layers) to O(1) layers plus the scan
     carry and what the policy keeps a layer. With no ``remat_policy``
-    that is the flash route's two residuals
-    (``save_only_these_names(*FLASH_RESIDUAL_NAMES)``): one
-    ``(B, H, T, d_v)`` tensor in the compute type and one ``(B, H, T)``
-    float32 a layer (136 MB + 2 MB at 32 heads x 16384 x 128 bfloat16),
-    for which the backward runs the O(T²) forward kernel once a step,
-    not twice; the projections, norms and MLP are still rebuilt. Only
-    ``flash_attention``'s differentiated forward emits the names (the
-    flash route, ulysses' local attention): the ``'full'`` and
-    ``'online'`` paths keep nothing. ``remat_policy`` selects a ``jax.checkpoint_policies`` name
-    in its place — ``'nothing_saveable'`` is full rematerialization,
-    for a run at the memory limit; ``'dots_saveable'`` etc. mean what
-    they mean in JAX."""
+    that is, by ``checkpoint_name``:
+
+    - always, the flash route's two residuals (``FLASH_RESIDUAL_NAMES``):
+      one ``(B, H, T, d_v)`` tensor in the compute type and one
+      ``(B, H, T)`` float32 a layer (136 MB + 2 MB at 32 heads x 16384 x
+      128 bfloat16), for which the backward runs the O(T²) forward
+      kernel once a step, not twice;
+    - while they fit, the longest prefix of ``LAYER_MATMUL_NAMES``, the
+      layer's matmul outputs that the backward reads: ``mlp_hidden``
+      (``B·T·mlp_ratio·dim`` elements; a gated MLP's two halves), then
+      ``flash_qkv`` (``B·T·(H + 2·H_kv)·d``: q / k / v as the kernel takes
+      them, rotated and split by head — under ``seq_mesh(N)`` k and v
+      gathered, q the local shard), then ``attn_out`` (``B·T·dim``). Kept,
+      the recompute holds no ``mlp_in``, q / k / v or output-projection
+      matmul; norms, activations and residual adds are always rebuilt.
+
+    THE FIT (:class:`~distributed_dot_product_tpu.models.remat.KeepWhatFits`)
+    is reckoned once, when the layer scan is traced, from shapes and one
+    constant of the chip — the same shapes on the same chip kind give
+    the same program: ``budget = 0.9444 · bytes_limit`` (the rest is the
+    fitted headroom under which the TPU compiler's schedule adds no
+    rematerialization of its own; PERF.md section 6, PR 37) ``− held −
+    n_layers · 2 · bytes(x)`` (the layer inputs the scan keeps and the
+    flash residuals) ``− max(transient, 2.8 · a layer's named bytes)``
+    (one layer's working set: its rebuilt tensors and their cotangents),
+    and name by name ``(n_layers − 1) · bytes`` is taken from it (the
+    layer being differentiated holds its own copy in the working set,
+    kept or rebuilt). ``held``, ``transient`` and the device whose
+    ``bytes_limit`` counts are the enclosing step's account
+    (``models.remat.step_holds``): ``train.make_lm_train_step`` sees the
+    whole model and its mesh and says parameters + gradients + optimizer
+    state + the compute-type copy of the parameters, the head's chunk
+    (less this stack's gradients, not live yet beside it) and the
+    mesh's device. A stack differentiated OUTSIDE that step sees only
+    its own parameters: it assumes four times their bytes (gradients,
+    two moments) and their copy, on the default device. The limit is the
+    device's reported one; a described device (an AOT compile) gets its
+    kind's, so the program compiled for it is the chip's; the CPU has
+    none and the whole tuple is kept; an accelerator that reports none
+    and whose kind is unknown keeps the flash residuals alone.
+    ``models.remat.remat_traces()`` reports what a trace took and why.
+
+    Who emits what: ``flash_attention``'s differentiated forward the
+    flash names and ``flash_qkv`` (the flash route, ulysses' local
+    attention); the attention module's ``__call__`` ``attn_out`` on every
+    softmax path; the block's MLP ``mlp_hidden``. So the ``'full'`` and
+    ``'online'`` (ring) paths keep ``mlp_hidden`` and ``attn_out`` and
+    rebuild their projections. A ``name`` equation lowers to nothing:
+    prefill and decode are the same programs with or without them.
+
+    ``remat_policy`` takes the default's place: a
+    ``jax.checkpoint_policies`` name — ``'nothing_saveable'`` is full
+    rematerialization, for a run at the memory limit; ``'dots_saveable'``
+    etc. mean what they mean in JAX — or a policy itself. THE WAY BACK
+    where the fitted default does not fit a step it was not fitted on
+    (the compile fails, or XLA's text holds ``.remat`` instructions and
+    the step runs slower) is a shorter prefix by hand,
+    ``jax.checkpoint_policies.save_only_these_names(
+    *FLASH_RESIDUAL_NAMES, *LAYER_MATMUL_NAMES[:n])``; ``n = 0`` is what
+    the stack kept before it knew these names."""
     dim: int
     num_heads: int
     n_layers: int = 2
@@ -385,7 +438,7 @@ class TransformerStack(nn.Module):
     attn_kwargs: Any = None
     scan_layers: bool = False
     remat: bool = False
-    remat_policy: Optional[str] = None
+    remat_policy: Optional[Union[str, Callable]] = None
     # The block's choices (TransformerBlock's norm / mixer / ffn /
     # residual / parallel fields, as a dict), and the KIND of each
     # layer: ``layer_kinds`` names the kinds, each with what it
@@ -436,7 +489,7 @@ class TransformerStack(nn.Module):
         if self.remat and not self.scan_layers:
             raise ValueError('remat=True requires scan_layers=True (the '
                              'unrolled stack has no scan body to wrap)')
-        if self.remat_policy is not None and not hasattr(
+        if isinstance(self.remat_policy, str) and not hasattr(
                 jax.checkpoint_policies, self.remat_policy):
             raise ValueError(
                 f'remat_policy {self.remat_policy!r} is not a '
@@ -467,11 +520,15 @@ class TransformerStack(nn.Module):
                            for i in range(self.n_layers)]
             return
         core = _ScanStackCore
+        self.keep = None
         if self.remat:
-            policy = (getattr(jax.checkpoint_policies, self.remat_policy)
-                      if self.remat_policy else
-                      jax.checkpoint_policies.save_only_these_names(
-                          *FLASH_RESIDUAL_NAMES))
+            if self.remat_policy is None:
+                policy = self.keep = KeepWhatFits(self.n_layers)
+            elif isinstance(self.remat_policy, str):
+                policy = getattr(jax.checkpoint_policies,
+                                 self.remat_policy)
+            else:
+                policy = self.remat_policy
             # static_argnums indexes layer()'s args after self:
             # deterministic (a Python bool) is arg 4.
             core = nn.remat(core, policy=policy, prevent_cse=False,
@@ -501,9 +558,16 @@ class TransformerStack(nn.Module):
         x = keys
         with device_scope('lm.stack_carry'):
             if self.scan_layers:
-                x, _ = self.layers.layer(
-                    x, jnp.arange(self.n_layers, dtype=jnp.int32),
-                    attn_mask, segment_ids, deterministic, dropout_seed)
+                tracing = contextlib.nullcontext()
+                if self.keep is not None:
+                    tracing = self.keep.tracing(
+                        x, self.layers.variables.get('params', {}),
+                        self.dtype)
+                with tracing:
+                    x, _ = self.layers.layer(
+                        x, jnp.arange(self.n_layers, dtype=jnp.int32),
+                        attn_mask, segment_ids, deterministic,
+                        dropout_seed)
                 return x
             for block in self.blocks:
                 x = block(x, attn_mask, segment_ids=segment_ids,

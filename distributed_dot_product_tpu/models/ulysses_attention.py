@@ -39,7 +39,10 @@ import jax
 from jax import lax
 import jax.numpy as jnp
 
-from distributed_dot_product_tpu.ops.pallas_attention import flash_attention
+from distributed_dot_product_tpu.models.remat import note_named
+from distributed_dot_product_tpu.ops.pallas_attention import (
+    FLASH_QKV_NAME, flash_attention,
+)
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
 __all__ = ['ulysses_attention']
@@ -162,6 +165,7 @@ def ulysses_attention(q, k, v, mask=None, *, axis_name=SEQ_AXIS,
     # kernel runs locally over the full sequence, so the per-row int8
     # quantization is computed on exactly the rows a single-device kernel
     # would see.
+    note_named(FLASH_QKV_NAME, qh, kh, vh)
     out = flash_attention(qh, kh, vh, full_mask, causal=causal, scale=scale,
                           softmax_mode=softmax_mode, segment_ids=seg_pair,
                           window=window, alibi_slopes=slopes_local,

@@ -67,7 +67,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributed_dot_product_tpu.obs.spans import device_scope
 
-__all__ = ['flash_attention', 'flash_bwd_traces', 'FLASH_RESIDUAL_NAMES']
+__all__ = ['flash_attention', 'flash_bwd_traces', 'FLASH_RESIDUAL_NAMES',
+           'FLASH_QKV_NAME']
 
 # ``jax.ad_checkpoint.checkpoint_name`` tags of the two residuals the
 # differentiated forward computes itself: the output ``(*batch, Tq, d_v)``
@@ -76,6 +77,12 @@ __all__ = ['flash_attention', 'flash_bwd_traces', 'FLASH_RESIDUAL_NAMES']
 # them with ``save_only_these_names(*FLASH_RESIDUAL_NAMES)`` and the
 # forward kernel is not run a second time (``TransformerStack``'s default).
 FLASH_RESIDUAL_NAMES = ('flash_out', 'flash_lse')
+
+# Tag of q, k and v as the differentiated forward takes them (rotated,
+# split by head, gathered): the backward kernel's other operands. A
+# checkpoint that keeps it rebuilds neither the projections nor the
+# rotation in front of them (``models.remat.LAYER_MATMUL_NAMES``).
+FLASH_QKV_NAME = 'flash_qkv'
 
 _NEG_BIG = -0.7 * 3.4e38  # large-finite fp32; keeps exp()/VJP NaN-free
 
@@ -1925,6 +1932,8 @@ def _flash_fwd(q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k,
     # (*batch, Tq) form, not the kernel's lane-padded (nb, Tq_p, 1).
     out = checkpoint_name(out, FLASH_RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
+    # The backward kernel's other operands, whole.
+    q, k, v = (checkpoint_name(x, FLASH_QKV_NAME) for x in (q, k, v))
     return out, (q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k,
                  pos_q, pos_k, alibi, dropout_seed, out, lse)
 

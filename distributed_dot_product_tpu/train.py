@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from distributed_dot_product_tpu.models.dense import dense_param_bytes
+from distributed_dot_product_tpu.models.remat import step_holds
 from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
@@ -231,7 +233,19 @@ def make_lm_train_step(model, optimizer, mesh, seq_axis=SEQ_AXIS,
                 count = lax.psum(count, axes)
             return loss_sum / jnp.maximum(count, 1.0)
 
-        local_val, grads = jax.value_and_grad(local_obj)(params)
+        # What this step holds whatever a rematted stack keeps (the
+        # stack's default policy fits its kept tensors beside it, see
+        # TransformerStack): parameters, their gradients, the optimizer
+        # state and the compute-type copy of the parameters; while no
+        # layer is live, the head's chunk of float32 logits and their
+        # gradient; on the device the mesh compiles for.
+        rows = tokens.size if loss_chunk is None else min(
+            tokens.size, tokens.shape[0] * loss_chunk)
+        with step_holds(
+                2 * dense_param_bytes(params) + dense_param_bytes(opt_state)
+                + dense_param_bytes(params, model.dtype),
+                2 * 4 * rows * model.vocab_size, mesh.devices.flat[0]):
+            local_val, grads = jax.value_and_grad(local_obj)(params)
         with device_scope('train.grad_sync'):
             # Shard-sum OUTSIDE the grad: the global token-mean loss
             # value…

@@ -16,8 +16,12 @@ import pytest
 from distributed_dot_product_tpu.analysis.jaxpr_rules import (
     _iter_eqns, _sub_jaxprs,
 )
-from distributed_dot_product_tpu.models import attention, lm, transformer
+from distributed_dot_product_tpu.models import (
+    attention, lm, remat, transformer,
+)
+from distributed_dot_product_tpu.models.dense import dense_param_bytes
 from distributed_dot_product_tpu.models.lm import TransformerLM, lm_targets
+from distributed_dot_product_tpu.models.remat import LAYER_MATMUL_NAMES
 from distributed_dot_product_tpu.obs.spans import (
     DEVICE_SCOPES, device_scope,
 )
@@ -414,6 +418,19 @@ def assert_bitwise(got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def rebuilt_matmuls(fn, *args):
+    """The dense layers whose matmul the rematerialized layer body runs
+    again, by their module names."""
+    return {str(eqn.source_info.name_stack).rsplit('/', 1)[-1]
+            for eqn in _iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == 'dot_general'
+            and 'rematted_computation' in str(eqn.source_info.name_stack)}
+
+
+PROJECTIONS = {'keys', 'queries', 'values'}
+LAYER_MATMULS = PROJECTIONS | {'composition', 'mlp_in'}
+
+
 @pytest.mark.parametrize('width', [1, 4])
 def test_remat_runs_the_flash_forward_once(width):
     """The scanned stack's remat keeps the flash forward's two residuals
@@ -426,24 +443,212 @@ def test_remat_runs_the_flash_forward_once(width):
         'flash_fwd', 'flash_bwd_fused']
     assert kernel_names(full, *full_args) == [
         'flash_fwd', 'flash_fwd', 'flash_bwd_fused']
-    assert checkpoint_names(kept, *kept_args) == set(FLASH_RESIDUAL_NAMES)
+    assert checkpoint_names(kept, *kept_args) == {
+        *FLASH_RESIDUAL_NAMES, *LAYER_MATMUL_NAMES}
     assert_bitwise(kept(*kept_args), full(*full_args))
 
 
+@pytest.mark.parametrize('width', [1, 4])
+def test_remat_rebuilds_no_matmul_it_kept(width):
+    """The CPU has no limit to fit, so the default keeps the whole of
+    ``LAYER_MATMUL_NAMES``: the rematerialized body holds no ``mlp_in``,
+    q / k / v or output-projection matmul (``mlp_out``'s output is read
+    by nothing in the backward: never rebuilt). Full remat, by its
+    name, rebuilds all five."""
+    kept, kept_args = train_step_and_args(width)
+    full, full_args = train_step_and_args(width, 'nothing_saveable')
+    assert rebuilt_matmuls(kept, *kept_args) == set()
+    assert rebuilt_matmuls(full, *full_args) == LAYER_MATMULS
+    assert checkpoint_names(full, *full_args) == {
+        *FLASH_RESIDUAL_NAMES, *LAYER_MATMUL_NAMES}
+
+
+def fit_record(width=1, **attn_kwargs):
+    """What ``remat_traces()`` says of the tiny train step's one stack."""
+    step, args = train_step_and_args(width, **attn_kwargs)
+    with remat.remat_traces() as traces:
+        jax.make_jaxpr(step)(*args)
+    (record,) = traces
+    return record
+
+
+def limit_for(record, n):
+    """The least ``bytes_limit`` at which the first ``n`` names fit."""
+    need = sum((record['n_layers'] - 1) * record['layer_bytes'][name]
+               for name in LAYER_MATMUL_NAMES[:n])
+    fixed = (record['held'] + record['layer_inputs']
+             + max(record['transient'], record['layer_work']))
+    return int((need + fixed) / (1 - remat._HEADROOM)) + 2
+
+
+@pytest.mark.parametrize('n', [0, 1, 2, 3])
+def test_the_fit_takes_each_prefix_as_the_limit_shrinks(monkeypatch, n):
+    """With a device that reports a limit, the default keeps the longest
+    prefix of ``LAYER_MATMUL_NAMES`` whose stacked bytes fit beside what
+    the step holds; ``remat_traces()`` says which names, the first one
+    refused and the budget's parts, and the rematerialized body rebuilds
+    exactly the matmuls behind the names it did not keep."""
+    monkeypatch.setattr(remat, 'device_bytes_limit', lambda _: 1 << 40)
+    roomy = fit_record()
+    assert roomy['first_refused'] is None and roomy['limit'] == 1 << 40
+    assert roomy['layer_bytes'] == {
+        # (1, 64, 4 x 32) float32; q, k and v (1, 2, 64, 16); (1, 64, 32)
+        'mlp_hidden': 32768, 'flash_qkv': 3 * 8192, 'attn_out': 8192}
+    limit = limit_for(roomy, n)
+    monkeypatch.setattr(remat, 'device_bytes_limit', lambda _: limit)
+    record = fit_record()
+    assert record['kept'] == (*FLASH_RESIDUAL_NAMES,
+                              *LAYER_MATMUL_NAMES[:n])
+    assert record['first_refused'] == (LAYER_MATMUL_NAMES + (None,))[n]
+    assert record['kept_bytes'] == sum(
+        roomy['layer_bytes'][name] for name in LAYER_MATMUL_NAMES[:n])
+    assert 0 <= record['budget'] - record['kept_bytes'] < 8
+    assert record['budget'] == (
+        limit * (1 - remat._HEADROOM) - record['held']
+        - record['layer_inputs']
+        - max(record['transient'], record['layer_work']))
+    assert record['headroom'] == limit * remat._HEADROOM
+    step, args = train_step_and_args(1)
+    assert rebuilt_matmuls(step, *args) == [
+        LAYER_MATMULS, PROJECTIONS | {'composition'}, {'composition'},
+        set()][n]
+    if n:   # a few bytes less and the n-th name is refused
+        monkeypatch.setattr(remat, 'device_bytes_limit',
+                            lambda _: limit - 16)
+        assert fit_record()['first_refused'] == LAYER_MATMUL_NAMES[n - 1]
+
+
+def test_the_step_tells_the_stack_what_it_holds(monkeypatch):
+    """Inside ``make_lm_train_step`` the budget is reckoned from the
+    step's own account (parameters twice, optimizer state, the
+    compute-type copy; the head's chunk less the stack's gradients);
+    a stack differentiated outside one assumes its own parameters four
+    times and their copy."""
+    monkeypatch.setattr(remat, 'device_bytes_limit', lambda _: 1 << 40)
+    step, (params, opt_state, _) = train_step_and_args(1)
+    record = fit_record()
+    size = dense_param_bytes
+    assert record['held'] == 3 * size(params) + size(opt_state)
+    stack = params['params']['stack']
+    # 16 rows of 64 float32 logits and their gradient: less than the
+    # stack's gradients, which are not live yet beside them.
+    assert record['transient'] == max(0, 2 * 4 * 16 * 64 - size(stack)) == 0
+
+    model = transformer.TransformerStack(
+        dim=32, num_heads=2, n_layers=2, scan_layers=True, remat=True,
+        attn_kwargs=dict(distributed=False))
+    x = jnp.zeros((1, 64, 32))
+    alone = model.init(jax.random.key(0), x, x, x)
+    with remat.remat_traces() as traces:
+        jax.make_jaxpr(jax.grad(
+            lambda p: model.apply(p, x, x, x).sum()))(alone)
+    assert traces[0]['held'] == 5 * size(alone)
+    assert traces[0]['transient'] == 0
+
+
+@pytest.mark.parametrize('width', [1, 4])
+def test_remat_is_bitwise_whatever_it_keeps(monkeypatch, width):
+    """The default with every name kept, with the flash residuals alone
+    (a limit nothing more fits) and full remat: the same matmuls, fewer
+    times — loss, parameters and optimizer state bit for bit the same,
+    on one device and over four."""
+    every, every_args = train_step_and_args(width)
+    want = every(*every_args)
+    full, full_args = train_step_and_args(width, 'nothing_saveable')
+    assert_bitwise(full(*full_args), want)
+    monkeypatch.setattr(remat, 'device_bytes_limit', lambda _: 1 << 40)
+    monkeypatch.setattr(
+        remat, 'device_bytes_limit',
+        lambda _, limit=limit_for(fit_record(width), 0): limit)
+    flash_only, flash_args = train_step_and_args(width)
+    with remat.remat_traces() as traces:
+        got = flash_only(*flash_args)
+    assert traces[0]['kept'] == FLASH_RESIDUAL_NAMES
+    assert_bitwise(got, want)
+
+
+@pytest.mark.parametrize('n', [0, 1, 2, 3])
+def test_remat_policy_takes_a_policy_itself(monkeypatch, n):
+    """The way back where the fitted default does not fit: a prefix of
+    the names by hand, as a ``jax.checkpoint_policies`` policy. It is the
+    program the fit gives at a limit that admits that prefix, equation
+    for equation, and the fit is not asked."""
+    by_hand = jax.checkpoint_policies.save_only_these_names(
+        *FLASH_RESIDUAL_NAMES, *LAYER_MATMUL_NAMES[:n])
+    step, args = train_step_and_args(1, by_hand)
+    with remat.remat_traces() as traces:
+        got = equations(jax.make_jaxpr(step)(*args).jaxpr)
+    assert not traces
+    monkeypatch.setattr(remat, 'device_bytes_limit', lambda _: 1 << 40)
+    limit = limit_for(fit_record(), n)
+    monkeypatch.setattr(remat, 'device_bytes_limit', lambda _: limit)
+    fitted, fitted_args = train_step_and_args(1)
+    assert got == equations(jax.make_jaxpr(fitted)(*fitted_args).jaxpr)
+
+
+class DescribedDevice:
+    """A device as ``topologies.get_topology_desc`` describes one: it
+    has a kind and no memory statistics."""
+    platform = 'tpu'
+
+    def __init__(self, device_kind):
+        self.device_kind = device_kind
+
+    def memory_stats(self):
+        raise jax.errors.JaxRuntimeError(
+            'INVALID_ARGUMENT: MemoryStats is only supported for '
+            'addressable PjRt devices.')
+
+
+class AttachedDevice(DescribedDevice):
+    def memory_stats(self):
+        return {'bytes_limit': 1 << 34, 'bytes_in_use': 1 << 20}
+
+
+@pytest.mark.parametrize('device, limit', [
+    (None, float('inf')),
+    (AttachedDevice('TPU v5 lite'), 1 << 34),
+    (DescribedDevice('TPU v5 lite'), 16909336064),
+    (DescribedDevice('TPU v9'), 0),
+], ids=['cpu', 'attached', 'described-v5e', 'described-unknown'])
+def test_the_limit_is_the_compile_targets(monkeypatch, device, limit):
+    """The fit's limit is the device's the step's mesh compiles for:
+    what it reports; a described device's kind's, so an AOT compile
+    gives the chip's program; none on the CPU (everything is kept); and
+    where an accelerator says nothing and its kind is unknown, 0 — the
+    flash residuals alone, the set that fitted before these names."""
+    device = device or jax.devices()[0]
+    assert remat.device_bytes_limit(device) == limit
+    asked = []
+    monkeypatch.setattr(remat, 'device_bytes_limit',
+                        lambda d: asked.append(d) or limit)
+    record = fit_record()
+    assert set(asked) == {seq_mesh(1).devices.flat[0]}
+    assert record['limit'] == limit
+    assert record['kept'] == (
+        *FLASH_RESIDUAL_NAMES, *(LAYER_MATMUL_NAMES if limit else ()))
+    assert record['first_refused'] == (None if limit else 'mlp_hidden')
+
+
 @pytest.mark.parametrize('softmax_impl', ['online', 'full'])
-def test_remat_keeps_nothing_where_no_flash_forward_is_differentiated(
+def test_remat_keeps_the_blocks_names_where_no_flash_forward_runs(
         softmax_impl):
-    """Only ``flash_attention``'s differentiated forward emits the names
-    (the flash route, ulysses' local attention): the ring fold calls the
-    kernels inside its own rule and the full path has none, so such a
-    stack under the same default is the full-remat program, equation for
-    equation, and gives its outputs."""
+    """Only ``flash_attention``'s differentiated forward emits the flash
+    names and q / k / v's (the flash route, ulysses' local attention):
+    the ring fold calls the kernels inside its own rule and the full
+    path has none. Such a stack keeps what the block and the attention
+    module's call name — the MLP's pre-activation and the output
+    projection — and rebuilds its q / k / v projections; the outputs are
+    full remat's."""
     kept, kept_args = train_step_and_args(softmax_impl=softmax_impl)
     full, full_args = train_step_and_args(
         remat_policy='nothing_saveable', softmax_impl=softmax_impl)
-    assert not checkpoint_names(kept, *kept_args)
-    assert (equations(jax.make_jaxpr(kept)(*kept_args).jaxpr)
-            == equations(jax.make_jaxpr(full)(*full_args).jaxpr))
+    assert checkpoint_names(kept, *kept_args) == {'mlp_hidden', 'attn_out'}
+    assert rebuilt_matmuls(kept, *kept_args) == PROJECTIONS
+    assert rebuilt_matmuls(full, *full_args) == LAYER_MATMULS
+    record = fit_record(2, softmax_impl=softmax_impl)
+    assert record['layer_bytes']['flash_qkv'] == 0
+    assert record['kept'] == (*FLASH_RESIDUAL_NAMES, *LAYER_MATMUL_NAMES)
     assert_bitwise(kept(*kept_args), full(*full_args))
 
 

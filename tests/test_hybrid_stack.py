@@ -329,14 +329,22 @@ PARENT_LOWERED.update({
         '031b8d9a7220df2eca934437bd9632f2c3d012f8e640cb664046c55d034c503d',
     'nemotron.decode':
         '29cca6473706468a888f6b950ff60344f8c9b8eb03221748e1b07596747eeee8',
-    'mpt.train':
-        '78a3b10d0d4e3b4ff721c32b29da655797011c42e3666d16c19683d722eba048',
-    'starcoder2.train':
-        '5b25661c9dd07f0ba73c97d6272a452454e10aa733297526b90d31f1df2f0520',
     'mpt.prefill':
         '815742e48f5b8dc41bd3067f861f7b5b70d5090b1b6d10d5bb0024cb405e5594',
     'mpt.decode':
         '225cd013e0f58c88df7ca82bb05f6ccbb244788f6d84d910e166e89f6ed64b33',
+})
+# The two training cells' loss and gradient are the program of the commit
+# that changed what a rematted layer keeps (PR 37: the flash residuals
+# and all of ``LAYER_MATMUL_NAMES`` where no device limit is reported;
+# before it '78a3b10d…' / '5b25661c…'). The serving programs above run
+# the same named MLP and attention output and are still the parents'
+# text (``RENUMBERED`` below).
+PARENT_LOWERED.update({
+    'mpt.train':
+        '34cf8c89a3ac811db9c762e508d0e96a1ee00f553c4f7c7c5448a1bf783233b2',
+    'starcoder2.train':
+        'a1eb34db815d5a9726e93baf7d193b53247c2d1b61f95ebdd5ea1ff854ea5378',
 })
 PRESETS = {'xing4': ('tiny_latent', 'tiny-xing4.decode'),
            'command-a': ('tiny_mixed', 'tiny-command-a.decode'),
@@ -345,8 +353,21 @@ PRESETS = {'xing4': ('tiny_latent', 'tiny-xing4.decode'),
            'starcoder2': ('tiny', 'tiny-starcoder2.decode')}
 
 
-def _sha(lowered):
-    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+# Since PR 37 a gated MLP names its pre-activation for a checkpoint. A
+# ``name`` equation lowers to nothing, so every serving program above is
+# still the parent's text — in xing4's two, whose dense layer and shared
+# experts are gated MLPs, but for the NUMBER jax's lowering gives one
+# private function (its symbols are numbered as they are asked for).
+RENUMBERED = {'xing4.prefill': ('@silu_355', '@silu_354'),
+              'xing4.decode': ('@silu_293', '@silu_292')}
+
+
+def _sha(lowered, renumbered=None):
+    text = lowered.as_text()
+    if renumbered:
+        assert renumbered[0] in text and renumbered[1] not in text
+        text = text.replace(*renumbered)
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 @pytest.mark.parametrize('what', sorted(PARENT_LOWERED))
@@ -389,7 +410,8 @@ def test_accepted_programs_lower_to_the_parents_text(what):
         jax.random.key(0), jnp.zeros((2, 8), jnp.int32)))
     caches = jax.eval_shape(lambda: model.make_decode_caches(2, 32))
     fn = jax.jit(lambda p, t, c: model.apply(p, t, c, method=method))
-    assert _sha(fn.lower(params, tok, caches)) == PARENT_LOWERED[what]
+    assert _sha(fn.lower(params, tok, caches),
+                RENUMBERED.get(what)) == PARENT_LOWERED[what]
 
 
 def test_the_dense_route_of_a_gated_layer_is_the_sorted_one():

@@ -848,3 +848,129 @@ def test_scanned_lm_train_step_runs_the_flash_forward_once(
     assert len(head) == 3 and all(
         'transpose(jvp(' not in n and 'rematted_computation' not in n
         for n in head)
+
+
+V5E_BYTES_LIMIT = 16909336064      # what a v5e chip reports
+XLAS_OWN_REMAT = r'%[\w.-]+\.remat\d* = '
+
+
+def _train_step_for_v5e(chip, model, optimizer):
+    """``make_lm_train_step`` at 16384 tokens compiled for the described
+    chip under the stack's default fit; ``(compiled, what
+    remat_traces() said, None of a policy by hand)``."""
+    from distributed_dot_product_tpu.models import remat
+    from distributed_dot_product_tpu.parallel.mesh import seq_mesh
+    from distributed_dot_product_tpu.train import make_lm_train_step
+    (device,) = chip.device_set
+    step = make_lm_train_step(model, optimizer,
+                              seq_mesh(1, devices=[device]), guard=False,
+                              loss_chunk=4096)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 128),
+                                                        jnp.int32)))
+    tok = jnp.zeros((1, 16384), jnp.int32)
+    with remat.remat_traces() as traces:
+        compiled = _compile(chip, step, params,
+                            jax.eval_shape(optimizer.init, params),
+                            (tok, tok), donate=(0, 1))
+    assert (compiled.memory_analysis().peak_memory_in_bytes
+            < V5E_BYTES_LIMIT)
+    if not traces:
+        return compiled, None
+    # The limit is the described device's kind's: nothing is patched.
+    assert traces[-1]['limit'] == V5E_BYTES_LIMIT
+    return compiled, traces[-1]
+
+
+def _rebuilt_matmuls(hlo):
+    return re.findall(r' convolution\([^\n]*op_name="([^"]*'
+                      r'rematted_computation[^"]*)"', hlo)
+
+
+@pytest.mark.parametrize('config, kept, refused', [
+    ('mpt-7b', ('mlp_hidden', 'flash_qkv', 'attn_out'), None),
+    ('starcoder2-3b', ('mlp_hidden',), 'flash_qkv'),
+])
+def test_training_cells_keep_what_fits_a_v5e(chip, monkeypatch, config,
+                                             kept, refused):
+    """Both training cells' steps (``benchmarks/system.build_lm`` at the
+    cell's depth, 16384 tokens, AdamW) with the fit reckoned against the
+    described v5e's ``bytes_limit``: MPT's two layers keep all of
+    ``LAYER_MATMUL_NAMES``, StarCoder2's five the MLP's pre-activation
+    alone. The step compiles, so it fits — and WITHOUT a
+    rematerialization of XLA's own (no ``.remat`` instruction: with q / k
+    / v kept too StarCoder2's step compiles only because XLA rebuilds
+    ``mlp_in`` itself, and runs slower than the parent's; chip, PR 37).
+    The kept tensors ride the scan as stacked buffers with a leading
+    layer axis, and no matmul behind a kept name is left in the
+    rematerialized body."""
+    import json
+    from benchmarks import system
+    from benchmarks.drivers.train import make_optimizer
+    from distributed_dot_product_tpu.ops.pallas_attention import (
+        FLASH_RESIDUAL_NAMES,
+    )
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           f'{config}.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'train-16k.json')) as f:
+        optimizer = make_optimizer(json.load(f)['optimizer'])
+    model = system.build_lm(cfg)
+    compiled, record = _train_step_for_v5e(chip, model, optimizer)
+    assert record['kept'] == (*FLASH_RESIDUAL_NAMES, *kept)
+    assert record['first_refused'] == refused
+    t, layers, hidden = 16384, model.n_layers, model.mlp_ratio * model.dim
+    assert record['layer_bytes']['mlp_hidden'] == t * hidden * 2
+    hlo = compiled.as_text()
+    assert not re.findall(XLAS_OWN_REMAT, hlo)
+    for stacked in (f'bf16[{layers},1,{t},{hidden}]',
+                    f'bf16[{layers},1,{model.num_heads},{t},128]'):
+        assert stacked in hlo
+    rebuilt = _rebuilt_matmuls(hlo)
+    assert not [n for n in rebuilt if '/mlp_in/' in n]
+    assert bool([n for n in rebuilt
+                 if re.search('/(keys|queries|values)/', n)]) == (
+        'flash_qkv' not in kept)
+    assert bool([n for n in rebuilt if '/composition/' in n]) == (
+        'attn_out' not in kept)
+
+
+def test_the_fit_holds_at_a_width_it_was_not_fitted_on(chip, monkeypatch):
+    """The fit's two constants were fitted at the two training cells'
+    widths. A step of another shape — 2048 wide, 16 heads, MLP 8192,
+    eight layers, a 49152-token untied head, plain ``optax.adamw`` —
+    compiled for the described v5e: the fit takes ``mlp_hidden`` and
+    refuses q / k / v, the step fits, and XLA adds no rematerialization
+    of its own to the pick. The refusal is not caution: with q / k / v
+    kept by hand (``remat_policy`` takes a policy) the step compiles
+    only with XLA's own ``.remat`` instructions. (At six and seven
+    layers the fit keeps all three names, free of them too; AOT,
+    PR 37.)"""
+    import optax
+    from distributed_dot_product_tpu import TransformerLM
+    from distributed_dot_product_tpu.models.remat import LAYER_MATMUL_NAMES
+    from distributed_dot_product_tpu.ops.pallas_attention import (
+        FLASH_RESIDUAL_NAMES,
+    )
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    model = TransformerLM(
+        vocab_size=49152, dim=2048, num_heads=16, n_layers=8,
+        dtype=jnp.bfloat16, scan_layers=True, remat=True,
+        tie_embeddings=False, attn_kwargs=dict(causal=True))
+    compiled, record = _train_step_for_v5e(chip, model, optax.adamw(3e-4))
+    assert record['kept'] == (*FLASH_RESIDUAL_NAMES, 'mlp_hidden')
+    assert record['first_refused'] == 'flash_qkv'
+    hlo = compiled.as_text()
+    assert not re.findall(XLAS_OWN_REMAT, hlo)
+    assert 'bf16[8,1,16384,8192]' in hlo
+    rebuilt = _rebuilt_matmuls(hlo)
+    assert not [n for n in rebuilt if '/mlp_in/' in n]
+    assert [n for n in rebuilt if '/queries/' in n]
+    by_hand = model.clone(
+        remat_policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUAL_NAMES, *LAYER_MATMUL_NAMES[:2]))
+    two, fit = _train_step_for_v5e(chip, by_hand, optax.adamw(3e-4))
+    assert fit is None and re.findall(XLAS_OWN_REMAT, two.as_text())
