@@ -193,6 +193,13 @@ class DistributedDotProductAttn(nn.Module):
     # parity path, the flash, ring and Ulysses routes, prefill and both
     # decode steps — so that they agree.
     softmax_scale: Optional[float] = None
+    # An elementwise output gate (``solar_open2``'s ``use_gqa_gate``): a
+    # second projection ``gate`` of the same input as ``keys`` — the
+    # literature's query side under the K-first convention — as wide as
+    # the heads' output, ``sigmoid``, times that output before the
+    # ``composition``; at every entry point, inside ``lm.attn_proj``.
+    # False adds no parameter and no operation.
+    out_gate: bool = False
     # 'int8' = int8 WEIGHT quantization for the four projection
     # matmuls (models/dense.py): kernels stored int8 with per-output-
     # channel scales (quantize_dense_params at load/convert time),
@@ -297,6 +304,22 @@ class DistributedDotProductAttn(nn.Module):
         self.values_proj = dense(
             kv_heads * (value_dim // self.num_heads), 'values')
         self.composition = dense(self.out_dim or value_dim, 'composition')
+        if self.out_gate:
+            self.gate_proj = dense(value_dim, 'gate')
+
+    def _gate(self, keys):
+        """The output gate of the UNPROJECTED ``keys`` input, float32
+        ``(…, T, value_dim)``; None without ``out_gate``."""
+        if not self.out_gate:
+            return None
+        return jax.nn.sigmoid(self.gate_proj(keys).astype(jnp.float32))
+
+    def _compose(self, outputs, gate):
+        """The output projection of the merged heads' ``outputs (…, T,
+        value_dim)``, under ``gate`` where there is one."""
+        if gate is not None:
+            outputs = (outputs * gate).astype(outputs.dtype)
+        return self.composition(outputs)
 
     @property
     def _scale(self):
@@ -339,6 +362,7 @@ class DistributedDotProductAttn(nn.Module):
         # O(T²) input left on the flash/ulysses/ring paths, so dropping it
         # (or using causal=True, handled blockwise in-kernel) is what lets
         # one chip train at T in the hundreds of thousands.
+        gate = self._gate(keys)
         keys = self.keys_proj(keys)
         queries = self.queries_proj(queries)
         values = self.values_proj(values)
@@ -522,7 +546,7 @@ class DistributedDotProductAttn(nn.Module):
                 outputs = jnp.swapaxes(outputs, -3, -2)
                 outputs = outputs.reshape(*outputs.shape[:-2],
                                           self._value_dim)
-            return self.composition(outputs)
+            return self._compose(outputs, gate)
 
         if softmax_impl == 'ulysses':
             # Head all-to-all path (distributed, num_heads > 1 guaranteed
@@ -541,7 +565,7 @@ class DistributedDotProductAttn(nn.Module):
                 dropout_rate=drop_rate, dropout_seed=drop_seed)
             outputs = jnp.swapaxes(outputs, -3, -2)
             outputs = outputs.reshape(*outputs.shape[:-2], self._value_dim)
-            return self.composition(outputs)
+            return self._compose(outputs, gate)
 
         if softmax_impl == 'online':
             # Long-context path: ring attention with online softmax — the
@@ -592,7 +616,7 @@ class DistributedDotProductAttn(nn.Module):
                 outputs = jnp.swapaxes(outputs, -3, -2)
                 outputs = outputs.reshape(*outputs.shape[:-2],
                                           self._value_dim)
-            return self.composition(outputs)
+            return self._compose(outputs, gate)
 
         if kv_group > 1:
             # Parity path under GQA: repeat the grouped heads up to H —
@@ -624,7 +648,7 @@ class DistributedDotProductAttn(nn.Module):
         if self.num_heads > 1:
             outputs = jnp.swapaxes(outputs, -3, -2)
             outputs = outputs.reshape(*outputs.shape[:-2], self._value_dim)
-        return self.composition(outputs)
+        return self._compose(outputs, gate)
 
     def make_decode_cache(self, batch, t_max, dtype=None):
         """A KV cache sized for this module's projections (GQA-aware:
@@ -656,10 +680,12 @@ class DistributedDotProductAttn(nn.Module):
         """Shared front half of :meth:`prefill`/:meth:`decode`: the four
         projections, GQA head split, and RoPE at the true global
         positions ``length + arange(n)`` (``length`` the cache's) — ONE
-        definition so the two inference entry points cannot drift."""
+        definition so the two inference entry points cannot drift. The
+        fourth result is the output gate (:meth:`_gate`)."""
         if not self.causal:
             raise ValueError('cached decoding is autoregressive and '
                              'requires causal=True')
+        gate = self._gate(keys)
         keys = self.keys_proj(keys)
         queries = self.queries_proj(queries)
         values = self.values_proj(values)
@@ -676,7 +702,7 @@ class DistributedDotProductAttn(nn.Module):
             pos = length + jnp.arange(n)
             keys = self._rope(keys, pos)
             queries = self._rope(queries, pos)
-        return keys, queries, values
+        return keys, queries, values, gate
 
     def _rope(self, x, pos):
         if self.rope_layout == 'half':
@@ -686,10 +712,10 @@ class DistributedDotProductAttn(nn.Module):
             x, pos, self.rope_base ** (
                 -jnp.arange(0, d, 2, dtype=jnp.float32) / d))
 
-    def _merge_decode_heads(self, out):
+    def _merge_decode_heads(self, out, gate):
         out = jnp.swapaxes(out, -3, -2)
         out = out.reshape(*out.shape[:-2], self._value_dim)
-        return self.composition(out)
+        return self._compose(out, gate)
 
     def prefill(self, keys, queries, values, cache, segment_ids=None,
                 seg_cache=None):
@@ -713,7 +739,7 @@ class DistributedDotProductAttn(nn.Module):
             RingCache, append_kv, ring_append, ring_window,
         )
         with device_scope('lm.attn_proj'):
-            keys, queries, values = self._project_for_decode(
+            keys, queries, values, gate = self._project_for_decode(
                 keys, queries, values, cache.length)
             if isinstance(cache, RingCache):
                 # The chunk sees the ring's previous rows (< window of
@@ -732,7 +758,7 @@ class DistributedDotProductAttn(nn.Module):
                     scale=self._scale,
                     window=self.window)
                 return (ring_append(cache, queries, values),
-                        self._merge_decode_heads(out))
+                        self._merge_decode_heads(out, gate))
             start = cache.length
             cache = append_kv(cache, queries, values)
             seg_pair = None
@@ -749,7 +775,7 @@ class DistributedDotProductAttn(nn.Module):
                 scale=self._scale, window=self.window,
                 alibi_slopes=self.alibi_slopes, qk_quant=self.qk_quant,
                 segment_ids=seg_pair)
-            return cache, self._merge_decode_heads(out)
+            return cache, self._merge_decode_heads(out, gate)
 
     def decode(self, keys, queries, values, cache, segment_ids=None,
                seg_cache=None, layer=None):
@@ -792,7 +818,7 @@ class DistributedDotProductAttn(nn.Module):
             if layer is not None:
                 length = jax.lax.dynamic_index_in_dim(
                     length, layer, keepdims=False)
-            keys, queries, values = self._project_for_decode(
+            keys, queries, values, gate = self._project_for_decode(
                 keys, queries, values, length)
             cache, out = decode_step(
                 keys, cache, queries, values,
@@ -800,7 +826,7 @@ class DistributedDotProductAttn(nn.Module):
                 window=self.window, alibi_slopes=self.alibi_slopes,
                 qk_quant=self.qk_quant, segment_ids=seg_cache,
                 seg_q=segment_ids, impl=self.decode_impl, layer=layer)
-            return cache, self._merge_decode_heads(out)
+            return cache, self._merge_decode_heads(out, gate)
 
     def decode_sharded(self, keys, queries, values, cache,
                        segment_ids=None, seg_cache=None, axis_name=None):
@@ -823,7 +849,7 @@ class DistributedDotProductAttn(nn.Module):
         )
         ax = axis_name or self.axis_name
         with device_scope('lm.attn_proj'):
-            keys, queries, values = self._project_for_decode(
+            keys, queries, values, gate = self._project_for_decode(
                 keys, queries, values, cache.length)
             cache, out = decode_step(
                 keys, cache, queries, values,
@@ -831,7 +857,7 @@ class DistributedDotProductAttn(nn.Module):
                 window=self.window, alibi_slopes=self.alibi_slopes,
                 qk_quant=self.qk_quant, segment_ids=seg_cache,
                 seg_q=segment_ids, axis_name=ax, impl=self.decode_impl)
-            return cache, self._merge_decode_heads(out)
+            return cache, self._merge_decode_heads(out, gate)
 
 
 def apply_seq_parallel(module, params, mesh, keys, queries, values,

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from distributed_dot_product_tpu.models.attention import (
     DistributedDotProductAttn,
 )
+from distributed_dot_product_tpu.models.delta import GatedDeltaMixer
 from distributed_dot_product_tpu.models.dense import OwnedDense
 from distributed_dot_product_tpu.models.hyper import (
     HyperConnection, mix_back,
@@ -55,6 +56,11 @@ from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
 __all__ = ['TransformerBlock', 'TransformerStack']
+
+
+# The recurrent mixers, by the name of a block's ``mixer`` AND of its
+# subtree: each takes ``ssm_kwargs`` and keeps a ``StateCache``.
+RECURRENT = {'ssm': Mamba2Mixer, 'delta': GatedDeltaMixer}
 
 
 def make_norm(kind, eps, dtype, name):
@@ -88,7 +94,9 @@ class TransformerBlock(nn.Module):
       are its ranks and head sizes, its cache one layer-stacked
       ``LatentCache`` addressed by ``layer``) | ``'ssm'``
       (``models/ssm.Mamba2Mixer(**ssm_kwargs)``, the subtree ``ssm``;
-      its cache a fixed-size ``StateCache``) | ``'none'``;
+      its cache a fixed-size ``StateCache``) | ``'delta'``
+      (``models/delta.GatedDeltaMixer(**ssm_kwargs)``, the subtree
+      ``delta``; a ``StateCache`` too) | ``'none'``;
     - ``ffn``: ``'gelu'`` (``mlp_ratio`` x dim) | ``'gated'``
       (``ffn_kwargs['hidden']``, SiLU-gated, no biases) | ``'experts'``
       (``models/moe.SparseExperts(**ffn_kwargs)``) | ``'none'``. A
@@ -149,13 +157,15 @@ class TransformerBlock(nn.Module):
                 kw.setdefault('out_dim', self.dim)
             self.attn = DistributedDotProductAttn(
                 num_heads=self.num_heads, **kw)
-        elif self.mixer == 'ssm':
-            self.ssm = Mamba2Mixer(dim=self.dim, name='ssm', **{
-                'dtype': self.dtype, 'norm_eps': self.norm_eps,
-                **(self.ssm_kwargs or {})})
+        elif self.mixer in RECURRENT:
+            setattr(self, self.mixer, RECURRENT[self.mixer](
+                dim=self.dim, name=self.mixer, **{
+                    'dtype': self.dtype, 'norm_eps': self.norm_eps,
+                    **(self.ssm_kwargs or {})}))
         elif self.mixer != 'none':
             raise ValueError(f"mixer must be 'attention', 'latent', "
-                             f"'ssm' or 'none', got {self.mixer!r}")
+                             f"'ssm', 'delta' or 'none', got "
+                             f'{self.mixer!r}')
         one_branch = 'none' in (self.mixer, self.ffn)
         if one_branch and (self.parallel or self.mixer == self.ffn):
             raise ValueError('a block has a mixer, a feed-forward or '
@@ -246,8 +256,8 @@ class TransformerBlock(nn.Module):
     def __call__(self, x, attn_mask=None, segment_ids=None,
                  deterministic=False, dropout_seed=None):
         def mixer(h):
-            if self.mixer == 'ssm':
-                return self.ssm(h)
+            if self.mixer in RECURRENT:
+                return getattr(self, self.mixer)(h)
             if self.mixer == 'latent':
                 return self.attn(h)
             return self.attn(h, h, h, attn_mask, segment_ids=segment_ids,
@@ -261,8 +271,9 @@ class TransformerBlock(nn.Module):
         held = [cache]
 
         def mixer(h):
-            if self.mixer == 'ssm':
-                held[0], a = getattr(self.ssm, method)(h, held[0])
+            if self.mixer in RECURRENT:
+                held[0], a = getattr(getattr(self, self.mixer), method)(
+                    h, held[0])
                 return a
             step = getattr(self.attn, method)
             if self.mixer == 'latent':
@@ -504,7 +515,7 @@ class TransformerStack(nn.Module):
                     f'{sorted(self.layer_kinds or {})} and divide '
                     f'n_layers {self.n_layers}')
         if self.scan_layers and (self._mixed or self._latent
-                                 or kw.get('mixer') == 'ssm'
+                                 or kw.get('mixer') in RECURRENT
                                  or kw.get('ffn') == 'experts'):
             # XLA's grouped-matmul kernel takes an expert layer's
             # weights whole, so nn.scan's slice of layer-stacked experts
@@ -513,8 +524,9 @@ class TransformerStack(nn.Module):
             # scan over periods; the latent cache is carried from block
             # to block, and a recurrent state is no layer of a stack.
             raise ValueError("more than one layer kind, mixer='latent', "
-                             "mixer='ssm' and ffn='experts' run "
-                             'unrolled: pass scan_layers=False')
+                             "a recurrent mixer ('ssm', 'delta') and "
+                             "ffn='experts' run unrolled: pass "
+                             'scan_layers=False')
         if not self.scan_layers:
             self.blocks = [self._block(f'block_{i}', i)
                            for i in range(self.n_layers)]
@@ -595,9 +607,9 @@ class TransformerStack(nn.Module):
             mixer = block.get('mixer', 'attention')
             if mixer == 'none':
                 return None
-            if mixer == 'ssm':
+            if mixer in RECURRENT:
                 # Of FIXED size: t_max says nothing to it.
-                return Mamba2Mixer(dim=self.dim, parent=None, **{
+                return RECURRENT[mixer](dim=self.dim, parent=None, **{
                     'dtype': self.dtype, **(block.get('ssm_kwargs') or {})
                 }).make_cache(batch, dtype=dtype)
             return DistributedDotProductAttn(

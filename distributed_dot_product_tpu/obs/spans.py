@@ -126,6 +126,22 @@ DEVICE_SCOPES = {
     'ops.ssm_scan': 'prefill / whole sequence: the recurrence in its '
                     'chunked form (decay-masked C·B^T products inside a '
                     'chunk, the state stepped between chunks)',
+    'lm.delta_proj': 'a gated delta-rule mixer outside its recurrence: '
+                     'the input projection, the three causal depthwise '
+                     'convolutions, the L2 norms of q and k, the '
+                     'low-rank decay (softplus) and rate (sigmoid), the '
+                     'per-head norm under its sigmoid gate and the '
+                     'output projection',
+    'ops.delta_step': 'decode: the one pass over a delta-rule layer\'s '
+                      'state (decay a key channel, the reduction '
+                      'against k, the rank-one correction, the read '
+                      'against q): the Pallas kernel delta_step and the '
+                      'transposition of its column operands, or the two '
+                      'XLA fusions that stand for it',
+    'ops.delta_scan': 'prefill / whole sequence: the delta rule in its '
+                      'chunked form (the chunks\' decay-difference '
+                      'products, the batched triangular inverse, the '
+                      'scan over chunks)',
     'lm.state_restore': 'copies of the recurrent layers\' states: the '
                         'snapshot at a prompt\'s end and the restore '
                         'from it between requests',

@@ -62,6 +62,13 @@ GRANITE_SCOPES = ['ops.ssm_step', 'lm.ssm_proj', 'ops.flash_decode',
                   'lm.attn_proj', 'lm.mlp', 'lm.moe_route',
                   'lm.moe_experts', 'lm.embed', 'lm.head',
                   'lm.stack_carry']
+# The ``solar_open2`` stack (gated delta-rule layers beside a gated GQA
+# layer, experts in every layer): the decode step and a prefill chunk
+# (the chunked delta rule).
+DELTA_SCOPES = ['ops.delta_step', 'ops.delta_scan', 'lm.delta_proj',
+                'ops.flash_decode', 'lm.attn_proj', 'lm.mlp',
+                'lm.moe_route', 'lm.moe_experts', 'lm.embed', 'lm.head',
+                'lm.stack_carry']
 
 
 def tiny_lm(remat_policy=None, **attn_kwargs):
@@ -215,6 +222,32 @@ def granite_op_names():
     ).lower(params, jnp.zeros((2, 1), jnp.int32), caches).compile())
 
 
+@pytest.fixture(scope='module')
+def delta_op_names():
+    """``{'decode': …, 'prefill': …}`` of the delta-rule / gated GQA +
+    expert stack, the step's state pass as the kernel."""
+    model = TransformerLM(
+        vocab_size=64, dim=32, num_heads=2, n_layers=2, scan_layers=False,
+        tie_embeddings=False,
+        attn_kwargs=dict(distributed=False, decode_impl='kernel',
+                         use_rope=False, out_gate=True),
+        block_kwargs=dict(
+            norm='rmsnorm', ffn='experts',
+            ffn_kwargs=dict(n_experts=8, top_k=2, hidden=16,
+                            experts_held=(0, 4))),
+        layer_kinds={
+            'kda': dict(mixer='delta', ssm_kwargs=dict(
+                heads=4, head_dim=8, chunk=8, step_impl='pallas')),
+            'gqa': dict(mixer='attention')},
+        layer_pattern=('gqa', 'kda'))
+    params = model.init(jax.random.key(0), jnp.zeros((2, 8), jnp.int32))
+    caches = model.make_decode_caches(2, 128)
+    return {method: op_names(jax.jit(
+        lambda p, tok, c, m=method: model.apply(p, tok, c, method=m)
+    ).lower(params, jnp.zeros((2, n), jnp.int32), caches).compile())
+        for method, n in (('decode', 1), ('prefill', 8))}
+
+
 def opened(scope, names):
     return any(f'/{scope}/' in f'/{name}/' for name in names)
 
@@ -247,6 +280,42 @@ def test_hybrid_stack_opens(scope, hybrid_op_names):
 @pytest.mark.parametrize('scope', GRANITE_SCOPES)
 def test_granite_decode_step_opens(scope, granite_op_names):
     assert opened(scope, granite_op_names)
+
+
+@pytest.mark.parametrize('scope', DELTA_SCOPES)
+def test_delta_stack_opens(scope, delta_op_names):
+    assert opened(scope, delta_op_names['decode']
+                  | delta_op_names['prefill'])
+
+
+def test_the_delta_rules_arithmetic_sits_in_its_scopes(delta_op_names):
+    """Nothing of the step's or the prefill's own arithmetic is
+    unscoped, and nothing of the new mixer's is left to the stack: the
+    recurrence is ``ops.delta_step`` (the kernel ``delta_step`` and the
+    transposition of its column operands) or ``ops.delta_scan`` (the
+    triangular solve among it), everything around it ``lm.delta_proj``,
+    the attention layer's gate (a ``logistic`` of its own projection)
+    ``lm.attn_proj``."""
+    def innermost(name):
+        return [part for part in name.split('/') if part in DEVICE_SCOPES][
+            -1]
+    for method, recurrence in (('decode', 'ops.delta_step'),
+                               ('prefill', 'ops.delta_scan')):
+        mine = [n for n in delta_op_names[method] if n.startswith('jit(')]
+        assert mine and all(
+            any(opened(scope, [n]) for scope in DEVICE_SCOPES)
+            for n in mine)
+        inside = {innermost(n) for n in mine if '/delta.' in n}
+        assert inside == {recurrence, 'lm.delta_proj'}
+        # the attention module's own operations, its gate among them
+        assert {innermost(n) for n in mine if '/attn.' in n} <= {
+            'lm.attn_proj', 'ops.flash_decode', 'ops.flash_fwd'}
+    kernel = [n for n in delta_op_names['decode'] if '/delta_step/' in n]
+    assert kernel and all('/ops.delta_step/delta_step/' in n
+                          for n in kernel)
+    assert any(innermost(n) == 'ops.delta_scan'
+               and n.endswith('/triangular_solve')
+               for n in delta_op_names['prefill'] if n.startswith('jit('))
 
 
 def test_the_multipliers_sit_inside_their_producers_scopes(
@@ -295,7 +364,7 @@ def test_latent_kernel_is_outside_the_projection_scope(latent_op_names):
 
 def test_the_steps_cover_the_vocabulary():
     assert (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(LATENT_SCOPES)
-            | set(MIXED_SCOPES) | set(HYBRID_SCOPES)
+            | set(MIXED_SCOPES) | set(HYBRID_SCOPES) | set(DELTA_SCOPES)
             == set(DEVICE_SCOPES))
 
 
@@ -720,6 +789,15 @@ def test_hit_experts_build_carries_its_kernel_name_under_its_scope():
               if 'moe_hit_experts' in n]
     assert kernel and all('/lm.moe_experts/moe_hit_experts/' in n
                           for n in kernel)
+
+
+def test_delta_step_build_carries_its_kernel_name():
+    from distributed_dot_product_tpu.ops.pallas_delta import delta_step
+    vec = jnp.zeros((2, 4, 8), jnp.float32)
+    assert kernel_names(delta_step, vec, vec, vec, vec,
+                        jnp.zeros((2, 4), jnp.float32),
+                        jnp.zeros((2, 4, 8, 8), jnp.float32)) == [
+        'delta_step']
 
 
 def test_every_kernel_name_has_its_scope():

@@ -346,7 +346,17 @@ PARENT_LOWERED.update({
     'starcoder2.train':
         'a1eb34db815d5a9726e93baf7d193b53247c2d1b61f95ebdd5ea1ff854ea5378',
 })
-PRESETS = {'xing4': ('tiny_latent', 'tiny-xing4.decode'),
+# The commit before the ``solar_open2`` fields (a third recurrent mixer
+# kind, the attention module's output gate): the two-branch recurrent +
+# expert cell's tiny preset, the seventh accepted cell.
+PARENT_LOWERED.update({
+    'granite.prefill':
+        '8833b977eb7033acc359fcd84a12639fcdb26dbb3dfa1663156ea858832883cd',
+    'granite.decode':
+        '18dfff1be57a8be18ecef2dc1ae688f97e0613f46b9511ab82944e699a0b6f6b',
+})
+PRESETS = {'granite': ('tiny_granite', 'tiny-granite.decode'),
+           'xing4': ('tiny_latent', 'tiny-xing4.decode'),
            'command-a': ('tiny_mixed', 'tiny-command-a.decode'),
            'nemotron': ('tiny_hybrid', 'tiny-nemotron.decode'),
            'mpt': ('tiny', 'tiny-mpt.decode'),

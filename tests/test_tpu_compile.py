@@ -794,6 +794,152 @@ def test_granite_decode_step_aliases_nine_states_and_fits(chip, monkeypatch):
     assert restored.as_text().count('lm.state_restore') >= 18
 
 
+def _entry_readers(hlo, shape):
+    """The ENTRY computation's fusions and custom calls that take an
+    operand of type ``shape`` (``'f32[128,64,128,128]'``): who reads a
+    buffer of that type."""
+    entry = hlo.split('ENTRY ')[1]
+    types = dict(re.findall(r'(%[\w.-]+) = \(?([a-z0-9]+\[[\d,]*\])', entry))
+    return [line.strip()[:200] for line in entry.splitlines()
+            if re.search(r' (fusion|custom-call)\(', line)
+            and any(types.get(name) == shape for name in re.findall(
+                r'%[\w.-]+', line.split('(', 1)[1].split('),')[0]))]
+
+
+def test_delta_step_kernel_compiles_and_xla_reads_the_state_twice(chip):
+    """One token of a gated delta-rule layer at the widths of
+    ``solar-open2-250b.decode-4k`` (128 sessions x 64 heads of ``(128,
+    128)`` float32), the state donated: the kernel ``delta_step`` is ONE
+    custom call with the state aliased and no state-sized temporary; the
+    plain XLA form is two fusions that each take the state — the
+    reduction against k, then the update — which is why there is a
+    kernel."""
+    from distributed_dot_product_tpu.ops.pallas_delta import (
+        delta_step, delta_step_reference, heads_tile,
+    )
+    b, h, d = 128, 64, 128
+    assert heads_tile(h, d, d) == 16         # 1 MiB of state a grid step
+    vec = jnp.zeros((b, h, d), jnp.float32)
+    args = (vec, vec, vec, vec, jnp.zeros((b, h), jnp.float32),
+            jnp.zeros((b, h, d, d), jnp.float32))
+    state_bytes = b * h * d * d * 4
+    state = f'f32[{b},{h},{d},{d}]'
+    for fn, readers in ((lambda *a: delta_step(*a, interpret=False), 1),
+                        (delta_step_reference, 2)):
+        shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), args)
+        compiled = jax.jit(fn, donate_argnums=(5,)).lower(*shapes).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == state_bytes
+        assert mem.temp_size_in_bytes < state_bytes // 8
+        takers = _entry_readers(compiled.as_text(), state)
+        assert len(takers) == readers, takers
+        assert all('delta_step' in t for t in takers) == (readers == 1)
+
+
+def test_solar_decode_step_reads_three_states_once_and_fits(
+        chip, monkeypatch):
+    """The token step of the delta-rule / gated-attention + expert stack
+    at the published widths and the traffic of
+    ``solar-open2-250b.decode-4k`` (4 layers, 128 sessions, three ``(128,
+    64, 128, 128)`` float32 states with 24576-channel windows beside one
+    5120-row slab of 8 KV heads), caches donated: the attention layer's
+    step resolves to the kernel; every delta mixer's step is the kernel
+    ``delta_step``, 16 heads a grid step — three custom calls, each
+    state aliased and read by nothing else; nothing as large as one
+    layer's 128 states (537 MB) is copied, sliced or written back and no
+    temporary is that large; every layer's 128-row expert call — the
+    rule's bound itself — is ONE ``moe_hit_experts`` kernel, two 640-wide
+    tiles an expert (1280 has no 512-column divisor) under the
+    ``vmem_limit_bytes`` the plan states, and no grouped matmul;
+    arguments + temporaries stay under 14.0 GiB with the snapshot
+    counted. The reset between requests writes all three states over in
+    place, under its scope's name, with no temporary."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_solar as driver
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    from distributed_dot_product_tpu.models.delta import delta_step_traces
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
+    from distributed_dot_product_tpu.ops.pallas_experts import (
+        HIT_LIST_ROWS, _vmem_limit, hidden_tile,
+    )
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'solar-open2-250b-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-4k-x128.json')) as f:
+        traffic = json.load(f)
+    model = driver.build_lm(cfg)
+    params = _shape_table_params(driver, cfg)
+    sessions, t_max = traffic['sessions'], traffic['t_max']
+    assert sessions == HIT_LIST_ROWS
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(sessions, t_max))
+    state = ((sessions, 64, 128, 128), (sessions, 3, 24576))
+    slab = 2 * ((sessions, 8, t_max, 128),)
+    assert [tuple(x.shape for x in c[:2]) for c in caches] == (
+        [slab] + 3 * [state])
+    assert caches[1].state.dtype == jnp.float32
+    stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
+    tok = jnp.zeros((sessions, 1), jnp.int32)
+    restore, step = driver.make_programs(model, cfg)[-2:]
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+    with decode_impl_traces() as traces, expert_route_traces() as routes, \
+            delta_step_traces() as forms:
+        compiled = step.lower(
+            *described((params, tok, caches, stats))).compile()
+    assert [(t['resolved'], t['cache']) for t in traces] == [
+        ('kernel', 'layer')]
+    assert forms == 3 * [{'form': 'pallas', 'tile': 16, 'chunk': 64}]
+    assert hidden_tile(4096, 1280, 3, 2) == 640
+    assert routes == 4 * [{'route': 'hit_list', 'n': sessions,
+                           'bound': 128, 'bound_by': 'rule',
+                           'tile': 640}]
+    # three blocks of 5.24 MB, double-buffered, and the default on top
+    assert _vmem_limit(4096, 640, 3, 2) == 46 << 20
+    hlo = compiled.as_text()
+    assert 'flash_decode' in hlo and 'ragged-dot' not in hlo
+    for kernel, calls in (('moe_hit_experts', 4), ('delta_step', 3)):
+        assert len(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*' + kernel,
+            hlo)) == calls
+    state_bytes = sessions * 64 * 128 * 128 * 4
+    assert _cache_sized_moves(hlo, state_bytes) == []
+    # Each state is an operand of its kernel and of nothing else: read
+    # once, written once.
+    takers = _entry_readers(hlo, f'f32[{sessions},64,128,128]')
+    assert len(takers) == 3 and all('delta_step' in t for t in takers)
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < state_bytes
+    states = [c if hasattr(c, 'state') else None for c in caches]
+    snapshot_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                         for x in jax.tree.leaves(states))
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            + snapshot_bytes)
+    assert 11.0 * 2 ** 30 <= peak <= 14.0 * 2 ** 30
+    restored = restore.lower(*described(
+        (caches, states, jnp.zeros((), jnp.int32)))).compile()
+    assert restored.memory_analysis().temp_size_in_bytes < 1 << 20
+    # Everything but the slab's 4-byte length, which is set, not kept.
+    assert restored.memory_analysis().alias_size_in_bytes >= (
+        cache_bytes - 4)
+    assert restored.as_text().count('lm.state_restore') >= 6
+
+
 @pytest.mark.parametrize('remat_policy, forwards', [
     (None, 1), ('nothing_saveable', 2)], ids=['kept', 'full-remat'])
 def test_scanned_lm_train_step_runs_the_flash_forward_once(
