@@ -251,7 +251,7 @@ def phase_train(progs, cfg, seed, state):
         TrainLoopConfig, TrainState, lm_targets, run_training,
     )
     from distributed_dot_product_tpu.ops.pallas_attention import (
-        flash_bwd_traces,
+        flash_block_traces, flash_bwd_traces,
     )
     from distributed_dot_product_tpu.parallel.mesh import seq_mesh
     from distributed_dot_product_tpu.train import make_lm_train_step
@@ -267,8 +267,10 @@ def phase_train(progs, cfg, seed, state):
     step = make_lm_train_step(model, optimizer, mesh, donate=False,
                               guard=True)
     # Which form the flash backward takes at this shape (the training
-    # cells' form: one fused kernel, dq resident in VMEM).
-    with flash_bwd_traces() as bwd_traces:
+    # cells' form: one fused kernel, dq resident in VMEM), and how many
+    # of each kernel's run blocks are interior (no position compares).
+    with flash_bwd_traces() as bwd_traces, \
+            flash_block_traces() as block_traces:
         progs.compile('train_step', step, params, opt_state, batch,
                       pallas=True)
     result = run_training(
@@ -308,7 +310,7 @@ def phase_train(progs, cfg, seed, state):
     return {
         'T': cfg['train_t'], 'losses': losses,
         'bad_steps': result.bad_steps,
-        'flash_bwd': bwd_traces,
+        'flash_bwd': bwd_traces, 'flash_blocks': block_traces,
         'ref_T': cfg['ref_t'], 'loss_flash': float(loss_f),
         'loss_plain': float(loss_p), 'logits_max_abs_err': logit_err,
         'logits_max_abs': logit_scale,

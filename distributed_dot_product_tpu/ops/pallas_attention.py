@@ -42,6 +42,13 @@ performance pass). Layout, per the TPU Pallas playbook:
   matmuls entirely (``pl.when``) — ~2× for causal attention, forward and
   backward; sliding-window programs additionally skip blocks wholly past
   the window (compute linear in T);
+- a computed block's position arithmetic goes by the block's KIND, told
+  once a block from its place in the grid (``_block_interior``): blocks
+  wholly under the diagonal and inside the window — most of a causal
+  call's — take a branch of the body without the causal / window /
+  padding compares, and ALiBi's bias is one ``(1, bk)`` vector a block,
+  its row constant carried in the logsumexp's domain (``_alibi_bias``);
+  :func:`flash_block_traces` counts a trace's blocks by kind;
 - masked logits are ``-inf`` (safe: every shift is clamped finite, see
   ``_apply_masks``), so fully-masked rows return 0 with zero gradients
   in-kernel, matching
@@ -59,6 +66,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 # pltpu is importable (pure Python) even off-TPU; the interpreter emulates
@@ -67,8 +75,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from distributed_dot_product_tpu.obs.spans import device_scope
 
-__all__ = ['flash_attention', 'flash_bwd_traces', 'FLASH_RESIDUAL_NAMES',
-           'FLASH_QKV_NAME']
+__all__ = ['flash_attention', 'flash_bwd_traces', 'flash_block_traces',
+           'FLASH_RESIDUAL_NAMES', 'FLASH_QKV_NAME']
 
 # ``jax.ad_checkpoint.checkpoint_name`` tags of the two residuals the
 # differentiated forward computes itself: the output ``(*batch, Tq, d_v)``
@@ -131,9 +139,29 @@ def _pad_dim(x, axis, mult):
 
 def _apply_masks(s, qi, ki, bq, bk, causal, kv_len, mask_ref, off_ref,
                  seg=None, pos=None, mask_live=None, window=None,
-                 alibi=None):
+                 alibi=None, interior=False):
     """Shared logit masking: user mask block, segment ids, causal future,
-    Tk padding.
+    Tk padding — and the ALiBi bias.
+
+    ``interior`` (static) is the block's KIND, decided once a block by
+    :func:`_block_interior` from the block's place in the grid: on an
+    interior block every row sees every column, so the causal, window and
+    ``kv_len`` selects would select nothing and are left out — the same
+    bits, none of the work. What the body then does an ELEMENT of the
+    ``(bq, bk)`` score block, beside the softmax's own operations:
+
+    - interior block, no ALiBi: nothing;
+    - interior block, ALiBi: ONE add, of the block's ``(1, bk)`` bias
+      vector (``_alibi_bias``; the row's constant never touches the
+      block);
+    - boundary block (the diagonal, the window's edge, a ragged last K
+      block): two iotas and two adds of the block origin, then a compare
+      and a select for the causal future, a subtract, a compare and a
+      select for the window, an iota, a compare and a select for the
+      padding, and ALiBi's add as above;
+    - a call that carries a dense mask, segment ids or explicit
+      positions (data, not place): every block is a boundary block and
+      the mask / segment / position selects come on top.
 
     The mask arrives as int8 (1 = masked): Mosaic widens bool kernel
     operands to s32 — a full-size O(4·Tq·Tk) HBM copy — but takes int8
@@ -158,6 +186,8 @@ def _apply_masks(s, qi, ki, bq, bk, causal, kv_len, mask_ref, off_ref,
     what makes whole-block skipping exact: a skipped block contributes
     nothing, the same as folding its all-zero weights.
     """
+    assert not interior or (mask_ref is None and seg is None
+                            and pos is None), 'data masks have no kind'
     if mask_ref is not None:
         masked = mask_ref[0] != 0
         if mask_live is not None:
@@ -167,21 +197,7 @@ def _apply_masks(s, qi, ki, bq, bk, causal, kv_len, mask_ref, off_ref,
             masked = jnp.logical_and(masked, mask_live)
         s = jnp.where(masked, -jnp.inf, s)
     if alibi is not None:
-        # ALiBi: additive relative-position bias slope·(col − row) over
-        # GLOBAL positions (the wrapper pre-folds log2e so the bias is in
-        # the kernel's log2 logit units). Distances come from the pos
-        # vectors when given (arbitrary layouts), else from the
-        # contiguous off_ref arithmetic — the wrapper guarantees one of
-        # the two (same requirement as ``window``).
-        if pos is not None:
-            dist = (pos[1][0] - pos[0][0]).astype(jnp.float32)
-        else:
-            rows = (off_ref[0, 0] + qi * bq
-                    + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
-            cols = (off_ref[0, 1] + ki * bk
-                    + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
-            dist = (cols - rows).astype(jnp.float32)
-        s = s + alibi * dist
+        s = s + _alibi_bias(alibi, qi, ki, bq, bk, off_ref, pos)
     if seg is not None:
         s = jnp.where(seg[0][0] != seg[1][0], -jnp.inf, s)
     if pos is not None:
@@ -190,6 +206,8 @@ def _apply_masks(s, qi, ki, bq, bk, causal, kv_len, mask_ref, off_ref,
             # Sliding window over explicit positions: a pair whose key
             # lies ≥ window positions in the query's past is masked.
             s = jnp.where(pos[0][0] - pos[1][0] >= window, -jnp.inf, s)
+    if interior:
+        return s
     if causal:
         rows = (off_ref[0, 0] + qi * bq
                 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
@@ -204,6 +222,67 @@ def _apply_masks(s, qi, ki, bq, bk, causal, kv_len, mask_ref, off_ref,
     return s
 
 
+def _alibi_bias(slope, qi, ki, bq, bk, off_ref, pos):
+    """ALiBi's addend to a score block, the ONE expression the forward and
+    every backward body build it by (the backward's ``exp2(s − lse)`` is
+    the forward's ``p`` only then). The wrapper pre-folds log2e into the
+    slope, so the bias is in the kernels' log2 logit units.
+
+    With explicit positions (arbitrary layouts) the distance is data:
+    ``slope · (pos_k − pos_q)``, a full ``(bq, bk)`` block. Else it is
+    place, and ``slope · (col − row)`` splits into a column's part and a
+    row's: the addend is the ``(1, bk)`` vector ``slope · (col − r_mid)``
+    (``r_mid``: the block's middle row — the split is about it so that
+    both parts stay small where the weights are, near the diagonal), ONE
+    add an element. The row's part ``slope · (row − r_mid)``, which a
+    block's scores are then too HIGH by, is constant along the softmax's
+    axis and the same in every K block of a row: it changes neither the
+    weights nor the output, only the logsumexp, and
+    :func:`_alibi_row_shift` gives it to the two places that hold one —
+    the forward's finalize takes it out once a Q block, a backward block
+    adds it to its ``(bq, 1)`` logsumexp from a scratch filled once a
+    batch-head. Not the last bits of ``s + slope · float(cols − rows)``:
+    a dominant score now rounds at its magnitude plus ≤
+    ``slope · bq / 2``."""
+    if pos is not None:
+        return slope * (pos[1][0] - pos[0][0]).astype(jnp.float32)
+    origin = (off_ref[0, 1] + ki * bk) - (off_ref[0, 0] + qi * bq + bq // 2)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1) + origin
+    return slope * col.astype(jnp.float32)
+
+
+def _alibi_row_shift(slope, bq):
+    """What ``_alibi_bias`` (by place) leaves a block's scores too high
+    by, a row: ``slope · (row − r_mid)`` as ``(bq, 1)``, the same in every
+    block of a batch-head. (A ``(bq, 1)`` array is one value a vector
+    register row: cheap once a Q block or a batch-head, a tenth of a
+    block's element operation if rebuilt every block.)"""
+    row = jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0) - bq // 2
+    return slope * row.astype(jnp.float32)
+
+
+def _fill_row_shift(shift_s, slope, bq, grid_axes):
+    """A backward kernel's row-shift scratch, written by the batch-head's
+    first program (TPU grids run in order on one core; the slope is the
+    batch-head's)."""
+    first = pl.program_id(1) == 0
+    for axis in range(2, grid_axes):
+        first = first & (pl.program_id(axis) == 0)
+
+    @pl.when(first)
+    def _():
+        shift_s[:] = _alibi_row_shift(slope, bq)
+
+
+def _shift_scratch(flags, bq):
+    """The LAST scratch of a backward kernel whose ALiBi bias goes by
+    place (slopes given, no explicit positions): the row shift."""
+    _, _, has_pos, has_alibi, _ = flags
+    if has_alibi and not has_pos:
+        return [pltpu.VMEM((bq, 1), jnp.float32)]
+    return []
+
+
 def _causal_run(causal, off_ref, qi, ki, bq, bk, window=None):
     """Block-skip predicate: does this (Q block, K block) pair contain any
     un-masked causal entry? With a traced row offset this is a dynamic
@@ -212,15 +291,54 @@ def _causal_run(causal, off_ref, qi, ki, bq, bk, window=None):
     oldest pair is newest-query − oldest-key = block row 0 vs the K
     block's LAST column): compute becomes O(Tq·window), not O(Tq·Tk).
     Row/column global offsets both come from ``off_ref`` (see
-    ``_apply_masks``)."""
+    ``_apply_masks``). Plain comparisons and ``&``: the counter
+    (:func:`flash_block_traces`) evaluates it over whole index arrays."""
     if not causal:
         return True
     rel = off_ref[0, 0] - off_ref[0, 1]
     run = rel + (qi + 1) * bq - 1 >= ki * bk
     if window is not None:
-        run = jnp.logical_and(
-            run, rel + qi * bq - (ki * bk + bk - 1) < window)
+        run = run & (rel + qi * bq - (ki * bk + bk - 1) < window)
     return run
+
+
+def _block_interior(causal, off_ref, qi, ki, bq, bk, kv_len, window=None,
+                    data_masks=False):
+    """A block's KIND, from the scalars ``_causal_run`` reads: *interior*
+    when every (row, column) pair of the block is attendable by place —
+    its smallest row is ≥ its largest column, its largest row less its
+    smallest column is < ``window``, and it holds no padded column. The
+    bodies are entered through one ``pl.when`` branch a kind
+    (:func:`_enter_by_kind`) and the interior branch leaves the causal,
+    window and ``kv_len`` selects out (``_apply_masks``). A dynamic scalar
+    like ``run``, so a traced offset (sequence-sharded callers) works.
+
+    Returns None — one branch, the whole body — where there is no kind to
+    tell: a call whose masks are data (``data_masks``: a dense mask,
+    segment ids, explicit positions), or one with nothing positional at
+    all (not causal, no padded column)."""
+    if data_masks or not (causal or kv_len % bk):
+        return None
+    inside = True
+    if kv_len % bk:
+        inside = (ki + 1) * bk <= kv_len
+    if causal:
+        rel = off_ref[0, 0] - off_ref[0, 1]
+        inside = inside & (rel + qi * bq >= ki * bk + bk - 1)
+        if window is not None:
+            inside = inside & (rel + (qi + 1) * bq - 1 - ki * bk < window)
+    return inside
+
+
+def _enter_by_kind(run, interior, body):
+    """Enter ``body(interior)`` for a block that runs: through two
+    ``pl.when`` branches, one a kind, where the call has kinds, and
+    through one (the whole body) where it has none."""
+    if interior is None:
+        pl.when(run)(functools.partial(body, False))
+    else:
+        pl.when(run & interior)(functools.partial(body, True))
+        pl.when(run & ~interior)(functools.partial(body, False))
 
 
 def _row_has_valid(mask, causal, tq, tk, row_offset=0, window=None):
@@ -405,7 +523,6 @@ def _trap_tables(rel, nqb, nkb, bq, bk):
     the future — negative ``rel``) keep one fully-masked pair so their
     output block is still written (as 0).
     """
-    import numpy as np
     qi = np.arange(nqb)
     ext = np.clip((rel + (qi + 1) * bq + bk - 1) // bk, 1, nkb)
     qtab = np.repeat(qi, ext)
@@ -421,7 +538,6 @@ def _trap_tables_t(rel, nqb, nkb, bq, bk):
     finalize at ``qi == nqb − 1`` (the bottom row block sees every K
     block). K blocks beyond every row keep one fully-masked pair so
     their dk/dv blocks are still written (as 0)."""
-    import numpy as np
     kj = np.arange(nkb)
     qlo = np.clip((kj * bk - rel + bq) // bq - 1, 0, nqb - 1)
     counts = nqb - qlo
@@ -438,7 +554,6 @@ def _trap_chunk_bounds(rel, tq, tk, bq, bk):
     covers — the kernels never see the full grid. Greedy accumulation of
     per-Q-block extents; returns [(row0, row1), ...] (block-aligned,
     one entry = no chunking needed)."""
-    import numpy as np
     nqb = -(-tq // bq)
     nkb = -(-tk // bk)
     ext = np.clip((rel + (np.arange(nqb) + 1) * bq + bk - 1) // bk,
@@ -463,11 +578,19 @@ def _trap_chunk_bounds_t(rel, tq, tk, bq, bk):
     """K-block chunk boundaries for the dk/dv pass (each K chunk's
     transposed pair table fits the cap); chunks emit DISJOINT dk/dv
     slices, so beyond-cap backward chunking needs no partial sums."""
-    import numpy as np
     nqb = -(-tq // bq)
     nkb = -(-tk // bk)
     qlo = np.clip((np.arange(nkb) * bk - rel + bq) // bq - 1, 0, nqb - 1)
     return _greedy_bounds(nqb - qlo, bk, tk)
+
+
+def _static_offsets(causal_offset, kv_offset):
+    """The (row, column) offsets as a pair of ints where both are known at
+    trace time, else None (a traced offset: sequence-sharded callers)."""
+    if all(isinstance(o, (int, np.integer))
+           for o in (causal_offset, kv_offset)):
+        return int(causal_offset), int(kv_offset)
+    return None
 
 
 def _trap_eligible(causal, window, mask, positions, causal_offset,
@@ -479,9 +602,7 @@ def _trap_eligible(causal, window, mask, positions, causal_offset,
     grid; dense masks keep the full grid (their skip tables are indexed
     by absolute blocks); 'bounded' keeps the full grid (its case is the
     forward-only pass)."""
-    import numpy as np
-    static = (isinstance(causal_offset, (int, np.integer))
-              and isinstance(kv_offset, (int, np.integer)))
+    static = _static_offsets(causal_offset, kv_offset) is not None
     return (causal and window is None and mask is None and positions is None
             and static and mode == 'exact'
             and ((not interpret) or _TRAP_ON_INTERPRET))
@@ -717,23 +838,29 @@ def _make_fwd_kernel(causal, bq, bk, kv_len, has_mask, has_seg, has_pos,
         slope = None if alibi_ref is None else alibi_ref[pid_b]
         run = _run_pred(causal, off_ref, qi, ki, bq, bk,
                         pl.program_id(0), seg, pos, runsum_ref, window)
+        interior = _block_interior(causal, off_ref, qi, ki, bq, bk,
+                                   kv_len, window,
+                                   has_mask or has_seg or has_pos)
 
-        @pl.when(run)
-        def _():
+        def body(interior):
             # Keep matmul operands in their native dtype (bf16 in, fp32
             # accumulate) — upcasting to fp32 before the dot halves MXU
             # throughput. The softmax scale and exp's internal log2(e)
             # multiply are BOTH pre-folded into q by the wrapper (the
-            # "exp2 trick"), so the only per-score-element VPU work here
-            # is max / subtract / exp2 / sum / downcast — at small head
-            # dim the kernel is VPU-bound and each removed op is ~15%.
+            # "exp2 trick"), so the softmax's own per-score-element VPU
+            # work is max / subtract / exp2 / sum / downcast. At head dim
+            # 128 the kernel is VPU-bound, and what ``_apply_masks`` adds
+            # an element weighs as much again — which is why it is done
+            # by the block's kind: an interior block (most of a causal
+            # call's) pays none of the position compares, a boundary
+            # block all of them (``_apply_masks`` counts both).
             v = v_ref[0]                                    # (BK, dv)
             s = _score_block(q_ref, k_ref, quant)  # (BQ, BK), log2 units
             mask_live = (None if runsum_ref is None else
                          runsum_ref[pl.program_id(0), qi, ki] == 1)
             s = _apply_masks(s, qi, ki, bq, bk, causal, kv_len,
                              mask_ref, off_ref, seg, pos, mask_live,
-                             window, slope)
+                             window, slope, interior)
 
             m_prev = m_s[:]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -754,6 +881,8 @@ def _make_fwd_kernel(causal, bq, bk, kv_len, has_mask, has_seg, has_pos,
                 p_num.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
+        _enter_by_kind(run, interior, body)
+
         @pl.when(last_k_cond)
         def _():
             l = l_s[:]
@@ -765,8 +894,13 @@ def _make_fwd_kernel(causal, bq, bk, kv_len, has_mask, has_seg, has_pos,
             o_ref[0] = out.astype(o_ref.dtype)
             if save_lse:
                 # Convert from log2 back to natural-log units for the
-                # backward: lse = ln2·(m₂ + log2 l) = m + ln l.
-                lse_ref[0] = _LN2 * (m_s[:] + jnp.log2(safe_l))
+                # backward: lse = ln2·(m₂ + log2 l) = m + ln l — less
+                # the row's part of an ALiBi bias by place, which the
+                # running max carried for the whole row.
+                lse2 = m_s[:] + jnp.log2(safe_l)
+                if slope is not None and pos is None:
+                    lse2 = lse2 - _alibi_row_shift(slope, bq)
+                lse_ref[0] = _LN2 * lse2
 
     return kernel
 
@@ -1130,7 +1264,18 @@ def _flash_fwd_impl(q, k, v, mask, causal_offset, scale, causal, interpret,
         out_shape = [out_shape,
                      jax.ShapeDtypeStruct((nb, tq_p, 1), jnp.float32)]
 
+    def walk(rel):
+        if trap:
+            return _trap_tables(rel, nqb, nkb, bq, bk)[:2]
+        return _grid_walk(nqb, grid[2],
+                          (lambda i: band_fn(i, rel)) if banded else None)
+
+    grid_kind = 'trap' if trap else 'band' if banded else 'full'
+
     def run_exact(*_):
+        name = 'flash_fwd_int8' if quantized else 'flash_fwd'
+        _note_blocks(name, grid_kind, walk, causal, causal_offset,
+                     kv_offset, bq, bk, tk, window, flags)
         kernel = _make_fwd_kernel(causal, bq, bk, tk, *flags, save_lse,
                                   window, band_fn, quantized, dropout,
                                   trap=bool(trap))
@@ -1141,8 +1286,8 @@ def _flash_fwd_impl(q, k, v, mask, causal_offset, scale, causal, interpret,
             o_specs = (_wrap_specs_pairs(o_specs) if save_lse
                        else _wrap_specs_pairs([o_specs])[0])
         return _pallas_call(
-            'flash_fwd_int8' if quantized else 'flash_fwd',
-            kernel, grid, in_specs, o_specs, _scratch(bq, d_v), out_shape,
+            name, kernel, grid, in_specs, o_specs,
+            _scratch(bq, d_v), out_shape,
             interpret, trap_pre if trap else [bandoff, runsum],
         )(off, *seed_args, *args, *aux_args)
 
@@ -1161,6 +1306,9 @@ def _flash_fwd_impl(q, k, v, mask, causal_offset, scale, causal, interpret,
         mvec_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j, *rs: (b, i, 0))
 
         def run_bounded(*_):
+            _note_blocks('flash_fwd_bounded', grid_kind, walk, causal,
+                         causal_offset, kv_offset, bq, bk, tk, window,
+                         flags)
             kernel = _make_fwd_kernel_bounded(
                 causal, bq, bk, tk, *flags, save_lse, window, band_fn)
             return _pallas_call(
@@ -1211,7 +1359,11 @@ def _make_fwd_kernel_bounded(causal, bq, bk, kv_len, has_mask, has_seg,
     whenever ``bound − true_rowmax`` stays within fp32's exponent range
     (the wrapper guarantees this by falling back to the exact kernel when
     the worst-case gap ``2·max(bound)`` exceeds ``_BOUNDED_SAFE_GAP``).
+    The bound does not cover an ALiBi bias: the wrapper sends such calls
+    to the exact kernel.
     """
+    assert not has_alibi, 'the norm bound does not cover the ALiBi bias'
+
     def kernel(*refs):
         if band_fn is not None:
             bandoff_ref, *refs = refs
@@ -1220,8 +1372,8 @@ def _make_fwd_kernel_bounded(causal, bq, bk, kv_len, has_mask, has_seg,
         else:
             runsum_ref = None
         off_ref, q_ref, k_ref, v_ref, m_ref, *rest = refs
-        mask_ref, seg, pos, alibi_ref, rest = _split_aux(
-            rest, has_mask, has_seg, has_pos, has_alibi)
+        mask_ref, seg, pos, _, rest = _split_aux(
+            rest, has_mask, has_seg, has_pos)
         if save_lse:
             o_ref, lse_ref, l_s, acc_s = rest
         else:
@@ -1236,14 +1388,13 @@ def _make_fwd_kernel_bounded(causal, bq, bk, kv_len, has_mask, has_seg,
             l_s[:] = jnp.zeros_like(l_s)
             acc_s[:] = jnp.zeros_like(acc_s)
 
-        pid_b = pl.program_id(0)  # hoisted: program_id inside a
-        # pl.when body is not substituted by the plain interpreter
-        slope = None if alibi_ref is None else alibi_ref[pid_b]
         run = _run_pred(causal, off_ref, qi, ki, bq, bk,
                         pl.program_id(0), seg, pos, runsum_ref, window)
+        interior = _block_interior(causal, off_ref, qi, ki, bq, bk,
+                                   kv_len, window,
+                                   has_mask or has_seg or has_pos)
 
-        @pl.when(run)
-        def _():
+        def body(interior):
             q = q_ref[0]                                    # (BQ, d)
             k = k_ref[0]                                    # (BK, d)
             v = v_ref[0]                                    # (BK, dv)
@@ -1254,12 +1405,14 @@ def _make_fwd_kernel_bounded(causal, bq, bk, kv_len, has_mask, has_seg,
                          runsum_ref[pl.program_id(0), qi, ki] == 1)
             s = _apply_masks(s, qi, ki, bq, bk, causal, kv_len,
                              mask_ref, off_ref, seg, pos, mask_live,
-                             window, slope)
+                             window, None, interior)
             p = jnp.exp2(s - m_ref[0])                      # bound shift
             l_s[:] += p.sum(axis=-1, keepdims=True)
             acc_s[:] += jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+
+        _enter_by_kind(run, interior, body)
 
         @pl.when(kj == last_k)
         def _():
@@ -1306,6 +1459,83 @@ def flash_bwd_traces():
         yield sink
     finally:
         _BWD_SINKS[:] = [s for s in _BWD_SINKS if s is not sink]
+
+
+_BLOCK_SINKS = []       # lists of the active flash_block_traces() blocks
+
+
+@contextlib.contextmanager
+def flash_block_traces():
+    """Collect what each flash kernel's blocks are by KIND while the
+    block runs: one dict ``{'kernel', 'grid', 'run_blocks',
+    'interior_blocks', 'alibi'}`` per TRACED kernel. ``kernel`` is the
+    Pallas name (``flash_fwd``, ``flash_bwd_fused``, …); ``grid`` is
+    ``'trap'`` (the causal pair grid), ``'band'`` (the window's) or
+    ``'full'``; ``run_blocks`` counts, a batch-head, the grid's blocks
+    that ``_causal_run`` lets compute and ``interior_blocks`` those of
+    them that ``_block_interior`` sends through the branch without the
+    causal / window / padding selects (0 for a call whose masks are
+    data: it has no kinds). Both are None where an offset is traced (the
+    kind is then a run-time scalar), ``run_blocks`` also where data
+    decides which blocks run. ``alibi`` says how the bias is built:
+    None, ``'vector'`` (a ``(1, bk)`` vector a block, the row's part
+    carried in the logsumexp's domain: ``_alibi_bias``) or
+    ``'positions'`` (a full block from explicit position vectors)::
+
+        with flash_block_traces() as traces:
+            step.lower(*args)
+        assert traces[0]['interior_blocks'] == 120      # of 136
+    """
+    sink = []
+    _BLOCK_SINKS.append(sink)
+    try:
+        yield sink
+    finally:
+        _BLOCK_SINKS[:] = [s for s in _BLOCK_SINKS if s is not sink]
+
+
+def _note_blocks(kernel, grid, walk, causal, causal_offset, kv_offset, bq,
+                 bk, kv_len, window, flags):
+    """Tell the open :func:`flash_block_traces` blocks about one kernel:
+    ``walk(rel)`` gives the (Q block, K block) index arrays of the grid's
+    programs at the static row − column offset ``rel``, counted by the
+    kernels' own two predicates."""
+    if not _BLOCK_SINKS:
+        return
+    has_mask, has_seg, has_pos, has_alibi, _ = flags
+    data = has_mask or has_seg or has_pos
+    run_blocks = interior_blocks = None
+    static = _static_offsets(causal_offset, kv_offset)
+    if static is not None:
+        with jax.ensure_compile_time_eval():
+            off = np.asarray([static])
+            qi, ki = (np.asarray(x) for x in walk(static[0] - static[1]))
+            run = np.broadcast_to(
+                _causal_run(causal, off, qi, ki, bq, bk, window), qi.shape)
+            inside = _block_interior(causal, off, qi, ki, bq, bk, kv_len,
+                                     window, data)
+        if not data:
+            run_blocks = int(run.sum())
+        interior_blocks = (0 if inside is None
+                           else int((run & inside).sum()))
+    alibi = None
+    if has_alibi:
+        alibi = 'positions' if has_pos else 'vector'
+    for sink in _BLOCK_SINKS:
+        sink.append({'kernel': kernel, 'grid': grid,
+                     'run_blocks': run_blocks,
+                     'interior_blocks': interior_blocks, 'alibi': alibi})
+
+
+def _grid_walk(n_outer, n_inner, inner_lo=None):
+    """Block indices ``(outer, inner)`` of a 3-axis grid's programs, a
+    batch-head: every pair, or with ``inner_lo`` (a band's first inner
+    block by outer block) the band's."""
+    outer = np.repeat(np.arange(n_outer), n_inner)
+    inner = np.tile(np.arange(n_inner), n_outer)
+    if inner_lo is not None:
+        inner = np.asarray(inner_lo(outer)) + inner
+    return outer, inner
 
 
 def _bwd_form(only, tq_p, d, dq_dtype):
@@ -1356,6 +1586,9 @@ def _make_dq_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
             quant = (sqf_ref, skr_ref)
         mask_ref, seg, pos, alibi_ref, rest = _split_aux(
             rest, has_mask, has_seg, has_pos, has_alibi)
+        shift_s = None
+        if has_alibi and not has_pos:
+            *rest, shift_s = rest
         dq_ref, dq_acc = rest
         if trap:
             p = pl.program_id(1)
@@ -1377,11 +1610,15 @@ def _make_dq_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
         pid_b = pl.program_id(0)  # hoisted: program_id inside a
         # pl.when body is not substituted by the plain interpreter
         slope = None if alibi_ref is None else alibi_ref[pid_b]
+        if shift_s is not None:
+            _fill_row_shift(shift_s, slope, bq, 2 if trap else 3)
         run = _run_pred(causal, off_ref, qi, ki, bq, bk,
                         pl.program_id(0), seg, pos, runsum_ref, window)
+        interior = _block_interior(causal, off_ref, qi, ki, bq, bk,
+                                   kv_len, window,
+                                   has_mask or has_seg or has_pos)
 
-        @pl.when(run)
-        def _():
+        def body(interior):
             # q_ref holds q·(scale·log2e) and lse_ref holds lse·log2e (both
             # pre-folded by the wrapper, mirroring the forward's exp2
             # trick) so no per-score-element multiply is needed here:
@@ -1395,8 +1632,11 @@ def _make_dq_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
                          runsum_ref[pl.program_id(0), qi, ki] == 1)
             s = _apply_masks(s, qi, ki, bq, bk, causal, kv_len,
                              mask_ref, off_ref, seg, pos, mask_live,
-                             window, slope)
-            p = jnp.exp2(s - lse_ref[0])                    # (BQ, BK)
+                             window, slope, interior)
+            lse = lse_ref[0]                                # (BQ, 1)
+            if shift_s is not None:
+                lse = lse + shift_s[:]     # the scores' row-shifted domain
+            p = jnp.exp2(s - lse)                           # (BQ, BK)
             dp = jax.lax.dot_general(
                 g, v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (BQ, BK)
@@ -1415,6 +1655,8 @@ def _make_dq_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
             dq_acc[:] += scale * jax.lax.dot_general(
                 ds, k_op, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (BQ, d)
+
+        _enter_by_kind(run, interior, body)
 
         @pl.when(last_k_cond)
         def _():
@@ -1454,6 +1696,9 @@ def _make_dkv_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
             quant = (sqf_ref, skr_ref)
         mask_ref, seg, pos, alibi_ref, rest = _split_aux(
             rest, has_mask, has_seg, has_pos, has_alibi)
+        shift_s = None
+        if has_alibi and not has_pos:
+            *rest, shift_s = rest
         if fused:
             dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = rest
         else:
@@ -1501,11 +1746,15 @@ def _make_dkv_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
         pid_b = pl.program_id(0)  # hoisted: program_id inside a
         # pl.when body is not substituted by the plain interpreter
         slope = None if alibi_ref is None else alibi_ref[pid_b]
+        if shift_s is not None:
+            _fill_row_shift(shift_s, slope, bq, 2 if trap else 3)
         run = _run_pred(causal, off_ref, qi, kj, bq, bk,
                         pl.program_id(0), seg, pos, runsum_ref, window)
+        interior = _block_interior(causal, off_ref, qi, kj, bq, bk,
+                                   kv_len, window,
+                                   has_mask or has_seg or has_pos)
 
-        @pl.when(run)
-        def _():
+        def body(interior):
             # q_ref / lse_ref are pre-folded by ·(scale·log2e) / ·log2e as
             # in the dq kernel. dk wants scale·dsᵀ·q with the ORIGINAL q;
             # the dot below uses the folded q, so divide the accumulator
@@ -1519,8 +1768,11 @@ def _make_dkv_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
                          runsum_ref[pl.program_id(0), qi, kj] == 1)
             s = _apply_masks(s, qi, kj, bq, bk, causal, kv_len,
                              mask_ref, off_ref, seg, pos, mask_live,
-                             window, slope)
-            p = jnp.exp2(s - lse_ref[0])                    # (BQ, BK)
+                             window, slope, interior)
+            lse = lse_ref[0]                                # (BQ, 1)
+            if shift_s is not None:
+                lse = lse + shift_s[:]     # the scores' row-shifted domain
+            p = jnp.exp2(s - lse)                           # (BQ, BK)
             p_num = p
             if dropout is not None:
                 keep, inv = _dropout_keep(seed_ref, pid_b, qi, kj,
@@ -1558,6 +1810,8 @@ def _make_dkv_kernel(scale, causal, bq, bk, kv_len, has_mask, has_seg,
                 dq_acc[qi] += scale * jax.lax.dot_general(
                     ds, k_op, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)     # (BQ, d)
+
+        _enter_by_kind(run, interior, body)
 
         @pl.when(last_q_cond)
         def _():
@@ -1754,6 +2008,7 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
     if dropout is not None:
         seed_specs = [pl.BlockSpec((1, 1), lambda b, i, j, *rs: (0, 0))]
         seed_args = [jnp.asarray(dropout_seed, jnp.int32).reshape(1, 1)]
+    grid_kind = 'trap' if trap else 'band' if banded else 'full'
 
     quant_specs = quant_specs_t = []
     if quantized:
@@ -1797,6 +2052,15 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
             dq_out_spec = _wrap_specs_pairs([dq_out_spec])[0]
         else:
             dq_grid = (nb, nqb, kband if banded else nkb)
+        def walk(rel):
+            if trap:
+                return _trap_tables(rel, nqb, nkb, bq, bk)[:2]
+            return _grid_walk(
+                nqb, dq_grid[2],
+                (lambda i: kband_fn(i, rel)) if banded else None)
+
+        _note_blocks('flash_bwd_dq', grid_kind, walk, causal, causal_offset,
+                     kv_offset, bq, bk, tk, window, flags)
         dq = _pallas_call(
             'flash_bwd_dq',
             _make_dq_kernel(scale, causal, bq, bk, tk, *flags,
@@ -1804,7 +2068,7 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
                             quantized=quantized, dropout=dropout,
                             trap=bool(trap)),
             dq_grid, dq_in_specs, dq_out_spec,
-            [pltpu.VMEM((bq, d), jnp.float32)],
+            [pltpu.VMEM((bq, d), jnp.float32)] + _shift_scratch(flags, bq),
             jax.ShapeDtypeStruct((nb, tq_p, d), grad_dtype or q.dtype),
             interpret, trap_pre if trap else [bandoff, runsum],
         )(off, *seed_args, *args, *aux_args)
@@ -1848,13 +2112,25 @@ def _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g, scale,
                                               transposed=True)
         else:
             dkv_grid = (nb, nkb, qband if banded else nqb)
+        def walk_t(rel):
+            if trap:
+                return _trap_tables_t(rel, nqb, nkb, bq, bk)[:2]
+            kj, qi = _grid_walk(
+                nkb, dkv_grid[2],
+                (lambda j: qband_fn(j, rel)) if banded else None)
+            return qi, kj
+
+        dkv_name = 'flash_bwd_fused' if fused else 'flash_bwd_dkv'
+        _note_blocks(dkv_name, grid_kind, walk_t, causal, causal_offset,
+                     kv_offset, bq, bk, tk, window, flags)
         dk, dv, *dq_fused = _pallas_call(
-            'flash_bwd_fused' if fused else 'flash_bwd_dkv',
+            dkv_name,
             _make_dkv_kernel(scale, causal, bq, bk, tk, *flags,
                              window=window, band_fn=qband_fn,
                              quantized=quantized, dropout=dropout,
                              trap=bool(trap), nqb=nqb, fused=fused),
-            dkv_grid, dkv_in_specs, dkv_out_specs, dkv_scratch,
+            dkv_grid, dkv_in_specs, dkv_out_specs,
+            dkv_scratch + _shift_scratch(flags, bq),
             dkv_out_shape, interpret,
             trap_pre_t if trap else [bandoff, runsum],
             vmem_limit_bytes=vmem_limit,
@@ -1900,11 +2176,19 @@ def _seg_pair(seg_q, seg_k):
     return None if seg_q is None else (seg_q, seg_k)
 
 
+def _offsets(causal_offset, kv_offset, static_off):
+    """The call's (row, column) offsets: the static pair where the caller
+    gave plain ints (``flash_attention``), else the traced operands."""
+    return (causal_offset, kv_offset) if static_off is None else static_off
+
+
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(12, 13, 14, 15, 16, 17, 18))
+                   nondiff_argnums=(12, 13, 14, 15, 16, 17, 18, 19))
 def _flash(q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k, pos_q,
            pos_k, alibi, dropout_seed, scale, causal, interpret, mode,
-           window, qk_quant, dropout_rate):
+           window, qk_quant, dropout_rate, static_off):
+    causal_offset, kv_offset = _offsets(causal_offset, kv_offset,
+                                        static_off)
     return _flash_fwd_impl(q, k, v, mask, causal_offset, scale, causal,
                            interpret, mode,
                            segment_ids=_seg_pair(seg_q, seg_k),
@@ -1916,8 +2200,9 @@ def _flash(q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k, pos_q,
 
 def _flash_fwd(q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k,
                pos_q, pos_k, alibi, dropout_seed, scale, causal, interpret,
-               mode, window, qk_quant, dropout_rate):
-    out, lse = _flash_fwd_impl(q, k, v, mask, causal_offset, scale, causal,
+               mode, window, qk_quant, dropout_rate, static_off):
+    row_off, col_off = _offsets(causal_offset, kv_offset, static_off)
+    out, lse = _flash_fwd_impl(q, k, v, mask, row_off, scale, causal,
                                interpret, mode, save_lse=True,
                                segment_ids=_seg_pair(seg_q, seg_k),
                                positions=_seg_pair(pos_q, pos_k),
@@ -1925,7 +2210,7 @@ def _flash_fwd(q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k,
                                qk_quant=qk_quant,
                                dropout_rate=dropout_rate,
                                dropout_seed=dropout_seed,
-                               kv_offset=kv_offset)
+                               kv_offset=col_off)
     # Identities unless a checkpoint policy names them. The NAMED ``out``
     # is the primal output too, so nothing of the kernel stays live in a
     # rematerialized forward that kept both; ``lse`` is the squeezed
@@ -1939,11 +2224,13 @@ def _flash_fwd(q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k,
 
 
 def _flash_bwd(scale, causal, interpret, mode, window, qk_quant,
-               dropout_rate, res, g):
+               dropout_rate, static_off, res, g):
     # The backward is mode-independent: lse = log Σ exp(s) is invariant to
     # the forward's shift choice, and the bwd kernels recompute p from it.
     (q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k, pos_q, pos_k,
      alibi, dropout_seed, out, lse) = res
+    causal_offset, kv_offset = _offsets(causal_offset, kv_offset,
+                                        static_off)
     dq, dk, dv = _flash_bwd_impl(q, k, v, mask, causal_offset, out, lse, g,
                                  scale, causal, interpret,
                                  segment_ids=_seg_pair(seg_q, seg_k),
@@ -2128,10 +2415,18 @@ def flash_attention(q, k, v, mask=None, *, causal=False, causal_offset=0,
             'scalar) — the kernel holds no hidden RNG state; derive it '
             'from your jax.random key, e.g. '
             'jax.random.randint(key, (), 0, 2**31 - 1)')
+    # Offsets given as plain ints are the call's PLACE, not data: they ride
+    # the custom_vjp as a static argument. As operands they would reach
+    # the forward rule as tracers wherever the call is staged (a scanned
+    # or rematerialized layer), and the forward would lose the trapezoid
+    # grid, which needs its pair count at trace time (``_trap_eligible``).
+    static_off = _static_offsets(causal_offset, kv_offset)
+    if static_off is not None:
+        causal_offset = kv_offset = None
     return _flash(q, k, v, mask, causal_offset, kv_offset, seg_q, seg_k,
                   pos_q, pos_k, alibi_slopes, dropout_seed, float(scale),
                   bool(causal), bool(interpret), softmax_mode, window,
-                  qk_quant, dropout_rate)
+                  qk_quant, dropout_rate, static_off)
 
 
 def graphlint_entrypoints():

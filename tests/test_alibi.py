@@ -130,3 +130,57 @@ def test_alibi_requires_positions_or_causal():
     q, k, v = _qkv(16)
     with pytest.raises(ValueError, match='alibi'):
         flash_attention(q, k, v, alibi_slopes=_slopes())
+
+
+def _iota_alibi_bias(slope, qi, ki, bq, bk, off_ref, pos):
+    """The whole bias an element, as every block rebuilt it before the
+    vector form: rows and columns from iotas, an int32 difference, a
+    convert, a multiply — and no row constant anywhere."""
+    rows = (off_ref[0, 0] + qi * bq
+            + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+    cols = (off_ref[0, 1] + ki * bk
+            + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    return slope * (cols - rows).astype(jnp.float32)
+
+
+@pytest.mark.parametrize('t,offset,window,blocks', [
+    (64, 0, None, (16, 16)), (100, 0, None, (16, 32)),
+    (64, 128, None, (32, 16)), (64, 0, 13, (16, 16)),
+    (100, 37, 24, (8, 32))])
+def test_alibi_vector_form_is_the_iota_form(monkeypatch, t, offset, window,
+                                            blocks):
+    """The bias as a ``(1, bk)`` vector a block with the row's constant
+    carried in the logsumexp's domain (``_alibi_bias``,
+    ``_alibi_row_shift``) against ``slope · float(cols − rows)`` rebuilt
+    from iotas an element, to this file's tolerance: output and all three
+    gradients, several blocks a side (forward and backward blocks of
+    different shapes), row offsets, windows, ragged lengths — and the
+    dense oracle beside."""
+    import distributed_dot_product_tpu.ops.pallas_attention as pa
+    monkeypatch.setattr(pa, '_block_sizes', lambda *a, **k: blocks)
+    monkeypatch.setattr(pa, '_bwd_block_sizes', lambda *a, **k: blocks[::-1])
+    q, k, v = _qkv(t, key=6)
+    kf = jnp.concatenate([k] * (1 + -(-offset // t)), axis=-2)
+    vf = jnp.concatenate([v] * (1 + -(-offset // t)), axis=-2)
+    sl = _slopes()
+
+    def run():
+        def f(q, k, v):
+            return flash_attention(q, k, v, causal=True,
+                                   causal_offset=offset, window=window,
+                                   alibi_slopes=sl)
+        out, vjp = jax.vjp(f, q, kf, vf)
+        return (out, *vjp(jnp.ones_like(out)))
+
+    got = run()
+    monkeypatch.setattr(pa, '_alibi_bias', _iota_alibi_bias)
+    monkeypatch.setattr(pa, '_alibi_row_shift',
+                            lambda slope, bq: jnp.zeros((bq, 1)))
+    want = run()
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+    ref = _oracle(q, kf, vf, sl, kf.shape[-2], offset=offset,
+                  window=window)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)

@@ -442,3 +442,287 @@ def test_backward_pass_asked_alone_is_split(monkeypatch, only):
     got = [x is not None for x in grads]
     assert got == ([True, False, False] if only == 'dq'
                    else [False, True, True])
+
+
+# -- a block's position arithmetic by the block's kind -------------------
+# A block whose every (row, column) pair is attendable by place is
+# *interior* (``_block_interior``) and goes through a branch of the body
+# without the causal / window / padding selects; a select that selects
+# nothing is the identity, so every result is the whole-mask form's bit
+# for bit. ALiBi's bias is a ``(1, bk)`` vector a block with the row's
+# constant carried in the logsumexp's domain (``_alibi_bias``): the
+# results of the form rebuilt from iotas to this file's tolerance.
+
+def _brute_interior(causal, off_r, off_c, qi, ki, bq, bk, kv_len, window):
+    rows = off_r + qi * bq + np.arange(bq)[:, None]
+    cols_local = ki * bk + np.arange(bk)[None, :]
+    cols = off_c + cols_local
+    ok = np.broadcast_to(cols_local < kv_len, (bq, bk))
+    if causal:
+        ok = ok & (rows >= cols)
+        if window is not None:
+            ok = ok & (rows - cols < window)
+    return bool(ok.all()), bool(ok.any())
+
+
+@pytest.mark.parametrize('window', [None, 1, 7, 16, 24, 40])
+@pytest.mark.parametrize('offsets', [(0, 0), (32, 0), (5, 0), (0, 24),
+                                     (0, 40), (-19, 3)])
+@pytest.mark.parametrize('blocks', [(16, 16), (8, 32), (32, 8)])
+def test_interior_predicate_is_the_brute_force_all(blocks, offsets, window):
+    """``_block_interior`` (and ``_causal_run`` beside it) against
+    ``all()`` / ``any()`` over the block's (row, column) pairs: row and
+    column offsets (negative ``rel`` too), ``bq != bk``, windows that are
+    and are not multiples of the block, a ragged ``kv_len``."""
+    bq, bk = blocks
+    off = np.asarray([offsets])
+    for kv_len in (64, 53):
+        nqb, nkb = 64 // bq, -(-kv_len // bk)
+        for qi in range(nqb):
+            for ki in range(nkb):
+                every, some = _brute_interior(True, *offsets, qi, ki, bq,
+                                              bk, kv_len, window)
+                inside = pa._block_interior(True, off, qi, ki, bq, bk,
+                                            kv_len, window)
+                run = pa._causal_run(True, off, qi, ki, bq, bk, window)
+                assert bool(inside) == every, (qi, ki, kv_len)
+                # run may keep a block the padding alone empties; it
+                # never skips one with an attendable pair
+                assert bool(run) or not some, (qi, ki, kv_len)
+                assert bool(run) or not bool(inside)
+    # not causal: only the padding has a place
+    for ki in range(4):
+        inside = pa._block_interior(False, off, 0, ki, bq, 16, 53, None)
+        assert bool(inside) == ((ki + 1) * 16 <= 53)
+
+
+def test_calls_without_a_kind_keep_one_branch():
+    """Data masks (dense mask, segment ids, positions) and calls with
+    nothing positional have no kinds: one branch, the whole body."""
+    off = np.asarray([[0, 0]])
+    assert pa._block_interior(True, off, 1, 0, 16, 16, 64, None,
+                              data_masks=True) is None
+    assert pa._block_interior(False, off, 1, 0, 16, 16, 64, None) is None
+    assert pa._block_interior(False, off, 1, 0, 16, 16, 53, None) is not None
+
+
+def _by_kind(monkeypatch, whole, *, blocks=(16, 32), bwd_blocks=(32, 16),
+             budget=1 << 20, hooks=(), tq=FT, tk=FT, hkv=None,
+             iota_alibi=False, **kw):
+    """(out, lse, dq, dk, dv) with the bodies entered by kind, or
+    (``whole``) with every block through the whole-mask branch."""
+    monkeypatch.setattr(pa, '_block_sizes', lambda *a, **k: blocks)
+    monkeypatch.setattr(pa, '_bwd_block_sizes', lambda *a, **k: bwd_blocks)
+    monkeypatch.setattr(pa, '_FUSED_DQ_BYTES', budget)
+    for hook in hooks:
+        monkeypatch.setattr(pa, hook, True)
+    if whole:
+        monkeypatch.setattr(pa, '_block_interior', lambda *a, **k: None)
+    if iota_alibi:
+        monkeypatch.setattr(pa, '_alibi_bias', _iota_alibi_bias)
+        monkeypatch.setattr(pa, '_alibi_row_shift',
+                            lambda slope, bq: jnp.zeros((bq, 1)))
+    q, k, v, g = _fused_inputs(tq, tk, hkv)
+    scale = 1.0 / np.sqrt(FD)
+    causal = kw.pop('causal', True)
+    off = kw.pop('causal_offset', 0)
+    out, lse = pa._flash_fwd_impl(q, k, v, None, off, scale, causal, True,
+                                  save_lse=True, **kw)
+    grads = pa._flash_bwd_impl(q, k, v, None, off, out, lse, g, scale,
+                               causal, True, **kw)
+    return (out, lse, *grads)
+
+
+def _iota_alibi_bias(slope, qi, ki, bq, bk, off_ref, pos):
+    """The whole bias an element, as every block rebuilt it before the
+    vector form: rows and columns from iotas, an int32 difference, a
+    convert, a multiply — and no row constant anywhere."""
+    rows = (off_ref[0, 0] + qi * bq
+            + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+    cols = (off_ref[0, 1] + ki * bk
+            + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    return slope * (cols - rows).astype(jnp.float32)
+
+
+_SLOPES = jnp.asarray([0.5, 0.0625])
+KIND_CASES = {
+    'causal_full': dict(),
+    'causal_full_split_bwd': dict(budget=0),
+    'causal_trap': dict(hooks=['_TRAP_ON_INTERPRET']),
+    'causal_trap_split_bwd': dict(hooks=['_TRAP_ON_INTERPRET'], budget=0),
+    'causal_square_blocks': dict(blocks=(16, 16), bwd_blocks=(16, 16),
+                                 hooks=['_TRAP_ON_INTERPRET']),
+    'window_full': dict(window=24),
+    'window_full_split_bwd': dict(window=24, budget=0),
+    'window_band': dict(window=24, hooks=['_BAND_ON_INTERPRET']),
+    'window_band_split_bwd': dict(window=24, budget=0,
+                                  hooks=['_BAND_ON_INTERPRET']),
+    'window_not_a_block_multiple': dict(window=21,
+                                        hooks=['_BAND_ON_INTERPRET']),
+    'window_one': dict(window=1),
+    'gqa_window_band': dict(window=24, hkv=1,
+                            hooks=['_BAND_ON_INTERPRET']),
+    'row_offset_trap': dict(causal_offset=32, tk=96,
+                            hooks=['_TRAP_ON_INTERPRET']),
+    'kv_offset_full': dict(kv_offset=16),
+    'ragged_causal': dict(tq=72, tk=72),
+    'ragged_window_band': dict(tq=72, tk=72, window=24,
+                               hooks=['_BAND_ON_INTERPRET']),
+    'ragged_not_causal': dict(causal=False, tq=56, tk=72),
+    'alibi_trap': dict(alibi=_SLOPES, hooks=['_TRAP_ON_INTERPRET']),
+    'alibi_window_band': dict(alibi=_SLOPES, window=24,
+                              hooks=['_BAND_ON_INTERPRET']),
+    'dropout_trap': dict(dropout_rate=0.25, dropout_seed=3,
+                         hooks=['_TRAP_ON_INTERPRET']),
+    'int8_full': dict(qk_quant='int8'),
+}
+
+
+@pytest.mark.parametrize('case', sorted(KIND_CASES))
+def test_by_kind_is_the_whole_mask_form_bit_for_bit(monkeypatch, case):
+    """Output, logsumexp and all three gradients, on the full, banded and
+    trapezoid grids, fused and split backward. (int8 scores: to the last
+    bit but one — with no select behind the two dequantization multiplies
+    XLA's CPU backend, which runs the interpreter, contracts the second
+    into the subtraction after it.)"""
+    got = _by_kind(monkeypatch, False, **KIND_CASES[case])
+    want = _by_kind(monkeypatch, True, **KIND_CASES[case])
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        if case.startswith('int8'):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize('case', ['alibi_trap', 'alibi_window_band',
+                                  'alibi_full', 'alibi_row_offset',
+                                  'alibi_split_bwd', 'alibi_ragged'])
+def test_alibi_vector_form_is_the_iota_form(monkeypatch, case):
+    """A ``(1, bk)`` vector a block, the row's constant taken out of the
+    logsumexp at the forward's end and put back by every backward block,
+    gives what ``s + slope · float(cols − rows)`` rebuilt from iotas an
+    element gives: output, logsumexp (the TRUE one: the row's constant
+    must not show in it), dq, dk, dv, to this file's tolerance."""
+    kw = dict(KIND_CASES.get(case, {}), alibi=_SLOPES)
+    kw.update({'alibi_row_offset': dict(causal_offset=32, tk=96),
+               'alibi_split_bwd': dict(budget=0),
+               'alibi_ragged': dict(tq=72, tk=72)}.get(case, {}))
+    got = _by_kind(monkeypatch, False, **kw)
+    want = _by_kind(monkeypatch, True, iota_alibi=True, **kw)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize('window', [None, 24])
+def test_by_kind_under_a_traced_offset_in_shard_map(monkeypatch, devices,
+                                                    window):
+    """Sequence-sharded callers: each shard's row offset is
+    ``axis_index · T/N``, a traced scalar, so the kind is decided at run
+    time like ``run``. Same bits as the whole-mask form, forward and
+    backward."""
+    monkeypatch.setattr(pa, '_block_sizes', lambda *a, **k: (16, 16))
+    monkeypatch.setattr(pa, '_bwd_block_sizes', lambda *a, **k: (16, 16))
+    mesh = seq_mesh(2)
+    q, k, v, g = _fused_inputs()
+
+    def local(q, k, v, g):
+        off = jax.lax.axis_index('seq') * q.shape[-2]
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, causal_offset=off, window=window,
+                alibi_slopes=_SLOPES), q, k, v)
+        dq, dk, dv = vjp(g)
+        return out, dq, jax.lax.psum(dk, 'seq'), jax.lax.psum(dv, 'seq')
+
+    rows, rep = P(None, None, 'seq', None), P()
+
+    def run():
+        with pa.flash_block_traces() as traces:
+            res = jax.shard_map(local, mesh=mesh,
+                                in_specs=(rows, rep, rep, rows),
+                                out_specs=(rows, rows, rep, rep),
+                                check_vma=False)(q, k, v, g)
+        return traces, res
+
+    traces, got = run()
+    assert traces and all(t['run_blocks'] is None
+                          and t['interior_blocks'] is None
+                          and t['alibi'] == 'vector' for t in traces)
+    monkeypatch.setattr(pa, '_block_interior', lambda *a, **k: None)
+    _, want = run()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _cell_call(heads, kv_heads, **kw):
+    """Trace (nothing runs) the forward and backward of one layer of a
+    training cell at T 16384, d 128, as the chip would take it."""
+    q = jax.ShapeDtypeStruct((1, heads, 16384, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, kv_heads, 16384, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False, **kw),
+                       dtype=jnp.float32)
+
+    with pa.flash_block_traces() as traces:
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
+    return traces
+
+
+@pytest.mark.parametrize('cell', ['mpt-7b.train-16k',
+                                  'starcoder2-3b.train-16k'])
+def test_block_counter_at_the_training_cells_shapes(cell):
+    """``flash_block_traces()``: 120 of MPT's 136 run blocks a head are
+    interior (trapezoid grid, ALiBi in the vector form), 42 of StarCoder2's 70
+    (banded grid), forward and fused backward alike."""
+    if cell.startswith('mpt'):
+        traces = _cell_call(32, 32, alibi_slopes=jnp.ones((32,)))
+        want = dict(grid='trap', run_blocks=136, interior_blocks=120,
+                    alibi='vector')
+    else:
+        traces = _cell_call(24, 2, window=4096)
+        want = dict(grid='band', run_blocks=70, interior_blocks=42,
+                    alibi=None)
+    assert traces == [dict(want, kernel='flash_fwd'),
+                      dict(want, kernel='flash_bwd_fused')]
+
+
+@pytest.mark.parametrize('case', ['segments', 'positions', 'dense_mask',
+                                  'not_causal', 'ragged_not_causal',
+                                  'split_backward'])
+def test_block_counter_says_what_has_no_kind(monkeypatch, case):
+    """Calls whose masks are data count no interior block (their body is
+    whole) and no run blocks (data decides); a call with nothing
+    positional runs every block through one branch."""
+    kw = dict({'segments': dict(causal=True, segment_ids=_SEG),
+               'positions': dict(positions=_POS, alibi_slopes=_SLOPES),
+               'dense_mask': dict(causal=True, mask=True),
+               'not_causal': dict(),
+               'ragged_not_causal': dict(tq=56, tk=72),
+               'split_backward': dict(causal=True)}[case])
+    if kw.pop('mask', False):
+        kw['mask'] = jnp.zeros((FB, FH, FT, FT), bool)
+    monkeypatch.setattr(pa, '_block_sizes', lambda *a, **k: (16, 16))
+    with pa.flash_block_traces() as traces:
+        _backward(monkeypatch, 0 if case == 'split_backward' else 1 << 20,
+                  **kw)
+    kernels = [t['kernel'] for t in traces]
+    if case == 'split_backward':
+        assert kernels == ['flash_fwd', 'flash_bwd_dq', 'flash_bwd_dkv']
+        assert {(t['run_blocks'], t['interior_blocks'])
+                for t in traces} == {(10, 6)}
+        return
+    assert kernels == ['flash_fwd', 'flash_bwd_fused']
+    for t in traces:
+        assert t['grid'] == 'full'
+        if case in ('segments', 'positions', 'dense_mask'):
+            assert (t['run_blocks'], t['interior_blocks']) == (None, 0)
+        elif case == 'not_causal':
+            assert (t['run_blocks'], t['interior_blocks']) == (16, 0)
+        else:       # the ragged last K block alone is a boundary block
+            assert (t['run_blocks'], t['interior_blocks']) == (20, 16)
+        assert t['alibi'] == ('positions' if case == 'positions' else None)

@@ -310,11 +310,11 @@ PARENT_LOWERED = {
     'experts':
         '3ee5a11d5306ab89107c9c4389ffa206c0cc05e51e0b049f90807a8f6335e10e',
     'xing4.prefill':
-        '204781b5075ab113fc6ea2b02aa84682b0184192c978f57d8ddfe35dca48ddc6',
+        '7ceed99790438b2773ebb293ced0f8fb69f9dc7065723562c6857b1edefbe841',
     'xing4.decode':
         'f8d9c82820af2fa2867304ecfb05746326f33faf5315bc2583c061c00ffb6188',
     'command-a.prefill':
-        '1a4285266c22a901f84f5fec0fc173aaa7d9727a8990f466b3f62d09caec1ea1',
+        'fbdb22fb6692f9668153737612b7941d38b78890936b992fea05e6f31ccf4db9',
     'command-a.decode':
         '77b4a278f438d0348bb3fb405253ebd737bfb712dc16375d112330032ac8f4cc',
 }
@@ -326,11 +326,11 @@ PARENT_LOWERED = {
 # no operation.
 PARENT_LOWERED.update({
     'nemotron.prefill':
-        '031b8d9a7220df2eca934437bd9632f2c3d012f8e640cb664046c55d034c503d',
+        '641a893b06658614a73289cbbe8537c00018ba45134cdc2235e9f95f658b7c0e',
     'nemotron.decode':
         '29cca6473706468a888f6b950ff60344f8c9b8eb03221748e1b07596747eeee8',
     'mpt.prefill':
-        '815742e48f5b8dc41bd3067f861f7b5b70d5090b1b6d10d5bb0024cb405e5594',
+        '10e556381e01ad86b88bfba14996ee41e20c32e1f01456153b48e99785384ffc',
     'mpt.decode':
         '225cd013e0f58c88df7ca82bb05f6ccbb244788f6d84d910e166e89f6ed64b33',
 })
@@ -342,19 +342,30 @@ PARENT_LOWERED.update({
 # text (``RENUMBERED`` below).
 PARENT_LOWERED.update({
     'mpt.train':
-        '34cf8c89a3ac811db9c762e508d0e96a1ee00f553c4f7c7c5448a1bf783233b2',
+        '46ba319f6662428fd948107bb9e6f30e991f832e233aaa0819cc71790e9af6c1',
     'starcoder2.train':
-        'a1eb34db815d5a9726e93baf7d193b53247c2d1b61f95ebdd5ea1ff854ea5378',
+        '141ef923cfceebdff205047b3e7c94bcfdb121b72cfd08b5829cc2f2c2433129',
 })
 # The commit before the ``solar_open2`` fields (a third recurrent mixer
 # kind, the attention module's output gate): the two-branch recurrent +
 # expert cell's tiny preset, the seventh accepted cell.
 PARENT_LOWERED.update({
     'granite.prefill':
-        '8833b977eb7033acc359fcd84a12639fcdb26dbb3dfa1663156ea858832883cd',
+        '9e4fed301e5aaaf94dd54ceeb359fdd6754bc80911c060a908f66b0ac8a2cb39',
     'granite.decode':
         '18dfff1be57a8be18ecef2dc1ae688f97e0613f46b9511ab82944e699a0b6f6b',
 })
+# PR 40 changed the flash kernels' bodies (a block's position arithmetic
+# goes by the block's kind, ALiBi's bias is a vector a block, offsets
+# given as ints are static through the custom_vjp), so every program
+# above that holds a flash kernel — the five prefills and the two
+# training steps — is by necessity another text and is pinned to that
+# commit's. Before it they read: command-a.prefill '1a428526…',
+# granite.prefill '8833b977…', mpt.prefill '815742e4…', mpt.train
+# '34cf8c89…', nemotron.prefill '031b8d9a…', starcoder2.train 'a1eb34db…',
+# xing4.prefill '204781b5…'.
+# Every ``*.decode`` entry and ``experts`` are the values they were: no
+# decode step moved.
 PRESETS = {'granite': ('tiny_granite', 'tiny-granite.decode'),
            'xing4': ('tiny_latent', 'tiny-xing4.decode'),
            'command-a': ('tiny_mixed', 'tiny-command-a.decode'),
@@ -364,12 +375,12 @@ PRESETS = {'granite': ('tiny_granite', 'tiny-granite.decode'),
 
 
 # Since PR 37 a gated MLP names its pre-activation for a checkpoint. A
-# ``name`` equation lowers to nothing, so every serving program above is
-# still the parent's text — in xing4's two, whose dense layer and shared
+# ``name`` equation lowers to nothing, so every decode step above is
+# still its parent's text — in xing4's, whose dense layer and shared
 # experts are gated MLPs, but for the NUMBER jax's lowering gives one
-# private function (its symbols are numbered as they are asked for).
-RENUMBERED = {'xing4.prefill': ('@silu_355', '@silu_354'),
-              'xing4.decode': ('@silu_293', '@silu_292')}
+# private function (its symbols are numbered as they are asked for; its
+# prefill, re-pinned at PR 40, is pinned as it lowers).
+RENUMBERED = {'xing4.decode': ('@silu_293', '@silu_292')}
 
 
 def _sha(lowered, renumbered=None):

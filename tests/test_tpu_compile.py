@@ -140,6 +140,47 @@ def test_flash_backward_form_compiles_for_v5e(chip, shape):
         assert 'flash_bwd_fused' not in hlo
 
 
+# What ``flash_block_traces()`` must say of a head of each training cell.
+_CELL_BLOCKS = {
+    'mpt-7b.train-16k': dict(grid='trap', run_blocks=136,
+                             interior_blocks=120, alibi='vector'),
+    'starcoder2-3b.train-16k': dict(grid='band', run_blocks=70,
+                                    interior_blocks=42, alibi=None),
+}
+
+
+@pytest.mark.parametrize('what', ['forward', 'grad'])
+@pytest.mark.parametrize('cell', sorted(_CELL_BLOCKS))
+def test_flash_kernels_by_kind_compile_for_v5e(chip, cell, what):
+    """Both training cells' forward (alone, and with its logsumexp) and
+    fused backward with the body entered by the block's kind — two
+    branches of the body in one kernel, for MPT with the ALiBi bias as a
+    vector a block: the forward under the compiler's default VMEM limit,
+    the backward under the limit ``_bwd_form`` states. The counter gives
+    the kinds a head."""
+    from distributed_dot_product_tpu.ops.pallas_attention import (
+        flash_block_traces,
+    )
+    h, h_kv, t, kw, _ = _BWD_SHAPES[cell]
+    kw = dict(kw)
+    if kw.pop('alibi', False):
+        kw['alibi_slopes'] = jnp.asarray(_mpt_slopes(h), jnp.float32)
+    q = jax.ShapeDtypeStruct((1, h, t, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, h_kv, t, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False, **kw),
+                       dtype=jnp.float32)
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if what == 'grad' else loss
+    with flash_block_traces() as traces:
+        hlo = _compile(chip, fn, q, kv, kv).as_text()
+    kernels = ['flash_fwd'] + ['flash_bwd_fused'] * (what == 'grad')
+    assert traces == [dict(_CELL_BLOCKS[cell], kernel=k) for k in kernels]
+    assert all(f'{k}/pallas_call' in hlo for k in kernels)
+
+
 def test_flash_backward_float32_grads_compile_at_the_budget(chip):
     """The ring fold's call (``grad_dtype=float32``: the output block is
     as wide as the accumulator) at the budget's edge: 64 MiB stated, half
@@ -1054,7 +1095,7 @@ def test_training_cells_keep_what_fits_a_v5e(chip, monkeypatch, config,
     from benchmarks import system
     from benchmarks.drivers.train import make_optimizer
     from distributed_dot_product_tpu.ops.pallas_attention import (
-        FLASH_RESIDUAL_NAMES,
+        FLASH_RESIDUAL_NAMES, flash_block_traces,
     )
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -1065,7 +1106,19 @@ def test_training_cells_keep_what_fits_a_v5e(chip, monkeypatch, config,
                            'train-16k.json')) as f:
         optimizer = make_optimizer(json.load(f)['optimizer'])
     model = system.build_lm(cfg)
-    compiled, record = _train_step_for_v5e(chip, model, optimizer)
+    with flash_block_traces() as blocks:
+        compiled, record = _train_step_for_v5e(chip, model, optimizer)
+    # Inside the scanned, rematted layer too the kernels see the call's
+    # offsets as the plain ints they are: MPT's forward takes the
+    # trapezoid grid like its backward (as custom_vjp operands the ints
+    # reached the forward rule as tracers and the forward ran the full
+    # grid, 120 skipped programs a head), and the kinds are counted.
+    # (``model.init``'s 128-token call, traced in there too, is one block.)
+    blocks = [b for b in blocks if b['run_blocks'] != 1]
+    assert all({k: b[k] for k in _CELL_BLOCKS[f'{config}.train-16k']}
+               == _CELL_BLOCKS[f'{config}.train-16k'] for b in blocks)
+    assert {b['kernel'] for b in blocks} == {'flash_fwd',
+                                             'flash_bwd_fused'}
     assert record['kept'] == (*FLASH_RESIDUAL_NAMES, *kept)
     assert record['first_refused'] == refused
     t, layers, hidden = 16384, model.n_layers, model.mlp_ratio * model.dim

@@ -168,3 +168,29 @@ def test_chunk_bounds_cover_rows_exactly():
             assert a1 == b0 and a0 < a1
     finally:
         m._TRAP_MAX_PAIRS = orig
+
+
+@pytest.mark.parametrize('case', sorted(CASES))
+def test_trapezoid_by_kind_is_the_whole_mask_form(monkeypatch, case):
+    """Several blocks a side on the pair grid: blocks under the diagonal
+    go through the branch without the causal select
+    (``_block_interior``), and output and gradients are bit for bit
+    those of every block through the whole-mask branch. (Segment ids are
+    data: that case has one branch either way.)"""
+    monkeypatch.setattr(pa, '_block_sizes', lambda *a, **k: (16, 16))
+    monkeypatch.setattr(pa, '_bwd_block_sizes', lambda *a, **k: (16, 16))
+    with pa.flash_block_traces() as traces:
+        a = _run(True, monkeypatch, **CASES[case])
+    assert {t['grid'] for t in traces} == {'trap'}
+    off = CASES[case].get('off', 0)
+    blocks = [(i, j) for i in range(4) for j in range(4)]
+    want = (sum(off + 16 * i + 15 >= 16 * j for i, j in blocks),
+            sum(off + 16 * i >= 16 * j + 15 for i, j in blocks))
+    if case == 'segments':      # data decides what runs; no kinds
+        want = (None, 0)
+    assert {(t['run_blocks'], t['interior_blocks'])
+            for t in traces} == {want}
+    monkeypatch.setattr(pa, '_block_interior', lambda *a, **k: None)
+    b = _run(True, monkeypatch, **CASES[case])
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))

@@ -277,3 +277,42 @@ def test_module_window_requires_causal():
     with pytest.raises(ValueError, match='causal'):
         DistributedDotProductAttn(key_dim=DIM, window=4).init(
             jax.random.key(0), *([jnp.zeros((1, 8, DIM))] * 3), None)
+
+
+@pytest.mark.parametrize('band', [False, True], ids=['full', 'band'])
+@pytest.mark.parametrize('t,window,off', [(64, 16, 0), (64, 40, 0),
+                                          (48, 5, 32), (64, 21, 0),
+                                          (100, 40, 0), (64, 48, 16)])
+def test_window_by_kind_is_the_whole_mask_form(monkeypatch, band, t, window,
+                                               off):
+    """Blocks inside the band (under the diagonal, above the window's
+    edge) take the branch without the causal and window selects; output
+    and gradients are bit for bit the whole-mask form's on the full and
+    the banded grid, for windows that are and are not block multiples, a
+    row offset and a ragged length."""
+    import distributed_dot_product_tpu.ops.pallas_attention as pa
+    monkeypatch.setattr(pa, '_block_sizes', lambda *a, **k: (16, 16))
+    monkeypatch.setattr(pa, '_bwd_block_sizes', lambda *a, **k: (16, 16))
+    monkeypatch.setattr(pa, '_BAND_ON_INTERPRET', band)
+    q, k, v = _qkv(t, key=11)
+    kf = jnp.concatenate([k, k], axis=-2)
+    vf = jnp.concatenate([v, v], axis=-2)
+
+    def run():
+        def f(q, k, v):
+            return flash_attention(q, k, v, causal=True, causal_offset=off,
+                                   window=window)
+        out, vjp = jax.vjp(f, q, kf, vf)
+        return (out, *vjp(jnp.ones_like(out)))
+
+    with pa.flash_block_traces() as traces:
+        got = run()
+    assert {tr['grid'] for tr in traces} == {'band' if band else 'full'}
+    assert all(0 <= tr['interior_blocks'] < tr['run_blocks']
+               for tr in traces)
+    # interior: 16 (qi − ki) + 15 < window for some qi > ki
+    assert any(tr['interior_blocks'] for tr in traces) == (window > 31)
+    monkeypatch.setattr(pa, '_block_interior', lambda *a, **k: None)
+    want = run()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
