@@ -250,6 +250,7 @@ def phase_train(progs, cfg, seed, state):
     from distributed_dot_product_tpu import (
         TrainLoopConfig, TrainState, lm_targets, run_training,
     )
+    from distributed_dot_product_tpu.models.lm import head_loss_traces
     from distributed_dot_product_tpu.ops.pallas_attention import (
         flash_block_traces, flash_bwd_traces,
     )
@@ -268,9 +269,11 @@ def phase_train(progs, cfg, seed, state):
                               guard=True)
     # Which form the flash backward takes at this shape (the training
     # cells' form: one fused kernel, dq resident in VMEM), and how many
-    # of each kernel's run blocks are interior (no position compares).
+    # of each kernel's run blocks are interior (no position compares);
+    # which route the head's gradient takes (the kernel, at real widths).
     with flash_bwd_traces() as bwd_traces, \
-            flash_block_traces() as block_traces:
+            flash_block_traces() as block_traces, \
+            head_loss_traces() as head_traces:
         progs.compile('train_step', step, params, opt_state, batch,
                       pallas=True)
     result = run_training(
@@ -311,6 +314,7 @@ def phase_train(progs, cfg, seed, state):
         'T': cfg['train_t'], 'losses': losses,
         'bad_steps': result.bad_steps,
         'flash_bwd': bwd_traces, 'flash_blocks': block_traces,
+        'head_loss': head_traces,
         'ref_T': cfg['ref_t'], 'loss_flash': float(loss_f),
         'loss_plain': float(loss_p), 'logits_max_abs_err': logit_err,
         'logits_max_abs': logit_scale,

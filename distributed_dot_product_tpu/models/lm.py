@@ -30,6 +30,7 @@ TPU-first notes:
   so sampling loops (greedy here; any sampler outside) stay trivial.
 """
 
+import contextlib
 import dataclasses
 import functools
 import warnings
@@ -44,9 +45,11 @@ from distributed_dot_product_tpu.models.transformer import (
     TransformerStack, make_norm,
 )
 from distributed_dot_product_tpu.obs.spans import device_scope
+from distributed_dot_product_tpu.ops.pallas_head import head_grad, head_tiles
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 
-__all__ = ['TransformerLM', 'greedy_generate', 'lm_targets']
+__all__ = ['TransformerLM', 'greedy_generate', 'head_loss_traces',
+           'lm_targets']
 
 
 def lm_targets(tokens, segment_ids=None, pad_id=None):
@@ -290,16 +293,59 @@ class TransformerLM(nn.Module):
         return caches, self._head(x)
 
 
+_HEAD_SINKS = []        # lists of the open head_loss_traces() blocks
+
+
+@contextlib.contextmanager
+def head_loss_traces():
+    """Collect which route each :func:`head_loss` takes while the block
+    runs: one dict per TRACE of its scan — ``route`` (``'kernel'``: a
+    chunk's ``dx`` and ``dW`` are ONE Pallas program,
+    ``ops.pallas_head.head_grad``; ``'xla'``: the two einsums), ``rows``
+    (a chunk's rows, batch × chunk), ``row_group`` / ``vocab_tile`` /
+    ``row_tile`` (the kernel's tiles, None on the XLA route) and ``why``
+    (the reason for the XLA route, None on the kernel's)::
+
+        with head_loss_traces() as traces:
+            step.lower(*args).compile()
+        assert [t['route'] for t in traces] == ['kernel']
+    """
+    sink = []
+    _HEAD_SINKS.append(sink)
+    try:
+        yield sink
+    finally:
+        _HEAD_SINKS[:] = [s for s in _HEAD_SINKS if s is not sink]
+
+
+def _head_route(rows, dim, vocab, dtype, with_grad):
+    """The kernel's tiles for one chunk, or None: the rule is
+    ``ops.pallas_head.head_tiles``'s, asked only where there is a
+    gradient to take. Tells the open :func:`head_loss_traces` blocks."""
+    tiles, why = None, 'not differentiated: the logits product alone'
+    if with_grad:
+        tiles, why = head_tiles(rows, dim, vocab, dtype)
+    for sink in _HEAD_SINKS:
+        sink.append({'route': 'kernel' if tiles else 'xla', 'rows': rows,
+                     **(tiles or dict.fromkeys(
+                         ('row_group', 'vocab_tile', 'row_tile'))),
+                     'why': why})
+    return tiles
+
+
 def _head_loss_scan(x, table, targets, chunk, logit_scale, with_grad):
     """The scan behind :func:`head_loss`. A chunk builds its float32
     logits ONCE; from them come the summed loss and the valid count
     and, ``with_grad``, the chunk's whole gradient while they are still
     live: ``dlogits = valid · logit_scale · (softmax − onehot)``, its
     ``dx`` rows (the scan's ``ys``) and its share of ``dW`` (an
-    accumulator in the carry). Returns ``(s, count)``, and with them
-    ``(dx, dW)`` at a unit cotangent of ``s``, both float32: ``dx`` is
-    rounded to ``x``'s type once, after the cotangent has scaled it, as
-    plain autodiff rounds it."""
+    accumulator in the carry) — one Pallas program for both where
+    ``ops.pallas_head.head_tiles`` takes the chunk's shapes (the
+    operands in ``x``'s type, the table cast once, outside the scan),
+    else two einsums. Returns ``(s, count)``, and with them ``(dx, dW)``
+    at a unit cotangent of ``s``, both float32: ``dx`` is rounded to
+    ``x``'s type once, after the cotangent has scaled it, as plain
+    autodiff rounds it."""
     tn, dim = x.shape[-2:]
     if chunk is None or chunk >= tn:
         chunk = tn
@@ -314,11 +360,20 @@ def _head_loss_scan(x, table, targets, chunk, logit_scale, with_grad):
     xr = jnp.moveaxis(x.reshape(*x.shape[:-2], n, chunk, dim), -3, 0)
     tr = jnp.moveaxis(targets.reshape(*targets.shape[:-1], n, chunk),
                       -2, 0)
+    rows = xr.size // (n * dim)
+    tiles = _head_route(rows, dim, table.shape[0], x.dtype, with_grad)
+    if tiles:
+        # The kernel's operands are the compute type's, as the MXU reads
+        # the float32 ones at the default precision: the table is cast
+        # ONCE, here, and the logits product reads the same copy.
+        table = table.astype(x.dtype)
 
     def body(carry, xs):
         x_c, t_c = xs
-        x_c = x_c.astype(jnp.float32)
-        logits = jnp.einsum('...cd,vd->...cv', x_c, table)
+        if not tiles:
+            x_c = x_c.astype(jnp.float32)
+        logits = jnp.einsum('...cd,vd->...cv', x_c, table,
+                            preferred_element_type=jnp.float32)
         if logit_scale != 1.0:
             logits = logits * logit_scale
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
@@ -333,6 +388,12 @@ def _head_loss_scan(x, table, targets, chunk, logit_scale, with_grad):
         c = carry[1] + jnp.sum(valid.astype(jnp.float32))
         if not with_grad:
             return (s, c), None
+        if tiles:
+            dx_c, dw = head_grad(
+                logits.reshape(rows, -1), lse.reshape(rows),
+                t_c.reshape(rows), x_c.reshape(rows, dim), table,
+                carry[2], logit_scale=logit_scale, tiles=tiles)
+            return (s, c, dw), dx_c.reshape(x_c.shape)
         dlogits = jnp.where(
             valid[..., None],
             jnp.exp(logits - lse[..., None]) - hit.astype(jnp.float32),
@@ -347,7 +408,7 @@ def _head_loss_scan(x, table, targets, chunk, logit_scale, with_grad):
     if not with_grad:
         return jax.lax.scan(body, (zero, zero), (xr, tr))[0]
     (s, c, dw), dxs = jax.lax.scan(
-        body, (zero, zero, jnp.zeros_like(table)), (xr, tr))
+        body, (zero, zero, jnp.zeros(table.shape, jnp.float32)), (xr, tr))
     dx = jnp.moveaxis(dxs, 0, -3).reshape(*x.shape[:-2], n * chunk, dim)
     return (s, c), (dx[..., :tn, :], dw)
 
@@ -363,11 +424,17 @@ def head_loss(x, table, targets, chunk, logit_scale):
     The loss is the last thing a forward computes and its cotangent is
     a scalar, so differentiated it takes its gradient IN THE FORWARD
     pass: a chunk's logits give the loss, ``dx`` and ``dW`` and are
-    dropped — three vocabulary-wide matmuls a chunk, nothing
+    dropped — three vocabulary-wide products a chunk, nothing
     ``(chunk, vocab)``-sized kept or rebuilt; the backward rule scales
     ``(dx, dW)`` by the sum's cotangent (the count carries none).
-    Un-differentiated it is the same scan with the logits matmul
-    alone. Reverse mode, first order only (a ``custom_vjp``)."""
+    Where ``ops.pallas_head.head_tiles`` takes the chunk's shapes (a
+    bfloat16 ``x``, a width in 128-column tiles, a vocabulary of four
+    and rows in 128-row tiles) the two gradient products are ONE Pallas
+    program that builds ``dlogits`` once, in VMEM
+    (``ops.pallas_head.head_grad``); else they are two einsums.
+    :func:`head_loss_traces` says which. Un-differentiated it is the
+    same scan with the logits matmul alone. Reverse mode, first order
+    only (a ``custom_vjp``)."""
     return _head_loss_scan(x, table, targets, chunk, logit_scale, False)
 
 

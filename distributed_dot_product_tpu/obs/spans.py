@@ -150,8 +150,10 @@ DEVICE_SCOPES = {
              'pre-mix into the branch input and the post / residual '
              'mix back into the stream',
     'lm.embed': 'the embedding gather (and its scatter-add backward)',
-    'lm.head_loss': 'training: ln_f, the chunked head matmul and '
-                    'logsumexp scan with its checkpointed body',
+    'lm.head_loss': 'training: ln_f and the chunked scan that takes '
+                    'loss, dx and dW from one set of logits (the logits '
+                    'matmul, logsumexp, and the head_grad kernel or the '
+                    'two einsums it stands for)',
     'lm.head': 'prefill / decode: ln_f and the head matmul',
     'lm.stack_carry': 'the layer stack outside its blocks\' sub-scopes: '
                       'ln1, residual adds, and the scan\'s own slicing, '

@@ -20,7 +20,9 @@ from distributed_dot_product_tpu.models import (
     attention, lm, remat, transformer,
 )
 from distributed_dot_product_tpu.models.dense import dense_param_bytes
-from distributed_dot_product_tpu.models.lm import TransformerLM, lm_targets
+from distributed_dot_product_tpu.models.lm import (
+    TransformerLM, head_loss_traces, lm_targets,
+)
 from distributed_dot_product_tpu.models.remat import LAYER_MATMUL_NAMES
 from distributed_dot_product_tpu.obs.spans import (
     DEVICE_SCOPES, device_scope,
@@ -395,13 +397,13 @@ def test_passes_show_in_op_names(remat_policy):
 
 
 def head_scan_vocab_dots(fn, *args, vocab=64):
-    """The ``dot_general``s with a vocabulary-sized dimension inside the
-    scans opened under ``lm.head_loss``, and the primitives of every
-    equation under that scope (``tiny_lm``'s vocabulary is 64 where its
-    width is 32 and the loss's chunk 16). A sub-jaxpr's name stacks are
-    relative to the equation that holds it, so the walk carries what
-    it is under."""
-    dots, under = [], set()
+    """The ``dot_general``s with a vocabulary-sized dimension and the
+    names of the Pallas calls inside the scans opened under
+    ``lm.head_loss``, and the primitives of every equation under that
+    scope (``tiny_lm``'s vocabulary is 64 where its width is 32 and the
+    loss's chunk 16). A sub-jaxpr's name stacks are relative to the
+    equation that holds it, so the walk carries what it is under."""
+    dots, kernels, under = [], [], set()
 
     def walk(jaxpr, in_head, in_scan):
         for eqn in jaxpr.eqns:
@@ -414,33 +416,53 @@ def head_scan_vocab_dots(fn, *args, vocab=64):
                     vocab in v.aval.shape
                     for v in (*eqn.invars, *eqn.outvars)):
                 dots.append(eqn)
+            if head and in_scan and name == 'pallas_call':
+                kernels.append(eqn.params['name'])
+                continue        # the kernel's own body is not the scan's
             for sub in _sub_jaxprs(eqn):
                 walk(sub, head, in_scan or (head and name == 'scan'))
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr, False, False)
-    return dots, under
+    return dots, kernels, under
 
 
-def test_head_takes_its_gradient_in_the_forward_pass():
+@pytest.mark.parametrize('route', ['xla', 'kernel'])
+def test_head_takes_its_gradient_in_the_forward_pass(route):
     """The "counter" of a static mechanism: differentiated, the loss's
-    scan over 4 chunks builds a chunk's logits once and takes dx and dW
-    from them — three vocabulary-wide matmuls, under the forward pass's
-    name, with no checkpoint to rebuild a fourth; un-differentiated it
-    holds the logits matmul alone."""
-    model = tiny_lm(distributed=False)
-    tokens = jax.random.randint(jax.random.key(1), (1, 64), 0, 64)
-    params = model.init(jax.random.key(0), tokens)
+    scan over its chunks builds a chunk's logits once and takes dx and
+    dW from them, with no checkpoint to rebuild them — by two einsums
+    (three vocabulary-wide matmuls a chunk) where the shapes are under
+    the head kernel's tiles, by ONE Pallas program beside the logits
+    matmul where ``ops.pallas_head.head_tiles`` takes them (the
+    narrowest bfloat16 model it takes, here);
+    ``models.lm.head_loss_traces()`` says which. Un-differentiated it
+    holds the logits matmul alone on either route."""
+    if route == 'xla':
+        model, vocab, chunk, t = tiny_lm(distributed=False), 64, 16, 64
+    else:
+        vocab, chunk, t = 512, 128, 256
+        model = TransformerLM(vocab_size=vocab, dim=128, num_heads=2,
+                              n_layers=1, remat=True, dtype=jnp.bfloat16,
+                              attn_kwargs=dict(distributed=False))
+    tokens = jax.random.randint(jax.random.key(1), (1, t), 0, vocab)
+    params = model.init(jax.random.key(0), tokens[:, :64])
 
     def loss(p):
-        return model.apply(p, tokens, lm_targets(tokens), chunk=16,
+        return model.apply(p, tokens, lm_targets(tokens), chunk=chunk,
                            method='nll_sum')[0]
 
-    dots, under = head_scan_vocab_dots(jax.value_and_grad(loss), params)
-    assert len(dots) == 3
+    with head_loss_traces() as traces:
+        dots, kernels, under = head_scan_vocab_dots(
+            jax.value_and_grad(loss), params, vocab=vocab)
+    assert [t['route'] for t in traces] == [route]
+    assert (len(dots), kernels) == (
+        (3, []) if route == 'xla' else (1, ['head_grad']))
     assert not {'checkpoint', 'custom_vjp_call'} & under
-    dots, under = head_scan_vocab_dots(loss, params)
-    assert len(dots) == 1
+    dots, kernels, under = head_scan_vocab_dots(loss, params, vocab=vocab)
+    assert len(dots) == 1 and not kernels
     assert 'custom_vjp_call' in under and 'checkpoint' not in under
+    if route == 'kernel':
+        return      # its compiled step: tests/test_tpu_compile.py
     # The step as compiled: the same three under the forward pass's
     # name, nothing of the head rematerialized.
     head = [n for n in train_names(None) if '/lm.head_loss/' in n]

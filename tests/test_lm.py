@@ -21,6 +21,7 @@ import optax
 import pytest
 
 from distributed_dot_product_tpu import TransformerLM, lm_targets
+from distributed_dot_product_tpu.models.lm import head_loss_traces
 from distributed_dot_product_tpu.parallel.mesh import (
     data_seq_mesh, seq_mesh,
 )
@@ -125,23 +126,25 @@ def _plain_nll(m, params, tokens, targets, seg):
     return nn.apply(nll, m)(params)
 
 
-def _assert_grads_close(got, want, dtype):
+def _assert_grads_close(got, want, dtype, tol=lambda name: 1e-4):
     """float32: element for element. bfloat16 compute: a parameter's
-    gradient as a whole, by the norm of its error against the norm of
-    the reference (single elements flip a rounding) — tight all the
-    same, because ``dx`` leaves the head in float32 and is rounded where
-    plain autodiff rounds it: a ``dx`` rounded before the cotangent
-    scales it as well reads 1e-2 here."""
+    gradient as a whole, by the norm of its error against ``tol`` (of
+    the parameter's name) times the norm of the reference (single
+    elements flip a rounding) — tight all the same, because ``dx``
+    leaves the head in float32 and is rounded where plain autodiff
+    rounds it: a ``dx`` rounded before the cotangent scales it as well
+    reads 1e-2 here."""
     got = jax.tree_util.tree_leaves_with_path(got)
     for (path, a), b in zip(got, jax.tree.leaves(want), strict=True):
+        name = jax.tree_util.keystr(path)
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         if dtype is None:
             np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4,
-                                       err_msg=jax.tree_util.keystr(path))
+                                       err_msg=name)
         else:
-            assert (np.linalg.norm(a - b)
-                    <= 1e-4 * np.linalg.norm(b) + 1e-9), (
-                jax.tree_util.keystr(path))
+            err = np.linalg.norm(a - b)
+            assert err <= tol(name) * np.linalg.norm(b) + 1e-9, (
+                name, err / np.linalg.norm(b))
 
 
 def _mean(nll):
@@ -188,12 +191,139 @@ def test_lm_chunked_nll_matches_unchunked(tie, logit_scale, dtype, chunk):
         return m.apply(p, tokens, targets, segment_ids=seg, chunk=chunk,
                        method='nll_sum')
 
-    got_s, got_c = nll(params)          # un-differentiated: the primal
+    with head_loss_traces() as traces:
+        got_s, got_c = nll(params)      # un-differentiated: the primal
+        got, got_g = jax.value_and_grad(_mean(nll))(params)  # the fused rule
     assert float(got_c) == float(want_c) == float(jnp.sum(targets >= 0))
     np.testing.assert_allclose(float(got_s), float(want_s), rtol=2e-6)
-    got, got_g = jax.value_and_grad(_mean(nll))(params)  # the fused rule
     np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
     _assert_grads_close(got_g, want_g, dtype)
+    # A 32-wide model over a 32-token vocabulary is under the kernel's
+    # tiles: both traces keep the two einsums, and say why.
+    assert [t['route'] for t in traces] == ['xla', 'xla']
+    assert 'not differentiated' in traces[0]['why']
+    assert ('float32' if dtype is None else 'width 32') in traces[1]['why']
+
+
+KERNEL_DIM = 128        # the narrowest width and the smallest vocabularies
+KERNEL_ROWS = 128       # and chunk ``ops.pallas_head.head_tiles`` takes
+
+
+@functools.cache
+def _kernel_case(tie, logit_scale, rows, vocab):
+    """A one-layer bfloat16 model at the smallest shapes the head's
+    kernel takes, a batch of ``rows`` tokens scanned in 128-row chunks
+    — ``'whole'``: two chunks; ``'pad'``: 192 rows, the second chunk
+    half padding; ``'ignored'``: the second chunk all -1 — and the plain
+    loss there."""
+    m = _model(vocab_size=vocab, dim=KERNEL_DIM, n_layers=1,
+               attn_kwargs=dict(distributed=False, softmax_impl='full'),
+               tie_embeddings=tie, logit_scale=logit_scale,
+               dtype=jnp.bfloat16)
+    t = 192 if rows == 'pad' else 256
+    tokens = jax.random.randint(jax.random.key(11), (1, t), 0, vocab)
+    targets = lm_targets(tokens)
+    if rows == 'ignored':
+        targets = targets.at[:, KERNEL_ROWS:].set(-1)
+    # Parameters that bfloat16 holds exactly: the kernel's route reads
+    # the table in the compute type, the plain loss in float32.
+    params = jax.tree.map(
+        lambda p: p.astype(jnp.bfloat16).astype(p.dtype),
+        m.init(jax.random.key(1), tokens[:, :16]))
+
+    def plain(p):
+        return _plain_nll(m, p, tokens, targets, None)
+
+    return (m, params, (tokens, targets), jax.jit(plain)(params),
+            jax.jit(jax.value_and_grad(_mean(plain)))(params))
+
+
+@pytest.mark.parametrize('vocab', [512, 576], ids=['whole-tiles', 'ragged'])
+@pytest.mark.parametrize('rows', ['whole', 'pad', 'ignored'])
+@pytest.mark.parametrize('logit_scale', [1.0, 0.5])
+@pytest.mark.parametrize('tie', [True, False], ids=['tied', 'untied'])
+def test_lm_chunked_nll_on_the_kernel_route(tie, logit_scale, rows, vocab):
+    """The same contract where the chunk's ``dx`` and ``dW`` are ONE
+    Pallas program (``ops.pallas_head.head_grad``, under the
+    interpreter here): the sum, the count and every parameter's gradient
+    against the plain loss, with a vocabulary of whole tiles and one
+    whose last block hangs over (576 = 4.5 tiles of 128). The kernel's
+    operands are bfloat16 — ``dlogits`` rounded as the MXU reads it —
+    where this machine's plain loss multiplies in float32, so the form
+    of the bfloat16 cases' tolerance holds at that rounding and not at
+    1e-4: 2^-8 of the norm for what the head itself gives (an untied
+    table's gradient and, through ``dx``, the final norm's), 2^-6 below
+    it, where a ``dx`` that differs in its last bfloat16 bit sends every
+    rounding of the bfloat16 backward pass another way."""
+    m, params, (tokens, targets), (want_s, want_c), (
+        want, want_g) = _kernel_case(tie, logit_scale, rows, vocab)
+
+    def nll(p):
+        return m.apply(p, tokens, targets, chunk=KERNEL_ROWS,
+                       method='nll_sum')
+
+    with head_loss_traces() as traces:
+        got, got_g = jax.jit(jax.value_and_grad(_mean(nll)))(params)
+    assert traces == [{'route': 'kernel', 'rows': KERNEL_ROWS,
+                       'row_group': 128, 'vocab_tile': 128,
+                       'row_tile': 128, 'why': None}]
+    got_s, got_c = jax.jit(nll)(params)
+    assert float(got_c) == float(want_c) == float(jnp.sum(targets >= 0))
+    np.testing.assert_allclose(float(got_s), float(want_s), rtol=2e-6)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
+    _assert_grads_close(
+        got_g, want_g, jnp.bfloat16, tol=lambda name: (
+            2 ** -8 if 'ln_f' in name or 'lm_head' in name else 2 ** -6))
+
+
+def test_lm_kernel_route_through_the_sharded_train_step():
+    """The kernel's route inside the step's ``shard_map``: four shards
+    each scan their own 256 rows in two chunks through ``head_grad``
+    (the accumulator aliased in and out of each shard's scan), and the
+    psum'd loss and SGD(1.0) update are the single-shard step's."""
+    m = _model(vocab_size=512, dim=KERNEL_DIM, n_layers=1,
+               dtype=jnp.bfloat16)
+    tokens = jax.random.randint(jax.random.key(3), (1, 1024), 0, 512)
+    batch = (tokens, lm_targets(tokens))
+    params = m.init(jax.random.key(1), tokens[:, :16])
+    opt = optax.sgd(1.0)
+    got = {}
+    for width in (4, 1):
+        step = make_lm_train_step(m, opt, seq_mesh(width), donate=False,
+                                  loss_chunk=KERNEL_ROWS)
+        with head_loss_traces() as traces:
+            new_params, _, loss = step(params, opt.init(params), batch)
+        assert [(t['route'], t['rows']) for t in traces] == [
+            ('kernel', KERNEL_ROWS)]
+        got[width] = (float(loss), jax.tree.map(
+            lambda new, old: np.asarray(new - old, np.float32),
+            new_params, params))
+    np.testing.assert_allclose(got[4][0], got[1][0], rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(got[4][1]), jax.tree.leaves(got[1][1]),
+                    strict=True):
+        assert np.linalg.norm(a - b) <= 2 ** -6 * np.linalg.norm(b) + 1e-9
+
+
+def test_head_tiles_takes_the_cells_and_refuses_what_is_under_a_tile():
+    """The one rule behind the route: both training cells' chunks take
+    the kernel at a 1024-row group (16 / 12 MiB of float32 dx resident);
+    a float32 model, a width that is no multiple of 128, a vocabulary
+    under four tiles and a chunk that is no multiple of the row tile
+    keep the XLA body, each with its reason."""
+    from distributed_dot_product_tpu.ops.pallas_head import head_tiles
+    for dim, vocab in ((4096, 50432), (3072, 49152)):
+        tiles, why = head_tiles(4096, dim, vocab, jnp.bfloat16)
+        assert why is None and tiles == {
+            'row_group': 1024, 'vocab_tile': 512, 'row_tile': 1024}
+    assert head_tiles(128, 128, 512, jnp.bfloat16)[0] == {
+        'row_group': 128, 'vocab_tile': 128, 'row_tile': 128}
+    for args, reason in (
+            ((4096, 4096, 50432, jnp.float32), 'float32'),
+            ((16, 32, 32, jnp.bfloat16), 'width 32'),
+            ((4096, 4096, 500, jnp.bfloat16), 'vocabulary 500'),
+            ((200, 4096, 50432, jnp.bfloat16), '200 rows')):
+        tiles, why = head_tiles(*args)
+        assert tiles is None and reason in why
 
 
 @pytest.mark.parametrize('tie', [True, False], ids=['tied', 'untied'])

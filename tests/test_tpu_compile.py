@@ -981,10 +981,20 @@ def test_solar_decode_step_reads_three_states_once_and_fits(
     assert restored.as_text().count('lm.state_restore') >= 6
 
 
-@pytest.mark.parametrize('remat_policy, forwards', [
-    (None, 1), ('nothing_saveable', 2)], ids=['kept', 'full-remat'])
+def _head_ops(hlo):
+    """``(convolutions, Mosaic calls)`` under ``lm.head_loss`` in a
+    compiled step, by their ``op_name``."""
+    under = r'op_name="([^"]*/lm\.head_loss/[^"]*)"'
+    return (re.findall(r' convolution\([^\n]*' + under, hlo),
+            re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                       + under, hlo))
+
+
+@pytest.mark.parametrize('remat_policy, forwards, head', [
+    (None, 1, 'kernel'), ('nothing_saveable', 2, 'kernel'),
+    (None, 1, 'xla')], ids=['kept', 'full-remat', 'kept-xla-head'])
 def test_scanned_lm_train_step_runs_the_flash_forward_once(
-        chip, monkeypatch, remat_policy, forwards):
+        chip, monkeypatch, remat_policy, forwards, head):
     """The scanned, rematted LM train step at the MPT training cell's
     shapes (2 layers x 16384 tokens, bfloat16 compute): the stack keeps
     the flash forward's output and logsumexp, and XLA drops the
@@ -993,12 +1003,19 @@ def test_scanned_lm_train_step_runs_the_flash_forward_once(
     rematerialized, and the kept tensors ride the scan as stacked
     ``(L, B, H, T, d)`` / ``(L, B, H, T)`` buffers (not as the kernel's
     lane-padded ``(nb, T, 1)`` logsumexp). Full remat, by its name, holds
-    the forward twice."""
+    the forward twice. The head takes its gradient in the forward pass,
+    by the route ``head_loss_traces()`` names: a chunk's logits matmul
+    and ONE ``head_grad`` program, or — the rule refusing, as it does a
+    shape under its tiles — the three matmuls of the XLA body."""
     import optax
     from distributed_dot_product_tpu import TransformerLM
+    from distributed_dot_product_tpu.models import lm
     from distributed_dot_product_tpu.parallel.mesh import seq_mesh
     from distributed_dot_product_tpu.train import make_lm_train_step
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    if head == 'xla':
+        monkeypatch.setattr(lm, 'head_tiles',
+                            lambda *shape: (None, 'refused by the test'))
     t, layers = 16384, 2
     model = TransformerLM(**{**LM, 'n_layers': layers}, dtype=jnp.bfloat16,
                           scan_layers=True, remat=True,
@@ -1014,27 +1031,65 @@ def test_scanned_lm_train_step_runs_the_flash_forward_once(
         lambda: model.init(jax.random.key(0), jnp.zeros((1, 128),
                                                         jnp.int32)))
     tok = jnp.zeros((1, t), jnp.int32)
-    hlo = _compile(chip, step, params,
-                   jax.eval_shape(optimizer.init, params),
-                   (tok, tok), donate=(0, 1)).as_text()
+    with lm.head_loss_traces() as traces:
+        hlo = _compile(chip, step, params,
+                       jax.eval_shape(optimizer.init, params),
+                       (tok, tok), donate=(0, 1)).as_text()
+    assert [r['route'] for r in traces] == [head]
     calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
                        r'op_name="([^"]*)"', hlo)
     fwd = [n for n in calls if n.endswith('flash_fwd/pallas_call')]
-    assert len(fwd) == forwards and len(calls) == forwards + 1
+    assert len(fwd) == forwards
+    assert len(calls) == forwards + 1 + (head == 'kernel')
     assert sum('rematted_computation' in n for n in fwd) == forwards - 1
     for stacked in (f'bf16[{layers},1,32,{t},128]', f'f32[{layers},1,32,{t}]'):
         assert (stacked in hlo) == (forwards == 1)
     assert f'f32[{layers},32,{t},1]' not in hlo
-    # The head takes its gradient in the forward pass: the logits, dx and
-    # dW matmuls of a loss chunk and no fourth, nothing of it rebuilt.
-    # (Parameters and optimizer state donated, as a training loop does:
-    # held twice they pass the chip's memory, and XLA's own
+    # The head takes its gradient in the forward pass: a loss chunk's
+    # logits matmul and the kernel that gives dx and dW (the XLA body:
+    # the logits, dx and dW matmuls) and no further matmul, nothing of
+    # it rebuilt. (Parameters and optimizer state donated, as a training
+    # loop does: held twice they pass the chip's memory, and XLA's own
     # rematerialization then rebuilds a chunk's logits for dW.)
-    head = re.findall(r' convolution\([^\n]*op_name="([^"]*/lm\.head_loss/'
-                      r'[^"]*)"', hlo)
-    assert len(head) == 3 and all(
-        'transpose(jvp(' not in n and 'rematted_computation' not in n
-        for n in head)
+    matmuls, kernels = _head_ops(hlo)
+    assert (len(matmuls), len(kernels)) == (
+        (1, 1) if head == 'kernel' else (3, 0))
+    assert all(n.endswith('head_grad/pallas_call') for n in kernels)
+    assert all('transpose(jvp(' not in n and 'jvp(' in n
+               and 'rematted_computation' not in n
+               for n in matmuls + kernels)
+    assert not re.findall(XLAS_OWN_REMAT, hlo)
+
+
+@pytest.mark.parametrize('rows, dim, vocab, logit_scale', [
+    (4096, 4096, 50432, 1.0), (1024, 1024, 50257, 0.5)],
+    ids=['mpt-chunk', 'ragged-vocab'])
+def test_head_grad_kernel_compiles_for_v5e(chip, rows, dim, vocab,
+                                           logit_scale):
+    """``ops.pallas_head.head_grad`` under the ``vmem_limit_bytes`` it
+    states: the MPT training cell's chunk, where the rule's 1024-row
+    group holds exactly the 16 MiB of float32 dx it budgets (dW blocks
+    of 512 vocabulary rows, 118 MiB of the chip's 128 in all), and a
+    vocabulary that is no multiple of any tile, whose last block is
+    masked inside the kernel."""
+    from distributed_dot_product_tpu.ops.pallas_head import (
+        head_grad, head_tiles,
+    )
+    tiles, why = head_tiles(rows, dim, vocab, jnp.bfloat16)
+    assert why is None and tiles['vocab_tile'] == 512
+    assert tiles['row_group'] == 1024
+
+    def fn(logits, lse, targets, x, table, dw):
+        return head_grad(logits, lse, targets, x, table, dw,
+                         logit_scale=logit_scale, tiles=tiles,
+                         interpret=False)
+
+    _compile(chip, fn, jax.ShapeDtypeStruct((rows, vocab), jnp.float32),
+             jax.ShapeDtypeStruct((rows,), jnp.float32),
+             jax.ShapeDtypeStruct((rows,), jnp.int32),
+             jax.ShapeDtypeStruct((rows, dim), jnp.bfloat16),
+             jax.ShapeDtypeStruct((vocab, dim), jnp.bfloat16),
+             jax.ShapeDtypeStruct((vocab, dim), jnp.float32), donate=(5,))
 
 
 V5E_BYTES_LIMIT = 16909336064      # what a v5e chip reports
@@ -1094,6 +1149,7 @@ def test_training_cells_keep_what_fits_a_v5e(chip, monkeypatch, config,
     import json
     from benchmarks import system
     from benchmarks.drivers.train import make_optimizer
+    from distributed_dot_product_tpu.models.lm import head_loss_traces
     from distributed_dot_product_tpu.ops.pallas_attention import (
         FLASH_RESIDUAL_NAMES, flash_block_traces,
     )
@@ -1106,8 +1162,11 @@ def test_training_cells_keep_what_fits_a_v5e(chip, monkeypatch, config,
                            'train-16k.json')) as f:
         optimizer = make_optimizer(json.load(f)['optimizer'])
     model = system.build_lm(cfg)
-    with flash_block_traces() as blocks:
+    with flash_block_traces() as blocks, head_loss_traces() as heads:
         compiled, record = _train_step_for_v5e(chip, model, optimizer)
+    # Both cells' loss chunks take the head's kernel, by the rule.
+    assert heads == [{'route': 'kernel', 'rows': 4096, 'row_group': 1024,
+                      'vocab_tile': 512, 'row_tile': 1024, 'why': None}]
     # Inside the scanned, rematted layer too the kernels see the call's
     # offsets as the plain ints they are: MPT's forward takes the
     # trapezoid grid like its backward (as custom_vjp operands the ints
@@ -1125,6 +1184,10 @@ def test_training_cells_keep_what_fits_a_v5e(chip, monkeypatch, config,
     assert record['layer_bytes']['mlp_hidden'] == t * hidden * 2
     hlo = compiled.as_text()
     assert not re.findall(XLAS_OWN_REMAT, hlo)
+    matmuls, kernels = _head_ops(hlo)
+    assert len(matmuls) == len(kernels) == 1
+    assert all('transpose(jvp(' not in n and 'jvp(' in n
+               for n in matmuls + kernels)
     for stacked in (f'bf16[{layers},1,{t},{hidden}]',
                     f'bf16[{layers},1,{model.num_heads},{t},128]'):
         assert stacked in hlo
