@@ -10,8 +10,10 @@ Five engines (see README "Static analysis"):
   ClosedJaxpr — fp32 accumulation on low-precision dots, surgical
   (aliased) KV-cache writes + real donation, no cache-shaped upcasts,
   collectives only on declared mesh axes.
-- **Retrace sentinel** (:mod:`.retrace`): runtime trace-count budgets
-  on jitted decode/serve entrypoints; on by default under pytest.
+- **Retrace sentinel** (:mod:`distributed_dot_product_tpu.utils.retrace`
+  — a runtime guard the lower layers import, so it lives below this
+  package): trace-count budgets on jitted decode/serve entrypoints; on
+  by default under pytest.
 - **AST ruleset** (:mod:`.astlint`): pure-``ast`` hazard patterns —
   host pulls of traced values and traced-bool branching in hot paths,
   clock reads inside jit, silent broad excepts.
@@ -33,21 +35,17 @@ CLI: ``python -m distributed_dot_product_tpu.analysis`` (exit 0 = no
 violations). The tier-1 gate test (tests/test_graphlint.py) asserts a
 clean tree, so a contract break fails CI before it ships.
 
-This ``__init__`` stays import-light (no jax): serving code imports
-:mod:`.retrace` at build time, and pulling the whole linter (which
-imports every layer) along with it would be an import cycle.
+This ``__init__`` stays import-light (no jax): the linter's engines and
+the example programs (:mod:`.entrypoints`, which imports every layer)
+load only when :func:`run_analysis` asks for them.
 """
 
 from distributed_dot_product_tpu.analysis.base import (     # noqa: F401
     RULES, Violation, active_violations, format_violations,
 )
-from distributed_dot_product_tpu.analysis.retrace import (  # noqa: F401
-    RetraceBudgetExceeded, watch_traces,
-)
 
 __all__ = ['RULES', 'Violation', 'active_violations',
-           'format_violations', 'watch_traces',
-           'RetraceBudgetExceeded', 'run_analysis']
+           'format_violations', 'run_analysis']
 
 
 def run_analysis(paths=None, rules=None, repo_root=None,

@@ -89,7 +89,7 @@ def main(argv=None):
         if runtime_only:
             parser.error(
                 f'{", ".join(runtime_only)}: enforced at RUNTIME by the '
-                f'retrace sentinel (analysis/retrace.py; on under '
+                f'retrace sentinel (utils/retrace.py; on under '
                 f'pytest), not statically — there is nothing for this '
                 f'command to check')
 
@@ -161,8 +161,8 @@ def _repo_root():
 
 def _affects_registry(path):
     """Can a change to ``path`` alter a registered entrypoint's jaxpr?
-    Conservative path heuristic over the LAYER_HOOKS modules plus the
-    analysis subsystem itself."""
+    Conservative path heuristic over the layers analysis/entrypoints.py
+    imports plus the analysis subsystem itself."""
     norm = os.path.abspath(path).replace(os.sep, '/')
     return any(frag in norm for frag in (
         '/ops/', '/models/', '/parallel/', '/analysis/',

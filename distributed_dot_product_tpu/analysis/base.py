@@ -27,7 +27,7 @@ __all__ = ['Violation', 'RULES', 'allowed_by_pragma',
 
 # Rule catalog. Jaxpr rules (J*) trace registered entrypoints and walk
 # the ClosedJaxpr; AST rules (A*) parse source; R* is enforced at
-# runtime by the retrace sentinel (analysis/retrace.py) under pytest.
+# runtime by the retrace sentinel (utils/retrace.py) under pytest.
 RULES = {
     'f32-accum': (
         'every dot_general on low-precision (bf16/f16/int8) operands '
@@ -84,7 +84,7 @@ RULES = {
         'through utils.tracing.log_exception or narrow the type '
         '(PR 1/2: fault paths must stay observable)'),
     'retrace-budget': (
-        'runtime rule (analysis/retrace.py): a watched decode/serve '
+        'runtime rule (utils/retrace.py): a watched decode/serve '
         'entrypoint may not trace more often than its declared budget '
         '— automates the round-5 decode_seq_parallel retrace-storm '
         'finding'),

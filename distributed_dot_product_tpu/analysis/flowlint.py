@@ -46,8 +46,9 @@ Four rules:
   ``pages_per_shard + 1`` stride arithmetic — the PR 18 contiguous-
   ownership contract has exactly one home.
 
-Scope: the installed package (minus ``analysis/`` itself — the linter
-does not lint the linter) is ALWAYS parsed in full as the
+Scope: the installed package (minus ``analysis/`` itself and its
+runtime rule, ``utils/retrace.py`` — the linter does not lint the
+linter) is ALWAYS parsed in full as the
 interprocedural universe, whatever path subset was requested, so
 ``--changed-only`` keeps whole-graph soundness; violations are then
 reported only when they touch a requested file. Files under
@@ -170,6 +171,10 @@ _BUILTIN_BASES = {
 }
 
 _PKG_PREFIX = 'distributed_dot_product_tpu.'
+# The retrace sentinel is the lint's runtime rule; it lives under utils/
+# because the model and the engine import it, and stays outside the
+# universe as analysis/ does.
+_SENTINEL = '/utils/retrace.py'
 _MAX_HOPS = 64
 
 
@@ -601,7 +606,7 @@ def _package_universe_paths():
         for n in sorted(names):
             if n.endswith('.py'):
                 out.append(os.path.join(base, n))
-    return out
+    return [p for p in out if _in_package(p)]
 
 
 def _build_universe(paths, repo_root):
@@ -919,7 +924,7 @@ def _lint_universe(uni, fixture, anchor_rels, rules):
 def _in_package(path):
     norm = os.path.abspath(path).replace(os.sep, '/')
     return f'/{_PKG_PREFIX.rstrip(".")}/' in norm \
-        and '/analysis/' not in norm
+        and '/analysis/' not in norm and not norm.endswith(_SENTINEL)
 
 
 def lint_paths(paths, repo_root=None, rules=None):

@@ -1,40 +1,20 @@
 # -*- coding: utf-8 -*-
 """
-Central entrypoint registry: the single place where every public
-computation of the package declares *example abstract shapes and
-meshes* so the jaxpr linter (analysis/jaxpr_rules.py) can trace it
-without running it.
+Central entrypoint registry: what one traceable example of a public
+computation is (:class:`TraceSpec`) and where the jaxpr linter
+(analysis/jaxpr_rules.py) finds them all (:func:`default_entrypoints`).
 
-The shapes live NEXT TO the code they describe: each layer module
-(``ops/functions.py``, ``ops/pallas_attention.py``,
-``models/attention.py``, ``models/decode.py``, ``models/lm.py``,
-``serve/engine.py``, ``train.py``) exposes a ``graphlint_entrypoints()``
-hook returning ``{name: builder}``; this module aggregates them. A new
-public entrypoint ships with its registration in the same diff, and the
-tier-1 gate test (tests/test_graphlint.py) fails if any registered
-entrypoint violates a rule — that is how the contracts survive growth.
-
-Builders are lazy (constructing flax params or meshes costs real work)
-and run on whatever devices are visible; mesh-using entries need >= 2
-devices (the CLI forces an 8-device CPU platform — see
-analysis/__main__.py — and the test suite already runs on one).
-
-Precision convention for examples: every projection matmul is the
-OWNED dense (models/dense.py — explicit ``preferred_element_type``
-accumulation), so module-level entries register at the serving dtype
-(bf16, plus int8-weight twins) right alongside the raw-op entries
-(flash kernels, decode steps, the LM head einsum) — the
-fp32/i32-accumulation contract is enforced end to end with zero
-waivers (the flax ``linen.Dense`` debt that used to force f32
-registration is retired).
+The examples themselves — the shapes, meshes and builders — are
+analysis/entrypoints.py, which imports every layer it lints; this module
+imports none of them, and no layer imports this one. The tier-1 gate
+test (tests/test_graphlint.py) fails if any registered entrypoint
+violates a rule — that is how the contracts survive growth.
 """
 
 import dataclasses
-from collections import OrderedDict
 from typing import Any, Callable, Optional, Tuple
 
-__all__ = ['TraceSpec', 'default_entrypoints', 'resolve_registry_arg',
-           'LAYER_HOOKS']
+__all__ = ['TraceSpec', 'default_entrypoints', 'resolve_registry_arg']
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,23 +59,9 @@ class TraceSpec:
         return dataclasses.replace(self, **kw)
 
 
-# (module path, hook name) for every layer that registers entrypoints.
-LAYER_HOOKS = (
-    'distributed_dot_product_tpu.ops.functions',
-    'distributed_dot_product_tpu.ops.pallas_attention',
-    'distributed_dot_product_tpu.models.attention',
-    'distributed_dot_product_tpu.models.decode',
-    'distributed_dot_product_tpu.models.lm',
-    'distributed_dot_product_tpu.serve.engine',
-    'distributed_dot_product_tpu.train',
-    'distributed_dot_product_tpu.obs',
-)
-
-
 def resolve_registry_arg(arg):
     """``MODULE:ATTR`` → a ``{name: builder}`` mapping (callables are
-    called) — the shared ``--registry`` escape hatch of the graphlint
-    and perf CLIs, in one place so the contract cannot drift. Raises
+    called) — the graphlint CLI's ``--registry`` escape hatch. Raises
     ValueError on a malformed argument."""
     import importlib
     modpath, _, attr = arg.partition(':')
@@ -106,21 +72,11 @@ def resolve_registry_arg(arg):
 
 
 def default_entrypoints():
-    """Aggregate every layer's ``graphlint_entrypoints()`` hook into one
-    ordered ``{name: builder}`` registry. Name collisions are an error —
-    the registry is the namespace the gate test and CLI report against."""
-    import importlib
-    registry = OrderedDict()
-    for modpath in LAYER_HOOKS:
-        mod = importlib.import_module(modpath)
-        hook = getattr(mod, 'graphlint_entrypoints', None)
-        if hook is None:
-            raise AttributeError(
-                f'{modpath} is listed in LAYER_HOOKS but defines no '
-                f'graphlint_entrypoints() hook')
-        for name, builder in hook().items():
-            if name in registry:
-                raise ValueError(f'duplicate entrypoint registration: '
-                                 f'{name!r} (from {modpath})')
-            registry[name] = builder
-    return registry
+    """The ordered ``{name: builder}`` registry of
+    analysis/entrypoints.py (a copy: a caller may take a subset).
+    Imported here, at the call, because that module imports every layer
+    of the package."""
+    from distributed_dot_product_tpu.analysis.entrypoints import (
+        ENTRYPOINTS,
+    )
+    return dict(ENTRYPOINTS)

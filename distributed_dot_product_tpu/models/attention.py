@@ -60,12 +60,13 @@ from distributed_dot_product_tpu.models.remat import (
 from distributed_dot_product_tpu.models.ulysses_attention import (
     ulysses_attention,
 )
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_attention import (
     FLASH_QKV_NAME, flash_attention,
 )
 from distributed_dot_product_tpu.ops.ops import matmul_all, matmul_nt
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
+from distributed_dot_product_tpu.utils.retrace import watch_traces
+from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['DistributedDotProductAttn', 'apply_seq_parallel',
            'decode_seq_parallel', 'make_decode_step']
@@ -933,12 +934,11 @@ def make_decode_step(module, mesh, mesh_axis=None, donate=True):
         fn, mesh=mesh,
         in_specs=(P(), P(), P(), P(), cache_spec),
         out_specs=(cache_spec, P()), check_vma=False)
-    # Retrace sentinel (analysis/retrace.py): a per-token serving loop
+    # Retrace sentinel (utils/retrace.py): a per-token serving loop
     # holds ONE of these steps, so more than budget traces of a single
     # instance is the round-5 retrace-storm class — raise (under
     # pytest / when enabled) instead of silently re-compiling. Budget 2:
     # one real trace plus one weak-type/lowering respin.
-    from distributed_dot_product_tpu.analysis.retrace import watch_traces
     step = watch_traces(step, name='attention.make_decode_step',
                         budget=2)
     return jax.jit(step, donate_argnums=(4,) if donate else ())
@@ -1011,100 +1011,3 @@ def decode_seq_parallel(module, params, mesh, keys, queries, values,
         lambda spec: NamedSharding(mesh, spec),
         _decode_cache_spec(module, mesh_axis or module.axis_name)))
     return step(params, keys, queries, values, cache)
-
-
-def graphlint_entrypoints():
-    """Static-analysis registration hook (analysis/registry.py): the
-    module-level attention surfaces on a real 2-device mesh — forward
-    and backward through every softmax_impl's comm pattern (all_gather,
-    ring ppermute, ulysses all_to_all) for the collective-axis rule,
-    and the full sequence-sharded decode step (make_decode_step) for
-    the donation + cache-alias rules on the exact callable a serving
-    loop holds. The projections are the owned dense (models/dense.py)
-    with explicit fp32 accumulation, so the bf16 serving-dtype twins
-    trace CLEAN — zero f32-accum waivers (the retired ROADMAP item 3a
-    debt) — and the int8-weight twin pins the s8×s8→s32 path."""
-    import functools
-
-    def _module(softmax_impl, **kw):
-        return DistributedDotProductAttn(
-            key_dim=8, num_heads=2, causal=True, offset=2,
-            softmax_impl=softmax_impl, **kw)
-
-    def _fwd_spec(name, softmax_impl, dtype=jnp.float32, **kw):
-        import jax
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        from distributed_dot_product_tpu.parallel.mesh import seq_mesh
-        mesh = seq_mesh(2)
-        module = _module(softmax_impl, dtype=dtype, **kw)
-        x = jnp.zeros((1, 16, 8), dtype)
-        params = module.init(jax.random.key(0), x, x, x, None)
-
-        def fn(p, k, q, v):
-            return apply_seq_parallel(module, p, mesh, k, q, v, None)
-
-        return TraceSpec(name=name, fn=fn, args=(params, x, x, x),
-                         mesh_axes=(SEQ_AXIS,))
-
-    def _bwd_spec(name, softmax_impl, **kw):
-        import jax
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        base = _fwd_spec(name, softmax_impl, **kw)
-
-        def loss(p, k, q, v):
-            return jnp.sum(base.fn(p, k, q, v))
-
-        return base.replace(fn=jax.grad(loss, argnums=(0, 1)))
-
-    def seq_parallel_step(name='decode.seq_parallel_step',
-                          dtype=jnp.float32):
-        import jax
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        from distributed_dot_product_tpu.parallel.mesh import seq_mesh
-        mesh = seq_mesh(2)
-        module = _module('flash', dtype=dtype)
-        x = jnp.zeros((1, 16, 8), dtype)
-        params = module.init(jax.random.key(0), x, x, x, None)
-        cache = module.make_decode_cache(1, 64)     # global t_max
-        step = make_decode_step(module, mesh)       # jitted + donating
-        tok = jnp.zeros((1, 1, 8), dtype)
-        return TraceSpec(
-            name=name, fn=step,
-            args=(params, tok, tok, tok, cache),
-            mesh_axes=(SEQ_AXIS,), prejitted=True,
-            cache_in=lambda a: [a[4].k, a[4].v],
-            cache_out=lambda o: [o[0].k, o[0].v],
-            expect_donation=True, min_donated=2)
-
-    # The *_bf16 twins trace the module-level surfaces at SERVING
-    # dtype, so the aliasing/donation/upcast/f32-accum contracts are
-    # enforced on the program a bf16 deployment actually runs — the
-    # owned-dense projections accumulate in fp32, so these trace with
-    # ZERO waivers. The _wq8 twin traces the int8-WEIGHT serving
-    # program (s8×s8→s32 projection dots + in-kernel dequant).
-    return {
-        'attention.fwd_flash': functools.partial(
-            _fwd_spec, 'attention.fwd_flash', 'flash'),
-        'attention.fwd_flash_bf16': functools.partial(
-            _fwd_spec, 'attention.fwd_flash_bf16', 'flash',
-            dtype=jnp.bfloat16),
-        'attention.fwd_flash_wq8': functools.partial(
-            _fwd_spec, 'attention.fwd_flash_wq8', 'flash',
-            dtype=jnp.bfloat16, weight_quant='int8'),
-        'attention.bwd_full': functools.partial(
-            _bwd_spec, 'attention.bwd_full', 'full'),
-        'attention.fwd_ring': functools.partial(
-            _fwd_spec, 'attention.fwd_ring', 'online'),
-        'attention.fwd_ulysses': functools.partial(
-            _fwd_spec, 'attention.fwd_ulysses', 'ulysses'),
-        'decode.seq_parallel_step': seq_parallel_step,
-        'decode.seq_parallel_step_bf16': functools.partial(
-            seq_parallel_step, 'decode.seq_parallel_step_bf16',
-            dtype=jnp.bfloat16),
-    }

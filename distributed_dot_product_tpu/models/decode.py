@@ -43,7 +43,6 @@ Performance note: jit your step with the cache DONATED
 copies the whole K/V buffer pair first.
 """
 
-import contextlib
 import math
 import zlib
 from typing import NamedTuple, Optional
@@ -54,8 +53,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
+from distributed_dot_product_tpu.utils.scopes import device_scope
+from distributed_dot_product_tpu.utils.trace_sinks import TraceSinks
 
 __all__ = ['DecodeCache', 'init_cache', 'append_kv', 'append_kv_sharded',
            'decode_attention', 'init_slot_cache', 'append_kv_slots',
@@ -1963,10 +1963,9 @@ def _put_layer(stacked, x, layer):
     return lax.dynamic_update_index_in_dim(stacked, x, layer, 0)
 
 
-_IMPL_SINKS = []        # lists of the active decode_impl_traces() blocks
+_IMPL_TRACES = TraceSinks()
 
 
-@contextlib.contextmanager
 def decode_impl_traces():
     """Collect what :func:`decode_step` resolves ``impl`` to while the
     block runs: one dict ``{'requested', 'resolved', 'reason',
@@ -1990,12 +1989,7 @@ def decode_impl_traces():
             step.lower(*args).compile()
         assert {t['resolved'] for t in traces} == {'kernel'}
     """
-    sink = []
-    _IMPL_SINKS.append(sink)
-    try:
-        yield sink
-    finally:
-        _IMPL_SINKS[:] = [s for s in _IMPL_SINKS if s is not sink]
+    return _IMPL_TRACES.open()
 
 
 def _kernel_step(q, cache, qk_quant):
@@ -2052,7 +2046,7 @@ def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
     # The step is reported where the caller says what it scores with:
     # decode_step does; a bare probe of the resolution has no queries.
     step = None
-    if resolved == 'kernel' and q is not None and _IMPL_SINKS:
+    if resolved == 'kernel' and q is not None and _IMPL_TRACES:
         step = _kernel_step(q, cache, qk_quant)
     kind = ('ring' if isinstance(cache, RingCache)
             else 'stacked' if stacked else 'layer')
@@ -2064,10 +2058,9 @@ def record_decode_impl(requested, resolved, reason, cache, step=None):
     """Tell the open :func:`decode_impl_traces` blocks what one traced
     decode step resolved to (this module's, and the latent cache's in
     ``models/latent.py``)."""
-    for sink in _IMPL_SINKS:
-        sink.append({'requested': requested or 'auto',
-                     'resolved': resolved, 'reason': reason,
-                     'cache': cache, 'step': step})
+    _IMPL_TRACES.note({'requested': requested or 'auto',
+                       'resolved': resolved, 'reason': reason,
+                       'cache': cache, 'step': step})
 
 
 def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
@@ -2432,245 +2425,6 @@ def _flash_merge(partials, axis_name, out_dtype):
     num = lax.psum(num * corr, axis_name)
     den = lax.psum(l * corr, axis_name)
     return (num / jnp.where(den == 0.0, 1.0, den)).astype(out_dtype)
-
-
-def graphlint_entrypoints():
-    """Static-analysis registration hook (analysis/registry.py): the
-    decode steps at the shapes where the contracts bite — bf16 caches
-    (cache-upcast/f32-accum), the int8 mirror through the fused kernel
-    (int32 accumulation + pallas input_output_aliases), and the
-    sequence-sharded slab (collective axes + aliasing across the
-    shard_map boundary). Builders are lazy: the registry only pays for
-    construction when the linter runs."""
-    from functools import partial
-
-    def step_xla_slots():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        b, h, t, d = 2, 2, 32, 8
-        cache = init_slot_cache(b, h, t, d, dtype=jnp.bfloat16)
-        new = jnp.zeros((b, h, 1, d), jnp.bfloat16)
-        return TraceSpec(
-            name='decode.step_xla_slots',
-            fn=partial(decode_step, impl='xla'),
-            args=(new, cache, new, new),
-            cache_in=lambda a: [a[1].k, a[1].v],
-            cache_out=lambda o: [o[0].k, o[0].v],
-            expect_donation=True, donate_argnums=(1,), min_donated=2)
-
-    def step_kernel_int8():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        b, h, t, d = 1, 2, 64, 8
-        cache = init_cache(b, h, t, d, dtype=jnp.bfloat16,
-                           qk_quant='int8')
-        new = jnp.zeros((b, h, 1, d), jnp.bfloat16)
-        return TraceSpec(
-            name='decode.step_kernel_int8',
-            fn=partial(decode_step, impl='kernel', qk_quant='int8',
-                       interpret=True),
-            args=(new, cache, new, new),
-            cache_in=lambda a: [a[1].k, a[1].v, a[1].k_q, a[1].k_scale],
-            cache_out=lambda o: [o[0].k, o[0].v, o[0].k_q,
-                                 o[0].k_scale],
-            expect_donation=True, donate_argnums=(1,), min_donated=4)
-
-    def step_sharded():
-        from jax.sharding import PartitionSpec as P
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        from distributed_dot_product_tpu.parallel.mesh import seq_mesh
-        mesh = seq_mesh(2)
-        b, h, t, d = 1, 2, 64, 8          # t is the GLOBAL capacity
-        cache = init_cache(b, h, t, d, dtype=jnp.bfloat16)
-        new = jnp.zeros((b, h, 1, d), jnp.bfloat16)
-        spec4 = P(None, None, SEQ_AXIS, None)
-        cache_spec = DecodeCache(k=spec4, v=spec4, length=P(),
-                                 k_q=None, k_scale=None)
-        step = jax.shard_map(
-            partial(decode_step, impl='xla', axis_name=SEQ_AXIS),
-            mesh=mesh, in_specs=(P(), cache_spec, P(), P()),
-            out_specs=(cache_spec, P()), check_vma=False)
-        return TraceSpec(
-            name='decode.step_sharded', fn=step,
-            args=(new, cache, new, new), mesh_axes=(SEQ_AXIS,),
-            cache_in=lambda a: [a[1].k, a[1].v],
-            cache_out=lambda o: [o[0].k, o[0].v],
-            expect_donation=True, donate_argnums=(1,), min_donated=2)
-
-    def _paged_args(qk_quant=None):
-        b, h, d = 2, 2, 8
-        cache = init_paged_cache(b, h, 32, d, pages=6, page_size=8,
-                                 dtype=jnp.bfloat16, qk_quant=qk_quant)
-        # A realistic mid-serve table: slot 0 holds two pages (fill 10),
-        # slot 1 one page (fill 3); pool page 3 stays free.
-        cache = cache._replace(
-            page_table=jnp.array([[0, 1, -1, -1], [2, -1, -1, -1]],
-                                 jnp.int32),
-            length=jnp.array([10, 3], jnp.int32))
-        new = jnp.zeros((b, h, 1, d), jnp.bfloat16)
-        return cache, new
-
-    def step_paged_xla():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        cache, new = _paged_args()
-        return TraceSpec(
-            name='decode.step_paged_xla',
-            fn=partial(decode_step, impl='xla'),
-            args=(new, cache, new, new),
-            cache_in=lambda a: [a[1].k_pool, a[1].v_pool],
-            cache_out=lambda o: [o[0].k_pool, o[0].v_pool],
-            expect_donation=True, donate_argnums=(1,), min_donated=2)
-
-    def step_paged_kernel():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        cache, new = _paged_args()
-        return TraceSpec(
-            name='decode.step_paged_kernel',
-            fn=partial(decode_step, impl='kernel', interpret=True),
-            args=(new, cache, new, new),
-            cache_in=lambda a: [a[1].k_pool, a[1].v_pool],
-            cache_out=lambda o: [o[0].k_pool, o[0].v_pool],
-            expect_donation=True, donate_argnums=(1,), min_donated=2)
-
-    def step_paged_kernel_int8():
-        # The tentpole composition: quantized decode ON the page pool
-        # through the fused kernel — the mirror POOLS must alias in
-        # place alongside the bf16 pools (4 aliased pairs), and every
-        # int8 dot must request its i32 accumulator.
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        cache, new = _paged_args(qk_quant='int8')
-        return TraceSpec(
-            name='decode.step_paged_kernel_int8',
-            fn=partial(decode_step, impl='kernel', qk_quant='int8',
-                       interpret=True),
-            args=(new, cache, new, new),
-            cache_in=lambda a: [a[1].k_pool, a[1].v_pool,
-                                a[1].k_q_pool, a[1].k_scale_pool],
-            cache_out=lambda o: [o[0].k_pool, o[0].v_pool,
-                                 o[0].k_q_pool, o[0].k_scale_pool],
-            expect_donation=True, donate_argnums=(1,), min_donated=4)
-
-    def _sharded_paged_args():
-        # Two shards over a pps=4 table (each owns 2 ordinals); a
-        # mid-serve fill: slot 0 holds 10 rows (ordinals 0-1, both
-        # shard 0's), slot 1 holds 3 (ordinal 0 → shard 0's page 2).
-        b, h, d = 2, 2, 8
-        cache = init_sharded_paged_cache(2, b, h, 32, d,
-                                         pages_per_shard=3, page_size=8,
-                                         dtype=jnp.bfloat16)
-        pt = np.full((2, b, 4), -1, np.int32)
-        pt[0, 0, 0] = 0
-        pt[0, 0, 1] = 1
-        pt[0, 1, 0] = 2
-        cache = cache._replace(page_table=jnp.asarray(pt),
-                               length=jnp.array([10, 3], jnp.int32))
-        new = jnp.zeros((b, h, 1, d), jnp.bfloat16)
-        return cache, new
-
-    def _sharded_paged_spec(impl):
-        from jax.sharding import PartitionSpec as P
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        from distributed_dot_product_tpu.parallel.mesh import seq_mesh
-        mesh = seq_mesh(2)
-        cache, new = _sharded_paged_args()
-        cache_spec = PagedDecodeCache(
-            k_pool=P(SEQ_AXIS), v_pool=P(SEQ_AXIS),
-            page_table=P(SEQ_AXIS), length=P(),
-            k_q_pool=None, k_scale_pool=None)
-
-        def body(qq, cc, kk, vv):
-            # Each member squeezes its (1, slots, pps) table block into
-            # the local view and runs the paged ring-decode step; the
-            # merged output is replicated by the psum/pmax rule.
-            local = cc._replace(page_table=cc.page_table[0])
-            out_cache, out = decode_step(
-                qq, local, kk, vv, impl=impl, axis_name=SEQ_AXIS,
-                **({'interpret': True} if impl == 'kernel' else {}))
-            return (out_cache._replace(
-                page_table=out_cache.page_table[None]), out)
-
-        step = jax.shard_map(
-            body, mesh=mesh, in_specs=(P(), cache_spec, P(), P()),
-            out_specs=(cache_spec, P()), check_vma=False)
-        suffix = '_kernel' if impl == 'kernel' else ''
-        return TraceSpec(
-            name=f'decode.step_paged_sharded{suffix}', fn=step,
-            args=(new, cache, new, new), mesh_axes=(SEQ_AXIS,),
-            cache_in=lambda a: [a[1].k_pool, a[1].v_pool],
-            cache_out=lambda o: [o[0].k_pool, o[0].v_pool],
-            expect_donation=True, donate_argnums=(1,), min_donated=2)
-
-    def step_paged_sharded():
-        # The paged ring-decode step (XLA formulation): the stacked
-        # sharded cache through shard_map — collective-axis and
-        # cache-alias rules must hold across the flash merge.
-        return _sharded_paged_spec('xla')
-
-    def step_paged_sharded_kernel():
-        # Same program on the fused kernel path: per-shard Pallas
-        # partials + the cross-shard pmax/psum merge, cache aliased in
-        # place per shard.
-        return _sharded_paged_spec('kernel')
-
-    def step_verify_slab():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        b, h, t, d, k = 2, 2, 32, 8, 3
-        cache = init_slot_cache(b, h, t, d, dtype=jnp.bfloat16)
-        cache = cache._replace(length=jnp.array([5, 9], jnp.int32))
-        q = jnp.zeros((b, h, k, d), jnp.bfloat16)
-        counts = jnp.array([3, 1], jnp.int32)   # mixed spec/non-spec
-        return TraceSpec(
-            name='decode.step_verify_slab',
-            fn=partial(decode_step, impl='kernel', interpret=True,
-                       counts=counts),
-            args=(q, cache, q, q),
-            cache_in=lambda a: [a[1].k, a[1].v],
-            cache_out=lambda o: [o[0].k, o[0].v],
-            expect_donation=True, donate_argnums=(1,), min_donated=2)
-
-    def step_verify_paged():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        cache, _ = _paged_args()
-        k = 3
-        q = jnp.zeros((2, 2, k, 8), jnp.bfloat16)
-        counts = jnp.array([3, 2], jnp.int32)
-        return TraceSpec(
-            name='decode.step_verify_paged',
-            fn=partial(decode_step, impl='kernel', interpret=True,
-                       counts=counts),
-            args=(q, cache, q, q),
-            cache_in=lambda a: [a[1].k_pool, a[1].v_pool],
-            cache_out=lambda o: [o[0].k_pool, o[0].v_pool],
-            expect_donation=True, donate_argnums=(1,), min_donated=2)
-
-    return {
-        'decode.step_xla_slots': step_xla_slots,
-        'decode.step_kernel_int8': step_kernel_int8,
-        'decode.step_sharded': step_sharded,
-        'decode.step_paged_xla': step_paged_xla,
-        'decode.step_paged_kernel': step_paged_kernel,
-        'decode.step_paged_kernel_int8': step_paged_kernel_int8,
-        'decode.step_paged_sharded': step_paged_sharded,
-        'decode.step_paged_sharded_kernel': step_paged_sharded_kernel,
-        'decode.step_verify_slab': step_verify_slab,
-        'decode.step_verify_paged': step_verify_paged,
-    }
 
 
 def decode_attention(q, cache: DecodeCache, *, scale=None, window=None,

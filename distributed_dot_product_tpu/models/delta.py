@@ -52,7 +52,6 @@ A)^-1``, ONE batched triangular solve over every chunk — are taken
 before the scan over chunks, whose body is matmuls alone.
 """
 
-import contextlib
 import math
 from typing import Any, Optional
 
@@ -63,18 +62,18 @@ from jax import lax
 
 from distributed_dot_product_tpu.models.decode import StateCache
 from distributed_dot_product_tpu.models.dense import OwnedDense
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_delta import (
     delta_step as delta_step_kernel, delta_step_reference, heads_tile,
 )
+from distributed_dot_product_tpu.utils.scopes import device_scope
+from distributed_dot_product_tpu.utils.trace_sinks import TraceSinks
 
 __all__ = ['GatedDeltaMixer', 'chunked_delta', 'delta_step',
            'delta_step_traces']
 
-_STEP_SINKS = []        # lists of the active delta_step_traces() blocks
+_STEP_TRACES = TraceSinks()
 
 
-@contextlib.contextmanager
 def delta_step_traces():
     """Collect which form each :class:`GatedDeltaMixer`'s decode step
     takes while the block runs: one dict ``{'form', 'tile', 'chunk'}``
@@ -88,12 +87,7 @@ def delta_step_traces():
             step.lower(*args).compile()
         assert {t['form'] for t in traces} == {'pallas'}
     """
-    sink = []
-    _STEP_SINKS.append(sink)
-    try:
-        yield sink
-    finally:
-        _STEP_SINKS[:] = [s for s in _STEP_SINKS if s is not sink]
+    return _STEP_TRACES.open()
 
 
 def step_form(impl):
@@ -295,11 +289,10 @@ class GatedDeltaMixer(nn.Module):
     def decode(self, h, cache):
         """One token ``h (B, 1, dim)``: ``(cache, out)``."""
         impl = step_form(self.step_impl)
-        for sink in _STEP_SINKS:
-            sink.append({'form': impl, 'chunk': self.chunk,
-                         'tile': heads_tile(self.heads, self.head_dim,
-                                            self.head_dim)
-                         if impl == 'pallas' else None})
+        _STEP_TRACES.note({'form': impl, 'chunk': self.chunk,
+                           'tile': heads_tile(self.heads, self.head_dim,
+                                              self.head_dim)
+                           if impl == 'pallas' else None})
         with device_scope('lm.delta_proj'):
             q, k, v, log_a, beta, z, window = self._split(h, cache.conv)
         with device_scope('ops.delta_step'):

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_dot_product_tpu.obs.spans import device_scope
+from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['HyperConnection', 'mix_back', 'sinkhorn']
 

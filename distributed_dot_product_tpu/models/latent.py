@@ -47,7 +47,6 @@ from distributed_dot_product_tpu.models.decode import (
     _take_layer, record_decode_impl,
 )
 from distributed_dot_product_tpu.models.dense import OwnedDense
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_attention import (
     flash_attention,
 )
@@ -57,6 +56,7 @@ from distributed_dot_product_tpu.ops.pallas_decode import (
 from distributed_dot_product_tpu.ops.rope import (
     rope_interleaved, yarn_inv_freq,
 )
+from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['LatentCache', 'init_latent_cache', 'insert_session',
            'LatentAttention']

@@ -30,7 +30,6 @@ TPU-first notes:
   so sampling loops (greedy here; any sampler outside) stay trivial.
 """
 
-import contextlib
 import dataclasses
 import functools
 import warnings
@@ -44,9 +43,11 @@ import jax.numpy as jnp
 from distributed_dot_product_tpu.models.transformer import (
     TransformerStack, make_norm,
 )
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_head import head_grad, head_tiles
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
+from distributed_dot_product_tpu.utils.retrace import watch_traces
+from distributed_dot_product_tpu.utils.scopes import device_scope
+from distributed_dot_product_tpu.utils.trace_sinks import TraceSinks
 
 __all__ = ['TransformerLM', 'greedy_generate', 'head_loss_traces',
            'lm_targets']
@@ -293,10 +294,9 @@ class TransformerLM(nn.Module):
         return caches, self._head(x)
 
 
-_HEAD_SINKS = []        # lists of the open head_loss_traces() blocks
+_HEAD_TRACES = TraceSinks()
 
 
-@contextlib.contextmanager
 def head_loss_traces():
     """Collect which route each :func:`head_loss` takes while the block
     runs: one dict per TRACE of its scan — ``route`` (``'kernel'``: a
@@ -310,12 +310,7 @@ def head_loss_traces():
             step.lower(*args).compile()
         assert [t['route'] for t in traces] == ['kernel']
     """
-    sink = []
-    _HEAD_SINKS.append(sink)
-    try:
-        yield sink
-    finally:
-        _HEAD_SINKS[:] = [s for s in _HEAD_SINKS if s is not sink]
+    return _HEAD_TRACES.open()
 
 
 def _head_route(rows, dim, vocab, dtype, with_grad):
@@ -325,11 +320,10 @@ def _head_route(rows, dim, vocab, dtype, with_grad):
     tiles, why = None, 'not differentiated: the logits product alone'
     if with_grad:
         tiles, why = head_tiles(rows, dim, vocab, dtype)
-    for sink in _HEAD_SINKS:
-        sink.append({'route': 'kernel' if tiles else 'xla', 'rows': rows,
-                     **(tiles or dict.fromkeys(
-                         ('row_group', 'vocab_tile', 'row_tile'))),
-                     'why': why})
+    _HEAD_TRACES.note({'route': 'kernel' if tiles else 'xla', 'rows': rows,
+                       **(tiles or dict.fromkeys(
+                           ('row_group', 'vocab_tile', 'row_tile'))),
+                       'why': why})
     return tiles
 
 
@@ -468,10 +462,6 @@ _GENERATE_WARNED_UNHASHABLE = False
 
 
 def _build_generate_programs(model, donate):
-    from distributed_dot_product_tpu.analysis.retrace import (
-        watch_traces,
-    )
-
     def prefill_fn(p, tok, c):
         return model.apply(p, tok, c, method='prefill')
 
@@ -562,67 +552,3 @@ def greedy_generate(model, params, prompt, steps, t_max, donate=True):
         caches, tok = step(params, tok, caches)
         out.append(tok)
     return jnp.concatenate(out, axis=1)
-
-
-def graphlint_entrypoints():
-    """Static-analysis registration hook (analysis/registry.py): the LM
-    head at bf16 — its einsum's explicit fp32 accumulation IS the PR-3
-    contract the f32-accum rule encodes — and the chunked token-mean
-    loss (nll_sum) whose scan must keep its logsumexp math in f32,
-    registered at f32 AND at the bf16 serving dtype. The loss entries
-    trace the loss un-differentiated AND its gradient: a rule reads the
-    primal through :func:`head_loss`'s ``custom_vjp`` call but not its
-    forward rule (a callable, traced only under differentiation), and
-    that rule holds the loss's other two matmuls. The projections are
-    the owned dense (models/dense.py), so the bf16 entry traces with
-    zero f32-accum waivers."""
-
-    def head_bf16():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        model = TransformerLM(
-            vocab_size=32, dim=16, num_heads=2, n_layers=1,
-            dtype=jnp.bfloat16,
-            attn_kwargs={'distributed': False})
-        tokens = jnp.zeros((1, 8), jnp.int32)
-        params = model.init(jax.random.key(0), tokens)
-        x = jax.ShapeDtypeStruct((1, 8, 16), jnp.bfloat16)
-
-        def fn(p, h):
-            return model.apply(p, h, method='_head')
-
-        return TraceSpec(name='lm.head_bf16', fn=fn, args=(params, x))
-
-    def loss_f32(name='lm.loss_f32', dtype=None):
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        kw = {} if dtype is None else {'dtype': dtype}
-        model = TransformerLM(
-            vocab_size=32, dim=16, num_heads=2, n_layers=1,
-            attn_kwargs={'distributed': False}, **kw)
-        tokens = jnp.zeros((1, 16), jnp.int32)
-        params = model.init(jax.random.key(0), tokens)
-        targets = jax.ShapeDtypeStruct((1, 16), jnp.int32)
-
-        def fn(p, tok, tgt):
-            def nll(p):
-                return model.apply(p, tok, tgt, chunk=4, method='nll_sum')
-
-            return nll(p), jax.grad(nll, has_aux=True)(p)[0]
-
-        return TraceSpec(name=name, fn=fn,
-                         args=(params, jax.ShapeDtypeStruct(
-                             (1, 16), jnp.int32), targets))
-
-    def loss_bf16():
-        # The full LM loss at SERVING dtype: the chunked-logsumexp f32
-        # math, the head contract AND the owned-dense projection
-        # accumulation are all enforced on the bf16 program — no
-        # waivers (the flax-Dense debt this entry used to carry is
-        # retired; the gate asserts zero waived records stay that way).
-        return loss_f32(name='lm.loss_bf16', dtype=jnp.bfloat16)
-
-    return {'lm.head_bf16': head_bf16, 'lm.loss_f32': loss_f32,
-            'lm.loss_bf16': loss_bf16}

@@ -77,7 +77,6 @@ through the batched matmuls over every held expert
 (``hit_experts_reference``).
 """
 
-import contextlib
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -89,10 +88,11 @@ from distributed_dot_product_tpu.models.dense import OwnedDense
 from distributed_dot_product_tpu.models.remat import (
     LAYER_MATMUL_NAMES, named,
 )
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_experts import (
     HIT_LIST_ROWS, hidden_tile, hit_experts, hit_list,
 )
+from distributed_dot_product_tpu.utils.scopes import device_scope
+from distributed_dot_product_tpu.utils.trace_sinks import TraceSinks
 
 __all__ = ['GatedMLP', 'PlainMLP', 'SparseExperts', 'expert_route_traces']
 
@@ -100,10 +100,9 @@ ACTIVATIONS = {'silu': nn.silu,
                'relu2': lambda x: jnp.square(nn.relu(x))}
 
 
-_ROUTE_SINKS = []       # lists of the active expert_route_traces() blocks
+_ROUTE_TRACES = TraceSinks()
 
 
-@contextlib.contextmanager
 def expert_route_traces():
     """Collect which route each :class:`SparseExperts` call takes while
     the block runs: one dict ``{'route', 'n', 'bound', 'bound_by',
@@ -120,12 +119,7 @@ def expert_route_traces():
             step.lower(*args).compile()
         assert {t['route'] for t in traces} == {'hit_list'}
     """
-    sink = []
-    _ROUTE_SINKS.append(sink)
-    try:
-        yield sink
-    finally:
-        _ROUTE_SINKS[:] = [s for s in _ROUTE_SINKS if s is not sink]
+    return _ROUTE_TRACES.open()
 
 
 class GatedMLP(nn.Module):
@@ -296,11 +290,10 @@ class SparseExperts(nn.Module):
 
         tile = hidden_tile(wide, self.hidden, 2 + gated,
                            w_up.dtype.itemsize) if hit_route else None
-        for sink in _ROUTE_SINKS:
-            sink.append({'route': 'hit_list' if hit_route else 'sorted',
-                         'n': n, 'bound': bound,
-                         'bound_by': 'rule' if by_rule else 'caller',
-                         'tile': tile})
+        _ROUTE_TRACES.note({'route': 'hit_list' if hit_route else 'sorted',
+                            'n': n, 'bound': bound,
+                            'bound_by': 'rule' if by_rule else 'caller',
+                            'tile': tile})
         if hit_route:
             y = self._hit_list(tokens, picked, gates, counts, w_gate, w_up,
                                w_down, act, lo, hi)

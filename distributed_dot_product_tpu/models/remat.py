@@ -18,6 +18,7 @@ from distributed_dot_product_tpu.models.dense import dense_param_bytes
 from distributed_dot_product_tpu.ops.pallas_attention import (
     FLASH_QKV_NAME, FLASH_RESIDUAL_NAMES,
 )
+from distributed_dot_product_tpu.utils.trace_sinks import TraceSinks
 
 __all__ = ['LAYER_MATMUL_NAMES', 'KeepWhatFits', 'named', 'note_named',
            'remat_traces', 'step_holds']
@@ -52,7 +53,7 @@ _LAYER_WORK = 2.8       # a rebuilt layer and its cotangents, in named bytes
 _DESCRIBED_LIMITS = {'TPU v5 lite': 16909336064}
 
 _OPEN = []              # the open step_holds() accounts, innermost last
-_REMAT_SINKS = []       # lists of the open remat_traces() blocks
+_REMAT_TRACES = TraceSinks()
 
 
 def device_bytes_limit(device):
@@ -121,7 +122,6 @@ def new_layer():
         account.named.clear()
 
 
-@contextlib.contextmanager
 def remat_traces():
     """Collect what each rematted ``TransformerStack`` keeps while the
     block runs: one dict per trace of a stack's layer scan under the
@@ -138,12 +138,7 @@ def remat_traces():
             step.lower(*args).compile()
         assert traces[0]['first_refused'] is None
     """
-    sink = []
-    _REMAT_SINKS.append(sink)
-    try:
-        yield sink
-    finally:
-        _REMAT_SINKS[:] = [s for s in _REMAT_SINKS if s is not sink]
+    return _REMAT_TRACES.open()
 
 
 class KeepWhatFits:
@@ -218,8 +213,7 @@ class KeepWhatFits:
     def __call__(self, prim, *avals, **params):
         if self.policy is None:
             kept, record = self.fit()
-            for sink in _REMAT_SINKS:
-                sink.append(record)
+            _REMAT_TRACES.note(record)
             self.policy = jax.checkpoint_policies.save_only_these_names(
                 *FLASH_RESIDUAL_NAMES, *kept)
         return self.policy(prim, *avals, **params)

@@ -34,7 +34,7 @@ from jax import lax
 
 from distributed_dot_product_tpu.models.decode import StateCache
 from distributed_dot_product_tpu.models.dense import OwnedDense
-from distributed_dot_product_tpu.obs.spans import device_scope
+from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['Mamba2Mixer', 'chunked_scan', 'state_step']
 

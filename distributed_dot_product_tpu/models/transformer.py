@@ -52,8 +52,8 @@ from distributed_dot_product_tpu.models.remat import (
     LAYER_MATMUL_NAMES, KeepWhatFits, named, new_layer,
 )
 from distributed_dot_product_tpu.models.ssm import Mamba2Mixer
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
+from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['TransformerBlock', 'TransformerStack']
 

@@ -21,10 +21,6 @@ functions.py:24-41) and the in-process ``MetricsRegistry``
 - :mod:`~distributed_dot_product_tpu.obs.exporter` — Prometheus-text
   rendering of the metrics registry plus the optional ``/metrics`` +
   ``/healthz`` + ``/profile`` HTTP thread (off by default).
-- :mod:`~distributed_dot_product_tpu.obs.perf` — compiled-program
-  cost/roofline accounting over the analysis registry and the
-  perf-regression gate (``python -m distributed_dot_product_tpu.obs.
-  perf {snapshot,check,report}``; scripts/ci.sh stage [5/5]).
 - :mod:`~distributed_dot_product_tpu.obs.devmon` — live device-memory
   telemetry gauges and guarded on-demand ``jax.profiler`` captures.
 - :mod:`~distributed_dot_product_tpu.obs.flight` — the incident flight
@@ -91,42 +87,3 @@ __all__ = [
     'AnomalyWatchdog', 'EwmaZScore', 'RateOfChange', 'StaticThreshold',
     'Watch', 'default_watches',
 ]
-
-
-def graphlint_entrypoints():
-    """Static-analysis registration hook (analysis/registry.py): trace
-    the serving engine's decode program THROUGH a host-side span — the
-    supported composition — and require the cache-alias / precision
-    contracts to hold unchanged. A span that leaked ops or constants
-    into the traced program (the clock-in-jit hazard the AST rule
-    rejects in jitted bodies) would surface here as a rule violation or
-    a jaxpr diff against the engine's own entry."""
-
-    def spanned_decode():
-        import jax.numpy as jnp
-
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        from distributed_dot_product_tpu.obs.spans import span
-        from distributed_dot_product_tpu.serve.engine import KernelEngine
-
-        eng = KernelEngine(slots=2, t_max=16, decode_impl='xla')
-        tokens = jnp.zeros((2,), jnp.int32)
-        active = jnp.ones((2,), bool)
-        poison = jnp.zeros((2,), bool)
-
-        def dispatch(cache, tokens, active, poison):
-            # The span wraps the dispatch from the HOST side; the traced
-            # body below it must come out identical to the unspanned
-            # engine entry (serve.engine_decode).
-            with span('obs.decode_dispatch'):
-                return eng._decode_impl(cache, tokens, active, poison)
-
-        return TraceSpec(
-            name='obs.spanned_decode', fn=dispatch,
-            args=(eng.cache, tokens, active, poison),
-            cache_in=lambda a: [a[0].k, a[0].v],
-            cache_out=lambda o: [o[0].k, o[0].v])
-
-    return {'obs.spanned_decode': spanned_decode}

@@ -1,7 +1,6 @@
 # -*- coding: utf-8 -*-
 """
-Live device telemetry and on-demand profiler capture — the runtime half
-of the perf observatory (obs/perf.py is the static, compiler half).
+Live device telemetry and on-demand profiler capture.
 
 Two pieces:
 

@@ -162,13 +162,10 @@ EVENT_SCHEMA = {
     'health.readiness': ('state',),
     # -- fault injection (utils/faults.py) -----------------------------
     'fault.inject': ('kind',),
-    # -- perf observatory (obs/perf.py, obs/devmon.py) -----------------
+    # -- live device telemetry (obs/devmon.py) -------------------------
     # One bounded jax.profiler capture began (manual /profile hit or
     # the scheduler's adaptive ttft-p99 trigger — `trigger` names it).
     'profile.capture': ('trigger', 'seconds', 'path'),
-    # `perf check` found a per-entry tolerance violation against the
-    # committed baseline (entry = registry name, metric = which gate).
-    'perf.regression': ('entry', 'metric'),
     # Dispatch-floor accounting: one record per decode tick that ran a
     # device program. `tick_seconds` is the REAL wall time of the whole
     # scheduler tick body, `device_seconds` the slice spent inside

@@ -21,8 +21,7 @@ derivation:
   in aggregate. Goodput % = met / submitted.
 - :func:`check_baseline`: the CI gate — compare a report against a
   committed ``SLO_BASELINE.json`` with tolerances, emitting
-  ``slo.violation`` events into the active log, exactly mirroring the
-  ``perf check`` gate (obs/perf.py).
+  ``slo.violation`` events into the active log.
 
 CLI (``python -m distributed_dot_product_tpu.obs slo ...``)::
 
@@ -192,9 +191,9 @@ def goodput(source, spec: SloSpec) -> SloReport:
 # -- the regression gate ------------------------------------------------
 
 DEFAULT_TOLERANCES = {
-    # Generous CPU tolerances (mirroring the PERF_BASELINE convention):
-    # the virtual clock makes a clean rerun EXACTLY reproducible, so
-    # these absorb intentional small config drift, not noise.
+    # Generous tolerances: the virtual clock makes a clean rerun EXACTLY
+    # reproducible, so these absorb intentional small config drift, not
+    # noise.
     'goodput_abs': 10.0,          # percentage points, aggregate
     'tenant_goodput_abs': 15.0,   # percentage points, per tenant
 }
@@ -227,7 +226,7 @@ def check_baseline(report: SloReport, baseline: dict, *,
     """Gate ``report`` against a committed baseline; returns violation
     strings (empty = pass). Every violation names the metric (and the
     tenant, when per-tenant) and also lands in the active event log as
-    an ``slo.violation`` — same discipline as ``perf check``."""
+    an ``slo.violation``."""
     violations = []
 
     def _flag(metric, msg, tenant=None, cur=None, base=None):

@@ -322,47 +322,3 @@ def distributed_matmul_all_global(left, right, offset=32, mesh=None,
                  **kw)
     return _shard_mapped(fn, mesh, (left.ndim, right.ndim), left.ndim,
                          mesh_axis)(left, right)
-
-
-def graphlint_entrypoints():
-    """Static-analysis registration hook (analysis/registry.py): the
-    distributed matmuls — forward AND the custom-vjp backward, whose
-    kernels are defined in terms of the other two ops — under a real
-    2-device mesh, so the collective-axis rule sees the all_gather /
-    ppermute / psum_scatter traffic of both comm impls."""
-
-    def _grad_spec(impl):
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        from distributed_dot_product_tpu.ops.ops import (
-            matmul_all, matmul_nt,
-        )
-        from distributed_dot_product_tpu.parallel.mesh import seq_mesh
-        mesh = seq_mesh(2)
-
-        def body(a, b):
-            scores = matmul_nt(a, b, 2, impl=impl)     # (B, T/N, T)
-            return matmul_all(scores, b, 2, impl=impl)
-
-        sharded = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(None, SEQ_AXIS, None), P(None, SEQ_AXIS, None)),
-            out_specs=P(None, SEQ_AXIS, None), check_vma=False)
-
-        def loss(a, b):
-            return jnp.sum(sharded(a, b).astype(jnp.float32))
-
-        a = jax.ShapeDtypeStruct((1, 8, 4), jnp.float32)
-        return TraceSpec(name=f'ops.matmul_grad_{impl}',
-                         fn=jax.grad(loss, argnums=(0, 1)),
-                         args=(a, a), mesh_axes=(SEQ_AXIS,))
-
-    from functools import partial as _partial
-    return {
-        'ops.matmul_grad_allgather': _partial(_grad_spec, 'allgather'),
-        'ops.matmul_grad_ring': _partial(_grad_spec, 'ring'),
-    }

@@ -60,7 +60,6 @@ Pallas interpreter mode, so the identical code paths are covered by the
 regular test suite.
 """
 
-import contextlib
 import functools
 import math
 
@@ -73,7 +72,8 @@ from jax.experimental import pallas as pl
 # VMEM scratch on CPU.
 from jax.experimental.pallas import tpu as pltpu
 
-from distributed_dot_product_tpu.obs.spans import device_scope
+from distributed_dot_product_tpu.utils.scopes import device_scope
+from distributed_dot_product_tpu.utils.trace_sinks import TraceSinks
 
 __all__ = ['flash_attention', 'flash_bwd_traces', 'flash_block_traces',
            'FLASH_RESIDUAL_NAMES', 'FLASH_QKV_NAME']
@@ -1039,7 +1039,7 @@ def _pallas_call(name, kernel, grid, in_specs, out_specs, scratch,
 
     ``name`` is the kernel's stable name in a device trace (a plain
     identifier: Mosaic takes it as a symbol); the call runs under the
-    :func:`~distributed_dot_product_tpu.obs.spans.device_scope` of the
+    :func:`~distributed_dot_product_tpu.utils.scopes.device_scope` of the
     kernel's family (``_KERNEL_SCOPES``). ``vmem_limit_bytes``: the
     scoped-VMEM limit the call states (None: the compiler's default)."""
     prefetch = [p for p in prefetch if p is not None]
@@ -1436,10 +1436,9 @@ _FUSED_DQ_BYTES = 16 * 1024 * 1024
 # (_bwd_block_sizes): its streams and the (bq, bk) score temporaries.
 _BWD_VMEM_BASE = 16 * 1024 * 1024
 
-_BWD_SINKS = []         # lists of the active flash_bwd_traces() blocks
+_BWD_TRACES = TraceSinks()
 
 
-@contextlib.contextmanager
 def flash_bwd_traces():
     """Collect which form each flash backward takes while the block
     runs: one dict ``{'form', 'reason', 'only', 'dq_bytes',
@@ -1453,18 +1452,12 @@ def flash_bwd_traces():
             step.lower(*args).compile()
         assert {t['form'] for t in traces} == {'fused'}
     """
-    sink = []
-    _BWD_SINKS.append(sink)
-    try:
-        yield sink
-    finally:
-        _BWD_SINKS[:] = [s for s in _BWD_SINKS if s is not sink]
+    return _BWD_TRACES.open()
 
 
-_BLOCK_SINKS = []       # lists of the active flash_block_traces() blocks
+_BLOCK_TRACES = TraceSinks()
 
 
-@contextlib.contextmanager
 def flash_block_traces():
     """Collect what each flash kernel's blocks are by KIND while the
     block runs: one dict ``{'kernel', 'grid', 'run_blocks',
@@ -1486,12 +1479,7 @@ def flash_block_traces():
             step.lower(*args)
         assert traces[0]['interior_blocks'] == 120      # of 136
     """
-    sink = []
-    _BLOCK_SINKS.append(sink)
-    try:
-        yield sink
-    finally:
-        _BLOCK_SINKS[:] = [s for s in _BLOCK_SINKS if s is not sink]
+    return _BLOCK_TRACES.open()
 
 
 def _note_blocks(kernel, grid, walk, causal, causal_offset, kv_offset, bq,
@@ -1500,7 +1488,7 @@ def _note_blocks(kernel, grid, walk, causal, causal_offset, kv_offset, bq,
     ``walk(rel)`` gives the (Q block, K block) index arrays of the grid's
     programs at the static row − column offset ``rel``, counted by the
     kernels' own two predicates."""
-    if not _BLOCK_SINKS:
+    if not _BLOCK_TRACES:
         return
     has_mask, has_seg, has_pos, has_alibi, _ = flags
     data = has_mask or has_seg or has_pos
@@ -1521,10 +1509,9 @@ def _note_blocks(kernel, grid, walk, causal, causal_offset, kv_offset, bq,
     alibi = None
     if has_alibi:
         alibi = 'positions' if has_pos else 'vector'
-    for sink in _BLOCK_SINKS:
-        sink.append({'kernel': kernel, 'grid': grid,
-                     'run_blocks': run_blocks,
-                     'interior_blocks': interior_blocks, 'alibi': alibi})
+    _BLOCK_TRACES.note({'kernel': kernel, 'grid': grid,
+                        'run_blocks': run_blocks,
+                        'interior_blocks': interior_blocks, 'alibi': alibi})
 
 
 def _grid_walk(n_outer, n_inner, inner_lo=None):
@@ -1555,10 +1542,9 @@ def _bwd_form(only, tq_p, d, dq_dtype):
     else:
         vmem_limit = (_BWD_VMEM_BASE + dq_bytes
                       + 2 * tq_p * lanes * jnp.dtype(dq_dtype).itemsize)
-    for sink in _BWD_SINKS:
-        sink.append({'form': 'split' if reason else 'fused',
-                     'reason': reason, 'only': only, 'dq_bytes': dq_bytes,
-                     'vmem_limit_bytes': vmem_limit})
+    _BWD_TRACES.note({'form': 'split' if reason else 'fused',
+                      'reason': reason, 'only': only, 'dq_bytes': dq_bytes,
+                      'vmem_limit_bytes': vmem_limit})
     return reason is None, vmem_limit
 
 
@@ -2427,60 +2413,3 @@ def flash_attention(q, k, v, mask=None, *, causal=False, causal_offset=0,
                   pos_q, pos_k, alibi_slopes, dropout_seed, float(scale),
                   bool(causal), bool(interpret), softmax_mode, window,
                   qk_quant, dropout_rate, static_off)
-
-
-def graphlint_entrypoints():
-    """Static-analysis registration hook (analysis/registry.py): the
-    fused flash kernels at bf16 — THE paths whose fp32-accumulation
-    contract the f32-accum rule encodes (every in-kernel dot_general
-    must carry preferred_element_type=f32, int8 scoring i32). The
-    linter descends into the pallas_call jaxprs, so a regression inside
-    a kernel body is caught even though the kernel is one opaque
-    primitive to XLA."""
-    from functools import partial
-
-    def _sds(*shape, dtype='bfloat16'):
-        import jax
-        import jax.numpy as jnp
-        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
-
-    def fwd_bf16():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        q = _sds(1, 2, 16, 8)
-        return TraceSpec(name='ops.flash_fwd_bf16',
-                         fn=partial(flash_attention, causal=True),
-                         args=(q, q, q))
-
-    def bwd_bf16():
-        import jax
-        import jax.numpy as jnp
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, causal=True)
-            return jnp.sum(out.astype(jnp.float32))
-
-        q = _sds(1, 2, 16, 8)
-        return TraceSpec(name='ops.flash_bwd_bf16',
-                         fn=jax.grad(loss, argnums=(0, 1, 2)),
-                         args=(q, q, q))
-
-    def fwd_int8():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        q = _sds(1, 2, 16, 8)
-        return TraceSpec(name='ops.flash_fwd_int8',
-                         fn=partial(flash_attention, causal=True,
-                                    qk_quant='int8'),
-                         args=(q, q, q))
-
-    return {
-        'ops.flash_fwd_bf16': fwd_bf16,
-        'ops.flash_bwd_bf16': bwd_bf16,
-        'ops.flash_fwd_int8': fwd_int8,
-    }

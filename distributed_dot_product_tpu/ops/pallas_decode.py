@@ -87,10 +87,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.ops.pallas_attention import (
     _LOG2E, _NEG_BIG, _quantize_rows,
 )
+from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['flash_decode', 'decode_block_k', 'decode_geometry',
            'flash_decode_geometry', 'DecodeGeometry']

@@ -59,6 +59,7 @@ from distributed_dot_product_tpu.models.decode import (
 from distributed_dot_product_tpu.obs import spans as obs_spans
 from distributed_dot_product_tpu.obs.spans import span
 from distributed_dot_product_tpu.serve.errors import ServeContractError
+from distributed_dot_product_tpu.utils.retrace import watch_traces
 
 __all__ = ['KernelEngine', 'PageCorruptionError']
 
@@ -326,14 +327,11 @@ class KernelEngine:
         self.program_seconds = 0.0
         # Donated caches: appends write in place — see models/decode.py's
         # performance note. One compiled program each for the lifetime —
-        # and the retrace sentinel (analysis/retrace.py) enforces it:
+        # and the retrace sentinel (utils/retrace.py) enforces it:
         # shapes are fixed at construction, so more than budget traces
         # of one program means something un-cacheable leaked into the
         # step (the round-5 retrace-storm class). Budget 2: the real
         # trace plus one registry lowering / weak-type respin.
-        from distributed_dot_product_tpu.analysis.retrace import (
-            watch_traces,
-        )
         if self.cache_mode == 'paged' and self.kv_shards > 1:
             # Every kv_shards program is the SAME paged body the
             # unsharded engine runs, wrapped in ONE shard_map: each
@@ -699,9 +697,6 @@ class KernelEngine:
         exists for benchmarks sweeping k in-process)."""
         prog = self._verifies.get(w)
         if prog is None:
-            from distributed_dot_product_tpu.analysis.retrace import (
-                watch_traces,
-            )
             prog = self._verifies[w] = jax.jit(
                 watch_traces(self._verify_impl, f'engine.verify_w{w}',
                              budget=2),
@@ -766,9 +761,6 @@ class KernelEngine:
     def _rollback_program(self, span_rows):
         prog = self._rollbacks.get(span_rows)
         if prog is None:
-            from distributed_dot_product_tpu.analysis.retrace import (
-                watch_traces,
-            )
             if self.cache_mode == 'paged' and self.kv_shards > 1:
                 from jax.sharding import PartitionSpec as P
 
@@ -1210,9 +1202,6 @@ class KernelEngine:
     def _transfer_program(self, src_shape):
         prog = self._transfers.get(src_shape)
         if prog is None:
-            from distributed_dot_product_tpu.analysis.retrace import (
-                watch_traces,
-            )
             if self.kv_shards > 1:
                 # Shard-local handoff: source pages arrive as a
                 # shard-STACKED slab (kv_shards, width, ...) laid out
@@ -1595,98 +1584,3 @@ class KernelEngine:
         if self.kv_shards > 1:
             buf = jax.device_put(buf, self._pt_sharding)
         self.cache = self.cache._replace(k_pool=buf)
-
-
-def graphlint_entrypoints():
-    """Static-analysis registration hook (analysis/registry.py): the
-    serving engine's batched decode step — the program the continuous-
-    batching scheduler drives per tick — checked for real cache
-    donation/aliasing and surgical per-slot writes on the exact jitted
-    callable the engine holds."""
-
-    def engine_decode():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        eng = KernelEngine(slots=2, t_max=16, decode_impl='xla')
-        tokens = jnp.zeros((2,), jnp.int32)
-        active = jnp.ones((2,), bool)
-        poison = jnp.zeros((2,), bool)
-        return TraceSpec(
-            name='serve.engine_decode', fn=eng._decode,
-            args=(eng.cache, tokens, active, poison),
-            prejitted=True,
-            cache_in=lambda a: [a[0].k, a[0].v],
-            cache_out=lambda o: [o[0].k, o[0].v],
-            expect_donation=True, min_donated=2)
-
-    def engine_decode_paged():
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        eng = KernelEngine(slots=2, t_max=16, decode_impl='xla',
-                           cache_mode='paged', page_size=8, pages=3)
-        active = jnp.ones((2,), bool)
-        assert eng.prepare_step(np.ones(2, bool)).all()
-        tokens = jnp.zeros((2,), jnp.int32)
-        poison = jnp.zeros((2,), bool)
-        return TraceSpec(
-            name='serve.engine_decode_paged', fn=eng._decode,
-            args=(eng.cache, tokens, active, poison),
-            prejitted=True,
-            cache_in=lambda a: [a[0].k_pool, a[0].v_pool],
-            cache_out=lambda o: [o[0].k_pool, o[0].v_pool],
-            expect_donation=True, min_donated=2)
-
-    def engine_decode_wq8():
-        # The int8-WEIGHT serving program: same decode step, weights
-        # stored int8 — the s8×s8→s32 projection dots must request
-        # their i32 accumulator and the cache contracts must survive
-        # the precision change.
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        eng = KernelEngine(slots=2, t_max=16, decode_impl='xla',
-                           weight_quant='int8')
-        tokens = jnp.zeros((2,), jnp.int32)
-        active = jnp.ones((2,), bool)
-        poison = jnp.zeros((2,), bool)
-        return TraceSpec(
-            name='serve.engine_decode_wq8', fn=eng._decode,
-            args=(eng.cache, tokens, active, poison),
-            prejitted=True,
-            cache_in=lambda a: [a[0].k, a[0].v],
-            cache_out=lambda o: [o[0].k, o[0].v],
-            expect_donation=True, min_donated=2)
-
-    def engine_decode_kv_sharded():
-        # The cluster-scale long-context serving program: the SAME
-        # engine decode body shard_mapped over the seq mesh with the
-        # page table split 2 ways — cache aliasing must survive the
-        # shard_map boundary (donation of the stacked sharded pools)
-        # and the flash-partials merge must keep its collectives on
-        # the declared mesh axis.
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
-        eng = KernelEngine(slots=2, t_max=32, decode_impl='xla',
-                           cache_mode='paged', page_size=8, pages=3,
-                           kv_shards=2)
-        assert eng.prepare_step(np.ones(2, bool)).all()
-        eng._sync_page_table()
-        tokens = jnp.zeros((2,), jnp.int32)
-        active = jnp.ones((2,), bool)
-        poison = jnp.zeros((2,), bool)
-        return TraceSpec(
-            name='serve.engine_decode_kv_sharded', fn=eng._decode,
-            args=(eng.cache, tokens, active, poison),
-            prejitted=True, mesh_axes=(SEQ_AXIS,),
-            cache_in=lambda a: [a[0].k_pool, a[0].v_pool],
-            cache_out=lambda o: [o[0].k_pool, o[0].v_pool],
-            expect_donation=True, min_donated=2)
-
-    return {'serve.engine_decode': engine_decode,
-            'serve.engine_decode_paged': engine_decode_paged,
-            'serve.engine_decode_wq8': engine_decode_wq8,
-            'serve.engine_decode_kv_sharded': engine_decode_kv_sharded}

@@ -56,6 +56,7 @@ from distributed_dot_product_tpu.serve.scheduler import (
     Scheduler, ServeConfig,
 )
 from distributed_dot_product_tpu.utils import tracing
+from distributed_dot_product_tpu.utils.retrace import watch_traces
 
 __all__ = ['TopologyConfig', 'parse_topology', 'PrefixHandle',
            'PrefillPool', 'DecodeReplica', 'ReplicaPool',
@@ -183,9 +184,6 @@ class PrefillPool:
     def _kv_program(self, bucket):
         prog = self._kv_programs.get(bucket)
         if prog is None:
-            from distributed_dot_product_tpu.analysis.retrace import (
-                watch_traces,
-            )
             axis = self.mesh.axis_names[0]
             shard = NamedSharding(self.mesh, P(axis))
             rep = NamedSharding(self.mesh, P())
@@ -201,9 +199,6 @@ class PrefillPool:
     def _fill_program(self, bucket):
         prog = self._fill_programs.get(bucket)
         if prog is None:
-            from distributed_dot_product_tpu.analysis.retrace import (
-                watch_traces,
-            )
 
             def body(cache, k, v, page_row, count):
                 return paged_append_rows(cache, k, v, page_row, 0,

@@ -30,8 +30,8 @@ from jax.sharding import PartitionSpec as P
 
 from distributed_dot_product_tpu.models.dense import dense_param_bytes
 from distributed_dot_product_tpu.models.remat import step_holds
-from distributed_dot_product_tpu.obs.spans import device_scope
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
+from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['make_train_step', 'make_lm_train_step', 'mse_loss']
 
@@ -320,35 +320,3 @@ def _module_has_dropout(module):
     # (transformer.py accepts any pair-iterable via dict(...)).
     kw = dict(getattr(module, 'attn_kwargs', None) or {})
     return bool(kw.get('dropout_rate', 0.0))
-
-
-def graphlint_entrypoints():
-    """Static-analysis registration hook (analysis/registry.py): the
-    full sharded LM train step — forward, chunked loss, cross-shard
-    gradient psum, optax update — as ONE traced program on a real
-    2-device mesh, plus the donation check on the jitted step (params
-    and optimizer state are donated by default; losing that doubles
-    peak parameter memory per step)."""
-
-    def lm_step():
-        import optax
-        from distributed_dot_product_tpu.analysis.registry import (
-            TraceSpec,
-        )
-        from distributed_dot_product_tpu.models.lm import TransformerLM
-        from distributed_dot_product_tpu.parallel.mesh import seq_mesh
-        mesh = seq_mesh(2)
-        model = TransformerLM(vocab_size=32, dim=16, num_heads=2,
-                              n_layers=1)
-        tokens = jnp.zeros((1, 16), jnp.int32)
-        params = model.init(jax.random.key(0), tokens)
-        optimizer = optax.sgd(1e-2)
-        opt_state = optimizer.init(params)
-        step = make_lm_train_step(model, optimizer, mesh, loss_chunk=8)
-        targets = jnp.zeros((1, 16), jnp.int32)
-        return TraceSpec(name='train.lm_step', fn=step,
-                         args=(params, opt_state, (tokens, targets)),
-                         mesh_axes=(SEQ_AXIS,), prejitted=True,
-                         expect_donation=True, min_donated=1)
-
-    return {'train.lm_step': lm_step}

@@ -37,8 +37,7 @@ import threading
 import weakref
 
 __all__ = ['RetraceBudgetExceeded', 'TraceCounter', 'watch_traces',
-           'sentinel_enabled', 'snapshot', 'total', 'totals', 'reset',
-           'ENV_VAR']
+           'sentinel_enabled', 'snapshot', 'total', 'reset', 'ENV_VAR']
 
 ENV_VAR = 'DDP_TPU_RETRACE_SENTINEL'
 
@@ -165,20 +164,6 @@ def total(name):
         return (_RETIRED.get(name, 0)
                 + sum(c.count for c in _live_counters()
                       if c.name == name))
-
-
-def totals():
-    """``{name: cumulative count}`` over EVERY name ever watched —
-    live counters plus the folded-at-death totals. Unlike
-    :func:`snapshot` (live only), the key set is stable across GC
-    timing, which is what lets a before/after diff of this mapping
-    (obs/perf.py's snapshot accounting) be deterministic regardless of
-    what the process traced — and retired — earlier."""
-    with _COUNTERS_LOCK:
-        out = dict(_RETIRED)
-        for c in _live_counters():
-            out[c.name] = out.get(c.name, 0) + c.count
-        return out
 
 
 def reset():

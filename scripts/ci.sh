@@ -18,7 +18,7 @@ cd "$(dirname "$0")/.."
 
 rc=0
 
-echo '=== [1/12] ruff (generic hygiene) ==='
+echo '=== [1/11] ruff (generic hygiene) ==='
 if command -v ruff >/dev/null 2>&1; then
     ruff check . || rc=1
 elif python -c 'import ruff' >/dev/null 2>&1; then
@@ -27,7 +27,7 @@ else
     echo 'ruff not installed in this image — skipping (graphlint still runs)'
 fi
 
-echo '=== [2/12] graphlint + servelint + flowlint (jaxpr/domain/serving contracts) ==='
+echo '=== [2/11] graphlint + servelint + flowlint (jaxpr/domain/serving contracts) ==='
 # Full pass: jaxpr rules over every registered entrypoint (incl. the
 # bf16 serving-dtype and int8-weight twins — the owned dense retired
 # the flax-Dense f32-accum waivers, so zero allowed records remain)
@@ -42,7 +42,7 @@ echo '=== [2/12] graphlint + servelint + flowlint (jaxpr/domain/serving contract
 #   python -m distributed_dot_product_tpu.analysis --changed-only origin/main
 JAX_PLATFORMS=cpu python -m distributed_dot_product_tpu.analysis || rc=1
 
-echo '=== [3/12] tier-1 tests ==='
+echo '=== [3/11] tier-1 tests ==='
 if [ "${SKIP_TESTS:-0}" = "1" ]; then
     echo 'SKIP_TESTS=1 — skipping pytest stage'
 else
@@ -50,7 +50,7 @@ else
         --continue-on-collection-errors -p no:cacheprovider || rc=1
 fi
 
-echo '=== [4/12] smoke serve + event-log schema validation ==='
+echo '=== [4/11] smoke serve + event-log schema validation ==='
 # Drives the real serving process through the fault cocktail and then
 # schema-validates + timeline-reconstructs its JSONL event log (the
 # obs validate CLI runs inside smoke_serve.sh over the run's log).
@@ -60,7 +60,7 @@ else
     scripts/smoke_serve.sh 12 4 || rc=1
 fi
 
-echo '=== [5/12] spec-decode bit-identity smoke (DDP_TPU_SPEC=ngram) ==='
+echo '=== [5/11] spec-decode bit-identity smoke (DDP_TPU_SPEC=ngram) ==='
 # Speculative decoding's exactness guarantee, proven on a real burst
 # through the ENV knob a deployment would flip: the same traffic served
 # with the n-gram proposer (verify-k steps) and without (plain n=1
@@ -118,7 +118,7 @@ print(f'spec smoke OK: {len(base)} streams bit-identical, '
 PY
 fi
 
-echo '=== [6/12] serve-load smoke + SLO goodput gate ==='
+echo '=== [6/11] serve-load smoke + SLO goodput gate ==='
 # A seeded open-loop trace (virtual clock — minutes of simulated
 # traffic in seconds of wall time, CPU-deterministic) drives the
 # scheduler, then the goodput report computed FROM THE EVENT LOG ALONE
@@ -144,7 +144,7 @@ else
     rm -f "$slo_log" "$slo_row"
 fi
 
-echo '=== [7/12] disaggregated-serving smoke (router + 2 decode pools) ==='
+echo '=== [7/11] disaggregated-serving smoke (router + 2 decode pools) ==='
 # The 1-router/2-pool cocktail on the CPU mesh: the seeded trace through
 # the disaggregated topology AND its single-process twin, member logs
 # schema-validated (--require router.route / prefill.handoff), goodput
@@ -156,25 +156,7 @@ else
     scripts/smoke_router.sh || rc=1
 fi
 
-echo '=== [8/12] perf gate (compiled-program cost vs committed baseline) ==='
-# Compiles every registered entrypoint hermetically (8-dev CPU mesh),
-# snapshots XLA cost/memory/compile-time/retrace accounting, and gates
-# it against the committed PERF_BASELINE.json (tolerances sized for
-# CPU-mesh determinism — see obs/perf.py Tolerances). On an
-# INTENTIONAL program change, refresh the baseline in the same diff:
-#   python -m distributed_dot_product_tpu.obs.perf snapshot -o PERF_BASELINE.json
-if [ "${SKIP_TESTS:-0}" = "1" ]; then
-    echo 'SKIP_TESTS=1 — skipping perf-gate stage'
-else
-    perf_now="$(mktemp /tmp/ddp_perf_now.XXXXXX.json)"
-    { JAX_PLATFORMS=cpu python -m distributed_dot_product_tpu.obs.perf \
-          snapshot -o "$perf_now" \
-      && JAX_PLATFORMS=cpu python -m distributed_dot_product_tpu.obs.perf \
-          check --against PERF_BASELINE.json --current "$perf_now"; } || rc=1
-    rm -f "$perf_now"
-fi
-
-echo '=== [9/12] closed-loop control smoke (static vs controlled under a ramp) ==='
+echo '=== [8/11] closed-loop control smoke (static vs controlled under a ramp) ==='
 # The control-plane acceptance row: the SAME seeded ramp trace (rate
 # climbing to 10x across the trace — deterministic overload) through a
 # 1-decode-replica topology twice. STATIC must breach the committed
@@ -235,7 +217,7 @@ PY
     rm -rf "$ctl_rows" "$ctl_static" "$ctl_logs"
 fi
 
-echo '=== [10/12] replica-failure-domain smoke (seeded crash + recovery) ==='
+echo '=== [9/11] replica-failure-domain smoke (seeded crash + recovery) ==='
 # The robustness acceptance row: the seeded CI trace with decode
 # replica r1 killed at a fixed virtual tick. Probes declare the loss,
 # every in-flight stream re-dispatches to the survivor bit-identical
@@ -249,7 +231,7 @@ else
     scripts/smoke_chaos.sh || rc=1
 fi
 
-echo '=== [11/12] data-integrity smoke (seeded bit flip + detect/heal) ==='
+echo '=== [10/11] data-integrity smoke (seeded bit flip + detect/heal) ==='
 # The KV-page-integrity acceptance row: the seeded CI trace with one
 # exponent bit flipped in a live KV page of r0 at a fixed virtual
 # tick. The scrub detects the flip before any poisoned token is
@@ -263,7 +245,7 @@ else
     scripts/smoke_corrupt.sh || rc=1
 fi
 
-echo '=== [12/12] long-context smoke (128k stream on the sharded KV mesh) ==='
+echo '=== [11/11] long-context smoke (128k stream on the sharded KV mesh) ==='
 # The cluster-scale long-context acceptance row: a 128k-token stream
 # prefilled into a kv_shards=8 paged engine (each mesh member owns a
 # contiguous page range, per-shard flash partials psum/pmax-merged)

@@ -40,7 +40,7 @@ setup_compile_cache()
 jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
 jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)
 
-# Retrace sentinel (analysis/retrace.py): ON for the whole suite —
+# Retrace sentinel (utils/retrace.py): ON for the whole suite —
 # every decode/serve test runs under its entrypoint's trace-count
 # budget, so a per-token retrace storm (the round-5 decode_seq_parallel
 # finding) fails the offending test loudly instead of showing up as
@@ -64,6 +64,6 @@ def _retrace_isolation():
     test's behavior (compiled steps and their jit caches persist across
     tests, so carried-over counts would charge later tests for earlier
     tests' legitimate traces)."""
-    from distributed_dot_product_tpu.analysis import retrace
+    from distributed_dot_product_tpu.utils import retrace
     retrace.reset()
     yield

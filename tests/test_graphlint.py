@@ -29,12 +29,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distributed_dot_product_tpu.analysis import retrace, run_analysis
+from distributed_dot_product_tpu.analysis import run_analysis
 from distributed_dot_product_tpu.analysis.astlint import lint_file
 from distributed_dot_product_tpu.analysis.jaxpr_rules import lint_spec
 from distributed_dot_product_tpu.analysis.registry import (
     default_entrypoints,
 )
+from distributed_dot_product_tpu.utils import retrace
 
 pytestmark = pytest.mark.analysis
 
@@ -80,31 +81,47 @@ def test_clean_tree_gate(devices):
         + '\n'.join(v.render() for v in waived))
 
 
-def test_registry_covers_every_layer(devices):
-    """The registry spans the whole stack — a layer hook silently
-    returning {} would shrink the gate's coverage without failing it."""
-    names = set(default_entrypoints())
-    expected = {
-        'ops.matmul_grad_allgather', 'ops.matmul_grad_ring',
-        'ops.flash_fwd_bf16', 'ops.flash_bwd_bf16', 'ops.flash_fwd_int8',
-        'attention.fwd_flash', 'attention.bwd_full', 'attention.fwd_ring',
-        'attention.fwd_ulysses', 'decode.seq_parallel_step',
-        'decode.step_xla_slots', 'decode.step_kernel_int8',
-        'decode.step_sharded', 'decode.step_paged_xla',
-        'decode.step_paged_kernel', 'decode.step_verify_slab',
-        'decode.step_verify_paged', 'lm.head_bf16', 'lm.loss_f32',
-        'serve.engine_decode', 'serve.engine_decode_paged',
-        'train.lm_step', 'obs.spanned_decode',
-        # serving-dtype twins (PR 13): module-level surfaces traced at
-        # bf16 so the cache/donation contracts gate the deployed dtype.
-        'attention.fwd_flash_bf16', 'decode.seq_parallel_step_bf16',
-        'lm.loss_bf16',
-        # low-precision end-to-end (PR 14): the int8-WEIGHT serving
-        # programs and the quantized decode step on the page pool.
-        'attention.fwd_flash_wq8', 'serve.engine_decode_wq8',
-        'decode.step_paged_kernel_int8',
-    }
-    assert expected <= names, f'missing: {expected - names}'
+# The registry's names, in its order. PR 42 moved the examples out of
+# the layer modules into analysis/entrypoints.py and changed none.
+REGISTERED = [
+    'ops.matmul_grad_allgather', 'ops.matmul_grad_ring',
+    'ops.flash_fwd_bf16', 'ops.flash_bwd_bf16', 'ops.flash_fwd_int8',
+    'attention.fwd_flash', 'attention.fwd_flash_bf16',
+    'attention.fwd_flash_wq8', 'attention.bwd_full', 'attention.fwd_ring',
+    'attention.fwd_ulysses', 'decode.seq_parallel_step',
+    'decode.seq_parallel_step_bf16', 'decode.step_xla_slots',
+    'decode.step_kernel_int8', 'decode.step_sharded',
+    'decode.step_paged_xla', 'decode.step_paged_kernel',
+    'decode.step_paged_kernel_int8', 'decode.step_paged_sharded',
+    'decode.step_paged_sharded_kernel', 'decode.step_verify_slab',
+    'decode.step_verify_paged', 'lm.head_bf16', 'lm.loss_f32',
+    'lm.loss_bf16', 'serve.engine_decode', 'serve.engine_decode_paged',
+    'serve.engine_decode_wq8', 'serve.engine_decode_kv_sharded',
+    'train.lm_step', 'obs.spanned_decode',
+]
+
+
+def test_registry_is_the_pinned_list(devices):
+    """The registry spans the whole stack — an example silently dropped
+    would shrink the gate's coverage without failing it — and a caller
+    gets a copy it may cut down."""
+    entries = default_entrypoints()
+    assert list(entries) == REGISTERED and len(REGISTERED) == 32
+    entries.clear()
+    assert list(default_entrypoints()) == REGISTERED
+
+
+def test_duplicate_registration_is_an_error():
+    from distributed_dot_product_tpu.analysis.entrypoints import (
+        ENTRYPOINTS, entrypoint,
+    )
+    with pytest.raises(ValueError, match='duplicate entrypoint'):
+        entrypoint('train.lm_step', lambda: None)
+    with pytest.raises(ValueError, match='duplicate entrypoint'):
+        @entrypoint('obs.spanned_decode')
+        def again():
+            pass
+    assert list(ENTRYPOINTS) == REGISTERED
 
 
 # -- AST rules: negative fixtures ---------------------------------------

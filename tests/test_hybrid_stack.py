@@ -623,7 +623,7 @@ def test_two_hit_counts_share_one_compiled_program():
     five of six held experts run one trace of one program (the retrace
     sentinel's budget of 1 holds), and hidden tiles narrower than the
     layer (two of 128 here) give the same numbers."""
-    from distributed_dot_product_tpu.analysis import retrace
+    from distributed_dot_product_tpu.utils import retrace
     from distributed_dot_product_tpu.models.moe import ACTIVATIONS
     from distributed_dot_product_tpu.ops.pallas_experts import (
         hit_experts, hit_experts_reference, hit_list,

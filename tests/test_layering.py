@@ -1,0 +1,121 @@
+# -*- coding: utf-8 -*-
+"""
+The package's module graph: the kernels, the model, the mesh helpers and
+the train step (``ops/``, ``models/``, ``parallel/``, ``train.py``) sit
+BELOW the tooling (``obs/``, ``analysis/``, ``serve/``) and import none
+of it, at module level or inside a function. What they need of it — the
+device-scope names, the retrace guard, the trace sinks — are leaves
+under ``utils/``.
+"""
+
+import ast
+import os
+
+import pytest
+
+PKG = 'distributed_dot_product_tpu'
+ROOT = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), PKG)
+UPPER = ('obs', 'analysis', 'serve')
+
+
+def _lower_files():
+    out = ['train.py']
+    for sub in ('ops', 'models', 'parallel'):
+        out += sorted(os.path.join(sub, f)
+                      for f in os.listdir(os.path.join(ROOT, sub))
+                      if f.endswith('.py'))
+    return out
+
+
+def _imported_modules(rel, root=ROOT):
+    """``(lineno, absolute dotted name)`` of everything ``rel`` imports,
+    at any depth of its AST: ``import a.b``, ``from a import b`` (both
+    ``a`` and ``a.b``: ``b`` may be a module), relative forms resolved
+    against the file's own package, and literal ``import_module`` /
+    ``__import__`` arguments."""
+    with open(os.path.join(root, rel), encoding='utf-8') as f:
+        tree = ast.parse(f.read(), rel)
+    here = [PKG] + rel.split(os.sep)[:-1]
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for alias in node.names:
+                yield node.lineno, alias.name
+        elif isinstance(node, ast.ImportFrom):
+            base = node.module or ''
+            if node.level:
+                up = here[:len(here) - node.level + 1]
+                base = '.'.join(up + ([base] if base else []))
+            yield node.lineno, base
+            for alias in node.names:
+                yield node.lineno, f'{base}.{alias.name}'
+        elif (isinstance(node, ast.Call) and node.args
+              and isinstance(node.args[0], ast.Constant)
+              and isinstance(node.args[0].value, str)
+              and getattr(node.func, 'attr', getattr(node.func, 'id', ''))
+              in ('import_module', '__import__')):
+            yield node.lineno, node.args[0].value
+
+
+def _is_upper(name):
+    return any(name == f'{PKG}.{up}' or name.startswith(f'{PKG}.{up}.')
+               for up in UPPER)
+
+
+@pytest.mark.parametrize('rel', _lower_files())
+def test_lower_layer_imports_no_tooling(rel):
+    bad = sorted({(line, name) for line, name in _imported_modules(rel)
+                  if _is_upper(name)})
+    assert not bad, (
+        f'{PKG}/{rel} imports the tooling above it: '
+        + ', '.join(f'line {line}: {name}' for line, name in bad))
+
+
+def test_obs_and_serve_import_nothing_of_the_lint():
+    """The lint's example programs import the engine and the spans, not
+    the reverse: ``obs/`` and ``serve/`` name no ``analysis`` module."""
+    bad = [(rel, line, name)
+           for sub in ('obs', 'serve')
+           for rel in sorted(os.path.join(sub, f)
+                             for f in os.listdir(os.path.join(ROOT, sub))
+                             if f.endswith('.py'))
+           for line, name in _imported_modules(rel)
+           if name.startswith(f'{PKG}.analysis')]
+    assert not bad, bad
+
+
+def test_the_walk_sees_lazy_relative_and_dynamic_imports(tmp_path):
+    """The guard guards: a lazy absolute import, a relative one and an
+    ``import_module`` string are each found."""
+    sub = tmp_path / 'models'
+    sub.mkdir()
+    (sub / 'x.py').write_text(
+        'def f():\n'
+        '    from distributed_dot_product_tpu.obs.spans import span\n'
+        '    from .. import serve\n'
+        '    from ..analysis.registry import TraceSpec\n'
+        '    import importlib\n'
+        '    importlib.import_module("distributed_dot_product_tpu.obs")\n'
+        '    from distributed_dot_product_tpu.utils.scopes import x\n')
+    found = {name for _, name in
+             _imported_modules(os.path.join('models', 'x.py'),
+                               root=str(tmp_path))
+             if _is_upper(name)}
+    assert found == {
+        f'{PKG}.obs', f'{PKG}.obs.spans', f'{PKG}.obs.spans.span',
+        f'{PKG}.serve', f'{PKG}.analysis.registry',
+        f'{PKG}.analysis.registry.TraceSpec'}
+
+
+def test_device_scopes_are_the_leafs_table():
+    """``obs.spans`` re-exports the leaf's two names, not copies (the
+    benchmark's readers import them from there), and an unknown scope
+    is still refused."""
+    from distributed_dot_product_tpu.obs import spans
+    from distributed_dot_product_tpu.utils import scopes
+    assert spans.DEVICE_SCOPES is scopes.DEVICE_SCOPES
+    assert spans.device_scope is scopes.device_scope
+    with pytest.raises(ValueError, match='unknown device scope'):
+        scopes.device_scope('no.such')
+    with scopes.device_scope('ops.flash_fwd'):
+        pass

@@ -397,7 +397,7 @@ def test_greedy_generate_reuses_compiled_programs():
     shape-determining input, and the watchers are retrace-budgeted so
     a regression raises rather than silently rebuilding."""
     from distributed_dot_product_tpu import greedy_generate
-    from distributed_dot_product_tpu.analysis import retrace
+    from distributed_dot_product_tpu.utils import retrace
     m = _model(attn_kwargs=dict(distributed=False))
     # Shapes unique to this test: the program cache is module-global,
     # so reusing another test's (b, n, t_max) would read its entry and

@@ -9,8 +9,7 @@ SLO accounting unit + gate tests (obs/slo.py):
 - the committed SLO_BASELINE.json gate end to end through the CLI —
   the seeded CI smoke passes clean (rc 0) and a seeded regression
   fixture (the same trace on 50x slower virtual ticks) fails (rc 1)
-  naming the metric and tenant, mirroring test_obs_perf's
-  PERF_BASELINE gate.
+  naming the metric and tenant.
 """
 
 import json

@@ -379,7 +379,7 @@ def test_spec_retrace_budget_one_program_per_width():
     """One verify program per width and one rollback program per span
     bucket over a whole serving run — the retrace sentinel (enabled
     suite-wide) would raise on a storm; this pins the totals."""
-    from distributed_dot_product_tpu.analysis import retrace
+    from distributed_dot_product_tpu.utils import retrace
     sched = _mk_sched('ngram', 'slab', slots=2, max_new=16)
     for i, p in enumerate(([1, 2, 3] * 3, [4, 5] * 4)):
         sched.submit(list(p), request_id=f'r{i}')
