@@ -67,7 +67,15 @@ FULL = dict(
     # fixed-size float32 state beside a slab and no cache
     hybrid=dict(dim=1024, heads=8, kv_heads=2, ssm_heads=32,
                 ssm_head_dim=64, state=128, groups=8, experts=8,
-                expert_hidden=256, latent=256, shared=512))
+                expert_hidden=256, latent=256, shared=512),
+    # generate_sparse: a learned block-sparse attention layer and a
+    # Lightning linear-attention layer in one stack (the two kinds of
+    # benchmarks/configs/minicpm-sala-serve at its head size and block):
+    # the 2048-token prompt ends above dense_len, so every served token
+    # picks 8 of its 32 blocks
+    sparse=dict(dim=1024, heads=8, kv_heads=2, hidden=2048,
+                select=dict(kernel=32, stride=16, block=64, window=256,
+                            topk=8, dense_len=1024)))
 TINY = dict(
     vocab=128, dim=64, heads=2, layers=1,
     train_t=128, ref_t=32,
@@ -83,7 +91,10 @@ TINY = dict(
                expert_hidden=32, shared=2, layers=4),
     hybrid=dict(dim=64, heads=2, kv_heads=1, ssm_heads=4, ssm_head_dim=16,
                 state=16, groups=2, experts=4, expert_hidden=32, latent=32,
-                shared=48))
+                shared=48),
+    sparse=dict(dim=64, heads=4, kv_heads=2, hidden=96,
+                select=dict(kernel=4, stride=2, block=8, window=8, topk=2,
+                            dense_len=16)))
 
 # bf16 tolerance, relative to the compared tensor's own scale: two paths
 # that are equal in exact arithmetic may differ by max|a - b| <=
@@ -624,6 +635,106 @@ def phase_generate_hybrid(progs, cfg, seed):
         }}
 
 
+def sparse_lm(cfg, **attn_kwargs):
+    """One learned block-sparse NoPE attention layer (``qk_norm``, an
+    output gate) and one Lightning linear-attention layer
+    (``cfg['sparse']``), each followed by a gated MLP: a ``SparseCache``
+    (slab and pooled keys) beside a ``StateCache`` with no window."""
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu import TransformerLM
+    c = cfg['sparse']
+    return TransformerLM(
+        vocab_size=cfg['vocab'], dim=c['dim'], num_heads=c['heads'],
+        n_layers=2, dtype=jnp.bfloat16, scan_layers=False,
+        tie_embeddings=False,
+        attn_kwargs={'num_kv_heads': c['kv_heads'], 'use_rope': False,
+                     'qk_norm': True, 'out_gate': True,
+                     'sparse': c['select'], **attn_kwargs},
+        block_kwargs={'norm': 'rmsnorm', 'ffn': 'gated',
+                      'ffn_kwargs': {'hidden': c['hidden']}},
+        layer_kinds={
+            'S': {'mixer': 'attention'},
+            'L': {'mixer': 'lightning', 'ssm_kwargs': {
+                'heads': c['heads'], 'head_dim': c['dim'] // c['heads']}}},
+        layer_pattern=('S', 'L'))
+
+
+def phase_generate_sparse(progs, cfg, seed):
+    """The picked-rows path end to end: a prompt prefilled under its
+    picks' block mask into a ``SparseCache`` beside a Lightning state,
+    the state SNAPSHOTTED at the prompt's end, a greedy request, the
+    state restored and the slab's length set back (the pooled keys
+    rewind with it), and the request again — which must read what the
+    first did, bit for bit — with the sparse layer's step on the kernel
+    ``sparse_decode`` (``sparse_decode_traces()``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_dot_product_tpu.models.decode import (
+        restore_states, snapshot_states, sparse_decode_traces,
+    )
+    model = sparse_lm(cfg)
+    n, steps, t_max = cfg['prompt'], cfg['new_tokens'], cfg['gen_t_max']
+    params = {'params': model.init(
+        jax.random.key(seed + 9), jnp.zeros((1, 16), 'int32'))['params']}
+    prompt = jax.random.randint(jax.random.key(seed + 2), (1, n), 0,
+                                cfg['vocab'], dtype='int32')
+    prefill = jax.jit(lambda p, t, c: model.apply(p, t, c,
+                                                  method='prefill'))
+    step = jax.jit(lambda p, t, c: model.apply(
+        p, t, c, method='decode', mutable=['counters']),
+        donate_argnums=(2,))
+
+    def reset(caches, taken):
+        return [c._replace(length=jnp.asarray(n, jnp.int32))
+                if hasattr(c, 'length') else c
+                for c in restore_states(caches, taken)]
+    reset = jax.jit(reset, donate_argnums=(0,))
+    caches = model.make_decode_caches(1, t_max)
+    kinds = [type(c).__name__ for c in caches]
+    with sparse_decode_traces() as forms:
+        progs.compile('sparse.prefill', prefill, params, prompt, caches,
+                      pallas=True)
+        progs.compile('sparse.decode', step, params, prompt[:, :1],
+                      caches, pallas=True)
+    caches, logits = prefill(params, prompt, caches)
+    first = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+    taken = snapshot_states(caches)
+    topk = cfg['sparse']['select']['topk']
+
+    def request(caches):
+        tok, out, counts = first, [], []
+        for _ in range(steps):
+            (caches, logits), sown = step(params, tok, caches)
+            out.append(np.asarray(logits[:, -1], np.float32))
+            counts.append(int(sown['counters']['stack']['block_0']['attn'][
+                'sparse_count']))
+            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        return caches, np.stack(out), counts
+    caches, once, counts = request(caches)
+    moved = float(np.max(np.abs(
+        np.asarray(caches[1].state) - np.asarray(taken[1].state))))
+    caches, again, _ = request(reset(caches, taken))
+    return {
+        'sparse_caches': kinds,
+        'sparse_decode': forms,
+        'sparse_picks_a_step': counts,
+        'sparse_state_moved_by_a_request': moved,
+        'sparse_logits_max_abs': float(np.max(np.abs(once))),
+        'checks': {
+            'sparse.cache_kinds': kinds == ['SparseCache', 'StateCache'],
+            'sparse.step_is_the_kernel': [
+                (f['impl'], f['topk']) for f in forms] == [('kernel', topk)],
+            'sparse.every_step_picks_topk': counts == steps * [topk],
+            'sparse.logits_finite': bool(np.all(np.isfinite(once))),
+            'sparse.a_request_moves_the_state': moved > 0,
+            'sparse.restored_request_agrees': bool(
+                np.array_equal(once, again)),
+        }}
+
+
 # -- one chip: serve -----------------------------------------------------
 
 def engine(cfg, seed, **kw):
@@ -995,6 +1106,8 @@ def main(argv=None):
                run_phase('generate_mixed', phase_generate_mixed, cfg,
                          args.seed),
                run_phase('generate_hybrid', phase_generate_hybrid, cfg,
+                         args.seed),
+               run_phase('generate_sparse', phase_generate_sparse, cfg,
                          args.seed),
                run_phase('serve', phase_serve, cfg, args.seed, out_dir)]
     else:

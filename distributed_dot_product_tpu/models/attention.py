@@ -63,6 +63,10 @@ from distributed_dot_product_tpu.models.ulysses_attention import (
 from distributed_dot_product_tpu.ops.pallas_attention import (
     FLASH_QKV_NAME, flash_attention,
 )
+from distributed_dot_product_tpu.models.sparse import (
+    SparseSpec, pool_rows, pooled_after_chunk, sparse_attention,
+    sparse_step,
+)
 from distributed_dot_product_tpu.ops.ops import matmul_all, matmul_nt
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 from distributed_dot_product_tpu.utils.retrace import watch_traces
@@ -201,6 +205,24 @@ class DistributedDotProductAttn(nn.Module):
     # ``composition``; at every entry point, inside ``lm.attn_proj``.
     # False adds no parameter and no operation.
     out_gate: bool = False
+    # A per-head RMSNorm with a learned ``(head_dim,)`` scale on both
+    # score operands after the head split and before any rotation
+    # (``minicpm_sala``'s ``qk_norm``; parameters ``keys_norm`` /
+    # ``queries_norm``), read at every entry point. False adds no
+    # parameter and no operation.
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
+    # Learned block-sparse attention (``models/sparse.py``): a dict of
+    # :class:`~distributed_dot_product_tpu.models.sparse.SparseSpec`'s
+    # sizes (``{}`` for the published ones). A token attends the blocks
+    # it picks by its scores against POOLED keys; the decode cache is a
+    # ``SparseCache`` (the slab and the pooled keys), a step the kernel
+    # ``sparse_decode``, a prompt chunk the flash forward under the
+    # picks' block mask. Causal, local (no sequence axis), and none of
+    # window / ALiBi / segments / int8 scores / dropout. The picks are
+    # sown into the ``'counters'`` collection (``sparse_picks``,
+    # ``sparse_count``) where a caller makes it mutable.
+    sparse: Optional[Any] = None
     # 'int8' = int8 WEIGHT quantization for the four projection
     # matmuls (models/dense.py): kernels stored int8 with per-output-
     # channel scales (quantize_dense_params at load/convert time),
@@ -307,6 +329,47 @@ class DistributedDotProductAttn(nn.Module):
         self.composition = dense(self.out_dim or value_dim, 'composition')
         if self.out_gate:
             self.gate_proj = dense(value_dim, 'gate')
+        if self.qk_norm:
+            ones = nn.initializers.ones_init()
+            self.keys_norm = self.param('keys_norm', ones,
+                                        (self.head_dim,), jnp.float32)
+            self.queries_norm = self.param('queries_norm', ones,
+                                           (self.head_dim,), jnp.float32)
+        self._sparse = None
+        if self.sparse is not None:
+            refused = [name for name, on in (
+                ('causal=False', not self.causal),
+                ('window', self.window is not None),
+                ('alibi_slopes', self.alibi_slopes is not None),
+                ('qk_quant', self.qk_quant is not None),
+                ('dropout_rate', bool(self.dropout_rate)),
+                (f'softmax_impl={self.softmax_impl!r}',
+                 self.softmax_impl != 'flash')) if on]
+            if refused:
+                raise ValueError(
+                    f'sparse attention is causal flash attention over '
+                    f'picked blocks; it does not take {refused}')
+            self._sparse = SparseSpec(**dict(self.sparse))
+
+    def _norm_heads(self, keys, queries):
+        """``qk_norm``: the per-head RMSNorm of both score operands
+        ``(…, heads, T, head_dim)``, statistics in float32."""
+        if not self.qk_norm:
+            return keys, queries
+
+        def norm(x, scale):
+            xf = x.astype(jnp.float32)
+            xf = xf * jax.lax.rsqrt(
+                jnp.mean(jnp.square(xf), -1, keepdims=True)
+                + self.qk_norm_eps)
+            return (xf * scale).astype(x.dtype)
+        return norm(keys, self.keys_norm), norm(queries, self.queries_norm)
+
+    def _sow_picks(self, picks, count):
+        self.sow('counters', 'sparse_picks', picks,
+                 reduce_fn=lambda _, new: new)
+        self.sow('counters', 'sparse_count', count,
+                 reduce_fn=lambda _, new: new)
 
     def _gate(self, keys):
         """The output gate of the UNPROJECTED ``keys`` input, float32
@@ -383,6 +446,9 @@ class DistributedDotProductAttn(nn.Module):
                            self._value_dim // self.num_heads)
             if attn_mask is not None:
                 attn_mask = attn_mask[..., None, :, :]
+            keys, queries = self._norm_heads(keys, queries)
+        elif self.qk_norm:
+            raise ValueError('qk_norm norms a head: it needs num_heads > 1')
 
         # During flax init the body runs outside any shard_map (no mesh axis
         # bound), and parameter shapes don't depend on the comm pattern —
@@ -417,6 +483,18 @@ class DistributedDotProductAttn(nn.Module):
                 pos = idx * tn + jnp.arange(tn)
             keys = self._rope(keys, pos)
             queries = self._rope(queries, pos)
+
+        if self._sparse is not None:
+            if (distributed or attn_mask is not None
+                    or segment_ids is not None or keys.ndim != 4):
+                raise ValueError(
+                    'sparse attention runs on one device, batched, with '
+                    'no mask but its own: distributed=False, no '
+                    'attn_mask, no segment_ids')
+            outputs = self._sparse_forward(keys, queries, values)
+            outputs = jnp.swapaxes(outputs, -3, -2)
+            outputs = outputs.reshape(*outputs.shape[:-2], self._value_dim)
+            return self._compose(outputs, gate)
 
         # Causal handling: ring/ulysses/flash take causal=True natively —
         # the kernels skip whole future blocks and need no materialized
@@ -651,18 +729,44 @@ class DistributedDotProductAttn(nn.Module):
             outputs = outputs.reshape(*outputs.shape[:-2], self._value_dim)
         return self._compose(outputs, gate)
 
+    def _sparse_forward(self, keys, queries, values):
+        """A whole sequence from position 0 over its own picks: the
+        pooled keys are taken from the sequence's keys."""
+        spec = self._sparse
+        t = keys.shape[-2]
+        pad = (-t) % spec.block
+        if pad:
+            queries, values = (
+                jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                for x in (queries, values))
+        pooled = pool_rows(queries, spec)        # whole blocks: strides
+        with device_scope('ops.sparse_prefill'):
+            out, picks, count = sparse_attention(
+                keys, queries, values, pooled, 0, spec, self._scale)
+        self._sow_picks(picks, count)
+        return out
+
     def make_decode_cache(self, batch, t_max, dtype=None):
         """A KV cache sized for this module's projections (GQA-aware:
         ``num_kv_heads`` heads of queries/values — the softmax-table side
         under the K-first convention). Plain Python (reads constructor
         fields only), so no ``apply`` is needed."""
         from distributed_dot_product_tpu.models.decode import (
-            init_cache, init_ring_cache,
+            init_cache, init_ring_cache, init_sparse_cache,
         )
         kv_heads = (self.num_kv_heads if self.num_kv_heads is not None
                     else self.num_heads)
         value_dim = (self.value_dim if self.value_dim is not None
                      else self.key_dim)
+        if self.sparse is not None:
+            spec = SparseSpec(**dict(self.sparse))
+            if t_max % spec.block:
+                raise ValueError(f't_max {t_max} is not whole blocks of '
+                                 f'{spec.block}')
+            return init_sparse_cache(
+                batch, kv_heads, t_max, self.key_dim // self.num_heads,
+                spec.stride, v_head_dim=value_dim // self.num_heads,
+                dtype=dtype or self.dtype or jnp.float32)
         if self.ring_cache is not None and self.ring_cache < t_max:
             if self.qk_quant is not None:
                 raise ValueError('a ring cache carries no int8 mirror')
@@ -699,6 +803,7 @@ class DistributedDotProductAttn(nn.Module):
         queries = split(queries, self._kv_heads, self.head_dim)
         values = split(values, self._kv_heads,
                        self._value_dim // self.num_heads)
+        keys, queries = self._norm_heads(keys, queries)
         if self.use_rope:
             pos = length + jnp.arange(n)
             keys = self._rope(keys, pos)
@@ -739,6 +844,9 @@ class DistributedDotProductAttn(nn.Module):
         from distributed_dot_product_tpu.models.decode import (
             RingCache, append_kv, ring_append, ring_window,
         )
+        if self._sparse is not None:
+            return self._sparse_prefill(keys, queries, values, cache,
+                                        segment_ids)
         with device_scope('lm.attn_proj'):
             keys, queries, values, gate = self._project_for_decode(
                 keys, queries, values, cache.length)
@@ -778,6 +886,51 @@ class DistributedDotProductAttn(nn.Module):
                 segment_ids=seg_pair)
             return cache, self._merge_decode_heads(out, gate)
 
+    def _sparse_prefill(self, keys, queries, values, cache, segment_ids):
+        """The sparse layer's chunk: the slab's append, the pooled rows
+        the chunk completes, then every row over its own picks. The
+        selection's and the attention's scopes are SIBLINGS of
+        ``lm.attn_proj``, which stays the projections alone (the
+        benchmark's accepted reader takes it for them)."""
+        from distributed_dot_product_tpu.models.decode import (
+            DecodeCache, append_kv,
+        )
+        if segment_ids is not None:
+            raise ValueError('sparse attention takes no segment_ids')
+        spec, start, n = self._sparse, cache.length, keys.shape[-2]
+        with device_scope('lm.attn_proj'):
+            keys, queries, values, gate = self._project_for_decode(
+                keys, queries, values, start)
+            slab = append_kv(DecodeCache(cache.k, cache.v, start), queries,
+                             values)
+        with device_scope('ops.sparse_select'):
+            pooled = pooled_after_chunk(slab.k, cache.pooled, start, n,
+                                        spec)
+        with device_scope('ops.sparse_prefill'):
+            out, picks, count = sparse_attention(
+                keys, slab.k, slab.v, pooled, start, spec, self._scale)
+        self._sow_picks(picks, count)
+        cache = cache._replace(k=slab.k, v=slab.v, length=slab.length,
+                               pooled=pooled)
+        with device_scope('lm.attn_proj'):
+            return cache, self._merge_decode_heads(out, gate)
+
+    def _sparse_decode(self, keys, queries, values, cache):
+        """The sparse layer's token: projections under ``lm.attn_proj``,
+        then the selection and the kernel under their own scopes, its
+        siblings (see :meth:`_sparse_prefill`)."""
+        if keys.shape[-2] != 1:
+            raise ValueError('the sparse step is one token')
+        with device_scope('lm.attn_proj'):
+            keys, queries, values, gate = self._project_for_decode(
+                keys, queries, values, cache.length)
+        cache, out, picks, count = sparse_step(
+            keys, cache, queries, values, self._sparse, self._scale,
+            impl=self.decode_impl)
+        self._sow_picks(picks, count)
+        with device_scope('lm.attn_proj'):
+            return cache, self._merge_decode_heads(out, gate)
+
     def decode(self, keys, queries, values, cache, segment_ids=None,
                seg_cache=None, layer=None):
         """Incremental (KV-cache) inference step — the module-level
@@ -814,6 +967,11 @@ class DistributedDotProductAttn(nn.Module):
         from distributed_dot_product_tpu.models.decode import (
             decode_step,
         )
+        if self._sparse is not None:
+            if layer is not None or segment_ids is not None:
+                raise ValueError('the sparse step is an unrolled stack\'s, '
+                                 'with no segment_ids')
+            return self._sparse_decode(keys, queries, values, cache)
         with device_scope('lm.attn_proj'):
             length = cache.length
             if layer is not None:

@@ -64,6 +64,7 @@ __all__ = ['DecodeCache', 'init_cache', 'append_kv', 'append_kv_sharded',
            'rollback_slots', 'RingCache', 'init_ring_cache',
            'ring_append', 'ring_window', 'insert_session',
            'StateCache', 'snapshot_states', 'restore_states',
+           'SparseCache', 'init_sparse_cache', 'sparse_decode_traces',
            'PagedDecodeCache', 'PagePool', 'PageChecksums',
            'ShardedPageTable', 'init_sharded_paged_cache',
            'init_paged_cache', 'paged_gather', 'paged_gather_mirror',
@@ -203,6 +204,36 @@ def ring_window(cache: RingCache, k_new, v_new, window):
     return lay(cache.k, k_new), lay(cache.v, v_new), offset
 
 
+class SparseCache(NamedTuple):
+    """A block-sparse attention layer's cache (``models/sparse.py``): the
+    slab ``k`` / ``v (B, H_kv, t_max, d·)`` with its scalar ``length``,
+    and beside it the POOLED keys ``(B, H_kv, t_max // stride, d)`` that
+    a token's block selection scores — a third cache that grows, one row
+    every ``stride`` tokens. A pooled row is written at the step that
+    completes it, from the slab's rows, and no row is scored before it
+    is complete: ``length`` set back rewinds both."""
+    k: jax.Array
+    v: jax.Array
+    length: jax.Array
+    pooled: jax.Array
+
+    @property
+    def t_max(self):
+        return self.k.shape[-2]
+
+
+def init_sparse_cache(batch, kv_heads, t_max, head_dim, stride,
+                      v_head_dim=None, dtype=jnp.bfloat16):
+    """Zero :class:`SparseCache` for ``t_max`` positions."""
+    return SparseCache(
+        k=jnp.zeros((batch, kv_heads, t_max, head_dim), dtype),
+        v=jnp.zeros((batch, kv_heads, t_max, v_head_dim or head_dim),
+                    dtype),
+        length=jnp.zeros((), jnp.int32),
+        pooled=jnp.zeros((batch, kv_heads, t_max // stride, head_dim),
+                         dtype))
+
+
 class StateCache(NamedTuple):
     """A recurrent layer's cache, of FIXED size: ``state (B, heads,
     head_dim, N)`` (float32 unless the mixer says otherwise) is what the
@@ -249,9 +280,9 @@ def restore_states(caches, snapshot):
 
 
 def insert_session(cache, session, one):
-    """``cache`` (a :class:`DecodeCache`, :class:`RingCache` or
-    :class:`StateCache` of a serving batch; None, a layer without a
-    mixer, passes through) with session ``session`` replaced by the
+    """``cache`` (a :class:`DecodeCache`, :class:`RingCache`,
+    :class:`SparseCache` or :class:`StateCache` of a serving batch;
+    None, a layer without a mixer, passes through) with session ``session`` replaced by the
     single session ``one`` holds — a prompt prefilled alone, then put in
     its slot. The batch shares one clock, so every session put in must
     be of ``one``'s length, which becomes the batch's (a state has
@@ -267,10 +298,14 @@ def insert_session(cache, session, one):
                          'with an int8 mirror is not covered')
     zero = jnp.zeros((), jnp.int32)
     at = (jnp.asarray(session, jnp.int32), zero, zero, zero)
+    more = {}
+    if isinstance(cache, SparseCache):
+        more['pooled'] = lax.dynamic_update_slice(cache.pooled,
+                                                  one.pooled, at)
     return cache._replace(
         k=lax.dynamic_update_slice(cache.k, one.k, at),
         v=lax.dynamic_update_slice(cache.v, one.v, at),
-        length=one.length)
+        length=one.length, **more)
 
 
 def append_kv(cache: DecodeCache, k_new, v_new) -> DecodeCache:
@@ -1990,6 +2025,29 @@ def decode_impl_traces():
         assert {t['resolved'] for t in traces} == {'kernel'}
     """
     return _IMPL_TRACES.open()
+
+
+_SPARSE_TRACES = TraceSinks()
+
+
+def sparse_decode_traces():
+    """Collect the form of every block-sparse layer's decode step
+    (``models/sparse.sparse_step``) while the block runs: one dict
+    ``{'impl', 'picks', 'topk', 'group'}`` per TRACE — ``impl`` is
+    ``'kernel'`` (the Pallas program ``sparse_decode``) or ``'xla'``
+    (the gathered softmax), ``picks`` the entries of a pick list,
+    ``topk`` how many of them a step above ``dense_len`` reads and
+    ``group`` the picks the kernel scores at a time::
+
+        with sparse_decode_traces() as traces:
+            step.lower(*args).compile()
+        assert [t['impl'] for t in traces] == ['kernel']
+    """
+    return _SPARSE_TRACES.open()
+
+
+def note_sparse_decode(trace):
+    _SPARSE_TRACES.note(trace)
 
 
 def _kernel_step(q, cache, qk_quant):

@@ -282,11 +282,13 @@ class GatedDeltaMixer(nn.Module):
     def __call__(self, h):
         return self._chunk(h, self.make_cache(h.shape[0], h.dtype))[1]
 
-    def prefill(self, h, cache):
-        """``h (B, n, dim)`` continuing ``cache``: ``(cache, out)``."""
+    def prefill(self, h, cache, position=None):
+        """``h (B, n, dim)`` continuing ``cache``: ``(cache, out)``
+        (``position``: every recurrent mixer is told it; nothing here
+        depends on one)."""
         return self._chunk(h, cache)
 
-    def decode(self, h, cache):
+    def decode(self, h, cache, position=None):
         """One token ``h (B, 1, dim)``: ``(cache, out)``."""
         impl = step_form(self.step_impl)
         _STEP_TRACES.note({'form': impl, 'chunk': self.chunk,

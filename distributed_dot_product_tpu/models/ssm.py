@@ -226,11 +226,13 @@ class Mamba2Mixer(nn.Module):
     def __call__(self, h):
         return self._chunk(h, self.make_cache(h.shape[0], h.dtype))[1]
 
-    def prefill(self, h, cache):
-        """``h (B, n, dim)`` continuing ``cache``: ``(cache, out)``."""
+    def prefill(self, h, cache, position=None):
+        """``h (B, n, dim)`` continuing ``cache``: ``(cache, out)``
+        (``position``: every recurrent mixer is told it; nothing here
+        depends on one)."""
         return self._chunk(h, cache)
 
-    def decode(self, h, cache):
+    def decode(self, h, cache, position=None):
         """One token ``h (B, 1, dim)``: ``(cache, out)``."""
         with device_scope('lm.ssm_proj'):
             z, x, b, c, dt, log_a, window = self._split(h, cache.conv)

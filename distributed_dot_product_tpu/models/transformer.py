@@ -47,6 +47,7 @@ from distributed_dot_product_tpu.models.hyper import (
 from distributed_dot_product_tpu.models.latent import (
     LatentAttention, init_latent_cache,
 )
+from distributed_dot_product_tpu.models.lightning import LightningMixer
 from distributed_dot_product_tpu.models.moe import GatedMLP, SparseExperts
 from distributed_dot_product_tpu.models.remat import (
     LAYER_MATMUL_NAMES, KeepWhatFits, named, new_layer,
@@ -59,8 +60,11 @@ __all__ = ['TransformerBlock', 'TransformerStack']
 
 
 # The recurrent mixers, by the name of a block's ``mixer`` AND of its
-# subtree: each takes ``ssm_kwargs`` and keeps a ``StateCache``.
-RECURRENT = {'ssm': Mamba2Mixer, 'delta': GatedDeltaMixer}
+# subtree: each takes ``ssm_kwargs`` and keeps a ``StateCache``; its
+# ``prefill`` / ``decode`` are told the ``position`` of the first new
+# token (the Lightning mixer rotates by it; the other two ignore it).
+RECURRENT = {'ssm': Mamba2Mixer, 'delta': GatedDeltaMixer,
+             'lightning': LightningMixer}
 
 
 def make_norm(kind, eps, dtype, name):
@@ -96,7 +100,12 @@ class TransformerBlock(nn.Module):
       (``models/ssm.Mamba2Mixer(**ssm_kwargs)``, the subtree ``ssm``;
       its cache a fixed-size ``StateCache``) | ``'delta'``
       (``models/delta.GatedDeltaMixer(**ssm_kwargs)``, the subtree
-      ``delta``; a ``StateCache`` too) | ``'none'``;
+      ``delta``; a ``StateCache`` too) | ``'lightning'``
+      (``models/lightning.LightningMixer(**ssm_kwargs)``, the subtree
+      ``lightning``: plain linear attention with RoPE, a ``StateCache``
+      and the stack's position) | ``'none'``. An ``'attention'`` mixer
+      with ``attn_kwargs['sparse']`` is the learned block-sparse layer
+      (``models/sparse.py``; its cache a ``SparseCache``);
     - ``ffn``: ``'gelu'`` (``mlp_ratio`` x dim) | ``'gated'``
       (``ffn_kwargs['hidden']``, SiLU-gated, no biases) | ``'experts'``
       (``models/moe.SparseExperts(**ffn_kwargs)``) | ``'none'``. A
@@ -164,7 +173,7 @@ class TransformerBlock(nn.Module):
                     **(self.ssm_kwargs or {})}))
         elif self.mixer != 'none':
             raise ValueError(f"mixer must be 'attention', 'latent', "
-                             f"'ssm', 'delta' or 'none', got "
+                             f"{sorted(RECURRENT)} or 'none', got "
                              f'{self.mixer!r}')
         one_branch = 'none' in (self.mixer, self.ffn)
         if one_branch and (self.parallel or self.mixer == self.ffn):
@@ -265,15 +274,17 @@ class TransformerBlock(nn.Module):
                              dropout_seed=dropout_seed)
         return self._both(x, mixer)
 
-    def _cached(self, method, x, cache, layer):
+    def _cached(self, method, x, cache, layer, position=None):
         """``prefill`` / ``decode`` share this: the mixer's cached entry
-        point in the attention branch."""
+        point in the attention branch. ``position``: the first new
+        token's, for a recurrent mixer (an attention cache carries its
+        own length)."""
         held = [cache]
 
         def mixer(h):
             if self.mixer in RECURRENT:
                 held[0], a = getattr(getattr(self, self.mixer), method)(
-                    h, held[0])
+                    h, held[0], position=position)
                 return a
             step = getattr(self.attn, method)
             if self.mixer == 'latent':
@@ -286,14 +297,14 @@ class TransformerBlock(nn.Module):
         x = self._both(x, mixer)
         return held[0], x
 
-    def prefill(self, x, cache, layer=None):
+    def prefill(self, x, cache, layer=None, position=None):
         # layer: a latent mixer's cache is layer-stacked in prefill too.
-        return self._cached('prefill', x, cache, layer)
+        return self._cached('prefill', x, cache, layer, position)
 
-    def decode(self, x, cache, layer=None):
+    def decode(self, x, cache, layer=None, position=None):
         # layer: cache is a layer-stacked cache and this block is layer
         # ``layer`` of it (a scanned stack) — see attn.decode.
-        return self._cached('decode', x, cache, layer)
+        return self._cached('decode', x, cache, layer, position)
 
 
 class _ScanStackCore(nn.Module):
@@ -524,7 +535,7 @@ class TransformerStack(nn.Module):
             # scan over periods; the latent cache is carried from block
             # to block, and a recurrent state is no layer of a stack.
             raise ValueError("more than one layer kind, mixer='latent', "
-                             "a recurrent mixer ('ssm', 'delta') and "
+                             f'a recurrent mixer {sorted(RECURRENT)} and '
                              "ffn='experts' run unrolled: pass "
                              'scan_layers=False')
         if not self.scan_layers:
@@ -629,6 +640,14 @@ class TransformerStack(nn.Module):
             cache, x = getattr(block, method)(x, cache, layer=i)
         return cache, x
 
+    @staticmethod
+    def _position(caches):
+        """Where the call's first token stands: the length of the first
+        layer cache that has one (the batch shares one clock), None in a
+        stack of recurrent layers alone."""
+        return next((c.length for c in caches if hasattr(c, 'length')),
+                    None)
+
     def prefill(self, x, caches):
         with device_scope('lm.stack_carry'):
             if self._latent:
@@ -636,9 +655,12 @@ class TransformerStack(nn.Module):
             if self.scan_layers:
                 x, caches = self.layers.prefill(x, caches)
                 return caches, x
+            # where the chunk's first token stands, for the recurrent
+            # mixers: the attention layers' length
+            position = self._position(caches)
             out = []
             for block, cache in zip(self.blocks, caches):
-                cache, x = block.prefill(x, cache)
+                cache, x = block.prefill(x, cache, position=position)
                 out.append(cache)
             return out, x
 
@@ -651,8 +673,9 @@ class TransformerStack(nn.Module):
                     (x, caches), jnp.arange(self.n_layers,
                                             dtype=jnp.int32))
                 return caches, x
+            position = self._position(caches)
             out = []
             for block, cache in zip(self.blocks, caches):
-                cache, x = block.decode(x, cache)
+                cache, x = block.decode(x, cache, position=position)
                 out.append(cache)
             return out, x

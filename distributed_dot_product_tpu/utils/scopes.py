@@ -97,6 +97,32 @@ DEVICE_SCOPES = {
                       'chunked form (the chunks\' decay-difference '
                       'products, the batched triangular inverse, the '
                       'scan over chunks)',
+    'lm.lightning_proj': 'a Lightning linear-attention mixer outside its '
+                         'recurrence: the input projection, the '
+                         'per-head norms of q and k, their rotation, '
+                         'the output norm under its sigmoid gate and the '
+                         'output projection',
+    'ops.lightning_step': 'decode: the one read-modify-write pass over a '
+                          'Lightning layer state (constant decay a '
+                          'head, outer-product update, the read against '
+                          'q): ssm.state_step, one XLA fusion',
+    'ops.lightning_scan': 'prefill / whole sequence: the Lightning '
+                          'recurrence in its chunked form '
+                          '(ssm.chunked_scan)',
+    'ops.sparse_select': 'the selection of a block-sparse attention '
+                         'layer: the pooled-key rows a step or a chunk '
+                         'completes, the scores against the pooled keys, '
+                         'softmax, the sum over a KV head\'s query heads, '
+                         'the max-pool to blocks, the forced blocks, '
+                         'top-k and the sort of the picks',
+    'ops.sparse_decode': 'decode: the Pallas kernel sparse_decode (the '
+                         'new row appended in place, the picked blocks '
+                         'moved by the kernel\'s own copies, an online '
+                         'softmax over them), or the gathered softmax '
+                         'that stands for it',
+    'ops.sparse_prefill': 'prefill / whole sequence of a block-sparse '
+                          'layer outside its selection: the block mask '
+                          'of the picks and the flash forward under it',
     'lm.state_restore': 'copies of the recurrent layers\' states: the '
                         'snapshot at a prompt\'s end and the restore '
                         'from it between requests',

@@ -80,13 +80,22 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     assert lines[-1] == {'ok': False, 'device': lines[0]['device']}
     phases = {rec['phase']: rec for rec in lines if 'phase' in rec}
     assert set(phases) == {'train', 'generate', 'generate_latent',
-                           'generate_mixed', 'generate_hybrid', 'serve'}
+                           'generate_mixed', 'generate_hybrid',
+                           'generate_sparse', 'serve'}
     # a recurrent state beside a slab: the request after a restore reads
     # what the first did
     hybrid = phases['generate_hybrid']
     assert hybrid['hybrid_caches'] == ['NoneType', 'StateCache',
                                        'DecodeCache']
     assert hybrid['checks']['hybrid.restored_request_agrees'] is True
+    # a block-sparse layer beside a Lightning state: every served step
+    # picks its topk blocks, and its form is printed beside the counters
+    sparse = phases['generate_sparse']
+    assert sparse['sparse_caches'] == ['SparseCache', 'StateCache']
+    assert sparse['sparse_decode'] == [
+        {'impl': 'xla', 'picks': 2, 'topk': 2, 'group': 2}]
+    assert sparse['checks']['sparse.every_step_picks_topk'] is True
+    assert sparse['checks']['sparse.restored_request_agrees'] is True
     # both kernel modes side by side, off the chip both through XLA
     assert phases['generate_mixed']['mixed_caches'] == ['layer', 'ring']
     for name, rec in phases.items():
@@ -95,5 +104,6 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
         # Everything a CPU can get right is right; only the checks
         # that need the chip fail.
         assert failed and all(
-            k.endswith('tpu_custom_call') or k.endswith('resolved_kernel')
+            k.endswith(('tpu_custom_call', 'resolved_kernel',
+                        'step_is_the_kernel'))
             for k in failed), (name, failed)

@@ -71,6 +71,15 @@ DELTA_SCOPES = ['ops.delta_step', 'ops.delta_scan', 'lm.delta_proj',
                 'ops.flash_decode', 'lm.attn_proj', 'lm.mlp',
                 'lm.moe_route', 'lm.moe_experts', 'lm.embed', 'lm.head',
                 'lm.stack_carry']
+# The block-sparse attention / Lightning linear-attention stack: the
+# decode step (the kernel ``sparse_decode``, the state's one pass) and a
+# prefill chunk (the picks' block mask under the flash forward, the
+# chunked scan).
+SALA_SCOPES = ['ops.sparse_select', 'ops.sparse_decode',
+               'ops.sparse_prefill', 'ops.lightning_step',
+               'ops.lightning_scan', 'lm.lightning_proj', 'ops.flash_fwd',
+               'lm.attn_proj', 'lm.mlp', 'lm.embed', 'lm.head',
+               'lm.stack_carry']
 
 
 def tiny_lm(remat_policy=None, **attn_kwargs):
@@ -250,6 +259,33 @@ def delta_op_names():
         for method, n in (('decode', 1), ('prefill', 8))}
 
 
+@pytest.fixture(scope='module')
+def sala_op_names():
+    """``{'decode': …, 'prefill': …}`` of the block-sparse / Lightning
+    stack, the sparse layer's step as the kernel."""
+    model = TransformerLM(
+        vocab_size=64, dim=32, num_heads=4, n_layers=2, scan_layers=False,
+        tie_embeddings=False, embed_scale=12.0, logit_scale=0.0625,
+        attn_kwargs=dict(distributed=False, decode_impl='kernel',
+                         use_rope=False, out_gate=True, qk_norm=True,
+                         num_kv_heads=2),
+        block_kwargs=dict(norm='rmsnorm', residual_scale=0.25,
+                          ffn='gated', ffn_kwargs=dict(hidden=64)),
+        layer_kinds={
+            'sparse': dict(mixer='attention', attn_kwargs=dict(sparse=dict(
+                kernel=8, stride=4, block=16, window=32, topk=4,
+                dense_len=64))),
+            'lightning': dict(mixer='lightning', ssm_kwargs=dict(
+                heads=4, head_dim=8, chunk=8))},
+        layer_pattern=('sparse', 'lightning'))
+    params = model.init(jax.random.key(0), jnp.zeros((2, 8), jnp.int32))
+    caches = model.make_decode_caches(2, 128)
+    return {method: op_names(jax.jit(
+        lambda p, tok, c, m=method: model.apply(p, tok, c, method=m)
+    ).lower(params, jnp.zeros((2, n), jnp.int32), caches).compile())
+        for method, n in (('decode', 1), ('prefill', 16))}
+
+
 def opened(scope, names):
     return any(f'/{scope}/' in f'/{name}/' for name in names)
 
@@ -288,6 +324,41 @@ def test_granite_decode_step_opens(scope, granite_op_names):
 def test_delta_stack_opens(scope, delta_op_names):
     assert opened(scope, delta_op_names['decode']
                   | delta_op_names['prefill'])
+
+
+@pytest.mark.parametrize('scope', SALA_SCOPES)
+def test_sala_stack_opens(scope, sala_op_names):
+    assert opened(scope, sala_op_names['decode']
+                  | sala_op_names['prefill'])
+
+
+def test_the_sparse_and_lightning_arithmetic_sits_in_its_scopes(
+        sala_op_names):
+    """Nothing of the step's or the prefill's own arithmetic is
+    unscoped; the Lightning mixer's is ``ops.lightning_step`` /
+    ``ops.lightning_scan`` or ``lm.lightning_proj``, never the stack's;
+    the sparse layer's selection (its ``top_k`` among it) is
+    ``ops.sparse_select``, the kernel ``sparse_decode`` sits in
+    ``ops.sparse_decode`` and the flash forward of a chunk inside
+    ``ops.sparse_prefill``."""
+    def innermost(name):
+        return [part for part in name.split('/') if part in DEVICE_SCOPES][
+            -1]
+    for method, recurrence in (('decode', 'ops.lightning_step'),
+                               ('prefill', 'ops.lightning_scan')):
+        mine = [n for n in sala_op_names[method] if n.startswith('jit(')]
+        assert mine and all(
+            any(opened(scope, [n]) for scope in DEVICE_SCOPES)
+            for n in mine)
+        inside = {innermost(n) for n in mine if '/lightning.' in n}
+        assert inside == {recurrence, 'lm.lightning_proj'}
+        assert any(innermost(n) == 'ops.sparse_select'
+                   and n.endswith('/top_k') for n in mine)
+    kernel = [n for n in sala_op_names['decode'] if '/sparse_decode/' in n]
+    assert kernel and all('/ops.sparse_decode/sparse_decode/' in n
+                          for n in kernel)
+    flash = [n for n in sala_op_names['prefill'] if '/ops.flash_fwd/' in n]
+    assert flash and all('/ops.sparse_prefill/' in n for n in flash)
 
 
 def test_the_delta_rules_arithmetic_sits_in_its_scopes(delta_op_names):
@@ -367,7 +438,7 @@ def test_latent_kernel_is_outside_the_projection_scope(latent_op_names):
 def test_the_steps_cover_the_vocabulary():
     assert (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(LATENT_SCOPES)
             | set(MIXED_SCOPES) | set(HYBRID_SCOPES) | set(DELTA_SCOPES)
-            == set(DEVICE_SCOPES))
+            | set(SALA_SCOPES) == set(DEVICE_SCOPES))
 
 
 def test_unknown_scope_raises():

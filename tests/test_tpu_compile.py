@@ -1236,3 +1236,125 @@ def test_the_fit_holds_at_a_width_it_was_not_fitted_on(chip, monkeypatch):
             *FLASH_RESIDUAL_NAMES, *LAYER_MATMUL_NAMES[:2]))
     two, fit = _train_step_for_v5e(chip, by_hand, optax.adamw(3e-4))
     assert fit is None and re.findall(XLAS_OWN_REMAT, two.as_text())
+
+
+@pytest.mark.parametrize('shape', [
+    # (sessions, heads, KV heads, t_max, block, picks): the cell's call,
+    # a pick list that is one group, and a wide group of query heads.
+    (64, 32, 2, 66560, 64, 128), (4, 8, 2, 4096, 64, 16),
+    (2, 64, 1, 8192, 128, 64)], ids=['cell', 'one_group', 'mqa'])
+def test_sparse_decode_kernel_compiles_for_v5e(chip, shape):
+    """``ops/pallas_sparse.sparse_decode`` for a described v5e, the K/V
+    buffers donated: ONE Mosaic call whose grid is (sessions, KV heads)
+    — not a grid step a picked block —, both buffers aliased, no
+    temporary as large as a session's rows."""
+    from distributed_dot_product_tpu.ops.pallas_sparse import (
+        picks_group, sparse_decode,
+    )
+    b, h, kv, t_max, block, picks = shape
+    d = 128
+    bf = jnp.bfloat16
+    args = (jnp.zeros((b, h, 1, d), bf), jnp.zeros((b, kv, 1, d), bf),
+            jnp.zeros((b, kv, 1, d), bf),
+            jnp.zeros((b, kv, t_max, d), bf),
+            jnp.zeros((b, kv, t_max, d), bf),
+            jnp.zeros((b, kv, picks), jnp.int32),
+            jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+    assert picks_group(picks, block) * block <= 1024
+    compiled = _compile(
+        chip, lambda *a: sparse_decode(*a, block=block, interpret=False),
+        *args, donate=(3, 4))
+    hlo = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                          r'sparse_decode', hlo)) == 1
+    mem = compiled.memory_analysis()
+    buffers = 2 * b * kv * t_max * d * 2
+    assert mem.alias_size_in_bytes == buffers
+    assert mem.temp_size_in_bytes < 2 * kv * t_max * d * 2
+
+
+def test_sala_decode_step_picks_its_rows_reads_three_states_once_and_fits(
+        chip, monkeypatch):
+    """The token step of the block-sparse / Lightning stack at the
+    published widths and the traffic of ``minicpm-sala.decode-64k`` (4
+    layers, 64 sessions, one 66560-row slab of 2 KV heads with 4160
+    pooled rows beside three ``(64, 32, 128, 128)`` float32 states),
+    caches donated: the sparse layer's step is the kernel
+    ``sparse_decode`` over a pick list of 128 entries of which 64 are
+    read above ``dense_len``, 16 picks a group — one custom call, the
+    only one of the step; every Lightning state is taken by ONE fusion
+    (read once, written once: no Pallas kernel is owed); nothing as
+    large as a layer's 64 states (134 MB) is copied, sliced or written
+    back but the pooled buffer's one-row update in place, and no
+    temporary is that large; arguments + temporaries with
+    the snapshot counted stay between 8 and 10 GiB. The reset between
+    requests writes the three states over in place."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_sala as driver
+    from distributed_dot_product_tpu.models.decode import (
+        SparseCache, StateCache, sparse_decode_traces,
+    )
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'minicpm-sala-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-64k-x64.json')) as f:
+        traffic = json.load(f)
+    model = driver.build_lm(cfg)
+    params = _shape_table_params(driver, cfg)
+    sessions, t_max = traffic['sessions'], traffic['t_max']
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(sessions, t_max))
+    assert [type(c) for c in caches] == [SparseCache] + 3 * [StateCache]
+    assert caches[0].k.shape == (sessions, 2, t_max, 128)
+    assert caches[0].pooled.shape == (sessions, 2, t_max // 16, 128)
+    assert caches[1].state.shape == (sessions, 32, 128, 128)
+    assert caches[1].state.dtype == jnp.float32
+    assert caches[1].conv.shape[1] == 0          # no convolution window
+    stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
+    tok = jnp.zeros((sessions, 1), jnp.int32)
+    restore, step = driver.make_programs(model, cfg)[-2:]
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+    with sparse_decode_traces() as forms:
+        compiled = step.lower(
+            *described((params, tok, caches, stats))).compile()
+    assert forms == [{'impl': 'kernel', 'picks': 128, 'topk': 64,
+                      'group': 16}]
+    hlo = compiled.as_text()
+    assert hlo.count('tpu_custom_call') == len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*sparse_decode',
+        hlo)) == 1
+    state_bytes = sessions * 32 * 128 * 128 * 4
+    # (the one move of that size is the pooled buffer's in-place write
+    # of the ONE row a step completes: 136 MB by its result type)
+    moves = _cache_sized_moves(hlo, state_bytes)
+    assert len(moves) == 1 and 'dynamic-update-slice(%c_0__pooled' in (
+        moves[0])
+    takers = _entry_readers(hlo, f'f32[{sessions},32,128,128]')
+    assert len(takers) == 3 and all(' fusion(' in t for t in takers)
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < state_bytes
+    states = [c if hasattr(c, 'state') else None for c in caches]
+    snapshot_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                         for x in jax.tree.leaves(states))
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            + snapshot_bytes)
+    assert 8.0 * 2 ** 30 <= peak <= 10.0 * 2 ** 30
+    restored = restore.lower(*described(
+        (caches, states, jnp.zeros((), jnp.int32)))).compile()
+    assert restored.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert restored.memory_analysis().alias_size_in_bytes >= (
+        cache_bytes - 4)
+    assert restored.as_text().count('lm.state_restore') >= 6
