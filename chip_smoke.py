@@ -667,7 +667,12 @@ def phase_generate_sparse(progs, cfg, seed):
     state restored and the slab's length set back (the pooled keys
     rewind with it), and the request again — which must read what the
     first did, bit for bit — with the sparse layer's step on the kernel
-    ``sparse_decode`` (``sparse_decode_traces()``)."""
+    ``sparse_decode`` (``sparse_decode_traces()``) and its pick list the
+    threshold's (``sparse_pick``). The picks themselves are made twice
+    from one set of block scores — seeded queries, a row a served step,
+    against the served session's own pooled keys — as the layer makes
+    them on this backend (``pick_blocks``) and as ``lax.top_k`` and a
+    sort do: entry for entry the same."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -675,6 +680,10 @@ def phase_generate_sparse(progs, cfg, seed):
     from distributed_dot_product_tpu.models.decode import (
         restore_states, snapshot_states, sparse_decode_traces,
     )
+    from distributed_dot_product_tpu.models.sparse import (
+        SparseSpec, block_scores, pick_blocks,
+    )
+    from distributed_dot_product_tpu.ops.pallas_sparse import sorted_picks
     model = sparse_lm(cfg)
     n, steps, t_max = cfg['prompt'], cfg['new_tokens'], cfg['gen_t_max']
     params = {'params': model.init(
@@ -717,6 +726,21 @@ def phase_generate_sparse(progs, cfg, seed):
     moved = float(np.max(np.abs(
         np.asarray(caches[1].state) - np.asarray(taken[1].state))))
     caches, again, _ = request(reset(caches, taken))
+    spec = SparseSpec(**cfg['sparse']['select'])
+    c = cfg['sparse']
+    d = c['dim'] // c['heads']
+
+    @jax.jit
+    def both(pooled):
+        keys = n + 1 + jnp.arange(steps)
+        scores = block_scores(
+            jax.random.normal(jax.random.key(seed + 3),
+                              (1, c['heads'], steps, d), jnp.bfloat16),
+            pooled, keys, spec, d ** -0.5, t_max // spec.block)
+        return (pick_blocks(scores, keys, spec)[0][..., :topk],
+                sorted_picks(scores, topk))
+    progs.compile('sparse.picks', both, caches[0].pooled, pallas=True)
+    routed, by_sort = (np.asarray(x) for x in both(caches[0].pooled))
     return {
         'sparse_caches': kinds,
         'sparse_decode': forms,
@@ -726,7 +750,11 @@ def phase_generate_sparse(progs, cfg, seed):
         'checks': {
             'sparse.cache_kinds': kinds == ['SparseCache', 'StateCache'],
             'sparse.step_is_the_kernel': [
-                (f['impl'], f['topk']) for f in forms] == [('kernel', topk)],
+                (f['impl'], f['topk'], f['select']) for f in forms] == [
+                    ('kernel', topk, 'threshold')],
+            'sparse.picks_are_the_sorted_route_s': bool(
+                routed.shape == (1, c['kv_heads'], steps, topk)
+                and np.array_equal(routed, by_sort)),
             'sparse.every_step_picks_topk': counts == steps * [topk],
             'sparse.logits_finite': bool(np.all(np.isfinite(once))),
             'sparse.a_request_moves_the_state': moved > 0,

@@ -2033,11 +2033,11 @@ _SPARSE_TRACES = TraceSinks()
 def sparse_decode_traces():
     """Collect the form of every block-sparse layer's decode step
     (``models/sparse.sparse_step``) while the block runs: one dict
-    ``{'impl', 'picks', 'topk', 'group'}`` per TRACE — ``impl`` is
-    ``'kernel'`` (the Pallas program ``sparse_decode``) or ``'xla'``
-    (the gathered softmax), ``picks`` the entries of a pick list,
-    ``topk`` how many of them a step above ``dense_len`` reads and
-    ``group`` the picks the kernel scores at a time::
+    ``{'impl', 'picks', 'topk', 'group', 'select'}`` per TRACE: ``impl``
+    ``'kernel'`` (the Pallas program ``sparse_decode``) or ``'xla'`` (the
+    gathered softmax), ``picks`` the entries of a pick list, ``topk`` how
+    many a step above ``dense_len`` reads, ``group`` the picks scored at
+    a time, ``select`` ``'threshold'`` (``sparse_pick``) or ``'sort'``::
 
         with sparse_decode_traces() as traces:
             step.lower(*args).compile()

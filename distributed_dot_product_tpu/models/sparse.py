@@ -34,7 +34,10 @@ Three pieces, each tested apart (``tests/test_sparse_attention.py``):
   ascending order ``(…, P) int32`` with ``P = max(topk, dense_len /
   block)`` and the count of them that are valid (below ``dense_len``
   the picks are simply all blocks; above it ``topk`` of them, the rest
-  a repeat of the last);
+  a repeat of the last). On a TPU the ``topk`` come from an exact
+  threshold over the row's block scores, every row of the call in one
+  Pallas program (``ops/pallas_sparse.threshold_picks``); elsewhere
+  from ``lax.top_k`` and a sort, the same entries (:func:`pick_form`);
 - the ATTENTION over the picks: one token through
   ``ops/pallas_sparse.sparse_decode`` (:func:`sparse_step`), a chunk of
   a prompt through the flash forward under a block mask
@@ -53,12 +56,13 @@ from distributed_dot_product_tpu.ops.pallas_attention import (
     flash_attention,
 )
 from distributed_dot_product_tpu.ops.pallas_sparse import (
-    picks_group, sparse_decode, sparse_decode_reference,
+    picks_group, sorted_picks, sparse_decode, sparse_decode_reference,
+    threshold_picks,
 )
 from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['SparseSpec', 'pool_rows', 'pooled_after_chunk',
-           'pooled_after_step', 'block_scores', 'pick_blocks',
+           'pooled_after_step', 'block_scores', 'pick_form', 'pick_blocks',
            'sparse_select', 'sparse_attention', 'sparse_step']
 
 
@@ -175,6 +179,14 @@ def block_scores(q, pooled, keys, spec, scale, n_blocks):
     return jnp.where(b <= own, best, -jnp.inf)
 
 
+def pick_form():
+    """How :func:`pick_blocks` finds a row's best blocks: ``'threshold'``
+    (``ops/pallas_sparse.threshold_picks``, one Pallas program for all
+    rows and no sort) on a TPU, ``'sort'`` (``lax.top_k`` and a sort of
+    its picks: the oracle) elsewhere. The same entries either way."""
+    return 'threshold' if jax.default_backend() == 'tpu' else 'sort'
+
+
 def pick_blocks(scores, keys, spec):
     """The picks of :func:`block_scores`' ``scores (B, H_kv, T,
     n_blocks)``: ``(picks (B, H_kv, T, P) int32, count (T,) int32)``,
@@ -182,7 +194,8 @@ def pick_blocks(scores, keys, spec):
     last of them, every entry a block of the cache."""
     n_blocks = scores.shape[-1]
     k = min(spec.topk, n_blocks)
-    picked = jnp.sort(lax.top_k(scores, k)[1].astype(jnp.int32), axis=-1)
+    picked = (threshold_picks if pick_form() == 'threshold'
+              else sorted_picks)(scores, k)
     picked = jnp.pad(picked, ((0, 0),) * 3 + ((0, spec.picks - k),),
                      mode='edge')
     every = jnp.minimum(jnp.arange(spec.picks, dtype=jnp.int32),
@@ -285,7 +298,8 @@ def sparse_step(q, cache, k_new, v_new, spec, scale=None, impl=None,
         picks, count = picks[:, :, 0], count[0]
     note_sparse_decode({
         'impl': impl, 'picks': spec.picks, 'topk': spec.topk,
-        'group': picks_group(spec.picks, spec.block)})
+        'group': picks_group(spec.picks, spec.block),
+        'select': pick_form()})
     with device_scope('ops.sparse_decode'):
         step = (functools.partial(sparse_decode, interpret=interpret)
                 if impl == 'kernel' else sparse_decode_reference)

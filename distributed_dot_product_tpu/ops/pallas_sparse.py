@@ -38,6 +38,27 @@ Off the TPU the kernel runs under the Pallas interpreter, as the other
 kernels do. :func:`sparse_decode_reference` is the same step as a
 gathered softmax in plain ``jax.numpy``: the tests' oracle, and what a
 layer runs where it is told ``'xla'``.
+
+The pick list itself — the ``k`` best of a row of block scores, in
+ascending block order — is :func:`threshold_picks`: ONE Pallas program
+(``name='sparse_pick'``) for all the rows of a call, 128 rows a grid
+step, rows on sublanes and blocks on lanes, with no sort in it:
+
+- a float32 score goes to its order-preserving int32 image (the bits,
+  with the magnitude flipped where the sign is set: XLA's total order,
+  ``-inf < -1.0 < -0.0 < +0.0 < +inf``); the row's ``k``-th largest
+  image ``thr`` is built a bit a round from the top, a round one compare
+  and one count along the row;
+- the picks are the blocks above ``thr`` and, of those equal to it, the
+  lowest-numbered ``k - #above`` — ``lax.top_k``'s tie rule, so the SET
+  is :func:`sorted_picks`' on every input;
+- with ``c_b`` the picks at or below block ``b`` (two running counts,
+  each a 128-lane tile's product with a triangle of ones), the ``j``-th
+  pick is ``#{b : c_b <= j}``: ascending as it is made.
+
+:func:`sorted_picks` is ``lax.top_k`` and a sort of its indices — two
+full sorts a row as XLA lowers them for a TPU: the tests' oracle, and
+what a layer runs off the TPU.
 """
 
 import functools
@@ -49,9 +70,14 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ['sparse_decode', 'sparse_decode_reference', 'picks_group']
+__all__ = ['sparse_decode', 'sparse_decode_reference', 'picks_group',
+           'threshold_picks', 'sorted_picks']
 
 _NEG_BIG = -1e30
+_LANES = 128
+_PICK_ROWS = 128        # rows of scores a grid step of ``sparse_pick``,
+_PICK_CELLS = 1 << 18   # and the most scores: 1 MiB an array in VMEM
+_INT_MIN = -2 ** 31
 
 
 def picks_group(picks, block):
@@ -281,3 +307,90 @@ def sparse_decode(q, k_new, v_new, k_cache, v_cache, picks, count, length,
             picks.astype(jnp.int32), meta, qg, padded(k_new, k_cache),
             padded(v_new, v_cache), k_cache, v_cache)
     return (out[:, :, :per].reshape(bsz, heads, 1, d_v), k_cache, v_cache)
+
+
+def sorted_picks(scores, k):
+    """The ``k`` best entries of each row of ``scores (…, n)``, ties to
+    the lower index, as ascending indices ``(…, k) int32``:
+    ``lax.top_k`` and a sort of what it picked."""
+    return jnp.sort(lax.top_k(scores, k)[1].astype(jnp.int32), axis=-1)
+
+
+def _pick_kernel(s_ref, o_ref, *, k, n):
+    rows, width = s_ref.shape
+    bits = lax.bitcast_convert_type(s_ref[...], jnp.int32)
+    key = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    # (a column past the row — the block overhangs the array — is below
+    # every score, and a higher index than any that ties with it)
+    col = lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    key = jnp.where(col < n, key, _INT_MIN)
+
+    # thr, the k-th largest image, as its offset from INT_MIN: the
+    # largest t with #{key >= t} >= k, a bit a round.
+    def bit(i, t):
+        up = t | lax.shift_left(jnp.int32(1), 31 - i)
+        at_least = jnp.sum(jnp.where(key >= (up ^ _INT_MIN), 1.0, 0.0),
+                           axis=-1, keepdims=True)
+        return jnp.where(at_least >= k, up, t)
+    thr = lax.fori_loop(0, 32, bit, jnp.zeros((rows, 1), jnp.int32)
+                        ) ^ _INT_MIN
+
+    # Running counts along the row, a lane tile at a time: the tile
+    # against a triangle of ones, and against ones for the tiles after.
+    i = lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+    j = lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
+    upto = (i <= j).astype(jnp.bfloat16)
+    ones = jnp.ones((_LANES, _LANES), jnp.bfloat16)
+
+    def running(mask):
+        """Per lane tile, how many of ``mask`` lie at or below each
+        column; and the row's total in every lane."""
+        before, tiles = jnp.zeros((rows, _LANES), jnp.float32), []
+        for t in range(width // _LANES):
+            m = mask[:, t * _LANES:(t + 1) * _LANES].astype(jnp.bfloat16)
+            tiles.append(before + jnp.dot(
+                m, upto, preferred_element_type=jnp.float32))
+            before = before + jnp.dot(
+                m, ones, preferred_element_type=jnp.float32)
+        return tiles, before
+    above, n_above = running(jnp.where(key > thr, 1.0, 0.0))
+    equal, _ = running(jnp.where(key == thr, 1.0, 0.0))
+    picked = jnp.concatenate(
+        [a + jnp.minimum(e, k - n_above) for a, e in zip(above, equal)],
+        axis=-1)                                        # c_b, (rows, width)
+
+    slot = lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
+
+    def place(j, out):
+        below = jnp.sum(jnp.where(picked <= j.astype(jnp.float32), 1.0,
+                                  0.0), axis=-1, keepdims=True)
+        return jnp.where(slot == j, below.astype(jnp.int32), out)
+    o_ref[...] = lax.fori_loop(0, k, place,
+                               jnp.zeros(o_ref.shape, jnp.int32))
+
+
+def threshold_picks(scores, k, *, interpret=None):
+    """:func:`sorted_picks` with no sort (module docstring): ``scores
+    (…, n) float32`` to ``(…, k) int32``, ``k <= n``. Exact: the same
+    entries on every input."""
+    *lead, n = scores.shape
+    if scores.dtype != jnp.float32 or not 0 < k <= n:
+        raise ValueError(f'threshold_picks takes float32 scores and 0 < k '
+                         f'<= n: got {scores.dtype}, k {k} of {n}')
+    if interpret is None:
+        interpret = jax.default_backend() != 'tpu'
+    rows = math.prod(lead)
+    width, slots = (-(-x // _LANES) * _LANES for x in (n, k))
+    tile = min(_PICK_ROWS, -(-rows // 8) * 8,
+               max(8, _PICK_CELLS // width // 8 * 8))
+    out = pl.pallas_call(
+        functools.partial(_pick_kernel, k=k, n=n),
+        grid=(pl.cdiv(rows, tile),),
+        in_specs=[pl.BlockSpec((tile, width), lambda r: (r, 0))],
+        out_specs=pl.BlockSpec((tile, slots), lambda r: (r, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, slots), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel',)),
+        interpret=interpret,
+        name='sparse_pick')(scores.reshape(rows, n))
+    return out[:, :k].reshape(*lead, k)

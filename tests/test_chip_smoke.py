@@ -93,7 +93,8 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     sparse = phases['generate_sparse']
     assert sparse['sparse_caches'] == ['SparseCache', 'StateCache']
     assert sparse['sparse_decode'] == [
-        {'impl': 'xla', 'picks': 2, 'topk': 2, 'group': 2}]
+        {'impl': 'xla', 'picks': 2, 'topk': 2, 'group': 2,
+         'select': 'sort'}]
     assert sparse['checks']['sparse.every_step_picks_topk'] is True
     assert sparse['checks']['sparse.restored_request_agrees'] is True
     # both kernel modes side by side, off the chip both through XLA

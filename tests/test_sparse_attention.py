@@ -25,8 +25,10 @@ from distributed_dot_product_tpu.models.sparse import (
     SparseSpec, block_scores, pick_blocks, pool_rows, pooled_after_chunk,
     pooled_after_step, sparse_attention, sparse_select, sparse_step,
 )
+from distributed_dot_product_tpu.models import sparse as sparse_model
 from distributed_dot_product_tpu.ops.pallas_sparse import (
-    picks_group, sparse_decode, sparse_decode_reference,
+    picks_group, sorted_picks, sparse_decode, sparse_decode_reference,
+    threshold_picks,
 )
 
 SPEC = SparseSpec(kernel=8, stride=4, block=16, init_blocks=1, window=32,
@@ -179,6 +181,93 @@ def test_ties_go_to_the_lower_block_and_forced_blocks_come_first():
     # block 0, the local two (7, 8), and of the all-equal rest the lowest
     assert np.asarray(picks[0, 0, 0]).tolist() == [0, 1, 7, 8]
     assert int(count[0]) == 4
+
+
+def _forced(scores, own, local):
+    """``block_scores``' last two lines: block 0 and the ``local`` blocks
+    up to ``own`` at ``+inf``, ``-inf`` past ``own``."""
+    b = jnp.arange(scores.shape[-1])
+    scores = jnp.where((b < 1) | (b > own - local), jnp.inf, scores)
+    return jnp.where(b <= own, scores, -jnp.inf)
+
+
+def _pick_case(name):
+    """``(scores (…, n) float32, k)`` of one case of the threshold pick."""
+    coarse = jnp.round(normal(11, 8, 300) * 2) / 2      # ~12 levels of 300
+    return {
+        'one_row': lambda: (normal(12, 1, 1, 1, 200), 16),
+        'step_128_rows': lambda: (jnp.abs(normal(13, 64, 2, 1, 1040)), 64),
+        'chunk_1024_rows': lambda: (
+            jnp.abs(normal(14, 1, 2, 512, 1040)), 64),
+        'whole_lane_tiles': lambda: (normal(15, 3, 256), 128),
+        'ties_across_the_kth_place': lambda: (coarse, 37),
+        'every_score_equal': lambda: (jnp.full((2, 140), 0.25), 9),
+        'signed_zeros': lambda: (
+            jnp.asarray([[0.0, -0.0, 0.0, -0.0, -1.0, 0.0, -0.0, 1.0]]), 4),
+        'forced_blocks_and_none_past_the_own': lambda: (
+            _forced(jnp.abs(normal(16, 4, 2, 1, 1040)) * 0.004, 1027, 32),
+            64),
+        'forced_blocks_tied_rest': lambda: (
+            _forced(jnp.full((1, 1, 1, 12), 0.25), 8, 2), 4),
+        'more_picks_than_live_blocks': lambda: (
+            _forced(jnp.abs(normal(17, 2, 40)), 5, 2), 8),
+        'incomplete_rows': lambda: (
+            _forced(jnp.where(jnp.arange(40) % 3 > 0, -1.0,
+                              jnp.abs(normal(18, 5, 40))), 30, 2), 16),
+        'every_block_picked': lambda: (normal(19, 3, 40), 40),
+        'picks_not_a_whole_group': lambda: (normal(20, 2, 2, 1, 24), 6),
+    }[name]()
+
+
+@pytest.mark.parametrize('name', [
+    'one_row', 'step_128_rows', 'chunk_1024_rows', 'whole_lane_tiles',
+    'ties_across_the_kth_place', 'every_score_equal', 'signed_zeros',
+    'forced_blocks_and_none_past_the_own', 'forced_blocks_tied_rest',
+    'more_picks_than_live_blocks', 'incomplete_rows', 'every_block_picked',
+    'picks_not_a_whole_group'])
+def test_the_threshold_pick_is_the_sorted_pick_entry_for_entry(name):
+    """``threshold_picks`` (the Pallas program, interpreted) against
+    ``lax.top_k`` and a sort of its picks: 1, 128 and 1024 rows, blocks
+    a whole number of lane tiles and not, ties that straddle the k-th
+    place (to the lower block), ``+0.0`` above ``-0.0`` as XLA's order
+    has them, the forced ``+inf`` blocks with ``-inf`` past the token's
+    own (and fewer live blocks than picks: the ``-inf`` ones by number),
+    the ``-1.0`` of incomplete pooled rows, ``k`` = every block, and a
+    ``k`` that is no multiple of the kernel's copy group."""
+    scores, k = _pick_case(name)
+    want = sorted_picks(scores, k)
+    assert want.shape == scores.shape[:-1] + (k,)
+    np.testing.assert_array_equal(
+        threshold_picks(scores, k, interpret=True), want)
+
+
+def test_threshold_picks_refuses_what_it_does_not_cover():
+    for scores, k in ((normal(21, 2, 40).astype(jnp.bfloat16), 4),
+                      (normal(21, 2, 40), 41), (normal(21, 2, 40), 0)):
+        with pytest.raises(ValueError, match='float32 scores'):
+            threshold_picks(scores, k, interpret=True)
+
+
+@pytest.mark.parametrize('n', [60, 128, 130, 192])
+def test_both_routes_of_the_selection_give_one_pick_list(n, monkeypatch):
+    """``pick_blocks`` on its TPU route (steered here: the program asks
+    the backend) against its route off the TPU, below and above
+    ``dense_len``, under a ``SparseSpec`` whose ``topk`` 6 is no whole
+    copy group of its 8-entry pick list: picks, padding and count
+    alike."""
+    spec = SparseSpec(kernel=8, stride=4, block=16, window=32, topk=6,
+                      dense_len=128)
+    assert spec.picks == 8 and picks_group(spec.picks, spec.block) == 8
+    scores = block_scores(normal(22, 2, 4, 3, D) * 3.0,
+                          normal(23, 2, 2, 48, D),
+                          jnp.asarray([n - 2, n - 1, n]), spec, 0.25, 12)
+    keys = jnp.asarray([n - 2, n - 1, n])
+    assert sparse_model.pick_form() == 'sort'
+    want = pick_blocks(scores, keys, spec)
+    monkeypatch.setattr(sparse_model, 'pick_form', lambda: 'threshold')
+    got = pick_blocks(scores, keys, spec)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_a_cache_of_fewer_blocks_than_topk_picks_them_all():
@@ -360,7 +449,8 @@ def test_prefill_then_decode_is_the_forward(prompt, impl, chunks):
         for t in range(start, 160):
             cache, o = step(cache, x[:, t:t + 1])
             outs.append(o)
-    assert forms == [{'impl': impl, 'picks': 4, 'topk': 4, 'group': 4}]
+    assert forms == [{'impl': impl, 'picks': 4, 'topk': 4, 'group': 4,
+                      'select': 'sort'}]
     assert int(cache.length) == 160
     np.testing.assert_allclose(jnp.concatenate(outs, 1), want, atol=3e-5,
                                rtol=3e-5)

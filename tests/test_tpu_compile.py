@@ -1238,6 +1238,16 @@ def test_the_fit_holds_at_a_width_it_was_not_fitted_on(chip, monkeypatch):
     assert fit is None and re.findall(XLAS_OWN_REMAT, two.as_text())
 
 
+SORT = r'\bsort\('
+
+
+def _mosaic_calls(hlo):
+    """The Pallas ``name=`` of every Mosaic custom call, in the text's
+    order."""
+    return re.findall(r'custom_call_target="tpu_custom_call"[^\n]*?'
+                      r'/(\w+)/pallas_call', hlo)
+
+
 @pytest.mark.parametrize('shape', [
     # (sessions, heads, KV heads, t_max, block, picks): the cell's call,
     # a pick list that is one group, and a wide group of query heads.
@@ -1273,6 +1283,25 @@ def test_sparse_decode_kernel_compiles_for_v5e(chip, shape):
     assert mem.temp_size_in_bytes < 2 * kv * t_max * d * 2
 
 
+def _sala_cell():
+    """``minicpm-sala.decode-64k`` as its driver builds it: ``(driver,
+    configuration, traffic, model, abstract parameters)``."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_sala as driver
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'minicpm-sala-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-64k-x64.json')) as f:
+        traffic = json.load(f)
+    return (driver, cfg, traffic, driver.build_lm(cfg),
+            _shape_table_params(driver, cfg))
+
+
 def test_sala_decode_step_picks_its_rows_reads_three_states_once_and_fits(
         chip, monkeypatch):
     """The token step of the block-sparse / Lightning stack at the
@@ -1281,32 +1310,21 @@ def test_sala_decode_step_picks_its_rows_reads_three_states_once_and_fits(
     pooled rows beside three ``(64, 32, 128, 128)`` float32 states),
     caches donated: the sparse layer's step is the kernel
     ``sparse_decode`` over a pick list of 128 entries of which 64 are
-    read above ``dense_len``, 16 picks a group — one custom call, the
-    only one of the step; every Lightning state is taken by ONE fusion
+    read above ``dense_len``, 16 picks a group, and the list comes from
+    ONE call of ``sparse_pick`` (the threshold over all 128 (session, KV
+    head) rows) — the step's two custom calls, and no ``sort`` in its
+    text; every Lightning state is taken by ONE fusion
     (read once, written once: no Pallas kernel is owed); nothing as
     large as a layer's 64 states (134 MB) is copied, sliced or written
     back but the pooled buffer's one-row update in place, and no
     temporary is that large; arguments + temporaries with
     the snapshot counted stay between 8 and 10 GiB. The reset between
     requests writes the three states over in place."""
-    import json
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from benchmarks.drivers import decode_sala as driver
     from distributed_dot_product_tpu.models.decode import (
         SparseCache, StateCache, sparse_decode_traces,
     )
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-    with open(os.path.join(root, 'benchmarks', 'configs',
-                           'minicpm-sala-serve.json')) as f:
-        cfg = json.load(f)
-    with open(os.path.join(root, 'benchmarks', 'traffic',
-                           'decode-64k-x64.json')) as f:
-        traffic = json.load(f)
-    model = driver.build_lm(cfg)
-    params = _shape_table_params(driver, cfg)
+    driver, cfg, traffic, model, params = _sala_cell()
     sessions, t_max = traffic['sessions'], traffic['t_max']
     caches = jax.eval_shape(
         lambda: model.make_decode_caches(sessions, t_max))
@@ -1327,11 +1345,10 @@ def test_sala_decode_step_picks_its_rows_reads_three_states_once_and_fits(
         compiled = step.lower(
             *described((params, tok, caches, stats))).compile()
     assert forms == [{'impl': 'kernel', 'picks': 128, 'topk': 64,
-                      'group': 16}]
+                      'group': 16, 'select': 'threshold'}]
     hlo = compiled.as_text()
-    assert hlo.count('tpu_custom_call') == len(re.findall(
-        r'custom_call_target="tpu_custom_call"[^\n]*sparse_decode',
-        hlo)) == 1
+    assert _mosaic_calls(hlo) == ['sparse_pick', 'sparse_decode']
+    assert not re.findall(SORT, hlo)
     state_bytes = sessions * 32 * 128 * 128 * 4
     # (the one move of that size is the pooled buffer's in-place write
     # of the ONE row a step completes: 136 MB by its result type)
@@ -1358,3 +1375,28 @@ def test_sala_decode_step_picks_its_rows_reads_three_states_once_and_fits(
     assert restored.memory_analysis().alias_size_in_bytes >= (
         cache_bytes - 4)
     assert restored.as_text().count('lm.state_restore') >= 6
+
+
+def test_sala_prefill_chunk_picks_its_blocks_without_a_sort(
+        chip, monkeypatch):
+    """A 4096-token context chunk of one session of
+    ``minicpm-sala.decode-64k`` for a described v5e: every row of the
+    chunk picks its 64 blocks of 1040 through ``sparse_pick`` — one
+    call in the text (the body of the map over groups of 512 query
+    rows, 1024 (KV head, row) pairs a call: eight grid steps of 128) —
+    and the program holds no ``sort``; the flash forward under the
+    picks' block mask is the other kernel, a call a KV head."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    driver, cfg, traffic, model, params = _sala_cell()
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(1, traffic['t_max']))
+    tok = jnp.zeros((1, traffic['prefill_chunk']), jnp.int32)
+    prefill = driver.make_programs(model, cfg)[0]
+    compiled = prefill.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        (params, tok, caches))).compile()
+    hlo = compiled.as_text()
+    calls = _mosaic_calls(hlo)
+    assert calls.count('sparse_pick') == 1
+    assert set(calls) - {'sparse_pick'} == {'flash_fwd'}
+    assert not re.findall(SORT, hlo)
