@@ -460,6 +460,9 @@ def generate_once(progs, tag, cfg, seed, params, lm=lm, **attn_kwargs):
     on = sorted({t['cache'] for t in traces})
     # What one grid step of the kernel holds (decode_geometry).
     kernel_step = next((t['step'] for t in traces if t['step']), None)
+    # … and the rows it moves of a slot's last split where no more are
+    # filled (None: that split is always moved whole), a cache kind.
+    kernel_tail = {t['cache']: t['tail'] for t in traces if t['step']}
     tokens = np.asarray(tokens)
     ours = forced_logits(progs, f'{tag}.auto', model, params, prompt,
                          tokens, t_max, pallas_step=True)
@@ -470,6 +473,7 @@ def generate_once(progs, tag, cfg, seed, params, lm=lm, **attn_kwargs):
         f'{tag}_resolved_impl': resolved,
         f'{tag}_caches': on,
         f'{tag}_kernel_step': kernel_step,
+        f'{tag}_kernel_tail': kernel_tail,
         f'{tag}_logits_max_abs_err': err,
         f'{tag}_logits_max_abs': scale,
         f'{tag}_replay_argmax_agrees': int(np.sum(

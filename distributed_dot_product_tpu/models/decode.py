@@ -2004,8 +2004,8 @@ _IMPL_TRACES = TraceSinks()
 def decode_impl_traces():
     """Collect what :func:`decode_step` resolves ``impl`` to while the
     block runs: one dict ``{'requested', 'resolved', 'reason',
-    'cache', 'step'}`` per TRACE (= per compiled step; ``reason`` names
-    why ``'auto'`` fell back to ``'xla'``, else None; ``cache`` is
+    'cache', 'step', 'tail'}`` per TRACE (= per compiled step; ``reason``
+    names why ``'auto'`` fell back to ``'xla'``, else None; ``cache`` is
     ``'stacked'`` where the step addressed a layer-stacked buffer by
     ``layer`` — a scanned stack's in-place loop — and ``'layer'`` where
     it was handed one layer's buffers, so a return to slicing the stack
@@ -2015,10 +2015,14 @@ def decode_impl_traces():
     ``{'heads', 'block_k', 'bytes'}`` — KV heads and cache rows of one
     step and the cache bytes it streams, as
     ``ops.pallas_decode.decode_geometry`` chose them from the call's
-    shapes — or None off the kernel). ``'auto'`` takes the XLA formulation
-    off-TPU and wherever the kernel does not cover the call, so a smoke
-    or benchmark run wraps the compile of its step in this and asserts
-    the path the program holds instead of trusting it::
+    shapes — or None off the kernel; ``tail`` is the rows the kernel
+    moves of the split that holds a slot's last valid column where no
+    more than those are filled of it, by the same function — None where
+    that split is always moved whole, and off the kernel). ``'auto'``
+    takes the XLA formulation off-TPU and wherever the kernel does not
+    cover the call, so a smoke or benchmark run wraps the compile of its
+    step in this and asserts the path the program holds instead of
+    trusting it::
 
         with decode_impl_traces() as traces:
             step.lower(*args).compile()
@@ -2050,9 +2054,9 @@ def note_sparse_decode(trace):
     _SPARSE_TRACES.note(trace)
 
 
-def _kernel_step(q, cache, qk_quant):
-    """The grid step the fused kernel takes for this call — the trace
-    field ``'step'`` — asked of the kernel's own
+def _kernel_geometry(q, cache, qk_quant):
+    """The grid step the fused kernel takes for this call and its tail —
+    the trace fields ``'step'`` and ``'tail'`` — asked of the kernel's own
     ``ops.pallas_decode.flash_decode_geometry`` with the operands
     :func:`decode_step` hands ``flash_decode``."""
     from distributed_dot_product_tpu.ops.pallas_decode import (
@@ -2064,8 +2068,9 @@ def _kernel_step(q, cache, qk_quant):
             qk_quant=qk_quant)
     else:
         geom = flash_decode_geometry(q, cache.k, cache.v,
-                                     qk_quant=qk_quant)
-    return geom.step()
+                                     qk_quant=qk_quant,
+                                     ring=isinstance(cache, RingCache))
+    return geom
 
 
 def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
@@ -2103,22 +2108,24 @@ def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
             resolved = 'kernel'
     # The step is reported where the caller says what it scores with:
     # decode_step does; a bare probe of the resolution has no queries.
-    step = None
+    geom = None
     if resolved == 'kernel' and q is not None and _IMPL_TRACES:
-        step = _kernel_step(q, cache, qk_quant)
+        geom = _kernel_geometry(q, cache, qk_quant)
     kind = ('ring' if isinstance(cache, RingCache)
             else 'stacked' if stacked else 'layer')
-    record_decode_impl(impl, resolved, reason, kind, step)
+    record_decode_impl(impl, resolved, reason, kind, geom)
     return resolved
 
 
-def record_decode_impl(requested, resolved, reason, cache, step=None):
+def record_decode_impl(requested, resolved, reason, cache, geom=None):
     """Tell the open :func:`decode_impl_traces` blocks what one traced
     decode step resolved to (this module's, and the latent cache's in
-    ``models/latent.py``)."""
+    ``models/latent.py``); ``geom`` the kernel's ``DecodeGeometry``."""
     _IMPL_TRACES.note({'requested': requested or 'auto',
                        'resolved': resolved, 'reason': reason,
-                       'cache': cache, 'step': step})
+                       'cache': cache,
+                       'step': geom.step() if geom else None,
+                       'tail': geom.tail if geom else None})
 
 
 def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,

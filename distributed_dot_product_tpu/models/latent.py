@@ -360,5 +360,5 @@ class LatentAttention(nn.Module):
                 resolved = 'xla'
                 reason = f'backend is {jax.default_backend()}, not tpu'
         record_decode_impl(impl, resolved, reason, 'stacked',
-                           geom.step() if resolved == 'kernel' else None)
+                           geom if resolved == 'kernel' else None)
         return resolved

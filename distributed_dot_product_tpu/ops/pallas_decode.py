@@ -58,7 +58,21 @@ This kernel is ONE Pallas program per decode step that
   which split holds the new rows) and the BlockSpec index maps read —
   blocks past a slot's fill are never even DMA'd (the index map clamps
   to the last useful block, and Pallas skips re-fetching a resident
-  block), so a half-empty serving batch streams half the bytes;
+  block), so a half-empty serving batch streams half the bytes; the
+  grid steps behind a slot's last block carry the NEXT grid row's
+  first block, so the row change waits for nothing;
+- **moves what is filled of the last split, not its 1024 rows**: where
+  no more than ``geom.tail`` rows (256, :func:`decode_geometry`) are
+  filled of the split that holds a slot's last valid column — every
+  token of the first 256 behind a multiple of 1024 — the whole-split
+  stream stops on the split before it and those rows alone are moved,
+  by a copy of the kernel's own from the aliased result in HBM, started
+  under the grid row before, and scored beside the last whole split. A
+  buffer cannot be handed to the call a second time for this: XLA
+  copies an operand that is aliased to a result and read through
+  another (measured: 5x the step). Further into the split the tail's
+  scoring would cost more than its bytes save, and the split is moved
+  whole as before;
 - **dequantizes int8 in kernel**: the quantized path streams the 1-byte
   ``k_q`` mirror plus its per-row scales and scores s8×s8→s32 on the
   MXU with the dequantization applied to the s32 block — the halved K
@@ -109,6 +123,11 @@ _STEP_STREAM_BYTES = 4 << 20
 # tiles, one head's temporaries): the v5e compiler's default scoped
 # limit is 16 MiB; stay a quarter under it.
 _VMEM_BUDGET = 12 << 20
+# The tail: where no more than this many rows are filled of the split
+# that holds a slot's last valid column, those rows alone are moved and
+# not the split's 1024 (decode_geometry takes the first that divides the
+# split and that the VMEM plan has room for).
+_TAIL_ROWS = (256, 128)
 
 
 def decode_block_k(t_max, cap=_BLOCK_K_CAP):
@@ -131,11 +150,14 @@ class DecodeGeometry(NamedTuple):
     """One grid step of the decode kernel: ``heads`` KV heads of one
     slot, ``block_k`` cache rows of each; ``write_rows`` rows of each
     head's buffers written back for the append; ``bytes`` the cache
-    bytes the step streams."""
+    bytes the step streams; ``tail`` the most rows filled of the split
+    that holds a slot's last valid column at which those rows alone are
+    moved, or None where that split is always moved whole."""
     heads: int
     block_k: int
     write_rows: int
     bytes: int
+    tail: int = None
 
     def step(self):
         """What ``decode_impl_traces()`` reports of it."""
@@ -149,14 +171,16 @@ def _lanes(x):
 
 
 def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
-                    n=1, quantized=False, page_size=None, block_k=None):
+                    n=1, quantized=False, page_size=None, block_k=None,
+                    ring=False):
     """The decode kernel's grid step for a call of these shapes, or None
     where no K split divides ``t_max`` (the caller takes the XLA path).
 
     ``rows`` is the query rows a KV head scores (``group · n``);
     ``v_dtype=None`` is the latent cache (one buffer, its values a lane
     slice of the streamed block); ``page_size`` a paged pool's page,
-    which IS the split; ``block_k`` the tests' override of the split.
+    which IS the split; ``block_k`` the tests' override of the split;
+    ``ring`` the ring mode.
 
     The K split stays at :data:`_BLOCK_K_CAP` rows (skip granularity);
     the step then takes the most KV heads ``hb | h_kv`` whose K + V
@@ -171,7 +195,17 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
     row (16 rows of bf16) where a single row is appended to a slab; a
     verify-k step (its rows may straddle tiles of ONE resident block), a
     paged pool (its page is the block) and the int8 mirror (its scale
-    row vector tiles by lanes, not rows) write back the whole split."""
+    row vector tiles by lanes, not rows) write back the whole split.
+
+    The same calls — one row appended to a slab of more than one split,
+    head dims whole lane tiles (Mosaic refuses a copy of a lane-padded
+    row) — get a ``tail``: the most rows of :data:`_TAIL_ROWS` that the
+    plan, with the heads chosen, still has room for as one more buffer
+    a head. Where no more than those are filled of the split that holds
+    a slot's last column the kernel moves them alone; a verify-k step,
+    a paged pool, the int8 mirror and the ring (``ring``; its newest
+    split is this case, its oldest the mirror image) move that split
+    whole, as a slab does further into it."""
     bk = page_size or block_k or decode_block_k(t_max)
     if bk is None or t_max % bk:
         return None
@@ -203,19 +237,28 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
     temps = (8 * g_pad * bk * 4 + bk * _lanes(dv) * (v_item + 4)
              + wr * held_row)
 
-    def vmem(hb):
-        # Streams and write-back blocks are double-buffered.
-        return hb * (2 * (bk + wr) * held_row + small) + temps
+    def vmem(hb, tail=0):
+        # Streams and write-back blocks are double-buffered; the tail's
+        # rows are one buffer (moved under the grid row before).
+        return hb * (2 * (bk + wr) * held_row + tail * stream_row
+                     + small) + temps
 
     hb = max(c for c in range(1, h_kv + 1)
              if h_kv % c == 0 and (c == 1 or (
                  c * bk * stream_row <= _STEP_STREAM_BYTES
                  and vmem(c) <= _VMEM_BUDGET)))
-    return DecodeGeometry(hb, bk, wr, hb * bk * stream_row)
+    tail = None
+    if (wr != bk and not ring and t_max > bk and d == _lanes(d)
+            and (v_dtype is None or dv == _lanes(dv))):
+        tail = next((rows for rows in _TAIL_ROWS
+                     if rows < bk and bk % rows == 0 and rows % wr == 0
+                     and vmem(hb, rows) <= _VMEM_BUDGET), None)
+    return DecodeGeometry(hb, bk, wr, hb * bk * stream_row, tail)
 
 
 def flash_decode_geometry(q, cache_k, cache_v=None, *, page_table=None,
-                          qk_quant=None, block_k=None, latent_v=None):
+                          qk_quant=None, block_k=None, latent_v=None,
+                          ring=False):
     """The grid step :func:`flash_decode` takes for these operands, of
     which only shapes and dtypes are read (abstract values will do) —
     or None where no K split divides the cache. ``flash_decode`` asks
@@ -231,7 +274,8 @@ def flash_decode_geometry(q, cache_k, cache_v=None, *, page_table=None,
         t_max, h_kv, d, latent_v if latent else cache_v.shape[-1],
         n * (h // h_kv), cache_k.dtype,
         None if latent else cache_v.dtype, n=n,
-        quantized=qk_quant == 'int8', page_size=page, block_k=block_k)
+        quantized=qk_quant == 'int8', page_size=page, block_k=block_k,
+        ring=ring)
 
 
 def _sublane(dtype):
@@ -280,6 +324,26 @@ def _ring_hits(ki, bk, head, span, t_max):
         lo >= 0,
         jnp.logical_and(ki * bk <= head, ki * bk + bk - 1 >= lo),
         jnp.logical_or(ki * bk <= head, ki * bk + bk - 1 >= lo + t_max))
+
+
+def _sweep_end(vt, ap, geom, t_max, n=1):
+    """Where a slot's sweep ends: ``(whole, last, tails)`` — ``last`` the
+    K split that holds its last useful column (the LAST new row attends
+    up to ``vt + n − 1``; or the append column, should a caller append
+    past it), ``tails`` whether what is filled of that split fits the
+    tail's ``geom.tail`` rows and is moved as those rows alone, and
+    ``whole`` the last split the whole-split stream moves (``last``,
+    or the one before it where the tail takes ``last``). Split 0 is
+    never a tail: the stream moves one block a grid row whatever
+    happens. ONE definition for the stream index maps and the kernel
+    body, which must agree."""
+    bk = geom.block_k
+    edge = jnp.maximum(vt + (n - 1), ap)
+    last = jnp.clip(edge // bk, 0, t_max // bk - 1)
+    if geom.tail is None:
+        return last, last, False
+    tails = jnp.logical_and(last > 0, edge - last * bk < geom.tail)
+    return last - tails.astype(jnp.int32), last, tails
 
 
 def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
@@ -343,13 +407,21 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
     which splits hold a valid column (``run``) and which columns of a
     split are valid (``masked``) — ask the cyclic interval instead.
     Everything else (scores, substitution, online softmax, write-back)
-    is the body above, unchanged: a column is a column."""
+    is the body above, unchanged: a column is a column.
+
+    TAIL (``geom.tail``): the K/V results are refs to the buffers in HBM
+    and not write-back blocks, and four scratch refs and a semaphore
+    array follow the softmax state. ``_sweep_end`` tells from the
+    prefetched lengths whether a slot's last split is taken as its
+    first ``tail`` rows; those are scored by the body above as one
+    more block (``score_block``), after the last whole split's."""
     latent = latent_v is not None
-    hb, bk, wr = geom.heads, geom.block_k, geom.write_rows
+    hb, bk, wr, tail = (geom.heads, geom.block_k, geom.write_rows,
+                       geom.tail)
     per_slot = h_kv // hb                       # grid rows a slot
 
     def kernel_body(vt_ref, ap_ref, nn_ref, *refs, pt_ref=None,
-                    span_ref=None):
+                    span_ref=None, row0_ref=None):
         b = pl.program_id(0)
         ki = pl.program_id(1)
         br = b // per_slot                      # cache batch row
@@ -377,6 +449,19 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
         kqo_ref = next(it) if quantized else None
         kso_ref = next(it) if quantized else None
         m_s, l_s, acc_s = next(it), next(it), next(it)
+        if tail:
+            # The tail's rows, the write-back tile's staging rows and
+            # their DMA semaphores.
+            ktail_ref = next(it)
+            vtail_ref = None if latent else next(it)
+            kw_ref = next(it)
+            vw_ref = None if latent else next(it)
+            sems = next(it)
+            # K then V (the latent buffer: K alone): the result in HBM,
+            # the tail's rows, the staging tile, the new rows.
+            kv = [(ko_ref, ktail_ref, kw_ref, kn_ref)]
+            if not latent:
+                kv.append((vo_ref, vtail_ref, vw_ref, vn_ref))
 
         # What scoring reads: the int8 mirror and its scales if any.
         score_ref, score_new_ref = ((kq_ref, kqn_ref) if quantized
@@ -403,19 +488,24 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
             l_s[...] = jnp.zeros_like(l_s)
             acc_s[...] = jnp.zeros_like(acc_s)
 
-        # Block-skip: no valid column in this split — strictly past the
-        # LAST new row's fill (row n−1 attends up to vt + n − 1), or —
-        # with a window — wholly before row 0's lookback (later rows
-        # look back from later positions, so row 0's bound is the
-        # earliest column any row can attend).
+        def hits(col0, size):
+            """Does the block of ``size`` columns from ``col0`` hold a
+            valid one? None at all lies strictly past the LAST new
+            row's fill (row n−1 attends up to vt + n − 1), or — with a
+            window — wholly before row 0's lookback (later rows look
+            back from later positions, so row 0's bound is the earliest
+            column any row can attend)."""
+            run = col0 <= vt + (n - 1)
+            if window is not None:
+                run = jnp.logical_and(run, col0 + size - 1 > vt - window)
+            return run
+
+        # Block-skip: no valid column in this split.
         if ring:
             span = span_ref[br]
             run = _ring_hits(ki, bk, vt, span, ns * bk)
         else:
-            run = ki * bk <= vt + (n - 1)
-            if window is not None:
-                run = jnp.logical_and(run,
-                                      ki * bk + bk - 1 > vt - window)
+            run = hits(ki * bk, bk)
         if pt_ref is not None:
             # Paged: only score pages this table actually holds — a −1
             # ordinal streams the sink (see flash_decode's index-map
@@ -425,13 +515,26 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
             # of the (num, m, l) partials reassembles exact full
             # attention.
             run = jnp.logical_and(run, pt_ref[br * ns + ki] >= 0)
-        # Does this split hold a column the step appends? (nn is 0
-        # where nothing is appended, so no split does.)
-        lands = jnp.logical_and(ki * bk < ap + nn, ap < ki * bk + bk)
+        if tail:
+            # Where the tail takes the split that holds the slot's last
+            # column, that split is not this stream's (its block stays
+            # on the split before): the tail below moves what is filled
+            # of it.
+            whole, last, tails = _sweep_end(vt, ap, geom, ns * bk)
+            run = jnp.logical_and(run, ki <= whole)
 
-        def score_split(substitute):
-            cols = (ki * bk
-                    + jax.lax.broadcasted_iota(jnp.int32, (g_pad, bk), 1))
+        def lands(col0, size):
+            # Does the block hold a column the step appends? (nn is 0
+            # where nothing is appended, so no block does.)
+            return jnp.logical_and(col0 < ap + nn, ap < col0 + size)
+
+        def score_block(col0, size, score_blk, scale_blk, k_blk, v_blk,
+                        substitute):
+            """Fold the resident block of ``size`` cache rows from
+            column ``col0`` into every head's softmax state: the whole
+            split (``score_ref`` …) or a sub-block of the tail."""
+            cols = (col0 + jax.lax.broadcasted_iota(
+                jnp.int32, (g_pad, size), 1))
             if ring:
                 # How far behind the newest row a column lies, around
                 # the ring; every column nearer than span is in-window
@@ -446,16 +549,16 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                     masked = jnp.logical_or(masked, rel <= -window)
             relf = rel.astype(jnp.float32) if has_alibi else None
             for h in range(hb):
-                s = scores(h, score_ref[h],
-                           ks_ref[h] if quantized else None)
-                v = k_ref[h, :, :latent_v] if latent else v_ref[h]
+                s = scores(h, score_blk[h],
+                           scale_blk[h] if quantized else None)
+                v = k_blk[h, :, :latent_v] if latent else v_blk[h]
                 if substitute:
                     # New row m replaces whatever the buffer held at
                     # column ap + m (the nn guard keeps rows a
                     # mixed-batch slot did NOT append from leaking in).
                     s_new = scores(h, score_new_ref[h],
                                    ksn_ref[h, 0, 0] if quantized else None)
-                    rows_v = ki * bk + jax.lax.broadcasted_iota(
+                    rows_v = col0 + jax.lax.broadcasted_iota(
                         jnp.int32, v.shape, 0)
                     for m in range(n):
                         s = jnp.where(
@@ -478,36 +581,79 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                     p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
 
-        pl.when(jnp.logical_and(run, lands))(
+        def score_split(substitute):
+            score_block(ki * bk, bk, score_ref, ks_ref, k_ref, v_ref,
+                        substitute)
+
+        landing = lands(ki * bk, bk)
+        pl.when(jnp.logical_and(run, landing))(
             lambda: score_split(True))
-        pl.when(jnp.logical_and(run, jnp.logical_not(lands)))(
+        pl.when(jnp.logical_and(run, jnp.logical_not(landing)))(
             lambda: score_split(False))
+
+        def put(h, src_ref, new_ref, dst_ref, off, row0):
+            """The write-back tile of head ``h`` — ``wr`` rows from row
+            ``off`` of the resident block, cache rows ``row0 …`` — with
+            the new rows substituted."""
+            if src_ref.shape[1] == wr:
+                old = src_ref[h]
+            else:
+                old = src_ref[h, pl.ds(pl.multiple_of(off, wr), wr), :]
+            rows = row0 + jax.lax.broadcasted_iota(
+                jnp.int32, old.shape, 0)
+            for m in range(n):
+                hit = jnp.logical_and(rows == ap + m, m < nn)
+                old = jnp.where(hit, new_ref[h, m:m + 1, :], old)
+            dst_ref[h] = old
 
         # In-place append: substitute the new rows into the tile of the
         # resident block and write it back — the ONLY cache rows written
         # this step (every other aliased row keeps its bits untouched).
-        @pl.when(ki == tile * wr // bk)
-        def _():
-            def put(h, src_ref, new_ref, dst_ref):
-                if wr == bk:
-                    old = src_ref[h]
-                else:
-                    off = pl.multiple_of(tile * wr - ki * bk, wr)
-                    old = src_ref[h, pl.ds(off, wr), :]
-                rows = tile * wr + jax.lax.broadcasted_iota(
-                    jnp.int32, old.shape, 0)
-                for m in range(n):
-                    hit = jnp.logical_and(rows == ap + m, m < nn)
-                    old = jnp.where(hit, new_ref[h, m:m + 1, :], old)
-                dst_ref[h] = old
+        split = tile * wr // bk                  # the split of the tile
+        at_split = ki == split
+        if tail:
+            # … which the whole-split stream holds unless it is the
+            # tail's. The tail's outputs are the buffers in HBM, written
+            # by a copy of the kernel's own: a slot that appends nothing
+            # writes nothing (no tile to copy through).
+            in_tail = jnp.logical_and(
+                appends, jnp.logical_and(tails, split == last))
+            at_split = jnp.logical_and(at_split, jnp.logical_and(
+                appends, jnp.logical_not(in_tail)))
+            # The grid row's first flat row of the cache buffers.
+            row0 = b * hb
+            if row0_ref is not None:
+                row0 = row0 + row0_ref[0] * hb
 
+            def stage(srcs, off):
+                """The tile from the resident rows of ``srcs`` (K, V)
+                into the staging blocks, the new rows substituted, and
+                on to the cache."""
+                for h in range(hb):
+                    for src, (_, _, tile_ref, new_ref) in zip(srcs, kv):
+                        put(h, src, new_ref, tile_ref, off, tile * wr)
+                at = pl.ds(pl.multiple_of(tile * wr, wr), wr)
+                back = [pltpu.make_async_copy(
+                    tile_ref, hbm.at[pl.ds(row0, hb), at, :], sems.at[i, 1])
+                    for i, (hbm, _, tile_ref, _) in enumerate(kv)]
+                for copy in back:
+                    copy.start()
+                for copy in back:
+                    copy.wait()
+
+        @pl.when(at_split)
+        def _():
+            off = tile * wr - ki * bk
+            if tail:
+                stage((k_ref, v_ref), off)
+                return
             # Head by head, like the scores: one head's tile in flight.
             for h in range(hb):
-                put(h, k_ref, kn_ref, ko_ref)
+                put(h, k_ref, kn_ref, ko_ref, off, tile * wr)
                 if not latent:
-                    put(h, v_ref, vn_ref, vo_ref)
+                    put(h, v_ref, vn_ref, vo_ref, off, tile * wr)
                 if quantized:
-                    put(h, kq_ref, kqn_ref, kqo_ref)
+                    put(h, kq_ref, kqn_ref, kqo_ref, off, tile * wr)
                     # (1, bk) scale row vector: the appended row is a
                     # lane, not a sublane.
                     lanes = tile * wr + jax.lax.broadcasted_iota(
@@ -518,6 +664,54 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                         kso = jnp.where(hit, ksn_ref[h, :, m:m + 1], kso)
                     kso_ref[h] = kso
 
+        if tail:
+            # THE TAIL: where what is filled of the split that holds
+            # the slot's last column fits ``tail`` rows, those rows
+            # alone are moved — by a copy of the kernel's own from the
+            # aliased result (the same buffer in HBM), started under
+            # the grid row BEFORE this one — and scored beside the last
+            # whole split. Rows that were not moved are not scored: an
+            # unfilled VMEM row may hold anything, NaN too, and 0 · NaN
+            # is NaN.
+            def moves(first_row, split):
+                """The copies of ``tail`` rows of ``split`` for the grid
+                row whose heads start at flat row ``first_row``."""
+                at = pl.ds(pl.multiple_of(split * bk, bk), tail)
+                return [pltpu.make_async_copy(
+                    hbm.at[pl.ds(first_row, hb), at, :], rows, sems.at[i, 0])
+                    for i, (hbm, rows, _, _) in enumerate(kv)]
+
+            @pl.when(jnp.logical_and(jnp.logical_and(b == 0, ki == 0),
+                                     tails))
+            def _():                 # nobody ran before the first row
+                for copy in moves(row0, last):
+                    copy.start()
+
+            @pl.when(jnp.logical_and(ki == whole, tails))
+            def _():
+                for copy in moves(row0, last):
+                    copy.wait()
+                col0 = last * bk
+                pl.when(hits(col0, tail))(lambda: score_block(
+                    col0, tail, ktail_ref, None, ktail_ref, vtail_ref,
+                    True))
+                pl.when(in_tail)(lambda: stage(
+                    (ktail_ref, vtail_ref), tile * wr - col0))
+
+            # Under this row's last step — behind its own tail, which a
+            # step before the last one took — the next row's.
+            rows_grid = pl.num_programs(0)
+            ahead = jnp.minimum(b + 1, rows_grid - 1) // per_slot
+            _, last_ahead, tails_ahead = _sweep_end(
+                vt_ref[ahead], ap_ref[ahead], geom, ns * bk)
+
+            @pl.when(jnp.logical_and(
+                jnp.logical_and(ki == ns - 1, b + 1 < rows_grid),
+                tails_ahead))
+            def _():
+                for copy in moves(row0 + hb, last_ahead):
+                    copy.start()
+
         @pl.when(ki == ns - 1)
         def _():
             o_ref[...] = acc_s[...]
@@ -525,10 +719,10 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
             l_ref[...] = l_s[...]
 
     if stacked:
-        # The layer index only steers the BlockSpec index maps: the
-        # body sees one layer's blocks and needs no change.
+        # The layer index steers the BlockSpec index maps (and the rows
+        # of the tail's own copies): the body sees one layer's blocks.
         def kernel_stacked(vt_ref, ap_ref, nn_ref, row0_ref, *refs):
-            kernel_body(vt_ref, ap_ref, nn_ref, *refs)
+            kernel_body(vt_ref, ap_ref, nn_ref, *refs, row0_ref=row0_ref)
 
         return kernel_stacked
     if ring:
@@ -654,6 +848,15 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     Pallas program is named ``flash_decode_ring`` and its device scope
     ``ops.flash_decode_ring``, opened inside ``ops.flash_decode``.
 
+    THE TAIL (no argument: :func:`decode_geometry` gives a call its
+    ``tail`` rows or None, ``valid_to`` decides a slot's step at run
+    time): where no more than ``tail`` rows are filled of the K split
+    that holds a slot's last valid column, those rows alone are moved
+    and scored and the split's other rows never enter a product; the
+    aliased results are then the buffers in HBM, the kernel copies the
+    one tile that holds the appended row into them, and a slot that
+    appends nothing writes nothing.
+
     Returns ``(out, cache_k, cache_v, k_q, k_scale)`` with
     ``out (B, H, k, dv)`` in ``cache_v.dtype`` — or, with
     ``partials=True``, ``((num, m, l), cache_k, cache_v, k_q, k_scale)``
@@ -726,13 +929,14 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         t_max = cache_k.shape[-2]
     geom = flash_decode_geometry(
         q, cache_k, cache_v, page_table=page_table, qk_quant=qk_quant,
-        block_k=block_k, latent_v=latent_v)
+        block_k=block_k, latent_v=latent_v, ring=ring)
     if geom is None:
         raise ValueError(
             f'no usable K split for t_max={t_max} (block_k must '
             f'divide it); use the XLA decode path for this cache '
             f'shape')
-    hb, bk, wr = geom.heads, geom.block_k, geom.write_rows
+    hb, bk, wr, tail = (geom.heads, geom.block_k, geom.write_rows,
+                        geom.tail)
     ns = t_max // bk
     if n > bk:
         raise ValueError(
@@ -817,13 +1021,15 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     def const_idx(bi, ki, *rs):
         return (bi, 0, 0)
 
-    def _stream_blk(bi, ki, vt):
+    def _stream_blk(bi, ki, vt, ap):
         # Never DMA past a slot's last useful block (the LAST new row
         # attends up to vt + n − 1): beyond-fill splits alias the
         # resident block (skipped in-kernel), so a half-empty slot
-        # streams half the bytes.
-        last = jnp.clip((vt[bi // per_slot] + (n - 1)) // bk, 0, ns - 1)
-        return jnp.minimum(ki, last)
+        # streams half the bytes. With a tail the last useful block may
+        # be the tail's: this stream then stops on the split before it.
+        br = bi // per_slot
+        return jnp.minimum(
+            ki, _sweep_end(vt[br], ap[br], geom, t_max, n)[0])
 
     def _write_blk(bi, ki, ap, nn):
         # In units of the write-back block's wr rows.
@@ -836,7 +1042,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         # of using it as the physical block — the gather that makes
         # paging nearly free (same DMA skip, same aliasing).
         def stream_idx(bi, ki, vt, ap, nn, pt):
-            blk = _stream_blk(bi, ki, vt)
+            blk = _stream_blk(bi, ki, vt, ap)
             pg = pt[(bi // per_slot) * ns + blk]
             # −1 (ordinal not held by this pool) → the sink page; the
             # kernel's run-gate skips scoring it.
@@ -882,10 +1088,23 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
             return jnp.where(_ring_hits(ki, bk, head, span[br], t_max),
                              ki, near)
 
+        def _slab_blk(bi, ki, vt, ap):
+            # (grid row, split). The steps behind a slot's last split
+            # are idle: the stream spends them on the NEXT grid row's
+            # first split (every row starts on split 0 and finds it
+            # resident), where staying put would leave the last split's
+            # scoring with nothing in flight and the row change with
+            # nothing to score.
+            blk = _stream_blk(bi, ki, vt, ap)
+            ahead = jnp.logical_and(ki > blk, bi + 1 < nb // hb)
+            return (jnp.where(ahead, bi + 1, bi),
+                    jnp.where(ahead, 0, blk))
+
         def stream_idx(bi, ki, vt, ap, nn, *lay):
             if ring:
                 return (bi, _ring_blk(bi, ki, vt, lay[0]), 0)
-            return (_row(bi, lay), _stream_blk(bi, ki, vt), 0)
+            row, blk = _slab_blk(bi, ki, vt, ap)
+            return (_row(row, lay), blk, 0)
 
         def write_idx(bi, ki, vt, ap, nn, *lay):
             return (_row(bi, lay), _write_blk(bi, ki, ap, nn), 0)
@@ -895,7 +1114,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         # the LAST axis, so the kernel consumes (1, BK) scale rows
         # directly.
         def stream_idx_row(bi, ki, vt, ap, nn, *lay):
-            return (_row(bi, lay), 0, _stream_blk(bi, ki, vt))
+            row, blk = _slab_blk(bi, ki, vt, ap)
+            return (_row(row, lay), 0, blk)
 
         def write_idx_row(bi, ki, vt, ap, nn, *lay):
             return (_row(bi, lay), 0, _write_blk(bi, ki, ap, nn))
@@ -960,7 +1180,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         pl.BlockSpec((hb, g_pad, dv), const_idx),  # num
         pl.BlockSpec((hb, g_pad, 1), const_idx),    # m
         pl.BlockSpec((hb, g_pad, 1), const_idx),    # l
-        pl.BlockSpec((hb, wr, d), write_idx),      # k (aliased)
+        # k (aliased): the write-back tile, or — with a tail — the
+        # buffer itself, which the kernel's own copies read and write.
+        (pl.BlockSpec(memory_space=pl.ANY) if tail
+         else pl.BlockSpec((hb, wr, d), write_idx)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((nb, g_pad, dv), jnp.float32),
@@ -982,7 +1205,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     n_prefetch = len(prefetch)
     aliases = {n_prefetch + k_in_pos: 3}
     if not latent:
-        out_specs.append(pl.BlockSpec((hb, wr, dv), write_idx))  # v (aliased)
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY) if tail else
+                         pl.BlockSpec((hb, wr, dv), write_idx))  # v (aliased)
         out_shape.append(jax.ShapeDtypeStruct(vf.shape, vf.dtype))
         aliases[n_prefetch + v_in_pos] = 4
     if quantized:
@@ -993,6 +1217,16 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         aliases[n_prefetch + kq_in_pos] = 5
         aliases[n_prefetch + ks_in_pos] = 6
 
+    scratch = [pltpu.VMEM((hb, g_pad, 1), jnp.float32),
+               pltpu.VMEM((hb, g_pad, 1), jnp.float32),
+               pltpu.VMEM((hb, g_pad, dv), jnp.float32)]
+    if tail:
+        # The tail's rows and the write-back tile's staging rows, K
+        # then V; a DMA semaphore each.
+        kv = [(d, kf.dtype)] + ([] if latent else [(dv, vf.dtype)])
+        scratch += [pltpu.VMEM((hb, tail, w), t) for w, t in kv]
+        scratch += [pltpu.VMEM((hb, wr, w), t) for w, t in kv]
+        scratch.append(pltpu.SemaphoreType.DMA((2, 2)))
     kernel = _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                                  quantized, has_alibi, paged=paged,
                                  stacked=stacked, latent_v=latent_v,
@@ -1013,10 +1247,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
                 grid=(nb // hb, ns),
                 in_specs=in_specs,
                 out_specs=out_specs,
-                scratch_shapes=[pltpu.VMEM((hb, g_pad, 1), jnp.float32),
-                                pltpu.VMEM((hb, g_pad, 1), jnp.float32),
-                                pltpu.VMEM((hb, g_pad, dv),
-                                           jnp.float32)]),
+                scratch_shapes=scratch),
             out_shape=out_shape,
             input_output_aliases=aliases,
             interpret=interpret,

@@ -471,6 +471,132 @@ def test_kernel_matches_xla_at_every_geometry(monkeypatch, h, h_kv, hb,
     np.testing.assert_array_equal(np.asarray(nv), np.asarray(cx.v))
 
 
+# ---------------------------------------------------------------------------
+# The tail: where no more than ``geom.tail`` rows are filled of the split
+# that holds a slot's last column, those rows alone are moved and scored
+# ---------------------------------------------------------------------------
+
+_TT, _TBK, _TSUB = 3072, 1024, 256      # three real splits, the tail's rows
+# Rows a slot holds before the step (its new row lands at that column):
+# around a split's start, around the tail's last row, the buffer's end.
+_TAIL_FILLS = {'k.bk-1': 2 * _TBK - 1, 'k.bk': 2 * _TBK,
+               'k.bk+1': 2 * _TBK + 1, '+sub-1': 2 * _TBK + _TSUB - 1,
+               '+sub': 2 * _TBK + _TSUB, '+sub+1': 2 * _TBK + _TSUB + 1,
+               't_max-1': _TT - 1}
+
+
+def _tail_case(kind, fill, key=50, d=128):
+    """Three slots: the fill under test; a slot inside its first split
+    (never a tail); a FROZEN slot 76 rows into its second split (a tail
+    that appends nothing). ``latent``: one buffer of one shared head."""
+    ks = jax.random.split(jax.random.key(key), 5)
+    h_kv, h = (1, 4) if kind == 'latent' else (2, 4)
+    lens = jnp.asarray([fill, 300, _TBK + 76], jnp.int32)
+    layers = 2 if kind in ('stack', 'latent') else 1
+    q = jax.random.normal(ks[0], (3, h, 1, d), jnp.float32)
+    kn = jax.random.normal(ks[1], (3, h_kv, 1, d), jnp.float32)
+    vn = jax.random.normal(ks[2], (3, h_kv, 1, d), jnp.float32)
+    k = jax.random.normal(ks[3], (layers, 3, h_kv, _TT, d), jnp.float32)
+    v = jax.random.normal(ks[4], (layers, 3, h_kv, _TT, d), jnp.float32)
+    return q, kn, vn, k, v, lens
+
+
+def _tail_geometry(kind, d=128):
+    from distributed_dot_product_tpu.ops import pallas_decode as pd
+    f32 = jnp.float32
+    if kind == 'latent':
+        return pd.decode_geometry(_TT, 1, d, d // 2, 4, f32, None)
+    return pd.decode_geometry(_TT, 2, d, d, 2, f32, f32)
+
+
+@pytest.mark.parametrize('fill', sorted(_TAIL_FILLS))
+@pytest.mark.parametrize('kind', ['slab', 'stack', 'latent'])
+def test_kernel_tail_matches_xla_at_the_edges(kind, fill):
+    """The kernel on caches of three real 1024-row splits against the
+    XLA formulation (the latent buffer: a plain softmax over its rows),
+    the slot under test one row either side of every edge of the rule:
+    the split's start, the tail's last row, the buffer's end. Outputs
+    to tolerance; the aliased caches bit for bit — the appended row in
+    place, every other row and layer untouched, the frozen slot's too
+    (with a tail nothing is copied through for it)."""
+    from distributed_dot_product_tpu.models.decode import DecodeCache
+    from distributed_dot_product_tpu.ops import pallas_decode as pd
+    geom = _tail_geometry(kind)
+    assert (geom.block_k, geom.tail) == (_TBK, _TSUB)
+    q, kn, vn, k, v, lens = _tail_case(kind, _TAIL_FILLS[fill])
+    mask = jnp.asarray([True, True, False])
+    vt = jnp.where(mask, lens, lens - 1)
+    ap = jnp.where(mask, lens, -1)
+    if kind == 'latent':
+        dv = q.shape[-1] // 2
+        out, rows, none, *_ = pd.flash_decode(
+            q, kn, None, k, None, vt, ap, layer=jnp.int32(1), latent_v=dv,
+            scale=0.3)
+        assert none is None
+        want_rows = np.array(k)
+        for i in range(2):
+            want_rows[1, i, 0, int(lens[i])] = np.asarray(kn[i, 0, 0])
+        assert np.array_equal(np.asarray(rows), want_rows)
+        held = want_rows[1, :, 0]
+        s = np.einsum('bhd,btd->bht', np.asarray(q[:, :, 0]), held) * 0.3
+        s = np.where(np.arange(_TT) <= np.asarray(vt)[:, None, None],
+                     s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum('bht,btd->bhd', p / p.sum(-1, keepdims=True),
+                         held[..., :dv])
+        np.testing.assert_allclose(np.asarray(out[:, :, 0]), want,
+                                   atol=2e-5, rtol=2e-5)
+        return
+    stacked = kind == 'stack'
+    cache = DecodeCache(k=k if stacked else k[0], v=v if stacked else v[0],
+                        length=jnp.stack([lens, lens]) if stacked else lens)
+    layer = 1 if stacked else None
+    cx, ox = decode_step(q, cache, kn, vn, slot_mask=mask, impl='xla',
+                         layer=layer)
+    ok, nk, nv, _, _ = pd.flash_decode(
+        q, kn, vn, cache.k, cache.v, vt, ap,
+        layer=None if layer is None else jnp.int32(layer))
+    np.testing.assert_allclose(np.asarray(ok), np.asarray(ox),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(nk), np.asarray(cx.k))
+    np.testing.assert_array_equal(np.asarray(nv), np.asarray(cx.v))
+
+
+@pytest.mark.parametrize('interpreter', ['plain', 'tpu-nan'])
+def test_rows_the_tail_did_not_move_reach_no_product(interpreter):
+    """Every cache row behind what the kernel moves is NaN — behind the
+    tail's 256 rows of the last split for the slots that take the tail,
+    behind the last split for the one that does not — and the result is
+    finite and the clean cache's to tolerance: masking a score is not
+    enough (0 · NaN is NaN), the rows must stay out of both products.
+    (The parent moved the whole last split and fails this.) Once more
+    under the TPU interpreter with uninitialised VMEM as NaN: what the
+    tail's buffer holds where no copy landed is not read either."""
+    from jax.experimental.pallas import tpu as pltpu
+    from distributed_dot_product_tpu.ops import pallas_decode as pd
+    assert _tail_geometry('slab').tail == _TSUB
+    q, kn, vn, k, v, lens = _tail_case('slab', 2 * _TBK + 40)
+    k, v = k[0], v[0]
+    # Rows moved: slot 0 two splits + the tail; slot 1 its one split
+    # whole; slot 2 one split + the tail.
+    moved = jnp.asarray([2 * _TBK + _TSUB, _TBK, _TBK + _TSUB])
+    behind = jnp.arange(_TT)[None, None, :, None] >= moved[:, None, None,
+                                                           None]
+    interp = (pltpu.InterpretParams(uninitialized_memory='nan')
+              if interpreter == 'tpu-nan' else None)
+    want, *_ = pd.flash_decode(q, kn, vn, k, v, lens, lens)
+    got, nk, nv, _, _ = pd.flash_decode(
+        q, kn, vn, jnp.where(behind, jnp.nan, k),
+        jnp.where(behind, jnp.nan, v), lens, lens, interpret=interp)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    # … and the poisoned rows are where they were: nothing was written
+    # but the appended rows.
+    assert np.isnan(np.asarray(nk)).sum() == np.isnan(
+        np.asarray(jnp.where(behind, jnp.nan, k))).sum()
+
+
 def test_decode_geometry_of_the_cells_and_its_budget():
     """The geometry function alone: the two decode cells' shapes, the
     divisor rule, the stream budget over head dims (Mosaic's verdict on
@@ -504,6 +630,18 @@ def test_decode_geometry_of_the_cells_and_its_budget():
     # No usable split: the caller takes the XLA path, as before.
     assert pd.decode_geometry(1027, 8, 128, 128, 1, bf16, bf16) is None
     assert pd.decode_block_k(1027) is None
+    # The tail: 256 rows for both cells' calls; 128 where the plan has
+    # room for no more; none for a cache of one split, a head dim that
+    # is not whole lane tiles, the ring, and the three modes above.
+    assert cell.tail == latent.tail == 256
+    assert pd.decode_geometry(16384, 8, 256, 256, 1, bf16, bf16).tail == 128
+    assert pd.decode_geometry(1024, 8, 128, 128, 1, bf16, bf16).tail is None
+    assert pd.decode_geometry(32768, 8, 96, 96, 1, bf16, bf16).tail is None
+    assert pd.decode_geometry(5120, 8, 128, 128, 16, bf16, bf16,
+                              ring=True).tail is None
+    for kw in (dict(n=4), dict(page_size=256), dict(quantized=True)):
+        assert pd.decode_geometry(32768, 8, 128, 128, 4, bf16, bf16,
+                                  **kw).tail is None
 
 
 def test_decode_impl_traces_carry_the_step():
@@ -528,3 +666,13 @@ def test_decode_impl_traces_carry_the_step():
         'heads': 2, 'block_k': T, 'bytes': geom.bytes}
     assert traces[0]['step']['heads'] == 2
     assert traces[1]['step'] is None and traces[2]['step'] is None
+    # The tail's rows ride beside the step, in a key of their own: one
+    # split holds this whole cache, so it is always moved whole.
+    assert [t['tail'] for t in traces] == [None, None, None]
+    wide = jnp.zeros((B, 4, 1, 128), jnp.float32)
+    big = init_slot_cache(B, 2, 4096, 128, dtype=jnp.float32)
+    with decode_impl_traces() as traces:
+        decode_step(wide, big, wide[:, :2], wide[:, :2], impl='kernel')
+    assert traces[0]['tail'] == decode_geometry(
+        4096, 2, 128, 128, 2, jnp.float32, jnp.float32).tail == 256
+    assert sorted(traces[0]['step']) == ['block_k', 'bytes', 'heads']

@@ -219,6 +219,7 @@ def _cache(layout, h_kv, qk_quant):
     ('slot', 8, None, 4), ('paged', 8, None, 4),          # verify-k
     ('mpt-7b.decode-12k', 32, None, 1),                   # the cells' own
     ('xing4-29b-a4b.decode-32k', 1, None, 1),
+    ('solar-open2-250b.decode-4k', 8, None, 1),
 ])
 def test_decode_kernel_compiles_for_v5e(chip, layout, h_kv, qk_quant, n):
     """The fused decode step in every cache layout. n=1 is the form the
@@ -227,7 +228,10 @@ def test_decode_kernel_compiles_for_v5e(chip, layout, h_kv, qk_quant, n):
     cells' own calls — the layer-stacked slab of ``mpt-7b.decode-12k``
     (8 KV heads a grid step) and the latent rows of
     ``xing4-29b-a4b.decode-32k`` — hold Mosaic's VMEM verdict on the
-    geometry ``decode_geometry`` chooses for them."""
+    geometry ``decode_geometry`` chooses for them, the tail's buffers
+    (256 rows of every head of the step, the write-back tile's staging
+    rows) counted; ``solar-open2-250b.decode-4k``'s call (128 slots of
+    8 KV heads, 5120 rows) is the shape the tail was laddered at."""
     if '.' in layout:
         _compile_cell_kernel(chip, layout)
         return
@@ -251,8 +255,8 @@ def _compile_cell_kernel(chip, cell):
     bf16 = jnp.bfloat16
     if cell == 'mpt-7b.decode-12k':
         layers, b, h, t_max, d = 8, 2, 32, 16384, 128
-        assert decode_geometry(t_max, h, d, d, 1, bf16, bf16)[:3] == (
-            8, 1024, 16)
+        assert decode_geometry(t_max, h, d, d, 1, bf16, bf16) == (
+            8, 1024, 16, 4 << 20, 256)
         slopes = _mpt_slopes(h)
         row = jax.ShapeDtypeStruct((b, h, 1, d), bf16)
         buf = jax.ShapeDtypeStruct((layers, b, h, t_max, d), bf16)
@@ -263,10 +267,25 @@ def _compile_cell_kernel(chip, cell):
                                 interpret=False)
 
         args, donate = (row, row, row, buf, buf), (3, 4)
+    elif cell == 'solar-open2-250b.decode-4k':
+        # One layer's buffers (the cell's stack is unrolled), GQA 64:8.
+        b, h, h_kv, t_max, d = 128, 64, 8, 5120, 128
+        assert decode_geometry(t_max, h_kv, d, d, h // h_kv, bf16,
+                               bf16) == (8, 1024, 16, 4 << 20, 256)
+        q = jax.ShapeDtypeStruct((b, h, 1, d), bf16)
+        row = jax.ShapeDtypeStruct((b, h_kv, 1, d), bf16)
+        buf = jax.ShapeDtypeStruct((b, h_kv, t_max, d), bf16)
+
+        def step(q, k_new, v_new, k, v, at, layer):
+            del layer
+            return flash_decode(q, k_new, v_new, k, v, at, at,
+                                interpret=False)
+
+        args, donate = (q, row, row, buf, buf), (3, 4)
     else:
         layers, b, h, t_max, d, dv = 6, 16, 32, 33792, 640, 512
-        assert decode_geometry(t_max, 1, d, dv, h, bf16, None)[:3] == (
-            1, 1024, 16)
+        assert decode_geometry(t_max, 1, d, dv, h, bf16, None) == (
+            1, 1024, 16, 1024 * 640 * 2, 256)
         q = jax.ShapeDtypeStruct((b, h, 1, d), bf16)
         row = jax.ShapeDtypeStruct((b, 1, 1, d), bf16)
         buf = jax.ShapeDtypeStruct((layers, b, 1, t_max, d), bf16)
@@ -281,24 +300,31 @@ def _compile_cell_kernel(chip, cell):
     layer = jax.ShapeDtypeStruct((), jnp.int32)
     compiled = _compile(chip, step, *args, at, layer, donate=donate)
     cache_bytes = sum(math.prod(x.shape) * 2 for x in args if x is buf)
+    # Aliased whole, and no copy of a cache in front of the kernel (a
+    # buffer handed to the call twice — once aliased, once to read its
+    # tail — is copied whole by XLA: section 6, PR 45).
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 8
 
 
 # The edges of ``decode_geometry``'s VMEM plan: shapes at which it returns
-# the most heads a step for a wide head, a grouped one, the int8 mirror,
-# a verify-k step (whose write-back is the whole split) and page pools.
-# (name, h, h_kv, d, n, page, qk_quant, window) -> heads a step.
+# the most heads a step for a wide head (whose plan has room for a tail
+# of 128 rows and not of 256), a grouped one, the int8 mirror, a
+# verify-k step (whose write-back is the whole split) and page pools
+# (the last three move the last split whole: no tail).
+# (name, h, h_kv, d, n, page, qk_quant, window) -> (heads a step, tail).
 _PLAN_EDGES = {
-    'mha-d256': ((8, 8, 256, 1, None, None, None), 4),
-    'starcoder2-gqa-window': ((24, 2, 128, 1, None, None, 4096), 2),
-    'int8-d128': ((32, 32, 128, 1, None, 'int8', None), 2),
-    'int8-d256': ((8, 8, 256, 1, None, 'int8', None), 1),
-    'verify8-gqa': ((32, 8, 128, 8, None, None, None), 4),
-    'verify4-d256': ((8, 8, 256, 4, None, None, None), 2),
-    'paged16': ((32, 32, 128, 1, 16, None, None), 32),
-    'paged256-verify4': ((32, 32, 128, 4, 256, None, None), 16),
-    'paged1024-d256': ((8, 8, 256, 1, 1024, None, None), 2),
-    'paged1024-int8': ((32, 32, 128, 1, 1024, 'int8', None), 2),
+    'mha-d256': ((8, 8, 256, 1, None, None, None), (4, 128)),
+    'starcoder2-gqa-window': ((24, 2, 128, 1, None, None, 4096), (2, 256)),
+    'gqa-group-64': ((512, 8, 128, 1, None, None, None), (4, 256)),
+    'int8-d128': ((32, 32, 128, 1, None, 'int8', None), (2, None)),
+    'int8-d256': ((8, 8, 256, 1, None, 'int8', None), (1, None)),
+    'verify8-gqa': ((32, 8, 128, 8, None, None, None), (4, None)),
+    'verify4-d256': ((8, 8, 256, 4, None, None, None), (2, None)),
+    'paged16': ((32, 32, 128, 1, 16, None, None), (32, None)),
+    'paged256-verify4': ((32, 32, 128, 4, 256, None, None), (16, None)),
+    'paged1024-d256': ((8, 8, 256, 1, 1024, None, None), (2, None)),
+    'paged1024-int8': ((32, 32, 128, 1, 1024, 'int8', None), (2, None)),
 }
 
 
@@ -311,7 +337,8 @@ def test_decode_kernel_compiles_at_the_plans_edges(chip, edge):
     from distributed_dot_product_tpu.ops.pallas_decode import (
         flash_decode, flash_decode_geometry,
     )
-    (h, h_kv, d, n, page, qk_quant, window), heads = _PLAN_EDGES[edge]
+    (h, h_kv, d, n, page, qk_quant, window), (heads, tail) = (
+        _PLAN_EDGES[edge])
     b, t_max, bf16 = 2, 16384, jnp.bfloat16
     lead = (b,) if page is None else (b * t_max // page + 1,)
     rows = t_max if page is None else page
@@ -328,9 +355,10 @@ def test_decode_kernel_compiles_at_the_plans_edges(chip, edge):
         ops['k_q'] = jax.ShapeDtypeStruct(lead + (h_kv, rows, d), jnp.int8)
         ops['k_scale'] = jax.ShapeDtypeStruct(lead + (h_kv, rows, 1),
                                               jnp.float32)
-    assert flash_decode_geometry(
+    geom = flash_decode_geometry(
         ops['q'], ops['k'], ops['v'], page_table=ops.get('page_table'),
-        qk_quant=qk_quant).heads == heads
+        qk_quant=qk_quant)
+    assert (geom.heads, geom.tail) == (heads, tail)
 
     def step(o):
         return flash_decode(
