@@ -75,7 +75,15 @@ FULL = dict(
     # picks 8 of its 32 blocks
     sparse=dict(dim=1024, heads=8, kv_heads=2, hidden=2048,
                 select=dict(kernel=32, stride=16, block=64, window=256,
-                            topk=8, dense_len=1024)))
+                            topk=8, dense_len=1024)),
+    # generate_ling: a delta-rule layer with full-rank gates and a
+    # bounded decay beside ONE latent layer with no query rank and a
+    # head-wise gate, experts under group-limited routing held as one
+    # group (the kinds of benchmarks/configs/ling-3.0-flash-serve at its
+    # head sizes): a LatentCache beside a StateCache in one list
+    ling=dict(dim=1024, heads=8, head_dim=128, kv_rank=512, nope=128,
+              rope=64, v=128, experts=32, top_k=4, groups=4, kept=2,
+              held=(8, 16), expert_hidden=256))
 TINY = dict(
     vocab=128, dim=64, heads=2, layers=1,
     train_t=128, ref_t=32,
@@ -94,7 +102,10 @@ TINY = dict(
                 shared=48),
     sparse=dict(dim=64, heads=4, kv_heads=2, hidden=96,
                 select=dict(kernel=4, stride=2, block=8, window=8, topk=2,
-                            dense_len=16)))
+                            dense_len=16)),
+    ling=dict(dim=64, heads=4, head_dim=16, kv_rank=32, nope=16, rope=16,
+              v=16, experts=8, top_k=2, groups=4, kept=2, held=(2, 4),
+              expert_hidden=32))
 
 # bf16 tolerance, relative to the compared tensor's own scale: two paths
 # that are equal in exact arithmetic may differ by max|a - b| <=
@@ -769,6 +780,133 @@ def phase_generate_sparse(progs, cfg, seed):
 
 # -- one chip: serve -----------------------------------------------------
 
+def ling_lm(cfg, **attn_kwargs):
+    """One delta-rule layer (full-rank gates, the bounded decay) and one
+    latent layer (no query rank, a head-wise output gate), each followed
+    by experts under group-limited routing of which ONE GROUP is held
+    (``cfg['ling']``): a ``StateCache`` and one layer's ``LatentCache``
+    side by side."""
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu import TransformerLM
+    c = cfg['ling']
+    return TransformerLM(
+        vocab_size=cfg['vocab'], dim=c['dim'], num_heads=c['heads'],
+        n_layers=2, dtype=jnp.bfloat16, scan_layers=False,
+        tie_embeddings=False,
+        block_kwargs={
+            'norm': 'rmsnorm', 'mixer': 'delta', 'ssm_kwargs': {
+                'heads': c['heads'], 'head_dim': c['head_dim'],
+                'beta_scale': 1.0, 'gate_rank': None, 'decay': 'bounded'},
+            'ffn': 'experts', 'ffn_kwargs': {
+                'n_experts': c['experts'], 'top_k': c['top_k'],
+                'hidden': c['expert_hidden'], 'scaling': 2.5,
+                'n_group': c['groups'], 'topk_group': c['kept'],
+                'experts_held': c['held']}},
+        layer_kinds={
+            'K': {},
+            'A': {'mixer': 'latent', 'attn_kwargs': {
+                'q_rank': None, 'kv_rank': c['kv_rank'],
+                'nope_dim': c['nope'], 'rope_dim': c['rope'],
+                'v_dim': c['v'], 'rope_theta': 6e6, 'out_gate': 'head',
+                **attn_kwargs}}},
+        layer_pattern=('K', 'A'))
+
+
+def phase_generate_ling(progs, cfg, seed):
+    """A latent cache beside a recurrent state, end to end: a prompt
+    prefilled through the chunked delta rule and the expanded latent
+    form, the state SNAPSHOTTED, a greedy request, the state restored
+    and the latent LENGTHS set back, and the request again — which must
+    read what the first did, bit for bit. Prints the step's forms: the
+    latent layer's (``mla_decode`` on its own buffer), the delta
+    mixer's, each expert layer's route, and the rows a step routed to
+    the held group."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces, restore_states, snapshot_states,
+    )
+    from distributed_dot_product_tpu.models.delta import delta_step_traces
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
+    model = ling_lm(cfg)
+    n, steps, t_max = cfg['prompt'], cfg['new_tokens'], cfg['gen_t_max']
+    params = {'params': model.init(
+        jax.random.key(seed + 9), jnp.zeros((1, 16), 'int32'))['params']}
+    prompt = jax.random.randint(jax.random.key(seed + 2), (1, n), 0,
+                                cfg['vocab'], dtype='int32')
+    prefill = jax.jit(lambda p, t, c: model.apply(p, t, c,
+                                                  method='prefill'))
+
+    def step_fn(p, t, c):
+        (c, logits), sown = model.apply(p, t, c, method='decode',
+                                        mutable=['counters'])
+        stack = sown['counters']['stack']
+        return c, logits, jnp.stack([
+            stack[f'block_{i}']['moe']['group_rows'] for i in range(2)])
+    step = jax.jit(step_fn, donate_argnums=(2,))
+
+    def reset(caches, taken):
+        return [c._replace(length=jnp.full_like(c.length, n))
+                if hasattr(c, 'length') else c
+                for c in restore_states(caches, taken)]
+    reset = jax.jit(reset, donate_argnums=(0,))
+    caches = model.make_decode_caches(1, t_max)
+    kinds = [type(c).__name__ for c in caches]
+    with decode_impl_traces() as traces, delta_step_traces() as forms, \
+            expert_route_traces() as routes:
+        progs.compile('ling.prefill', prefill, params, prompt, caches,
+                      pallas=True)
+        routes.clear()                  # the step's alone
+        progs.compile('ling.decode', step, params, prompt[:, :1], caches,
+                      pallas=True)
+    caches, logits = prefill(params, prompt, caches)
+    first = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+    taken = snapshot_states(caches)
+
+    def request(caches):
+        tok, out, rows = first, [], []
+        for _ in range(steps):
+            caches, logits, group_rows = step(params, tok, caches)
+            out.append(np.asarray(logits[:, -1], np.float32))
+            rows.append(np.asarray(group_rows))
+            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        return caches, np.stack(out), np.stack(rows)
+    caches, once, rows = request(caches)
+    moved = float(np.max(np.abs(
+        np.asarray(caches[0].state) - np.asarray(taken[0].state))))
+    grown = np.asarray(caches[1].length).tolist()
+    caches, again, _ = request(reset(caches, taken))
+    resolved = sorted({f"{t['resolved']}:{t['cache']}" for t in traces})
+    return {
+        'ling_caches': kinds,
+        'ling_mla_decode': resolved,
+        'ling_delta_step': forms,
+        'ling_expert_routes': routes,
+        'ling_group_rows': rows.tolist(),
+        'ling_state_moved_by_a_request': moved,
+        'ling_logits_max_abs': float(np.max(np.abs(once))),
+        'checks': {
+            'ling.cache_kinds': kinds == ['StateCache', 'LatentCache'],
+            'ling.latent_resolved_kernel': resolved == ['kernel:latent'],
+            'ling.delta_step_is_the_kernel': [f['form'] for f in forms] == [
+                'pallas'],
+            'ling.experts_on_the_hit_list': [
+                (r['route'], r['bound_by']) for r in routes] == 2 * [
+                    ('hit_list', 'rule')],
+            # one session: a step routes its row to the held group or not
+            'ling.group_rows_counted': bool(
+                np.all((rows == 0) | (rows == 1))),
+            'ling.latent_rows_grew': grown == [n + steps],
+            'ling.logits_finite': bool(np.all(np.isfinite(once))),
+            'ling.a_request_moves_the_state': moved > 0,
+            'ling.restored_request_agrees': bool(
+                np.array_equal(once, again)),
+        }}
+
+
 def engine(cfg, seed, **kw):
     import jax.numpy as jnp
 
@@ -1140,6 +1278,8 @@ def main(argv=None):
                run_phase('generate_hybrid', phase_generate_hybrid, cfg,
                          args.seed),
                run_phase('generate_sparse', phase_generate_sparse, cfg,
+                         args.seed),
+               run_phase('generate_ling', phase_generate_ling, cfg,
                          args.seed),
                run_phase('serve', phase_serve, cfg, args.seed, out_dir)]
     else:

@@ -65,6 +65,7 @@ __all__ = ['DecodeCache', 'init_cache', 'append_kv', 'append_kv_sharded',
            'ring_append', 'ring_window', 'insert_session',
            'StateCache', 'snapshot_states', 'restore_states',
            'SparseCache', 'init_sparse_cache', 'sparse_decode_traces',
+           'LatentCache',
            'PagedDecodeCache', 'PagePool', 'PageChecksums',
            'ShardedPageTable', 'init_sharded_paged_cache',
            'init_paged_cache', 'paged_gather', 'paged_gather_mirror',
@@ -246,10 +247,28 @@ class StateCache(NamedTuple):
     conv: jax.Array
 
 
+class LatentCache(NamedTuple):
+    """A latent-attention (MLA) layer's cache (``models/latent.py``):
+    ``rows (B, t_max, width)`` the compressed row of session ``b``'s
+    token ``t``, ``length (B,) int32`` the rows held of each session —
+    ONE layer's, beside the other layers' caches of a mixed stack. A
+    stack of latent layers alone keeps one layer-stacked buffer ``(L, B,
+    t_max, width)`` with lengths ``(L, B)`` (a layer advances its own,
+    as the slab caches' layers do). Rows past a length are never read:
+    a length set back rewinds it."""
+    rows: jax.Array
+    length: jax.Array
+
+    @property
+    def t_max(self):
+        return self.rows.shape[-2]
+
+
 def snapshot_states(caches):
     """A copy of every :class:`StateCache` in the per-layer list
-    ``caches`` (None at the layers of other kinds): what a prefix cache
-    of a recurrent model holds at the prompt's end."""
+    ``caches`` (None at the layers of other kinds — a cache that grows,
+    a :class:`LatentCache` among them, rewinds by its length): what a
+    prefix cache of a recurrent model holds at the prompt's end."""
     with device_scope('lm.state_restore'):
         return [jax.tree.map(jnp.copy, c) if isinstance(c, StateCache)
                 else None for c in caches]
@@ -281,14 +300,26 @@ def restore_states(caches, snapshot):
 
 def insert_session(cache, session, one):
     """``cache`` (a :class:`DecodeCache`, :class:`RingCache`,
-    :class:`SparseCache` or :class:`StateCache` of a serving batch;
-    None, a layer without a mixer, passes through) with session ``session`` replaced by the
+    :class:`SparseCache`, :class:`LatentCache` or :class:`StateCache` of
+    a serving batch; None, a layer without a mixer, passes through) with
+    session ``session`` replaced by the
     single session ``one`` holds — a prompt prefilled alone, then put in
     its slot. The batch shares one clock, so every session put in must
     be of ``one``'s length, which becomes the batch's (a state has
-    none). Donate ``cache``: the update is in place."""
+    none; a latent cache keeps a length a session, and the session's is
+    put in with its rows). Donate ``cache``: the update is in place."""
     if cache is None:
         return None
+    if isinstance(cache, LatentCache):
+        # one layer's (B, t_max, w) or the stacked (L, B, t_max, w)
+        zero = jnp.zeros((), jnp.int32)
+        lead = (zero,) * (cache.rows.ndim - 3)
+        session = jnp.asarray(session, jnp.int32)
+        return LatentCache(
+            rows=lax.dynamic_update_slice(
+                cache.rows, one.rows, (*lead, session, zero, zero)),
+            length=lax.dynamic_update_slice(cache.length, one.length,
+                                            (*lead, session)))
     if isinstance(cache, StateCache):
         return StateCache(*(
             lax.dynamic_update_index_in_dim(buf, new[0], session, 0)

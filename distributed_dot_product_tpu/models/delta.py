@@ -20,6 +20,15 @@ rank of the two low-rank gates:
     o_t = S_tᵀ q_t
     out = (RMSNorm_head(o) ⊙ sigmoid(z W_z)) W_out
 
+Two switches cover the mixer as ``bailing_hybrid`` (Ling 3.0) writes it.
+``gate_rank=None``: the decay and the output gate are FULL matrices,
+``f`` and ``z`` come out of the input projection ``inner`` wide each and
+there is no ``W_f`` / ``W_z``. ``decay='bounded'``: ``g = lower ·
+sigmoid(exp(A_log) · (f + dt_bias))`` with ``lower = decay_lower_bound``
+(−5: ``α`` in ``(e^-5, 1)`` a channel), where the default is the
+``softplus`` form above. The chunked form and the step's kernel take
+the log-decay they are given.
+
 Three entry points over one set of parameters, as
 :class:`~distributed_dot_product_tpu.models.ssm.Mamba2Mixer` has them:
 ``__call__`` (a whole sequence from a zero state), ``prefill`` (a chunk
@@ -177,10 +186,13 @@ def chunked_delta(q, k, v, log_a, beta, state, chunk):
 class GatedDeltaMixer(nn.Module):
     """The mixer of the module docstring. ``dim`` is the stream's width;
     ``heads x head_dim`` the inner width (``d_k = d_v = head_dim``);
-    the two low-rank gates' rank is ``head_dim``; ``conv`` the
-    convolutions' taps; ``chunk`` the chunked form's chunk;
-    ``beta_scale`` 2 where a head may have a negative eigenvalue, else
-    1; ``step_impl`` the decode step's form (:func:`delta_step`)."""
+    the two low-rank gates' rank is ``gate_rank`` (``'head_dim'``, the
+    default: ``head_dim``; None: full matrices inside ``in_proj``);
+    ``decay`` the log-decay's form, ``'softplus'`` or ``'bounded'``
+    below by ``decay_lower_bound``; ``conv`` the convolutions' taps;
+    ``chunk`` the chunked form's chunk; ``beta_scale`` 2 where a head
+    may have a negative eigenvalue, else 1; ``step_impl`` the decode
+    step's form (:func:`delta_step`)."""
     dim: int
     heads: int
     head_dim: int
@@ -191,10 +203,22 @@ class GatedDeltaMixer(nn.Module):
     dtype: Optional[jnp.dtype] = None
     state_dtype: Any = jnp.float32
     step_impl: Optional[str] = None
+    gate_rank: Any = 'head_dim'
+    decay: str = 'softplus'
+    decay_lower_bound: float = -5.0
 
     @property
     def inner(self):
         return self.heads * self.head_dim
+
+    @property
+    def _gate_width(self):
+        """Columns of ``in_proj`` each gate takes: its rank, or
+        ``inner`` where it is a full matrix."""
+        if self.gate_rank is None:
+            return self.inner
+        return (self.head_dim if self.gate_rank == 'head_dim'
+                else self.gate_rank)
 
     def make_cache(self, batch, dtype=None):
         """A zero :class:`StateCache` for ``batch`` sessions — plain
@@ -209,12 +233,17 @@ class GatedDeltaMixer(nn.Module):
         if self.step_impl not in (None, 'pallas', 'xla'):
             raise ValueError(f"step_impl must be None, 'pallas' or "
                              f"'xla', got {self.step_impl!r}")
+        if self.decay not in ('softplus', 'bounded'):
+            raise ValueError(f"decay must be 'softplus' or 'bounded', "
+                             f'got {self.decay!r}')
         dense = dict(use_bias=False, dtype=self.dtype)
-        rank = self.head_dim
-        self.in_proj = OwnedDense(3 * self.inner + 2 * rank + self.heads,
-                                  name='in_proj', **dense)
-        self.decay_up = OwnedDense(self.inner, name='decay_up', **dense)
-        self.gate_up = OwnedDense(self.inner, name='gate_up', **dense)
+        self.in_proj = OwnedDense(
+            3 * self.inner + 2 * self._gate_width + self.heads,
+            name='in_proj', **dense)
+        if self.gate_rank is not None:
+            self.decay_up = OwnedDense(self.inner, name='decay_up',
+                                       **dense)
+            self.gate_up = OwnedDense(self.inner, name='gate_up', **dense)
         self.out_proj = OwnedDense(self.dim, name='out_proj', **dense)
         init = nn.initializers
         self.conv_kernel = self.param(
@@ -231,9 +260,10 @@ class GatedDeltaMixer(nn.Module):
         """The input projection, the convolutions over ``window (B, K -
         1, 3 · inner)`` then the chunk, and the gates: ``q``, ``k``,
         ``v``, ``log_a (B, n, H, head_dim)`` and ``beta (B, n, H)``
-        float32, the output gate's low-rank half ``z (B, n, rank)`` and
-        the new window."""
-        rank = self.head_dim
+        float32, the output gate's low-rank half ``z (B, n, rank)`` (the
+        whole gate, ``inner`` wide, where ``gate_rank`` is None) and the
+        new window."""
+        rank = self._gate_width
         qkv, f, z, b = jnp.split(self.in_proj(h), [
             3 * self.inner, 3 * self.inner + rank,
             3 * self.inner + 2 * rank], -1)
@@ -250,9 +280,16 @@ class GatedDeltaMixer(nn.Module):
         def unit(x):
             return x * lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True)
                                  + 1e-6)
-        decay = nn.softplus(self.decay_up(f).astype(jnp.float32)
-                            + self.dt_bias).reshape(lead)
-        log_a = -jnp.exp(self.A_log)[:, None] * decay
+        if self.gate_rank is not None:
+            f = self.decay_up(f)
+        if self.decay == 'softplus':
+            decay = nn.softplus(f.astype(jnp.float32)
+                                + self.dt_bias).reshape(lead)
+            log_a = -jnp.exp(self.A_log)[:, None] * decay
+        else:
+            rate = (f.astype(jnp.float32) + self.dt_bias).reshape(lead)
+            log_a = self.decay_lower_bound * nn.sigmoid(
+                jnp.exp(self.A_log)[:, None] * rate)
         beta = self.beta_scale * nn.sigmoid(b.astype(jnp.float32))
         return (unit(q) * (1.0 / math.sqrt(self.head_dim)), unit(k), v,
                 log_a, beta, z, seen[:, n:].astype(window.dtype))
@@ -263,7 +300,9 @@ class GatedDeltaMixer(nn.Module):
         rank)``."""
         o = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
                           + self.norm_eps) * self.norm_scale
-        gate = nn.sigmoid(self.gate_up(z).astype(jnp.float32))
+        if self.gate_rank is not None:
+            z = self.gate_up(z)
+        gate = nn.sigmoid(z.astype(jnp.float32))
         o = o.reshape(gate.shape) * gate
         return self.out_proj(o.astype(z.dtype))
 

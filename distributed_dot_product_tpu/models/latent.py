@@ -24,11 +24,21 @@ Two forms of the same arithmetic:
   row itself, once, for all heads: ``flash_decode``'s latent mode
   (``ops.mla_decode``), or the XLA formulation off the chip.
 
-The cache (:class:`LatentCache`) is one layer-stacked buffer
-``(L, B, t_max, width)`` with per-layer, per-session lengths. It rides
-the layer loop's CARRY in prefill and in decode alike and every layer
-writes its own rows of it in place by index, so no layer is sliced out
-or written back. ``width`` is ``kv_rank + rope_dim`` rounded up to the
+Two switches cover the layer as ``bailing_hybrid`` (Ling 3.0) writes it:
+``q_rank=None`` has no query rank (``q = x W_q``, one matrix, no
+``q_a`` / ``q_norm``), and ``out_gate='head'`` multiplies each head's
+context by ``sigmoid(x W_g)_h`` (``W_g (dim, H)``, from the layer's
+normed input) before ``W_o`` — in the absorbed form AFTER ``W_kvb``'s V
+half, where the expanded form has it.
+
+The cache (:class:`LatentCache`) of a stack of latent layers alone is
+one layer-stacked buffer ``(L, B, t_max, width)`` with per-layer,
+per-session lengths. It rides the layer loop's CARRY in prefill and in
+decode alike and every layer writes its own rows of it in place by
+index, so no layer is sliced out or written back. A latent layer among
+layers of other kinds keeps ONE LAYER'S buffer ``(B, t_max, width)``
+with lengths ``(B,)`` beside their caches (``layer=None`` at every
+entry point). ``width`` is ``kv_rank + rope_dim`` rounded up to the
 128-lane tile (576 -> 640, the tail zeros): the chip lays an array
 whose minor dimension is no multiple of 128 out with the NEXT dimension
 minor, and the kernel's row blocks would then cost a cache-sized
@@ -36,7 +46,7 @@ relayout a layer a token (AOT for v5e, PR 26).
 """
 
 import math
-from typing import Any, NamedTuple, Optional
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -44,7 +54,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from distributed_dot_product_tpu.models.decode import (
-    _take_layer, record_decode_impl,
+    LatentCache, _take_layer, insert_session, record_decode_impl,
 )
 from distributed_dot_product_tpu.models.dense import OwnedDense
 from distributed_dot_product_tpu.ops.pallas_attention import (
@@ -65,37 +75,13 @@ LANES = 128
 HEAD_GROUP = 8
 
 
-class LatentCache(NamedTuple):
-    """``rows (L, B, t_max, width)``: layer ``l``'s compressed row of
-    session ``b``'s token ``t``; ``length (L, B) int32``: the rows each
-    layer holds of each session (a layer advances its own, as the slab
-    caches' layers do)."""
-    rows: jax.Array
-    length: jax.Array
-
-    @property
-    def t_max(self):
-        return self.rows.shape[-2]
-
-
 def init_latent_cache(layers, batch, t_max, row_dim, dtype=jnp.bfloat16):
+    """A zero :class:`LatentCache`; ``layers=None``: one layer's."""
     width = -(-row_dim // LANES) * LANES
+    lead = () if layers is None else (layers,)
     return LatentCache(
-        rows=jnp.zeros((layers, batch, t_max, width), dtype),
-        length=jnp.zeros((layers, batch), jnp.int32))
-
-
-def insert_session(cache: LatentCache, session, one: LatentCache):
-    """``cache`` with session ``session`` replaced by the single session
-    ``one`` holds (a prompt prefilled alone, then put in its slot of the
-    serving batch). Donate ``cache``: the update is in place."""
-    zero = jnp.zeros((), jnp.int32)
-    session = jnp.asarray(session, jnp.int32)
-    return LatentCache(
-        rows=lax.dynamic_update_slice(cache.rows, one.rows,
-                                      (zero, session, zero, zero)),
-        length=lax.dynamic_update_slice(cache.length, one.length,
-                                        (zero, session)))
+        rows=jnp.zeros((*lead, batch, t_max, width), dtype),
+        length=jnp.zeros((*lead, batch), jnp.int32))
 
 
 class LatentAttention(nn.Module):
@@ -109,11 +95,16 @@ class LatentAttention(nn.Module):
     ``yarn_mscale(mscale) / yarn_mscale(mscale_all_dim)`` (DeepSeek-V3's
     reading; both 1 where the two are equal).
 
+    ``q_rank=None``: no query rank, ``q = x W_q`` (the subtree ``q``;
+    no ``q_a`` / ``q_norm`` / ``q_b``). ``out_gate``: None, or ``'head'``
+    — each head's context times ``sigmoid(x W_g)_h`` before ``W_o`` (the
+    subtree ``gate``, ``(dim, H)``).
+
     ``decode_impl``: ``'auto'`` (the kernel on a TPU where the cache's
     ``t_max`` has a K split, else XLA), ``'kernel'``, ``'xla'``."""
     dim: int
     num_heads: int
-    q_rank: int
+    q_rank: Optional[int]
     kv_rank: int
     nope_dim: int
     rope_dim: int
@@ -123,15 +114,25 @@ class LatentAttention(nn.Module):
     norm_eps: float = 1e-6
     dtype: Optional[jnp.dtype] = None
     decode_impl: str = 'auto'
+    out_gate: Optional[str] = None
 
     def setup(self):
         h = self.num_heads
         dense = dict(use_bias=False, dtype=self.dtype)
-        self.q_a = OwnedDense(self.q_rank, name='q_a', **dense)
-        self.q_norm = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
-                                 name='q_norm')
-        self.q_b = OwnedDense(h * (self.nope_dim + self.rope_dim),
-                              name='q_b', **dense)
+        if self.out_gate not in (None, 'head'):
+            raise ValueError(f"out_gate must be None or 'head', got "
+                             f'{self.out_gate!r}')
+        if self.q_rank is None:
+            self.q = OwnedDense(h * (self.nope_dim + self.rope_dim),
+                                name='q', **dense)
+        else:
+            self.q_a = OwnedDense(self.q_rank, name='q_a', **dense)
+            self.q_norm = nn.RMSNorm(epsilon=self.norm_eps,
+                                     dtype=self.dtype, name='q_norm')
+            self.q_b = OwnedDense(h * (self.nope_dim + self.rope_dim),
+                                  name='q_b', **dense)
+        if self.out_gate:
+            self.gate = OwnedDense(h, name='gate', **dense)
         self.kv_a = OwnedDense(self.kv_rank + self.rope_dim, name='kv_a',
                                **dense)
         self.kv_norm = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
@@ -177,6 +178,7 @@ class LatentAttention(nn.Module):
         return out if mag == 1.0 else (out * mag).astype(x.dtype)
 
     def make_cache(self, layers, batch, t_max, dtype=None):
+        """``layers=None``: one layer's buffer (a mixed stack's)."""
         return init_latent_cache(layers, batch, t_max, self.row_dim,
                                  dtype or self.dtype or jnp.float32)
 
@@ -186,7 +188,8 @@ class LatentAttention(nn.Module):
         """``q_nope (B, H, T, nope)``, rotated ``q_rope (B, H, T, rope)``
         for ``x (B, T, dim)`` at ``positions (B, T)``."""
         b, t, _ = x.shape
-        q = self.q_b(self.q_norm(self.q_a(x)))
+        q = (self.q(x) if self.q_rank is None
+             else self.q_b(self.q_norm(self.q_a(x))))
         q = q.reshape(b, t, self.num_heads, self.nope_dim + self.rope_dim)
         q = jnp.swapaxes(q, 1, 2)
         q_rope = self._rotate(q[..., self.nope_dim:],
@@ -207,11 +210,20 @@ class LatentAttention(nn.Module):
         w = self.kv_b.astype(dtype)
         return w[..., :self.nope_dim], w[..., self.nope_dim:]
 
-    def _expanded(self, q_nope, q_rope, rows, offset):
+    def _gated(self, ctx, x):
+        """The heads' contexts ``ctx (B, T, H, v)`` under the head-wise
+        output gate of the layer's input ``x (B, T, dim)``."""
+        if not self.out_gate:
+            return ctx
+        gate = nn.sigmoid(self.gate(x).astype(jnp.float32))
+        return (ctx * gate[..., None]).astype(ctx.dtype)
+
+    def _expanded(self, q_nope, q_rope, rows, offset, x):
         """Causal attention of the query rows (global positions
         ``offset + i``) over the keys and values ``W_kvb`` expands
         ``rows (B, S, >= row_dim)`` to, through the flash forward
-        kernel; returns ``(B, T, dim)``. The heads go through
+        kernel, gated by the layer's input ``x`` (``out_gate``);
+        returns ``(B, T, dim)``. The heads go through
         ``HEAD_GROUP`` at a time, so the expanded keys and values that
         exist at once are a group's (a 32k-row session's are 0.8 GB for
         32 heads)."""
@@ -254,8 +266,8 @@ class LatentAttention(nn.Module):
             out = lax.map(group, (by_group(q, 1), by_group(wk, 1),
                                   by_group(wv, 1)))
             out = jnp.moveaxis(out, 0, 1).reshape(b, h, t, self.v_dim)
-        out = jnp.swapaxes(out, 1, 2).reshape(b, t, h * self.v_dim)
-        return self.out(out)
+        out = self._gated(jnp.swapaxes(out, 1, 2), x)
+        return self.out(out.reshape(b, t, h * self.v_dim))
 
     # -- entry points -------------------------------------------------------
 
@@ -264,11 +276,12 @@ class LatentAttention(nn.Module):
             b, t, _ = x.shape
             pos = jnp.broadcast_to(jnp.arange(t), (b, t))
             q_nope, q_rope = self._queries(x, pos)
-            return self._expanded(q_nope, q_rope, self._rows(x, pos), 0)
+            return self._expanded(q_nope, q_rope, self._rows(x, pos), 0, x)
 
-    def prefill(self, x, cache: LatentCache, layer):
+    def prefill(self, x, cache: LatentCache, layer=None):
         """Append the chunk ``x (B, n, dim)`` to layer ``layer`` of the
-        stacked cache, in place, and attend it over the rows held. The
+        stacked cache (``layer=None``: to the one layer's buffer
+        ``cache`` is), in place, and attend it over the rows held. The
         sessions of one call share a length (one causal offset a kernel
         call), as the slab caches' do."""
         with device_scope('lm.attn_proj'):
@@ -278,19 +291,33 @@ class LatentAttention(nn.Module):
             q_nope, q_rope = self._queries(x, pos)
             new = self._rows(x, pos, cache.rows.shape[-1])
             zero = jnp.zeros((), jnp.int32)
-            layer = jnp.asarray(layer, jnp.int32)
-            rows = lax.dynamic_update_slice(
-                cache.rows, new.astype(cache.rows.dtype)[None],
-                (layer, zero, start, zero))
-            cache = LatentCache(
-                rows=rows, length=cache.length.at[layer].add(n))
+            new = new.astype(cache.rows.dtype)
+            if layer is None:
+                rows = lax.dynamic_update_slice(cache.rows, new,
+                                                (zero, start, zero))
+            else:
+                # (this order of operations is the accepted programs'
+                # text: tests/test_hybrid_stack.py)
+                layer = jnp.asarray(layer, jnp.int32)
+                rows = lax.dynamic_update_slice(
+                    cache.rows, new[None], (layer, zero, start, zero))
+            cache = LatentCache(rows=rows,
+                                length=self._advanced(cache, layer, n))
             out = self._expanded(q_nope, q_rope, _take_layer(rows, layer),
-                                 start)
+                                 start, x)
             return cache, out
 
-    def decode(self, x, cache: LatentCache, layer):
+    @staticmethod
+    def _advanced(cache, layer, n):
+        """The lengths after ``n`` more rows of layer ``layer``."""
+        if layer is None:
+            return cache.length + n
+        return cache.length.at[layer].add(n)
+
+    def decode(self, x, cache: LatentCache, layer=None):
         """One token a session, ``x (B, 1, dim)``: the absorbed form
-        over layer ``layer`` of the stacked cache, appended in place."""
+        over layer ``layer`` of the stacked cache (``layer=None``: over
+        the one layer's buffer), appended in place."""
         with device_scope('lm.attn_proj'):
             b = x.shape[0]
             length = _take_layer(cache.length, layer)
@@ -307,8 +334,8 @@ class LatentAttention(nn.Module):
         # The kernel's view: one KV "head", one query row a session.
         shape = cache.rows.shape
         q4 = q[:, :, None]
-        rows4 = cache.rows.reshape(*shape[:2], 1, *shape[2:])
-        impl = self._resolve(q4, rows4)
+        rows4 = cache.rows.reshape(*shape[:-2], 1, *shape[-2:])
+        impl = self._resolve(q4, rows4, layer)
         if impl == 'kernel':
             ctx, rows, *_ = flash_decode(
                 q4, new[:, None], None, rows4, None, length, length,
@@ -317,7 +344,9 @@ class LatentAttention(nn.Module):
             ctx, rows = ctx[:, :, 0], rows.reshape(shape)
         else:
             with device_scope('lm.attn_proj'):
-                rows = cache.rows.at[layer, jnp.arange(b), length].set(
+                at = (jnp.arange(b), length)
+                rows = cache.rows.at[at if layer is None
+                                     else (layer, *at)].set(
                     new[:, 0].astype(cache.rows.dtype), mode='drop')
                 held = _take_layer(rows, layer)
                 s = jnp.einsum('bhc,bsc->bhs', q, held,
@@ -334,14 +363,18 @@ class LatentAttention(nn.Module):
             out = jnp.einsum('bhc,chd->bhd', ctx.astype(x.dtype), wv,
                              preferred_element_type=jnp.float32
                              ).astype(x.dtype)
+            if self.out_gate:
+                out = self._gated(out[:, None], x)
             out = self.out(out.reshape(b, 1, self.num_heads * self.v_dim))
-            return LatentCache(rows=rows,
-                               length=cache.length.at[layer].add(1)), out
+            return LatentCache(rows=rows, length=self._advanced(
+                cache, layer, 1)), out
 
-    def _resolve(self, q, rows):
+    def _resolve(self, q, rows, layer):
         """``decode_impl`` for the kernel operands ``q (B, H, 1, d)`` and
-        ``rows (L, B, 1, t_max, d)``, recorded for
-        ``decode_impl_traces()`` with the grid step the kernel takes."""
+        ``rows ([L,] B, 1, t_max, d)``, recorded for
+        ``decode_impl_traces()`` with the grid step the kernel takes and
+        the cache it was on: ``'stacked'``, or ``'latent'`` for one
+        layer's buffer."""
         impl, reason = self.decode_impl, None
         if impl not in ('auto', 'kernel', 'xla'):
             raise ValueError(f"decode_impl must be 'auto', 'kernel' or "
@@ -359,6 +392,7 @@ class LatentAttention(nn.Module):
             elif jax.default_backend() != 'tpu':
                 resolved = 'xla'
                 reason = f'backend is {jax.default_backend()}, not tpu'
-        record_decode_impl(impl, resolved, reason, 'stacked',
+        record_decode_impl(impl, resolved, reason,
+                           'latent' if layer is None else 'stacked',
                            geom if resolved == 'kernel' else None)
         return resolved

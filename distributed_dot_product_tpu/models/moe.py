@@ -2,13 +2,25 @@
 """
 A sparse-expert feed-forward layer: a router over ``n_experts`` gated
 MLPs, ``top_k`` of them a token, beside ``n_shared`` shared experts that
-every token takes (DeepSeek-V3's layer, ``noaux_tc`` routing with one
-group):
+every token takes (DeepSeek-V3's layer, ``noaux_tc`` routing):
 
     s = sigmoid(x W_g)                       float32, (n_experts,)
     picked = top_k(s + b)                    b: the correction bias
+                                             (over the kept groups'
+                                             experts, below)
     g_i = s_i / sum_picked s · scaling       (norm_topk), i in picked
     y = sum_i g_i E_i(x) + E_shared(x)       E(x) = W_down(silu(W_gate x) * W_up x)
+
+GROUP-LIMITED routing (``n_group > 1``; DeepSeek-V3's, Ling 3.0's): the
+experts are ``n_group`` consecutive groups of ``n_experts / n_group``, a
+group's score is the sum of its two best BIASED scores, the best
+``topk_group`` groups are kept, and the pick is the top-k of the biased
+scores of the kept groups' experts alone; the gates are the picks'
+unbiased scores as before. Where a deployment gives each chip one group
+a token reaches ``topk_group`` chips at most; a holder of one whole
+group (``experts_held``) gets no row from a token whose kept groups
+leave its group out, and the ``counters`` collection says how many rows
+it did get (``group_rows``).
 
 No capacity factor: no token is dropped. Two switches cover the same
 layer as other families write it: ``router_bias=False`` has no
@@ -158,7 +170,9 @@ class SparseExperts(nn.Module):
     """``y, tokens_per_expert = layer(x)`` for ``x (..., dim)``;
     ``tokens_per_expert (n_experts,) int32`` counts this call's picks
     over ALL experts (also sown into the ``counters`` collection as
-    ``expert_tokens``, with the picks ``expert_picks (tokens, top_k)``,
+    ``expert_tokens``, with the picks ``expert_picks (tokens, top_k)``
+    and, under group-limited routing, ``group_rows``: the tokens of the
+    call whose kept groups include a group with an expert held here,
     where the caller makes that collection mutable)."""
     n_experts: int
     top_k: int
@@ -176,6 +190,8 @@ class SparseExperts(nn.Module):
     latent: Optional[int] = None
     shared_hidden: Optional[int] = None
     dense_tokens: Optional[int] = None
+    n_group: int = 1
+    topk_group: int = 1
     dtype: Optional[jnp.dtype] = None
     kernel_init: Any = nn.initializers.lecun_normal(in_axis=-2,
                                                     out_axis=-1,
@@ -196,6 +212,22 @@ class SparseExperts(nn.Module):
         with device_scope('lm.moe_experts'):
             return hit_experts(tokens, table[:, lo:hi], hits, count,
                                w_gate, w_up, w_down, act)
+
+    def _kept_groups(self, choice, lo, hi):
+        """Group-limited routing's first half: ``choice (n, n_experts)``
+        (the biased scores) with every expert outside the token's best
+        ``topk_group`` groups at ``-inf`` — a group's score the sum of
+        its two best — and how many of the ``n`` tokens kept a group
+        that holds an expert of ``[lo, hi)``."""
+        n, size = choice.shape[0], self.n_experts // self.n_group
+        best2, _ = lax.top_k(choice.reshape(n, self.n_group, size), 2)
+        _, kept = lax.top_k(jnp.sum(best2, -1), self.topk_group)
+        keep = jnp.zeros((n, self.n_group), bool).at[
+            jnp.arange(n)[:, None], kept].set(True)
+        mine = jnp.any(keep[:, lo // size:(hi - 1) // size + 1], axis=-1)
+        return (jnp.where(jnp.repeat(keep, size, axis=1), choice,
+                          -jnp.inf),
+                jnp.sum(mine, dtype=jnp.int32))
 
     @nn.compact
     def __call__(self, x):
@@ -221,6 +253,15 @@ class SparseExperts(nn.Module):
                 'picked logits: it sums to one and takes no bias and no '
                 'factor (pass router_bias=False; norm_topk and scaling '
                 'stay at their defaults)')
+        grouped = self.n_group > 1
+        if grouped and (picked_softmax or self.n_experts % self.n_group
+                        or not 0 < self.topk_group <= self.n_group
+                        or self.n_experts // self.n_group < 2):
+            raise ValueError(
+                f'group-limited routing keeps topk_group '
+                f'{self.topk_group} of n_group {self.n_group} equal '
+                f'groups (two experts or more each) of the '
+                f'{self.n_experts} experts, by sigmoid scores')
         act = ACTIVATIONS[self.activation]
         gated = self.expert_form == 'gated'
         dim = x.shape[-1]
@@ -257,8 +298,10 @@ class SparseExperts(nn.Module):
                 gates = jax.nn.softmax(top, axis=-1)
             else:
                 scores = jax.nn.sigmoid(scores)
-                _, picked = lax.top_k(
-                    scores if bias is None else scores + bias, k)
+                choice = scores if bias is None else scores + bias
+                if grouped:
+                    choice, group_rows = self._kept_groups(choice, lo, hi)
+                _, picked = lax.top_k(choice, k)
                 gates = jnp.take_along_axis(scores, picked, axis=-1)
                 if self.norm_topk:
                     gates = gates / jnp.sum(gates, -1, keepdims=True)
@@ -287,6 +330,10 @@ class SparseExperts(nn.Module):
         self.sow('counters', 'expert_picks', picked,
                  reduce_fn=lambda old, new: new,
                  init_fn=lambda: jnp.zeros((n, k), jnp.int32))
+        if grouped:
+            self.sow('counters', 'group_rows', group_rows,
+                     reduce_fn=lambda old, new: new,
+                     init_fn=lambda: jnp.zeros((), jnp.int32))
 
         tile = hidden_tile(wide, self.hidden, 2 + gated,
                            w_up.dtype.itemsize) if hit_route else None

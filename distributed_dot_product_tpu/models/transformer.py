@@ -96,7 +96,9 @@ class TransformerBlock(nn.Module):
       keys/queries/values, reference example.py:31's usage) |
       ``'latent'`` (``models/latent.LatentAttention``; ``attn_kwargs``
       are its ranks and head sizes, its cache one layer-stacked
-      ``LatentCache`` addressed by ``layer``) | ``'ssm'``
+      ``LatentCache`` addressed by ``layer`` in a stack of latent
+      layers alone, one layer's ``LatentCache`` beside the other
+      layers' caches in a mixed stack) | ``'ssm'``
       (``models/ssm.Mamba2Mixer(**ssm_kwargs)``, the subtree ``ssm``;
       its cache a fixed-size ``StateCache``) | ``'delta'``
       (``models/delta.GatedDeltaMixer(**ssm_kwargs)``, the subtree
@@ -465,7 +467,9 @@ class TransformerStack(nn.Module):
     # residual / parallel fields, as a dict), and the KIND of each
     # layer: ``layer_kinds`` names the kinds, each with what it
     # overrides of ``block_kwargs`` (its ``'attn_kwargs'`` entry is laid
-    # over the stack's ``attn_kwargs``, not in place of it), and
+    # over the stack's ``attn_kwargs``, not in place of it — but for a
+    # ``'latent'`` kind in a stack whose own mixer is another: the
+    # stack's are another module's fields and say nothing to it), and
     # ``layer_pattern`` gives the layers' kinds in order — one name a
     # layer, or one period of names that the depth repeats (three window
     # layers then a full one; a model's leading dense layers before its
@@ -473,8 +477,9 @@ class TransformerStack(nn.Module):
     # latent mixer or an expert feed-forward, runs unrolled
     # (``scan_layers=False``); its caches are a list, each layer's of
     # its own kind's geometry: a slab or a ring for an attention layer,
-    # a ``StateCache`` for a recurrent one, None for a layer without a
-    # mixer.
+    # a ``StateCache`` for a recurrent one, one layer's ``LatentCache``
+    # for a latent one, None for a layer without a mixer. (A stack whose
+    # EVERY layer is latent keeps ONE layer-stacked ``LatentCache``.)
     block_kwargs: Any = None
     layer_kinds: Any = None
     layer_pattern: Any = None
@@ -487,7 +492,11 @@ class TransformerStack(nn.Module):
         if self.layer_pattern:
             over = dict(self.layer_kinds[self.layer_pattern[
                 i % len(self.layer_pattern)]] or {})
-            attn.update(over.pop('attn_kwargs', None) or {})
+            own = over.pop('attn_kwargs', None) or {}
+            if (over.get('mixer') == 'latent'
+                    and block.get('mixer') != 'latent'):
+                attn = {}
+            attn.update(own)
             block.update(over)
         return attn, block
 
@@ -505,7 +514,11 @@ class TransformerStack(nn.Module):
 
     @property
     def _latent(self):
-        return (self.block_kwargs or {}).get('mixer') == 'latent'
+        """Every layer a latent mixer: ONE layer-stacked cache, carried
+        from block to block (:meth:`_latent_step`). A latent layer among
+        other kinds is a kind like them, in the by-kind loop."""
+        return all(self._layer_kwargs(i)[1].get('mixer') == 'latent'
+                   for i in range(self.n_layers))
 
     def setup(self):
         if self.remat and not self.scan_layers:
@@ -605,19 +618,24 @@ class TransformerStack(nn.Module):
         # kind's ring beside a full kind's slab. Scanned stacks get ONE
         # cache pytree with a leading layer axis (prefill's scanned
         # input, decode's loop carry); unrolled stacks a list.
+        def latent_cache(layers, attn):
+            return init_latent_cache(
+                layers, batch, t_max, attn['kv_rank'] + attn['rope_dim'],
+                dtype or attn.get('dtype') or self.dtype or jnp.float32)
+
         if self._latent:
             # ONE layer-stacked buffer: every block addresses its own
             # layer of it.
-            kw = self._layer_kwargs(0)[0]
-            return init_latent_cache(
-                self.n_layers, batch, t_max,
-                kw['kv_rank'] + kw['rope_dim'],
-                dtype or kw.get('dtype') or self.dtype or jnp.float32)
+            return latent_cache(self.n_layers, self._layer_kwargs(0)[0])
+
         def layer_cache(i):
             attn, block = self._layer_kwargs(i)
             mixer = block.get('mixer', 'attention')
             if mixer == 'none':
                 return None
+            if mixer == 'latent':
+                # one layer's buffer, beside the other kinds' caches
+                return latent_cache(None, attn)
             if mixer in RECURRENT:
                 # Of FIXED size: t_max says nothing to it.
                 return RECURRENT[mixer](dim=self.dim, parent=None, **{
@@ -643,10 +661,12 @@ class TransformerStack(nn.Module):
     @staticmethod
     def _position(caches):
         """Where the call's first token stands: the length of the first
-        layer cache that has one (the batch shares one clock), None in a
+        layer cache that has one (the batch shares one clock: of a
+        latent cache's lengths, one a session, the first), None in a
         stack of recurrent layers alone."""
-        return next((c.length for c in caches if hasattr(c, 'length')),
-                    None)
+        length = next((c.length for c in caches if hasattr(c, 'length')),
+                      None)
+        return length[0] if getattr(length, 'ndim', 0) else length
 
     def prefill(self, x, caches):
         with device_scope('lm.stack_carry'):

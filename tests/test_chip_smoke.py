@@ -11,6 +11,7 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
 from distributed_dot_product_tpu.utils import compile_cache
@@ -81,7 +82,7 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     phases = {rec['phase']: rec for rec in lines if 'phase' in rec}
     assert set(phases) == {'train', 'generate', 'generate_latent',
                            'generate_mixed', 'generate_hybrid',
-                           'generate_sparse', 'serve'}
+                           'generate_sparse', 'generate_ling', 'serve'}
     # a recurrent state beside a slab: the request after a restore reads
     # what the first did
     hybrid = phases['generate_hybrid']
@@ -97,6 +98,17 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
          'select': 'sort'}]
     assert sparse['checks']['sparse.every_step_picks_topk'] is True
     assert sparse['checks']['sparse.restored_request_agrees'] is True
+    # a latent cache beside a delta-rule state: the step's forms, the
+    # expert routes and the rows routed to the held group are printed
+    ling = phases['generate_ling']
+    assert ling['ling_caches'] == ['StateCache', 'LatentCache']
+    assert ling['ling_mla_decode'] == ['xla:latent']
+    assert [f['form'] for f in ling['ling_delta_step']] == ['xla']
+    assert [r['route'] for r in ling['ling_expert_routes']] == 2 * [
+        'hit_list']
+    assert np.asarray(ling['ling_group_rows']).shape == (3, 2)
+    assert ling['checks']['ling.restored_request_agrees'] is True
+    assert ling['checks']['ling.latent_rows_grew'] is True
     # both kernel modes side by side, off the chip both through XLA
     assert phases['generate_mixed']['mixed_caches'] == ['layer', 'ring']
     for name, rec in phases.items():

@@ -231,6 +231,43 @@ def granite_op_names():
     return op_names(jax.jit(
         lambda p, tok, c: model.apply(p, tok, c, method='decode')
     ).lower(params, jnp.zeros((2, 1), jnp.int32), caches).compile())
+# The ``bailing_hybrid`` stack (delta-rule layers beside ONE latent
+# layer with a head-wise output gate, group-limited experts): no scope
+# of its own — the gate is ``lm.attn_proj``'s, the group selection
+# ``lm.moe_route``'s.
+LING_SCOPES = ['ops.delta_step', 'ops.delta_scan', 'lm.delta_proj',
+               'ops.mla_decode', 'ops.flash_fwd', 'lm.attn_proj', 'lm.mlp',
+               'lm.moe_route', 'lm.moe_experts', 'lm.embed', 'lm.head',
+               'lm.stack_carry']
+
+
+@pytest.fixture(scope='module')
+def ling_op_names():
+    """``{'decode': …, 'prefill': …}`` of a delta-rule + latent stack
+    with full-rank KDA gates, the bounded decay, the MLA layer's
+    head-wise gate and group-limited routing, both kernels on."""
+    model = TransformerLM(
+        vocab_size=64, dim=32, num_heads=4, n_layers=3, scan_layers=False,
+        tie_embeddings=False,
+        block_kwargs=dict(
+            norm='rmsnorm', mixer='delta', ssm_kwargs=dict(
+                heads=4, head_dim=8, chunk=8, step_impl='pallas',
+                beta_scale=1.0, gate_rank=None, decay='bounded'),
+            ffn='experts', ffn_kwargs=dict(
+                n_experts=16, top_k=3, hidden=16, experts_held=(4, 8),
+                n_group=4, topk_group=2)),
+        layer_kinds={
+            'D': dict(ffn='gated', ffn_kwargs=dict(hidden=48)), 'K': {},
+            'A': dict(mixer='latent', attn_kwargs=dict(
+                q_rank=None, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8,
+                out_gate='head', decode_impl='kernel'))},
+        layer_pattern=('D', 'K', 'A'))
+    params = model.init(jax.random.key(0), jnp.zeros((2, 8), jnp.int32))
+    caches = model.make_decode_caches(2, 128)
+    return {method: op_names(jax.jit(
+        lambda p, tok, c, m=method: model.apply(p, tok, c, method=m)
+    ).lower(params, jnp.zeros((2, n), jnp.int32), caches).compile())
+        for method, n in (('decode', 1), ('prefill', 8))}
 
 
 @pytest.fixture(scope='module')
@@ -391,6 +428,48 @@ def test_the_delta_rules_arithmetic_sits_in_its_scopes(delta_op_names):
                for n in delta_op_names['prefill'] if n.startswith('jit('))
 
 
+@pytest.mark.parametrize('scope', LING_SCOPES)
+def test_ling_stack_opens(scope, ling_op_names):
+    assert opened(scope, ling_op_names['decode']
+                  | ling_op_names['prefill'])
+
+
+def test_the_gate_and_the_group_selection_sit_in_their_scopes(
+        ling_op_names):
+    """Nothing of the step's or the prefill's own arithmetic is
+    unscoped. The latent layer's head-wise gate (its projection, the
+    ``logistic`` and the product) is ``lm.attn_proj``'s in both forms,
+    the latent kernel stays that scope's SIBLING; the group selection
+    (two more ``top_k`` a layer beside the pick's own, the kept-group
+    mask, the row count) is ``lm.moe_route``'s; the full-rank gates and
+    the bounded decay's ``logistic`` are ``lm.delta_proj``'s."""
+    def innermost(name):
+        return [part for part in name.split('/') if part in DEVICE_SCOPES][
+            -1]
+    for method in ('decode', 'prefill'):
+        mine = [n for n in ling_op_names[method] if n.startswith('jit(')]
+        assert mine and all(
+            any(opened(scope, [n]) for scope in DEVICE_SCOPES)
+            for n in mine)
+        gate = [n for n in mine if '/attn.' in n and (
+            '/gate/' in n or n.endswith('/logistic'))]
+        assert gate and {innermost(n) for n in gate} == {'lm.attn_proj'}
+        # (the decode step's view of the cache as the kernel's one KV
+        # head, a reshape between the two scopes, is the stack's)
+        assert {innermost(n) for n in mine if '/attn.' in n} <= {
+            'lm.attn_proj', 'ops.mla_decode', 'ops.flash_fwd',
+            'lm.stack_carry'}
+        picks = [n for n in mine if n.endswith('/top_k')]
+        assert len(picks) >= 3
+        assert {innermost(n) for n in picks} == {'lm.moe_route'}
+        assert {innermost(n) for n in mine if '/delta.' in n} == {
+            'lm.delta_proj',
+            'ops.delta_step' if method == 'decode' else 'ops.delta_scan'}
+    kernel = [n for n in ling_op_names['decode']
+              if '/ops.mla_decode/' in f'/{n}/']
+    assert kernel and not any('lm.attn_proj' in n for n in kernel)
+
+
 def test_the_multipliers_sit_inside_their_producers_scopes(
         granite_op_names):
     """Nothing of the step's own arithmetic is unscoped; the embedding's
@@ -438,7 +517,7 @@ def test_latent_kernel_is_outside_the_projection_scope(latent_op_names):
 def test_the_steps_cover_the_vocabulary():
     assert (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(LATENT_SCOPES)
             | set(MIXED_SCOPES) | set(HYBRID_SCOPES) | set(DELTA_SCOPES)
-            | set(SALA_SCOPES) == set(DEVICE_SCOPES))
+            | set(SALA_SCOPES) | set(LING_SCOPES) == set(DEVICE_SCOPES))
 
 
 def test_unknown_scope_raises():

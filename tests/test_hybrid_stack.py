@@ -366,7 +366,27 @@ PARENT_LOWERED.update({
 # xing4.prefill '204781b5…'.
 # Every ``*.decode`` entry and ``experts`` are the values they were: no
 # decode step moved.
+# The commit before the ``bailing_hybrid`` fields (PR 46: a latent mixer
+# as one KIND of a mixed stack with ``q_rank=None`` / ``out_gate``, the
+# delta mixer's ``gate_rank`` / ``decay``, the experts' ``n_group`` /
+# ``topk_group``, ``LatentCache`` moved beside the other caches): the
+# delta-rule cell's and the block-sparse / Lightning cell's tiny presets
+# as their drivers build them, the eighth and ninth accepted cells. With
+# these, every accepted cell's programs are pinned: each new field at
+# its default adds no operation.
+PARENT_LOWERED.update({
+    'solar.prefill':
+        'ae6062cbb942cbee3e8574b213a639c35eca7f896b3224c563e3f7061983488b',
+    'solar.decode':
+        '4d37eb3e635a0288704d0714139e4be9e5e970e433a7621bf69c4480fd4a4943',
+    'sala.prefill':
+        'be003dd10ce02b306b0bca6125a4910d71480ca882cff06569d66948160653a6',
+    'sala.decode':
+        'ff86461db157c36d6d46cb3c6a95423b30f337842b411a9eead072f00b161fdc',
+})
 PRESETS = {'granite': ('tiny_granite', 'tiny-granite.decode'),
+           'solar': ('tiny_solar', 'tiny-solar.decode'),
+           'sala': ('tiny_sala', 'tiny-sala.decode'),
            'xing4': ('tiny_latent', 'tiny-xing4.decode'),
            'command-a': ('tiny_mixed', 'tiny-command-a.decode'),
            'nemotron': ('tiny_hybrid', 'tiny-nemotron.decode'),

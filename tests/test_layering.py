@@ -119,3 +119,22 @@ def test_device_scopes_are_the_leafs_table():
         scopes.device_scope('no.such')
     with scopes.device_scope('ops.flash_fwd'):
         pass
+
+
+def test_the_latent_cache_lives_below_the_latent_mixer():
+    """``models/decode.py`` holds every cache type and the functions
+    that move sessions and states between them (``insert_session``,
+    ``snapshot_states`` / ``restore_states``) and imports no mixer:
+    ``LatentCache`` is defined there, beside ``StateCache``, and
+    ``models/latent.py`` re-exports the same objects (the Xing4 cell's
+    driver imports ``insert_session`` from there)."""
+    from distributed_dot_product_tpu.models import decode, latent
+    mixers = {f'{PKG}.models.{m}' for m in (
+        'latent', 'delta', 'ssm', 'lightning', 'sparse', 'moe',
+        'transformer', 'attention')}
+    assert not {name for _, name in _imported_modules(
+        'models/decode.py') if any(
+            name == m or name.startswith(m + '.') for m in mixers)}
+    assert latent.LatentCache is decode.LatentCache
+    assert latent.insert_session is decode.insert_session
+    assert 'LatentCache' in decode.__all__

@@ -1428,3 +1428,103 @@ def test_sala_prefill_chunk_picks_its_blocks_without_a_sort(
     assert calls.count('sparse_pick') == 1
     assert set(calls) - {'sparse_pick'} == {'flash_fwd'}
     assert not re.findall(SORT, hlo)
+
+
+def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
+        chip, monkeypatch):
+    """The token step of the delta-rule / latent-attention + expert stack
+    at the published widths and the traffic of
+    ``ling-3.0-flash.decode-32k`` (7 layers, 96 sessions: six ``(96, 32,
+    128, 128)`` float32 states with 12288-channel windows beside ONE
+    layer's latent buffer ``(96, 33792, 640)``), caches donated: the MLA
+    layer's step resolves to the kernel's latent mode over its own
+    buffer (``kernel:latent``), ONE ``mla_decode`` call; every delta
+    mixer's step is the kernel ``delta_step`` — six custom calls, each
+    state aliased and read by nothing else; the latent buffer is aliased
+    and nothing as large as one layer's 96 states (201 MB) is copied,
+    sliced or written back, no temporary is that large; every expert
+    layer's 96-row call is ONE ``moe_hit_experts`` kernel by the rule's
+    bound and no grouped matmul; arguments + temporaries stay under 14.0
+    GiB with the snapshot counted. The reset between requests writes all
+    six states over in place, under its scope's name, with no temporary,
+    and sets the latent lengths back."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_ling as driver
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces,
+    )
+    from distributed_dot_product_tpu.models.delta import delta_step_traces
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
+    from distributed_dot_product_tpu.ops.pallas_experts import hidden_tile
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'ling-3.0-flash-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-32k-x96.json')) as f:
+        traffic = json.load(f)
+    model = driver.build_lm(cfg)
+    assert driver.layer_kinds(cfg) == list('DKKKKKA')
+    params = _shape_table_params(driver, cfg)
+    sessions, t_max = traffic['sessions'], traffic['t_max']
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(sessions, t_max))
+    state = ((sessions, 32, 128, 128), (sessions, 3, 12288))
+    assert [tuple(x.shape for x in c[:2]) for c in caches] == (
+        6 * [state] + [((sessions, t_max, 640), (sessions,))])
+    assert caches[0].state.dtype == jnp.float32
+    assert caches[6].rows.dtype == jnp.bfloat16
+    stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
+    tok = jnp.zeros((sessions, 1), jnp.int32)
+    restore, step = driver.make_programs(model, cfg)[-2:]
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+    with decode_impl_traces() as traces, expert_route_traces() as routes, \
+            delta_step_traces() as forms:
+        compiled = step.lower(
+            *described((params, tok, caches, stats))).compile()
+    assert [(t['resolved'], t['cache']) for t in traces] == [
+        ('kernel', 'latent')]
+    assert forms == 6 * [{'form': 'pallas', 'tile': 16, 'chunk': 64}]
+    tile = hidden_tile(2560, 768, 3, 2)
+    assert routes == 6 * [{'route': 'hit_list', 'n': sessions,
+                           'bound': 128, 'bound_by': 'rule',
+                           'tile': tile}]
+    hlo = compiled.as_text()
+    assert 'ragged-dot' not in hlo
+    for kernel, calls in (('moe_hit_experts', 6), ('delta_step', 6),
+                          ('mla_decode', 1)):
+        assert len(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*' + kernel,
+            hlo)) == calls, kernel
+    state_bytes = sessions * 32 * 128 * 128 * 4
+    assert _cache_sized_moves(hlo, state_bytes) == []
+    # Each state is an operand of its kernel and of nothing else: read
+    # once, written once.
+    takers = _entry_readers(hlo, f'f32[{sessions},32,128,128]')
+    assert len(takers) == 6 and all('delta_step' in t for t in takers)
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < state_bytes
+    states = [c if hasattr(c, 'state') else None for c in caches]
+    snapshot_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                         for x in jax.tree.leaves(states))
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            + snapshot_bytes)
+    assert 11.0 * 2 ** 30 <= peak <= 14.0 * 2 ** 30, peak / 2 ** 30
+    restored = restore.lower(*described(
+        (caches, states, jnp.zeros((), jnp.int32)))).compile()
+    assert restored.memory_analysis().temp_size_in_bytes < 1 << 20
+    # Everything but the latent lengths (96 x 4 B), which are set.
+    assert restored.memory_analysis().alias_size_in_bytes >= (
+        cache_bytes - 4 * sessions)
+    assert restored.as_text().count('lm.state_restore') >= 12
