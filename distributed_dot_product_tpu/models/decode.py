@@ -249,19 +249,23 @@ class StateCache(NamedTuple):
 
 class LatentCache(NamedTuple):
     """A latent-attention (MLA) layer's cache (``models/latent.py``):
-    ``rows (B, t_max, width)`` the compressed row of session ``b``'s
-    token ``t``, ``length (B,) int32`` the rows held of each session —
-    ONE layer's, beside the other layers' caches of a mixed stack. A
-    stack of latent layers alone keeps one layer-stacked buffer ``(L, B,
-    t_max, width)`` with lengths ``(L, B)`` (a layer advances its own,
-    as the slab caches' layers do). Rows past a length are never read:
-    a length set back rewinds it."""
+    ``rows (B, row_dim, t_max)``, TIME-MINOR — column ``t`` of session
+    ``b`` is its token ``t``'s compressed row, ``row_dim`` values with
+    no padding (576 is no multiple of the 128-lane tile: stored a token
+    a row it is either padded to 640, a ninth of what a decode step
+    streams, or laid out time-minor by the chip anyway) —, ``length
+    (B,) int32`` the tokens held of each session — ONE layer's, beside
+    the other layers' caches of a mixed stack. A stack of latent layers
+    alone keeps one layer-stacked buffer ``(L, B, row_dim, t_max)`` with
+    lengths ``(L, B)`` (a layer advances its own, as the slab caches'
+    layers do). Columns past a length are never read: a length set back
+    rewinds it."""
     rows: jax.Array
     length: jax.Array
 
     @property
     def t_max(self):
-        return self.rows.shape[-2]
+        return self.rows.shape[-1]
 
 
 def snapshot_states(caches):
@@ -311,7 +315,7 @@ def insert_session(cache, session, one):
     if cache is None:
         return None
     if isinstance(cache, LatentCache):
-        # one layer's (B, t_max, w) or the stacked (L, B, t_max, w)
+        # one layer's (B, w, t_max) or the stacked (L, B, w, t_max)
         zero = jnp.zeros((), jnp.int32)
         lead = (zero,) * (cache.rows.ndim - 3)
         session = jnp.asarray(session, jnp.int32)

@@ -32,17 +32,22 @@ normed input) before ``W_o`` — in the absorbed form AFTER ``W_kvb``'s V
 half, where the expanded form has it.
 
 The cache (:class:`LatentCache`) of a stack of latent layers alone is
-one layer-stacked buffer ``(L, B, t_max, width)`` with per-layer,
+one layer-stacked buffer ``(L, B, row_dim, t_max)`` with per-layer,
 per-session lengths. It rides the layer loop's CARRY in prefill and in
-decode alike and every layer writes its own rows of it in place by
+decode alike and every layer writes its own columns of it in place by
 index, so no layer is sliced out or written back. A latent layer among
-layers of other kinds keeps ONE LAYER'S buffer ``(B, t_max, width)``
+layers of other kinds keeps ONE LAYER'S buffer ``(B, row_dim, t_max)``
 with lengths ``(B,)`` beside their caches (``layer=None`` at every
-entry point). ``width`` is ``kv_rank + rope_dim`` rounded up to the
-128-lane tile (576 -> 640, the tail zeros): the chip lays an array
-whose minor dimension is no multiple of 128 out with the NEXT dimension
-minor, and the kernel's row blocks would then cost a cache-sized
-relayout a layer a token (AOT for v5e, PR 26).
+entry point). The buffer is TIME-MINOR — a token is a column of
+``row_dim = kv_rank + rope_dim`` values (576), nothing padded: 576 is no
+multiple of the 128-lane tile, so a ``(…, t_max, 576)`` array the chip
+lays out with time minor anyway (a row-block kernel then pays a
+cache-sized relayout a layer a token: AOT for v5e, PR 26), and rows
+padded to 640 make a ninth of every byte a decode step streams a zero
+(until PR 47). As stored, ``row_dim`` is 36 whole sublane tiles of
+bfloat16 and a K split of the decode kernel whole lane tiles; prefill
+writes a chunk transposed and ``_expanded`` reads the latent with the
+rank axis major.
 """
 
 import math
@@ -77,10 +82,9 @@ HEAD_GROUP = 8
 
 def init_latent_cache(layers, batch, t_max, row_dim, dtype=jnp.bfloat16):
     """A zero :class:`LatentCache`; ``layers=None``: one layer's."""
-    width = -(-row_dim // LANES) * LANES
     lead = () if layers is None else (layers,)
     return LatentCache(
-        rows=jnp.zeros((*lead, batch, t_max, width), dtype),
+        rows=jnp.zeros((*lead, batch, row_dim, t_max), dtype),
         length=jnp.zeros((*lead, batch), jnp.int32))
 
 
@@ -196,15 +200,13 @@ class LatentAttention(nn.Module):
                               positions[:, None, :])
         return q[..., :self.nope_dim], q_rope
 
-    def _rows(self, x, positions, width=None):
-        """The tokens' cache rows ``(B, T, width)``: normalised latent,
-        rotated shared key, zeros to ``width``."""
+    def _rows(self, x, positions):
+        """The tokens' cache rows ``(B, T, row_dim)``: normalised
+        latent, rotated shared key (a cache holds them as columns)."""
         ckv = self.kv_a(x)
         c = self.kv_norm(ckv[..., :self.kv_rank])
         k_rope = self._rotate(ckv[..., self.kv_rank:], positions)
-        rows = jnp.concatenate([c, k_rope.astype(c.dtype)], axis=-1)
-        pad = (width or self.row_dim) - self.row_dim
-        return jnp.pad(rows, ((0, 0), (0, 0), (0, pad))) if pad else rows
+        return jnp.concatenate([c, k_rope.astype(c.dtype)], axis=-1)
 
     def _kv_b(self, dtype):
         w = self.kv_b.astype(dtype)
@@ -221,7 +223,7 @@ class LatentAttention(nn.Module):
     def _expanded(self, q_nope, q_rope, rows, offset, x):
         """Causal attention of the query rows (global positions
         ``offset + i``) over the keys and values ``W_kvb`` expands
-        ``rows (B, S, >= row_dim)`` to, through the flash forward
+        the columns ``rows (B, row_dim, S)`` to, through the flash forward
         kernel, gated by the layer's input ``x`` (``out_gate``);
         returns ``(B, T, dim)``. The heads go through
         ``HEAD_GROUP`` at a time, so the expanded keys and values that
@@ -230,14 +232,15 @@ class LatentAttention(nn.Module):
         b, h, t, _ = q_nope.shape
         g = HEAD_GROUP if h % HEAD_GROUP == 0 else h
         wk, wv = self._kv_b(rows.dtype)
-        c = rows[..., :self.kv_rank]
-        s_len = rows.shape[1]
+        c = rows[:, :self.kv_rank]
+        s_len = rows.shape[2]
         # K and q are built at the lane-tile width the flash kernel
         # would pad them to anyway (192 -> 256, the tail zeros), so the
         # expanded keys exist once, not unpadded and padded.
         tail = -(self.nope_dim + self.rope_dim) % LANES
+        k_rope = jnp.swapaxes(rows[:, self.kv_rank:], 1, 2)
         k_tail = jnp.concatenate(
-            [jnp.broadcast_to(rows[:, None, :, self.kv_rank:self.row_dim],
+            [jnp.broadcast_to(k_rope[:, None],
                               (b, g, s_len, self.rope_dim)),
              jnp.zeros((b, g, s_len, tail), rows.dtype)], axis=-1)
         q = jnp.concatenate(
@@ -246,10 +249,10 @@ class LatentAttention(nn.Module):
 
         def group(args):
             q_g, wk_g, wv_g = args
-            k_nope = jnp.einsum('bsc,chd->bhsd', c, wk_g,
+            k_nope = jnp.einsum('bcs,chd->bhsd', c, wk_g,
                                 preferred_element_type=jnp.float32
                                 ).astype(rows.dtype)
-            v = jnp.einsum('bsc,chd->bhsd', c, wv_g,
+            v = jnp.einsum('bcs,chd->bhsd', c, wv_g,
                            preferred_element_type=jnp.float32
                            ).astype(rows.dtype)
             return flash_attention(
@@ -276,7 +279,9 @@ class LatentAttention(nn.Module):
             b, t, _ = x.shape
             pos = jnp.broadcast_to(jnp.arange(t), (b, t))
             q_nope, q_rope = self._queries(x, pos)
-            return self._expanded(q_nope, q_rope, self._rows(x, pos), 0, x)
+            return self._expanded(q_nope, q_rope,
+                                  jnp.swapaxes(self._rows(x, pos), 1, 2),
+                                  0, x)
 
     def prefill(self, x, cache: LatentCache, layer=None):
         """Append the chunk ``x (B, n, dim)`` to layer ``layer`` of the
@@ -289,18 +294,16 @@ class LatentAttention(nn.Module):
             start = _take_layer(cache.length, layer)[0]
             pos = jnp.broadcast_to(start + jnp.arange(n), (b, n))
             q_nope, q_rope = self._queries(x, pos)
-            new = self._rows(x, pos, cache.rows.shape[-1])
+            new = jnp.swapaxes(self._rows(x, pos), 1, 2)
             zero = jnp.zeros((), jnp.int32)
             new = new.astype(cache.rows.dtype)
             if layer is None:
                 rows = lax.dynamic_update_slice(cache.rows, new,
-                                                (zero, start, zero))
+                                                (zero, zero, start))
             else:
-                # (this order of operations is the accepted programs'
-                # text: tests/test_hybrid_stack.py)
                 layer = jnp.asarray(layer, jnp.int32)
                 rows = lax.dynamic_update_slice(
-                    cache.rows, new[None], (layer, zero, start, zero))
+                    cache.rows, new[None], (layer, zero, zero, start))
             cache = LatentCache(rows=rows,
                                 length=self._advanced(cache, layer, n))
             out = self._expanded(q_nope, q_rope, _take_layer(rows, layer),
@@ -322,41 +325,42 @@ class LatentAttention(nn.Module):
             b = x.shape[0]
             length = _take_layer(cache.length, layer)
             q_nope, q_rope = self._queries(x, length[:, None])
-            new = self._rows(x, length[:, None], cache.rows.shape[-1])
+            new = self._rows(x, length[:, None])[:, 0].astype(
+                cache.rows.dtype)
             wk, wv = self._kv_b(x.dtype)
             q_lat = jnp.einsum('bhd,chd->bhc', q_nope[:, :, 0], wk,
                                preferred_element_type=jnp.float32
                                ).astype(x.dtype)
-            pad = cache.rows.shape[-1] - self.row_dim
-            q = jnp.concatenate(
-                [q_lat, q_rope[:, :, 0],
-                 jnp.zeros((b, self.num_heads, pad), x.dtype)], axis=-1)
-        # The kernel's view: one KV "head", one query row a session.
-        shape = cache.rows.shape
-        q4 = q[:, :, None]
-        rows4 = cache.rows.reshape(*shape[:-2], 1, *shape[-2:])
-        impl = self._resolve(q4, rows4, layer)
+            q = jnp.concatenate([q_lat, q_rope[:, :, 0]], axis=-1)
+            # The kernel's view: one KV "head", one query row a session,
+            # the new row as a lane tile of identical columns.
+            shape = cache.rows.shape
+            q4 = q[:, :, None]
+            rows4 = cache.rows.reshape(*shape[:-2], 1, *shape[-2:])
+            impl = self._resolve(q4, rows4, layer)
+            if impl == 'kernel':
+                tile = jnp.broadcast_to(new[:, None, :, None],
+                                        (b, 1, self.row_dim, LANES))
         if impl == 'kernel':
             ctx, rows, *_ = flash_decode(
-                q4, new[:, None], None, rows4, None, length, length,
+                q4, tile, None, rows4, None, length, length,
                 layer=layer, latent_v=self.kv_rank,
                 scale=self.softmax_scale())
             ctx, rows = ctx[:, :, 0], rows.reshape(shape)
         else:
             with device_scope('lm.attn_proj'):
-                at = (jnp.arange(b), length)
+                at = (jnp.arange(b), slice(None), length)
                 rows = cache.rows.at[at if layer is None
-                                     else (layer, *at)].set(
-                    new[:, 0].astype(cache.rows.dtype), mode='drop')
+                                     else (layer, *at)].set(new, mode='drop')
                 held = _take_layer(rows, layer)
-                s = jnp.einsum('bhc,bsc->bhs', q, held,
+                s = jnp.einsum('bhc,bcs->bhs', q, held,
                                preferred_element_type=jnp.float32)
-                seen = jnp.arange(held.shape[1]) <= length[:, None, None]
+                seen = jnp.arange(held.shape[2]) <= length[:, None, None]
                 p = jax.nn.softmax(
                     jnp.where(seen, s * self.softmax_scale(), -jnp.inf),
                     axis=-1)
-                ctx = jnp.einsum('bhs,bsc->bhc', p.astype(held.dtype),
-                                 held[..., :self.kv_rank],
+                ctx = jnp.einsum('bhs,bcs->bhc', p.astype(held.dtype),
+                                 held[:, :self.kv_rank],
                                  preferred_element_type=jnp.float32
                                  ).astype(x.dtype)
         with device_scope('lm.attn_proj'):
@@ -371,7 +375,7 @@ class LatentAttention(nn.Module):
 
     def _resolve(self, q, rows, layer):
         """``decode_impl`` for the kernel operands ``q (B, H, 1, d)`` and
-        ``rows ([L,] B, 1, t_max, d)``, recorded for
+        ``rows ([L,] B, 1, d, t_max)``, recorded for
         ``decode_impl_traces()`` with the grid step the kernel takes and
         the cache it was on: ``'stacked'``, or ``'latent'`` for one
         layer's buffer."""
@@ -379,7 +383,7 @@ class LatentAttention(nn.Module):
         if impl not in ('auto', 'kernel', 'xla'):
             raise ValueError(f"decode_impl must be 'auto', 'kernel' or "
                              f"'xla', got {impl!r}")
-        t_max = rows.shape[-2]
+        t_max = rows.shape[-1]
         geom = flash_decode_geometry(q, rows, latent_v=self.kv_rank)
         if impl == 'kernel' and geom is None:
             raise ValueError(f'the latent decode kernel has no K split '

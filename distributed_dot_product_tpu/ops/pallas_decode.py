@@ -107,7 +107,7 @@ from distributed_dot_product_tpu.ops.pallas_attention import (
 from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['flash_decode', 'decode_block_k', 'decode_geometry',
-           'flash_decode_geometry', 'DecodeGeometry']
+           'latent_geometry', 'flash_decode_geometry', 'DecodeGeometry']
 
 # K-split cap, in cache rows: the granularity at which a slot's unfilled
 # tail is skipped (never streamed). 1024 rows stream 7 % over a 12.4k
@@ -170,17 +170,17 @@ def _lanes(x):
     return -(-x // 128) * 128
 
 
-def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
+def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype, *,
                     n=1, quantized=False, page_size=None, block_k=None,
                     ring=False):
     """The decode kernel's grid step for a call of these shapes, or None
     where no K split divides ``t_max`` (the caller takes the XLA path).
 
     ``rows`` is the query rows a KV head scores (``group · n``);
-    ``v_dtype=None`` is the latent cache (one buffer, its values a lane
-    slice of the streamed block); ``page_size`` a paged pool's page,
-    which IS the split; ``block_k`` the tests' override of the split;
-    ``ring`` the ring mode.
+    ``page_size`` a paged pool's page, which IS the split; ``block_k``
+    the tests' override of the split; ``ring`` the ring mode. (The
+    latent cache's kernel has a rule of its own,
+    :func:`latent_geometry`.)
 
     The K split stays at :data:`_BLOCK_K_CAP` rows (skip granularity);
     the step then takes the most KV heads ``hb | h_kv`` whose K + V
@@ -209,8 +209,7 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
     bk = page_size or block_k or decode_block_k(t_max)
     if bk is None or t_max % bk:
         return None
-    sub = max(_sublane(k_dtype),
-              _sublane(k_dtype if v_dtype is None else v_dtype))
+    sub = max(_sublane(k_dtype), _sublane(v_dtype))
     wr = bk
     if n == 1 and not quantized and page_size is None and bk % sub == 0:
         wr = sub
@@ -219,8 +218,7 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
     # int8 mirror row and its f32 scale in place of the K row, which is
     # then fetched at its write block alone.
     k_row = _lanes(d) * jnp.dtype(k_dtype).itemsize
-    v_row = 0 if v_dtype is None else (_lanes(dv)
-                                       * jnp.dtype(v_dtype).itemsize)
+    v_row = _lanes(dv) * jnp.dtype(v_dtype).itemsize
     stream_row = (_lanes(d) + 4 if quantized else k_row) + v_row
     held_row = stream_row + (k_row if quantized else 0)
     q_sub = _sublane(jnp.int8) if quantized else sub
@@ -233,7 +231,7 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
     # that takes the new rows, as a value beside its row index; the
     # write-back block. Mosaic's own count (bisected vmem_limit_bytes
     # over 19 extreme shapes, PERF.md section 6, PR 27) stays under it.
-    v_item = jnp.dtype(k_dtype if v_dtype is None else v_dtype).itemsize
+    v_item = jnp.dtype(v_dtype).itemsize
     temps = (8 * g_pad * bk * 4 + bk * _lanes(dv) * (v_item + 4)
              + wr * held_row)
 
@@ -249,7 +247,7 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype=None, *,
                  and vmem(c) <= _VMEM_BUDGET)))
     tail = None
     if (wr != bk and not ring and t_max > bk and d == _lanes(d)
-            and (v_dtype is None or dv == _lanes(dv))):
+            and dv == _lanes(dv)):
         tail = next((rows for rows in _TAIL_ROWS
                      if rows < bk and bk % rows == 0 and rows % wr == 0
                      and vmem(hb, rows) <= _VMEM_BUDGET), None)
@@ -266,16 +264,16 @@ def flash_decode_geometry(q, cache_k, cache_v=None, *, page_table=None,
     ``models.decode.decode_impl_traces``) hands over what it hands the
     kernel and cannot drift from it."""
     h, n, d = q.shape[-3:]
+    if latent_v is not None:
+        return latent_geometry(cache_k.shape[-1], d, latent_v, h * n,
+                               cache_k.dtype, block_k=block_k)
     h_kv, t_max, page = cache_k.shape[-3], cache_k.shape[-2], None
     if page_table is not None:
         page, t_max = t_max, page_table.shape[1] * t_max
-    latent = latent_v is not None
     return decode_geometry(
-        t_max, h_kv, d, latent_v if latent else cache_v.shape[-1],
-        n * (h // h_kv), cache_k.dtype,
-        None if latent else cache_v.dtype, n=n,
-        quantized=qk_quant == 'int8', page_size=page, block_k=block_k,
-        ring=ring)
+        t_max, h_kv, d, cache_v.shape[-1], n * (h // h_kv), cache_k.dtype,
+        cache_v.dtype, n=n, quantized=qk_quant == 'int8', page_size=page,
+        block_k=block_k, ring=ring)
 
 
 def _sublane(dtype):
@@ -348,7 +346,7 @@ def _sweep_end(vt, ap, geom, t_max, n=1):
 
 def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                         quantized, has_alibi, paged=False, stacked=False,
-                        latent_v=None, ring=False):
+                        ring=False):
     """Kernel body; refs are ordered to match ``flash_decode``'s spec
     list below. Grid = (B·H_kv / hb, ns) with the K split innermost:
     one step holds ``hb = geom.heads`` KV heads of ONE slot (every
@@ -395,11 +393,6 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
     redundant with the fill check; for the sharded table it is the
     whole shard-local page-range view.
 
-    LATENT (``latent_v``): there is ONE buffer. The values are the first
-    ``latent_v`` columns of the very block the scores were taken from,
-    so the V refs (new rows, cache in, cache out) are absent and every
-    read of them below is a static lane slice of the K ones.
-
     RING (``ring``): the buffer's columns are a ring, not positions.
     ``vt`` is the column of the newest valid row (the append column)
     and a fourth prefetched vector ``span`` the number of valid rows
@@ -415,7 +408,6 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
     prefetched lengths whether a slot's last split is taken as its
     first ``tail`` rows; those are scored by the body above as one
     more block (``score_block``), after the last whole split's."""
-    latent = latent_v is not None
     hb, bk, wr, tail = (geom.heads, geom.block_k, geom.write_rows,
                        geom.tail)
     per_slot = h_kv // hb                       # grid rows a slot
@@ -437,31 +429,26 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
         kn_ref = next(it)
         kqn_ref = next(it) if quantized else None
         ksn_ref = next(it) if quantized else None
-        vn_ref = None if latent else next(it)
+        vn_ref = next(it)
         k_ref = next(it)
         kq_ref = next(it) if quantized else None
         ks_ref = next(it) if quantized else None
-        v_ref = None if latent else next(it)
+        v_ref = next(it)
         alibi_ref = next(it) if has_alibi else None
-        o_ref, m_ref, l_ref, ko_ref = (
-            next(it), next(it), next(it), next(it))
-        vo_ref = None if latent else next(it)
+        o_ref, m_ref, l_ref, ko_ref, vo_ref = (
+            next(it), next(it), next(it), next(it), next(it))
         kqo_ref = next(it) if quantized else None
         kso_ref = next(it) if quantized else None
         m_s, l_s, acc_s = next(it), next(it), next(it)
         if tail:
             # The tail's rows, the write-back tile's staging rows and
             # their DMA semaphores.
-            ktail_ref = next(it)
-            vtail_ref = None if latent else next(it)
-            kw_ref = next(it)
-            vw_ref = None if latent else next(it)
-            sems = next(it)
-            # K then V (the latent buffer: K alone): the result in HBM,
-            # the tail's rows, the staging tile, the new rows.
-            kv = [(ko_ref, ktail_ref, kw_ref, kn_ref)]
-            if not latent:
-                kv.append((vo_ref, vtail_ref, vw_ref, vn_ref))
+            ktail_ref, vtail_ref, kw_ref, vw_ref, sems = (
+                next(it), next(it), next(it), next(it), next(it))
+            # K then V: the result in HBM, the tail's rows, the staging
+            # tile, the new rows.
+            kv = [(ko_ref, ktail_ref, kw_ref, kn_ref),
+                  (vo_ref, vtail_ref, vw_ref, vn_ref)]
 
         # What scoring reads: the int8 mirror and its scales if any.
         score_ref, score_new_ref = ((kq_ref, kqn_ref) if quantized
@@ -551,7 +538,7 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
             for h in range(hb):
                 s = scores(h, score_blk[h],
                            scale_blk[h] if quantized else None)
-                v = k_blk[h, :, :latent_v] if latent else v_blk[h]
+                v = v_blk[h]
                 if substitute:
                     # New row m replaces whatever the buffer held at
                     # column ap + m (the nn guard keeps rows a
@@ -566,8 +553,7 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                             s_new[:, m:m + 1], s)
                         v = jnp.where(
                             jnp.logical_and(rows_v == ap + m, m < nn),
-                            (kn_ref[h, m:m + 1, :latent_v] if latent
-                             else vn_ref[h, m:m + 1, :]), v)
+                            vn_ref[h, m:m + 1, :], v)
                 if has_alibi:
                     s = s + alibi_ref[h] * relf
                 s = jnp.where(masked, -jnp.inf, s)
@@ -650,8 +636,7 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
             # Head by head, like the scores: one head's tile in flight.
             for h in range(hb):
                 put(h, k_ref, kn_ref, ko_ref, off, tile * wr)
-                if not latent:
-                    put(h, v_ref, vn_ref, vo_ref, off, tile * wr)
+                put(h, v_ref, vn_ref, vo_ref, off, tile * wr)
                 if quantized:
                     put(h, kq_ref, kqn_ref, kqo_ref, off, tile * wr)
                     # (1, bk) scale row vector: the appended row is a
@@ -822,14 +807,18 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
 
     ``latent_v`` (static int): LATENT mode, for a cache that keeps ONE
     row a token for all heads (multi-head latent attention's compressed
-    row, ``[c_kv ; k_rope]``): ``cache_v`` and ``v_new`` are None, the
-    one buffer ``cache_k (…, 1, t_max, d)`` is appended to and streamed
-    ONCE, all ``H`` query heads are the rows of one score matmul over
-    its ``d`` columns, and the values are the first ``latent_v`` columns
-    of the same resident block. ``out`` is ``(B, H, k, latent_v)``; the
-    returned ``cache_v`` is None. The Pallas program and its device
-    scope are named ``mla_decode`` / ``ops.mla_decode``. Not with
-    ``page_table`` or ``qk_quant``.
+    row, ``[c_kv ; k_rope]``), stored TIME-MINOR with no padding:
+    ``cache_k ([L,] B, 1, d, t_max)``, a token a column. It is a
+    program of its own (:func:`_latent_decode`: the Pallas program and
+    its device scope are named ``mla_decode`` / ``ops.mla_decode``),
+    single-token: ``cache_v`` and ``v_new`` are None, ``k_new (B, 1, d,
+    128)`` is the new row as a lane tile of 128 identical columns (what
+    the value block and the write-back tile take a column from), the
+    one buffer is appended to and streamed ONCE, all ``H`` query heads
+    are the rows of one score matmul over its ``d`` sublanes, and the
+    values are the first ``latent_v`` sublanes of the same resident
+    block. ``out`` is ``(B, H, 1, latent_v)``; the returned ``cache_v``
+    is None. With ``layer`` or without; no other mode goes with it.
 
     ``ring_span (B,) int32``: RING mode, for a window layer's recycled
     cache (``models.decode.RingCache``), whose ``t_max`` columns hold
@@ -865,25 +854,28 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     row, for the flash-decoding cross-shard merge (pmax the maxes,
     rescale, psum).
     """
-    b, h, n, d = q.shape
-    h_kv = cache_k.shape[-3]
-    latent = latent_v is not None
-    paged = page_table is not None
-    stacked = layer is not None
-    if latent:
-        if (cache_v is not None or v_new is not None or paged
-                or qk_quant is not None or h_kv != 1
-                or not 0 < latent_v <= d):
+    if latent_v is not None:
+        if not (cache_v is None and v_new is None and n_new is None
+                and page_table is None and k_q is None and k_scale is None
+                and window is None and alibi_slopes is None
+                and qk_quant is None and ring_span is None
+                and not partials):
             raise ValueError(
                 'flash_decode: latent_v reads the values from the one '
-                'buffer cache_k (…, 1, t_max, d >= latent_v): pass '
-                'cache_v=None and v_new=None, no page_table and no '
-                'qk_quant')
-        dv, v_dtype = latent_v, cache_k.dtype
-    else:
-        dv, v_dtype = cache_v.shape[-1], cache_v.dtype
+                'time-minor buffer cache_k (…, 1, d >= latent_v, t_max): '
+                'pass cache_v=None and v_new=None, and no other mode '
+                'than layer')
+        out, rows = _latent_decode(
+            q, k_new, cache_k, valid_to, append_at, latent_v, layer=layer,
+            scale=scale, interpret=interpret, block_k=block_k)
+        return out, rows, None, None, None
+    b, h, n, d = q.shape
+    h_kv = cache_k.shape[-3]
+    paged = page_table is not None
+    stacked = layer is not None
+    dv, v_dtype = cache_v.shape[-1], cache_v.dtype
     ring = ring_span is not None
-    if ring and (n != 1 or window is not None or latent or paged or stacked
+    if ring and (n != 1 or window is not None or paged or stacked
                  or alibi_slopes is not None or qk_quant is not None):
         raise ValueError(
             'flash_decode: ring_span is single-token and masks by the '
@@ -929,7 +921,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         t_max = cache_k.shape[-2]
     geom = flash_decode_geometry(
         q, cache_k, cache_v, page_table=page_table, qk_quant=qk_quant,
-        block_k=block_k, latent_v=latent_v, ring=ring)
+        block_k=block_k, ring=ring)
     if geom is None:
         raise ValueError(
             f'no usable K split for t_max={t_max} (block_k must '
@@ -978,9 +970,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     # are zeros the kernel never substitutes (its loops stop at n).
     knf = _pad_rows(k_new.astype(cache_k.dtype).reshape(nb, n, d),
                     _sublane(cache_k.dtype))
-    vnf = None if latent else _pad_rows(
-        v_new.astype(cache_v.dtype).reshape(nb, n, dv),
-        _sublane(cache_v.dtype))
+    vnf = _pad_rows(v_new.astype(cache_v.dtype).reshape(nb, n, dv),
+                    _sublane(cache_v.dtype))
     if paged:
         # Pool flattening mirrors the slab's (B, H_kv) fold: pool page
         # p's head hh lives at flat row p·H_kv + hh, so one BlockSpec
@@ -1004,7 +995,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         # A stacked buffer folds its layer axis into the rows too:
         # layer l's (slot, head) row r lives at flat row l·nb + r.
         kf = cache_k.reshape(-1, t_max, d)
-        vf = None if latent else cache_v.reshape(-1, t_max, dv)
+        vf = cache_v.reshape(-1, t_max, dv)
     valid_to = jnp.asarray(valid_to, jnp.int32)
     append_at = jnp.asarray(append_at, jnp.int32)
     # Per-slot appended-row count: callers without mixed batches get
@@ -1131,9 +1122,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         in_specs += [pl.BlockSpec((hb,) + kni.shape[1:], const_idx),
                      pl.BlockSpec((hb, 1, 1), const_idx)]
         args += [kni, kns.reshape(nb, 1, 1)]
-    if not latent:
-        in_specs.append(pl.BlockSpec((hb,) + vnf.shape[1:], const_idx))
-        args.append(vnf)
+    in_specs.append(pl.BlockSpec((hb,) + vnf.shape[1:], const_idx))
+    args.append(vnf)
     # The bf16 K buffer: streamed for scoring in the plain path; in the
     # quantized path scoring reads the mirror instead, so K is fetched
     # ONLY at its write block (one DMA per slot, to seed the append).
@@ -1159,10 +1149,9 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         args.append(kqf)
         ks_in_pos = len(args)
         args.append(ksf)
-    if not latent:
-        in_specs.append(pl.BlockSpec((hb, bk, dv), stream_idx))
-        v_in_pos = len(args)
-        args.append(vf)
+    in_specs.append(pl.BlockSpec((hb, bk, dv), stream_idx))
+    v_in_pos = len(args)
+    args.append(vf)
     has_alibi = alibi_slopes is not None
     if has_alibi:
         # Per-query-head slopes, pre-folded by log2e (the kernel's
@@ -1203,12 +1192,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     elif ring:
         prefetch += (jnp.asarray(ring_span, jnp.int32),)
     n_prefetch = len(prefetch)
-    aliases = {n_prefetch + k_in_pos: 3}
-    if not latent:
-        out_specs.append(pl.BlockSpec(memory_space=pl.ANY) if tail else
-                         pl.BlockSpec((hb, wr, dv), write_idx))  # v (aliased)
-        out_shape.append(jax.ShapeDtypeStruct(vf.shape, vf.dtype))
-        aliases[n_prefetch + v_in_pos] = 4
+    aliases = {n_prefetch + k_in_pos: 3, n_prefetch + v_in_pos: 4}
+    out_specs.append(pl.BlockSpec(memory_space=pl.ANY) if tail else
+                     pl.BlockSpec((hb, wr, dv), write_idx))  # v (aliased)
+    out_shape.append(jax.ShapeDtypeStruct(vf.shape, vf.dtype))
     if quantized:
         out_specs += [pl.BlockSpec((hb, wr, d), write_idx),
                       pl.BlockSpec((hb, 1, wr), write_idx_row)]
@@ -1223,23 +1210,21 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     if tail:
         # The tail's rows and the write-back tile's staging rows, K
         # then V; a DMA semaphore each.
-        kv = [(d, kf.dtype)] + ([] if latent else [(dv, vf.dtype)])
+        kv = [(d, kf.dtype), (dv, vf.dtype)]
         scratch += [pltpu.VMEM((hb, tail, w), t) for w, t in kv]
         scratch += [pltpu.VMEM((hb, wr, w), t) for w, t in kv]
         scratch.append(pltpu.SemaphoreType.DMA((2, 2)))
     kernel = _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                                  quantized, has_alibi, paged=paged,
-                                 stacked=stacked, latent_v=latent_v,
-                                 ring=ring)
-    name = 'mla_decode' if latent else 'flash_decode'
+                                 stacked=stacked, ring=ring)
+    name = 'flash_decode'
     # The ring mode's scope opens INSIDE the kernel's own: a reader that
     # knows only ops.flash_decode still counts it as the decode kernel.
     inner = contextlib.nullcontext()
     if ring:
         name, inner = 'flash_decode_ring', device_scope(
             'ops.flash_decode_ring')
-    with device_scope('ops.mla_decode' if latent
-                      else 'ops.flash_decode'), inner:
+    with device_scope('ops.flash_decode'), inner:
         outs = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1259,7 +1244,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         new_kq = outs[5].reshape(k_q.shape)
         new_ks = outs[6].reshape(k_scale.shape)   # same flat order
     new_k = new_k.reshape(cache_k.shape)
-    new_v = None if latent else outs[4].reshape(cache_v.shape)
+    new_v = outs[4].reshape(cache_v.shape)
 
     def head_shape(x):
         # Rows are new-row-major per kv head: undo the (n, group) fold
@@ -1272,3 +1257,394 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         return (num, m, l), new_k, new_v, new_kq, new_ks
     out = (num / jnp.where(l == 0.0, 1.0, l)).astype(v_dtype)
     return out, new_k, new_v, new_kq, new_ks
+
+
+# ---------------------------------------------------------------------------
+# The latent cache's kernel (``mla_decode``): ONE buffer, stored time-minor
+# ---------------------------------------------------------------------------
+#
+# A latent cache keeps one row of ``d = kv_rank + rope_dim`` values a token
+# for all heads (576 of bfloat16 where every accepted configuration is
+# concerned), and 576 is no multiple of the 128-lane tile. Stored a token a
+# ROW the buffer is either padded to 640 in HBM — a ninth of every byte the
+# step moves is then a zero — or laid out by the chip with time minor
+# anyway, and a row-block kernel pays a relayout. So the buffer IS
+# time-minor, ``(rows, d, t_max)``: ``d`` lies on sublanes (576 = 36 tiles
+# of 16), a token is a column, a K split a ``(d, block_k)`` block of whole
+# lane tiles, and nothing in HBM is padding.
+#
+# Bytes alone move nothing here (chip, PR 47): with 32 query rows a session
+# every value of a block enters an MXU twice, for a quarter of the array's
+# rows, which takes as long as the block's bytes; and a grid step costs
+# ~0.35 us whatever it moves. So the body is a program of its own. It shares
+# the slab kernel's online softmax and its way with the idle steps, and none
+# of its block shapes, copies or write-back:
+#
+# - the two products swap orientation: scores ``q · blk`` plain, the context
+#   ``p · blk[:dv]ᵀ`` over the lanes of both;
+# - the K split is LONG (``_LATENT_BLOCK_K``: up to 2048 columns), so the
+#   step's own cost is a small part of it, and a block is scored in
+#   SUB-BLOCKS of 512 columns whose score products are all issued before
+#   the first softmax — the MXU has the next sub-block's product to run
+#   under the VPU's max / exp2 chain of this one;
+# - the split that holds a session's last valid column is NOT streamed
+#   (a long split would move up to its whole length past the fill): it is
+#   moved in PIECES of ``geom.tail`` columns, as many as hold a valid
+#   column, by the kernel's own copies from the aliased result, started
+#   under the session before;
+# - the append is one COLUMN: a read-modify-write of the lane tile that
+#   holds it.
+
+# The K splits a latent buffer is tried at, longest first: multiples of
+# the 512-column sub-block that the VMEM plan has room for four times
+# (the stream and the last split's pieces, each double-buffered).
+_LATENT_BLOCK_K = (2048, 1536, 1024, 512)
+_LATENT_SUB = 512
+_LATENT_PIECE = 256
+# The latent kernel's VMEM plan, and the limit it asks the compiler for
+# where the plan passes the slab kernel's (:data:`_VMEM_BUDGET`, what the
+# default scoped limit of 16 MiB holds: a split of 1536 columns for 32
+# query rows and not one of 2048; a v5e core has 128 MiB). A call within
+# the default asks for nothing: scoped VMEM a kernel reserves is VMEM XLA
+# cannot keep the next layer's prefetched weights in across it.
+_LATENT_VMEM_BUDGET = 24 << 20
+_LATENT_VMEM_LIMIT = 32 << 20
+
+
+def _latent_vmem(bk, d, dv, rows, dtype):
+    """The latent kernel's VMEM plan at a split of ``bk`` columns: the
+    stream and the last split's pieces, each double-buffered, the
+    staging tile and the new row's tile (double-buffered); the queries,
+    the softmax state and the results at 4 bytes an element; a block's
+    scores and a sub-block's probabilities, masks and value half with
+    the new column selected in."""
+    item = jnp.dtype(dtype).itemsize
+    sub = _sublane(dtype)
+    d_sub, g_pad = -(-d // sub) * sub, -(-rows // sub) * sub
+    return ((4 * bk + 3 * 128) * d_sub * item
+            + 3 * g_pad * (_lanes(d) + _lanes(dv) + 256) * 4
+            + g_pad * (bk + 6 * min(bk, _LATENT_SUB)) * 4
+            + min(bk, _LATENT_SUB) * dv * (item + 4))
+
+
+def latent_geometry(t_max, d, dv, rows, dtype, *, block_k=None):
+    """The latent kernel's grid step for a time-minor buffer of ``t_max``
+    columns of ``d`` values (``dv`` of them the values), ``rows`` query
+    rows (the heads) a session — or None where no K split divides
+    ``t_max`` into whole lane tiles within the VMEM plan (the caller
+    takes the XLA path).
+
+    A step is ONE session's ``(d, block_k)`` block (``heads`` 1: the
+    buffer has one shared head), ``bytes`` what it streams — stored
+    bytes, nothing padded. The split is the longest of
+    :data:`_LATENT_BLOCK_K` that divides ``t_max`` (else 256 or 128; a
+    buffer of no more columns than the longest is one split).
+    ``write_rows`` is the COLUMNS written back for the append: the
+    128-lane tile that holds the new column (the whole split where it is
+    no multiple of 128). ``tail`` is the PIECE, in columns,
+    by which the split that holds a session's last valid column is
+    moved (None for a buffer of one split, which is streamed whole)."""
+    if block_k:
+        splits = (block_k,)
+    elif t_max <= _LATENT_BLOCK_K[0]:
+        splits = (t_max,)
+    else:
+        splits = _LATENT_BLOCK_K + (256, 128)
+    bk = next((c for c in splits if t_max % c == 0
+               and (c % 128 == 0 or c == t_max)
+               and _latent_vmem(c, d, dv, rows, dtype)
+               <= _LATENT_VMEM_BUDGET), None)
+    if bk is None:
+        return None
+    wr = 128 if bk % 128 == 0 else bk
+    tail = None
+    if t_max > bk:
+        tail = _LATENT_PIECE if bk % _LATENT_PIECE == 0 else 128
+    return DecodeGeometry(1, bk, wr,
+                          bk * d * jnp.dtype(dtype).itemsize, tail)
+
+
+def _latent_sweep(vt, ap, geom, t_max):
+    """Where a session's sweep ends: ``(whole, last, pieces)`` — ``last``
+    the K split that holds its last useful column (``vt``; or the append
+    column, should a caller append past it), ``whole`` the last split the
+    stream moves — the one before ``last``, whose filled part is moved in
+    ``pieces`` pieces of ``geom.tail`` columns; or ``last`` itself, with
+    no pieces, where that is split 0 (the stream moves one block a
+    session whatever happens) or the buffer is one split. ONE definition
+    for the stream's index map and the kernel body, which must agree."""
+    bk = geom.block_k
+    edge = jnp.maximum(vt, ap)
+    last = jnp.clip(edge // bk, 0, t_max // bk - 1)
+    if geom.tail is None:
+        return last, last, 0
+    tails = (last > 0).astype(jnp.int32)
+    return (last - tails, last,
+            tails * ((edge - last * bk) // geom.tail + 1))
+
+
+def _columns(tile, width):
+    """``width`` columns of the new row from its tile of identical
+    ones."""
+    if width == tile.shape[1]:
+        return tile
+    return jnp.broadcast_to(tile[:, :1], (tile.shape[0], width))
+
+
+def _make_latent_kernel(geom, ns, nb, g_pad, dv, stacked):
+    """The latent kernel's body. Grid = (sessions, ns), the K split
+    innermost; refs in ``_latent_decode``'s order: the prefetched
+    ``valid_to``, ``append_at`` (and, stacked, the layer's first flat
+    row); the session's queries ``(1, g_pad, d)``, its new row as a tile
+    of identical columns ``(1, 1, d, 128)``, the resident block of the
+    stream ``(1, d, bk)``; the results ``num``, ``m``, ``l`` and the
+    buffer itself in HBM (aliased: the pieces' copies read it, the
+    write-back writes it); the softmax state, the staging tile, the DMA
+    semaphores (pieces by parity, tile in, tile out) and, where the
+    buffer is more than one split, the last split's pieces, a buffer a
+    session's parity.
+
+    THE APPEND is a read-modify-write of the lane tile that holds the
+    new column, by the kernel's own copies from and to the aliased
+    result: read under the session's first step, the column selected in
+    and the tile sent back under its last, the copy back awaited under
+    the NEXT session's first step (the last session's at once) — so a
+    session that appends nothing writes nothing, and no step waits on a
+    copy it has just started."""
+    bk, wr, piece = geom.block_k, geom.write_rows, geom.tail
+    t_max = ns * bk
+    sub = _LATENT_SUB if bk % _LATENT_SUB == 0 else bk
+
+    def kernel(vt_ref, ap_ref, *refs):
+        first = 0
+        if stacked:
+            row0_ref, *refs = refs
+            first = row0_ref[0]
+        (q_ref, new_ref, k_ref, o_ref, m_ref, l_ref, hbm,
+         m_s, l_s, acc_s, stage, sems, *last_refs) = refs
+        b = pl.program_id(0)
+        ki = pl.program_id(1)
+        row = first + b                          # flat row of the buffer
+        vt = vt_ref[b]                           # last column attended
+        ap = ap_ref[b]                           # append column (−1 none)
+        appends = ap >= 0
+        whole, last, pieces = _latent_sweep(vt, ap, geom, t_max)
+
+        @pl.when(ki == 0)
+        def _():
+            m_s[...] = jnp.full_like(m_s, _NEG_BIG)
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+
+        def scores(blk):
+            return jnp.dot(q_ref[0], blk,
+                           preferred_element_type=jnp.float32)
+
+        def fold(col0, s, v, substitute):
+            """One sub-block's scores ``s (g_pad, size)`` and values ``v
+            (dv, size)`` of the columns from ``col0`` into the softmax
+            state; ``substitute``: the new row takes the place of
+            whatever the buffer holds at column ``ap``."""
+            cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            if substitute:
+                new = new_ref[0, 0]
+                s = jnp.where(cols == ap, scores(new)[:, :1], s)
+                v = jnp.where(cols[:1] == ap,
+                              _columns(new[:dv], s.shape[1]), v)
+            s = jnp.where(cols > vt, -jnp.inf, s)
+            m_prev = m_s[...]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp2(s - m_new)
+            corr = jnp.exp2(m_prev - m_new)
+            m_s[...] = m_new
+            l_s[...] = l_s[...] * corr + p.sum(axis=-1, keepdims=True)
+            acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        def score_split(substitute):
+            """The stream's resident split: every sub-block's score
+            product first, then their folds."""
+            at = [slice(i, i + sub) for i in range(0, bk, sub)]
+            ss = [scores(k_ref[0, :, a]) for a in at]
+            for a, s in zip(at, ss):
+                fold(ki * bk + a.start, s, k_ref[0, :dv, a], substitute)
+
+        # Scored where it holds a valid column and is not the pieces'
+        # split; the new column selected in where it lands there.
+        run = jnp.logical_and(ki * bk <= vt, ki <= whole)
+        landing = jnp.logical_and(
+            appends, jnp.logical_and(ki * bk <= ap, ap < ki * bk + bk))
+        pl.when(jnp.logical_and(run, landing))(lambda: score_split(True))
+        pl.when(jnp.logical_and(run, jnp.logical_not(landing)))(
+            lambda: score_split(False))
+
+        if piece:
+            # THE LAST SPLIT, in pieces: those that hold a valid column
+            # are moved — copies from the aliased result, started under
+            # the session BEFORE this one — and scored beside the last
+            # whole split. What was not moved is not scored: a VMEM
+            # column no copy landed in may hold anything, NaN too, and
+            # 0 · NaN is NaN.
+            last_ref, = last_refs   # (a session's parity, piece, d, piece)
+
+            def moves(r, split, i, slot):
+                at = pl.multiple_of(split * bk + i * piece, piece)
+                return pltpu.make_async_copy(
+                    hbm.at[pl.ds(r, 1), :, pl.ds(at, piece)],
+                    last_ref.at[slot, pl.ds(i, 1)], sems.at[slot])
+
+            def each(count, do):
+                jax.lax.fori_loop(0, count, lambda i, _: do(i), None)
+
+            def start(r, split, count, slot):
+                each(count, lambda i: moves(r, split, i, slot).start())
+
+            @pl.when(jnp.logical_and(b == 0, ki == 0))
+            def _():                 # nobody ran before the first one
+                start(row, last, pieces, 0)
+
+            # The NEXT session's pieces start under this session's last
+            # scored step, before its own pieces are scored: that step
+            # computes a split and the pieces, and the stream alone
+            # would leave the copy engine idle for the pieces' part.
+            ahead = jnp.minimum(b + 1, nb - 1)
+            _, last_ahead, pieces_ahead = _latent_sweep(
+                vt_ref[ahead], ap_ref[ahead], geom, t_max)
+            pl.when(jnp.logical_and(ki == whole, b + 1 < nb))(
+                lambda: start(row + 1, last_ahead, pieces_ahead,
+                              (b + 1) % 2))
+
+            @pl.when(jnp.logical_and(ki == whole, pieces > 0))
+            def _():
+                slot = b % 2
+                each(pieces, lambda i: moves(row, last, i, slot).wait())
+                # piece by piece, those whose first column is valid: no
+                # further than the copies reached
+                each(jnp.clip((vt - last * bk) // piece + 1, 0, pieces),
+                     lambda i: fold(last * bk + i * piece,
+                                    scores(last_ref[slot, i]),
+                                    last_ref[slot, i, :dv], True))
+
+        # The append: the lane tile that holds column ``ap``.
+        def tile_of(r, col):
+            at = jnp.clip(col // wr, 0, t_max // wr - 1) * wr
+            return hbm.at[pl.ds(r, 1), :, pl.ds(pl.multiple_of(at, wr), wr)]
+
+        def sent(r, col):
+            return pltpu.make_async_copy(stage, tile_of(r, col), sems.at[3])
+
+        before = ap_ref[jnp.maximum(b - 1, 0)]
+
+        @pl.when(jnp.logical_and(ki == 0,
+                                 jnp.logical_and(b > 0, before >= 0)))
+        def _():
+            sent(row - 1, before).wait()
+
+        fetched = pltpu.make_async_copy(tile_of(row, ap), stage, sems.at[2])
+        pl.when(jnp.logical_and(ki == 0, appends))(fetched.start)
+
+        @pl.when(jnp.logical_and(ki == ns - 1, appends))
+        def _():
+            fetched.wait()
+            lanes = (ap // wr) * wr + jax.lax.broadcasted_iota(
+                jnp.int32, (1, wr), 1)
+            stage[0] = jnp.where(lanes == ap, _columns(new_ref[0, 0], wr),
+                                 stage[0])
+            sent(row, ap).start()
+            pl.when(b == nb - 1)(sent(row, ap).wait)
+
+        @pl.when(ki == ns - 1)
+        def _():
+            o_ref[0] = acc_s[...]
+            m_ref[0] = m_s[...]
+            l_ref[0] = l_s[...]
+
+    return kernel
+
+
+def _latent_decode(q, new, rows, valid_to, append_at, dv, *, layer=None,
+                   scale=None, interpret=None, block_k=None):
+    """:func:`flash_decode`'s latent mode: ``q (B, H, 1, d)``, ``new (B,
+    1, d, 128)``, ``rows ([L,] B, 1, d, t_max)``; returns ``(out (B, H,
+    1, dv), rows)``."""
+    b, h, n, d = q.shape
+    stacked = layer is not None
+    t_max = rows.shape[-1]
+    if (n != 1 or rows.ndim != 4 + stacked or rows.shape[-3:-1] != (1, d)
+            or new.shape != (b, 1, d, 128) or not 0 < dv <= d):
+        raise ValueError(
+            f'flash_decode: latent_v takes one query row a head, q '
+            f'(B, H, 1, d), the new row as k_new (B, 1, d, 128) and the '
+            f'time-minor buffer cache_k ([L,] B, 1, d >= latent_v, t_max) '
+            f'(with layer= where it has the L); got q {q.shape}, k_new '
+            f'{new.shape}, cache_k {rows.shape}, latent_v {dv}')
+    geom = latent_geometry(t_max, d, dv, h, rows.dtype, block_k=block_k)
+    if geom is None:
+        raise ValueError(
+            f'no usable K split for the latent buffer\'s t_max={t_max} '
+            f'(whole 128-column tiles that divide it); use the XLA '
+            f'decode path for this cache shape')
+    bk, wr = geom.block_k, geom.write_rows
+    ns = t_max // bk
+    if interpret is None:
+        interpret = jax.default_backend() != 'tpu'
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
+    sub = _sublane(rows.dtype)
+    g_pad = -(-h // sub) * sub
+    qf = _pad_rows((q[:, :, 0].astype(jnp.float32) * (scale * _LOG2E)
+                    ).astype(rows.dtype), sub)
+    kf = rows.reshape(-1, d, t_max)
+    prefetch = (jnp.asarray(valid_to, jnp.int32),
+                jnp.asarray(append_at, jnp.int32))
+    if stacked:
+        prefetch += ((jnp.asarray(layer, jnp.int32) * b).reshape(1),)
+
+    def const_idx(bi, ki, *rs):
+        return (bi, 0, 0)
+
+    def stream_idx(bi, ki, vt, ap, *lay):
+        # Never past a session's last whole split; the steps behind it
+        # carry the NEXT session's first split (the slab's ``_slab_blk``).
+        blk = jnp.minimum(ki, _latent_sweep(vt[bi], ap[bi], geom,
+                                            t_max)[0])
+        ahead = jnp.logical_and(ki > blk, bi + 1 < b)
+        row = jnp.where(ahead, bi + 1, bi)
+        return (row + lay[0][0] if lay else row, 0,
+                jnp.where(ahead, 0, blk))
+
+    scratch = [pltpu.VMEM((g_pad, 1), jnp.float32),
+               pltpu.VMEM((g_pad, 1), jnp.float32),
+               pltpu.VMEM((g_pad, dv), jnp.float32),
+               pltpu.VMEM((1, d, wr), rows.dtype),
+               pltpu.SemaphoreType.DMA((4,))]
+    if geom.tail:
+        scratch.append(pltpu.VMEM((2, bk // geom.tail, d, geom.tail),
+                                  rows.dtype))
+    with device_scope('ops.mla_decode'):
+        num, _, l, new_rows = pl.pallas_call(
+            _make_latent_kernel(geom, ns, b, g_pad, dv, stacked),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch),
+                grid=(b, ns),
+                in_specs=[pl.BlockSpec((1, g_pad, d), const_idx),
+                          pl.BlockSpec((1, 1, d, 128),
+                                       lambda bi, ki, *rs: (bi, 0, 0, 0)),
+                          pl.BlockSpec((1, d, bk), stream_idx)],
+                out_specs=[pl.BlockSpec((1, g_pad, dv), const_idx),
+                           pl.BlockSpec((1, g_pad, 1), const_idx),
+                           pl.BlockSpec((1, g_pad, 1), const_idx),
+                           pl.BlockSpec(memory_space=pl.ANY)],
+                scratch_shapes=scratch),
+            out_shape=[jax.ShapeDtypeStruct((b, g_pad, dv), jnp.float32),
+                       jax.ShapeDtypeStruct((b, g_pad, 1), jnp.float32),
+                       jax.ShapeDtypeStruct((b, g_pad, 1), jnp.float32),
+                       jax.ShapeDtypeStruct(kf.shape, kf.dtype)],
+            input_output_aliases={len(prefetch) + 2: 3},
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_LATENT_VMEM_LIMIT if _latent_vmem(
+                    bk, d, dv, h, rows.dtype) > _VMEM_BUDGET else None),
+            interpret=interpret,
+            name='mla_decode')(*prefetch, qf, new, kf)
+    out = (num / jnp.where(l == 0.0, 1.0, l))[:, :h, None].astype(rows.dtype)
+    return out, new_rows.reshape(rows.shape)

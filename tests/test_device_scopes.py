@@ -928,9 +928,11 @@ def test_ring_decode_build_carries_its_kernel_name():
 
 
 def test_latent_decode_build_carries_its_kernel_name():
-    cache = jnp.zeros((1, 1, 128, 128), jnp.float32)
-    q = jnp.zeros((1, 2, 1, 128), jnp.float32)
-    new = jnp.zeros((1, 1, 1, 128), jnp.float32)
+    # the latent buffer is time-minor: (B, 1, d, t_max), the new row a
+    # lane tile of identical columns
+    cache = jnp.zeros((1, 1, 72, 128), jnp.float32)
+    q = jnp.zeros((1, 2, 1, 72), jnp.float32)
+    new = jnp.zeros((1, 1, 72, 128), jnp.float32)
     at = jnp.zeros((1,), jnp.int32)
 
     def step(q, k, ck):

@@ -309,10 +309,15 @@ def test_four_shares_of_a_latent_layer_add_up_to_the_uncut_layer(
 PARENT_LOWERED = {
     'experts':
         '3ee5a11d5306ab89107c9c4389ffa206c0cc05e51e0b049f90807a8f6335e10e',
+    # (the two xing4 entries: as they lower since PR 47 — the latent
+    # cache is time-minor, 576 values a token and not 640, so prefill
+    # writes a chunk transposed, the expansion reads the latent with the
+    # rank axis major and the XLA step scatters a column: by necessity
+    # another text; before it '7ceed997…' / 'f8d9c828…')
     'xing4.prefill':
-        '7ceed99790438b2773ebb293ced0f8fb69f9dc7065723562c6857b1edefbe841',
+        '077d39206758d21cfa6bbace2a432ff246fd98498b7823aeb692d08cf40d86fb',
     'xing4.decode':
-        'f8d9c82820af2fa2867304ecfb05746326f33faf5315bc2583c061c00ffb6188',
+        'a68c9dc891550dcb2ede3d8905d35095532f8cb1fd59a392f8186197af595a15',
     'command-a.prefill':
         'fbdb22fb6692f9668153737612b7941d38b78890936b992fea05e6f31ccf4db9',
     'command-a.decode':
@@ -339,7 +344,7 @@ PARENT_LOWERED.update({
 # and all of ``LAYER_MATMUL_NAMES`` where no device limit is reported;
 # before it '78a3b10d…' / '5b25661c…'). The serving programs above run
 # the same named MLP and attention output and are still the parents'
-# text (``RENUMBERED`` below).
+# text.
 PARENT_LOWERED.update({
     'mpt.train':
         '46ba319f6662428fd948107bb9e6f30e991f832e233aaa0819cc71790e9af6c1',
@@ -396,19 +401,10 @@ PRESETS = {'granite': ('tiny_granite', 'tiny-granite.decode'),
 
 # Since PR 37 a gated MLP names its pre-activation for a checkpoint. A
 # ``name`` equation lowers to nothing, so every decode step above is
-# still its parent's text — in xing4's, whose dense layer and shared
-# experts are gated MLPs, but for the NUMBER jax's lowering gives one
-# private function (its symbols are numbered as they are asked for; its
-# prefill, re-pinned at PR 40, is pinned as it lowers).
-RENUMBERED = {'xing4.decode': ('@silu_293', '@silu_292')}
-
-
-def _sha(lowered, renumbered=None):
-    text = lowered.as_text()
-    if renumbered:
-        assert renumbered[0] in text and renumbered[1] not in text
-        text = text.replace(*renumbered)
-    return hashlib.sha256(text.encode()).hexdigest()
+# still its parent's text (xing4's two programs, re-pinned at PR 40 and
+# PR 47, are pinned as they lower).
+def _sha(lowered):
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
 
 
 @pytest.mark.parametrize('what', sorted(PARENT_LOWERED))
@@ -451,8 +447,7 @@ def test_accepted_programs_lower_to_the_parents_text(what):
         jax.random.key(0), jnp.zeros((2, 8), jnp.int32)))
     caches = jax.eval_shape(lambda: model.make_decode_caches(2, 32))
     fn = jax.jit(lambda p, t, c: model.apply(p, t, c, method=method))
-    assert _sha(fn.lower(params, tok, caches),
-                RENUMBERED.get(what)) == PARENT_LOWERED[what]
+    assert _sha(fn.lower(params, tok, caches)) == PARENT_LOWERED[what]
 
 
 def test_the_dense_route_of_a_gated_layer_is_the_sorted_one():

@@ -313,28 +313,39 @@ def test_accepted_configurations_keep_their_parameter_trees(name):
     assert flat == {k: v[0] for k, v in weights.shapes(cfg).items()}
 
 
+def _latent_operands(held, new):
+    """The latent kernel's operands from row-major ones: the buffer
+    ``([L,] B, t, d)`` time-minor with its unit head axis, the new rows
+    ``(B, d)`` as lane tiles of identical columns."""
+    rows = jnp.swapaxes(held, -1, -2)[..., None, :, :]
+    tile = jnp.broadcast_to(new[:, None, :, None],
+                            (new.shape[0], 1, new.shape[1], 128))
+    return rows, tile
+
+
 def test_latent_kernel_mode_matches_xla_formulation():
     """``flash_decode(latent_v=)`` (interpret mode) against a plain
-    softmax over the one buffer: unequal fills, an idle slot, the
-    append in place, other layers untouched."""
+    softmax over the one time-minor buffer: unequal fills, an idle
+    slot, the append in place, other layers untouched."""
     from distributed_dot_product_tpu.ops.pallas_decode import flash_decode
     rng = np.random.default_rng(7)
-    layers, b, h, t_max, d, dv = 3, 3, 4, 64, 128, 96
-    cache = jnp.asarray(rng.normal(size=(layers, b, 1, t_max, d)),
-                        jnp.float32)
+    layers, b, h, t_max, d, dv = 3, 3, 4, 512, 72, 48
+    held = jnp.asarray(rng.normal(size=(layers, b, t_max, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(b, h, 1, d)), jnp.float32)
-    new = jnp.asarray(rng.normal(size=(b, 1, 1, d)), jnp.float32)
-    length = jnp.asarray([5, 40, 17], jnp.int32)
-    append = jnp.asarray([5, 40, -1], jnp.int32)
+    new = jnp.asarray(rng.normal(size=(b, d)), jnp.float32)
+    length = jnp.asarray([5, 300, 140], jnp.int32)
+    append = jnp.asarray([5, 300, -1], jnp.int32)
+    cache, tile = _latent_operands(held, new)
     out, rows, v, *_ = flash_decode(
-        q, new, None, cache, None, length, append, layer=1, latent_v=dv,
-        scale=0.2, block_k=32, interpret=True)
+        q, tile, None, cache, None, length, append, layer=1, latent_v=dv,
+        scale=0.2, block_k=128, interpret=True)
     assert v is None
-    want_rows = np.array(cache)
+    want_rows = np.array(held)
     for i in range(2):
-        want_rows[1, i, 0, int(length[i])] = np.asarray(new[i, 0, 0])
-    assert np.array_equal(np.asarray(rows), want_rows)
-    held = want_rows[1, :, 0]
+        want_rows[1, i, int(length[i])] = np.asarray(new[i])
+    assert np.array_equal(np.asarray(rows[:, :, 0]),
+                          np.swapaxes(want_rows, -1, -2))
+    held = want_rows[1]
     s = np.einsum('bhd,btd->bht', np.asarray(q[:, :, 0]), held) * 0.2
     s = np.where(np.arange(t_max) <= np.asarray(length)[:, None, None],
                  s, -np.inf)

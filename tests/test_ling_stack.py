@@ -83,8 +83,10 @@ def test_the_absorbed_form_is_the_expanded_form(cache, impl):
     want = layer.apply(params, x)
     layers, at = (None, None) if cache == 'one-layer' else (3, 1)
     held = init_latent_cache(layers, 2, 128, 20, jnp.float32)
-    assert held.rows.shape == ((2, 128, 128) if layers is None
-                               else (3, 2, 128, 128))
+    # time-minor, nothing padded: 16 + 4 values a token, a column each
+    assert held.rows.shape == ((2, 20, 128) if layers is None
+                               else (3, 2, 20, 128))
+    assert held.t_max == 128
     with decode_impl_traces() as traces:
         held, out = layer.apply(params, x[:, :7], held, at,
                                 method='prefill')
@@ -103,6 +105,103 @@ def test_the_absorbed_form_is_the_expanded_form(cache, impl):
                                       [[0, 0], [12, 12], [0, 0]])
         assert not np.any(np.asarray(held.rows[0]))
         assert not np.any(np.asarray(held.rows[2]))
+
+
+# Columns a session holds before the step (its token lands there) on a
+# buffer of two 1536-column splits (what the kernel's own rule takes of
+# 3072 columns): inside the first split; the last column of a split; 1 /
+# 128 / 129 / 256 / 257 / 512 / 513 columns into the last split once the
+# token is in (the first piece's first column, a lane tile's last column
+# and the next one's first — the append on a tile's edge —, a piece's
+# last column and the next one's first, the same for a sub-block of 512);
+# the buffer's last column.
+_ROUTES = {'first-split': 300, 'split-end': 1535, 'into-1': 1536,
+           'into-128': 1536 + 127, 'into-129': 1536 + 128,
+           'into-256': 1536 + 255, 'into-257': 1536 + 256,
+           'into-512': 1536 + 511, 'into-513': 1536 + 512,
+           'buffer-end': 3071}
+
+
+@pytest.mark.parametrize('route', sorted(_ROUTES))
+@pytest.mark.parametrize('cache', ['one-layer', 'stacked'])
+def test_the_latent_kernel_is_the_xla_form_on_every_route(cache, route):
+    """One token step of the layer through the kernel (interpret mode)
+    against the XLA form of ``LatentAttention.decode`` on the SAME
+    time-minor buffer — random columns everywhere, so every length is a
+    rewound one — with the session under test at a length that takes
+    each route of the kernel, a session inside its first split, and a
+    session whose last split is one piece: outputs to tolerance, the
+    buffers and lengths bit for bit (the other layers' and every other
+    column untouched). Then a
+    slot that appends nothing: the kernel itself at the same operands,
+    the buffer returned as it came."""
+    from distributed_dot_product_tpu.ops.pallas_decode import flash_decode
+    layers, at = (None, None) if cache == 'one-layer' else (3, 1)
+    lead = () if layers is None else (layers,)
+    lens = jnp.asarray([_ROUTES[route], 300, 1536 + 76], jnp.int32)
+    rng = np.random.default_rng(3)
+    held = LatentCache(
+        rows=jnp.asarray(rng.normal(size=(*lead, 3, 20, 3072)),
+                         jnp.float32),
+        length=jnp.broadcast_to(lens, (*lead, 3)))
+    x = jnp.asarray(rng.normal(size=(3, 1, 32)), jnp.float32)
+    got = {}
+    for impl in ('xla', 'kernel'):
+        layer, _, params = _latent(decode_impl=impl)
+        got[impl] = layer.apply(params, x, held, at, method='decode')
+    np.testing.assert_allclose(got['kernel'][1], got['xla'][1], atol=TOL)
+    np.testing.assert_array_equal(got['kernel'][0].rows,
+                                  got['xla'][0].rows)
+    np.testing.assert_array_equal(got['kernel'][0].length,
+                                  got['xla'][0].length)
+    assert np.sum(np.asarray(got['kernel'][0].rows != held.rows)) == 3 * 20
+    # nothing appended (append_at −1): nothing written
+    rows5 = held.rows[..., None, :, :]
+    tile = jnp.ones((3, 1, 20, 128), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(3, 4, 1, 20)), jnp.float32)
+    _, same, *_ = flash_decode(
+        q, tile, None, rows5, None, lens - 1, jnp.full((3,), -1),
+        layer=at, latent_v=16, interpret=True)
+    np.testing.assert_array_equal(same, rows5)
+
+
+@pytest.mark.parametrize('impl', ['xla', 'kernel'])
+@pytest.mark.parametrize('cache', ['one-layer', 'stacked'])
+def test_a_session_prefilled_alone_in_odd_chunks_decodes_in_its_slot(
+        cache, impl):
+    """A session prefilled ALONE into a one-session cache in chunks of
+    5, 1, 130 and 7 tokens — starts 0, 5, 6, 136: no lane-tile multiple
+    behind the first, one chunk across a tile's edge — then put in slot
+    1 of a batch of three (``insert_session``) whose other slots hold
+    another session, and decoded for four steps: the whole-sequence
+    expanded form's outputs, token for token, for both."""
+    layer, _, params = _latent(decode_impl=impl)
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(2, 147, 32)), jnp.float32)
+    want = layer.apply(params, x)
+    layers, at = (None, None) if cache == 'one-layer' else (3, 1)
+
+    def alone(i):
+        one = init_latent_cache(layers, 1, 256, 20, jnp.float32)
+        start = 0
+        for n in (5, 1, 130, 7):
+            one, out = layer.apply(params, x[i:i + 1, start:start + n],
+                                   one, at, method='prefill')
+            np.testing.assert_allclose(
+                out, want[i:i + 1, start:start + n], atol=TOL)
+            start += n
+        return one
+    batch = init_latent_cache(layers, 3, 256, 20, jnp.float32)
+    put = jax.jit(insert_session)
+    for slot, i in enumerate((0, 1, 0)):
+        batch = put(batch, slot, alone(i))
+    for t in range(143, 147):
+        step = x[jnp.asarray([0, 1, 0]), t:t + 1]
+        batch, out = layer.apply(params, step, batch, at, method='decode')
+        np.testing.assert_allclose(out[:, 0], want[jnp.asarray([0, 1, 0]), t],
+                                   atol=TOL)
+    np.testing.assert_array_equal(
+        batch.length[at] if layers else batch.length, [147, 147, 147])
 
 
 @pytest.mark.parametrize('dropped', ['gate', 'gate-before-values'])
@@ -384,7 +483,7 @@ def test_insert_session_puts_a_latent_session_in_its_slot():
         batch = batch._replace(
             rows=batch.rows + 1.0,
             length=jnp.full((*lead, 3), 5, jnp.int32))
-        one = LatentCache(rows=jnp.full((*lead, 1, 16, 128), 7.0),
+        one = LatentCache(rows=jnp.full((*lead, 1, 20, 16), 7.0),
                           length=jnp.full((*lead, 1), 9, jnp.int32))
         out = jax.jit(insert_session)(batch, 1, one)
         np.testing.assert_array_equal(out.rows[..., 1, :, :], one.rows[
@@ -564,7 +663,7 @@ def test_the_all_latent_stack_keeps_its_one_stacked_cache():
                        prefix_kwargs={'ffn_kwargs': {'hidden': 24}})
     cache = lm.make_decode_caches(2, 128)
     assert isinstance(cache, LatentCache)
-    assert cache.rows.shape == (3, 2, 128, 128)
+    assert cache.rows.shape == (3, 2, 20, 128)
     mixed = TransformerLM(
         vocab_size=16, dim=32, num_heads=4, n_layers=2, scan_layers=False,
         block_kwargs={'mixer': 'delta', 'ffn': 'gated',
@@ -576,4 +675,4 @@ def test_the_all_latent_stack_keeps_its_one_stacked_cache():
     caches = mixed.make_decode_caches(2, 128)
     assert [type(c).__name__ for c in caches] == ['StateCache',
                                                   'LatentCache']
-    assert caches[1].rows.shape == (2, 128, 128)
+    assert caches[1].rows.shape == (2, 20, 128)

@@ -479,8 +479,11 @@ def test_kernel_matches_xla_at_every_geometry(monkeypatch, h, h_kv, hb,
 _TT, _TBK, _TSUB = 3072, 1024, 256      # three real splits, the tail's rows
 # Rows a slot holds before the step (its new row lands at that column):
 # around a split's start, around the tail's last row, the buffer's end.
+# (and, for the latent buffer's lane tiles, the append in a tile's last
+# column and in the next one's first: 128 and 129 columns into the split)
 _TAIL_FILLS = {'k.bk-1': 2 * _TBK - 1, 'k.bk': 2 * _TBK,
-               'k.bk+1': 2 * _TBK + 1, '+sub-1': 2 * _TBK + _TSUB - 1,
+               'k.bk+1': 2 * _TBK + 1, '+lane-1': 2 * _TBK + 127,
+               '+lane': 2 * _TBK + 128, '+sub-1': 2 * _TBK + _TSUB - 1,
                '+sub': 2 * _TBK + _TSUB, '+sub+1': 2 * _TBK + _TSUB + 1,
                't_max-1': _TT - 1}
 
@@ -488,9 +491,11 @@ _TAIL_FILLS = {'k.bk-1': 2 * _TBK - 1, 'k.bk': 2 * _TBK,
 def _tail_case(kind, fill, key=50, d=128):
     """Three slots: the fill under test; a slot inside its first split
     (never a tail); a FROZEN slot 76 rows into its second split (a tail
-    that appends nothing). ``latent``: one buffer of one shared head."""
+    that appends nothing). ``latent`` / ``latent-one``: one buffer of
+    one shared head, layer-stacked / one layer's; every column past a
+    length holds what a longer session left there (a rewound length)."""
     ks = jax.random.split(jax.random.key(key), 5)
-    h_kv, h = (1, 4) if kind == 'latent' else (2, 4)
+    h_kv, h = (1, 4) if kind.startswith('latent') else (2, 4)
     lens = jnp.asarray([fill, 300, _TBK + 76], jnp.int32)
     layers = 2 if kind in ('stack', 'latent') else 1
     q = jax.random.normal(ks[0], (3, h, 1, d), jnp.float32)
@@ -504,21 +509,35 @@ def _tail_case(kind, fill, key=50, d=128):
 def _tail_geometry(kind, d=128):
     from distributed_dot_product_tpu.ops import pallas_decode as pd
     f32 = jnp.float32
-    if kind == 'latent':
-        return pd.decode_geometry(_TT, 1, d, d // 2, 4, f32, None)
+    if kind.startswith('latent'):
+        # (its own rule would take one split of 1536 here: the tests hold
+        # it to the slab's three splits, so the same fills are its edges —
+        # a split's start, a piece's last column, the buffer's end)
+        return pd.latent_geometry(_TT, d, d // 2, 4, f32, block_k=_TBK)
     return pd.decode_geometry(_TT, 2, d, d, 2, f32, f32)
 
 
+def _time_minor(held, new):
+    """The latent kernel's operands from row-major ones: the buffer
+    ``(…, 1, t, d)`` time-minor, the new rows ``(B, 1, 1, d)`` as lane
+    tiles of 128 identical columns."""
+    b, d = new.shape[0], new.shape[-1]
+    return (jnp.swapaxes(held, -1, -2),
+            jnp.broadcast_to(new.reshape(b, 1, d, 1), (b, 1, d, 128)))
+
+
 @pytest.mark.parametrize('fill', sorted(_TAIL_FILLS))
-@pytest.mark.parametrize('kind', ['slab', 'stack', 'latent'])
+@pytest.mark.parametrize('kind', ['slab', 'stack', 'latent', 'latent-one'])
 def test_kernel_tail_matches_xla_at_the_edges(kind, fill):
     """The kernel on caches of three real 1024-row splits against the
-    XLA formulation (the latent buffer: a plain softmax over its rows),
-    the slot under test one row either side of every edge of the rule:
-    the split's start, the tail's last row, the buffer's end. Outputs
-    to tolerance; the aliased caches bit for bit — the appended row in
-    place, every other row and layer untouched, the frozen slot's too
-    (with a tail nothing is copied through for it)."""
+    XLA formulation (the latent buffer, time-minor, layer 1 of a stack
+    or one layer's: a plain softmax over its columns), the slot under
+    test one row either side of every edge of the rule: the split's
+    start, a lane tile's last column and the next one's first, the
+    tail's last row, the buffer's end. Outputs to tolerance; the aliased
+    caches bit for bit — the appended row in place, every other row and
+    layer untouched, the frozen slot's too (with a tail nothing is
+    copied through for it)."""
     from distributed_dot_product_tpu.models.decode import DecodeCache
     from distributed_dot_product_tpu.ops import pallas_decode as pd
     geom = _tail_geometry(kind)
@@ -527,17 +546,23 @@ def test_kernel_tail_matches_xla_at_the_edges(kind, fill):
     mask = jnp.asarray([True, True, False])
     vt = jnp.where(mask, lens, lens - 1)
     ap = jnp.where(mask, lens, -1)
-    if kind == 'latent':
+    if kind.startswith('latent'):
         dv = q.shape[-1] // 2
+        stacked = kind == 'latent'
+        cache, tile = _time_minor(k if stacked else k[0], kn)
         out, rows, none, *_ = pd.flash_decode(
-            q, kn, None, k, None, vt, ap, layer=jnp.int32(1), latent_v=dv,
-            scale=0.3)
+            q, tile, None, cache, None, vt, ap,
+            layer=jnp.int32(1) if stacked else None, latent_v=dv,
+            scale=0.3, block_k=_TBK)
         assert none is None
         want_rows = np.array(k)
+        at = 1 if stacked else 0
         for i in range(2):
-            want_rows[1, i, 0, int(lens[i])] = np.asarray(kn[i, 0, 0])
-        assert np.array_equal(np.asarray(rows), want_rows)
-        held = want_rows[1, :, 0]
+            want_rows[at, i, 0, int(lens[i])] = np.asarray(kn[i, 0, 0])
+        assert np.array_equal(
+            np.asarray(rows), np.swapaxes(
+                want_rows if stacked else want_rows[0], -1, -2))
+        held = want_rows[at, :, 0]
         s = np.einsum('bhd,btd->bht', np.asarray(q[:, :, 0]), held) * 0.3
         s = np.where(np.arange(_TT) <= np.asarray(vt)[:, None, None],
                      s, -np.inf)
@@ -563,7 +588,8 @@ def test_kernel_tail_matches_xla_at_the_edges(kind, fill):
 
 
 @pytest.mark.parametrize('interpreter', ['plain', 'tpu-nan'])
-def test_rows_the_tail_did_not_move_reach_no_product(interpreter):
+@pytest.mark.parametrize('kind', ['slab', 'latent-one'])
+def test_rows_the_tail_did_not_move_reach_no_product(kind, interpreter):
     """Every cache row behind what the kernel moves is NaN — behind the
     tail's 256 rows of the last split for the slots that take the tail,
     behind the last split for the one that does not — and the result is
@@ -571,11 +597,15 @@ def test_rows_the_tail_did_not_move_reach_no_product(interpreter):
     enough (0 · NaN is NaN), the rows must stay out of both products.
     (The parent moved the whole last split and fails this.) Once more
     under the TPU interpreter with uninitialised VMEM as NaN: what the
-    tail's buffer holds where no copy landed is not read either."""
+    tail's buffer holds where no copy landed is not read either. The
+    latent kernel the same, in columns of its time-minor buffer: of the
+    split that holds a session's last column it moves the 256-column
+    pieces that hold a valid one (the lane tile it reads and writes back
+    for the append lies inside them)."""
     from jax.experimental.pallas import tpu as pltpu
     from distributed_dot_product_tpu.ops import pallas_decode as pd
-    assert _tail_geometry('slab').tail == _TSUB
-    q, kn, vn, k, v, lens = _tail_case('slab', 2 * _TBK + 40)
+    assert _tail_geometry(kind).tail == _TSUB
+    q, kn, vn, k, v, lens = _tail_case(kind, 2 * _TBK + 40)
     k, v = k[0], v[0]
     # Rows moved: slot 0 two splits + the tail; slot 1 its one split
     # whole; slot 2 one split + the tail.
@@ -584,6 +614,21 @@ def test_rows_the_tail_did_not_move_reach_no_product(interpreter):
                                                            None]
     interp = (pltpu.InterpretParams(uninitialized_memory='nan')
               if interpreter == 'tpu-nan' else None)
+    if kind == 'latent-one':
+        dv = q.shape[-1] // 2
+        clean, tile = _time_minor(k, kn)
+        dirty, _ = _time_minor(jnp.where(behind, jnp.nan, k), kn)
+        want, *_ = pd.flash_decode(q, tile, None, clean, None, lens, lens,
+                                   latent_v=dv, block_k=_TBK)
+        got, rows, *_ = pd.flash_decode(q, tile, None, dirty, None, lens,
+                                        lens, latent_v=dv, block_k=_TBK,
+                                        interpret=interp)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+        assert np.isnan(np.asarray(rows)).sum() == np.isnan(
+            np.asarray(dirty)).sum()
+        return
     want, *_ = pd.flash_decode(q, kn, vn, k, v, lens, lens)
     got, nk, nv, _, _ = pd.flash_decode(
         q, kn, vn, jnp.where(behind, jnp.nan, k),
@@ -608,10 +653,29 @@ def test_decode_geometry_of_the_cells_and_its_budget():
     assert cell.heads >= 4 and cell.block_k == 1024
     assert cell.write_rows == 16
     assert cell.bytes == cell.heads * 1024 * 128 * 2 * 2
-    # xing4-29b-a4b.decode-32k: one latent row 640 wide, 32 query rows.
-    latent = pd.decode_geometry(33792, 1, 640, 512, 32, bf16, None)
-    assert (latent.heads, latent.block_k) == (1, 1024)
-    assert latent.bytes == 1024 * 640 * 2
+    # xing4-29b-a4b.decode-32k and ling-3.0-flash.decode-32k: the latent
+    # buffer's own rule — a token a COLUMN of 576 values, nothing padded
+    # (until PR 47 a row of 640: 1 310 720 B a step), 32 query rows; the
+    # append's write-back the 128-lane tile that holds the column.
+    # The split is LONG: the longest of 2048 / 1536 / 1024 / 512 that
+    # divides t_max (33792 = 22 x 1536) within the VMEM plan; the split
+    # that holds a session's last column moves in pieces of 256.
+    latent = pd.latent_geometry(33792, 576, 512, 32, bf16)
+    assert latent == (1, 1536, 128, 1536 * 576 * 2, 256)
+    assert latent.bytes == 1769472 == 1536 * 1152
+    assert pd.latent_geometry(32768, 576, 512, 32, bf16).block_k == 2048
+    assert pd.latent_geometry(32768, 576, 512, 128, bf16).block_k == 2048
+    # float32 rows of 128 heads: the plan has room for 1536 columns
+    assert pd.latent_geometry(32768 * 3, 576, 512, 128,
+                              jnp.float32).block_k == 1536
+    assert pd.latent_geometry(66560, 576, 512, 32, bf16).block_k == 1024
+    # a split of whole lane tiles, or the one split the buffer is
+    assert pd.latent_geometry(33792, 576, 512, 32, bf16, block_k=64) is None
+    assert pd.latent_geometry(192, 576, 512, 32, bf16) == (
+        1, 192, 192, 192 * 576 * 2, None)
+    assert pd.latent_geometry(2048, 576, 512, 32, bf16) == (
+        1, 2048, 128, 2048 * 576 * 2, None)
+    assert pd.latent_geometry(1027 * 4, 576, 512, 32, bf16) is None
     for h_kv in (1, 2, 3, 8, 12, 32):
         for d in (64, 96, 128, 256):
             for rows in (1, 12):
@@ -633,7 +697,7 @@ def test_decode_geometry_of_the_cells_and_its_budget():
     # The tail: 256 rows for both cells' calls; 128 where the plan has
     # room for no more; none for a cache of one split, a head dim that
     # is not whole lane tiles, the ring, and the three modes above.
-    assert cell.tail == latent.tail == 256
+    assert cell.tail == 256
     assert pd.decode_geometry(16384, 8, 256, 256, 1, bf16, bf16).tail == 128
     assert pd.decode_geometry(1024, 8, 128, 128, 1, bf16, bf16).tail is None
     assert pd.decode_geometry(32768, 8, 96, 96, 1, bf16, bf16).tail is None

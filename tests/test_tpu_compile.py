@@ -250,7 +250,7 @@ def _compile_cell_kernel(chip, cell):
     """``flash_decode`` as a decode cell calls it, on its layer-stacked
     cache at the benchmark's widths."""
     from distributed_dot_product_tpu.ops.pallas_decode import (
-        decode_geometry, flash_decode,
+        decode_geometry, flash_decode, latent_geometry,
     )
     bf16 = jnp.bfloat16
     if cell == 'mpt-7b.decode-12k':
@@ -283,12 +283,15 @@ def _compile_cell_kernel(chip, cell):
 
         args, donate = (q, row, row, buf, buf), (3, 4)
     else:
-        layers, b, h, t_max, d, dv = 6, 16, 32, 33792, 640, 512
-        assert decode_geometry(t_max, 1, d, dv, h, bf16, None) == (
-            1, 1024, 16, 1024 * 640 * 2, 256)
+        # The latent buffer is time-minor, 576 values a token and nothing
+        # padded (until PR 47 rows of 640): splits of 1536 columns, the
+        # append's lane tile, the last split in pieces of 256.
+        layers, b, h, t_max, d, dv = 6, 16, 32, 33792, 576, 512
+        assert latent_geometry(t_max, d, dv, h, bf16) == (
+            1, 1536, 128, 1536 * 576 * 2, 256)
         q = jax.ShapeDtypeStruct((b, h, 1, d), bf16)
-        row = jax.ShapeDtypeStruct((b, 1, 1, d), bf16)
-        buf = jax.ShapeDtypeStruct((layers, b, 1, t_max, d), bf16)
+        row = jax.ShapeDtypeStruct((b, 1, d, 128), bf16)
+        buf = jax.ShapeDtypeStruct((layers, b, 1, d, t_max), bf16)
 
         def step(q, k_new, rows, at, layer):
             return flash_decode(q, k_new, None, rows, None, at, at,
@@ -368,6 +371,57 @@ def test_decode_kernel_compiles_at_the_plans_edges(chip, edge):
             interpret=False)
 
     _compile(chip, step, ops)
+
+
+# The edges of ``latent_geometry``'s plan: (heads, t_max, stacked) -> the
+# split it takes. The cells' 32 query rows on their 33792 columns; a
+# power-of-two buffer (the longest split, past the compiler's default
+# scoped limit: the call asks for its own); 128 query rows (DeepSeek-V3's
+# heads: four times the scores and probabilities), and in float32 (twice
+# the bytes a column: the plan steps down to 1536); a buffer of one split
+# (no pieces); one whose only divisor is 512.
+_LATENT_EDGES = {
+    'cells-32-rows': ((32, 33792, True, jnp.bfloat16), 1536),
+    'power-of-two': ((32, 32768, False, jnp.bfloat16), 2048),
+    '128-rows': ((128, 32768, True, jnp.bfloat16), 2048),
+    'float32-128-rows': ((128, 32768 * 3, False, jnp.float32), 1536),
+    'one-split': ((32, 2048, False, jnp.bfloat16), 2048),
+    'split-512': ((32, 512 * 7, False, jnp.bfloat16), 512),
+}
+
+
+@pytest.mark.parametrize('edge', sorted(_LATENT_EDGES))
+def test_latent_kernel_compiles_at_its_plans_edges(chip, edge):
+    """Mosaic's verdict on the latent kernel where ``latent_geometry``
+    packs the most into a step (the stream and the last split's pieces
+    double-buffered, 256-column pieces, the 576-deep score product over
+    sublanes that are no whole lane tile), the buffer aliased whole and
+    nothing copied."""
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        flash_decode, latent_geometry,
+    )
+    (h, t_max, stacked, dtype), bk = _LATENT_EDGES[edge]
+    b, d, dv = 4, 576, 512
+    geom = latent_geometry(t_max, d, dv, h, dtype)
+    assert (geom.block_k, geom.tail) == (
+        bk, 256 if t_max > bk else None)
+    lead = (3,) if stacked else ()
+    buf = jax.ShapeDtypeStruct((*lead, b, 1, d, t_max), dtype)
+
+    def step(q, new, rows, at, layer):
+        return flash_decode(q, new, None, rows, None, at, at,
+                            layer=layer if stacked else None,
+                            latent_v=dv, interpret=False)[:2]
+
+    compiled = _compile(
+        chip, step, jax.ShapeDtypeStruct((b, h, 1, d), dtype),
+        jax.ShapeDtypeStruct((b, 1, d, 128), dtype), buf,
+        jax.ShapeDtypeStruct((b,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32), donate=(2,))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (math.prod(buf.shape)
+                                       * jnp.dtype(dtype).itemsize)
+    assert mem.temp_size_in_bytes < 1 << 20
 
 
 # ---------------------------------------------------------------------------
@@ -470,6 +524,97 @@ def _cache_sized_moves(hlo, at_least):
                 and _result_bytes(m.group(2)) >= at_least):
             found.append(line.strip()[:160])
     return found
+
+
+def _relayouts(hlo, t_max, at_least):
+    """The optimized HLO's copies and transposes of an array with a
+    ``t_max`` axis and at least ``at_least`` bytes: what a latent buffer
+    in a layout the chip would not pick (or a consumer that wants
+    another) costs a layer a call. (An asynchronous copy between memory
+    spaces that keeps the layout — the compiler parking a one-session
+    buffer in VMEM — is none.)"""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if not (m and m.group(3) in ('copy', 'copy-start', 'transpose')
+                and re.search(rf'[\[,]{t_max}[\],]', m.group(2))
+                and _result_bytes(m.group(2)) >= at_least):
+            continue
+        ends = re.findall(r'\w+\[[\d,]*\]\{[^}]*\}',
+                          re.sub(r'S\(\d+\)', '', m.group(2)))
+        if m.group(3) == 'copy-start' and len(set(ends[:2])) == 1:
+            continue
+        found.append(line.strip()[:160])
+    return found
+
+
+def _latent_prefill_chunk(chip, driver, cfg, traffic, latent_layers):
+    """ONE prefill chunk (4096 tokens of one session into its
+    33792-column latent buffer) of a latent cell's driver, compiled for
+    a described v5e: every latent layer's chunk is written in place —
+    the buffers aliased whole, one ``dynamic-update-slice`` a layer on
+    the time-minor buffer as the program's parameter holds it — and no
+    copy or transpose as large as one layer's buffer has a ``t_max``
+    axis (a relayout of the cache, or of the latent on its way into the
+    expansion, would hide in ``setup_s``)."""
+    model = driver.build_lm(cfg)
+    params = _shape_table_params(driver, cfg)
+    t_max = traffic['t_max']
+    caches = jax.eval_shape(lambda: model.make_decode_caches(1, t_max))
+    tok = jnp.zeros((1, traffic['prefill_chunk']), jnp.int32)
+    prefill = driver.make_programs(model, cfg)[0]
+    compiled = prefill.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        (params, tok, caches))).compile()
+    hlo = compiled.as_text()
+    layer_bytes = 576 * t_max * 2
+    assert _relayouts(hlo, t_max, layer_bytes) == []
+    updates = re.findall(
+        rf'= bf16\[(?:{latent_layers},)?1,576,{t_max}\]\S* '
+        r'dynamic-update-slice\(', hlo)
+    assert len(updates) == latent_layers
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    return hlo
+
+
+def test_xing4_prefill_chunk_writes_its_columns_in_place(chip, monkeypatch):
+    """``xing4-29b-a4b.decode-32k``'s set-up step (three of its six
+    layers): :func:`_latent_prefill_chunk`."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_latent as driver
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'xing4-29b-a4b-serve.json')) as f:
+        cfg = dict(json.load(f), num_hidden_layers=3)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-32k-x16.json')) as f:
+        traffic = json.load(f)
+    _latent_prefill_chunk(chip, driver, cfg, traffic, 3)
+
+
+def test_ling_prefill_chunk_writes_its_columns_in_place(chip, monkeypatch):
+    """``ling-3.0-flash.decode-32k``'s set-up step (all seven layers,
+    ONE of them latent): :func:`_latent_prefill_chunk`."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_ling as driver
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'ling-3.0-flash-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-32k-x96.json')) as f:
+        traffic = json.load(f)
+    _latent_prefill_chunk(chip, driver, cfg, traffic, 1)
 
 
 def test_cache_sized_moves_reads_hlo():
@@ -592,7 +737,7 @@ def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
     sessions, t_max = traffic['sessions'], traffic['t_max']
     caches = jax.eval_shape(
         lambda: model.make_decode_caches(sessions, t_max))
-    assert caches.rows.shape == (3, sessions, t_max, 640)
+    assert caches.rows.shape == (3, sessions, 576, t_max)
     stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
     tok = jnp.zeros((sessions, 1), jnp.int32)
     step = driver.make_programs(model, cfg)[2]
@@ -610,12 +755,16 @@ def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
     assert len(re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*moe_hit_experts',
         hlo)) == 2
-    layer_bytes = sessions * t_max * 640 * 2
+    layer_bytes = sessions * t_max * 576 * 2
     experts_bytes = 64 * 3584 * 1024 * 2
     assert _cache_sized_moves(hlo, min(layer_bytes, experts_bytes)) == []
+    assert _relayouts(hlo, t_max, layer_bytes) == []
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 3 * layer_bytes
     assert mem.temp_size_in_bytes < experts_bytes
+    # The engage counter: the stored bytes a grid step, 1536 columns of
+    # 576 values (640-wide rows of 1024 were 1 310 720).
+    assert {t['step']['bytes'] for t in traces} == {1536 * 576 * 2}
 
 
 def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
@@ -1436,7 +1585,8 @@ def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
     at the published widths and the traffic of
     ``ling-3.0-flash.decode-32k`` (7 layers, 96 sessions: six ``(96, 32,
     128, 128)`` float32 states with 12288-channel windows beside ONE
-    layer's latent buffer ``(96, 33792, 640)``), caches donated: the MLA
+    layer's time-minor latent buffer ``(96, 576, 33792)``), caches
+    donated: the MLA
     layer's step resolves to the kernel's latent mode over its own
     buffer (``kernel:latent``), ONE ``mla_decode`` call; every delta
     mixer's step is the kernel ``delta_step`` — six custom calls, each
@@ -1475,7 +1625,7 @@ def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
         lambda: model.make_decode_caches(sessions, t_max))
     state = ((sessions, 32, 128, 128), (sessions, 3, 12288))
     assert [tuple(x.shape for x in c[:2]) for c in caches] == (
-        6 * [state] + [((sessions, t_max, 640), (sessions,))])
+        6 * [state] + [((sessions, 576, t_max), (sessions,))])
     assert caches[0].state.dtype == jnp.float32
     assert caches[6].rows.dtype == jnp.bfloat16
     stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
@@ -1489,8 +1639,8 @@ def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
             delta_step_traces() as forms:
         compiled = step.lower(
             *described((params, tok, caches, stats))).compile()
-    assert [(t['resolved'], t['cache']) for t in traces] == [
-        ('kernel', 'latent')]
+    assert [(t['resolved'], t['cache'], t['step']['bytes'])
+            for t in traces] == [('kernel', 'latent', 1536 * 576 * 2)]
     assert forms == 6 * [{'form': 'pallas', 'tile': 16, 'chunk': 64}]
     tile = hidden_tile(2560, 768, 3, 2)
     assert routes == 6 * [{'route': 'hit_list', 'n': sessions,
@@ -1505,6 +1655,7 @@ def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
             hlo)) == calls, kernel
     state_bytes = sessions * 32 * 128 * 128 * 4
     assert _cache_sized_moves(hlo, state_bytes) == []
+    assert _relayouts(hlo, t_max, sessions * 576 * t_max * 2) == []
     # Each state is an operand of its kernel and of nothing else: read
     # once, written once.
     takers = _entry_readers(hlo, f'f32[{sessions},32,128,128]')
