@@ -126,39 +126,28 @@ def emit(rec):
 
 class Programs:
     """Per-phase record of the programs compiled ahead of running them:
-    seconds to trace + lower, seconds in the backend compile (the part
-    a warm persistent cache removes), and whether the compiled HLO
-    holds a Mosaic kernel (``tpu_custom_call``) — an interpreted or
-    reference path cannot pass for a program that is supposed to
-    contain one."""
+    whether the compiled HLO holds a Mosaic kernel (``tpu_custom_call``)
+    — an interpreted or reference path cannot pass for a program that is
+    supposed to contain one — and the collectives it must hold. What a
+    program cost to build is the ledger's to say (the phase's ``build``
+    line): ONE clock for a build."""
 
     def __init__(self):
         self.records = {}
 
     def compile(self, name, jitted, *args, pallas, collectives=()):
-        t0 = time.perf_counter()
-        lowered = jitted.lower(*args)
-        t1 = time.perf_counter()
-        compiled = lowered.compile()
-        rec = {'lower_seconds': round(t1 - t0, 3),
-               'compile_seconds': round(time.perf_counter() - t1, 3)}
+        compiled = jitted.lower(*args).compile()
         text = compiled.as_text()
+        rec = {op: op in text for op in collectives}
         if pallas:
             rec['tpu_custom_call'] = 'tpu_custom_call' in text
-        for op in collectives:
-            rec[op] = op in text
         self.records[name] = rec
         return compiled
-
-    @property
-    def seconds(self):
-        return round(sum(r['compile_seconds']
-                         for r in self.records.values()), 3)
 
     def checks(self):
         return {f'{name}.{key}': val
                 for name, rec in self.records.items()
-                for key, val in rec.items() if isinstance(val, bool)}
+                for key, val in rec.items()}
 
 
 def run_phase(name, fn, *args):
@@ -176,9 +165,26 @@ def run_phase(name, fn, *args):
     ok = 'error' not in rec and bool(checks) and all(checks.values())
     emit({'phase': name, 'ok': ok,
           'seconds': round(time.perf_counter() - t0, 3),
-          'compile_seconds': progs.seconds, **rec, 'checks': checks,
-          'programs': progs.records})
+          **rec, 'checks': checks})
+    emit(build_line(name, t0))
     return ok
+
+
+def build_line(name, since):
+    """What the phase BUILT, from the program's own ledger
+    (``utils/build_ledger.py``): self seconds by kind (a kernel's body
+    is ``build``, out of its program's ``trace``), the persistent
+    cache's counts, the kernels' bodies by name and the three costliest
+    programs — the ones to look at when a phase's seconds move."""
+    from distributed_dot_product_tpu.utils import build_ledger
+    s = build_ledger.summary(since=since)
+    return {'build': name,
+            'seconds': {k: round(v, 3) for k, v in s['seconds'].items()},
+            'cache': s['cache'],
+            'kernels': {k: round(v, 3) for k, v in s['kernels'].items()},
+            'costliest': [[program, round(total, 3)] for program, total, _
+                          in build_ledger.costliest(s, 3)],
+            'records': s['records'], 'dropped': s['dropped']}
 
 
 def max_err(a, b):

@@ -73,6 +73,11 @@ def _is_jax_call(node):
 # The obs spans layer (obs/spans.py): roots its calls may appear under.
 _SPAN_ROOTS = {'obs', 'spans', 'obs_spans'}
 _SPAN_NAMES = {'span', 'spanned'}
+# NOT among them, on purpose: utils/build_ledger.build_span, the one
+# clock-reading name that belongs in jitted code — it times the TRACE
+# (a kernel's body, a distributed matmul) and leaves nothing in the
+# program (fixture each way: tests/graphlint_fixtures/
+# fx_build_span_in_jit.py).
 
 
 def _is_span_call(node):

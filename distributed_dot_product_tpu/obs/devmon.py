@@ -49,8 +49,8 @@ def _device_label(device):
 
 def _safe_memory_stats(device):
     """``device.memory_stats()`` or None — the narrowed exception set is
-    every "stats unsupported here" shape observed (see
-    utils.tracing.device_peak_bytes)."""
+    every "stats unsupported here" shape observed (CPU, some PJRT
+    plugins); anything else (a real runtime fault) propagates."""
     try:
         return device.memory_stats() or None
     except (AttributeError, NotImplementedError, RuntimeError, TypeError):

@@ -4,15 +4,23 @@ Hierarchical host-side wall-time spans — the structured successor of the
 reference ``measure`` decorator (reference functions.py:24-41), grown
 from a per-call print into a nestable tree an operator can read.
 
-Two kinds of name live here, and which is for what:
+Three kinds of name, and which is for what (two live here):
 
-- :func:`span` names HOST time (below). It reads the clock, so it never
-  goes inside a jitted function.
+- :func:`span` names HOST RUN time (below): the dispatch of a compiled
+  step, a readback, scheduling. It reads the clock, so it never goes
+  inside a jitted function.
 - :func:`device_scope` names DEVICE time: a ``jax.named_scope`` from
   the fixed :data:`DEVICE_SCOPES` vocabulary, for use inside jitted
   code. Both live in :mod:`distributed_dot_product_tpu.utils.scopes`, a
   leaf the kernels and the model import; they are re-exported here
   unchanged for the trace readers that import them from this module.
+- ``build_span`` (:mod:`distributed_dot_product_tpu.utils.build_ledger`,
+  not re-exported) names BUILD time: Python that runs while a program is
+  being traced — a Pallas kernel's body, a distributed matmul under
+  ``jit``. It reads the clock twice AT TRACE TIME on purpose, notes a
+  ``build`` record on this module's clock (``perf_counter``, as
+  :attr:`SpanRecord.start`) and leaves nothing in the program, so it is
+  the one clock-reading name ``clock-in-jit`` lets into jitted code.
 
 Contract of ``span`` (the part graphlint enforces — see
 analysis/astlint.py):

@@ -60,8 +60,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from distributed_dot_product_tpu.utils.build_ledger import build_span
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
-from distributed_dot_product_tpu.utils.tracing import measure
 
 __all__ = [
     'distributed_matmul_nt', 'distributed_matmul_tn',
@@ -97,7 +97,7 @@ def _pad_to_multiple(x, multiple, axis):
     return jnp.pad(x, pad), target
 
 
-@measure
+@build_span('ops.nt')
 def distributed_matmul_nt(left, right, offset=32, axis_name=SEQ_AXIS,
                           impl='allgather', precision=None):
     """``A·Bᵀ`` over sequence-sharded operands (reference functions.py:44-99).
@@ -180,7 +180,7 @@ def _matmul_nt_ring(left, right, axis_name, precision):
     return compute(W - 1, buf, out)
 
 
-@measure
+@build_span('ops.tn')
 def distributed_matmul_tn(left, right, axis_name=SEQ_AXIS, precision=None):
     """``Aᵀ·B`` over sequence-sharded operands (reference
     functions.py:102-148).
@@ -209,7 +209,7 @@ def distributed_matmul_tn(left, right, axis_name=SEQ_AXIS, precision=None):
                             tiled=False)
 
 
-@measure
+@build_span('ops.all')
 def distributed_matmul_all(left, right, offset=32, axis_name=SEQ_AXIS,
                            impl='allgather', precision=None):
     """``A·B`` over sequence-sharded operands (reference

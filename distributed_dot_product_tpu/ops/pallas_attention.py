@@ -72,6 +72,7 @@ from jax.experimental import pallas as pl
 # VMEM scratch on CPU.
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_dot_product_tpu.ops.kernel_call import kernel_call
 from distributed_dot_product_tpu.utils.scopes import device_scope
 from distributed_dot_product_tpu.utils.trace_sinks import TraceSinks
 
@@ -1051,7 +1052,7 @@ def _pallas_call(name, kernel, grid, in_specs, out_specs, scratch,
         params['compiler_params'] = pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes)
     if prefetch:
-        call = pl.pallas_call(
+        call = kernel_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(prefetch), grid=grid,
@@ -1059,7 +1060,7 @@ def _pallas_call(name, kernel, grid, in_specs, out_specs, scratch,
                 scratch_shapes=scratch),
             out_shape=out_shape, interpret=interp, name=name, **params)
     else:
-        call = pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+        call = kernel_call(kernel, grid=grid, in_specs=in_specs,
                               out_specs=out_specs, scratch_shapes=scratch,
                               out_shape=out_shape, interpret=interp,
                               name=name, **params)

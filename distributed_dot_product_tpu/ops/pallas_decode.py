@@ -101,6 +101,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_dot_product_tpu.ops.kernel_call import kernel_call
 from distributed_dot_product_tpu.ops.pallas_attention import (
     _LOG2E, _NEG_BIG, _quantize_rows,
 )
@@ -1225,7 +1226,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         name, inner = 'flash_decode_ring', device_scope(
             'ops.flash_decode_ring')
     with device_scope('ops.flash_decode'), inner:
-        outs = pl.pallas_call(
+        outs = kernel_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=n_prefetch,
@@ -1622,7 +1623,7 @@ def _latent_decode(q, new, rows, valid_to, append_at, dv, *, layer=None,
         scratch.append(pltpu.VMEM((2, bk // geom.tail, d, geom.tail),
                                   rows.dtype))
     with device_scope('ops.mla_decode'):
-        num, _, l, new_rows = pl.pallas_call(
+        num, _, l, new_rows = kernel_call(
             _make_latent_kernel(geom, ns, b, g_pad, dv, stacked),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(prefetch),

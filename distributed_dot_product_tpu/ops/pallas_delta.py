@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from distributed_dot_product_tpu.ops.kernel_call import kernel_call
+
 __all__ = ['delta_step', 'delta_step_reference', 'heads_tile']
 
 # The most bytes of state one grid step holds: a step costs ~0.4 µs that
@@ -114,7 +116,7 @@ def delta_step(q, k, v, a, b, state, *, interpret=None):
                     b.astype(f32), hb)
     rows = pl.BlockSpec((1, hb, d_v), lambda i, h: (i, h, 0))
     tiles = pl.BlockSpec((1, hb, d_k, d_v), lambda i, h: (i, h, 0, 0))
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_kernel, hb=hb),
         grid=(bsz, heads // hb),
         in_specs=[pl.BlockSpec((1, 1, d_k, 4 * hb),

@@ -67,6 +67,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_dot_product_tpu.ops.kernel_call import kernel_call
 from distributed_dot_product_tpu.ops.pallas_decode import (
     _STEP_STREAM_BYTES, _VMEM_BUDGET, _pad_rows, _sublane,
 )
@@ -228,7 +229,7 @@ def _hit_experts(tokens, gates, hits, count, w_gate, w_up, w_down, act,
         return (0, 0)
 
     up_spec = pl.BlockSpec((1, wide, tile), up_idx)
-    out = pl.pallas_call(
+    out = kernel_call(
         functools.partial(_kernel, act=act, gated=gated),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

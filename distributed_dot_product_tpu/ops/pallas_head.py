@@ -47,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_dot_product_tpu.ops.kernel_call import kernel_call
+
 __all__ = ['head_grad', 'head_tiles']
 
 # The group's float32 dx stays in VMEM across the vocabulary walk: the
@@ -178,7 +180,7 @@ def head_grad(logits, lse, targets, x, table, dw, *, logit_scale, tiles,
     def by_block(g, j):
         return (j, 0)
 
-    return pl.pallas_call(
+    return kernel_call(
         functools.partial(_kernel, vocab=vocab, logit_scale=logit_scale),
         grid=(rows // group, pl.cdiv(vocab, tile)),
         in_specs=[pl.BlockSpec((group, tile), lambda g, j: (g, j)),

@@ -70,6 +70,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributed_dot_product_tpu.ops.kernel_call import kernel_call
+
 __all__ = ['sparse_decode', 'sparse_decode_reference', 'picks_group',
            'threshold_picks', 'sorted_picks']
 
@@ -283,7 +285,7 @@ def sparse_decode(q, k_new, v_new, k_cache, v_cache, picks, count, length,
         return (i, j, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    out, k_cache, v_cache = pl.pallas_call(
+    out, k_cache, v_cache = kernel_call(
         functools.partial(_kernel, block=block, group=group, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -383,7 +385,7 @@ def threshold_picks(scores, k, *, interpret=None):
     width, slots = (-(-x // _LANES) * _LANES for x in (n, k))
     tile = min(_PICK_ROWS, -(-rows // 8) * 8,
                max(8, _PICK_CELLS // width // 8 * 8))
-    out = pl.pallas_call(
+    out = kernel_call(
         functools.partial(_pick_kernel, k=k, n=n),
         grid=(pl.cdiv(rows, tile),),
         in_specs=[pl.BlockSpec((tile, width), lambda r: (r, 0))],

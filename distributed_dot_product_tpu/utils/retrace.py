@@ -29,8 +29,14 @@ wrapper's lifetime. Per-token loops own ONE wrapper per compiled step
 (e.g. ``make_decode_step`` wraps at build time), so legitimate
 shape-driven retraces of a *new* step get a fresh budget while the
 per-token storm on a single step trips immediately.
+
+This module counts traces of WATCHED callables and raises; it sees no
+seconds and no unwatched program. What a build COST — trace, lowering,
+compile, cache, by program — is in ``utils/build_ledger.py``, which sees
+every program and raises nothing.
 """
 
+import collections
 import functools
 import os
 import threading
@@ -71,13 +77,15 @@ class TraceCounter:
         self._lock = threading.Lock()
 
     def __del__(self):
-        # Fold the final count into the per-name retired total so
-        # total() stays exact however the GC times wrapper teardown
-        # (the rebuild-storm path discards one wrapper per token).
+        # Hand the final count to the per-name retired total so total()
+        # stays exact however the GC times wrapper teardown (the
+        # rebuild-storm path discards one wrapper per token). NO lock
+        # here: the collector runs a finalizer on whatever thread
+        # allocates, and that thread may hold _COUNTERS_LOCK (seen: a
+        # tier-1 worker hung in _live_counters). A deque's append is
+        # atomic; the readers fold it in under the lock.
         try:
-            with _COUNTERS_LOCK:
-                _RETIRED[self.name] = (_RETIRED.get(self.name, 0)
-                                       + self.count)
+            _DYING.append((self.name, self.count))
         except Exception:  # graphlint: allow[silent-except]
             pass           # interpreter shutdown: globals may be gone
 
@@ -100,12 +108,22 @@ class TraceCounter:
 # wrapper (the pathological case the sentinel observes — a step rebuilt
 # per token — discards one wrapper per token; holding them strongly
 # here would turn the observer into its own leak). A dying counter
-# folds its count into the per-name _RETIRED total (TraceCounter.
-# __del__), so total() is exact regardless of GC timing, and reset()
-# always reaches every counter that could still raise.
+# leaves its count in _DYING (TraceCounter.__del__, lock-free) and the
+# readers fold that into the per-name _RETIRED total, so total() is
+# exact regardless of GC timing, and reset() always reaches every
+# counter that could still raise.
 _COUNTERS = []                   # weakref.ref(TraceCounter)
 _RETIRED = {}                    # name -> folded count from dead
+_DYING = collections.deque()     # (name, count) from finalizers
 _COUNTERS_LOCK = threading.Lock()
+
+
+def _fold_dying():
+    """Move the finalizers' counts into ``_RETIRED``. Callers must hold
+    _COUNTERS_LOCK."""
+    while _DYING:
+        name, count = _DYING.popleft()
+        _RETIRED[name] = _RETIRED.get(name, 0) + count
 
 
 def _live_counters():
@@ -161,6 +179,7 @@ def total(name):
     rebuilt-per-token step they total N. tests/test_graphlint.py pins
     both numbers for decode_seq_parallel's LRU step cache."""
     with _COUNTERS_LOCK:
+        _fold_dying()
         return (_RETIRED.get(name, 0)
                 + sum(c.count for c in _live_counters()
                       if c.name == name))
@@ -174,4 +193,5 @@ def reset():
     with _COUNTERS_LOCK:
         for c in _live_counters():
             c.count = 0
+        _fold_dying()
         _RETIRED.clear()

@@ -1,116 +1,32 @@
 # -*- coding: utf-8 -*-
 """
-Per-op tracing / profiling utilities.
+Host-side observability primitives the train loop and the serving layer
+share: :func:`log_exception` / :func:`log_step` (counters and the event
+log), :func:`hard_sync` (a host readback that fences on any backend) and
+the lightweight metrics registry.
 
-TPU-native replacement for the reference ``measure`` decorator
-(reference functions.py:24-41), which printed per-call wall time, operand
-shapes and CUDA max-memory delta when the env var ``DISTRIBUTED_DOT_DEBUG``
-was set (reference functions.py:21,30).
-
-Differences, deliberate:
-
-- **Honest timing.** The reference never called ``torch.cuda.synchronize()``
-  before stopping the clock (noted in SURVEY §5 / BASELINE.md), so its GPU
-  numbers are enqueue-biased. We fence with :func:`hard_sync` (a host
-  readback; on the directly attached v5e ``jax.block_until_ready`` fences
-  just as well — see :func:`hard_sync`) before reading the clock.
-- **Memory** comes from ``device.memory_stats()`` (TPU/GPU); on backends
-  without stats (CPU) it is reported as ``None``.
-- ``measure`` on a function *called inside jit/shard_map* times the trace,
-  not the execution (the result is a tracer, which cannot be synced) — the
-  printed line is tagged ``traced`` in that case. Execution numbers come
-  from the benchmark (``benchmarks/run.py``; its ``--trace 1`` runs read
-  the profiler's own trace), on the chip.
+The reference's ``measure`` decorator (reference functions.py:24-41)
+printed per-call wall time under an environment switch; on a function
+called inside ``jit`` that is the time of the TRACE, and it lives where
+the rest of build time does now: the three distributed matmuls of
+``ops/functions.py`` open ``build_span('ops.nt' | 'ops.all' |
+'ops.tn')`` and the build ledger (``utils/build_ledger.py``) keeps the
+record. Execution numbers come from the benchmark (``benchmarks/run.py``;
+its ``--trace 1`` runs read the profiler's own trace), on the chip.
 """
 
 import bisect
 import collections
-import functools
-import os
 import threading
-import time
 
 import jax
-
-# Same env-var name as the reference (functions.py:21) so users can flip the
-# identical switch.
-DEBUG_ENV_VAR = 'DISTRIBUTED_DOT_DEBUG'
-
-
-def _debug_enabled():
-    return bool(os.environ.get(DEBUG_ENV_VAR))
-
-
-def device_peak_bytes(device=None):
-    """Peak device-memory bytes, or None when the backend has no stats
-    (replaces ``torch.cuda.max_memory_allocated``, reference functions.py:28)."""
-    device = device or jax.devices()[0]
-    try:
-        stats = device.memory_stats()
-    except (AttributeError, NotImplementedError, RuntimeError, TypeError):
-        # Backend without memory stats (CPU, some PJRT plugins) — the
-        # narrowed set is every "stats unsupported here" shape observed;
-        # anything else (a real runtime fault) propagates.
-        return None
-    if not stats:
-        return None
-    return stats.get('peak_bytes_in_use', stats.get('bytes_in_use'))
-
-
-def _shape_of(x):
-    return tuple(getattr(x, 'shape', ())) or None
-
-
-def measure(fn):
-    """Decorator: when ``DISTRIBUTED_DOT_DEBUG`` is set, print wall time,
-    operand shapes and peak device memory per call (reference
-    functions.py:24-41). Zero overhead when disabled.
-    """
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        if not _debug_enabled():
-            return fn(*args, **kwargs)
-        peak_before = device_peak_bytes()
-        start = time.perf_counter()
-        result = fn(*args, **kwargs)
-        traced = ''
-        try:
-            hard_sync(result)
-        except (jax.errors.ConcretizationTypeError,
-                jax.errors.TracerArrayConversionError):
-            # Tracer under jit/shard_map: only trace time is observable.
-            # (Real runtime errors — OOM — propagate.) Both types
-            # named: TracerArrayConversionError is NOT a
-            # ConcretizationTypeError subclass, and the sync probe's
-            # np.asarray raises it.
-            traced = ' (traced)'
-        elapsed = time.perf_counter() - start
-        shapes = [_shape_of(a) for a in args if _shape_of(a) is not None]
-        # Peak-memory DELTA across the call (before/after readings of
-        # the monotonic peak), matching the reference semantics
-        # (reference functions.py:28 reports max-memory growth per
-        # call) — an absolute peak says nothing about THIS op once any
-        # larger op has run in the process.
-        peak_after = device_peak_bytes()
-        if peak_before is None or peak_after is None:
-            peak_s = 'n/a'
-        else:
-            delta = peak_after - peak_before
-            peak_s = f'+{delta / 2 ** 30:.3f} GiB'
-        print(f'[{DEBUG_ENV_VAR}] {fn.__name__}: {elapsed * 1000:.3f} ms'
-              f'{traced} shapes={shapes} peak_mem_delta={peak_s}')
-        return result
-
-    return wrapper
 
 
 def log_exception(context, exc, registry=None):
     """Record a swallowed-but-survivable exception so fault paths stay
     observable: bumps ``exceptions_swallowed`` (total + per-context)
     in the metrics registry — a health endpoint or operator sees the
-    count move even when nothing prints — and prints the exception
-    under the ``DISTRIBUTED_DOT_DEBUG`` switch.
+    count move even when nothing prints.
 
     This is the logging half of the ``silent-except`` lint contract
     (analysis/astlint.py): a broad handler must re-raise, narrow its
@@ -126,9 +42,6 @@ def log_exception(context, exc, registry=None):
     reg.counter(f'exceptions_swallowed.{context}').inc()
     _emit_event('exception', context=context,
                 type=type(exc).__name__, message=str(exc))
-    if _debug_enabled():
-        print(f'[{DEBUG_ENV_VAR}] swallowed exception in {context}: '
-              f'{type(exc).__name__}: {exc}', flush=True)
 
 
 def _emit_event(event, **fields):
@@ -142,13 +55,11 @@ def _emit_event(event, **fields):
 
 def log_step(step, loss, grad_norm=None, bad=False, seconds=None,
              extra='', force=False):
-    """One-line per-step training log, gated by the same
-    ``DISTRIBUTED_DOT_DEBUG`` switch as :func:`measure` (``force=True``
-    prints unconditionally — the driver uses it for its periodic log
-    cadence). The resilient train loop feeds its per-step
-    ``{loss, bad_step, grad_norm}`` records through here.
+    """One-line per-step training log, printed where ``force=True``
+    (the driver's periodic log cadence). The resilient train loop feeds
+    its per-step ``{loss, bad_step, grad_norm}`` records through here.
 
-    Independently of the print gate, every record is routed into the
+    Whether or not it prints, every record is routed into the
     active observability event log (obs/events.py) when one exists —
     training history lands in the same durable JSONL stream as the
     serving lifecycle (``train.step`` + ``train.bad_step``)."""
@@ -158,7 +69,7 @@ def log_step(step, loss, grad_norm=None, bad=False, seconds=None,
                 bad=bool(bad), seconds=seconds, extra=extra or None)
     if bad:
         _emit_event('train.bad_step', step=int(step), loss=float(loss))
-    if not (force or _debug_enabled()):
+    if not force:
         return
     parts = [f'step {step}: loss={loss:.6f}']
     if grad_norm is not None:

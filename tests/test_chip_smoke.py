@@ -111,6 +111,22 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     assert ling['checks']['ling.latent_rows_grew'] is True
     # both kernel modes side by side, off the chip both through XLA
     assert phases['generate_mixed']['mixed_caches'] == ['layer', 'ring']
+    # one `build` line a phase, from the program's own ledger: seconds by
+    # kind, the kernels' bodies by their Pallas names, the costliest
+    # programs; nothing fell off the ledger's far end
+    built = {rec['build']: rec for rec in lines if 'build' in rec}
+    assert set(built) == set(phases)
+    for name, rec in built.items():
+        assert set(rec['seconds']) == {'trace', 'lower', 'compile',
+                                       'build', 'cache_read'}
+        assert rec['seconds']['trace'] > 0 and rec['records'] > 0
+        assert rec['dropped'] == 0
+        assert 1 <= len(rec['costliest']) <= 3
+        assert sum(rec['seconds'].values()) <= phases[name]['seconds']
+    assert {'flash_fwd', 'flash_bwd_fused'} <= set(
+        built['train']['kernels'])
+    assert 'moe_hit_experts' in built['generate_ling']['kernels']
+    assert built['train']['seconds']['build'] > 0
     for name, rec in phases.items():
         assert 'error' not in rec, (name, rec.get('error'))
         failed = {k for k, v in rec['checks'].items() if not v}

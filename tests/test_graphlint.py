@@ -139,6 +139,7 @@ def _expected_lines(path):
     (os.path.join('ops', 'fx_traced_bool.py'), 'traced-bool-branch'),
     ('fx_clock_in_jit.py', 'clock-in-jit'),
     ('fx_span_in_jit.py', 'clock-in-jit'),
+    ('fx_build_span_in_jit.py', 'clock-in-jit'),
     ('fx_silent_except.py', 'silent-except'),
 ])
 def test_ast_rule_catches_fixture(fixture, rule):
@@ -254,6 +255,28 @@ def test_retrace_disabled_counts_but_never_raises(monkeypatch):
     step(jnp.ones((2,)))
     step(jnp.ones((3,)))          # over budget, but sentinel is off
     assert watched._graphlint_counter.count == 2
+
+
+def test_a_counter_may_die_while_the_registry_is_locked():
+    """The collector runs a finalizer on whatever thread allocates, and
+    that thread may hold the registry's lock (``watch_traces`` prunes
+    under it): a dying counter takes no lock, and its count still
+    reaches ``total()``."""
+    import threading
+    counter = retrace.TraceCounter('unit.dying', budget=1)
+    counter.count = 3
+    done = threading.Event()
+
+    def die_under_the_lock():
+        nonlocal counter
+        with retrace._COUNTERS_LOCK:
+            counter = None          # the last reference: __del__ runs here
+        done.set()
+
+    t = threading.Thread(target=die_under_the_lock, daemon=True)
+    t.start()
+    assert done.wait(10), 'a finalizer waited for the lock its thread holds'
+    assert retrace.total('unit.dying') == 3
 
 
 def _decode_module(**kw):

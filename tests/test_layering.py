@@ -71,6 +71,30 @@ def test_lower_layer_imports_no_tooling(rel):
         + ', '.join(f'line {line}: {name}' for line, name in bad))
 
 
+def _ops_files():
+    return sorted(os.path.join('ops', f)
+                  for f in os.listdir(os.path.join(ROOT, 'ops'))
+                  if f.endswith('.py'))
+
+
+@pytest.mark.parametrize('rel', _ops_files())
+def test_ops_import_no_utils_tracing(rel):
+    """``utils/tracing.py`` reaches ``obs/`` (its event log); since the
+    ``@measure`` decorator went, no kernel file needs it: build time is
+    the leaf ``utils/build_ledger.py``'s."""
+    bad = [(line, name) for line, name in _imported_modules(rel)
+           if name.startswith(f'{PKG}.utils.tracing')]
+    assert not bad, bad
+
+
+def test_the_build_ledger_is_a_leaf():
+    """``utils/build_ledger.py`` imports JAX and nothing of the package,
+    so ``ops/`` may use it."""
+    mine = [name for _, name in _imported_modules(
+        os.path.join('utils', 'build_ledger.py')) if name.startswith(PKG)]
+    assert not mine, mine
+
+
 def test_obs_and_serve_import_nothing_of_the_lint():
     """The lint's example programs import the engine and the spans, not
     the reverse: ``obs/`` and ``serve/`` name no ``analysis`` module."""
