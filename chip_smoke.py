@@ -826,8 +826,12 @@ def phase_generate_ling(progs, cfg, seed):
     and the latent LENGTHS set back, and the request again — which must
     read what the first did, bit for bit. Prints the step's forms: the
     latent layer's (``mla_decode`` on its own buffer), the delta
-    mixer's, each expert layer's route, and the rows a step routed to
-    the held group."""
+    mixer's, each expert layer's route and how it made its choices, and
+    the rows a step routed to the held group. Then ONE seeded ``(96,
+    512)`` grouped call both ways: the hit list's choices (on the chip
+    by compares and ``sparse_pick``, no sort) against the sorted
+    route's (``lax.top_k`` everywhere) — the same picks as sets, the
+    same counts, the same rows for the held group."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -836,7 +840,9 @@ def phase_generate_ling(progs, cfg, seed):
         decode_impl_traces, restore_states, snapshot_states,
     )
     from distributed_dot_product_tpu.models.delta import delta_step_traces
-    from distributed_dot_product_tpu.models.moe import expert_route_traces
+    from distributed_dot_product_tpu.models.moe import (
+        SparseExperts, expert_route_traces,
+    )
     model = ling_lm(cfg)
     n, steps, t_max = cfg['prompt'], cfg['new_tokens'], cfg['gen_t_max']
     params = {'params': model.init(
@@ -886,6 +892,27 @@ def phase_generate_ling(progs, cfg, seed):
     grown = np.asarray(caches[1].length).tolist()
     caches, again, _ = request(reset(caches, taken))
     resolved = sorted({f"{t['resolved']}:{t['cache']}" for t in traces})
+
+    grouped = dict(n_experts=512, top_k=8, hidden=128, scaling=2.5,
+                   n_group=8, topk_group=4, experts_held=(0, 64))
+    x = jax.random.normal(jax.random.key(seed + 4), (96, 128), jnp.float32)
+    router = SparseExperts(**grouped).init(jax.random.key(seed + 5), x)
+    router['params']['router_bias'] = 0.05 * jax.random.normal(
+        jax.random.key(seed + 6), (512,), jnp.float32)
+
+    @jax.jit
+    def both(p, x):
+        out = []
+        for dense_tokens in (None, 0):      # the hit list, the sorted route
+            (_, counts), sown = SparseExperts(
+                **grouped, dense_tokens=dense_tokens).apply(
+                    p, x, mutable=['counters'])
+            out.append((jnp.sort(sown['counters']['expert_picks'], -1),
+                        counts, sown['counters']['group_rows']))
+        return out
+    with expert_route_traces() as selects:
+        progs.compile('ling.picks', both, router, x, pallas=True)
+    by_threshold, by_sort = jax.tree.map(np.asarray, both(router, x))
     return {
         'ling_caches': kinds,
         'ling_mla_decode': resolved,
@@ -902,6 +929,16 @@ def phase_generate_ling(progs, cfg, seed):
             'ling.experts_on_the_hit_list': [
                 (r['route'], r['bound_by']) for r in routes] == 2 * [
                     ('hit_list', 'rule')],
+            # the step's two layers and the seeded call's hit-list half
+            # choose by compares and ``sparse_pick``; its sorted half
+            # by ``lax.top_k``, as everywhere
+            'ling.select_step_is_the_kernel': [
+                (r['route'], r['select']) for r in routes + selects] == (
+                    3 * [('hit_list', 'threshold')] + [('sorted', 'sort')]),
+            'ling.picks_are_top_k_s': bool(
+                by_threshold[0].shape == (96, 8)
+                and all(np.array_equal(a, b)
+                        for a, b in zip(by_threshold, by_sort))),
             # one session: a step routes its row to the held group or not
             'ling.group_rows_counted': bool(
                 np.all((rows == 0) | (rows == 1))),

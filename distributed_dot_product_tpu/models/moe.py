@@ -87,8 +87,26 @@ rule's (None, the default: the rule; 0: always the sorted route).
 by whose bound. Under ``jax.grad`` the hit-list route differentiates
 through the batched matmuls over every held expert
 (``hit_experts_reference``).
+
+The hit-list route's discrete choices take one of two forms, by the
+backend alone (:func:`select_form`). Off the TPU ``lax.top_k`` makes
+them (three a layer under group-limited routing), a gather reads the
+gates and scatters build the kept-group mask, the gate table and the
+counts: the oracle. On a TPU each ``top_k`` is a sort and each scatter
+costs what a sort does (chip, PR 49: 0.18 ms a layer of 96 rows x 512
+experts), so there the layer compares instead: a group's two best from
+two maxima, the kept groups by counting who beats whom, the k picks by
+ONE Pallas program (``ops/pallas_sparse.threshold_picks``: the k-th
+largest by bisection, ties to the lower index) that also gives the
+picks' MASK, and the gate table and the counts from the mask. The picks
+are ``lax.top_k``'s sets on every input and come ASCENDING by index;
+nothing on this route reads their order but the sum that normalises the
+gates, so a gate may differ from the sorted form's in its last float32
+bit. The sorted route keeps ``lax.top_k`` everywhere: there the picks'
+order is the order the k-way combine adds in.
 """
 
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -103,10 +121,14 @@ from distributed_dot_product_tpu.models.remat import (
 from distributed_dot_product_tpu.ops.pallas_experts import (
     HIT_LIST_ROWS, hidden_tile, hit_experts, hit_list,
 )
+from distributed_dot_product_tpu.ops.pallas_sparse import (
+    order_image, threshold_picks,
+)
 from distributed_dot_product_tpu.utils.scopes import device_scope
 from distributed_dot_product_tpu.utils.trace_sinks import TraceSinks
 
-__all__ = ['GatedMLP', 'PlainMLP', 'SparseExperts', 'expert_route_traces']
+__all__ = ['GatedMLP', 'PlainMLP', 'SparseExperts', 'expert_route_traces',
+           'select_form']
 
 ACTIVATIONS = {'silu': nn.silu,
                'relu2': lambda x: jnp.square(nn.relu(x))}
@@ -117,21 +139,45 @@ _ROUTE_TRACES = TraceSinks()
 
 def expert_route_traces():
     """Collect which route each :class:`SparseExperts` call takes while
-    the block runs: one dict ``{'route', 'n', 'bound', 'bound_by',
-    'tile'}`` per TRACE of a layer. ``route`` is ``'hit_list'`` (the
-    ``moe_hit_experts`` kernel over the call's hit experts) or
-    ``'sorted'`` (the grouped matmuls over the rows sorted by expert);
-    ``n`` the call's rows, ``bound`` the most rows that take the hit
-    list, ``bound_by`` whose it was — ``'rule'``
-    (``ops.pallas_experts.HIT_LIST_ROWS``) or ``'caller'``
-    (``dense_tokens``) — and ``tile`` the columns of ``hidden`` one grid
-    step of the kernel takes (None on the sorted route)::
+    the block runs: one dict ``{'route', 'select', 'n', 'bound',
+    'bound_by', 'tile'}`` per TRACE of a layer. ``route`` is
+    ``'hit_list'`` (the ``moe_hit_experts`` kernel over the call's hit
+    experts) or ``'sorted'`` (the grouped matmuls over the rows sorted
+    by expert); ``select`` how the picks were made — ``'threshold'``
+    (compares and the ``sparse_pick`` program, no sort) or ``'sort'``
+    (``lax.top_k``), :func:`select_form`; ``n`` the call's rows,
+    ``bound`` the most rows that take the hit list, ``bound_by`` whose
+    it was — ``'rule'`` (``ops.pallas_experts.HIT_LIST_ROWS``) or
+    ``'caller'`` (``dense_tokens``) — and ``tile`` the columns of
+    ``hidden`` one grid step of the kernel takes (None on the sorted
+    route)::
 
         with expert_route_traces() as traces:
             step.lower(*args).compile()
         assert {t['route'] for t in traces} == {'hit_list'}
     """
     return _ROUTE_TRACES.open()
+
+
+@functools.partial(jax.jit, static_argnames=('k', 'interpret'))
+def _picks_and_mask(choice, k, interpret):
+    """``threshold_picks`` with the mask, under ``jit`` so that the
+    layers of one program share ONE trace of the kernel's body and one
+    lowering of it (the body is 0.2 s of Python a call on the chip's
+    host: chip, PR 49)."""
+    return threshold_picks(choice, k, mask=True, interpret=interpret)
+
+
+def select_form():
+    """How a hit-list call makes its discrete choices: ``'threshold'``
+    on a TPU — a group's two best by two maxima, the kept groups by
+    counting who beats whom, the k picks by
+    ``ops/pallas_sparse.threshold_picks``, and the gate table and the
+    counts from the picks' mask: no sort, no scatter — and ``'sort'``
+    (``lax.top_k``: the oracle) elsewhere. The same picks either way.
+    The sorted route is ``'sort'`` everywhere: there the picks' order
+    is the order its k-way sum adds in."""
+    return 'threshold' if jax.default_backend() == 'tpu' else 'sort'
 
 
 class GatedMLP(nn.Module):
@@ -197,17 +243,14 @@ class SparseExperts(nn.Module):
                                                     out_axis=-1,
                                                     batch_axis=(0,))
 
-    def _hit_list(self, tokens, picked, gates, counts, w_gate, w_up,
-                  w_down, act, lo, hi):
+    def _hit_list(self, tokens, table, counts, w_gate, w_up, w_down, act,
+                  lo, hi):
         """Every HIT held expert on every token of ``tokens (n, wide)``,
-        the token's gate for it zero where it was not picked: the gate
-        table and the step's hit list, then one kernel that streams the
-        hit experts' weights and adds the picks up in float32."""
+        the token's gate for it — ``table (n, n_experts)`` — zero where
+        it was not picked: the step's hit list, then one kernel that
+        streams the hit experts' weights and adds the picks up in
+        float32."""
         with device_scope('lm.moe_route'):
-            table = jnp.zeros((tokens.shape[0], self.n_experts),
-                              jnp.float32)
-            table = table.at[jnp.arange(tokens.shape[0])[:, None],
-                             picked].set(gates)
             hits, count = hit_list(counts[lo:hi])
         with device_scope('lm.moe_experts'):
             return hit_experts(tokens, table[:, lo:hi], hits, count,
@@ -228,6 +271,56 @@ class SparseExperts(nn.Module):
         return (jnp.where(jnp.repeat(keep, size, axis=1), choice,
                           -jnp.inf),
                 jnp.sum(mine, dtype=jnp.int32))
+
+    def _kept_groups_by_count(self, choice, lo, hi):
+        """:meth:`_kept_groups` with no sort and no scatter: a group's
+        two best from a maximum, the removal of ONE occurrence of it
+        (the lowest index) and a second maximum; a group is kept where
+        fewer than ``topk_group`` groups beat it — a higher score in
+        ``lax.top_k``'s order, or the same at a lower index."""
+        n, size = choice.shape[0], self.n_experts // self.n_group
+        part = choice.reshape(n, self.n_group, size)
+        at = lax.broadcasted_iota(jnp.int32, part.shape, 2)
+        best = jnp.max(part, -1, keepdims=True)
+        first = jnp.min(jnp.where(part == best, at, size), -1,
+                        keepdims=True)
+        second = jnp.max(jnp.where(at == first, -jnp.inf, part), -1)
+        key = order_image(best[..., 0] + second)          # (n, n_group)
+        group = jnp.arange(self.n_group)
+        other, own = key[:, None, :], key[:, :, None]
+        beaten_by = (other > own) | ((other == own)
+                                     & (group[None, :] < group[:, None]))
+        keep = jnp.sum(beaten_by, -1) < self.topk_group
+        mine = jnp.any(keep[:, lo // size:(hi - 1) // size + 1], axis=-1)
+        return (jnp.where(keep[:, :, None], part, -jnp.inf).reshape(n, -1),
+                jnp.sum(mine, dtype=jnp.int32))
+
+    def _threshold_route(self, scores, choice, lo, hi):
+        """The hit-list route's choices where :func:`select_form` says
+        ``'threshold'``: ``(picked (n, k)`` ASCENDING by index, the gate
+        table ``(n, n_experts)``, ``counts``, ``group_rows`` or None``)``
+        from the ``scores (n, n_experts)`` the gates are made of and the
+        ``choice`` the picks go by (the scores with their bias). The
+        picks are the sorted form's SET on every input; a gate is the
+        sorted form's but for the order its k-term normalising sum adds
+        in."""
+        group_rows = None
+        if self.n_group > 1:
+            choice, group_rows = self._kept_groups_by_count(choice, lo, hi)
+        picked, mask = _picks_and_mask(
+            lax.stop_gradient(choice), self.top_k,
+            interpret=jax.default_backend() != 'tpu')
+        if self.score == 'softmax_picked':
+            top = jnp.max(jnp.where(mask, scores, -jnp.inf), -1,
+                          keepdims=True)
+            table = jnp.where(mask, jnp.exp(scores - top), 0.0)
+            table = table / jnp.sum(table, -1, keepdims=True)
+        else:
+            table = jnp.where(mask, scores, 0.0)
+            if self.norm_topk:
+                table = table / jnp.sum(table, -1, keepdims=True)
+            table = table * self.scaling
+        return picked, table, jnp.sum(mask, 0, dtype=jnp.int32), group_rows
 
     @nn.compact
     def __call__(self, x):
@@ -256,12 +349,15 @@ class SparseExperts(nn.Module):
         grouped = self.n_group > 1
         if grouped and (picked_softmax or self.n_experts % self.n_group
                         or not 0 < self.topk_group <= self.n_group
-                        or self.n_experts // self.n_group < 2):
+                        or self.n_experts // self.n_group < 2
+                        or self.topk_group * (self.n_experts
+                                              // self.n_group) < self.top_k):
             raise ValueError(
                 f'group-limited routing keeps topk_group '
                 f'{self.topk_group} of n_group {self.n_group} equal '
-                f'groups (two experts or more each) of the '
-                f'{self.n_experts} experts, by sigmoid scores')
+                f'groups (two experts or more each, top_k {self.top_k} '
+                f'or more in the kept ones) of the {self.n_experts} '
+                f'experts, by sigmoid scores')
         act = ACTIVATIONS[self.activation]
         gated = self.expert_form == 'gated'
         dim = x.shape[-1]
@@ -289,39 +385,48 @@ class SparseExperts(nn.Module):
                                     dtype=self.dtype,
                                     name='latent_down')(flat)
 
+        # Few enough rows that every hit expert can take them all behind
+        # its weights' DMA: the call's own shape decides.
+        by_rule = self.dense_tokens is None
+        bound = HIT_LIST_ROWS if by_rule else self.dense_tokens
+        hit_route = n <= bound
+        select = select_form() if hit_route else 'sort'
         with device_scope('lm.moe_route'):
             scores = jnp.dot(
                 flat.astype(jnp.float32), router.astype(jnp.float32),
                 precision=lax.Precision.HIGHEST)
-            if picked_softmax:
-                top, picked = lax.top_k(scores, k)               # (n, k)
-                gates = jax.nn.softmax(top, axis=-1)
-            else:
+            if not picked_softmax:
                 scores = jax.nn.sigmoid(scores)
-                choice = scores if bias is None else scores + bias
+            # (the picked logits' softmax takes no bias: refused above)
+            choice = scores if bias is None else scores + bias
+            if select == 'threshold':
+                picked, table, counts, group_rows = self._threshold_route(
+                    scores, choice, lo, hi)
+            else:
                 if grouped:
                     choice, group_rows = self._kept_groups(choice, lo, hi)
-                _, picked = lax.top_k(choice, k)
-                gates = jnp.take_along_axis(scores, picked, axis=-1)
-                if self.norm_topk:
-                    gates = gates / jnp.sum(gates, -1, keepdims=True)
-                gates = gates * self.scaling
-            expert = picked.reshape(-1)                          # (n·k,)
-            counts = jnp.zeros((self.n_experts,), jnp.int32).at[
-                expert].add(1)
-            # Few enough rows that every hit expert can take them all
-            # behind its weights' DMA: the call's own shape decides.
-            by_rule = self.dense_tokens is None
-            bound = HIT_LIST_ROWS if by_rule else self.dense_tokens
-            hit_route = n <= bound
-            if not hit_route:
-                mine = (expert >= lo) & (expert < hi)
-                # Rows of experts held elsewhere sort behind the last
-                # group and are masked out of the combine.
-                order = jnp.argsort(jnp.where(mine, expert - lo, held),
-                                    stable=True)
-                rows = tokens[order // k]                       # (n·k, wide)
-                sizes = lax.dynamic_slice_in_dim(counts, lo, held)
+                top, picked = lax.top_k(choice, k)               # (n, k)
+                if picked_softmax:
+                    gates = jax.nn.softmax(top, axis=-1)
+                else:
+                    gates = jnp.take_along_axis(scores, picked, axis=-1)
+                    if self.norm_topk:
+                        gates = gates / jnp.sum(gates, -1, keepdims=True)
+                    gates = gates * self.scaling
+                expert = picked.reshape(-1)                      # (n·k,)
+                counts = jnp.zeros((self.n_experts,), jnp.int32).at[
+                    expert].add(1)
+                if hit_route:
+                    table = jnp.zeros((n, self.n_experts), jnp.float32).at[
+                        jnp.arange(n)[:, None], picked].set(gates)
+                else:
+                    mine = (expert >= lo) & (expert < hi)
+                    # Rows of experts held elsewhere sort behind the last
+                    # group and are masked out of the combine.
+                    order = jnp.argsort(
+                        jnp.where(mine, expert - lo, held), stable=True)
+                    rows = tokens[order // k]                   # (n·k, wide)
+                    sizes = lax.dynamic_slice_in_dim(counts, lo, held)
         # Counters for a caller that makes the collection mutable (a
         # no-op otherwise): this call's tokens per expert and picks.
         self.sow('counters', 'expert_tokens', counts,
@@ -338,12 +443,12 @@ class SparseExperts(nn.Module):
         tile = hidden_tile(wide, self.hidden, 2 + gated,
                            w_up.dtype.itemsize) if hit_route else None
         _ROUTE_TRACES.note({'route': 'hit_list' if hit_route else 'sorted',
-                            'n': n, 'bound': bound,
+                            'select': select, 'n': n, 'bound': bound,
                             'bound_by': 'rule' if by_rule else 'caller',
                             'tile': tile})
         if hit_route:
-            y = self._hit_list(tokens, picked, gates, counts, w_gate, w_up,
-                               w_down, act, lo, hi)
+            y = self._hit_list(tokens, table, counts, w_gate, w_up, w_down,
+                               act, lo, hi)
         else:
             with device_scope('lm.moe_experts'):
                 def grouped(a, w):

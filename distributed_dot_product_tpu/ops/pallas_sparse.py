@@ -54,7 +54,10 @@ step, rows on sublanes and blocks on lanes, with no sort in it:
   is :func:`sorted_picks`' on every input;
 - with ``c_b`` the picks at or below block ``b`` (two running counts,
   each a 128-lane tile's product with a triangle of ones), the ``j``-th
-  pick is ``#{b : c_b <= j}``: ascending as it is made.
+  pick is ``#{b : c_b <= j}``: ascending as it is made;
+- asked for the MASK too (``mask=True``: a sparse-expert layer's router,
+  ``models/moe.py``), the same program writes ``(rows, n)`` true at the
+  picks — above ``thr``, or equal to it among the first ``k - #above``.
 
 :func:`sorted_picks` is ``lax.top_k`` and a sort of its indices — two
 full sorts a row as XLA lowers them for a TPU: the tests' oracle, and
@@ -73,7 +76,7 @@ from jax.experimental.pallas import tpu as pltpu
 from distributed_dot_product_tpu.ops.kernel_call import kernel_call
 
 __all__ = ['sparse_decode', 'sparse_decode_reference', 'picks_group',
-           'threshold_picks', 'sorted_picks']
+           'threshold_picks', 'sorted_picks', 'order_image']
 
 _NEG_BIG = -1e30
 _LANES = 128
@@ -318,10 +321,18 @@ def sorted_picks(scores, k):
     return jnp.sort(lax.top_k(scores, k)[1].astype(jnp.int32), axis=-1)
 
 
-def _pick_kernel(s_ref, o_ref, *, k, n):
+def order_image(scores):
+    """The order-preserving int32 image of float32 ``scores``: the bits,
+    with the magnitude flipped where the sign is set, so that integer
+    compares give XLA's total order (``-inf < -1.0 < -0.0 < +0.0 <
+    +inf``), the one ``lax.top_k`` ranks by."""
+    bits = lax.bitcast_convert_type(scores, jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def _pick_kernel(s_ref, o_ref, m_ref=None, *, k, n):
     rows, width = s_ref.shape
-    bits = lax.bitcast_convert_type(s_ref[...], jnp.int32)
-    key = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    key = order_image(s_ref[...])
     # (a column past the row — the block overhangs the array — is below
     # every score, and a higher index than any that ties with it)
     col = lax.broadcasted_iota(jnp.int32, (rows, width), 1)
@@ -360,6 +371,12 @@ def _pick_kernel(s_ref, o_ref, *, k, n):
     picked = jnp.concatenate(
         [a + jnp.minimum(e, k - n_above) for a, e in zip(above, equal)],
         axis=-1)                                        # c_b, (rows, width)
+    if m_ref is not None:
+        # The picks as a mask: what lies above thr and, of what ties
+        # with it, the first ``k - #above`` along the row.
+        tied = jnp.concatenate([e <= k - n_above for e in equal], axis=-1)
+        m_ref[...] = ((key > thr) | ((key == thr) & tied)).astype(
+            jnp.int32)
 
     slot = lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
 
@@ -371,10 +388,12 @@ def _pick_kernel(s_ref, o_ref, *, k, n):
                                jnp.zeros(o_ref.shape, jnp.int32))
 
 
-def threshold_picks(scores, k, *, interpret=None):
+def threshold_picks(scores, k, *, mask=False, interpret=None):
     """:func:`sorted_picks` with no sort (module docstring): ``scores
     (…, n) float32`` to ``(…, k) int32``, ``k <= n``. Exact: the same
-    entries on every input."""
+    entries on every input. With ``mask`` also the picks as ``(…, n)
+    bool``, true at a row's ``k`` picked entries, from the same
+    program."""
     *lead, n = scores.shape
     if scores.dtype != jnp.float32 or not 0 < k <= n:
         raise ValueError(f'threshold_picks takes float32 scores and 0 < k '
@@ -385,14 +404,25 @@ def threshold_picks(scores, k, *, interpret=None):
     width, slots = (-(-x // _LANES) * _LANES for x in (n, k))
     tile = min(_PICK_ROWS, -(-rows // 8) * 8,
                max(8, _PICK_CELLS // width // 8 * 8))
+    out_specs = pl.BlockSpec((tile, slots), lambda r: (r, 0))
+    out_shape = jax.ShapeDtypeStruct((rows, slots), jnp.int32)
+    if mask:
+        out_specs = [out_specs,
+                     pl.BlockSpec((tile, width), lambda r: (r, 0))]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((rows, width), jnp.int32)]
     out = kernel_call(
         functools.partial(_pick_kernel, k=k, n=n),
         grid=(pl.cdiv(rows, tile),),
         in_specs=[pl.BlockSpec((tile, width), lambda r: (r, 0))],
-        out_specs=pl.BlockSpec((tile, slots), lambda r: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, slots), jnp.int32),
+        out_specs=out_specs,
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel',)),
         interpret=interpret,
         name='sparse_pick')(scores.reshape(rows, n))
-    return out[:, :k].reshape(*lead, k)
+    if not mask:
+        return out[:, :k].reshape(*lead, k)
+    picks, picked = out
+    return (picks[:, :k].reshape(*lead, k),
+            (picked[:, :n] != 0).reshape(*lead, n))

@@ -104,8 +104,10 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     assert ling['ling_caches'] == ['StateCache', 'LatentCache']
     assert ling['ling_mla_decode'] == ['xla:latent']
     assert [f['form'] for f in ling['ling_delta_step']] == ['xla']
-    assert [r['route'] for r in ling['ling_expert_routes']] == 2 * [
-        'hit_list']
+    assert [(r['route'], r['select'])
+            for r in ling['ling_expert_routes']] == 2 * [
+                ('hit_list', 'sort')]
+    assert ling['checks']['ling.picks_are_top_k_s'] is True
     assert np.asarray(ling['ling_group_rows']).shape == (3, 2)
     assert ling['checks']['ling.restored_request_agrees'] is True
     assert ling['checks']['ling.latent_rows_grew'] is True

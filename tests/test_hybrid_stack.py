@@ -389,7 +389,19 @@ PARENT_LOWERED.update({
     'sala.decode':
         'ff86461db157c36d6d46cb3c6a95423b30f337842b411a9eead072f00b161fdc',
 })
+# The commit before the hit-list route's choices stopped sorting on a TPU
+# (PR 49): the tenth cell's tiny preset, its prefill held to the SORTED
+# route as the cell's 4096-row chunk takes it and its step as built (the
+# hit list). Off the TPU both make their choices by ``lax.top_k`` as the
+# parent did, and on a TPU the sorted route does: the parent's text.
+PARENT_LOWERED.update({
+    'ling.prefill':
+        '2f87789b3fd68f201ab9d197b0bd8ec2003adc251522b7e879dcba9d80275fb1',
+    'ling.decode':
+        '74f022ef4fa7db0154742184af4b0a7d3598740cbf03d1f9b9726c61d6e4677c',
+})
 PRESETS = {'granite': ('tiny_granite', 'tiny-granite.decode'),
+           'ling': ('tiny_ling', 'tiny-ling.decode'),
            'solar': ('tiny_solar', 'tiny-solar.decode'),
            'sala': ('tiny_sala', 'tiny-sala.decode'),
            'xing4': ('tiny_latent', 'tiny-xing4.decode'),
@@ -438,7 +450,7 @@ def test_accepted_programs_lower_to_the_parents_text(what):
         assert _sha(jax.jit(jax.value_and_grad(loss)).lower(
             params, tok)) == PARENT_LOWERED[what]
         return
-    if name in ('xing4', 'command-a'):
+    if name in ('xing4', 'command-a') or what == 'ling.prefill':
         experts = {**model.block_kwargs['ffn_kwargs'], 'dense_tokens': 0}
         model = model.clone(block_kwargs={**model.block_kwargs,
                                           'ffn_kwargs': experts})
@@ -497,8 +509,8 @@ def test_the_dense_route_is_the_sorted_route(form, latent, held):
     dense = SparseExperts(**kw, dense_tokens=15)
     with expert_route_traces() as traces:
         got, dense_counts = dense.apply(params, x)
-    assert traces == [{'route': 'hit_list', 'n': 15, 'bound': 15,
-                       'bound_by': 'caller', 'tile': 12}]
+    assert traces == [{'route': 'hit_list', 'select': 'sort', 'n': 15,
+                       'bound': 15, 'bound_by': 'caller', 'tile': 12}]
     np.testing.assert_allclose(got, want, atol=TOL)
     np.testing.assert_array_equal(dense_counts, counts)
     if held:
@@ -531,8 +543,8 @@ def test_the_calls_rows_choose_the_route(dense_tokens, rows):
         got, got_counts = layer.apply(params, x)
     bound = HIT_LIST_ROWS if dense_tokens is None else dense_tokens
     assert traces == [{
-        'route': 'hit_list' if rows <= bound else 'sorted', 'n': rows,
-        'bound': bound,
+        'route': 'hit_list' if rows <= bound else 'sorted',
+        'select': 'sort', 'n': rows, 'bound': bound,
         'bound_by': 'rule' if dense_tokens is None else 'caller',
         'tile': hidden_tile(16, 256, 3, 4) if rows <= bound else None}]
     np.testing.assert_allclose(got, want, atol=TOL)

@@ -32,8 +32,9 @@ from distributed_dot_product_tpu.models.delta import (  # noqa: E402
 from distributed_dot_product_tpu.models.latent import (  # noqa: E402
     LatentAttention, init_latent_cache,
 )
+from distributed_dot_product_tpu.models import moe as moe_model  # noqa: E402
 from distributed_dot_product_tpu.models.moe import (  # noqa: E402
-    SparseExperts,
+    SparseExperts, expert_route_traces,
 )
 
 TINY = os.path.join(ROOT, 'benchmarks', 'tests', 'tiny_ling')
@@ -443,6 +444,154 @@ def test_ties_go_to_the_lower_index_and_a_token_may_pick_nothing_held():
     assert not np.any(np.asarray(y))
 
 
+def _levels(seed, rows, width, levels):
+    """Logits that take few values, so that experts (and groups' sums)
+    tie all over the row."""
+    return np.random.default_rng(seed).integers(
+        -levels, levels + 1, size=(rows, width)).astype(np.float32) / 2
+
+
+def _select_case(name):
+    """``(layer fields, router logits (rows, n_experts), bias | None)``
+    of a hit-list call: the six expert cells' widths, rows and k, and
+    the inputs on which a selection by compares could leave
+    ``lax.top_k``'s."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+
+    def normal(rows, width, scale=1.0):
+        return (rng.normal(size=(rows, width)) * scale).astype(np.float32)
+
+    def biased(width):
+        return (rng.normal(size=width) * 0.05).astype(np.float32)
+    ling = dict(n_experts=512, top_k=8, n_group=8, topk_group=4,
+                scaling=2.5)
+    if name == 'ties_across_the_kth_place':
+        return dict(n_experts=64, top_k=6), _levels(1, 12, 64, 2), None
+    if name == 'two_groups_tie_for_the_last_kept_place':
+        # groups 1, 2, 5, 6 hold the same two best; one of them is cut
+        logits = normal(12, 64, 0.1)
+        for g in (1, 2, 5, 6):
+            logits[:, 8 * g + 3], logits[:, 8 * g + 6] = 2.0, 1.5
+        logits[:, 0] = logits[:, 1] = 3.0
+        return (dict(n_experts=64, top_k=8, n_group=8, topk_group=4),
+                logits, np.zeros(64, np.float32))
+    if name == 'a_groups_two_best_are_equal':
+        logits = normal(12, 128)
+        for g in range(4):
+            logits[:, 32 * g + 5] = logits[:, 32 * g + 20] = 2.0 + g % 2
+        return (dict(n_experts=128, top_k=8, n_group=4, topk_group=2),
+                logits, np.zeros(128, np.float32))
+    if name == 'every_group_and_expert_ties':
+        return (dict(n_experts=64, top_k=6, n_group=4, topk_group=2),
+                np.zeros((12, 64), np.float32), np.zeros(64, np.float32))
+    if name == 'saturated_sigmoids':
+        # float32 sigmoid is exactly 1.0 from ~17 up: dozens an expert row
+        return ling, normal(96, 512, 20.0), np.zeros(512, np.float32)
+    if name == 'signed_zeros':
+        zeros = rng.choice(np.asarray([-0.0, 0.0, -1.0, 1.0], np.float32),
+                           size=(12, 72))
+        return (dict(n_experts=72, top_k=10, score='softmax_picked',
+                     router_bias=False), zeros, None)
+    if name == 'xing4_16_rows_of_64':
+        return (dict(n_experts=64, top_k=6, scaling=2.0),
+                normal(16, 64), biased(64))
+    if name == 'one_row_of_64':
+        return dict(n_experts=64, top_k=6), normal(1, 64), biased(64)
+    if name == 'granite_80_rows_of_72_softmax_picked':
+        return (dict(n_experts=72, top_k=10, score='softmax_picked',
+                     router_bias=False, experts_held=(18, 36)),
+                normal(80, 72, 3.0), None)
+    if name == 'command_a_12_rows_of_128':
+        return (dict(n_experts=128, top_k=8, router_bias=False,
+                     experts_held=(16, 32)), normal(12, 128), None)
+    if name == 'solar_128_rows_of_320':
+        return (dict(n_experts=320, top_k=8, experts_held=(40, 80)),
+                normal(128, 320), biased(320))
+    if name == 'nemotron_top_22_of_512':
+        return (dict(n_experts=512, top_k=22, norm_topk=False,
+                     experts_held=(128, 160)), normal(12, 512),
+                biased(512))
+    if name == 'ling_96_rows_of_512_grouped':
+        return ({**ling, 'experts_held': (0, 64)}, normal(96, 512),
+                biased(512))
+    if name == 'ling_holding_group_5':
+        return ({**ling, 'experts_held': (320, 384)}, _levels(2, 96, 512, 3),
+                biased(512))
+    if name == 'four_groups_at_the_rules_bound':
+        return (dict(n_experts=128, top_k=10, n_group=4, topk_group=2,
+                     experts_held=(32, 64)), normal(128, 128), biased(128))
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize('name', [
+    'ties_across_the_kth_place', 'two_groups_tie_for_the_last_kept_place',
+    'a_groups_two_best_are_equal', 'every_group_and_expert_ties',
+    'saturated_sigmoids', 'signed_zeros', 'xing4_16_rows_of_64',
+    'one_row_of_64', 'granite_80_rows_of_72_softmax_picked',
+    'command_a_12_rows_of_128', 'solar_128_rows_of_320',
+    'nemotron_top_22_of_512', 'ling_96_rows_of_512_grouped',
+    'ling_holding_group_5', 'four_groups_at_the_rules_bound'])
+def test_the_threshold_selection_picks_what_top_k_picks(name, monkeypatch):
+    """The hit-list route's choices as a TPU makes them (``select_form``
+    steered here: the program asks the backend; ``sparse_pick``
+    interpreted) against ``lax.top_k``'s: the picks the same SETS entry
+    for entry — ascending, where ``top_k``'s come by score —, the same
+    counts, ``group_rows`` and result, the gate table zero off the picks.
+    First the selection alone on the case's logits as they are (signed
+    zeros reach it), then the layer, whose router is made to give each
+    row its logits."""
+    fields, logits, bias = _select_case(name)
+    fields = {'hidden': 8, **fields}
+    layer = SparseExperts(**fields)
+    rows, width = logits.shape
+    k, lo, hi = (fields['top_k'],
+                 *(fields.get('experts_held') or (0, width)))
+    raw = scores = jnp.asarray(logits)
+    if fields.get('score') != 'softmax_picked':
+        scores = jax.nn.sigmoid(raw)
+    choice = kept = scores if bias is None else scores + bias
+    want_rows = None
+    if fields.get('n_group', 1) > 1:
+        kept, want_rows = layer._kept_groups(choice, lo, hi)
+    want = np.sort(jax.lax.top_k(kept, k)[1], -1)
+    picked, table, counts, group_rows = layer._threshold_route(
+        scores, choice, lo, hi)
+    np.testing.assert_array_equal(picked, want)
+    np.testing.assert_array_equal(counts, np.bincount(want.reshape(-1),
+                                                      minlength=width))
+    assert (group_rows is None) == (want_rows is None)
+    if want_rows is not None:
+        assert int(group_rows) == int(want_rows)
+    on = np.zeros((rows, width), bool)
+    np.put_along_axis(on, want, True, axis=-1)
+    assert np.all((np.asarray(table) != 0) == on)
+
+    dim = max(rows, 8)
+    x = jnp.eye(rows, dim, dtype=jnp.float32)
+    params = SparseExperts(**{**fields, 'experts_held': None}).init(
+        jax.random.key(0), x)
+    params['params']['router'] = jnp.zeros((dim, width)).at[:rows].set(raw)
+    if bias is not None:
+        params['params']['router_bias'] = jnp.asarray(bias)
+    params = _share(params, lo, hi)
+    assert moe_model.select_form() == 'sort'
+    (y, tokens), sown = layer.apply(params, x, mutable=['counters'])
+    monkeypatch.setattr(moe_model, 'select_form', lambda: 'threshold')
+    with expert_route_traces() as traces:
+        (got_y, got_tokens), got = layer.apply(params, x,
+                                               mutable=['counters'])
+    assert [(t['route'], t['select']) for t in traces] == [
+        ('hit_list', 'threshold')]
+    np.testing.assert_array_equal(
+        got['counters']['expert_picks'],
+        np.sort(sown['counters']['expert_picks'], -1))
+    np.testing.assert_array_equal(got_tokens, tokens)
+    if want_rows is not None:
+        assert int(got['counters']['group_rows']) == int(
+            sown['counters']['group_rows'])
+    np.testing.assert_allclose(got_y, y, atol=TOL)
+
+
 def test_the_group_shares_add_up_to_the_uncut_layer():
     """THE SHARE TEST: the parts all four one-group shares give, the
     shared expert counted once, add up to the uncut layer; the rows the
@@ -464,6 +613,7 @@ def test_the_group_shares_add_up_to_the_uncut_layer():
 
 @pytest.mark.parametrize('bad', [dict(n_group=3), dict(topk_group=5),
                                  dict(n_group=16, topk_group=4),
+                                 dict(top_k=9),
                                  dict(score='softmax_picked', scaling=1.0,
                                       router_bias=False)])
 def test_groups_that_do_not_divide_the_experts_are_refused(bad):

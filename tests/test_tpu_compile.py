@@ -614,7 +614,19 @@ def test_ling_prefill_chunk_writes_its_columns_in_place(chip, monkeypatch):
     with open(os.path.join(root, 'benchmarks', 'traffic',
                            'decode-32k-x96.json')) as f:
         traffic = json.load(f)
-    _latent_prefill_chunk(chip, driver, cfg, traffic, 1)
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
+    with expert_route_traces() as routes:
+        hlo = _latent_prefill_chunk(chip, driver, cfg, traffic, 1)
+    # The chunk's 4096 rows take the sorted route, whose picks stay
+    # ``lax.top_k``'s on a TPU too (their order is the order its k-way
+    # sum adds in): three ``top_k`` sorts a layer, as the parent's
+    # chunk holds, and no threshold program.
+    assert [(t['route'], t['select']) for t in routes] == 6 * [
+        ('sorted', 'sort')]
+    assert 'sparse_pick' not in hlo
+    assert len([line for line in hlo.splitlines()
+                if re.search(SORT, line) and 'lm.moe_route' in line
+                and '/top_k' in line]) == 6 * 3
 
 
 def test_cache_sized_moves_reads_hlo():
@@ -748,8 +760,9 @@ def test_latent_decode_step_moves_no_cache_and_no_expert_stack(
         compiled = step.lower(*shapes).compile()
     assert {(t['resolved'], t['cache']) for t in traces} == {
         ('kernel', 'stacked')}
-    assert routes == 2 * [{'route': 'hit_list', 'n': sessions,
-                           'bound': 128, 'bound_by': 'rule', 'tile': 512}]
+    assert routes == 2 * [{'route': 'hit_list', 'select': 'threshold',
+                           'n': sessions, 'bound': 128,
+                           'bound_by': 'rule', 'tile': 512}]
     hlo = compiled.as_text()
     assert hlo.count('mla_decode') and 'ragged-dot' not in hlo
     assert len(re.findall(
@@ -816,8 +829,9 @@ def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
     assert {tuple(t['step'].items()) for t in traces} == {
         (('heads', 8), ('block_k', 1024), ('bytes', 4 << 20))}
     # 12 rows: the held experts' hit list, by the rule, in every layer
-    assert routes == 4 * [{'route': 'hit_list', 'n': sessions,
-                           'bound': 128, 'bound_by': 'rule', 'tile': 512}]
+    assert routes == 4 * [{'route': 'hit_list', 'select': 'threshold',
+                           'n': sessions, 'bound': 128,
+                           'bound_by': 'rule', 'tile': 512}]
     hlo = compiled.as_text()
     assert hlo.count('flash_decode_ring') and 'ragged-dot' not in hlo
     assert len(re.findall(
@@ -892,8 +906,9 @@ def test_hybrid_stack_decode_step_aliases_every_state_and_fits(
         ('kernel', 'layer', {'heads': 2, 'block_k': 1024,
                              'bytes': 1 << 20})]
     # the driver's own bound, honoured as it was
-    assert routes == 5 * [{'route': 'hit_list', 'n': sessions,
-                           'bound': 64, 'bound_by': 'caller', 'tile': 896}]
+    assert routes == 5 * [{'route': 'hit_list', 'select': 'threshold',
+                           'n': sessions, 'bound': 64,
+                           'bound_by': 'caller', 'tile': 896}]
     hlo = compiled.as_text()
     # 48 tokens: every hit expert on every token, one kernel an expert
     # layer, no grouped matmul and no batched one over all held experts
@@ -981,9 +996,9 @@ def test_granite_decode_step_aliases_nine_states_and_fits(chip, monkeypatch):
         ('kernel', 'layer')]
     # 768 has no 512-column divisor: the 1 KB slab rule takes the whole
     # expert, 18.9 MB a grid step, and asks for its blocks' room.
-    assert routes == 10 * [{'route': 'hit_list', 'n': sessions,
-                            'bound': 128, 'bound_by': 'rule',
-                            'tile': 768}]
+    assert routes == 10 * [{'route': 'hit_list', 'select': 'threshold',
+                            'n': sessions, 'bound': 128,
+                            'bound_by': 'rule', 'tile': 768}]
     assert _vmem_limit(4096, 768, 3, 2) == 52 << 20
     hlo = compiled.as_text()
     assert 'flash_decode' in hlo and 'ragged-dot' not in hlo
@@ -1120,8 +1135,8 @@ def test_solar_decode_step_reads_three_states_once_and_fits(
         ('kernel', 'layer')]
     assert forms == 3 * [{'form': 'pallas', 'tile': 16, 'chunk': 64}]
     assert hidden_tile(4096, 1280, 3, 2) == 640
-    assert routes == 4 * [{'route': 'hit_list', 'n': sessions,
-                           'bound': 128, 'bound_by': 'rule',
+    assert routes == 4 * [{'route': 'hit_list', 'select': 'threshold',
+                           'n': sessions, 'bound': 128, 'bound_by': 'rule',
                            'tile': 640}]
     # three blocks of 5.24 MB, double-buffered, and the default on top
     assert _vmem_limit(4096, 640, 3, 2) == 46 << 20
@@ -1460,6 +1475,33 @@ def test_sparse_decode_kernel_compiles_for_v5e(chip, shape):
     assert mem.temp_size_in_bytes < 2 * kv * t_max * d * 2
 
 
+@pytest.mark.parametrize('shape', [
+    # (rows, experts, k): the six expert cells' hit-list calls, and the
+    # one-session step of ``chip_smoke.py``.
+    (96, 512, 8), (80, 72, 10), (48, 512, 22), (128, 320, 8),
+    (12, 128, 8), (16, 64, 4), (1, 512, 8)],
+    ids=['ling', 'granite', 'nemotron', 'solar', 'command-a', 'xing4',
+         'one_row'])
+def test_the_routers_pick_program_compiles_for_v5e(chip, shape):
+    """``ops/pallas_sparse.threshold_picks`` with its MASK — what a
+    hit-list expert layer picks by on a TPU since PR 49 — for a
+    described v5e: ONE ``sparse_pick`` call that gives the ``(rows, k)``
+    picks and the ``(rows, experts)`` mask, and no sort beside it."""
+    from distributed_dot_product_tpu.ops.pallas_sparse import (
+        threshold_picks,
+    )
+    rows, experts, k = shape
+    compiled = _compile(
+        chip, lambda s: threshold_picks(s, k, mask=True, interpret=False),
+        jnp.zeros((rows, experts), jnp.float32))
+    hlo = compiled.as_text()
+    assert _mosaic_calls(hlo) == ['sparse_pick']
+    assert not re.findall(SORT, hlo)
+    picks, mask = compiled.out_info
+    assert (picks.shape, picks.dtype) == ((rows, k), jnp.int32)
+    assert (mask.shape, mask.dtype) == ((rows, experts), jnp.bool_)
+
+
 def _sala_cell():
     """``minicpm-sala.decode-64k`` as its driver builds it: ``(driver,
     configuration, traffic, model, abstract parameters)``."""
@@ -1594,10 +1636,14 @@ def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
     and nothing as large as one layer's 96 states (201 MB) is copied,
     sliced or written back, no temporary is that large; every expert
     layer's 96-row call is ONE ``moe_hit_experts`` kernel by the rule's
-    bound and no grouped matmul; arguments + temporaries stay under 14.0
-    GiB with the snapshot counted. The reset between requests writes all
-    six states over in place, under its scope's name, with no temporary,
-    and sets the latent lengths back."""
+    bound and no grouped matmul, and its choices — a group's two best,
+    the kept groups, the 8 picks — hold no sort (since PR 49: the parent
+    had three ``top_k`` a layer): ONE ``sparse_pick`` call a layer, the
+    gate table and the counts from its mask, and the only scatter left
+    under ``lm.moe_route`` is the hit list's; arguments + temporaries
+    stay under 14.0 GiB with the snapshot counted. The reset between
+    requests writes all six states over in place, under its scope's
+    name, with no temporary, and sets the latent lengths back."""
     import json
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -1643,16 +1689,22 @@ def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
             for t in traces] == [('kernel', 'latent', 1536 * 576 * 2)]
     assert forms == 6 * [{'form': 'pallas', 'tile': 16, 'chunk': 64}]
     tile = hidden_tile(2560, 768, 3, 2)
-    assert routes == 6 * [{'route': 'hit_list', 'n': sessions,
-                           'bound': 128, 'bound_by': 'rule',
+    assert routes == 6 * [{'route': 'hit_list', 'select': 'threshold',
+                           'n': sessions, 'bound': 128, 'bound_by': 'rule',
                            'tile': tile}]
     hlo = compiled.as_text()
     assert 'ragged-dot' not in hlo
     for kernel, calls in (('moe_hit_experts', 6), ('delta_step', 6),
-                          ('mla_decode', 1)):
+                          ('mla_decode', 1), ('sparse_pick', 6)):
         assert len(re.findall(
             r'custom_call_target="tpu_custom_call"[^\n]*' + kernel,
             hlo)) == calls, kernel
+    assert not re.findall(SORT, hlo)
+    routing = [line for line in hlo.splitlines() if 'lm.moe_route' in line]
+    assert routing and not [line for line in routing
+                            if re.search(r'top-?k', line, re.I)]
+    assert len([line for line in routing
+                if re.search(r'\bscatter\(', line)]) == 6
     state_bytes = sessions * 32 * 128 * 128 * 4
     assert _cache_sized_moves(hlo, state_bytes) == []
     assert _relayouts(hlo, t_max, sessions * 576 * t_max * 2) == []
