@@ -83,7 +83,14 @@ FULL = dict(
     # head sizes): a LatentCache beside a StateCache in one list
     ling=dict(dim=1024, heads=8, head_dim=128, kv_rank=512, nope=128,
               rope=64, v=128, experts=32, top_k=4, groups=4, kept=2,
-              held=(8, 16), expert_hidden=256))
+              held=(8, 16), expert_hidden=256),
+    # generate_conv: a gated short-convolution layer under a dense MLP,
+    # one under experts with no shared one, and a GQA layer at 64-wide
+    # heads on the PACKED slab (the kinds of
+    # benchmarks/configs/lfm2-8b-a1b-serve at its head size): a
+    # StateCache that is a window alone beside a PackedCache
+    conv=dict(dim=1024, heads=16, kv_heads=4, hidden=2048, experts=8,
+              top_k=2, expert_hidden=256))
 TINY = dict(
     vocab=128, dim=64, heads=2, layers=1,
     train_t=128, ref_t=32,
@@ -105,6 +112,8 @@ TINY = dict(
                             dense_len=16)),
     ling=dict(dim=64, heads=4, head_dim=16, kv_rank=32, nope=16, rope=16,
               v=16, experts=8, top_k=2, groups=4, kept=2, held=(2, 4),
+              expert_hidden=32),
+    conv=dict(dim=128, heads=2, kv_heads=1, hidden=96, experts=4, top_k=2,
               expert_hidden=32))
 
 # bf16 tolerance, relative to the compared tensor's own scale: two paths
@@ -784,6 +793,118 @@ def phase_generate_sparse(progs, cfg, seed):
         }}
 
 
+def conv_lm(cfg, **attn_kwargs):
+    """A gated short-convolution layer under a dense gated MLP, one
+    under experts, and a GQA layer at 64-wide heads with per-head q / k
+    norms on the packed slab under experts (``cfg['conv']``)."""
+    import jax.numpy as jnp
+
+    from distributed_dot_product_tpu import TransformerLM
+    c = cfg['conv']
+    return TransformerLM(
+        vocab_size=cfg['vocab'], dim=c['dim'], num_heads=c['heads'],
+        n_layers=3, dtype=jnp.bfloat16, scan_layers=False,
+        attn_kwargs={'num_kv_heads': c['kv_heads'], 'qk_norm': True,
+                     'kv_packed': True, **attn_kwargs},
+        block_kwargs={'norm': 'rmsnorm', 'ssm_kwargs': {'taps': 3},
+                      'ffn': 'experts', 'ffn_kwargs': {
+                          'n_experts': c['experts'], 'top_k': c['top_k'],
+                          'hidden': c['expert_hidden'], 'n_shared': 0}},
+        layer_kinds={
+            'D': {'mixer': 'conv', 'ffn': 'gated',
+                  'ffn_kwargs': {'hidden': c['hidden']}},
+            'C': {'mixer': 'conv'}, 'A': {'mixer': 'attention'}},
+        layer_pattern=('D', 'C', 'A'))
+
+
+def phase_generate_conv(progs, cfg, seed):
+    """The window-only recurrent layer and the packed slab end to end:
+    a prompt prefilled, the windows SNAPSHOTTED at its end, a greedy
+    request, the windows restored and the slab's length set back, and
+    the request again — which must read what the first did, bit for bit
+    — with the attention layer's step on the kernel over the PACKED
+    cache (``decode_impl_traces()``: its form and bytes a token) and the
+    conv mixers' form (``conv_step_traces()``) and the expert calls'
+    routes printed beside it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_dot_product_tpu.models.decode import (
+        decode_impl_traces, restore_states, snapshot_states,
+    )
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
+    from distributed_dot_product_tpu.models.shortconv import (
+        conv_step_traces,
+    )
+    model = conv_lm(cfg)
+    n, steps, t_max = cfg['prompt'], cfg['new_tokens'], cfg['gen_t_max']
+    params = {'params': model.init(
+        jax.random.key(seed + 11), jnp.zeros((1, 16), 'int32'))['params']}
+    prompt = jax.random.randint(jax.random.key(seed + 2), (1, n), 0,
+                                cfg['vocab'], dtype='int32')
+    prefill = jax.jit(lambda p, t, c: model.apply(p, t, c,
+                                                  method='prefill'))
+    step = jax.jit(lambda p, t, c: model.apply(p, t, c, method='decode'),
+                   donate_argnums=(2,))
+
+    def reset(caches, taken):
+        return [c._replace(length=jnp.asarray(n, jnp.int32))
+                if hasattr(c, 'length') else c
+                for c in restore_states(caches, taken)]
+    reset = jax.jit(reset, donate_argnums=(0,))
+    caches = model.make_decode_caches(1, t_max)
+    kinds = [type(c).__name__ for c in caches]
+    progs.compile('conv.prefill', prefill, params, prompt, caches,
+                  pallas=True)
+    with decode_impl_traces() as traces, conv_step_traces() as forms, \
+            expert_route_traces() as routes:
+        progs.compile('conv.decode', step, params, prompt[:, :1], caches,
+                      pallas=True)
+    caches, logits = prefill(params, prompt, caches)
+    first = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+    taken = snapshot_states(caches)
+
+    def request(caches):
+        tok, out = first, []
+        for _ in range(steps):
+            caches, logits = step(params, tok, caches)
+            out.append(np.asarray(logits[:, -1], np.float32))
+            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        return caches, np.stack(out)
+    caches, once = request(caches)
+    moved = float(np.max(np.abs(
+        np.asarray(caches[0].conv, np.float32)
+        - np.asarray(taken[0].conv, np.float32))))
+    caches, again = request(reset(caches, taken))
+    slab = [{k: t[k] for k in ('resolved', 'cache', 'token_bytes', 'tail')}
+            for t in traces]
+    return {
+        'conv_caches': kinds,
+        'conv_slab_step': slab,
+        'conv_step_forms': forms,
+        'conv_expert_routes': routes,
+        'conv_window_moved_by_a_request': moved,
+        'conv_logits_max_abs': float(np.max(np.abs(once))),
+        'checks': {
+            'conv.cache_kinds': kinds == ['StateCache', 'StateCache',
+                                          'PackedCache'],
+            'conv.slab_resolved_kernel': [
+                (t['resolved'], t['cache'], t['token_bytes'])
+                for t in slab] == [('kernel', 'packed', 256)],
+            'conv.steps_are_the_shift': forms == 2 * [
+                {'form': 'shift', 'taps': 3,
+                 'channels': cfg['conv']['dim']}],
+            'conv.expert_calls_by_the_rule': [
+                (r['route'], r['bound_by']) for r in routes] == 2 * [
+                    ('hit_list', 'rule')],
+            'conv.logits_finite': bool(np.all(np.isfinite(once))),
+            'conv.a_request_moves_the_window': moved > 0,
+            'conv.restored_request_agrees': bool(
+                np.array_equal(once, again)),
+        }}
+
+
 # -- one chip: serve -----------------------------------------------------
 
 def ling_lm(cfg, **attn_kwargs):
@@ -1323,6 +1444,8 @@ def main(argv=None):
                run_phase('generate_sparse', phase_generate_sparse, cfg,
                          args.seed),
                run_phase('generate_ling', phase_generate_ling, cfg,
+                         args.seed),
+               run_phase('generate_conv', phase_generate_conv, cfg,
                          args.seed),
                run_phase('serve', phase_serve, cfg, args.seed, out_dir)]
     else:

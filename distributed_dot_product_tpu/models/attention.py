@@ -184,6 +184,14 @@ class DistributedDotProductAttn(nn.Module):
     # slab stores all t_max and only skips reading them. Where t_max is
     # no more than this the slab is the smaller one and is built.
     ring_cache: Optional[int] = None
+    # The decode cache of heads NARROWER than a lane tile as ONE slab of
+    # keys and values side by side (models.decode.PackedCache: at 64-wide
+    # heads a 128-lane row a token a KV head, nothing padded, where the
+    # two buffers of a DecodeCache are each stored and streamed at 128
+    # lanes). Keys and values of one width whose double is whole lane
+    # tiles; no int8 mirror, ring or sparse cache beside it. False
+    # builds the cache this module always built.
+    kv_packed: bool = False
     # Decode-step implementation: None/'auto' picks the fused Pallas
     # decode kernel (in-place aliased cache append + split-K masked
     # attention, ops/pallas_decode.py) on TPU and the portable XLA
@@ -752,12 +760,26 @@ class DistributedDotProductAttn(nn.Module):
         under the K-first convention). Plain Python (reads constructor
         fields only), so no ``apply`` is needed."""
         from distributed_dot_product_tpu.models.decode import (
-            init_cache, init_ring_cache, init_sparse_cache,
+            init_cache, init_packed_cache, init_ring_cache,
+            init_sparse_cache,
         )
         kv_heads = (self.num_kv_heads if self.num_kv_heads is not None
                     else self.num_heads)
         value_dim = (self.value_dim if self.value_dim is not None
                      else self.key_dim)
+        if self.kv_packed:
+            head_dim = self.key_dim // self.num_heads
+            if (value_dim != self.key_dim or (2 * head_dim) % 128
+                    or self.qk_quant is not None or self.sparse is not None
+                    or self.ring_cache is not None):
+                raise ValueError(
+                    f'kv_packed keeps keys and values of ONE width '
+                    f'({head_dim} and {value_dim // self.num_heads} here) '
+                    f'side by side in whole lane tiles, with no int8 '
+                    f'mirror, ring or sparse cache')
+            return init_packed_cache(
+                batch, kv_heads, t_max, head_dim,
+                dtype=dtype or self.dtype or jnp.float32)
         if self.sparse is not None:
             spec = SparseSpec(**dict(self.sparse))
             if t_max % spec.block:
@@ -842,7 +864,8 @@ class DistributedDotProductAttn(nn.Module):
         appended (rows attend their own columns). Returns
         ``(cache, out)``."""
         from distributed_dot_product_tpu.models.decode import (
-            RingCache, append_kv, ring_append, ring_window,
+            PackedCache, RingCache, append_kv, packed_append,
+            packed_views, ring_append, ring_window,
         )
         if self._sparse is not None:
             return self._sparse_prefill(keys, queries, values, cache,
@@ -869,6 +892,19 @@ class DistributedDotProductAttn(nn.Module):
                 return (ring_append(cache, queries, values),
                         self._merge_decode_heads(out, gate))
             start = cache.length
+            if isinstance(cache, PackedCache):
+                # The chunk over the slab's two halves as buffers of
+                # their own: the flash forward at the head's own width.
+                if (segment_ids is not None or self.qk_quant is not None):
+                    raise ValueError('a packed cache is prefilled with no '
+                                     'segment_ids and no qk_quant')
+                cache = packed_append(cache, queries, values)
+                k_all, v_all = packed_views(cache)
+                out = flash_attention(
+                    keys, k_all, v_all, causal=True, causal_offset=start,
+                    scale=self._scale, window=self.window,
+                    alibi_slopes=self.alibi_slopes)
+                return cache, self._merge_decode_heads(out, gate)
             cache = append_kv(cache, queries, values)
             seg_pair = None
             if segment_ids is not None:

@@ -65,7 +65,8 @@ __all__ = ['DecodeCache', 'init_cache', 'append_kv', 'append_kv_sharded',
            'ring_append', 'ring_window', 'insert_session',
            'StateCache', 'snapshot_states', 'restore_states',
            'SparseCache', 'init_sparse_cache', 'sparse_decode_traces',
-           'LatentCache',
+           'LatentCache', 'PackedCache', 'init_packed_cache',
+           'packed_append', 'packed_views',
            'PagedDecodeCache', 'PagePool', 'PageChecksums',
            'ShardedPageTable', 'init_sharded_paged_cache',
            'init_paged_cache', 'paged_gather', 'paged_gather_mirror',
@@ -239,12 +240,74 @@ class StateCache(NamedTuple):
     """A recurrent layer's cache, of FIXED size: ``state (B, heads,
     head_dim, N)`` (float32 unless the mixer says otherwise) is what the
     layer remembers of every position so far, ``conv (B, K − 1,
-    channels)`` the convolution's last inputs. A step OVERWRITES both,
+    channels)`` the convolution's last inputs. Either may be EMPTY: a
+    Lightning layer has no convolution (``conv`` of no rows), a gated
+    short convolution no recurrence (``state (B, 0, 0, 0)``, of no
+    elements: its window is all it remembers). A step OVERWRITES both,
     so nothing of it rewinds by a length: a serving loop that sets a
     request back to its prompt's end puts both back from a copy taken
     there (:func:`snapshot_states` / :func:`restore_states`)."""
     state: jax.Array
     conv: jax.Array
+
+
+class PackedCache(NamedTuple):
+    """A slab cache for heads NARROWER than a lane tile: ``kv (B, H_kv,
+    t_max, 2·d)`` holds a token's key (as scored: normed and rotated) in
+    lanes ``[0, d)`` and its value in lanes ``[d, 2·d)`` of ONE row;
+    ``length`` is the scalar clock of a :class:`DecodeCache`. At ``d =
+    64`` a row is one 128-lane tile with nothing padded — ``2 · 64``
+    values a token a KV head in HBM, where a ``DecodeCache``'s two
+    ``(…, t_max, 64)`` buffers are each tiled to 128 lanes and a decode
+    step streams twice the bytes. A step is ``decode_step``'s
+    (``flash_decode``'s packed mode: the query zero-extended over the
+    value lanes, a resident block in both products, the output the value
+    lanes); a length set back rewinds it."""
+    kv: jax.Array
+    length: jax.Array
+
+    @property
+    def t_max(self):
+        return self.kv.shape[-2]
+
+    @property
+    def head_dim(self):
+        return self.kv.shape[-1] // 2
+
+
+def init_packed_cache(batch, kv_heads, t_max, head_dim,
+                      dtype=jnp.bfloat16):
+    """Zero :class:`PackedCache` for ``t_max`` positions of ``kv_heads``
+    heads ``head_dim`` wide (keys and values alike)."""
+    return PackedCache(
+        kv=jnp.zeros((batch, kv_heads, t_max, 2 * head_dim), dtype),
+        length=jnp.zeros((), jnp.int32))
+
+
+def packed_append(cache: PackedCache, k_new, v_new) -> PackedCache:
+    """:func:`append_kv` on a :class:`PackedCache`: ``k_new`` / ``v_new
+    (B, H_kv, n, d)`` go to the two halves of rows ``length … length + n
+    − 1``. Past ``t_max`` nothing is written while the length still
+    advances (the traced guard of :func:`append_kv`)."""
+    new = jnp.concatenate([k_new, v_new], axis=-1).astype(cache.kv.dtype)
+    n = new.shape[-2]
+    if n > cache.t_max:
+        raise ValueError(f'appending {n} positions to a t_max='
+                         f'{cache.t_max} cache')
+    zero = jnp.zeros((), jnp.int32)
+    idx = (zero, zero, cache.length, zero)
+    cur = lax.dynamic_slice(cache.kv, idx, new.shape)
+    kv = lax.dynamic_update_slice(
+        cache.kv, jnp.where(cache.length + n > cache.t_max, cur, new), idx)
+    return PackedCache(kv=kv, length=cache.length + n)
+
+
+def packed_views(cache: PackedCache):
+    """``(k, v)``, each ``(B, H_kv, t_max, d)``: the two halves as
+    buffers of their own (copies: what prefill's flash forward and the
+    XLA step read; the decode kernel reads the packed rows)."""
+    d = cache.head_dim
+    return cache.kv[..., :d], cache.kv[..., d:]
 
 
 class LatentCache(NamedTuple):
@@ -303,9 +366,10 @@ def restore_states(caches, snapshot):
 
 
 def insert_session(cache, session, one):
-    """``cache`` (a :class:`DecodeCache`, :class:`RingCache`,
-    :class:`SparseCache`, :class:`LatentCache` or :class:`StateCache` of
-    a serving batch; None, a layer without a mixer, passes through) with
+    """``cache`` (a :class:`DecodeCache`, :class:`PackedCache`,
+    :class:`RingCache`, :class:`SparseCache`, :class:`LatentCache` or
+    :class:`StateCache` of a serving batch; None, a layer without a
+    mixer, passes through) with
     session ``session`` replaced by the
     single session ``one`` holds — a prompt prefilled alone, then put in
     its slot. The batch shares one clock, so every session put in must
@@ -333,6 +397,10 @@ def insert_session(cache, session, one):
                          'with an int8 mirror is not covered')
     zero = jnp.zeros((), jnp.int32)
     at = (jnp.asarray(session, jnp.int32), zero, zero, zero)
+    if isinstance(cache, PackedCache):
+        return PackedCache(
+            kv=lax.dynamic_update_slice(cache.kv, one.kv, at),
+            length=one.length)
     more = {}
     if isinstance(cache, SparseCache):
         more['pooled'] = lax.dynamic_update_slice(cache.pooled,
@@ -2039,13 +2107,16 @@ _IMPL_TRACES = TraceSinks()
 def decode_impl_traces():
     """Collect what :func:`decode_step` resolves ``impl`` to while the
     block runs: one dict ``{'requested', 'resolved', 'reason',
-    'cache', 'step', 'tail'}`` per TRACE (= per compiled step; ``reason``
+    'cache', 'step', 'tail', 'token_bytes'}`` per TRACE (= per compiled
+    step; ``reason``
     names why ``'auto'`` fell back to ``'xla'``, else None; ``cache`` is
     ``'stacked'`` where the step addressed a layer-stacked buffer by
     ``layer`` — a scanned stack's in-place loop — and ``'layer'`` where
     it was handed one layer's buffers, so a return to slicing the stack
-    per layer shows here, and ``'ring'`` for a window layer's
-    :class:`RingCache`, whose kernel step is the ring mode; ``step`` is
+    per layer shows here, ``'ring'`` for a window layer's
+    :class:`RingCache`, whose kernel step is the ring mode, and
+    ``'packed'`` for a :class:`PackedCache`, keys and values in one
+    unpadded row; ``step`` is
     the kernel's grid step,
     ``{'heads', 'block_k', 'bytes'}`` — KV heads and cache rows of one
     step and the cache bytes it streams, as
@@ -2053,7 +2124,11 @@ def decode_impl_traces():
     shapes — or None off the kernel; ``tail`` is the rows the kernel
     moves of the split that holds a slot's last valid column where no
     more than those are filled of it, by the same function — None where
-    that split is always moved whole, and off the kernel). ``'auto'``
+    that split is always moved whole, and off the kernel; ``token_bytes``
+    the bytes of keys and values one token of one KV head costs the
+    step's stream — lane padding counted, so 512 for two bfloat16
+    buffers of 64-wide heads and 256 for the packed slab — None off the
+    kernel). ``'auto'``
     takes the XLA formulation off-TPU and wherever the kernel does not
     cover the call, so a smoke or benchmark run wraps the compile of its
     step in this and asserts the path the program holds instead of
@@ -2101,6 +2176,8 @@ def _kernel_geometry(q, cache, qk_quant):
         geom = flash_decode_geometry(
             q, cache.k_pool, cache.v_pool, page_table=cache.page_table,
             qk_quant=qk_quant)
+    elif isinstance(cache, PackedCache):
+        geom = flash_decode_geometry(q, cache.kv)
     else:
         geom = flash_decode_geometry(q, cache.k, cache.v,
                                      qk_quant=qk_quant,
@@ -2147,6 +2224,7 @@ def _resolve_decode_impl(impl, cache, n, segment_ids, qk_quant,
     if resolved == 'kernel' and q is not None and _IMPL_TRACES:
         geom = _kernel_geometry(q, cache, qk_quant)
     kind = ('ring' if isinstance(cache, RingCache)
+            else 'packed' if isinstance(cache, PackedCache)
             else 'stacked' if stacked else 'layer')
     record_decode_impl(impl, resolved, reason, kind, geom)
     return resolved
@@ -2160,7 +2238,9 @@ def record_decode_impl(requested, resolved, reason, cache, geom=None):
                        'resolved': resolved, 'reason': reason,
                        'cache': cache,
                        'step': geom.step() if geom else None,
-                       'tail': geom.tail if geom else None})
+                       'tail': geom.tail if geom else None,
+                       'token_bytes': (geom.bytes // (
+                           geom.heads * geom.block_k) if geom else None)})
 
 
 def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
@@ -2232,6 +2312,18 @@ def decode_step(q, cache: DecodeCache, k_new, v_new, *, slot_mask=None,
                              f'window and impl alone, got {extra}')
         return _ring_step(q, cache, k_new, v_new, scale=scale,
                           window=window, impl=impl, interpret=interpret)
+    if isinstance(cache, PackedCache):
+        given = dict(slot_mask=slot_mask, counts=counts,
+                     segment_ids=segment_ids, seg_q=seg_q,
+                     qk_quant=qk_quant, axis_name=axis_name, layer=layer)
+        extra = sorted(k for k, v in given.items() if v is not None)
+        if extra:
+            raise ValueError(f'decode_step: a PackedCache step takes '
+                             f'scale, window, alibi_slopes and impl '
+                             f'alone, got {extra}')
+        return _packed_step(q, cache, k_new, v_new, scale=scale,
+                            window=window, alibi_slopes=alibi_slopes,
+                            impl=impl, interpret=interpret)
     paged = isinstance(cache, PagedDecodeCache)
     stack = None
     if layer is not None:
@@ -2511,6 +2603,38 @@ def _ring_step(q, cache: RingCache, k_new, v_new, *, scale, window, impl,
         q, k_new, v_new, cache.k, cache.v, col, col, ring_span=span,
         scale=scale, interpret=interpret)
     return RingCache(k=new_k, v=new_v, length=cache.length + 1), out
+
+
+def _packed_step(q, cache: PackedCache, k_new, v_new, *, scale, window,
+                 alibi_slopes, impl, interpret):
+    """:func:`decode_step` on a :class:`PackedCache`: the new rows go
+    to both halves of rows ``length …`` and the queries attend the
+    prefix and themselves. The kernel (``flash_decode``'s packed mode)
+    streams the one buffer; the XLA formulation (:func:`packed_append`,
+    then :func:`decode_attention` over the two halves) is its oracle and
+    what ``'auto'`` takes off the TPU."""
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        flash_decode,
+    )
+    b, _, n, d = q.shape
+    if d != cache.head_dim:
+        raise ValueError(f'queries {d} wide against a packed cache of '
+                         f'{cache.head_dim}-wide heads')
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
+    wide = jnp.concatenate([q, jnp.zeros_like(q)], axis=-1)
+    impl = _resolve_decode_impl(impl, cache, n, None, None, q=wide)
+    if impl == 'xla':
+        cache = packed_append(cache, k_new, v_new)
+        k, v = packed_views(cache)
+        return cache, decode_attention(
+            q, DecodeCache(k=k, v=v, length=cache.length), scale=scale,
+            window=window, alibi_slopes=alibi_slopes)
+    at = jnp.broadcast_to(cache.length, (b,))
+    out, kv, _, _, _ = flash_decode(
+        wide, jnp.concatenate([k_new, v_new], axis=-1), None, cache.kv,
+        None, at, jnp.where(at + n <= cache.t_max, at, -1), scale=scale,
+        window=window, alibi_slopes=alibi_slopes, interpret=interpret)
+    return PackedCache(kv=kv, length=cache.length + n), out
 
 
 def _flash_merge(partials, axis_name, out_dtype):

@@ -64,10 +64,12 @@ width where it is not ``n_shared x hidden``. Both projections are
 linear and bias-free, so the parts of a latent layer divided over
 several holders, each through its own ``W_up``, still add up.
 
-Two routes give the routed part, and the call's ROWS choose between
-them (:data:`ops.pallas_experts.HIT_LIST_ROWS`). A call of at most that
-many tokens — a decode step — runs every HIT held expert, one that some
-token of the call picked, on every token, the gate zero where the token
+Two routes give the routed part, and the call's ROWS and their WIDTH
+choose between them (:func:`ops.pallas_experts.hit_list_rows`: 128 rows
+whatever the width, 256 where the rows' resident blocks still fit the
+kernel's VMEM plan — a stream of 2048 or narrower). A call of at most
+that many tokens — a decode step — runs every HIT held expert, one that
+some token of the call picked, on every token, the gate zero where the token
 did not pick it: one Pallas program a layer
 (``ops/pallas_experts.hit_experts``, ``moe_hit_experts``) over the
 call's hit list, which streams each hit expert's weights once and no
@@ -119,7 +121,7 @@ from distributed_dot_product_tpu.models.remat import (
     LAYER_MATMUL_NAMES, named,
 )
 from distributed_dot_product_tpu.ops.pallas_experts import (
-    HIT_LIST_ROWS, hidden_tile, hit_experts, hit_list,
+    hidden_tile, hit_experts, hit_list, hit_list_rows,
 )
 from distributed_dot_product_tpu.ops.pallas_sparse import (
     order_image, threshold_picks,
@@ -147,7 +149,7 @@ def expert_route_traces():
     (compares and the ``sparse_pick`` program, no sort) or ``'sort'``
     (``lax.top_k``), :func:`select_form`; ``n`` the call's rows,
     ``bound`` the most rows that take the hit list, ``bound_by`` whose
-    it was — ``'rule'`` (``ops.pallas_experts.HIT_LIST_ROWS``) or
+    it was — ``'rule'`` (``ops.pallas_experts.hit_list_rows``) or
     ``'caller'`` (``dense_tokens``) — and ``tile`` the columns of
     ``hidden`` one grid step of the kernel takes (None on the sorted
     route)::
@@ -388,7 +390,7 @@ class SparseExperts(nn.Module):
         # Few enough rows that every hit expert can take them all behind
         # its weights' DMA: the call's own shape decides.
         by_rule = self.dense_tokens is None
-        bound = HIT_LIST_ROWS if by_rule else self.dense_tokens
+        bound = hit_list_rows(wide) if by_rule else self.dense_tokens
         hit_route = n <= bound
         select = select_form() if hit_route else 'sort'
         with device_scope('lm.moe_route'):

@@ -52,6 +52,7 @@ from distributed_dot_product_tpu.models.moe import GatedMLP, SparseExperts
 from distributed_dot_product_tpu.models.remat import (
     LAYER_MATMUL_NAMES, KeepWhatFits, named, new_layer,
 )
+from distributed_dot_product_tpu.models.shortconv import ShortConvMixer
 from distributed_dot_product_tpu.models.ssm import Mamba2Mixer
 from distributed_dot_product_tpu.utils.comm import SEQ_AXIS
 from distributed_dot_product_tpu.utils.scopes import device_scope
@@ -60,11 +61,13 @@ __all__ = ['TransformerBlock', 'TransformerStack']
 
 
 # The recurrent mixers, by the name of a block's ``mixer`` AND of its
-# subtree: each takes ``ssm_kwargs`` and keeps a ``StateCache``; its
+# subtree: each takes ``ssm_kwargs`` and keeps a ``StateCache`` (a state
+# matrix a head and a convolution window; the Lightning mixer's window
+# has no rows, the short convolution's state no elements); its
 # ``prefill`` / ``decode`` are told the ``position`` of the first new
-# token (the Lightning mixer rotates by it; the other two ignore it).
+# token (the Lightning mixer rotates by it; the other three ignore it).
 RECURRENT = {'ssm': Mamba2Mixer, 'delta': GatedDeltaMixer,
-             'lightning': LightningMixer}
+             'lightning': LightningMixer, 'conv': ShortConvMixer}
 
 
 def make_norm(kind, eps, dtype, name):
@@ -105,7 +108,10 @@ class TransformerBlock(nn.Module):
       ``delta``; a ``StateCache`` too) | ``'lightning'``
       (``models/lightning.LightningMixer(**ssm_kwargs)``, the subtree
       ``lightning``: plain linear attention with RoPE, a ``StateCache``
-      and the stack's position) | ``'none'``. An ``'attention'`` mixer
+      and the stack's position) | ``'conv'``
+      (``models/shortconv.ShortConvMixer(**ssm_kwargs)``, the subtree
+      ``conv``: a double-gated depthwise convolution of a few taps; a
+      ``StateCache`` of the window alone) | ``'none'``. An ``'attention'`` mixer
       with ``attn_kwargs['sparse']`` is the learned block-sparse layer
       (``models/sparse.py``; its cache a ``SparseCache``);
     - ``ffn``: ``'gelu'`` (``mlp_ratio`` x dim) | ``'gated'``

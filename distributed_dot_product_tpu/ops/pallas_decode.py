@@ -73,6 +73,15 @@ This kernel is ONE Pallas program per decode step that
   another (measured: 5x the step). Further into the split the tail's
   scoring would cost more than its bytes save, and the split is moved
   whole as before;
+- **streams a narrow head's keys and values as ONE row** (the packed
+  mode, ``cache_v=None``): a 64-wide head stored as a K and a V buffer
+  is tiled to 128 lanes in each, half of every byte a step moves is
+  padding and ``decode_geometry`` gives such rows no tail; packed, a
+  token's key sits in lanes 0-63 and its value in lanes 64-127 of one
+  ``(…, t_max, 128)`` buffer, the query rides zero-extended (``q̃ · [k |
+  v] = q · k``), the resident block enters both products and the output
+  is the value lanes of ``p · [k | v]`` — one stream where there were
+  two, nothing padded in HBM, the MXU's passes those of a 128-wide head;
 - **dequantizes int8 in kernel**: the quantized path streams the 1-byte
   ``k_q`` mirror plus its per-row scales and scores s8×s8→s32 on the
   MXU with the dequantization applied to the s32 block — the halved K
@@ -173,14 +182,16 @@ def _lanes(x):
 
 def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype, *,
                     n=1, quantized=False, page_size=None, block_k=None,
-                    ring=False):
+                    ring=False, packed=False):
     """The decode kernel's grid step for a call of these shapes, or None
     where no K split divides ``t_max`` (the caller takes the XLA path).
 
     ``rows`` is the query rows a KV head scores (``group · n``);
     ``page_size`` a paged pool's page, which IS the split; ``block_k``
-    the tests' override of the split; ``ring`` the ring mode. (The
-    latent cache's kernel has a rule of its own,
+    the tests' override of the split; ``ring`` the ring mode; ``packed``
+    the one-buffer mode (``flash_decode``: ``d`` is then the packed
+    row's width, keys and values together, ``dv = d``, and a row is
+    streamed ONCE). (The latent cache's kernel has a rule of its own,
     :func:`latent_geometry`.)
 
     The K split stays at :data:`_BLOCK_K_CAP` rows (skip granularity);
@@ -219,7 +230,7 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype, *,
     # int8 mirror row and its f32 scale in place of the K row, which is
     # then fetched at its write block alone.
     k_row = _lanes(d) * jnp.dtype(k_dtype).itemsize
-    v_row = _lanes(dv) * jnp.dtype(v_dtype).itemsize
+    v_row = 0 if packed else _lanes(dv) * jnp.dtype(v_dtype).itemsize
     stream_row = (_lanes(d) + 4 if quantized else k_row) + v_row
     held_row = stream_row + (k_row if quantized else 0)
     q_sub = _sublane(jnp.int8) if quantized else sub
@@ -271,10 +282,13 @@ def flash_decode_geometry(q, cache_k, cache_v=None, *, page_table=None,
     h_kv, t_max, page = cache_k.shape[-3], cache_k.shape[-2], None
     if page_table is not None:
         page, t_max = t_max, page_table.shape[1] * t_max
+    # No value buffer: the packed mode, whose one buffer is both.
+    packed = cache_v is None
+    values = cache_k if packed else cache_v
     return decode_geometry(
-        t_max, h_kv, d, cache_v.shape[-1], n * (h // h_kv), cache_k.dtype,
-        cache_v.dtype, n=n, quantized=qk_quant == 'int8', page_size=page,
-        block_k=block_k, ring=ring)
+        t_max, h_kv, d, values.shape[-1], n * (h // h_kv), cache_k.dtype,
+        values.dtype, n=n, quantized=qk_quant == 'int8', page_size=page,
+        block_k=block_k, ring=ring, packed=packed)
 
 
 def _sublane(dtype):
@@ -347,7 +361,7 @@ def _sweep_end(vt, ap, geom, t_max, n=1):
 
 def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                         quantized, has_alibi, paged=False, stacked=False,
-                        ring=False):
+                        ring=False, packed=False):
     """Kernel body; refs are ordered to match ``flash_decode``'s spec
     list below. Grid = (B·H_kv / hb, ns) with the K split innermost:
     one step holds ``hb = geom.heads`` KV heads of ONE slot (every
@@ -408,7 +422,12 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
     array follow the softmax state. ``_sweep_end`` tells from the
     prefetched lengths whether a slot's last split is taken as its
     first ``tail`` rows; those are scored by the body above as one
-    more block (``score_block``), after the last whole split's."""
+    more block (``score_block``), after the last whole split's.
+
+    PACKED (``packed``): there is no value operand, new rows, buffer or
+    result: every name of the body that says V is the K ref of the same
+    kind (a resident block enters both products), and the append is the
+    one buffer's."""
     hb, bk, wr, tail = (geom.heads, geom.block_k, geom.write_rows,
                        geom.tail)
     per_slot = h_kv // hb                       # grid rows a slot
@@ -430,26 +449,31 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
         kn_ref = next(it)
         kqn_ref = next(it) if quantized else None
         ksn_ref = next(it) if quantized else None
-        vn_ref = next(it)
+        vn_ref = kn_ref if packed else next(it)
         k_ref = next(it)
         kq_ref = next(it) if quantized else None
         ks_ref = next(it) if quantized else None
-        v_ref = next(it)
+        v_ref = k_ref if packed else next(it)
         alibi_ref = next(it) if has_alibi else None
-        o_ref, m_ref, l_ref, ko_ref, vo_ref = (
-            next(it), next(it), next(it), next(it), next(it))
+        o_ref, m_ref, l_ref, ko_ref = (
+            next(it), next(it), next(it), next(it))
+        vo_ref = None if packed else next(it)
         kqo_ref = next(it) if quantized else None
         kso_ref = next(it) if quantized else None
         m_s, l_s, acc_s = next(it), next(it), next(it)
         if tail:
             # The tail's rows, the write-back tile's staging rows and
             # their DMA semaphores.
-            ktail_ref, vtail_ref, kw_ref, vw_ref, sems = (
-                next(it), next(it), next(it), next(it), next(it))
+            ktail_ref = next(it)
+            vtail_ref = ktail_ref if packed else next(it)
+            kw_ref = next(it)
+            vw_ref = None if packed else next(it)
+            sems = next(it)
             # K then V: the result in HBM, the tail's rows, the staging
             # tile, the new rows.
-            kv = [(ko_ref, ktail_ref, kw_ref, kn_ref),
-                  (vo_ref, vtail_ref, vw_ref, vn_ref)]
+            kv = [(ko_ref, ktail_ref, kw_ref, kn_ref)]
+            if not packed:
+                kv.append((vo_ref, vtail_ref, vw_ref, vn_ref))
 
         # What scoring reads: the int8 mirror and its scales if any.
         score_ref, score_new_ref = ((kq_ref, kqn_ref) if quantized
@@ -637,7 +661,8 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
             # Head by head, like the scores: one head's tile in flight.
             for h in range(hb):
                 put(h, k_ref, kn_ref, ko_ref, off, tile * wr)
-                put(h, v_ref, vn_ref, vo_ref, off, tile * wr)
+                if not packed:
+                    put(h, v_ref, vn_ref, vo_ref, off, tile * wr)
                 if quantized:
                     put(h, kq_ref, kqn_ref, kqo_ref, off, tile * wr)
                     # (1, bk) scale row vector: the appended row is a
@@ -838,6 +863,20 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     Pallas program is named ``flash_decode_ring`` and its device scope
     ``ops.flash_decode_ring``, opened inside ``ops.flash_decode``.
 
+    PACKED mode (``cache_v=None`` and ``v_new=None``, no ``latent_v``):
+    for heads narrower than a lane tile, whose K and V buffers would
+    each be stored and streamed padded to 128 lanes. ``cache_k (B, H_kv,
+    t_max, w)`` holds BOTH: a token's key (as scored: normed, rotated)
+    in lanes ``[0, w/2)`` and its value in lanes ``[w/2, w)`` — at
+    64-wide heads one 128-lane row with nothing padded, half the bytes
+    of the two padded buffers. ``q (B, H, k, w)`` arrives with zeros in
+    the value lanes, so ``q · [k | v] = q · k``; ``k_new (B, H_kv, k,
+    w)`` is the new packed rows; a resident block enters both products,
+    streamed once, and ``out (B, H, k, w/2)`` is the value lanes of
+    ``p · [k | v]``. Pass ``scale`` (the default reads ``q``'s width).
+    Not with ``page_table``, ``layer``, ``qk_quant`` or ``ring_span``.
+    The same kernel body and program name.
+
     THE TAIL (no argument: :func:`decode_geometry` gives a call its
     ``tail`` rows or None, ``valid_to`` decides a slot's step at run
     time): where no more than ``tail`` rows are filled of the K split
@@ -874,7 +913,16 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     h_kv = cache_k.shape[-3]
     paged = page_table is not None
     stacked = layer is not None
-    dv, v_dtype = cache_v.shape[-1], cache_v.dtype
+    packed = cache_v is None
+    if packed and (v_new is not None or paged or stacked or d % 2
+                   or qk_quant is not None or ring_span is not None
+                   or k_q is not None or k_scale is not None):
+        raise ValueError(
+            'flash_decode: the packed mode (cache_v=None) keeps keys and '
+            'values in the two halves of ONE slab: pass v_new=None, and '
+            'no page_table, layer, qk_quant or ring_span')
+    dv, v_dtype = ((d, cache_k.dtype) if packed
+                   else (cache_v.shape[-1], cache_v.dtype))
     ring = ring_span is not None
     if ring and (n != 1 or window is not None or paged or stacked
                  or alibi_slopes is not None or qk_quant is not None):
@@ -971,8 +1019,9 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     # are zeros the kernel never substitutes (its loops stop at n).
     knf = _pad_rows(k_new.astype(cache_k.dtype).reshape(nb, n, d),
                     _sublane(cache_k.dtype))
-    vnf = _pad_rows(v_new.astype(cache_v.dtype).reshape(nb, n, dv),
-                    _sublane(cache_v.dtype))
+    if not packed:
+        vnf = _pad_rows(v_new.astype(cache_v.dtype).reshape(nb, n, dv),
+                        _sublane(cache_v.dtype))
     if paged:
         # Pool flattening mirrors the slab's (B, H_kv) fold: pool page
         # p's head hh lives at flat row p·H_kv + hh, so one BlockSpec
@@ -996,7 +1045,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         # A stacked buffer folds its layer axis into the rows too:
         # layer l's (slot, head) row r lives at flat row l·nb + r.
         kf = cache_k.reshape(-1, t_max, d)
-        vf = cache_v.reshape(-1, t_max, dv)
+        vf = None if packed else cache_v.reshape(-1, t_max, dv)
     valid_to = jnp.asarray(valid_to, jnp.int32)
     append_at = jnp.asarray(append_at, jnp.int32)
     # Per-slot appended-row count: callers without mixed batches get
@@ -1123,8 +1172,9 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         in_specs += [pl.BlockSpec((hb,) + kni.shape[1:], const_idx),
                      pl.BlockSpec((hb, 1, 1), const_idx)]
         args += [kni, kns.reshape(nb, 1, 1)]
-    in_specs.append(pl.BlockSpec((hb,) + vnf.shape[1:], const_idx))
-    args.append(vnf)
+    if not packed:
+        in_specs.append(pl.BlockSpec((hb,) + vnf.shape[1:], const_idx))
+        args.append(vnf)
     # The bf16 K buffer: streamed for scoring in the plain path; in the
     # quantized path scoring reads the mirror instead, so K is fetched
     # ONLY at its write block (one DMA per slot, to seed the append).
@@ -1150,9 +1200,10 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         args.append(kqf)
         ks_in_pos = len(args)
         args.append(ksf)
-    in_specs.append(pl.BlockSpec((hb, bk, dv), stream_idx))
-    v_in_pos = len(args)
-    args.append(vf)
+    if not packed:
+        in_specs.append(pl.BlockSpec((hb, bk, dv), stream_idx))
+        v_in_pos = len(args)
+        args.append(vf)
     has_alibi = alibi_slopes is not None
     if has_alibi:
         # Per-query-head slopes, pre-folded by log2e (the kernel's
@@ -1193,10 +1244,12 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     elif ring:
         prefetch += (jnp.asarray(ring_span, jnp.int32),)
     n_prefetch = len(prefetch)
-    aliases = {n_prefetch + k_in_pos: 3, n_prefetch + v_in_pos: 4}
-    out_specs.append(pl.BlockSpec(memory_space=pl.ANY) if tail else
-                     pl.BlockSpec((hb, wr, dv), write_idx))  # v (aliased)
-    out_shape.append(jax.ShapeDtypeStruct(vf.shape, vf.dtype))
+    aliases = {n_prefetch + k_in_pos: 3}
+    if not packed:
+        aliases[n_prefetch + v_in_pos] = 4
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY) if tail else
+                         pl.BlockSpec((hb, wr, dv), write_idx))  # v (aliased)
+        out_shape.append(jax.ShapeDtypeStruct(vf.shape, vf.dtype))
     if quantized:
         out_specs += [pl.BlockSpec((hb, wr, d), write_idx),
                       pl.BlockSpec((hb, 1, wr), write_idx_row)]
@@ -1211,13 +1264,13 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     if tail:
         # The tail's rows and the write-back tile's staging rows, K
         # then V; a DMA semaphore each.
-        kv = [(d, kf.dtype), (dv, vf.dtype)]
+        kv = [(d, kf.dtype)] + ([] if packed else [(dv, vf.dtype)])
         scratch += [pltpu.VMEM((hb, tail, w), t) for w, t in kv]
         scratch += [pltpu.VMEM((hb, wr, w), t) for w, t in kv]
         scratch.append(pltpu.SemaphoreType.DMA((2, 2)))
     kernel = _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                                  quantized, has_alibi, paged=paged,
-                                 stacked=stacked, ring=ring)
+                                 stacked=stacked, ring=ring, packed=packed)
     name = 'flash_decode'
     # The ring mode's scope opens INSIDE the kernel's own: a reader that
     # knows only ops.flash_decode still counts it as the decode kernel.
@@ -1245,7 +1298,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         new_kq = outs[5].reshape(k_q.shape)
         new_ks = outs[6].reshape(k_scale.shape)   # same flat order
     new_k = new_k.reshape(cache_k.shape)
-    new_v = outs[4].reshape(cache_v.shape)
+    new_v = None if packed else outs[4].reshape(cache_v.shape)
 
     def head_shape(x):
         # Rows are new-row-major per kv head: undo the (n, group) fold
@@ -1254,6 +1307,8 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         return jnp.swapaxes(x, 2, 3).reshape(b, h, n, x.shape[-1])
 
     num, m, l = head_shape(num), head_shape(m), head_shape(l)
+    if packed:
+        num = num[..., d // 2:]     # the value lanes of p · [k | v]
     if partials:
         return (num, m, l), new_k, new_v, new_kq, new_ks
     out = (num / jnp.where(l == 0.0, 1.0, l)).astype(v_dtype)

@@ -52,7 +52,12 @@ Command A+'s (12 tokens, 4096 / 4096 = 8 x 512, 16 held): three blocks
 of 4 MiB, 24 MiB double-buffered, the rest under 0.7 MiB, limit 40 MiB;
 at :data:`HIT_LIST_ROWS` 128 rows the rest is 9 MiB (tokens and output
 1 MiB each and double-buffered, accumulator and product 2 MiB each).
-``tests/test_tpu_compile.py`` compiles each for a v5e.
+LFM2's layer at the rule's bound for its width (256 tokens, 2048 /
+1792 = 2 x 896, 32 held; :func:`hit_list_rows`): three blocks of 3.5
+MiB, 21 MiB double-buffered, limit 37 MiB; the rows' resident blocks 8
+MiB (tokens and output 1 MiB each and double-buffered, accumulator and
+product 2 MiB each) and three ``(256, 896)`` float32 activations 2.6
+MiB. ``tests/test_tpu_compile.py`` compiles each for a v5e.
 
 Off the TPU the kernel runs under the Pallas interpreter, as the other
 kernels do. :func:`hit_experts_reference` is the same layer as two
@@ -73,7 +78,7 @@ from distributed_dot_product_tpu.ops.pallas_decode import (
 )
 
 __all__ = ['hit_list', 'hit_experts', 'hit_experts_reference',
-           'hidden_tile', 'HIT_LIST_ROWS']
+           'hidden_tile', 'hit_list_rows', 'HIT_LIST_ROWS']
 
 # The most rows of a call that take this kernel (``models/moe.py``
 # chooses by it): every hit expert gets ALL the call's rows, and one MXU
@@ -84,7 +89,17 @@ __all__ = ['hit_list', 'hit_experts', 'hit_experts_reference',
 # hit experts where the sorted route's grows with the picks. On the chip
 # (PR 35, both gated shapes, a layer's routed part): level from 64 to
 # 256 rows, 1.8x that at 512, and under the sorted route's all the way.
+# This is the bound of EVERY call (:func:`hit_list_rows` never says
+# less); what the rule reads past it is the bytes the call's rows keep
+# resident.
 HIT_LIST_ROWS = 128
+# What the rows of a call may keep resident in VMEM for the whole grid —
+# tokens and output double-buffered in the compute type, the float32
+# accumulator and the float32 product, 16 bytes a row and column of
+# ``wide`` — where the call is past :data:`HIT_LIST_ROWS`: what 128 rows
+# of a 4096-wide stream take, the most the plan above was compiled at
+# (``tests/test_tpu_compile.py``).
+_RESIDENT_BYTES = 128 * 4096 * 16
 # A block of ``w_up`` / ``w_gate`` is a column slab of a row-major
 # matrix: its DMA moves ``wide`` rows of ``tile x itemsize`` bytes. Rows
 # of 256 and 512 bytes streamed 6 % under rows of 1 KB and 2 KB at both
@@ -95,6 +110,28 @@ _SLAB_ROW_BYTES = 1024
 _VMEM_CEILING = 64 << 20
 # The compiler's default scoped VMEM limit on a v5e.
 _SCOPED_VMEM = 16 << 20
+
+
+def hit_list_rows(wide):
+    """The most rows of a call over ``wide``-wide rows that take this
+    kernel — the rule ``models/moe.py`` routes by: :data:`HIT_LIST_ROWS`
+    whatever the width (one MXU pass: the expert's matmuls stay behind
+    its weights' DMA), and TWICE that where the rows' resident blocks
+    still fit :data:`_RESIDENT_BYTES` (a stream of 2048 or narrower). A
+    second pass of 128 rows is 0.65 x 2 = 1.3 ms of the MXU's peak a GB
+    of weights beside 1.22 ms of HBM: at the ridge. On the chip (PR 51,
+    a layer of 32 experts of 2048 x 1792 at top-4, every expert hit) the
+    kernel read 0.984 ms at 128 rows, 0.996 at 192, 1.016 at 256 (84 %
+    of the HBM peak), then 1.475 at 384 and 1.935 at 512 — the work
+    grows with rows x hit experts past the ridge — against the sorted
+    route's 2.62, 2.01, 2.74, 2.87 and 2.99 ms (a sort of the picks, a
+    gather, a scatter and a row tile a group before its first weight
+    arrives). The kernel stayed ahead to 512 rows there and at 256 rows
+    of a 4096-wide stream (40 experts of 4096 x 1280: 1.757 against
+    4.69 ms); the rule takes the rows one cell times and the VMEM plan
+    was compiled for, no more."""
+    twice = 2 * HIT_LIST_ROWS
+    return twice if twice * wide * 16 <= _RESIDENT_BYTES else HIT_LIST_ROWS
 
 
 def hit_list(counts):

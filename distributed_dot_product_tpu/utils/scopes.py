@@ -109,6 +109,10 @@ DEVICE_SCOPES = {
     'ops.lightning_scan': 'prefill / whole sequence: the Lightning '
                           'recurrence in its chunked form '
                           '(ssm.chunked_scan)',
+    'lm.conv_proj': 'a gated short-convolution mixer, all of it: the input '
+                    'and output projections, both gates, the depthwise '
+                    'taps over the carried window and the new rows, and '
+                    'the window\'s shift',
     'ops.sparse_select': 'the selection of a block-sparse attention '
                          'layer: the pooled-key rows a step or a chunk '
                          'completes, the scores against the pooled keys, '

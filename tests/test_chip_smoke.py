@@ -82,7 +82,8 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     phases = {rec['phase']: rec for rec in lines if 'phase' in rec}
     assert set(phases) == {'train', 'generate', 'generate_latent',
                            'generate_mixed', 'generate_hybrid',
-                           'generate_sparse', 'generate_ling', 'serve'}
+                           'generate_sparse', 'generate_ling',
+                           'generate_conv', 'serve'}
     # a recurrent state beside a slab: the request after a restore reads
     # what the first did
     hybrid = phases['generate_hybrid']
@@ -111,6 +112,21 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
     assert np.asarray(ling['ling_group_rows']).shape == (3, 2)
     assert ling['checks']['ling.restored_request_agrees'] is True
     assert ling['checks']['ling.latent_rows_grew'] is True
+    # a window-only recurrent layer beside the packed slab: both forms
+    # and the expert calls' routes are printed
+    conv = phases['generate_conv']
+    assert conv['conv_caches'] == ['StateCache', 'StateCache',
+                                   'PackedCache']
+    assert conv['conv_slab_step'] == [{
+        'resolved': 'xla', 'cache': 'packed', 'token_bytes': None,
+        'tail': None}]
+    assert conv['conv_step_forms'] == 2 * [
+        {'form': 'shift', 'taps': 3, 'channels': 128}]
+    assert [(r['route'], r['bound_by'])
+            for r in conv['conv_expert_routes']] == 2 * [
+                ('hit_list', 'rule')]
+    assert conv['checks']['conv.restored_request_agrees'] is True
+    assert conv['checks']['conv.a_request_moves_the_window'] is True
     # both kernel modes side by side, off the chip both through XLA
     assert phases['generate_mixed']['mixed_caches'] == ['layer', 'ring']
     # one `build` line a phase, from the program's own ledger: seconds by

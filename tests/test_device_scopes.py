@@ -80,6 +80,13 @@ SALA_SCOPES = ['ops.sparse_select', 'ops.sparse_decode',
                'ops.lightning_scan', 'lm.lightning_proj', 'ops.flash_fwd',
                'lm.attn_proj', 'lm.mlp', 'lm.embed', 'lm.head',
                'lm.stack_carry']
+# The ``lfm2_moe`` stack (gated short-convolution layers beside a GQA
+# layer at 64-wide heads on a packed slab, a dense layer and an expert
+# one): the decode step (the kernel on the packed cache) and a prefill
+# chunk (the flash forward over the slab's two halves).
+LFM2_SCOPES = ['lm.conv_proj', 'ops.flash_decode', 'ops.flash_fwd',
+               'lm.attn_proj', 'lm.mlp', 'lm.moe_route', 'lm.moe_experts',
+               'lm.embed', 'lm.head', 'lm.stack_carry']
 
 
 def tiny_lm(remat_policy=None, **attn_kwargs):
@@ -323,6 +330,31 @@ def sala_op_names():
         for method, n in (('decode', 1), ('prefill', 16))}
 
 
+@pytest.fixture(scope='module')
+def lfm2_op_names():
+    """``{'decode': …, 'prefill': …}`` of the short-convolution / GQA
+    stack, the attention layer's step as the kernel on its packed
+    cache."""
+    model = TransformerLM(
+        vocab_size=64, dim=128, num_heads=2, n_layers=3, scan_layers=False,
+        attn_kwargs=dict(distributed=False, decode_impl='kernel',
+                         num_kv_heads=1, qk_norm=True, kv_packed=True),
+        block_kwargs=dict(norm='rmsnorm', ssm_kwargs=dict(taps=3),
+                          ffn='experts', ffn_kwargs=dict(
+                              n_experts=4, top_k=2, hidden=16, n_shared=0)),
+        layer_kinds={
+            'dense': dict(mixer='conv', ffn='gated',
+                          ffn_kwargs=dict(hidden=48)),
+            'conv': dict(mixer='conv'), 'attn': dict(mixer='attention')},
+        layer_pattern=('dense', 'conv', 'attn'))
+    params = model.init(jax.random.key(0), jnp.zeros((2, 8), jnp.int32))
+    caches = model.make_decode_caches(2, 128)
+    return {method: op_names(jax.jit(
+        lambda p, tok, c, m=method: model.apply(p, tok, c, method=m)
+    ).lower(params, jnp.zeros((2, n), jnp.int32), caches).compile())
+        for method, n in (('decode', 1), ('prefill', 16))}
+
+
 def opened(scope, names):
     return any(f'/{scope}/' in f'/{name}/' for name in names)
 
@@ -367,6 +399,37 @@ def test_delta_stack_opens(scope, delta_op_names):
 def test_sala_stack_opens(scope, sala_op_names):
     assert opened(scope, sala_op_names['decode']
                   | sala_op_names['prefill'])
+
+
+@pytest.mark.parametrize('scope', LFM2_SCOPES)
+def test_lfm2_stack_opens(scope, lfm2_op_names):
+    assert opened(scope, lfm2_op_names['decode']
+                  | lfm2_op_names['prefill'])
+
+
+def test_the_convolution_mixers_arithmetic_sits_in_its_scope(
+        lfm2_op_names):
+    """Nothing of the step's or the prefill's own arithmetic is
+    unscoped; ALL of a conv mixer's — both projections, both gates, the
+    taps and the window's shift — is ``lm.conv_proj``, never the
+    stack's; the decode kernel sits in ``ops.flash_decode`` inside
+    ``lm.attn_proj`` as on the padded slab, and a chunk's flash forward
+    in ``ops.flash_fwd``."""
+    def innermost(name):
+        return [part for part in name.split('/') if part in DEVICE_SCOPES][
+            -1]
+    for method in ('decode', 'prefill'):
+        mine = [n for n in lfm2_op_names[method] if n.startswith('jit(')]
+        assert mine and all(
+            any(opened(scope, [n]) for scope in DEVICE_SCOPES)
+            for n in mine)
+        inside = {innermost(n) for n in mine if '/conv.' in n}
+        assert inside == {'lm.conv_proj'}
+        assert any(n.endswith('/dot_general') for n in mine
+                   if innermost(n) == 'lm.conv_proj')
+    kernel = [n for n in lfm2_op_names['decode'] if '/flash_decode/' in n]
+    assert kernel and all('/lm.attn_proj/ops.flash_decode/' in n
+                          for n in kernel)
 
 
 def test_the_sparse_and_lightning_arithmetic_sits_in_its_scopes(
@@ -517,7 +580,8 @@ def test_latent_kernel_is_outside_the_projection_scope(latent_op_names):
 def test_the_steps_cover_the_vocabulary():
     assert (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(LATENT_SCOPES)
             | set(MIXED_SCOPES) | set(HYBRID_SCOPES) | set(DELTA_SCOPES)
-            | set(SALA_SCOPES) | set(LING_SCOPES) == set(DEVICE_SCOPES))
+            | set(SALA_SCOPES) | set(LING_SCOPES) | set(LFM2_SCOPES)
+            == set(DEVICE_SCOPES))
 
 
 def test_unknown_scope_raises():

@@ -28,7 +28,7 @@ from distributed_dot_product_tpu.models.moe import (  # noqa: E402
     SparseExperts, expert_route_traces,
 )
 from distributed_dot_product_tpu.ops.pallas_experts import (  # noqa: E402
-    HIT_LIST_ROWS, hidden_tile, hit_experts_reference,
+    HIT_LIST_ROWS, hidden_tile, hit_experts_reference, hit_list_rows,
 )
 from distributed_dot_product_tpu.models.ssm import (  # noqa: E402
     Mamba2Mixer,
@@ -400,7 +400,20 @@ PARENT_LOWERED.update({
     'ling.decode':
         '74f022ef4fa7db0154742184af4b0a7d3598740cbf03d1f9b9726c61d6e4677c',
 })
+# PR 51 (the ``lfm2_moe`` fields: a fourth recurrent mixer kind, the
+# packed slab ``kv_packed``, the route rule's second quantity) left every
+# entry above as it was: all ten accepted cells' programs lower to the
+# parent's text. The eleventh cell's tiny preset is pinned as this PR
+# lowers it — the conv mixer, the packed slab's XLA step, two 16-row
+# expert calls on the hit list — so that the next field is held to it.
+PARENT_LOWERED.update({
+    'lfm2.prefill':
+        '2c7bd95a34b9987a11b77f432d6622a72ac78826ef487f47442b787626591e64',
+    'lfm2.decode':
+        '42af8d237d55ca4564c9bbbe781af6402f14fe4aac564a7c561069666463f643',
+})
 PRESETS = {'granite': ('tiny_granite', 'tiny-granite.decode'),
+           'lfm2': ('tiny_lfm2', 'tiny-lfm2.decode'),
            'ling': ('tiny_ling', 'tiny-ling.decode'),
            'solar': ('tiny_solar', 'tiny-solar.decode'),
            'sala': ('tiny_sala', 'tiny-sala.decode'),
@@ -520,16 +533,18 @@ def test_the_dense_route_is_the_sorted_route(form, latent, held):
         params, x).as_text()
 
 
-@pytest.mark.parametrize('rows', [HIT_LIST_ROWS, HIT_LIST_ROWS + 1],
+@pytest.mark.parametrize('rows', [2 * HIT_LIST_ROWS, 2 * HIT_LIST_ROWS + 1],
                          ids=['at-the-bound', 'one-past'])
 @pytest.mark.parametrize('dense_tokens', [None, 200, 0],
                          ids=['rule', 'caller', 'never'])
 def test_the_calls_rows_choose_the_route(dense_tokens, rows):
     """The accepted gated layer (silu, ``w_gate``) holding a sub-range
     beside two shared experts averaged: with no bound passed the call's
-    rows choose — the hit list up to ``HIT_LIST_ROWS``, the sorted
-    route one row past it — and a caller's integer is its own bound (0:
-    never). Whichever route, one result: the sorted route's, and for the
+    rows choose — the hit list up to ``hit_list_rows`` of the stream's
+    width (two MXU passes of ``HIT_LIST_ROWS`` here: a 16-wide row keeps
+    next to nothing resident; one pass for a stream past 2048), the
+    sorted route one row past it — and a caller's integer is its own
+    bound (0: never). Whichever route, one result: the sorted route's, and for the
     routed part the batched form's over every held expert.
     ``expert_route_traces`` says which, by whose bound, at what tile."""
     kw = dict(n_experts=8, top_k=3, hidden=256, n_shared=2, scaling=2.0,
@@ -541,7 +556,9 @@ def test_the_calls_rows_choose_the_route(dense_tokens, rows):
     layer = SparseExperts(**kw, dense_tokens=dense_tokens)
     with expert_route_traces() as traces:
         got, got_counts = layer.apply(params, x)
-    bound = HIT_LIST_ROWS if dense_tokens is None else dense_tokens
+    assert hit_list_rows(16) == hit_list_rows(2048) == 2 * HIT_LIST_ROWS
+    assert hit_list_rows(2049) == hit_list_rows(4096) == HIT_LIST_ROWS
+    bound = hit_list_rows(16) if dense_tokens is None else dense_tokens
     assert traces == [{
         'route': 'hit_list' if rows <= bound else 'sorted',
         'select': 'sort', 'n': rows, 'bound': bound,
@@ -571,7 +588,7 @@ def test_a_decode_step_of_every_expert_cell_takes_the_hit_list(preset):
     """The three expert cells' tiny presets: every expert layer of a
     decode step is on the hit-list route — the two gated cells' by the
     rule (their drivers pass no bound), the hybrid cell's by its
-    driver's own — and a 200-row prefill chunk of a gated cell is on the
+    driver's own — and a 300-row prefill chunk of a gated cell is on the
     sorted one."""
     root, name = {**PRESETS, 'nemotron': (
         'tiny_hybrid', 'tiny-nemotron.decode')}[preset]
@@ -593,7 +610,7 @@ def test_a_decode_step_of_every_expert_cell_takes_the_hit_list(preset):
     by = 'caller' if preset == 'nemotron' else 'rule'
     assert routes('decode', 1) == ({'hit_list'}, {2}, {by})
     if by == 'rule':
-        assert routes('prefill', 100) == ({'sorted'}, {200}, {by})
+        assert routes('prefill', 150) == ({'sorted'}, {300}, {by})
 
 
 def _routed(hit, n=10, held=6, wide=16, hidden=12, gated=False, seed=3):

@@ -154,7 +154,7 @@ def test_the_latent_cache_lives_below_the_latent_mixer():
     driver imports ``insert_session`` from there)."""
     from distributed_dot_product_tpu.models import decode, latent
     mixers = {f'{PKG}.models.{m}' for m in (
-        'latent', 'delta', 'ssm', 'lightning', 'sparse', 'moe',
+        'latent', 'delta', 'ssm', 'lightning', 'shortconv', 'sparse', 'moe',
         'transformer', 'attention')}
     assert not {name for _, name in _imported_modules(
         'models/decode.py') if any(
@@ -162,3 +162,22 @@ def test_the_latent_cache_lives_below_the_latent_mixer():
     assert latent.LatentCache is decode.LatentCache
     assert latent.insert_session is decode.insert_session
     assert 'LatentCache' in decode.__all__
+
+
+def test_the_convolution_mixer_is_a_leaf_beside_the_other_mixers():
+    """``models/shortconv.py`` imports the cache types, the dense layer
+    and the two leaves (scopes, trace sinks) and nothing else of the
+    package — no other mixer, no kernel, nothing of ``analysis/``,
+    ``obs/`` or ``serve/`` —, the stack knows it through ``RECURRENT``
+    alone, and ``PackedCache`` lives beside the other caches."""
+    from distributed_dot_product_tpu.models import decode, transformer
+    from distributed_dot_product_tpu.models.shortconv import ShortConvMixer
+    mine = {name for _, name in _imported_modules('models/shortconv.py')
+            if name.startswith(PKG)}
+    allowed = {f'{PKG}.models.decode', f'{PKG}.models.dense',
+               f'{PKG}.utils.scopes', f'{PKG}.utils.trace_sinks'}
+    assert mine and all(any(name == m or name.startswith(m + '.')
+                            for m in allowed) for name in mine)
+    assert transformer.RECURRENT['conv'] is ShortConvMixer
+    assert {'PackedCache', 'init_packed_cache', 'packed_append',
+            'packed_views'} <= set(decode.__all__)

@@ -246,6 +246,45 @@ def test_the_cells_geometry():
                            bf16) == (8, 1024, 16, 4 << 20, 256)
 
 
+# ``decode_geometry`` of every accepted call — and of the shapes at its
+# rules' corners — as the commit before the packed mode returned it (PR
+# 51's parent, read there): ``(heads, block_k, write_rows, bytes, tail)``.
+# The packed mode is one more argument at its default.
+_PARENT_GEOMETRY = {
+    'mpt-stacked': ((12544, 32, 128, 128, 1, {}),
+                    (16, 256, 16, 2097152, 128)),
+    'command-a-full': ((66560, 8, 128, 128, 16, {}),
+                       (8, 1024, 16, 4194304, 256)),
+    'command-a-ring': ((5120, 8, 128, 128, 16, {'ring': True}),
+                       (8, 1024, 16, 4194304, None)),
+    'nemotron': ((33792, 2, 128, 128, 16, {}), (2, 1024, 16, 1048576, 256)),
+    'granite': ((5120, 8, 128, 128, 4, {}), (8, 1024, 16, 4194304, 256)),
+    'solar': ((5120, 8, 128, 128, 8, {}), (8, 1024, 16, 4194304, 256)),
+    'sala-slab': ((66560, 2, 128, 128, 16, {}),
+                  (2, 1024, 16, 1048576, 256)),
+    'padded-d64': ((5120, 8, 64, 64, 4, {}), (8, 1024, 16, 4194304, None)),
+    'int8-mirror': ((16384, 32, 128, 128, 1, {'quantized': True}),
+                    (2, 1024, 1024, 794624, None)),
+    'paged-256': ((16384, 8, 128, 128, 4, {'page_size': 256}),
+                  (8, 256, 256, 1048576, None)),
+    'verify-4': ((8192, 8, 128, 128, 16, {'n': 4}),
+                 (4, 1024, 1024, 2097152, None)),
+}
+
+
+@pytest.mark.parametrize('call', sorted(_PARENT_GEOMETRY))
+def test_every_accepted_calls_geometry_is_the_parents(call):
+    (t_max, h_kv, d, dv, rows, more), want = _PARENT_GEOMETRY[call]
+    bf16 = jnp.bfloat16
+    assert decode_geometry(t_max, h_kv, d, dv, rows, bf16, bf16,
+                           **more) == want
+    # … and the packed form of the 64-wide call beside its padded one:
+    # half the bytes a step, and the tail a padded row cannot have.
+    if call == 'padded-d64':
+        assert decode_geometry(t_max, h_kv, 2 * d, 2 * d, rows, bf16, bf16,
+                               packed=True) == (8, 1024, 16, 2097152, 256)
+
+
 # The slab kernel's program for an MPT-shaped call (MHA, ALiBi, d 128,
 # bfloat16, two K splits): the ring mode is Python-level branches only,
 # so this does not move with it. Pinned as PR 45 traced it — the call

@@ -465,6 +465,10 @@ _EXPERT_SHAPES = {
     'ragged-hidden': ((24, 8, 512, 200, True, jnp.bfloat16), (200, None)),
     'wide-stream': ((16, 2, 16384, 1024, True, jnp.bfloat16),
                     (128, 40 << 20)),
+    # LFM2's layer at its decode step's rows: the rule's bound for a
+    # 2048-wide stream, two MXU passes (32 experts x 2 tiles of 896).
+    'lfm2-gated-256-rows': ((256, 32, 2048, 1792, True, jnp.bfloat16),
+                            (896, 37 << 20)),
 }
 
 
@@ -476,11 +480,11 @@ def test_hit_experts_kernel_compiles_for_v5e(chip, shape):
     bytes asked for): a plan that is wrong is a compile error."""
     from distributed_dot_product_tpu.models.moe import ACTIVATIONS
     from distributed_dot_product_tpu.ops.pallas_experts import (
-        HIT_LIST_ROWS, _vmem_limit, hidden_tile, hit_experts,
+        _vmem_limit, hidden_tile, hit_experts, hit_list_rows,
     )
     (n, held, wide, hidden, gated, w_dtype), (tile, limit) = (
         _EXPERT_SHAPES[shape])
-    assert n <= HIT_LIST_ROWS
+    assert n <= hit_list_rows(wide)
     itemsize = jnp.dtype(w_dtype).itemsize
     assert hidden_tile(wide, hidden, 2 + gated, itemsize) == tile
     assert _vmem_limit(wide, tile, 2 + gated, itemsize) == limit
@@ -1731,3 +1735,158 @@ def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
     assert restored.memory_analysis().alias_size_in_bytes >= (
         cache_bytes - 4 * sessions)
     assert restored.as_text().count('lm.state_restore') >= 12
+
+
+# ``flash_decode``'s PACKED mode (keys and values of a 64-wide head in
+# the two halves of one 128-lane row) at the edges of its plan: the new
+# cell's own call (8 KV heads of a slot a step, the tail), a multi-head
+# layer whose 16 heads do not fit one step, a verify-k step (the whole
+# split written back, no tail), a buffer of one split (no tail).
+# (b, h, h_kv, d, n, t_max) -> (heads a step, tail, bytes a token).
+_PACKED_EDGES = {
+    'lfm2-cell': ((256, 32, 8, 64, 1, 5120), (8, 256, 256)),
+    'mha-16-heads': ((4, 16, 16, 64, 1, 16384), (8, 256, 256)),
+    'verify4': ((4, 32, 8, 64, 4, 8192), (8, None, 256)),
+    'one-split': ((4, 32, 8, 64, 1, 1024), (8, None, 256)),
+    'd128-pairs': ((4, 8, 2, 128, 1, 8192), (2, 256, 512)),
+}
+
+
+@pytest.mark.parametrize('edge', sorted(_PACKED_EDGES))
+def test_packed_decode_kernel_compiles_at_its_edges(chip, edge):
+    """Mosaic's verdict on the packed mode: ONE buffer aliased whole, no
+    cache-sized temporary, and the grid step and tail that
+    ``decode_geometry`` reports for a row that is streamed once."""
+    from distributed_dot_product_tpu.ops.pallas_decode import (
+        flash_decode, flash_decode_geometry,
+    )
+    (b, h, h_kv, d, n, t_max), (heads, tail, token_bytes) = (
+        _PACKED_EDGES[edge])
+    bf16 = jnp.bfloat16
+    q = jax.ShapeDtypeStruct((b, h, n, 2 * d), bf16)
+    new = jax.ShapeDtypeStruct((b, h_kv, n, 2 * d), bf16)
+    kv = jax.ShapeDtypeStruct((b, h_kv, t_max, 2 * d), bf16)
+    at = jax.ShapeDtypeStruct((b,), jnp.int32)
+    geom = flash_decode_geometry(q, kv)
+    assert (geom.heads, geom.tail) == (heads, tail)
+    assert geom.bytes // (geom.heads * geom.block_k) == token_bytes
+
+    def step(q, new, kv, at):
+        return flash_decode(q, new, None, kv, None, at, at,
+                            scale=d ** -0.5, interpret=False)[:2]
+
+    compiled = _compile(chip, step, q, new, kv, at, donate=(2,))
+    cache_bytes = math.prod(kv.shape) * 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < max(
+        cache_bytes // 8, 32 << 20)
+
+
+def test_lfm2_decode_step_streams_packed_rows_and_fits(chip, monkeypatch):
+    """The token step of the short-convolution / GQA + expert stack at
+    the published widths and the traffic of ``lfm2-8b-a1b.decode-4k`` (9
+    layers, 256 sessions: seven ``(256, 2, 2048)`` bfloat16 windows
+    beside two packed ``(256, 8, 5120, 128)`` slabs), caches donated:
+    both attention layers' step resolves to the kernel on the PACKED
+    cache at 256 bytes a token a KV head — 8 heads of a session a grid
+    step, the tail's 256 rows —, every conv mixer's step is its traced
+    form, every expert layer's 256-row call takes the route the rule
+    names by the rule's own bound — ONE ``moe_hit_experts`` kernel a
+    layer, two 896-wide tiles an expert, and no grouped matmul —,
+    nothing as large as one slab is copied, sliced or held as a
+    temporary, and arguments + temporaries stay under 14.0 GiB with the
+    snapshot counted. The reset between requests writes the seven
+    windows over in place."""
+    import json
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.drivers import decode_lfm2 as driver
+    from distributed_dot_product_tpu.models.decode import (
+        PackedCache, decode_impl_traces,
+    )
+    from distributed_dot_product_tpu.models.moe import expert_route_traces
+    from distributed_dot_product_tpu.models.shortconv import (
+        conv_step_traces,
+    )
+    from distributed_dot_product_tpu.ops.pallas_experts import (
+        _vmem_limit, hidden_tile, hit_list_rows,
+    )
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    with open(os.path.join(root, 'benchmarks', 'configs',
+                           'lfm2-8b-a1b-serve.json')) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, 'benchmarks', 'traffic',
+                           'decode-4k-x256.json')) as f:
+        traffic = json.load(f)
+    model = driver.build_lm(cfg)
+    params = _shape_table_params(driver, cfg)
+    sessions, t_max = traffic['sessions'], traffic['t_max']
+    caches = jax.eval_shape(
+        lambda: model.make_decode_caches(sessions, t_max))
+    kinds = driver.layer_kinds(cfg)
+    assert kinds == ['conv'] * 4 + ['attn'] + ['conv'] * 3 + ['attn']
+    for kind, cache in zip(kinds, caches):
+        if kind == 'attn':
+            assert isinstance(cache, PackedCache)
+            assert cache.kv.shape == (sessions, 8, t_max, 128)
+        else:
+            assert cache.state.size == 0
+            assert cache.conv.shape == (sessions, 2, 2048)
+            assert cache.conv.dtype == jnp.bfloat16
+    stats = jax.eval_shape(lambda: driver.zero_stats(cfg, traffic))
+    tok = jnp.zeros((sessions, 1), jnp.int32)
+    restore, step = driver.make_programs(model, cfg)[-2:]
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=chip), tree)
+    with decode_impl_traces() as traces, expert_route_traces() as routes, \
+            conv_step_traces() as forms:
+        compiled = step.lower(
+            *described((params, tok, caches, stats))).compile()
+    assert [(t['resolved'], t['cache'], t['token_bytes'], t['tail'],
+             t['step']['heads']) for t in traces] == 2 * [
+        ('kernel', 'packed', 256, 256, 8)]
+    assert forms == 7 * [{'form': 'shift', 'taps': 3, 'channels': 2048}]
+    assert hidden_tile(2048, 1792, 3, 2) == 896
+    bound = hit_list_rows(2048)
+    assert routes == 8 * [{
+        'route': 'hit_list' if sessions <= bound else 'sorted',
+        'select': 'threshold' if sessions <= bound else 'sort',
+        'n': sessions, 'bound': bound, 'bound_by': 'rule',
+        'tile': 896 if sessions <= bound else None}]
+    # three blocks of 3.67 MB, double-buffered, and the default on top
+    assert _vmem_limit(2048, 896, 3, 2) == 37 << 20
+    hlo = compiled.as_text()
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*flash_decode',
+        hlo)) == 2
+    if sessions <= bound:
+        assert 'ragged-dot' not in hlo
+        assert len(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*moe_hit_experts',
+            hlo)) == 8
+    slab_bytes = sessions * 8 * t_max * 128 * 2
+    assert _cache_sized_moves(hlo, slab_bytes // 8) == []
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert cache_bytes == 2 * slab_bytes + 7 * sessions * 2 * 2048 * 2 + 8
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < slab_bytes // 8
+    states = [c if hasattr(c, 'state') else None for c in caches]
+    snapshot_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                         for x in jax.tree.leaves(states))
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            + snapshot_bytes)
+    assert 10.5 * 2 ** 30 <= peak <= 14.0 * 2 ** 30, peak / 2 ** 30
+    restored = restore.lower(*described(
+        (caches, states, jnp.zeros((), jnp.int32)))).compile()
+    assert restored.memory_analysis().temp_size_in_bytes < 1 << 20
+    # Everything but the two slabs' 4-byte lengths, which are set.
+    assert restored.memory_analysis().alias_size_in_bytes >= (
+        cache_bytes - 8)
+    assert restored.as_text().count('lm.state_restore') >= 14
