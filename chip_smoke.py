@@ -823,7 +823,8 @@ def phase_generate_conv(progs, cfg, seed):
     request, the windows restored and the slab's length set back, and
     the request again — which must read what the first did, bit for bit
     — with the attention layer's step on the kernel over the PACKED
-    cache (``decode_impl_traces()``: its form and bytes a token) and the
+    cache (``decode_impl_traces()``: its form, bytes a token and the
+    KV heads its body scores a pass) and the
     conv mixers' form (``conv_step_traces()``) and the expert calls'
     routes printed beside it."""
     import jax
@@ -877,11 +878,36 @@ def phase_generate_conv(progs, cfg, seed):
         np.asarray(caches[0].conv, np.float32)
         - np.asarray(taken[0].conv, np.float32))))
     caches, again = request(reset(caches, taken))
-    slab = [{k: t[k] for k in ('resolved', 'cache', 'token_bytes', 'tail')}
+    slab = [{**{k: t[k] for k in ('resolved', 'cache', 'token_bytes',
+                                  'tail')},
+             'heads_a_pass': (t['step'] or {}).get('heads_a_pass')}
             for t in traces]
+    # The slab's kernel step against its XLA step on the same operands,
+    # at this layer's heads: what the chip computes, checked on the chip
+    # (PR 52: a piece that read right under the interpreter and on
+    # XLA:CPU read wrong on XLA:TPU, in one head of every pair).
+    from distributed_dot_product_tpu.models.decode import (
+        PackedCache, decode_step,
+    )
+    c = cfg['conv']
+    ks = jax.random.split(jax.random.key(seed + 5), 4)
+    shape = (2, c['kv_heads'], 1, c['dim'] // c['heads'])
+    slab_cache = PackedCache(
+        kv=jax.random.normal(ks[0], (2, c['kv_heads'], t_max,
+                                     2 * shape[-1]), jnp.bfloat16),
+        length=jnp.asarray(n, jnp.int32))
+    q = jax.random.normal(ks[1], (2, c['heads'], 1, shape[-1]),
+                          jnp.bfloat16)
+    kn, vn = (jax.random.normal(k, shape, jnp.bfloat16) for k in ks[2:])
+    slab_err = float(np.max(np.abs(
+        np.asarray(decode_step(q, slab_cache, kn, vn, impl='kernel')[1],
+                   np.float32)
+        - np.asarray(decode_step(q, slab_cache, kn, vn, impl='xla')[1],
+                     np.float32))))
     return {
         'conv_caches': kinds,
         'conv_slab_step': slab,
+        'conv_slab_kernel_vs_xla': slab_err,
         'conv_step_forms': forms,
         'conv_expert_routes': routes,
         'conv_window_moved_by_a_request': moved,
@@ -889,9 +915,13 @@ def phase_generate_conv(progs, cfg, seed):
         'checks': {
             'conv.cache_kinds': kinds == ['StateCache', 'StateCache',
                                           'PackedCache'],
+            # … its KV heads scored two a pass where they pair up
             'conv.slab_resolved_kernel': [
-                (t['resolved'], t['cache'], t['token_bytes'])
-                for t in slab] == [('kernel', 'packed', 256)],
+                (t['resolved'], t['cache'], t['token_bytes'],
+                 t['heads_a_pass']) for t in slab] == [
+                     ('kernel', 'packed', 256,
+                      1 if cfg['conv']['kv_heads'] % 2 else 2)],
+            'conv.slab_kernel_is_its_xla_step': slab_err < 8e-3,
             'conv.steps_are_the_shift': forms == 2 * [
                 {'form': 'shift', 'taps': 3,
                  'channels': cfg['conv']['dim']}],

@@ -262,7 +262,8 @@ class PackedCache(NamedTuple):
     step streams twice the bytes. A step is ``decode_step``'s
     (``flash_decode``'s packed mode: the query zero-extended over the
     value lanes, a resident block in both products, the output the value
-    lanes); a length set back rewinds it."""
+    lanes — two KV heads a pass where a grid step holds an even number
+    of them); a length set back rewinds it."""
     kv: jax.Array
     length: jax.Array
 
@@ -2118,10 +2119,12 @@ def decode_impl_traces():
     ``'packed'`` for a :class:`PackedCache`, keys and values in one
     unpadded row; ``step`` is
     the kernel's grid step,
-    ``{'heads', 'block_k', 'bytes'}`` — KV heads and cache rows of one
-    step and the cache bytes it streams, as
-    ``ops.pallas_decode.decode_geometry`` chose them from the call's
-    shapes — or None off the kernel; ``tail`` is the rows the kernel
+    ``{'heads', 'block_k', 'bytes', 'heads_a_pass'}`` — KV heads and
+    cache rows of one step, the cache bytes it streams and the heads one
+    pass of the body scores (2 where the packed mode's pair pass runs, 1
+    elsewhere), as ``ops.pallas_decode.decode_geometry`` chose them from
+    the call's shapes — or None off the kernel; ``tail`` is the rows the
+    kernel
     moves of the split that holds a slot's last valid column where no
     more than those are filled of it, by the same function — None where
     that split is always moved whole, and off the kernel; ``token_bytes``

@@ -81,7 +81,16 @@ This kernel is ONE Pallas program per decode step that
   ``(…, t_max, 128)`` buffer, the query rides zero-extended (``q̃ · [k |
   v] = q · k``), the resident block enters both products and the output
   is the value lanes of ``p · [k | v]`` — one stream where there were
-  two, nothing padded in HBM, the MXU's passes those of a 128-wide head;
+  two, nothing padded in HBM. Scored a head at a time that is the MXU
+  passes and the softmax tiles of a 128-wide head behind HALF its bytes
+  a step, and the step is no longer the stream's (chip, PR 51: the
+  cell's call 4.11 ms at 66 % of its bytes' roofline); so a step of an
+  even number of heads is scored TWO HEADS A PASS — heads A and B as
+  ``[k_A | k_B]`` and ``[v_A | v_B]``, formed in VMEM by a lane rotation
+  and a lane select each way, their queries block-diagonal in one tile,
+  one score product, one softmax tile and one ``p · v`` product a pair
+  (``pair_block`` in the kernel body; ``DecodeGeometry.step()`` says
+  ``heads_a_pass``). The slab in HBM is what it was;
 - **dequantizes int8 in kernel**: the quantized path streams the 1-byte
   ``k_q`` mirror plus its per-row scales and scores s8×s8→s32 on the
   MXU with the dequantization applied to the s32 block — the halved K
@@ -117,7 +126,8 @@ from distributed_dot_product_tpu.ops.pallas_attention import (
 from distributed_dot_product_tpu.utils.scopes import device_scope
 
 __all__ = ['flash_decode', 'decode_block_k', 'decode_geometry',
-           'latent_geometry', 'flash_decode_geometry', 'DecodeGeometry']
+           'latent_geometry', 'flash_decode_geometry', 'DecodeGeometry',
+           'PairedGeometry']
 
 # K-split cap, in cache rows: the granularity at which a slot's unfilled
 # tail is skipped (never streamed). 1024 rows stream 7 % over a 12.4k
@@ -127,7 +137,12 @@ __all__ = ['flash_decode', 'decode_block_k', 'decode_geometry',
 _BLOCK_K_CAP = 1024
 # What one grid step should stream (K + V blocks of all its heads): a
 # step costs ~0.46 us of pipeline bookkeeping whatever it moves (chip,
-# PR 27), and 4 MB is ~5 us of HBM time on a v5e.
+# PR 27), and 4 MB is ~5 us of HBM time on a v5e. That figure was read
+# at 4 MiB steps of 128-wide heads, where the body — two MXU passes and
+# one softmax tile a head, whatever the head's width — hides under the
+# DMA; a packed step of narrow heads moves half the bytes for the same
+# body and was bound by it until its heads were paired (PERF.md
+# section 6, PR 52).
 _STEP_STREAM_BYTES = 4 << 20
 # VMEM the geometry may plan for (double-buffered streams and write-back
 # tiles, one head's temporaries): the v5e compiler's default scoped
@@ -169,10 +184,27 @@ class DecodeGeometry(NamedTuple):
     bytes: int
     tail: int = None
 
+    @property
+    def heads_a_pass(self):
+        """KV heads one pass of the body scores: 2 where the packed
+        mode's pair body runs (:class:`PairedGeometry`), else 1."""
+        return 1
+
     def step(self):
         """What ``decode_impl_traces()`` reports of it."""
         return {'heads': self.heads, 'block_k': self.block_k,
-                'bytes': self.bytes}
+                'bytes': self.bytes, 'heads_a_pass': self.heads_a_pass}
+
+
+class PairedGeometry(DecodeGeometry):
+    """The grid step of a PACKED call that holds an even number of KV
+    heads: the same tuple (the step, its bytes and its tail are what
+    they were), scored two heads a pass."""
+    __slots__ = ()
+
+    @property
+    def heads_a_pass(self):
+        return 2
 
 
 def _lanes(x):
@@ -191,7 +223,10 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype, *,
     the tests' override of the split; ``ring`` the ring mode; ``packed``
     the one-buffer mode (``flash_decode``: ``d`` is then the packed
     row's width, keys and values together, ``dv = d``, and a row is
-    streamed ONCE). (The latent cache's kernel has a rule of its own,
+    streamed ONCE; a packed step of an even number of heads comes back
+    as a :class:`PairedGeometry`, the same tuple scored two heads a
+    pass, and its plan counts the pair's two block-sized temporaries).
+    (The latent cache's kernel has a rule of its own,
     :func:`latent_geometry`.)
 
     The K split stays at :data:`_BLOCK_K_CAP` rows (skip granularity);
@@ -249,9 +284,17 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype, *,
 
     def vmem(hb, tail=0):
         # Streams and write-back blocks are double-buffered; the tail's
-        # rows are one buffer (moved under the grid row before).
+        # rows are one buffer (moved under the grid row before). A
+        # packed step of an even number of heads scores them in pairs:
+        # the pair's keys and its values, formed from the two resident
+        # blocks, are two more block-sized temporaries, and its score
+        # tile holds both heads' query rows.
+        pair = 0
+        if packed and hb % 2 == 0:
+            g_pair = -(-2 * rows // q_sub) * q_sub
+            pair = 2 * bk * k_row + 8 * (g_pair - g_pad) * bk * 4
         return hb * (2 * (bk + wr) * held_row + tail * stream_row
-                     + small) + temps
+                     + small) + temps + pair
 
     hb = max(c for c in range(1, h_kv + 1)
              if h_kv % c == 0 and (c == 1 or (
@@ -263,7 +306,8 @@ def decode_geometry(t_max, h_kv, d, dv, rows, k_dtype, v_dtype, *,
         tail = next((rows for rows in _TAIL_ROWS
                      if rows < bk and bk % rows == 0 and rows % wr == 0
                      and vmem(hb, rows) <= _VMEM_BUDGET), None)
-    return DecodeGeometry(hb, bk, wr, hb * bk * stream_row, tail)
+    kind = PairedGeometry if packed and hb % 2 == 0 else DecodeGeometry
+    return kind(hb, bk, wr, hb * bk * stream_row, tail)
 
 
 def flash_decode_geometry(q, cache_k, cache_v=None, *, page_table=None,
@@ -359,6 +403,25 @@ def _sweep_end(vt, ap, geom, t_max, n=1):
     return last - tails.astype(jnp.int32), last, tails
 
 
+def _pair_lanes(xa, xb):
+    """``([a_lo | b_lo], [a_hi | b_hi])`` of two ``(rows, w)`` values
+    whose halves are ``[lo | hi]``: one lane rotation by ``w / 2`` of
+    one operand and one lane select against the other, each way. Mosaic
+    rotates 32-bit lanes alone, so a narrower type goes as the words
+    its rows pack into — whole rows of the same lane, which a lane
+    rotation and a lane select move together."""
+    dtype, half = xa.dtype, xa.shape[-1] // 2
+    narrow = dtype.itemsize < 4
+    if narrow:
+        xa, xb = (pltpu.bitcast(x, jnp.uint32) for x in (xa, xb))
+    low = jax.lax.broadcasted_iota(jnp.int32, xa.shape, 1) < half
+    lo = jnp.where(low, xa, pltpu.roll(xb, half, 1))
+    hi = jnp.where(low, pltpu.roll(xa, half, 1), xb)
+    if narrow:
+        lo, hi = pltpu.bitcast(lo, dtype), pltpu.bitcast(hi, dtype)
+    return lo, hi
+
+
 def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                         quantized, has_alibi, paged=False, stacked=False,
                         ring=False, packed=False):
@@ -427,10 +490,33 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
     PACKED (``packed``): there is no value operand, new rows, buffer or
     result: every name of the body that says V is the K ref of the same
     kind (a resident block enters both products), and the append is the
-    one buffer's."""
+    one buffer's.
+
+    THE PAIR PASS (``geom.heads_a_pass == 2``: a packed step of an even
+    number of heads) is a body of the packed mode's own, ``pair_block``.
+    A packed block ``[k | v]`` in both products pushes every byte
+    through the MXU twice, half of each push a contraction against
+    zeros or an output nobody reads, behind a query tile that is mostly
+    padding. So heads ``2j`` and ``2j + 1`` of the step are scored
+    together: their keys ``[k_A | k_B]`` and their values ``[v_A | v_B]``
+    are formed in VMEM (:func:`_pair_lanes`), ``q_ref[j]`` holds A's
+    query rows and then B's, each ``[q | 0]`` as the mode's caller
+    sends them and each head's new-row-major (so a row's intra-step
+    index is ``(row mod n·group) // group``), B's are turned half a row
+    to ``[0 | q_B]`` (``pair_queries``), one score product, one softmax
+    tile, one
+    ``(m, l, acc)`` state and one ``p · [v_A | v_B]`` serve the pair;
+    the context is lanes ``[0, w/2)`` of A's rows and ``[w/2, w)`` of
+    B's, and the last step turns A's half a row so that both leave in
+    the value lanes, as the single-head body's does (a stack of two
+    lane-offset slices in the caller read WRONG on XLA:TPU; chip, PR
+    52). A column's score is the same products summed in the same
+    split order as the single-head body's. The write-back is a head's
+    own rows in the buffer's own layout, as everywhere."""
     hb, bk, wr, tail = (geom.heads, geom.block_k, geom.write_rows,
                        geom.tail)
     per_slot = h_kv // hb                       # grid rows a slot
+    pair = geom.heads_a_pass == 2
 
     def kernel_body(vt_ref, ap_ref, nn_ref, *refs, pt_ref=None,
                     span_ref=None, row0_ref=None):
@@ -492,7 +578,11 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
 
         # Intra-step row index: row j·group + g is new row j's head g,
         # so j = row // group (padded rows land past n — don't-care).
-        jrow = jax.lax.broadcasted_iota(jnp.int32, (g_pad, 1), 0) // group
+        jrow = jax.lax.broadcasted_iota(jnp.int32, (g_pad, 1), 0)
+        if pair:
+            # Two heads' rows a tile, each head's new-row-major.
+            jrow = jrow % (n * group)
+        jrow = jrow // group
 
         @pl.when(ki == 0)
         def _():
@@ -540,11 +630,11 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
             # where nothing is appended, so no block does.)
             return jnp.logical_and(col0 < ap + nn, ap < col0 + size)
 
-        def score_block(col0, size, score_blk, scale_blk, k_blk, v_blk,
-                        substitute):
-            """Fold the resident block of ``size`` cache rows from
-            column ``col0`` into every head's softmax state: the whole
-            split (``score_ref`` …) or a sub-block of the tail."""
+        def positions(col0, size):
+            """``(cols, masked, relf)`` of a block of ``size`` columns
+            from ``col0``, a query tile's rows against them: the column
+            numbers, which pairs are masked, and the float32 distance
+            ALiBi scales (None without it)."""
             cols = (col0 + jax.lax.broadcasted_iota(
                 jnp.int32, (g_pad, size), 1))
             if ring:
@@ -560,6 +650,28 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                 if window is not None:
                     masked = jnp.logical_or(masked, rel <= -window)
             relf = rel.astype(jnp.float32) if has_alibi else None
+            return cols, masked, relf
+
+        def online_softmax(i, s, masked, v):
+            """Fold the base-2 logits ``s`` of query tile ``i`` and
+            their values ``v`` into the tile's running state."""
+            s = jnp.where(masked, -jnp.inf, s)
+            m_prev = m_s[i]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp2(s - m_new)
+            corr = jnp.exp2(m_prev - m_new)
+            m_s[i] = m_new
+            l_s[i] = l_s[i] * corr + p.sum(axis=-1, keepdims=True)
+            acc_s[i] = acc_s[i] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        def score_block(col0, size, score_blk, scale_blk, k_blk, v_blk,
+                        substitute):
+            """Fold the resident block of ``size`` cache rows from
+            column ``col0`` into every head's softmax state: the whole
+            split (``score_ref`` …) or a sub-block of the tail."""
+            cols, masked, relf = positions(col0, size)
             for h in range(hb):
                 s = scores(h, score_blk[h],
                            scale_blk[h] if quantized else None)
@@ -581,20 +693,59 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                             vn_ref[h, m:m + 1, :], v)
                 if has_alibi:
                     s = s + alibi_ref[h] * relf
-                s = jnp.where(masked, -jnp.inf, s)
-                m_prev = m_s[h]
-                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-                p = jnp.exp2(s - m_new)
-                corr = jnp.exp2(m_prev - m_new)
-                m_s[h] = m_new
-                l_s[h] = l_s[h] * corr + p.sum(axis=-1, keepdims=True)
-                acc_s[h] = acc_s[h] * corr + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                online_softmax(h, s, masked, v)
+
+        # Of a pair's tile, the rows that are head A's.
+        is_a = pair and jax.lax.broadcasted_iota(
+            jnp.int32, (g_pad, 1), 0) < n * group
+
+        def pair_queries(j):
+            """Pair ``j``'s queries block-diagonally: A's rows as they
+            arrive, ``[q_A | 0]``, B's turned half a row, ``[0 | q_B]``
+            (their value lanes are zeros by the mode's contract). Here
+            and not in the caller: XLA:TPU would not be trusted with it
+            (chip, PR 52 — a ``jnp.roll`` of these few rows aborted its
+            compiler when called eagerly). Mosaic rotates 32-bit lanes
+            alone; the round trip through float32 is exact."""
+            q2 = q_ref[j]
+            turned = pltpu.roll(q2.astype(jnp.float32), q2.shape[-1] // 2,
+                                1).astype(q2.dtype)
+            return jnp.where(is_a, q2, turned)
+
+        def pair_block(col0, size, blk, _scales, _k, _v, substitute):
+            """``score_block`` for the packed mode's pairs, two heads a
+            pass (the mode's blocks are ONE ref: ``blk`` is what is
+            scored, the keys and the values)."""
+            cols, masked, relf = positions(col0, size)
+            for j in range(hb // 2):
+                k, v = _pair_lanes(blk[2 * j], blk[2 * j + 1])
+                q2 = pair_queries(j)
+                s = jax.lax.dot_general(
+                    q2, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
+                if substitute:
+                    k_new, v_new = _pair_lanes(kn_ref[2 * j],
+                                               kn_ref[2 * j + 1])
+                    s_new = jax.lax.dot_general(
+                        q2, k_new, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                    rows_v = col0 + jax.lax.broadcasted_iota(
+                        jnp.int32, v.shape, 0)
+                    for m in range(n):
+                        s = jnp.where(
+                            jnp.logical_and(cols == ap + m, m < nn),
+                            s_new[:, m:m + 1], s)
+                        v = jnp.where(
+                            jnp.logical_and(rows_v == ap + m, m < nn),
+                            v_new[m:m + 1, :], v)
+                if has_alibi:
+                    s = s + alibi_ref[j] * relf
+                online_softmax(j, s, masked, v)
+
+        fold = pair_block if pair else score_block
 
         def score_split(substitute):
-            score_block(ki * bk, bk, score_ref, ks_ref, k_ref, v_ref,
-                        substitute)
+            fold(ki * bk, bk, score_ref, ks_ref, k_ref, v_ref, substitute)
 
         landing = lands(ki * bk, bk)
         pl.when(jnp.logical_and(run, landing))(
@@ -703,7 +854,7 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
                 for copy in moves(row0, last):
                     copy.wait()
                 col0 = last * bk
-                pl.when(hits(col0, tail))(lambda: score_block(
+                pl.when(hits(col0, tail))(lambda: fold(
                     col0, tail, ktail_ref, None, ktail_ref, vtail_ref,
                     True))
                 pl.when(in_tail)(lambda: stage(
@@ -725,7 +876,16 @@ def _make_decode_kernel(geom, ns, n, group, g_pad, h_kv, window,
 
         @pl.when(ki == ns - 1)
         def _():
-            o_ref[...] = acc_s[...]
+            if pair:
+                # p · [v_A | v_B]: B's rows hold their context in the
+                # value lanes, where the packed mode's caller looks for
+                # it; A's hold theirs in the key lanes, half a row off.
+                for j in range(hb // 2):
+                    acc = acc_s[j]
+                    o_ref[j] = jnp.where(
+                        is_a, pltpu.roll(acc, acc.shape[-1] // 2, 1), acc)
+            else:
+                o_ref[...] = acc_s[...]
             m_ref[...] = m_s[...]
             l_ref[...] = l_s[...]
 
@@ -875,7 +1035,12 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     streamed once, and ``out (B, H, k, w/2)`` is the value lanes of
     ``p · [k | v]``. Pass ``scale`` (the default reads ``q``'s width).
     Not with ``page_table``, ``layer``, ``qk_quant`` or ``ring_span``.
-    The same kernel body and program name.
+    The same program name; where a grid step holds an even number of
+    KV heads (``flash_decode_geometry(...).heads_a_pass == 2``) the
+    body scores them two a pass — a pair's query rows share one tile,
+    which the kernel makes block-diagonal — and the single-head body
+    otherwise. Same operands, same results to
+    the order of float32 sums inside a pass.
 
     THE TAIL (no argument: :func:`decode_geometry` gives a call its
     ``tail`` rows or None, ``valid_to`` decides a slot's step at run
@@ -991,15 +1156,19 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     nb = b * h_kv
     per_slot = h_kv // hb                   # grid rows a slot
 
+    # Query tiles (and softmax states, and result blocks) of a grid
+    # step, and of the call: a head a tile, or — the packed mode's pair
+    # pass — heads 2j and 2j + 1 one after the other in tile j.
+    tiles, nt = hb // geom.heads_a_pass, nb // geom.heads_a_pass
     # Query rows grouped per cache head, NEW-ROW-major (row j·group + g
     # = new row j, query head g — the layout the kernel's per-row
     # intra-step mask assumes), padded to the sublane multiple of their
     # kernel dtype; padded rows are sliced off the output.
     qg = jnp.swapaxes(q.reshape(b, h_kv, group, n, d), 2, 3
-                      ).reshape(nb, n * group, d)
+                      ).reshape(nt, geom.heads_a_pass * n * group, d)
     rows = n * group
     sub = _sublane(jnp.int8 if quantized else cache_k.dtype)
-    g_pad = -(-rows // sub) * sub
+    g_pad = -(-qg.shape[1] // sub) * sub
     if quantized:
         qi, sq = _quantize_rows(qg, nb, rows, d)
         qf = _pad_rows(qi, sub)
@@ -1161,7 +1330,7 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         def write_idx_row(bi, ki, vt, ap, nn, *lay):
             return (_row(bi, lay), 0, _write_blk(bi, ki, ap, nn))
 
-    in_specs = [pl.BlockSpec((hb, g_pad, d), const_idx)]
+    in_specs = [pl.BlockSpec((tiles, g_pad, d), const_idx)]
     args = [qf]
     if quantized:
         in_specs.append(pl.BlockSpec((hb, g_pad, 1), const_idx))
@@ -1214,22 +1383,22 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
             h_kv, group, 1) * _LOG2E
         slopes = jnp.broadcast_to(slopes[None, :, None],
                                   (b, h_kv, n, group, 1))
-        in_specs.append(pl.BlockSpec((hb, g_pad, 1), const_idx))
-        args.append(_pad_rows(slopes.reshape(nb, n * group, 1), sub))
+        in_specs.append(pl.BlockSpec((tiles, g_pad, 1), const_idx))
+        args.append(_pad_rows(slopes.reshape(nt, -1, 1), sub))
 
     out_specs = [
-        pl.BlockSpec((hb, g_pad, dv), const_idx),  # num
-        pl.BlockSpec((hb, g_pad, 1), const_idx),    # m
-        pl.BlockSpec((hb, g_pad, 1), const_idx),    # l
+        pl.BlockSpec((tiles, g_pad, dv), const_idx),  # num
+        pl.BlockSpec((tiles, g_pad, 1), const_idx),    # m
+        pl.BlockSpec((tiles, g_pad, 1), const_idx),    # l
         # k (aliased): the write-back tile, or — with a tail — the
         # buffer itself, which the kernel's own copies read and write.
         (pl.BlockSpec(memory_space=pl.ANY) if tail
          else pl.BlockSpec((hb, wr, d), write_idx)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((nb, g_pad, dv), jnp.float32),
-        jax.ShapeDtypeStruct((nb, g_pad, 1), jnp.float32),
-        jax.ShapeDtypeStruct((nb, g_pad, 1), jnp.float32),
+        jax.ShapeDtypeStruct((nt, g_pad, dv), jnp.float32),
+        jax.ShapeDtypeStruct((nt, g_pad, 1), jnp.float32),
+        jax.ShapeDtypeStruct((nt, g_pad, 1), jnp.float32),
         jax.ShapeDtypeStruct(kf.shape, kf.dtype),
     ]
     # +n_prefetch: alias indices count the scalar-prefetch operands
@@ -1258,9 +1427,9 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
         aliases[n_prefetch + kq_in_pos] = 5
         aliases[n_prefetch + ks_in_pos] = 6
 
-    scratch = [pltpu.VMEM((hb, g_pad, 1), jnp.float32),
-               pltpu.VMEM((hb, g_pad, 1), jnp.float32),
-               pltpu.VMEM((hb, g_pad, dv), jnp.float32)]
+    scratch = [pltpu.VMEM((tiles, g_pad, 1), jnp.float32),
+               pltpu.VMEM((tiles, g_pad, 1), jnp.float32),
+               pltpu.VMEM((tiles, g_pad, dv), jnp.float32)]
     if tail:
         # The tail's rows and the write-back tile's staging rows, K
         # then V; a DMA semaphore each.
@@ -1301,9 +1470,11 @@ def flash_decode(q, k_new, v_new, cache_k, cache_v, valid_to, append_at,
     new_v = None if packed else outs[4].reshape(cache_v.shape)
 
     def head_shape(x):
-        # Rows are new-row-major per kv head: undo the (n, group) fold
-        # back to (B, H, n, ·).
-        x = x[:, :n * group].reshape(b, h_kv, n, group, x.shape[-1])
+        # Rows are new-row-major per kv head (a pair's tile holds head
+        # A's, then head B's): undo the (n, group) fold back to
+        # (B, H, n, ·).
+        x = x[:, :geom.heads_a_pass * n * group].reshape(
+            b, h_kv, n, group, x.shape[-1])
         return jnp.swapaxes(x, 2, 3).reshape(b, h, n, x.shape[-1])
 
     num, m, l = head_shape(num), head_shape(m), head_shape(l)

@@ -119,7 +119,7 @@ def test_chip_smoke_tiny_cpu_rehearsal_still_fails():
                                    'PackedCache']
     assert conv['conv_slab_step'] == [{
         'resolved': 'xla', 'cache': 'packed', 'token_bytes': None,
-        'tail': None}]
+        'tail': None, 'heads_a_pass': None}]
     assert conv['conv_step_forms'] == 2 * [
         {'form': 'shift', 'taps': 3, 'channels': 128}]
     assert [(r['route'], r['bound_by'])

@@ -225,7 +225,8 @@ def test_ring_step_reports_its_mode():
     assert [(t['resolved'], t['cache']) for t in traces] == [
         ('kernel', 'ring'), ('xla', 'ring')]
     assert traces[0]['step'] == {'heads': 1, 'block_k': 16,
-                                 'bytes': 16384}   # lanes pad d to 128
+                                 'bytes': 16384,   # lanes pad d to 128
+                                 'heads_a_pass': 1}
 
 
 def test_the_cells_geometry():

@@ -727,7 +727,7 @@ def test_decode_impl_traces_carry_the_step():
     geom = decode_geometry(T, 2, D, D, 2, jnp.float32, jnp.float32)
     assert [t['resolved'] for t in traces] == ['kernel', 'xla', 'kernel']
     assert traces[0]['step'] == geom.step() == {
-        'heads': 2, 'block_k': T, 'bytes': geom.bytes}
+        'heads': 2, 'block_k': T, 'bytes': geom.bytes, 'heads_a_pass': 1}
     assert traces[0]['step']['heads'] == 2
     assert traces[1]['step'] is None and traces[2]['step'] is None
     # The tail's rows ride beside the step, in a key of their own: one
@@ -739,4 +739,5 @@ def test_decode_impl_traces_carry_the_step():
         decode_step(wide, big, wide[:, :2], wide[:, :2], impl='kernel')
     assert traces[0]['tail'] == decode_geometry(
         4096, 2, 128, 128, 2, jnp.float32, jnp.float32).tail == 256
-    assert sorted(traces[0]['step']) == ['block_k', 'bytes', 'heads']
+    assert sorted(traces[0]['step']) == [
+        'block_k', 'bytes', 'heads', 'heads_a_pass']

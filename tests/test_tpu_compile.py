@@ -831,7 +831,8 @@ def test_mixed_stack_decode_step_moves_no_cache_and_fits(chip,
     assert [(t['resolved'], t['cache']) for t in traces] == 3 * [
         ('kernel', 'ring')] + [('kernel', 'layer')]
     assert {tuple(t['step'].items()) for t in traces} == {
-        (('heads', 8), ('block_k', 1024), ('bytes', 4 << 20))}
+        (('heads', 8), ('block_k', 1024), ('bytes', 4 << 20),
+         ('heads_a_pass', 1))}
     # 12 rows: the held experts' hit list, by the rule, in every layer
     assert routes == 4 * [{'route': 'hit_list', 'select': 'threshold',
                            'n': sessions, 'bound': 128,
@@ -908,7 +909,7 @@ def test_hybrid_stack_decode_step_aliases_every_state_and_fits(
             *described((params, tok, caches, stats))).compile()
     assert [(t['resolved'], t['cache'], t['step']) for t in traces] == [
         ('kernel', 'layer', {'heads': 2, 'block_k': 1024,
-                             'bytes': 1 << 20})]
+                             'bytes': 1 << 20, 'heads_a_pass': 1})]
     # the driver's own bound, honoured as it was
     assert routes == 5 * [{'route': 'hit_list', 'select': 'threshold',
                            'n': sessions, 'bound': 64,
@@ -1741,14 +1742,21 @@ def test_ling_decode_step_reads_latent_rows_and_six_states_once_and_fits(
 # the two halves of one 128-lane row) at the edges of its plan: the new
 # cell's own call (8 KV heads of a slot a step, the tail), a multi-head
 # layer whose 16 heads do not fit one step, a verify-k step (the whole
-# split written back, no tail), a buffer of one split (no tail).
-# (b, h, h_kv, d, n, t_max) -> (heads a step, tail, bytes a token).
+# split written back, no tail), a buffer of one split (no tail). Since
+# PR 52 a step of an even number of heads is scored two heads a pass —
+# the cell's call (256 x 8 x 5120 x 128, group 4) is the PAIR body with
+# its two block-sized temporaries inside the plan, under the compiler's
+# default VMEM; one head and three keep the single-head body.
+# (b, h, h_kv, d, n, t_max) -> (heads a step, tail, bytes a token, heads
+# a pass).
 _PACKED_EDGES = {
-    'lfm2-cell': ((256, 32, 8, 64, 1, 5120), (8, 256, 256)),
-    'mha-16-heads': ((4, 16, 16, 64, 1, 16384), (8, 256, 256)),
-    'verify4': ((4, 32, 8, 64, 4, 8192), (8, None, 256)),
-    'one-split': ((4, 32, 8, 64, 1, 1024), (8, None, 256)),
-    'd128-pairs': ((4, 8, 2, 128, 1, 8192), (2, 256, 512)),
+    'lfm2-cell': ((256, 32, 8, 64, 1, 5120), (8, 256, 256, 2)),
+    'mha-16-heads': ((4, 16, 16, 64, 1, 16384), (8, 256, 256, 2)),
+    'verify4': ((4, 32, 8, 64, 4, 8192), (8, None, 256, 2)),
+    'one-split': ((4, 32, 8, 64, 1, 1024), (8, None, 256, 2)),
+    'd128-pairs': ((4, 8, 2, 128, 1, 8192), (2, 256, 512, 2)),
+    'one-head': ((16, 4, 1, 64, 1, 8192), (1, 256, 256, 1)),
+    'three-heads': ((16, 12, 3, 64, 1, 8192), (3, 256, 256, 1)),
 }
 
 
@@ -1760,7 +1768,7 @@ def test_packed_decode_kernel_compiles_at_its_edges(chip, edge):
     from distributed_dot_product_tpu.ops.pallas_decode import (
         flash_decode, flash_decode_geometry,
     )
-    (b, h, h_kv, d, n, t_max), (heads, tail, token_bytes) = (
+    (b, h, h_kv, d, n, t_max), (heads, tail, token_bytes, a_pass) = (
         _PACKED_EDGES[edge])
     bf16 = jnp.bfloat16
     q = jax.ShapeDtypeStruct((b, h, n, 2 * d), bf16)
@@ -1770,6 +1778,7 @@ def test_packed_decode_kernel_compiles_at_its_edges(chip, edge):
     geom = flash_decode_geometry(q, kv)
     assert (geom.heads, geom.tail) == (heads, tail)
     assert geom.bytes // (geom.heads * geom.block_k) == token_bytes
+    assert geom.step()['heads_a_pass'] == a_pass
 
     def step(q, new, kv, at):
         return flash_decode(q, new, None, kv, None, at, at,
@@ -1789,7 +1798,8 @@ def test_lfm2_decode_step_streams_packed_rows_and_fits(chip, monkeypatch):
     beside two packed ``(256, 8, 5120, 128)`` slabs), caches donated:
     both attention layers' step resolves to the kernel on the PACKED
     cache at 256 bytes a token a KV head — 8 heads of a session a grid
-    step, the tail's 256 rows —, every conv mixer's step is its traced
+    step, scored two heads a pass, the tail's 256 rows —, every conv
+    mixer's step is its traced
     form, every expert layer's 256-row call takes the route the rule
     names by the rule's own bound — ONE ``moe_hit_experts`` kernel a
     layer, two 896-wide tiles an expert, and no grouped matmul —,
@@ -1847,8 +1857,8 @@ def test_lfm2_decode_step_streams_packed_rows_and_fits(chip, monkeypatch):
         compiled = step.lower(
             *described((params, tok, caches, stats))).compile()
     assert [(t['resolved'], t['cache'], t['token_bytes'], t['tail'],
-             t['step']['heads']) for t in traces] == 2 * [
-        ('kernel', 'packed', 256, 256, 8)]
+             t['step']['heads'], t['step']['heads_a_pass'])
+            for t in traces] == 2 * [('kernel', 'packed', 256, 256, 8, 2)]
     assert forms == 7 * [{'form': 'shift', 'taps': 3, 'channels': 2048}]
     assert hidden_tile(2048, 1792, 3, 2) == 896
     bound = hit_list_rows(2048)

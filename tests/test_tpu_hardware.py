@@ -843,3 +843,38 @@ def test_verify_k_kernel_matches_sequential_bitwise_on_chip(h, h_kv, dtype):
                       (cv.length, seq.length)):
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize('h_kv', [8, 2, 1])
+@pytest.mark.parametrize('fill', [500, 2648, 4224])
+def test_packed_kernel_matches_its_xla_step_on_chip(h_kv, fill):
+    """``flash_decode``'s packed mode under Mosaic — the pair pass (8 and
+    2 KV heads) and the single-head body (1) — against the packed XLA
+    step, head by head: a fill of one split, a row landing deep in a
+    split and the cell's (four whole splits and the tail). Every piece
+    of the pair pass read right under the interpreter AND on XLA:CPU
+    while its first caller-side pick of the context (a stack of two
+    lane-offset slices) read wrong on XLA:TPU and head B of every pair
+    with it (chip, PR 52): what the chip computes is checked on the
+    chip."""
+    from distributed_dot_product_tpu.models.decode import (
+        PackedCache, decode_impl_traces, decode_step,
+    )
+    b, group, d, t_max = 2, 4, 64, 5120
+    ks = jax.random.split(jax.random.key(11), 4)
+    cache = PackedCache(
+        kv=jax.random.normal(ks[0], (b, h_kv, t_max, 2 * d), jnp.bfloat16),
+        length=jnp.asarray(fill, jnp.int32))
+    q = jax.random.normal(ks[1], (b, h_kv * group, 1, d), jnp.bfloat16)
+    kn = jax.random.normal(ks[2], (b, h_kv, 1, d), jnp.bfloat16)
+    vn = jax.random.normal(ks[3], (b, h_kv, 1, d), jnp.bfloat16)
+    with decode_impl_traces() as traces:
+        got_cache, got = decode_step(q, cache, kn, vn, impl='kernel')
+    want_cache, want = decode_step(q, cache, kn, vn, impl='xla')
+    assert traces[0]['step']['heads_a_pass'] == (1 if h_kv == 1 else 2)
+    np.testing.assert_array_equal(np.asarray(got_cache.kv, np.float32),
+                                  np.asarray(want_cache.kv, np.float32))
+    err = np.abs(np.asarray(got, np.float32)
+                 - np.asarray(want, np.float32)).max(axis=(0, 2, 3))
+    # bfloat16 contexts of ~0.1: a rounding, in every head
+    assert err.max() < 4e-3, err.reshape(h_kv, group).max(1)
